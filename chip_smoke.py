@@ -1,0 +1,582 @@
+"""The quickest proof that the system still starts on the chip.
+
+GPT-2 small at its published widths (12 layers, d_model 768, 12 heads of
+64, vocab 50257; random weights from ``--seed``) trains a few steps and
+answers a few requests on ONE TPU chip through the normal entry points,
+in one process:
+
+    python chip_smoke.py              # needs one chip; ~2-5 min cold
+    python chip_smoke.py --chips 4    # the data-parallel phase alone, 4 chips
+    python chip_smoke.py --rehearse [--chips 4]   # CPU, tiny, never "ok"
+
+Phases, each printing one JSON line when it is done (any failed assertion
+raises — there is no handler that lets the run end 0):
+
+- ``device``     platform, ``device_kind``, count, versions, compile cache.
+- ``train``      ``python -m mpit_tpu.asyncsgd gpt2`` (T=1024, flash kernel,
+                 ZeRO-1, fused LM head): finite, falling loss; the flash
+                 kernel in the compiled step; compile seconds; one step
+                 closed with ``block_until_ready`` and one with a host fetch.
+- ``serve``      ``python -m mpit_tpu.serve --model small`` (paged bf16 KV,
+                 chunked prefill, kernel decode): every request retired with
+                 the asked number of tokens; kernel in the decode step;
+                 prefill- and decode-step logits against the model's plain
+                 full forward pass.
+- ``serve_int8`` the same requests on int8 KV + int8 weights; logits against
+                 the f32-weight engine; which matmuls ran the Pallas kernel.
+- ``dp4``        (``--chips 4`` only, and then the only phase) ZeRO-1 data
+                 parallel over four chips vs the same global batch and seed
+                 on one of them; then ``grad_sync=ring`` and ``ring_q8``.
+
+The last line of stdout is ``{"ok": true, "device": {...}}``. Without an
+accelerator the script exits non-zero before doing any work and prints no
+result. ``--rehearse`` changes sizes and runs the serve kernels in interpret
+mode, nothing else; it ends ``"ok": false`` and exit code 3 when every
+phase passed.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import sys
+import threading
+import time
+
+# Logit tolerances (max abs difference; the logits of a random-init GPT-2
+# small have a standard deviation of 0.55). Measured on the v5e, PR 21:
+# 0.024 kernel vs plain, 0.056 int8 vs f32 weights.
+TOL_KERNEL_VS_PLAIN = 0.05  # same weights, bf16 compute, other op order
+TOL_INT8_VS_F32 = 0.25  # tests/test_weights_quant.py's weight-store bound
+# Per-step loss tolerances of the four-chip runs (loss is ~10.9). Measured
+# on four v5e chips, PR 21: 6e-5 vs one chip, 7e-5 ring, 2e-3 ring_q8.
+TOL_DP4_VS_ONE_CHIP = 0.01
+TOL_RING_Q8_VS_PSUM = 0.05
+RING_TIMEOUT_S = 300.0  # the ring kernels' protocol has only run interpreted
+
+FULL = dict(
+    model=["--num-layers", "12", "--d-model", "768", "--num-heads", "12",
+           "--vocab-size", "50257", "--seq-len", "1024"],
+    batch=16, steps=6,
+    serve=["--model", "small", "--slots", "8", "--max-len", "1024",
+           "--prefill-len", "256", "--kv-pages", "512", "--kv-page-size",
+           "16", "--prefill-chunk", "64", "--requests", "6", "--prompt-len",
+           "200", "--max-new-tokens", "16"],
+    decode_attention="kernel", probe_len=96,
+)
+TINY = dict(
+    model=["--num-layers", "2", "--d-model", "64", "--num-heads", "4",
+           "--vocab-size", "512", "--seq-len", "128"],
+    batch=8, steps=6,
+    serve=["--model", "tiny", "--slots", "4", "--max-len", "128",
+           "--prefill-len", "64", "--kv-pages", "32", "--kv-page-size",
+           "16", "--prefill-chunk", "16", "--requests", "4", "--prompt-len",
+           "40", "--max-new-tokens", "4"],
+    decode_attention="interpret", probe_len=24,
+)
+
+
+def emit(phase: str, **fields) -> None:
+    print(json.dumps({"phase": phase, **fields}), flush=True)
+
+
+def phase_device(want_chips: int, rehearse: bool) -> dict:
+    import jax
+    import jaxlib
+
+    from mpit_tpu.utils import compile_cache_dir
+
+    cache = compile_cache_dir()
+    devs = jax.devices()
+    device = {
+        "platform": devs[0].platform,
+        "kind": devs[0].device_kind,
+        "count": len(devs),
+    }
+    if not rehearse and device["platform"] != "tpu":
+        print(f"chip_smoke: no accelerator ({device})", file=sys.stderr)
+        raise SystemExit(2)
+    if len(devs) != want_chips:
+        print(
+            f"chip_smoke: wants {want_chips} device(s), found {len(devs)}",
+            file=sys.stderr,
+        )
+        raise SystemExit(2)
+    from importlib import metadata
+
+    try:
+        libtpu = metadata.version("libtpu")
+    except metadata.PackageNotFoundError:
+        libtpu = "unknown"
+    emit("device", **device, jax=jax.__version__, jaxlib=jaxlib.__version__,
+         libtpu=libtpu, compile_cache_dir=cache, rehearse=rehearse)
+    return device
+
+
+# ---------------------------------------------------------------------------
+# Training
+# ---------------------------------------------------------------------------
+
+
+def _train_argv(sz, *extra) -> list[str]:
+    return [*sz["model"], "--flash", "true", "--batch-size", str(sz["batch"]),
+            "--steps", str(sz["steps"]), "--log-every", "1", *extra]
+
+
+def _build_train(world, tcfg, grad_sync: str):
+    """The DP branch of ``asyncsgd.gpt2.main``, by its own pieces, for a
+    caller who needs the state and the step function afterwards."""
+    import jax
+    import jax.numpy as jnp
+
+    from mpit_tpu.asyncsgd import runner
+    from mpit_tpu.data import SyntheticLM
+    from mpit_tpu.models import GPT2
+    from mpit_tpu.opt import goo_adam, schedules
+    from mpit_tpu.train import make_train_step
+
+    model = GPT2(tcfg.model_config())
+
+    def loss_fn(params, batch):
+        return GPT2.fused_loss_fn(model, params, batch["tokens"]), {}
+
+    tx = goo_adam(schedules.from_config(tcfg), weight_decay=tcfg.weight_decay)
+    init_fn, step_fn, _ = make_train_step(
+        loss_fn, tx, world, zero1=True, grad_sync=grad_sync,
+        grad_bucket_mb=tcfg.grad_bucket_mb,
+    )
+    params = jax.jit(model.init)(
+        jax.random.key(tcfg.seed), jnp.zeros((1, tcfg.seq_len), jnp.int32)
+    )["params"]
+    stream = runner.make_stream(
+        tcfg, SyntheticLM(vocab_size=tcfg.vocab_size, seed=tcfg.seed),
+        tcfg.seq_len,
+    )
+    return init_fn(params), step_fn, stream
+
+
+def _run_train(world, tcfg, grad_sync: str):
+    """``steps`` steps under ``hardened_loop``: (losses, state, step_fn)."""
+    from mpit_tpu.train import hardened_loop
+
+    state, step_fn, stream = _build_train(world, tcfg, grad_sync)
+    result = hardened_loop(
+        world, state, step_fn, stream, steps=tcfg.steps, log_every=1,
+        items_per_batch=tcfg.batch_size * tcfg.seq_len,
+    )
+    return result["losses"], result["state"], step_fn
+
+
+def _check_losses(losses, steps: int) -> None:
+    import math
+
+    assert len(losses) == steps, (len(losses), steps)
+    assert all(math.isfinite(x) for x in losses), losses
+    assert losses[-1] < losses[0], losses
+
+
+def phase_train(sz, seed: int, rehearse: bool) -> None:
+    import jax
+
+    import mpit_tpu
+    from mpit_tpu.asyncsgd import gpt2
+    from mpit_tpu.asyncsgd.config import from_argv
+    from mpit_tpu.data import native, shard_batch
+
+    # The command line a user runs, native (C++) token stream included:
+    # the library is not tracked, so this also proves it builds here.
+    argv = _train_argv(sz, "--seed", str(seed))
+    t0 = time.perf_counter()
+    out = gpt2.main([*argv, "--native", "true"])
+    cli_wall = time.perf_counter() - t0
+    assert native.available(), "native data core did not build"
+    assert out["tier"] == "shard_map+zero1", out["tier"]
+    _check_losses(out["losses"], sz["steps"])
+
+    # The same step by its pieces: its compiled text, and two closed steps.
+    tcfg = from_argv(gpt2.GPT2TrainConfig, argv)
+    world = mpit_tpu.init()
+    state, step_fn, stream = _build_train(world, tcfg, "psum")
+    batch = shard_batch(world, next(stream))
+    t0 = time.perf_counter()
+    compiled = step_fn.build(state.params, state.extra).lower(
+        state, batch
+    ).compile()
+    compile_s = time.perf_counter() - t0
+    kernels = compiled.as_text().count("tpu_custom_call")
+    assert rehearse or kernels, "no flash kernel in the compiled train step"
+
+    state, _ = compiled(state, batch)  # warm: first execution
+    jax.block_until_ready(state)
+    # One step closed with block_until_ready, then a host fetch of its
+    # loss (which should then cost nothing); one closed by the fetch alone.
+    t0 = time.perf_counter()
+    state, metrics = compiled(state, batch)
+    jax.block_until_ready((state, metrics))
+    t_block = time.perf_counter() - t0
+    float(metrics["loss"])
+    t_block_then_fetch = time.perf_counter() - t0
+    t0 = time.perf_counter()
+    state, metrics = compiled(state, batch)
+    float(metrics["loss"])
+    t_fetch = time.perf_counter() - t0
+    emit(
+        "train", losses=out["losses"], steps=sz["steps"], batch=sz["batch"],
+        seq_len=tcfg.seq_len, native_core=True, cli_wall_s=round(cli_wall, 2),
+        compile_s=round(compile_s, 2), custom_calls_in_step=kernels,
+        step_s_block_until_ready=t_block,
+        step_s_block_then_fetch=t_block_then_fetch,
+        step_s_host_fetch_only=t_fetch,
+    )
+
+
+# ---------------------------------------------------------------------------
+# Serving
+# ---------------------------------------------------------------------------
+
+
+def _serve_argv(sz, seed: int, *extra) -> list[str]:
+    return [*sz["serve"], "--decode-attention", sz["decode_attention"],
+            "--seed", str(seed), *extra]
+
+
+def _decode_step_kernels(engine) -> int:
+    """``tpu_custom_call`` count in the engine's compiled decode step."""
+    import jax
+    import jax.numpy as jnp
+
+    s = engine.slots
+    args = (
+        engine.params, engine.cache, engine.last_token,
+        jnp.zeros((s,), bool),
+        jnp.zeros((s, engine.pages_per_slot), jnp.int32),
+        jax.random.key(0), jnp.zeros((s,), jnp.float32),
+        jnp.zeros((s,), jnp.int32),
+    )
+    text = engine._decode_paged_jit.lower(*args).compile().as_text()
+    return text.count("tpu_custom_call")
+
+
+def _engine_logits(engine, prompt, first=None):
+    """Logits of the model the engine really runs (``engine.cfg``: kernel
+    attention, its matmuls) over its own page pool: the prompt's last
+    position from one T=len(prompt) prefill, then one T=1 decode step on
+    the token that follows (``first``, default the prefill's argmax)."""
+    import jax
+    import jax.numpy as jnp
+
+    from mpit_tpu.models import GPT2
+
+    model = GPT2(engine.cfg)
+    table = jnp.arange(engine.pages_per_slot, dtype=jnp.int32)[None]
+
+    @jax.jit
+    def forward(params, tokens, k, v, lengths):
+        valid = jnp.ones(tokens.shape, bool)
+        return model.apply(
+            {"params": params}, tokens,
+            paged_cache=(k, v, lengths, table, valid),
+        )
+
+    n = len(prompt)
+    pre, (k, v) = forward(
+        engine.params, jnp.asarray([prompt], jnp.int32),
+        engine.cache.k, engine.cache.v, jnp.zeros((1,), jnp.int32),
+    )
+    if first is None:
+        first = int(jnp.argmax(pre[0, n - 1]))
+    dec, _ = forward(
+        engine.params, jnp.asarray([[first]], jnp.int32), k, v,
+        jnp.full((1,), n, jnp.int32),
+    )
+    return pre[0, n - 1], dec[0, 0], first
+
+
+def _paged_kernel_vs_reference(engine, seed: int) -> dict:
+    """The paged flash-decode kernel alone, at the engine's geometry, on
+    random bf16 data and ragged lengths: output against the gather-dense
+    reference, and the kernel's own visited-tile count against the host
+    formula the scheduler's counters use."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from mpit_tpu.ops.decode_attention import (
+        flash_paged_decode_attention,
+        num_kv_blocks,
+        reference_paged_decode_attention,
+    )
+
+    cfg, b, pps = engine.cfg, engine.slots, engine.pages_per_slot
+    kq, kk, kv = jax.random.split(jax.random.key(seed), 3)
+    pool = (engine.num_pages, engine.page_size, cfg.num_heads, cfg.head_dim)
+    q = jax.random.normal(kq, (b, 1, *pool[2:]), jnp.bfloat16)
+    k = jax.random.normal(kk, pool, jnp.bfloat16)
+    v = jax.random.normal(kv, pool, jnp.bfloat16)
+    rng = np.random.RandomState(seed)
+    lengths = jnp.asarray(rng.randint(0, engine.max_len - 1, size=b), jnp.int32)
+    table = jnp.asarray(
+        rng.permutation(engine.num_pages)[: b * pps].reshape(b, pps), jnp.int32
+    )
+    out, visited = flash_paged_decode_attention(
+        q, k, v, lengths, table, block_k=engine.decode_block_k,
+        interpret=True if engine.decode_attention == "interpret" else None,
+        return_visited=True,
+    )
+    want = reference_paged_decode_attention(q, k, v, lengths, table)
+    err = _max_abs(out, want)
+    tiles = num_kv_blocks(
+        np.asarray(lengths), 1, engine.max_len, engine.decode_block_k
+    )
+    assert np.array_equal(np.asarray(visited), tiles), (visited, tiles)
+    assert err <= 0.02, err  # bf16 rows of unit variance
+    return {"decode_kernel_err_vs_reference": err,
+            "visited_tiles": np.asarray(visited).tolist()}
+
+
+def _max_abs(a, b) -> float:
+    import jax.numpy as jnp
+
+    return float(jnp.max(jnp.abs(a.astype(jnp.float32) - b.astype(jnp.float32))))
+
+
+def _run_requests(engine, scfg, vocab: int) -> dict:
+    from mpit_tpu.serve import Server
+    from mpit_tpu.serve.__main__ import synthetic_requests
+
+    server = Server(engine)
+    requests = list(synthetic_requests(scfg, vocab))
+    for r in requests:
+        server.submit(r)
+    done = {c.rid: c for c in server.run()}
+    assert sorted(done) == [r.rid for r in requests], sorted(done)
+    for r in requests:
+        c = done[r.rid]
+        assert len(c.tokens) == r.max_new_tokens and not c.truncated, r.rid
+    return {
+        "requests": len(requests),
+        "prompt_lens": [len(r.prompt) for r in requests],
+        "tokens_each": scfg.max_new_tokens,
+    }
+
+
+def phase_serve(sz, seed: int, rehearse: bool):
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from mpit_tpu.asyncsgd.config import from_argv
+    from mpit_tpu.models import GPT2
+    from mpit_tpu.serve import __main__ as serve_cli
+    from mpit_tpu.serve import warm_engine
+
+    # The command line a user runs.
+    argv = _serve_argv(sz, seed)
+    scfg = from_argv(serve_cli.ServeConfig, argv)
+    out = serve_cli.main(argv)
+    assert out["requests_completed"] == scfg.requests, out
+    assert out["generated_tokens"] == scfg.requests * scfg.max_new_tokens
+    assert out["decode_attention"] == "kernel", out["decode_attention"]
+    assert not out["truncated"], out
+
+    # The same engine by the CLI's own builder, to look inside.
+    engine, mcfg = serve_cli._build_engine(scfg)
+    assert engine.decode_attention_mode == "kernel"
+    t0 = time.perf_counter()
+    warm_engine(engine)
+    compile_s = time.perf_counter() - t0
+    kernels = _decode_step_kernels(engine)
+    assert rehearse or kernels, "no kernel in the compiled decode step"
+    ran = _run_requests(engine, scfg, mcfg.vocab_size)
+    ran.update(_paged_kernel_vs_reference(engine, seed))
+
+    prompt = np.random.RandomState(seed).randint(
+        0, mcfg.vocab_size, size=sz["probe_len"]
+    ).tolist()
+    pre, dec, first = _engine_logits(engine, prompt)
+    plain = jax.jit(GPT2(mcfg).apply)(
+        {"params": engine.params}, jnp.asarray([prompt + [first]], jnp.int32)
+    )[0]
+    err_pre = _max_abs(pre, plain[len(prompt) - 1])
+    err_dec = _max_abs(dec, plain[len(prompt)])
+    assert np.isfinite(np.asarray(dec, np.float32)).all()
+    assert dec.shape == (mcfg.vocab_size,), dec.shape
+    assert max(err_pre, err_dec) <= TOL_KERNEL_VS_PLAIN, (err_pre, err_dec)
+    emit(
+        "serve", **ran, cli_wall_s=out["wall_s"],
+        decode_attention=engine.decode_attention_mode,
+        kv_dtype=engine.kv_dtype, weights_dtype=engine.weights_dtype,
+        compile_s=round(compile_s, 2), custom_calls_in_decode_step=kernels,
+        logit_std=float(jnp.std(plain)), prefill_logit_err=err_pre,
+        decode_logit_err=err_dec, tolerance=TOL_KERNEL_VS_PLAIN,
+    )
+    return prompt, first, dec
+
+
+def phase_serve_int8(sz, seed: int, rehearse: bool, probe) -> None:
+    import numpy as np
+
+    from mpit_tpu import obs
+    from mpit_tpu.asyncsgd.config import from_argv
+    from mpit_tpu.serve import __main__ as serve_cli
+    from mpit_tpu.serve import warm_engine
+
+    prompt, first, dec_f32 = probe
+    scfg = from_argv(
+        serve_cli.ServeConfig,
+        _serve_argv(sz, seed, "--kv-dtype", "int8", "--weights-dtype", "int8"),
+    )
+    rec = obs.enable(obs.Recorder())  # quantized_matmul stamps its path
+    try:
+        engine, mcfg = serve_cli._build_engine(scfg)
+        assert engine.decode_attention_mode == "kernel"
+        assert engine.kv_quantized and engine.weights_quantized
+        t0 = time.perf_counter()
+        warm_engine(engine)
+        compile_s = time.perf_counter() - t0
+        kernels = _decode_step_kernels(engine)
+        ran = _run_requests(engine, scfg, mcfg.vocab_size)
+        _, dec, _ = _engine_logits(engine, prompt, first)
+        paths: dict = {"kernel": set(), "lax": set()}
+        for attrs, _n in rec.counter_items("quantized_matmul_calls"):
+            paths[attrs["path"]].add(attrs["shape"])
+    finally:
+        obs.disable()
+    assert rehearse or (kernels and paths["kernel"]), (kernels, paths)
+    err = _max_abs(dec, dec_f32)
+    assert np.isfinite(np.asarray(dec, np.float32)).all()
+    assert 0.0 < err <= TOL_INT8_VS_F32, err
+    emit(
+        "serve_int8", **ran, kv_dtype=engine.kv_dtype,
+        weights_dtype=engine.weights_dtype, compile_s=round(compile_s, 2),
+        custom_calls_in_decode_step=kernels,
+        quantized_matmul_kernel=sorted(paths["kernel"]),
+        quantized_matmul_lax=sorted(paths["lax"]),
+        lm_head_sampler="lm_head_sample streams int8 vocab tiles (lax)",
+        decode_logit_err_vs_f32_weights=err, tolerance=TOL_INT8_VS_F32,
+    )
+
+
+# ---------------------------------------------------------------------------
+# Four chips: data-parallel training
+# ---------------------------------------------------------------------------
+
+
+def _holds_a_shard_each(state, devices) -> dict:
+    """ZeRO-1 optimizer state must span every device, a shard on each."""
+    import jax
+
+    sharded = [
+        leaf for leaf in jax.tree.leaves(state.opt_state)
+        if hasattr(leaf, "addressable_shards")
+        and leaf.addressable_shards[0].data.shape != leaf.shape
+    ]
+    assert sharded, "no optimizer-state leaf is sharded"
+    for leaf in sharded:
+        held = {s.device for s in leaf.addressable_shards}
+        assert held == set(devices), (leaf.shape, held)
+    in_use = []
+    for d in devices:
+        stats = d.memory_stats()  # None on the CPU backend
+        if stats is not None:
+            in_use.append(stats["bytes_in_use"])
+    return {"sharded_leaves": len(sharded), "bytes_in_use": in_use}
+
+
+def phase_dp4(sz, seed: int, rehearse: bool) -> bool:
+    import gc
+
+    import jax
+
+    import mpit_tpu
+    from mpit_tpu.asyncsgd import gpt2
+    from mpit_tpu.asyncsgd.config import from_argv
+
+    tcfg = from_argv(gpt2.GPT2TrainConfig, _train_argv(sz, "--seed", str(seed)))
+    devices = jax.devices()
+    one = mpit_tpu.init({"data": 1}, devices=devices[:1], set_default=False)
+    four = mpit_tpu.init({"data": 4})
+
+    ref, _, _ = _run_train(one, tcfg, "psum")
+    gc.collect()
+    psum, state, _ = _run_train(four, tcfg, "psum")
+    _check_losses(psum, sz["steps"])
+    placed = _holds_a_shard_each(state, devices)
+    if placed["bytes_in_use"]:
+        # Params alone are ~0.5 GB on every chip at GPT-2 small.
+        assert min(placed["bytes_in_use"]) > 2**28, placed
+    del state
+    gc.collect()
+    diff = max(abs(a - b) for a, b in zip(ref, psum))
+    assert diff <= TOL_DP4_VS_ONE_CHIP, (ref, psum)
+    emit("dp4", grad_sync="psum", losses=psum, one_chip_losses=ref,
+         max_loss_diff_vs_one_chip=diff, tolerance=TOL_DP4_VS_ONE_CHIP,
+         global_batch=sz["batch"], **placed)
+
+    # The ring kernels' remote-DMA and semaphore protocol has only ever
+    # run in the interpreter; a hang is the likely failure. A watchdog
+    # reports it and ends the process (a hung device call cannot be
+    # interrupted from Python).
+    def hung():
+        emit("dp4", ring=f"failed: no result within {RING_TIMEOUT_S:.0f}s")
+        os._exit(1)
+
+    ok = True
+    for mode, tol in (
+        ("ring", TOL_DP4_VS_ONE_CHIP), ("ring_q8", TOL_RING_Q8_VS_PSUM)
+    ):
+        watchdog = threading.Timer(RING_TIMEOUT_S, hung)
+        watchdog.daemon = True
+        watchdog.start()
+        try:
+            losses, state, step_fn = _run_train(four, tcfg, mode)
+        finally:
+            watchdog.cancel()
+        del state
+        gc.collect()
+        _check_losses(losses, sz["steps"])
+        # Off the chip the ring modes run their lax composition, and say so.
+        assert rehearse or step_fn.grad_sync_mode == mode, step_fn.grad_sync_mode
+        diff = max(abs(a - b) for a, b in zip(psum, losses))
+        passed = diff <= tol
+        ok = ok and passed
+        emit("dp4", grad_sync=mode, executed=step_fn.grad_sync_mode,
+             losses=losses, max_loss_diff_vs_psum=diff, tolerance=tol,
+             bitwise_equal_to_psum=losses == psum, passed=passed)
+    return ok
+
+
+def main(argv=None) -> int:
+    parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    parser.add_argument("--chips", type=int, choices=(1, 4), default=1)
+    parser.add_argument("--seed", type=int, default=0)
+    parser.add_argument("--rehearse", action="store_true")
+    args = parser.parse_args(argv)
+    if args.rehearse:
+        # Sizes and the platform, before jax starts.
+        os.environ["JAX_PLATFORMS"] = "cpu"
+        os.environ["XLA_FLAGS"] = (
+            f"--xla_force_host_platform_device_count={args.chips}"
+        )
+    sz = TINY if args.rehearse else FULL
+
+    device = phase_device(args.chips, args.rehearse)
+    if args.chips == 4:
+        ok = phase_dp4(sz, args.seed, args.rehearse)
+    else:
+        phase_train(sz, args.seed, args.rehearse)
+        probe = phase_serve(sz, args.seed, args.rehearse)
+        phase_serve_int8(sz, args.seed, args.rehearse, probe)
+        ok = True
+    if args.rehearse:
+        # A rehearsal is never a pass: it says what it ran on, and 3.
+        print(json.dumps({"ok": False, "rehearsal_passed": ok,
+                          "device": device}))
+        return 3 if ok else 1
+    print(json.dumps({"ok": ok, "device": device}))
+    return 0 if ok else 1
+
+
+if __name__ == "__main__":
+    raise SystemExit(main())

@@ -39,29 +39,34 @@ Phases (mirroring the dryrun, plus the memory-regression shape):
 from __future__ import annotations
 
 import json
-import os
 import sys
 import time
 import traceback
-
-# Persistent compile cache (same dir as bench.py): repeated AOT runs —
-# and the driver's — replay cached compilations instead of paying the
-# multi-minute phases again.
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
-                      os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                                   ".jax_cache"))
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from mpit_tpu.utils import compile_cache_dir
 from mpit_tpu.utils.aot import (
     abstractify,
     aot_compile,
     memory_report,
     topology_world,
 )
+
+
+def _kernel_report(compiled) -> dict:
+    """memory_report, for a phase whose point is a Pallas kernel: the
+    compiled program must contain one. The ops decide "kernel or lax"
+    from the attached platform, which is ``cpu`` wherever this script
+    can run, so a kernel phase that is not steered compiles the lax
+    composition and would otherwise pass having compiled no kernel."""
+    n = compiled.as_text().count("tpu_custom_call")
+    if not n:
+        raise AssertionError("no tpu_custom_call in the compiled program")
+    return {"custom_calls": n, **memory_report(compiled)}
 
 
 def _params_mb(params) -> float:
@@ -311,7 +316,7 @@ def phase_3d_dp_cp_tp(topology):
         P("data", "seq"),
     )
     compiled = aot_compile(step_fn.build(stacked), state, batch_abs)
-    return {"params_mb": round(_params_mb(full), 1), **memory_report(compiled)}
+    return {"params_mb": round(_params_mb(full), 1), **_kernel_report(compiled)}
 
 
 def phase_ulysses_in_tp(topology):
@@ -432,7 +437,7 @@ def phase_cp_long_context(topology):
         "global_tokens": t_global,
         "seq_shards": 8,
         "params_mb": round(_params_mb(params), 1),
-        **memory_report(compiled),
+        **_kernel_report(compiled),
     }
 
 
@@ -463,7 +468,9 @@ def phase_ep_moe(topology):
 
 
 def phase_pallas_ring_allreduce(topology):
-    from mpit_tpu.ops import ring_allreduce
+    from unittest import mock
+
+    from mpit_tpu.ops import ring_allreduce, ring_collectives
 
     world = topology_world({"data": 8}, topology)
     f = jax.jit(
@@ -478,8 +485,9 @@ def phase_pallas_ring_allreduce(topology):
         world.mesh,
         P("data"),
     )
-    compiled = aot_compile(f, x)
-    return memory_report(compiled)
+    with mock.patch.object(ring_collectives, "_use_kernel", lambda _: True):
+        compiled = aot_compile(f, x)
+    return _kernel_report(compiled)
 
 
 PHASES = [
@@ -499,6 +507,7 @@ PHASES = [
 
 
 def main(topology: str = "v5e:2x4") -> int:
+    compile_cache_dir()
     record = {"topology": topology, "phases": {}}
     failed = []
     for name, fn in PHASES:

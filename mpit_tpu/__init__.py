@@ -47,9 +47,4 @@ This package is NOT a port. It is a ground-up TPU-first (JAX / XLA / Pallas /
 
 __version__ = "0.1.0"
 
-# Must run before any submodule touches the 0.9-era jax API surface
-# (see its docstring): installs semantics-preserving fallbacks when the
-# environment's jax predates typeof/axis_size/shard_map-with-check_vma.
-import mpit_tpu._jaxcompat  # noqa: F401  (import is the side effect)
-
 from mpit_tpu.comm import init, init_hybrid, World  # noqa: F401

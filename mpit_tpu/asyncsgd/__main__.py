@@ -34,4 +34,7 @@ def main(argv: list[str] | None = None) -> int:
 
 
 if __name__ == "__main__":
+    from mpit_tpu.utils import compile_cache_dir
+
+    compile_cache_dir()
     raise SystemExit(main())

@@ -60,7 +60,8 @@ class TrainConfig:
     # Gradient-sync wire tier (ISSUE 9; train/grad_sync.py):
     # "psum" = stock XLA collectives (default, seed behavior);
     # "ring" = in-kernel Pallas ring reduce-scatter/all-gather, issued
-    # per grad bucket (numerically identical to psum — pinned);
+    # per grad bucket (same sums in ring order: bitwise psum on the CPU
+    # fallback, reduction-order noise on chips);
     # "ring_q8" = the ring with the int8 quantized wire (per-chunk
     # scales, ~1/4 the wire bytes) — LOSSY: trajectory differs from
     # f32 sync by design (loss-curve-pinned within noise), so resuming

@@ -35,42 +35,15 @@ from jax import lax
 # Varying→invariant all-gather: the result is identical on every device and
 # is *marked* replicated for shard_map's VMA checker (plain lax.all_gather
 # returns a varying-typed value). Public in spirit; lives in _src in jax 0.9.
-# Pre-VMA jax has no such op (nothing to mark) — the compat gate's plain
-# all_gather stands in.
-try:
-    from jax._src.lax.parallel import (
-        all_gather_invariant as _all_gather_invariant,
-    )
-except ImportError:
-    from mpit_tpu._jaxcompat import all_gather_invariant as _all_gather_invariant
+from jax._src.lax.parallel import (
+    all_gather_invariant as _all_gather_invariant,
+)
 
 
 def _pvary(x, names):
-    # Replicated→varying retype: jax 0.9's public spelling is
-    # lax.pcast(..., to='varying'); fall back to the deprecated lax.pvary,
-    # and to identity on pre-VMA jax (nothing to retype for).
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, names, to="varying")
-    if hasattr(lax, "pvary"):
-        return lax.pvary(x, names)
-    return x
+    """Replicated→varying retype."""
+    return lax.pcast(x, names, to="varying")
 
-
-def unvary(x, names):
-    """Varying→replicated retype for a value PROVEN identical on every
-    device along ``names`` — the claim ``all_gather_invariant`` makes
-    for its own output, extended to values whose invariance the caller
-    establishes by construction (a ring all-gather's output, a
-    ppermute-circulated broadcast). Pre-VMA jax: identity. A WRONG use
-    (value actually differs per device) silently desynchronizes
-    replicas — callers own the proof."""
-    if hasattr(lax, "pcast"):
-        for to in ("invariant", "replicated"):
-            try:
-                return lax.pcast(x, names, to=to)
-            except (TypeError, ValueError):
-                continue
-    return x
 
 AxisName = str | Sequence[str]
 

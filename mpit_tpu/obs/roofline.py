@@ -26,7 +26,7 @@ P2P matrix established:
   ``hbm_util_pct`` / ``ici_util_pct`` against the chip peaks, plus the
   binding-resource verdict (:func:`rollup` / :func:`utilization`).
 
-Honesty rules (the repo's dead-tunnel discipline): modeled cost and
+Honesty rules: modeled cost and
 achieved-work *totals* are recorded on every platform, but utilization
 *percentages* — measured seconds against TPU peaks — are only computed
 when the recording platform IS the chip (``platform="tpu"``); CPU /
@@ -94,11 +94,22 @@ _BOUND_BY_COMPONENT = {"flops": "compute", "hbm_bytes": "hbm",
                        "ici_bytes": "ici"}
 
 
-def chip_peaks(chip=None) -> dict:
+def chip_peaks(chip=None, *, platform: str = "modeled") -> dict:
     """``{chip, peak_flops, peak_hbm, peak_ici}`` from a
-    :class:`~mpit_tpu.utils.profiling.ChipSpec` (default: the TPU v5e
-    spec, imported lazily so this module costs nothing at import)."""
-    if chip is None:
+    :class:`~mpit_tpu.utils.profiling.ChipSpec`. With no ``chip``:
+    ``platform="tpu"`` means a utilization of the attached device is
+    about to be computed, so its peaks come from the one table keyed by
+    ``device_kind`` and an unknown device raises; any other platform
+    gets the TPU v5e spec as the *modeled* chip (those runs report no
+    percentages). Imports are lazy: this module costs nothing at
+    import."""
+    if chip is None and platform == "tpu":
+        import jax
+
+        from mpit_tpu.utils.profiling import chip_spec_for
+
+        chip = chip_spec_for(jax.devices()[0].device_kind)
+    elif chip is None:
         from mpit_tpu.utils.profiling import TPU_V5E as chip
     return {
         "chip": chip.name,
@@ -183,7 +194,7 @@ def register_cost(
             "ici_bytes": float(ici_bytes),
             "platform": str(platform),
             "source": source,
-            **chip_peaks(chip),
+            **chip_peaks(chip, platform=platform),
         },
     )
 

@@ -8,7 +8,7 @@ a rolling median/MAD detector over the loop's host-side phase times —
 step wall, prefetch wait, host fence — that flags
 
 - ``spike``: one observation far above the rolling median (a stall,
-  a preemption hiccup, a contended tunnel);
+  a preemption hiccup, a contended host);
 - ``sustained_degradation``: several consecutive observations above a
   lower threshold (the run got durably slower — a thermal throttle, a
   neighbor, a regression that warmup hid);

@@ -176,7 +176,8 @@ def _decode_kernel(
 
     Dense (``page_size=None``) refs: ``lengths_ref`` [B] int32 SMEM,
     ``q_ref`` [1, T, H·D] VMEM, ``k_hbm``/``v_hbm`` [B, S, H·D]
-    ANY/HBM, ``o_ref``, ``visited_ref``, scratch. Paged adds ``bt_ref``
+    ANY/HBM, ``o_ref``, ``visited_ref`` (whole [B] int32 SMEM, entry
+    ``b`` written by program ``b``), scratch. Paged adds ``bt_ref``
     [B, pages_per_slot] int32 SMEM after ``lengths_ref`` and the HBM
     operands become the [num_pages, page_size, H·D] pool — the ONLY
     other difference is the DMA source: tile ``ki`` is resolved through
@@ -184,9 +185,10 @@ def _decode_kernel(
     loop, masks and accumulators are byte-for-byte the same code.
 
     ``quantized`` (ISSUE 15): the HBM operand list interleaves scale
-    planes — ``k, k_scale, v, v_scale`` with scales [B, S, H] (dense)
-    or [num_pages, page_size, H] (paged) f32 — and the scratch grows
-    matching [2, block_k, H] double buffers on two extra DMA channels.
+    planes — ``k, k_scale, v, v_scale`` with scales [B, S, Hp] (dense)
+    or [num_pages, page_size, Hp] (paged) f32, Hp = H lane-padded to 128
+    (:func:`_kv_operands`) — and the scratch grows matching
+    [2, block_k, Hp] double buffers on two extra DMA channels.
     Each visited tile dequantizes in VMEM, per head, through the shared
     :func:`~mpit_tpu.ops.ring_collectives.dequantize_blocks`; the rest
     of the loop is identical, in f32 operands.
@@ -223,7 +225,7 @@ def _decode_kernel(
     # paged case the clamp also bounds the block-table index, so a stale
     # table entry past the mapped pages is never resolved).
     n_k = jnp.clip((length + t_q + block_k - 1) // block_k, 1, s // block_k)
-    visited_ref[0, 0] = n_k
+    visited_ref[b] = n_k
 
     def dma(which_hbm, which_buf, sem_row, slot, ki):
         if bt_ref is None:
@@ -327,6 +329,10 @@ def _vma(x):
     return getattr(jax.typeof(x), "vma", frozenset()) or frozenset()
 
 
+def _lane_pad(n: int) -> int:
+    return -(-n // 128) * 128
+
+
 def _kv_operands(k, v, h, pk):
     """The kernel's HBM operand list + matching double-buffer scratch
     for one (K, V) pair — plain buffers or the quantized interleave
@@ -337,7 +343,13 @@ def _kv_operands(k, v, h, pk):
     quantized = isinstance(k, QuantizedKV)
     if not quantized:
         return quantized, [pk(k), pk(v)], [k.dtype, v.dtype]
-    psc = lambda sc: sc.reshape(sc.shape[0], sc.shape[1], h)
+    # Mosaic DMAs whole 128-lane tiles: a [.., H] f32 plane narrower
+    # than a lane tile is refused ("slice shape ... must be aligned to
+    # tiling (128)"), so the scale operand is lane-padded here.
+    psc = lambda sc: jnp.pad(
+        sc.reshape(sc.shape[0], sc.shape[1], h),
+        ((0, 0), (0, 0), (0, _lane_pad(h) - h)),
+    )
     ops = [pk(k.q), psc(k.scale), pk(v.q), psc(v.scale)]
     return quantized, ops, [jnp.int8, jnp.float32, jnp.int8, jnp.float32]
 
@@ -345,7 +357,8 @@ def _kv_operands(k, v, h, pk):
 def _scratch_for(quantized, block_k, hd, h, dtypes):
     """Double-buffer VMEM scratch matching :func:`_kv_operands`' order
     (+ the DMA semaphore array sized to the channel count)."""
-    widths = [hd, h, hd, h] if quantized else [hd, hd]
+    hp = _lane_pad(h)
+    widths = [hd, hp, hd, hp] if quantized else [hd, hd]
     bufs = [
         pltpu.VMEM((2, block_k, w), dt) for w, dt in zip(widths, dtypes)
     ]
@@ -377,23 +390,23 @@ def _decode_call(q, k, v, lengths, *, block_k, interpret):
         ]
         # K/V (+ scale planes when quantized) stay in HBM; the kernel
         # DMAs visited tiles itself.
-        + [pl.BlockSpec(memory_space=pltpu.ANY) for _ in kv_ops],
+        + [pl.BlockSpec(memory_space=pl.ANY) for _ in kv_ops],
         out_specs=[
             pl.BlockSpec(
                 (1, t, hd), lambda i: (i, 0, 0), memory_space=pltpu.VMEM
             ),
-            pl.BlockSpec(
-                (1, 1), lambda i: (i, 0), memory_space=pltpu.SMEM
-            ),
+            # Whole [B] counter in SMEM, each program writing its own
+            # entry: Mosaic refuses a blocked (1, 1) SMEM output.
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, t, hd), q.dtype, vma=_vma(q)),
-            jax.ShapeDtypeStruct((b, 1), jnp.int32, vma=_vma(q)),
+            jax.ShapeDtypeStruct((b,), jnp.int32, vma=_vma(q)),
         ],
         scratch_shapes=_scratch_for(quantized, block_k, hd, h, kv_dtypes),
         interpret=bool(interpret),
     )(jnp.asarray(lengths, jnp.int32), pk(q), *kv_ops)
-    return o.reshape(b, t, h, d), visited[:, 0]
+    return o.reshape(b, t, h, d), visited
 
 
 @functools.partial(
@@ -427,18 +440,18 @@ def _paged_decode_call(
             ),
         ]
         # K/V pools (+ scale planes when quantized) stay in HBM.
-        + [pl.BlockSpec(memory_space=pltpu.ANY) for _ in kv_ops],
+        + [pl.BlockSpec(memory_space=pl.ANY) for _ in kv_ops],
         out_specs=[
             pl.BlockSpec(
                 (1, t, hd), lambda i: (i, 0, 0), memory_space=pltpu.VMEM
             ),
-            pl.BlockSpec(
-                (1, 1), lambda i: (i, 0), memory_space=pltpu.SMEM
-            ),
+            # Whole [B] counter in SMEM, each program writing its own
+            # entry: Mosaic refuses a blocked (1, 1) SMEM output.
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, t, hd), q.dtype, vma=_vma(q)),
-            jax.ShapeDtypeStruct((b, 1), jnp.int32, vma=_vma(q)),
+            jax.ShapeDtypeStruct((b,), jnp.int32, vma=_vma(q)),
         ],
         scratch_shapes=_scratch_for(quantized, block_k, hd, h, kv_dtypes),
         interpret=bool(interpret),
@@ -447,7 +460,7 @@ def _paged_decode_call(
         jnp.asarray(block_table, jnp.int32),
         pk(q), *kv_ops,
     )
-    return o.reshape(b, t, h, d), visited[:, 0]
+    return o.reshape(b, t, h, d), visited
 
 
 def flash_paged_decode_attention(

@@ -85,6 +85,11 @@ DEFAULT_BLOCK_ROWS = 256
 
 _LANE = 128
 _SUBLANE_F32 = 8
+# Output rows per kernel program. The [rows, F] f32 accumulator, its
+# pipelined output block and the [block, F] dequantized tile share the
+# 16 MB scoped VMEM; 128 rows fit at the widest GPT-2 small F (3072),
+# 512 do not ("ran out of memory in memory space vmem").
+_ROW_TILE = 128
 
 
 def _round_up(x: int, m: int) -> int:
@@ -170,6 +175,17 @@ def weight_wire_bytes(shape, dtype) -> float:
     return float(n * jnp.dtype(dtype).itemsize)
 
 
+def _stamp_path(path: str, rows: int, cols: int) -> None:
+    """Trace-time stamp (the ``comm.collectives._rec`` idiom) of which
+    path a weight shape took, so that the dispatcher's step-aside to
+    the lax path is visible in the obs counters."""
+    from mpit_tpu.obs import core as _obs
+
+    _obs.counter(
+        "quantized_matmul_calls", 1, path=path, shape=f"{rows}x{cols}"
+    )
+
+
 def _pad_blocks(w: QuantizedTensor, block: int):
     """Pad a quantized weight's rows to a multiple of ``block`` and
     reshape to per-block tiles: ``([n, block, cols] int8, [n, block]
@@ -228,6 +244,7 @@ def quantized_matmul_t(x, w: QuantizedTensor, *, block_rows=None):
     full-D contraction: bitwise identical to whole-dequant, with only a
     ``[block, D]`` f32 tile live. Returns f32 ``[..., V]``."""
     v, d = w.q.shape
+    _stamp_path("lax", v, d)
     block = min(block_rows or DEFAULT_BLOCK_ROWS, _round_up(v, 8))
     qb, sb = _pad_blocks(w, block)
     n = qb.shape[0]
@@ -262,30 +279,31 @@ def quantized_matmul_reference(x, w: QuantizedTensor, *, block_rows=None):
 
 
 # ---------------------------------------------------------------------------
-# Kernel. x resident in VMEM; the int8 row-block tiles and their scale
-# blocks stay in HBM (memory_space=ANY) and are DMA'd in by the kernel
-# on two channels of a double buffer — exactly the PR 15 quantized
-# decode-attention transfer pattern, aimed at weights.
+# Kernel. x and the scale column resident in VMEM; the int8 row-block
+# tiles stay in HBM (memory_space=ANY) and are DMA'd in by the kernel,
+# double-buffered — the PR 15 quantized decode-attention transfer
+# pattern, aimed at weights.
 # ---------------------------------------------------------------------------
 
 
-def _qmm_kernel(x_ref, q_hbm, s_hbm, o_ref, q_buf, s_buf, sem, *, n_blocks):
+def _qmm_kernel(x_ref, q_hbm, s_ref, o_ref, q_buf, sem, *, n_blocks):
     """One program: ``o = Σ_i x[i] @ (q[i] · s[i])`` with f32 accumulate.
 
     ``x_ref`` [n, M, block] f32 VMEM (pre-blocked over the contraction
-    dim); ``q_hbm`` [n, block, F] int8 / ``s_hbm`` [n, block] f32 in
-    HBM; double-buffered VMEM scratch ``q_buf`` [2, block, F] /
-    ``s_buf`` [2, block]; ``sem`` [2 channels, 2 slots] DMA semaphores.
+    dim); ``q_hbm`` [n, block, F] int8 in HBM, double-buffered through
+    ``q_buf`` [2, block, F] on ``sem`` [2] DMA semaphores; ``s_ref``
+    [D, 1] f32 VMEM, the whole scale column. The scales ride as a
+    resident column, not a second DMA channel: Mosaic refuses the
+    one-row slice a per-block [block] scale DMA takes ("slice shape
+    along dimension 0 must be aligned to tiling"), and a [block, 1]
+    column is what the row-wise dequant broadcasts from anyway.
     """
+    block = q_buf.shape[1]
 
     def dma(i, slot):
-        return (
-            pltpu.make_async_copy(q_hbm.at[i], q_buf.at[slot], sem.at[0, slot]),
-            pltpu.make_async_copy(s_hbm.at[i], s_buf.at[slot], sem.at[1, slot]),
-        )
+        return pltpu.make_async_copy(q_hbm.at[i], q_buf.at[slot], sem.at[slot])
 
-    for c in dma(0, 0):
-        c.start()
+    dma(0, 0).start()
 
     m, f = o_ref.shape
 
@@ -294,15 +312,14 @@ def _qmm_kernel(x_ref, q_hbm, s_hbm, o_ref, q_buf, s_buf, sem, *, n_blocks):
 
         @pl.when(i + 1 < n_blocks)
         def _prefetch():
-            for c in dma(i + 1, 1 - slot):
-                c.start()
+            dma(i + 1, 1 - slot).start()
 
-        for c in dma(i, slot):
-            c.wait()
+        dma(i, slot).wait()
 
         # Fused dequant in VMEM: the f32 weight exists only as this
         # [block, F] tile.
-        w_tile = q_buf[slot].astype(jnp.float32) * s_buf[slot][:, None]
+        s_col = s_ref[pl.ds(pl.multiple_of(i * block, block), block), :]
+        w_tile = q_buf[slot].astype(jnp.float32) * s_col
         return acc + jnp.dot(
             x_ref[i], w_tile, preferred_element_type=jnp.float32
         )
@@ -314,28 +331,32 @@ def _qmm_kernel(x_ref, q_hbm, s_hbm, o_ref, q_buf, s_buf, sem, *, n_blocks):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def _qmm_call(x_blocked, q_blocked, s_blocked, *, interpret):
-    n, m, _ = x_blocked.shape
+def _qmm_call(x_blocked, q_blocked, scale, *, interpret):
+    n, m, block = x_blocked.shape
     f = q_blocked.shape[-1]
+    tm = min(m, _ROW_TILE)
     kern = functools.partial(_qmm_kernel, n_blocks=n)
     return pl.pallas_call(
         kern,
+        grid=(m // tm,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.VMEM),  # x, whole [n, M, b]
-            # int8 tiles + scale blocks stay in HBM; the kernel DMAs
-            # them per row-block on two channels.
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(
+                (n, tm, block), lambda i: (0, i, 0), memory_space=pltpu.VMEM
+            ),
+            # int8 tiles stay in HBM; the kernel DMAs them per row-block.
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pltpu.VMEM),  # scales, whole [D, 1]
         ],
-        out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+        out_specs=pl.BlockSpec(
+            (tm, f), lambda i: (i, 0), memory_space=pltpu.VMEM
+        ),
         out_shape=jax.ShapeDtypeStruct((m, f), jnp.float32),
         scratch_shapes=[
-            pltpu.VMEM((2, q_blocked.shape[1], f), jnp.int8),
-            pltpu.VMEM((2, q_blocked.shape[1]), jnp.float32),
-            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.VMEM((2, block, f), jnp.int8),
+            pltpu.SemaphoreType.DMA((2,)),
         ],
         interpret=bool(interpret),
-    )(x_blocked, q_blocked, s_blocked)
+    )(x_blocked, q_blocked, scale)
 
 
 def quantized_matmul(
@@ -356,13 +377,17 @@ def quantized_matmul(
     aligned = (
         block % _LANE == 0 and f % _LANE == 0 and d % block == 0
     )
-    if not (_use_kernel(interpret) and aligned):
+    kernel = _use_kernel(interpret) and aligned
+    _stamp_path("kernel" if kernel else "lax", d, f)
+    if not kernel:
         return quantized_matmul_lax(x, w, block_rows=block)
     n = d // block
     m = 1
     for s in x.shape[:-1]:
         m *= int(s)
-    m_pad = _round_up(max(m, 1), _SUBLANE_F32)
+    m_pad = _round_up(
+        max(m, 1), _SUBLANE_F32 if m <= _ROW_TILE else _ROW_TILE
+    )
     x2 = x.reshape(m, d).astype(jnp.float32)
     if m_pad != m:
         x2 = jnp.concatenate(
@@ -371,6 +396,5 @@ def quantized_matmul(
     # [M, D] -> [n, M, block]: each kernel tick reads one slab.
     xb = jnp.moveaxis(x2.reshape(m_pad, n, block), 1, 0)
     qb = w.q.reshape(n, block, f)
-    sb = w.scale.reshape(n, block)
-    out = _qmm_call(xb, qb, sb, interpret=interpret is True)
+    out = _qmm_call(xb, qb, w.scale, interpret=interpret is True)
     return out[:m].reshape(*x.shape[:-1], f)

@@ -71,7 +71,6 @@ from mpit_tpu.comm.collectives import (
     _all_gather_invariant,
     _pvary,
     _rec,
-    unvary,
 )
 
 _LANE = 128
@@ -643,23 +642,19 @@ def _ag_fallback(x2d, plan: RingPlan, *, axis, quantized):
         # raw primitive, NOT C.allgather: the caller already charged
         # this collective's wire bytes at the ring model.
         return _all_gather_invariant(x2d, axis, axis=0, tiled=True)
-    # Quantize once, circulate (q, scale) verbatim, dequantize every
-    # chunk (the own one included — replica consistency, see kernel).
+    # Quantize once, gather (q, scale) verbatim, dequantize every chunk
+    # (the own one included — replica consistency, see kernel). An
+    # all-gather has no per-hop math, so the invariant gather of the
+    # int8 payload IS the ring's result, bit for bit — and it is typed
+    # replicated, which a ppermute-circulated value cannot be.
     p, rows = plan.p, plan.padded_rows
-    i = lax.axis_index(axis)
     q_own, scale_own = quantize_chunk(x2d.astype(jnp.float32))
-    out = jnp.zeros((p, rows, _LANE), x2d.dtype)
-    own = dequantize_chunk(q_own, scale_own).astype(x2d.dtype)
-    out = lax.dynamic_update_index_in_dim(out, own, i, axis=0)
-    q, s = q_own, scale_own
-    for step in range(p - 1):
-        recv_c = lax.rem(i - 1 - step + 2 * p, p)
-        q = _shift_right(q, axis)
-        s = _shift_right(s, axis)
-        out = lax.dynamic_update_index_in_dim(
-            out, dequantize_chunk(q, s).astype(x2d.dtype), recv_c, axis=0
-        )
-    return unvary(out.reshape(p * rows, _LANE), (axis,))
+    q_all = _all_gather_invariant(q_own, axis, axis=0, tiled=True)
+    s_all = _all_gather_invariant(scale_own[None], axis, axis=0, tiled=True)
+    out = dequantize_chunk(
+        q_all.reshape(p, rows, _LANE), s_all[:, None, None]
+    ).astype(x2d.dtype)
+    return out.reshape(p * rows, _LANE)
 
 
 # ---------------------------------------------------------------------------

@@ -440,7 +440,8 @@ def _live_line(registry, monitor, server, now: float) -> str:
         if fl and getattr(server.engine, "platform", "") == "tpu":
             from mpit_tpu.obs.roofline import chip_peaks
 
-            line += f" mfu={100.0 * fl / chip_peaks()['peak_flops']:.1f}%"
+            peak = chip_peaks(platform="tpu")["peak_flops"]
+            line += f" mfu={100.0 * fl / peak:.1f}%"
         else:
             line += " mfu=-"
     if monitor is not None:
@@ -642,7 +643,9 @@ def main(argv: list[str] | None = None) -> dict:
         try:
             engine.register_roofline()
         except Exception:
-            pass  # backends without AOT cost support: phases-only output
+            if engine.platform == "tpu":
+                raise
+            # backends without AOT cost support: phases-only output
     summ = rec.summary()
     stats = server.stats()
     decode_s = summ["phases"].get("decode", {}).get("total_s", 0.0)
@@ -697,4 +700,7 @@ def main(argv: list[str] | None = None) -> dict:
 
 
 if __name__ == "__main__":
+    from mpit_tpu.utils import compile_cache_dir
+
+    compile_cache_dir()
     print(json.dumps(main(sys.argv[1:])))

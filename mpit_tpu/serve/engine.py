@@ -1872,6 +1872,8 @@ class Engine:
             try:
                 cost = _roofline.cost_from_fn(fn, *args)
             except Exception as e:  # a backend without AOT cost support
+                if self.platform == "tpu":
+                    raise  # on the chip this is a fault, not a backend gap
                 cost = {"flops": 0.0, "hbm_bytes": 0.0,
                         "error": f"{type(e).__name__}: {e}"[:120]}
             _roofline.register_cost(

@@ -14,12 +14,14 @@ from mpit_tpu.utils.aot import (
     topology_devices,
     topology_world,
 )
+from mpit_tpu.utils.compile_cache import compile_cache_dir
 from mpit_tpu.utils.profiling import (
     ChipSpec,
     CommModel,
     StepTimer,
     TPU_V5E,
     allreduce_gbps,
+    chip_spec_for,
     collective_bytes,
     compiled_cost,
     modeled_all_gather_seconds,
@@ -38,11 +40,13 @@ __all__ = [
     "memory_report",
     "topology_devices",
     "topology_world",
+    "compile_cache_dir",
     "ChipSpec",
     "CommModel",
     "StepTimer",
     "TPU_V5E",
     "allreduce_gbps",
+    "chip_spec_for",
     "collective_bytes",
     "compiled_cost",
     "modeled_all_gather_seconds",

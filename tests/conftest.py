@@ -1,33 +1,26 @@
-"""Test harness: force a fake 8-device CPU mesh (SURVEY.md §5.2).
+"""Test harness: eight virtual CPU devices (SURVEY.md §5.2).
 
-The primary re-exec onto the CPU mesh happens in the early plugin
-``reexec_cpu.py`` (see its docstring) loaded via ``pytest.ini``, which
-preserves test output. This conftest keeps a fallback for invocations that
-bypass pytest.ini (e.g. a different rootdir): the re-exec'd child still runs
-and reports pass/fail via exit code, but its output is swallowed by pytest's
-already-started capture.
+``reexec_cpu.py`` (the early plugin ``pytest.ini`` loads) has normally set
+the environment already; this conftest calls it again for invocations
+that bypass ``pytest.ini`` (e.g. a different rootdir). Either way it runs
+before jax is imported, which is when the device count is read.
 """
 
 import os
 import sys
 
-if (
-    os.environ.get("MPIT_TEST_REEXEC") != "1"
-    and os.environ.get("MPIT_TEST_PLATFORM", "cpu") == "cpu"
-):
-    sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    import reexec_cpu
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+import reexec_cpu  # noqa: E402
 
-    reexec_cpu.reexec_onto_cpu_mesh_if_needed()
+reexec_cpu.use_cpu_mesh()
 
 import jax  # noqa: E402
 import pytest  # noqa: E402
 
-# NOTE (round 10): do NOT enable the persistent XLA compile cache here,
-# tempting as the ~25% compile-dominated suite wall is — on this
-# jaxlib (0.4.37) reloading a cached executable for the fake 8-device
-# CPU mesh aborts the process (XLA CHECK failure inside the second
-# build of a donated-args SPMD step; reproduced deterministically on
+# Do NOT enable the persistent XLA compile cache here, tempting as the
+# compile-dominated suite wall is: reloading a cached executable for the
+# virtual 8-device CPU mesh has aborted the process (XLA CHECK failure
+# inside the second build of a donated-args SPMD step; reproduced on
 # tests/test_asyncsgd.py::test_spmd_checkpoint_resume with a same-run,
 # same-platform cache). bench.py's cache stays safe because bench never
 # rebuilds an identical step inside one process.
@@ -169,6 +162,19 @@ def world_2d():
     from mpit_tpu import comm
 
     return comm.init({"data": 4, "model": 2}, set_default=False)
+
+
+@pytest.fixture(scope="session")
+def v5e_world():
+    """A pure-DP World over a *described* v5e:2x4 (device proxies, no
+    hardware) for the real-compiler tests; skips where this installation
+    cannot describe the topology."""
+    from mpit_tpu.utils.aot import topology_world
+
+    try:
+        return topology_world({"data": 8}, "v5e:2x4")
+    except Exception as e:  # no TPU compiler installed
+        pytest.skip(f"v5e topology cannot be described: {e}")
 
 
 def require_devices(n: int):

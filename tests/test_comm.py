@@ -279,7 +279,6 @@ class TestMultiHostBootstrap:
         procs = []
         for pid in range(n_proc):
             env = reexec_cpu.cpu_mesh_env(2)  # 2 local devices per process
-            env.pop("MPIT_TEST_REEXEC", None)
             env["JAX_COORDINATOR_ADDRESS"] = f"127.0.0.1:{port}"
             env["JAX_NUM_PROCESSES"] = str(n_proc)
             env["JAX_PROCESS_ID"] = str(pid)

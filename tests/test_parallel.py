@@ -23,16 +23,6 @@ from mpit_tpu.parallel import (
 )
 from mpit_tpu.parallel.pipeline import stack_stage_params
 from mpit_tpu.parallel.tp import specs_like_params
-from mpit_tpu import _jaxcompat
-
-# Cross-tier gradient parity depends on jax 0.9's VMA AD semantics
-# (vary()/auto-psum, see comm.collectives.vary); on pre-VMA jax the
-# shard_map transpose produces different reductions and the exactness
-# contract cannot hold — skip rather than assert a wrong baseline.
-requires_vma = pytest.mark.skipif(
-    not _jaxcompat.HAS_VMA,
-    reason="jax 0.9 VMA gradient semantics required for parity",
-)
 
 
 def _qkv(key, b=2, t=32, h=4, d=8, dtype=jnp.float32):
@@ -518,8 +508,6 @@ class TestRingFlashAttention:
     def _io(self, world, T=256, B=2, H=2, D=64):
         ks = jax.random.split(jax.random.key(7), 3)
         return tuple(jax.random.normal(k, (B, T, H, D)) for k in ks)
-
-    @requires_vma
     def test_matches_full_attention(self, n_devices):
         import mpit_tpu
         from mpit_tpu.ops import reference_attention
@@ -1316,7 +1304,6 @@ class Test3DComposition:
         return optax.apply_updates(full, up)
 
     @pytest.mark.parametrize("zero1", [False, True])
-    @requires_vma
     def test_dp_tp_pp_matches_single_device(self, zero1):
         import mpit_tpu
         from mpit_tpu.data import shard_batch
@@ -1367,8 +1354,6 @@ class Test3DComposition:
             state.params,
             ref,
         )
-
-    @requires_vma
     def test_dp_cp_tp_ulysses_matches_single_device(self):
         """Ulysses all-to-all INSIDE the Megatron block (round-2 verdict
         item 9): same single-device-exact parity as the K/V ring — the
@@ -1376,7 +1361,6 @@ class Test3DComposition:
         self.test_dp_cp_tp_matches_single_device(True, ulysses=True)
 
     @pytest.mark.parametrize("zero1", [False, True])
-    @requires_vma
     def test_dp_cp_tp_matches_single_device(self, zero1, ulysses=False):
         """Ring attention INSIDE the Megatron block: TP x CP."""
         import mpit_tpu
@@ -1607,7 +1591,6 @@ class TestExpertParallelTier:
         return cfg, moe, model, full, world
 
     @pytest.mark.parametrize("zero1", [False, True])
-    @requires_vma
     def test_dense_parity_in_ample_capacity(self, zero1):
         """With ample capacity (no drops) and aux_weight=0, one EP step
         equals the dense single-device step exactly."""
@@ -1674,8 +1657,6 @@ class TestExpertParallelTier:
             auxes.append(float(m["aux"]))
         assert losses[-1] < losses[0], losses
         assert all(np.isfinite(auxes)), auxes
-
-    @requires_vma
     def test_composes_with_checkpointing(self, tmp_path):
         """Save mid-run, restore into a fresh state, trajectories match —
         the tier's state_specs drive the sharded orbax restore."""

@@ -13,6 +13,7 @@ from mpit_tpu.utils import (
     CommModel,
     StepTimer,
     allreduce_gbps,
+    chip_spec_for,
     collective_bytes,
     compiled_cost,
     roofline,
@@ -65,6 +66,31 @@ class TestRoofline:
         r3 = roofline(1e6, 1e6, ici_bytes=1e12)
         assert r3["bound"] == "ici"
         assert r1["seconds_lower_bound"] > 0
+
+
+class TestChipSpecTable:
+    """One table of published peaks, keyed by ``device_kind``: a real
+    device that is not in it is an error, never a silent v5e default."""
+
+    def test_known_device_kind(self):
+        assert chip_spec_for("TPU v5 lite").peak_flops_bf16 == 197e12
+
+    def test_unknown_device_kind_raises(self):
+        with pytest.raises(ValueError, match="no published peaks"):
+            chip_spec_for("TPU v9 imaginary")
+
+    def test_peaks_for_a_real_device_read_the_table(self, monkeypatch):
+        from mpit_tpu.obs import roofline as R
+
+        class _Dev:
+            device_kind = "TPU v9 imaginary"
+
+        # CPU runs keep the v5e spec as their *modeled* chip ...
+        assert R.chip_peaks()["chip"] == "tpu-v5e"
+        # ... a utilization for an attached device must know the device.
+        monkeypatch.setattr(jax, "devices", lambda: [_Dev()])
+        with pytest.raises(ValueError, match="no published peaks"):
+            R.chip_peaks(platform="tpu")
 
 
 class TestCollectiveModel:
