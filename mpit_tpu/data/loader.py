@@ -349,12 +349,9 @@ class Prefetcher:
         with self._cond:
             if blocked >= 4 and self._depth < self._max_depth:
                 self._depth += 1
-                obs.counter("prefetch_depth_grow")
                 self._cond.notify_all()  # device stage may be waiting on depth
             elif blocked == 0 and self._depth > self._depth0:
                 self._depth -= 1
-                obs.counter("prefetch_depth_shrink")
-        obs.gauge("prefetch_depth", float(self._depth))
 
     @property
     def depth(self) -> int:

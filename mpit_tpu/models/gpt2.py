@@ -14,7 +14,12 @@ Built TPU-first and parallelism-aware:
   parallelism (ring attention) and Pallas flash kernels substitute without
   touching the module tree;
 - bfloat16 activations/matmuls (MXU-native), float32 params, logits and
-  layernorms in float32.
+  layernorms in float32;
+- ``jax.named_scope`` at the seams a device trace is read by
+  (``embed``; per block ``attn`` with ``kv_write`` / ``kv_gather`` inside
+  it on the cache paths, and ``mlp``; ``lm_head``): an operation's
+  ``op_name`` carries them beside the flax module names, so a reduction
+  of the profiler's trace can sum device time by what the code does.
 """
 
 from __future__ import annotations
@@ -154,7 +159,8 @@ def paged_gather(pool, block_table):
         g = pl[block_table]  # [B, n_ps, ps, H, Dh]
         return g.reshape(g.shape[0], -1, *g.shape[3:])
 
-    return jax.tree.map(g1, pool)
+    with jax.named_scope("kv_gather"):
+        return jax.tree.map(g1, pool)
 
 
 def paged_cached_attention(q, k_pool, v_pool, lengths, block_table):
@@ -196,8 +202,9 @@ def cached_attention(q, k, v, lengths):
     what cross HBM→VMEM there.
     """
     if isinstance(k, QuantizedKV):
-        k = dequantize_kv(k)
-        v = dequantize_kv(v)
+        with jax.named_scope("kv_gather"):
+            k = dequantize_kv(k)
+            v = dequantize_kv(v)
     dh = q.shape[-1]
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) / jnp.sqrt(dh)
     t_q, s_max = q.shape[1], k.shape[1]
@@ -360,38 +367,46 @@ class Block(nn.Module):
             block_rows=cfg.quant_block_rows,
             name=name,
         )
-        h = nn.LayerNorm(dtype=cfg.ln_out_dtype, name="ln1")(x)
-        qkv = dense(3 * cfg.d_model, "qkv")(h)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
         split = lambda t: t.reshape(*t.shape[:-1], cfg.num_heads, cfg.head_dim)
-        if layer_cache is None:
-            attn = cfg.attention_fn(split(q), split(k), split(v), causal=True)
-            new_cache = None
-        elif len(layer_cache) == 5:
-            k_pool, v_pool, lengths, block_table, write_valid = layer_cache
-            k_pool = paged_cache_update(
-                k_pool, split(k), lengths, block_table, valid=write_valid
-            )
-            v_pool = paged_cache_update(
-                v_pool, split(v), lengths, block_table, valid=write_valid
-            )
-            attn_fn = cfg.paged_attention_fn or paged_cached_attention
-            attn = attn_fn(split(q), k_pool, v_pool, lengths, block_table)
-            new_cache = (k_pool, v_pool)
-        else:
-            k_cache, v_cache, lengths = layer_cache
-            k_cache = cache_update(k_cache, split(k), lengths)
-            v_cache = cache_update(v_cache, split(v), lengths)
-            attn_fn = cfg.cache_attention_fn or cached_attention
-            attn = attn_fn(split(q), k_cache, v_cache, lengths)
-            new_cache = (k_cache, v_cache)
-        attn = attn.reshape(*attn.shape[:-2], cfg.d_model)
-        x = x + dense(cfg.d_model, "proj")(attn)
+        with jax.named_scope("attn"):
+            h = nn.LayerNorm(dtype=cfg.ln_out_dtype, name="ln1")(x)
+            qkv = dense(3 * cfg.d_model, "qkv")(h)
+            q, k, v = jnp.split(qkv, 3, axis=-1)
+            if layer_cache is None:
+                attn = cfg.attention_fn(
+                    split(q), split(k), split(v), causal=True
+                )
+                new_cache = None
+            elif len(layer_cache) == 5:
+                k_pool, v_pool, lengths, block_table, write_valid = layer_cache
+                with jax.named_scope("kv_write"):
+                    k_pool = paged_cache_update(
+                        k_pool, split(k), lengths, block_table,
+                        valid=write_valid,
+                    )
+                    v_pool = paged_cache_update(
+                        v_pool, split(v), lengths, block_table,
+                        valid=write_valid,
+                    )
+                attn_fn = cfg.paged_attention_fn or paged_cached_attention
+                attn = attn_fn(split(q), k_pool, v_pool, lengths, block_table)
+                new_cache = (k_pool, v_pool)
+            else:
+                k_cache, v_cache, lengths = layer_cache
+                with jax.named_scope("kv_write"):
+                    k_cache = cache_update(k_cache, split(k), lengths)
+                    v_cache = cache_update(v_cache, split(v), lengths)
+                attn_fn = cfg.cache_attention_fn or cached_attention
+                attn = attn_fn(split(q), k_cache, v_cache, lengths)
+                new_cache = (k_cache, v_cache)
+            attn = attn.reshape(*attn.shape[:-2], cfg.d_model)
+            x = x + dense(cfg.d_model, "proj")(attn)
 
-        h = nn.LayerNorm(dtype=cfg.ln_out_dtype, name="ln2")(x)
-        h = dense(cfg.ff_dim, "fc")(h)
-        h = nn.gelu(h)
-        x = x + dense(cfg.d_model, "out")(h)
+        with jax.named_scope("mlp"):
+            h = nn.LayerNorm(dtype=cfg.ln_out_dtype, name="ln2")(x)
+            h = dense(cfg.ff_dim, "fc")(h)
+            h = nn.gelu(h)
+            x = x + dense(cfg.d_model, "out")(h)
         return x if layer_cache is None else (x, new_cache)
 
 
@@ -491,38 +506,48 @@ class GPT2(nn.Module):
             jnp.float32,
         )
         t = tokens.shape[-1]
-        pe = wpe[:t] if positions is None else wpe[positions]
-        emb = wte[tokens]
-        if isinstance(emb, QuantizedTensor):
-            # Gather picked int8 rows AND their scales; dequantize the
-            # gathered [B, T, D] view — activation-sized, never the
-            # [vocab, D] table.
-            emb = dequantize_tensor(emb)
-        x = emb.astype(cfg.dtype) + pe.astype(cfg.dtype)
+        with jax.named_scope("embed"):
+            pe = wpe[:t] if positions is None else wpe[positions]
+            emb = wte[tokens]
+            if isinstance(emb, QuantizedTensor):
+                # Gather picked int8 rows AND their scales; dequantize the
+                # gathered [B, T, D] view — activation-sized, never the
+                # [vocab, D] table.
+                emb = dequantize_tensor(emb)
+            x = emb.astype(cfg.dtype) + pe.astype(cfg.dtype)
         block = Block
         if cfg.remat:
             block = nn.remat(Block)
         new_k, new_v = [], []
         for i in range(cfg.num_layers):
             if cache is not None:
+                with jax.named_scope("kv_write"):
+                    layer = (cache_k[i], cache_v[i])
                 x, (k_i, v_i) = block(cfg, name=f"block_{i}")(
-                    x, (cache_k[i], cache_v[i], cache_lengths)
+                    x, (*layer, cache_lengths)
                 )
                 new_k.append(k_i)
                 new_v.append(v_i)
             elif paged_cache is not None:
+                with jax.named_scope("kv_write"):
+                    layer = (pool_k[i], pool_v[i])
                 x, (k_i, v_i) = block(cfg, name=f"block_{i}")(
-                    x,
-                    (pool_k[i], pool_v[i], cache_lengths, block_tables,
-                     write_valid),
+                    x, (*layer, cache_lengths, block_tables, write_valid)
                 )
                 new_k.append(k_i)
                 new_v.append(v_i)
             else:
                 x = block(cfg, name=f"block_{i}")(x)
-        x = nn.LayerNorm(dtype=cfg.ln_out_dtype, name="ln_f")(x)
+        if new_k:
+            # A functional update hands the whole cache back: taking a
+            # layer's buffer out of the stack (above) and stacking the
+            # written ones again belong to the write.
+            with jax.named_scope("kv_write"):
+                new_kv = (kv_stack(new_k), kv_stack(new_v))
+        with jax.named_scope("lm_head"):
+            x = nn.LayerNorm(dtype=cfg.ln_out_dtype, name="ln_f")(x)
         if return_hidden:
-            return x, (kv_stack(new_k), kv_stack(new_v))
+            return x, new_kv
         # LM head (f32 accumulation regardless of operand dtype); tied to
         # wte by default, separate under tie_head=False (see GPT2Config).
         head = (
@@ -538,9 +563,10 @@ class GPT2(nn.Module):
         if targets is not None:
             from mpit_tpu.ops.lm_head import lm_head_xent
 
-            return lm_head_xent(
-                x, head, targets, compute_dtype=cfg.head_dtype
-            )
+            with jax.named_scope("lm_head"):
+                return lm_head_xent(
+                    x, head, targets, compute_dtype=cfg.head_dtype
+                )
         if isinstance(head, QuantizedTensor):
             # Blocked x @ head.T — ALWAYS, even for reference engines:
             # the speculative draft runs this head pass inside a hot
@@ -549,19 +575,21 @@ class GPT2(nn.Module):
             # serving jaxpr. Blocking over vocab rows is bitwise
             # identical to whole-dequant (full-D contraction per
             # logit), so nothing is lost.
-            logits = quantized_matmul_t(
-                x.astype(cfg.head_dtype), head,
-                block_rows=cfg.quant_block_rows or None,
-            )
+            with jax.named_scope("lm_head"):
+                logits = quantized_matmul_t(
+                    x.astype(cfg.head_dtype), head,
+                    block_rows=cfg.quant_block_rows or None,
+                )
         else:
-            logits = jnp.einsum(
-                "btd,vd->btv",
-                x.astype(cfg.head_dtype),
-                head.astype(cfg.head_dtype),
-                preferred_element_type=jnp.float32,
-            )
+            with jax.named_scope("lm_head"):
+                logits = jnp.einsum(
+                    "btd,vd->btv",
+                    x.astype(cfg.head_dtype),
+                    head.astype(cfg.head_dtype),
+                    preferred_element_type=jnp.float32,
+                )
         if cache is not None or paged_cache is not None:
-            return logits, (kv_stack(new_k), kv_stack(new_v))
+            return logits, new_kv
         return logits
 
     @staticmethod

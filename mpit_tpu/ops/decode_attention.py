@@ -370,7 +370,8 @@ def _decode_call(q, k, v, lengths, *, block_k, interpret):
     b, t, h, d = q.shape
     hd = h * d
     pk = lambda x: x.reshape(x.shape[0], x.shape[1], hd)  # free head-pack
-    quantized, kv_ops, kv_dtypes = _kv_operands(k, v, h, pk)
+    with jax.named_scope("kv_gather"):
+        quantized, kv_ops, kv_dtypes = _kv_operands(k, v, h, pk)
     kern = functools.partial(
         _decode_kernel,
         block_k=block_k,
@@ -381,6 +382,7 @@ def _decode_call(q, k, v, lengths, *, block_k, interpret):
     )
     o, visited = pl.pallas_call(
         kern,
+        name="decode_attn",
         grid=(b,),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),  # lengths, whole [B]
@@ -419,7 +421,8 @@ def _paged_decode_call(
     b, t, h, d = q.shape
     hd = h * d
     pk = lambda x: x.reshape(x.shape[0], x.shape[1], hd)  # free head-pack
-    quantized, kv_ops, kv_dtypes = _kv_operands(k_pool, v_pool, h, pk)
+    with jax.named_scope("kv_gather"):
+        quantized, kv_ops, kv_dtypes = _kv_operands(k_pool, v_pool, h, pk)
     kern = functools.partial(
         _decode_kernel,
         block_k=block_k,
@@ -431,6 +434,7 @@ def _paged_decode_call(
     )
     o, visited = pl.pallas_call(
         kern,
+        name="paged_decode_attn",
         grid=(b,),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),  # lengths, whole [B]

@@ -361,6 +361,7 @@ def _fwd_packed(q, k, v, qoff, koff, *, g, ng, d, causal, block_q, block_k, inte
     )
     o, lse = pl.pallas_call(
         kern,
+        name="flash_fwd",
         grid=grid,
         in_specs=[
             _smem_scalar(), _smem_scalar(),
@@ -410,6 +411,7 @@ def _bwd_packed(q, k, v, o, lse, do, g_lse, qoff, koff, *, g, ng, d, causal, blo
             _bwd_dq_kernel, block_k=block_k, causal=causal, scale=scale,
             num_heads=g, head_dim=d,
         ),
+        name="flash_bwd_dq",
         grid=(b * ng, t // block_q),
         in_specs=[
             _smem_scalar(), _smem_scalar(),
@@ -430,6 +432,7 @@ def _bwd_packed(q, k, v, o, lse, do, g_lse, qoff, koff, *, g, ng, d, causal, blo
             _bwd_dkv_kernel, block_q=block_q, causal=causal, scale=scale,
             num_heads=g, head_dim=d,
         ),
+        name="flash_bwd_dkv",
         grid=(b * ng, t // block_k),
         in_specs=[
             _smem_scalar(), _smem_scalar(),

@@ -73,13 +73,17 @@ def _block_logits(h, head_block, valid, compute_dtype):
     this [block, D] tile, which is exactly the in-kernel fused-dequant
     discipline the int8 weight store demands of the decode head (the
     single biggest weight in the model)."""
-    if isinstance(head_block, QuantizedTensor):
-        head_block = dequantize_tensor(head_block)
-    logits = jnp.dot(
-        h.astype(compute_dtype),
-        head_block.astype(compute_dtype).T,
-        preferred_element_type=jnp.float32,
-    )
+    # Scoped here so that a sampler that streams the head
+    # (lm_head_sample, lm_head_verify) still shows the product apart
+    # from the sampling round it.
+    with jax.named_scope("lm_head"):
+        if isinstance(head_block, QuantizedTensor):
+            head_block = dequantize_tensor(head_block)
+        logits = jnp.dot(
+            h.astype(compute_dtype),
+            head_block.astype(compute_dtype).T,
+            preferred_element_type=jnp.float32,
+        )
     return jnp.where(valid[None, :], logits, _NEG_BIG)
 
 

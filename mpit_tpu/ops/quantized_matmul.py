@@ -338,6 +338,7 @@ def _qmm_call(x_blocked, q_blocked, scale, *, interpret):
     kern = functools.partial(_qmm_kernel, n_blocks=n)
     return pl.pallas_call(
         kern,
+        name="quantized_matmul",
         grid=(m // tm,),
         in_specs=[
             pl.BlockSpec(

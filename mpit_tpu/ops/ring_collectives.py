@@ -548,6 +548,7 @@ def _call_ring(kernel, x2d, out_shape, scratch, *, axis, p, interpret):
     )
     return pl.pallas_call(
         kern,
+        name="ring_step",
         out_shape=out_shape,
         in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),

@@ -208,29 +208,36 @@ def sharded(
         n = lax.axis_size(axis)
         flat_g, unravel = flat_ravel(grads)
         size = flat_g.shape[0]
-        if comm is None:
-            # reduce-scatter: each device receives the summed shard it
-            # owns. [rows, LANE] view keeps the lowering's minor dim
-            # lane-aligned (see module docstring: the 1-D form
-            # tile-pads 16x at 300M+).
-            g2 = _pad_to(flat_g, n * LANE).reshape(-1, LANE)
-            g_shard = C.reduce_scatter(g2, axis).reshape(-1)
-        else:
-            g_shard = comm.scatter_grads(flat_g)
-        if mean_grads:
-            g_shard = g_shard / n
+        # ``grad_sync`` and ``zero1_gather`` are the scope names a device
+        # trace is summed by: the two collectives apart from the update
+        # between them, whatever opcode the compiler or the ring tier
+        # gives them.
+        with jax.named_scope("grad_sync"):
+            if comm is None:
+                # reduce-scatter: each device receives the summed shard
+                # it owns. [rows, LANE] view keeps the lowering's minor
+                # dim lane-aligned (see module docstring: the 1-D form
+                # tile-pads 16x at 300M+).
+                g2 = _pad_to(flat_g, n * LANE).reshape(-1, LANE)
+                g_shard = C.reduce_scatter(g2, axis).reshape(-1)
+            else:
+                g_shard = comm.scatter_grads(flat_g)
+            if mean_grads:
+                g_shard = g_shard / n
         flat_p, _ = flat_ravel(params)
         p_shard = shard_of(flat_p, axis) if comm is None else comm.param_shard(flat_p)
         u_shard, new_state = tx.update(g_shard, state, p_shard)
-        if comm is None:
-            # invariant gather: updates are identical everywhere and
-            # typed replicated, so they can exit shard_map with a
-            # replicated spec.
-            flat_u = C.allgather(
-                u_shard.reshape(-1, LANE), axis, tiled=True, invariant=True
-            ).reshape(-1)[:size]
-        else:
-            flat_u = comm.gather_updates(u_shard, size)
+        with jax.named_scope("zero1_gather"):
+            if comm is None:
+                # invariant gather: updates are identical everywhere and
+                # typed replicated, so they can exit shard_map with a
+                # replicated spec.
+                flat_u = C.allgather(
+                    u_shard.reshape(-1, LANE), axis, tiled=True,
+                    invariant=True,
+                ).reshape(-1)[:size]
+            else:
+                flat_u = comm.gather_updates(u_shard, size)
         # Barrier before unravel: without it, XLA's algebraic simplifier
         # rewrites a leaf extraction (1-D slice + reshape to e.g. the MoE
         # router's [768, 8]) into a reshape of the WHOLE flat vector to

@@ -87,6 +87,7 @@ from mpit_tpu.ops.quantized_matmul import (
     quantized_matmul_reference,
     quantized_matmul_t,
 )
+from mpit_tpu import obs
 from mpit_tpu.obs import roofline as _roofline
 from mpit_tpu.ops.decode_attention import (
     flash_decode_attention,
@@ -141,6 +142,20 @@ _DTYPE_SHORT = {"float32": "f32", "bfloat16": "bf16", "int8": "int8"}
 # VMEM tile at a time, never as a full f32 array in HBM. Same lifetime
 # compile count; the decode HBM sweep's weight term shrinks ~4x.
 _WEIGHT_DTYPES = ("f32", "int8")
+
+
+def _jit_as(name: str, step):
+    """``jax.jit(step)`` under the stable module name ``jit_<name>``
+    (a bound method would give ``jit__paged_decode_step``): a device
+    trace names every operation's module, and a reduction tells the
+    tick's own operations from the RNG split's and the page copies' by
+    it."""
+
+    def named(*args):
+        return step(*args)
+
+    named.__name__ = named.__qualname__ = name
+    return jax.jit(named)
 
 
 def _kv_where(mask, new, old):
@@ -219,13 +234,16 @@ def _tp_forward_body(
     positions = lengths[:, None] + jnp.arange(t)[None, :]
     if clip_positions:
         positions = jnp.minimum(positions, cfg.max_seq_len - 1)
-    emb = params["wte"][tokens]
-    if isinstance(emb, QuantizedTensor):
-        # int8 weight store (ISSUE 17): the embedding GATHER picks T
-        # int8 rows + their scales; only those rows dequantize — never
-        # the whole [V, D] table.
-        emb = dequantize_tensor(emb)
-    x = emb.astype(cfg.dtype) + params["wpe"][positions].astype(cfg.dtype)
+    with jax.named_scope("embed"):
+        emb = params["wte"][tokens]
+        if isinstance(emb, QuantizedTensor):
+            # int8 weight store (ISSUE 17): the embedding GATHER picks T
+            # int8 rows + their scales; only those rows dequantize — never
+            # the whole [V, D] table.
+            emb = dequantize_tensor(emb)
+        x = emb.astype(cfg.dtype) + params["wpe"][positions].astype(
+            cfg.dtype
+        )
 
     dt = cfg.dtype
     # Quantized kernels (int8 weight store) keep their int8+scale wire —
@@ -237,56 +255,66 @@ def _tp_forward_body(
     new_k, new_v = [], []
     for i in range(cfg.num_layers):
         blk = params[f"block_{i}"]
-        h = M.layernorm(x, blk["ln1"]["scale"], blk["ln1"]["bias"]).astype(dt)
-        qkv = M.column_parallel_dense(
-            h, wdt(blk["qkv"]["kernel"]), blk["qkv"]["bias"].astype(dt)
-        )
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        k_i, v_i, attn = layer_kv(i, split(q), split(k), split(v))
-        attn = attn.reshape(*attn.shape[:-2], -1)
-        x = x + M.row_parallel_dense(
-            attn,
-            wdt(blk["proj"]["kernel"]),
-            blk["proj"]["bias"].astype(dt),
-            axis=axis,
-        )
-        h = M.layernorm(x, blk["ln2"]["scale"], blk["ln2"]["bias"]).astype(dt)
-        h = jax.nn.gelu(
-            M.column_parallel_dense(
-                h, wdt(blk["fc"]["kernel"]), blk["fc"]["bias"].astype(dt)
+        # The scope names of models.gpt2's Block: both forwards read
+        # alike in a trace.
+        with jax.named_scope("attn"):
+            h = M.layernorm(
+                x, blk["ln1"]["scale"], blk["ln1"]["bias"]
+            ).astype(dt)
+            qkv = M.column_parallel_dense(
+                h, wdt(blk["qkv"]["kernel"]), blk["qkv"]["bias"].astype(dt)
             )
-        )
-        x = x + M.row_parallel_dense(
-            h,
-            wdt(blk["out"]["kernel"]),
-            blk["out"]["bias"].astype(dt),
-            axis=axis,
-        )
+            q, k, v = jnp.split(qkv, 3, axis=-1)
+            k_i, v_i, attn = layer_kv(i, split(q), split(k), split(v))
+            attn = attn.reshape(*attn.shape[:-2], -1)
+            x = x + M.row_parallel_dense(
+                attn,
+                wdt(blk["proj"]["kernel"]),
+                blk["proj"]["bias"].astype(dt),
+                axis=axis,
+            )
+        with jax.named_scope("mlp"):
+            h = M.layernorm(
+                x, blk["ln2"]["scale"], blk["ln2"]["bias"]
+            ).astype(dt)
+            h = jax.nn.gelu(
+                M.column_parallel_dense(
+                    h, wdt(blk["fc"]["kernel"]), blk["fc"]["bias"].astype(dt)
+                )
+            )
+            x = x + M.row_parallel_dense(
+                h,
+                wdt(blk["out"]["kernel"]),
+                blk["out"]["bias"].astype(dt),
+                axis=axis,
+            )
         new_k.append(k_i)
         new_v.append(v_i)
 
-    x = M.layernorm(x, params["ln_f"]["scale"], params["ln_f"]["bias"])
+    with jax.named_scope("lm_head"):
+        x = M.layernorm(x, params["ln_f"]["scale"], params["ln_f"]["bias"])
     if not with_head:
         # Blocked decode head: the replicated post-ln_f hiddens go back
         # to the jitted step, which samples via lm_head_sample — no
         # [B, T, vocab] logits here either.
         return x, (new_k, new_v)
     head = params.get("head", params["wte"])
-    if isinstance(head, QuantizedTensor):
-        # Blocked x @ head.T over vocab-row tiles (ISSUE 17) — bitwise
-        # equal to the dequantized einsum (full-D contraction per
-        # logit), without a [V, D] f32 intermediate.
-        logits = quantized_matmul_t(
-            x.astype(cfg.head_dtype), head,
-            block_rows=cfg.quant_block_rows or None,
-        )
-    else:
-        logits = jnp.einsum(
-            "btd,vd->btv",
-            x.astype(cfg.head_dtype),
-            head.astype(cfg.head_dtype),
-            preferred_element_type=jnp.float32,
-        )
+    with jax.named_scope("lm_head"):
+        if isinstance(head, QuantizedTensor):
+            # Blocked x @ head.T over vocab-row tiles (ISSUE 17) — bitwise
+            # equal to the dequantized einsum (full-D contraction per
+            # logit), without a [V, D] f32 intermediate.
+            logits = quantized_matmul_t(
+                x.astype(cfg.head_dtype), head,
+                block_rows=cfg.quant_block_rows or None,
+            )
+        else:
+            logits = jnp.einsum(
+                "btd,vd->btv",
+                x.astype(cfg.head_dtype),
+                head.astype(cfg.head_dtype),
+                preferred_element_type=jnp.float32,
+            )
     return logits, (new_k, new_v)
 
 
@@ -299,8 +327,9 @@ def _tp_cache_forward(
     hiddens) + this device's updated cache shard."""
 
     def layer_kv(i, q, k, v):
-        k_i = cache_update(cache.k[i], k, cache.lengths)
-        v_i = cache_update(cache.v[i], v, cache.lengths)
+        with jax.named_scope("kv_write"):
+            k_i = cache_update(cache.k[i], k, cache.lengths)
+            v_i = cache_update(cache.v[i], v, cache.lengths)
         # Heads-local by construction (kernel or reference): this
         # device's H/P head shard of the cache goes in unchanged.
         attn = (attn_fn or cached_attention)(q, k_i, v_i, cache.lengths)
@@ -310,9 +339,10 @@ def _tp_cache_forward(
         params, tokens, cache.lengths, cfg=cfg, axis=axis,
         layer_kv=layer_kv, with_head=with_head,
     )
-    return out, KVCache(
-        k=kv_stack(new_k), v=kv_stack(new_v), lengths=cache.lengths
-    )
+    with jax.named_scope("kv_write"):
+        return out, KVCache(
+            k=kv_stack(new_k), v=kv_stack(new_v), lengths=cache.lengths
+        )
 
 
 def _tp_paged_forward(
@@ -330,12 +360,15 @@ def _tp_paged_forward(
     the same rows."""
 
     def layer_kv(i, q, k, v):
-        k_i = paged_cache_update(
-            cache.k[i], k, cache.lengths, block_tables, valid=write_valid
-        )
-        v_i = paged_cache_update(
-            cache.v[i], v, cache.lengths, block_tables, valid=write_valid
-        )
+        with jax.named_scope("kv_write"):
+            k_i = paged_cache_update(
+                cache.k[i], k, cache.lengths, block_tables,
+                valid=write_valid,
+            )
+            v_i = paged_cache_update(
+                cache.v[i], v, cache.lengths, block_tables,
+                valid=write_valid,
+            )
         attn = (attn_fn or paged_cached_attention)(
             q, k_i, v_i, cache.lengths, block_tables
         )
@@ -345,9 +378,10 @@ def _tp_paged_forward(
         params, tokens, cache.lengths, cfg=cfg, axis=axis,
         layer_kv=layer_kv, with_head=with_head, clip_positions=True,
     )
-    return out, PagedKVCache(
-        k=kv_stack(new_k), v=kv_stack(new_v), lengths=cache.lengths
-    )
+    with jax.named_scope("kv_write"):
+        return out, PagedKVCache(
+            k=kv_stack(new_k), v=kv_stack(new_v), lengths=cache.lengths
+        )
 
 
 def _trimmed_sharding(world, spec):
@@ -855,28 +889,44 @@ class Engine:
                 sharding=sharding, dtype=self._cache_dtype,
                 quantized=self.kv_quantized,
             )
-            self._prefill_paged_jit = jax.jit(self._paged_prefill_step)
+            self._prefill_paged_jit = _jit_as(
+                "prefill_paged", self._paged_prefill_step
+            )
             if self.spec_k:
-                self._spec_draft_jit = jax.jit(self._spec_draft_step)
-                self._spec_verify_jit = jax.jit(self._spec_verify_step)
+                self._spec_draft_jit = _jit_as(
+                    "spec_draft", self._spec_draft_step
+                )
+                self._spec_verify_jit = _jit_as(
+                    "spec_verify", self._spec_verify_step
+                )
             else:
-                self._decode_paged_jit = jax.jit(self._paged_decode_step)
-            self._copy_page_jit = jax.jit(self._copy_page_step)
+                self._decode_paged_jit = _jit_as(
+                    "decode_paged", self._paged_decode_step
+                )
+            self._copy_page_jit = _jit_as("copy_page", self._copy_page_step)
             if self.host_pages:
-                self._gather_page_jit = jax.jit(self._gather_page_step)
-                self._scatter_page_jit = jax.jit(self._scatter_page_step)
+                self._gather_page_jit = _jit_as(
+                    "gather_page", self._gather_page_step
+                )
+                self._scatter_page_jit = _jit_as(
+                    "scatter_page", self._scatter_page_step
+                )
         else:
             self.allocator = None
             self.cache = alloc_cache(
                 cfg, slots, self.max_len, sharding=sharding,
                 dtype=self._cache_dtype, quantized=self.kv_quantized,
             )
-            self._prefill_jit = jax.jit(self._prefill_step)
+            self._prefill_jit = _jit_as("prefill", self._prefill_step)
             if self.spec_k:
-                self._spec_draft_jit = jax.jit(self._spec_draft_step)
-                self._spec_verify_jit = jax.jit(self._spec_verify_step)
+                self._spec_draft_jit = _jit_as(
+                    "spec_draft", self._spec_draft_step
+                )
+                self._spec_verify_jit = _jit_as(
+                    "spec_verify", self._spec_verify_step
+                )
             else:
-                self._decode_jit = jax.jit(self._decode_step)
+                self._decode_jit = _jit_as("decode", self._decode_step)
         self.last_token = jnp.zeros((slots,), jnp.int32)
         if tp_axis is not None:
             # Pin the slot-width control state (lengths, last token)
@@ -1028,18 +1078,21 @@ class Engine:
         — blocked path: gather the HIDDEN row and stream the head
         (:func:`lm_head_sample`, no [slots, vocab] array); dense path:
         gather the logits row and sample as in PR 4."""
-        row = jnp.take_along_axis(
-            out, gather_idx[:, None, None], axis=1
-        )[:, 0]
-        if not self._blocked_head:
-            return sample_tokens(row.astype(jnp.float32), key, temp, topk)
-        head = params["head"] if "head" in params else params["wte"]
-        return lm_head_sample(
-            row, head, key, temp, topk,
-            block_size=self._sample_block,
-            k_cap=self.sample_k_cap,
-            compute_dtype=self.cfg.head_dtype,
-        )
+        with jax.named_scope("sample"):
+            row = jnp.take_along_axis(
+                out, gather_idx[:, None, None], axis=1
+            )[:, 0]
+            if not self._blocked_head:
+                return sample_tokens(
+                    row.astype(jnp.float32), key, temp, topk
+                )
+            head = params["head"] if "head" in params else params["wte"]
+            return lm_head_sample(
+                row, head, key, temp, topk,
+                block_size=self._sample_block,
+                k_cap=self.sample_k_cap,
+                compute_dtype=self.cfg.head_dtype,
+            )
 
     # -- draft forwards (ISSUE 13) ------------------------------------------
     def _draft_forward(self, dparams, tokens, dcache: KVCache, *, with_head):
@@ -1488,30 +1541,34 @@ class Engine:
                 "the paged engine prefills through prefill_paged (block-"
                 "table writes + chunking); the dense prefill has no pages"
             )
-        args = [
-            self.params,
-            self.cache,
-            self.last_token,
-            jnp.asarray(tokens, jnp.int32),
-            jnp.asarray(prompt_lens, jnp.int32),
-            jnp.asarray(admit, bool),
-            self._split(),
-            jnp.asarray(temp, jnp.float32),
-            jnp.asarray(topk, jnp.int32),
-        ]
-        if self.spec_k:
-            args += [self.draft_params, self.draft_cache]
-            self.cache, self.last_token, self.draft_cache = (
-                self.compile_watch.call("prefill", self._prefill_jit, *args)
-            )
-        else:
-            self.cache, self.last_token = self.compile_watch.call(
-                "prefill", self._prefill_jit, *args
-            )
-        # The step's one deliberate completion fence (docstring
-        # contract: the fetch closes the caller's span).
-        # analysis: allow(host-sync-in-hot-seam)
-        return np.asarray(self.last_token)
+        with obs.span("prefill_dispatch"):  # staging and enqueue
+            args = [
+                self.params,
+                self.cache,
+                self.last_token,
+                jnp.asarray(tokens, jnp.int32),
+                jnp.asarray(prompt_lens, jnp.int32),
+                jnp.asarray(admit, bool),
+                self._split(),
+                jnp.asarray(temp, jnp.float32),
+                jnp.asarray(topk, jnp.int32),
+            ]
+            if self.spec_k:
+                args += [self.draft_params, self.draft_cache]
+                self.cache, self.last_token, self.draft_cache = (
+                    self.compile_watch.call(
+                        "prefill", self._prefill_jit, *args
+                    )
+                )
+            else:
+                self.cache, self.last_token = self.compile_watch.call(
+                    "prefill", self._prefill_jit, *args
+                )
+        with obs.span("prefill_fetch"):  # the wait and the copy back
+            # The step's one deliberate completion fence (docstring
+            # contract: the fetch closes the caller's span).
+            # analysis: allow(host-sync-in-hot-seam)
+            return np.asarray(self.last_token)
 
     def prefill_paged(
         self, tokens, base, chunk_lens, floor, sample_mask, temp, topk
@@ -1525,54 +1582,58 @@ class Engine:
         ``sample_mask`` is set) as host numpy."""
         if not self.paged:
             raise ValueError("prefill_paged requires Engine(kv_pages=...)")
-        args = [
-            self.params,
-            self.cache,
-            self.last_token,
-            jnp.asarray(tokens, jnp.int32),
-            jnp.asarray(base, jnp.int32),
-            jnp.asarray(chunk_lens, jnp.int32),
-            jnp.asarray(floor, jnp.int32),
-            jnp.asarray(sample_mask, bool),
-            jnp.asarray(self.allocator.block_tables, jnp.int32),
-            self._split(),
-            jnp.asarray(temp, jnp.float32),
-            jnp.asarray(topk, jnp.int32),
-        ]
-        if self.spec_k:
-            args += [self.draft_params, self.draft_cache]
-            self.cache, self.last_token, self.draft_cache = (
-                self.compile_watch.call(
+        with obs.span("prefill_dispatch"):  # staging and enqueue
+            args = [
+                self.params,
+                self.cache,
+                self.last_token,
+                jnp.asarray(tokens, jnp.int32),
+                jnp.asarray(base, jnp.int32),
+                jnp.asarray(chunk_lens, jnp.int32),
+                jnp.asarray(floor, jnp.int32),
+                jnp.asarray(sample_mask, bool),
+                jnp.asarray(self.allocator.block_tables, jnp.int32),
+                self._split(),
+                jnp.asarray(temp, jnp.float32),
+                jnp.asarray(topk, jnp.int32),
+            ]
+            if self.spec_k:
+                args += [self.draft_params, self.draft_cache]
+                self.cache, self.last_token, self.draft_cache = (
+                    self.compile_watch.call(
+                        "prefill", self._prefill_paged_jit, *args
+                    )
+                )
+            else:
+                self.cache, self.last_token = self.compile_watch.call(
                     "prefill", self._prefill_paged_jit, *args
                 )
-            )
-        else:
-            self.cache, self.last_token = self.compile_watch.call(
-                "prefill", self._prefill_paged_jit, *args
-            )
-        # The step's one deliberate completion fence (docstring
-        # contract: the fetch closes the caller's span).
-        # analysis: allow(host-sync-in-hot-seam)
-        return np.asarray(self.last_token)
+        with obs.span("prefill_fetch"):  # the wait and the copy back
+            # The step's one deliberate completion fence (docstring
+            # contract: the fetch closes the caller's span).
+            # analysis: allow(host-sync-in-hot-seam)
+            return np.asarray(self.last_token)
 
     def copy_page(self, src: int, dst: int) -> None:
         """Device half of a COW remap: copy pool page ``src`` → ``dst``
         (all layers, K and V; the draft pool too on a speculative
         engine — same block tables, same remap). Page ids ride as
         traced scalars — one compile serves every copy."""
-        args = [
-            self.cache,
-            jnp.asarray(src, jnp.int32),
-            jnp.asarray(dst, jnp.int32),
-        ]
-        if self.spec_k:
-            self.cache, self.draft_cache = self.compile_watch.call(
-                "copy_page", self._copy_page_jit, *args, self.draft_cache
-            )
-        else:
-            self.cache = self.compile_watch.call(
-                "copy_page", self._copy_page_jit, *args
-            )
+        with obs.span("copy_page"):
+            args = [
+                self.cache,
+                jnp.asarray(src, jnp.int32),
+                jnp.asarray(dst, jnp.int32),
+            ]
+            if self.spec_k:
+                self.cache, self.draft_cache = self.compile_watch.call(
+                    "copy_page", self._copy_page_jit, *args,
+                    self.draft_cache,
+                )
+            else:
+                self.cache = self.compile_watch.call(
+                    "copy_page", self._copy_page_jit, *args
+                )
 
     # -- host KV tier (ISSUE 20) --------------------------------------------
     def spill_page(self, device_page: int, host_page: int, *,
@@ -1587,12 +1648,13 @@ class Engine:
         overlap discipline) or on demand before a restore. The host
         tier's ledger bytes are charged HERE: dispatch is the
         commitment."""
-        args = [self.cache, jnp.asarray(device_page, jnp.int32)]
-        if self.spec_k:
-            args.append(self.draft_cache)
-        payload = self.compile_watch.call(
-            "gather_page", self._gather_page_jit, *args
-        )
+        with obs.span("spill_page"):
+            args = [self.cache, jnp.asarray(device_page, jnp.int32)]
+            if self.spec_k:
+                args.append(self.draft_cache)
+            payload = self.compile_watch.call(
+                "gather_page", self._gather_page_jit, *args
+            )
         self._pending_spills.append((int(host_page), payload))
         self.memledger.grant(
             "kv_host_pages", self.page_bytes,
@@ -1610,8 +1672,11 @@ class Engine:
         if not self._pending_spills:
             return 0
         pending, self._pending_spills = self._pending_spills, []
-        for host_page, payload in pending:
-            self._host_store[host_page] = jax.tree.map(np.asarray, payload)
+        with obs.span("drain_spills"):
+            for host_page, payload in pending:
+                self._host_store[host_page] = jax.tree.map(
+                    np.asarray, payload
+                )
         return len(pending)
 
     def restore_page(self, host_page: int, device_page: int, *,
@@ -1626,16 +1691,19 @@ class Engine:
         if any(hp == host_page for hp, _ in self._pending_spills):
             self.drain_spills()
         payload = self._host_store[host_page]
-        args = [self.cache, jnp.asarray(device_page, jnp.int32), payload]
-        if self.spec_k:
-            self.cache, self.draft_cache = self.compile_watch.call(
-                "scatter_page", self._scatter_page_jit, *args,
-                self.draft_cache,
-            )
-        else:
-            self.cache = self.compile_watch.call(
-                "scatter_page", self._scatter_page_jit, *args
-            )
+        with obs.span("restore_page"):
+            args = [
+                self.cache, jnp.asarray(device_page, jnp.int32), payload
+            ]
+            if self.spec_k:
+                self.cache, self.draft_cache = self.compile_watch.call(
+                    "scatter_page", self._scatter_page_jit, *args,
+                    self.draft_cache,
+                )
+            else:
+                self.cache = self.compile_watch.call(
+                    "scatter_page", self._scatter_page_jit, *args
+                )
         self.host_restreamed_pages += 1
         self.host_restream_bytes += self.page_bytes
         if release:
@@ -1748,38 +1816,32 @@ class Engine:
                 "a speculative engine ticks through spec_draft + "
                 "spec_verify (there is no plain decode step to run)"
             )
-        if self.paged:
-            self.cache, self.last_token = self.compile_watch.call(
-                "decode",
-                self._decode_paged_jit,
+        with obs.span("decode_dispatch"):  # staging and enqueue
+            args = [
                 self.params,
                 self.cache,
                 self.last_token,
                 jnp.asarray(active, bool),
-                jnp.asarray(self.allocator.block_tables, jnp.int32),
+            ]
+            if self.paged:
+                args.append(
+                    jnp.asarray(self.allocator.block_tables, jnp.int32)
+                )
+            args += [
                 self._split(),
                 jnp.asarray(temp, jnp.float32),
                 jnp.asarray(topk, jnp.int32),
+            ]
+            self.cache, self.last_token = self.compile_watch.call(
+                "decode",
+                self._decode_paged_jit if self.paged else self._decode_jit,
+                *args,
             )
+        with obs.span("decode_fetch"):  # the wait and the copy back
             # The step's one deliberate completion fence (docstring
             # contract: the fetch closes the caller's span).
             # analysis: allow(host-sync-in-hot-seam)
             return np.asarray(self.last_token)
-        self.cache, self.last_token = self.compile_watch.call(
-            "decode",
-            self._decode_jit,
-            self.params,
-            self.cache,
-            self.last_token,
-            jnp.asarray(active, bool),
-            self._split(),
-            jnp.asarray(temp, jnp.float32),
-            jnp.asarray(topk, jnp.int32),
-        )
-        # The step's one deliberate completion fence (docstring
-        # contract: the fetch closes the caller's span).
-        # analysis: allow(host-sync-in-hot-seam)
-        return np.asarray(self.last_token)
 
     # -- roofline accounting (ISSUE 8) --------------------------------------
     def register_roofline(self) -> dict:

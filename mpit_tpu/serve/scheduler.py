@@ -12,7 +12,13 @@ Observability (``mpit_tpu.obs``) is first-class, not bolted on:
 
 - spans: ``prefill`` (per admission batch) and ``decode`` (per tick) —
   both close on the host fetch of the sampled tokens, so their wall
-  clock covers real device completion;
+  clock covers real device completion. They are two nodes of a tree,
+  nested by time on the loop's thread: ``tick`` round every iteration,
+  ``admit``, ``prefill``, ``gauges``, ``decode`` and ``retire`` inside
+  it in the order they run (:meth:`Server._run_tick`), and the engine's
+  ``decode_dispatch`` / ``decode_fetch`` (``prefill_*`` likewise)
+  inside the two calls. No argument of a span is computed when no
+  recorder is installed;
 - per-request intervals recorded with explicit timestamps
   (``obs.span_at``): ``queue_wait`` (submit → admit), ``request_ttft``
   (submit → first token) and ``request_latency`` (submit → retire) —
@@ -707,7 +713,6 @@ class Server:
                     self.engine.restore_page(
                         hp, dp, owner=live.req.rid, tick=self.tick
                     )
-                obs.counter("kv_host_restreams", len(plan.restream))
             if self._ledger is not None:
                 self._ledger.event(
                     live.req.rid, "slot_bind", slot=slot, tick=self.tick,
@@ -735,15 +740,6 @@ class Server:
                     "request_resumed", generated=len(live.tokens),
                     **self._span_attrs(live.req),
                 )
-                if self._host_tier:
-                    # The restream-vs-recompute OUTCOME instant
-                    # (ISSUE 20): which rebuild path this resume took,
-                    # joinable to the per-mode duration windows.
-                    obs.instant(
-                        "resume_" + resume_mode,
-                        generated=len(live.tokens),
-                        **self._span_attrs(live.req),
-                    )
                 if self._ledger is not None:
                     self._ledger.event(
                         live.req.rid, "preempt_resume", slot=slot,
@@ -829,7 +825,6 @@ class Server:
                 live.req.rid, tick=self.tick,
                 tenant=live.req.tenant or None, state="parked",
             )
-        obs.counter("serve_preemptions")
         # The displacing rid (ISSUE 16): the head whose projected TTFT
         # miss justified this eviction — recorded by wants_preemption,
         # "" when the park came from a direct _preempt call.
@@ -879,8 +874,6 @@ class Server:
             self.engine.host_free(hp, kind="host_evict")
         for dp, hp in copies:
             self.engine.spill_page(dp, hp, owner=owner, tick=self.tick)
-        if copies:
-            obs.counter("kv_prefix_spills", len(copies))
 
     def _restream_parked(self, slot: int, live: _Live, plan, rec) -> bool:
         """Rebuild a resumed victim's cache rows ``[shared, fill)`` from
@@ -915,7 +908,6 @@ class Server:
             pair = alloc.cow_before_write(slot, s)
             if pair is not None:
                 eng.copy_page(*pair)
-                obs.counter("kv_cow_copies")
                 if self._ledger is not None:
                     self._ledger.event(
                         rid, "cow_copy", tick=self.tick,
@@ -967,7 +959,6 @@ class Server:
                 pair = alloc.cow_before_write(slot, first_write)
                 if pair is not None:
                     eng.copy_page(*pair)
-                    obs.counter("kv_cow_copies")
                     if self._ledger is not None:
                         self._ledger.event(
                             live.req.rid, "cow_copy", tick=self.tick,
@@ -980,15 +971,17 @@ class Server:
             if live.base + n == len(p):
                 sample_mask[slot] = True
                 finishing.append((slot, live))
-        with obs.span(
-            "prefill",
-            admitted=len(finishing),
-            chunks=int((chunk_lens > 0).sum()),
-            attention=self._attn_mode,
-            sampler=self._sampler,
-            rids=[live.req.rid for live in self.prefilling.values()],
-            **self._kv_attrs,
-        ):
+        attrs = {}
+        if obs.enabled():  # a disabled span costs a tick nothing
+            attrs = dict(
+                admitted=len(finishing),
+                chunks=int((chunk_lens > 0).sum()),
+                attention=self._attn_mode,
+                sampler=self._sampler,
+                rids=[live.req.rid for live in self.prefilling.values()],
+                **self._kv_attrs,
+            )
+        with obs.span("prefill", **attrs):
             first = eng.prefill_paged(
                 tokens, base, chunk_lens, floor, sample_mask,
                 self._temp, self._topk,
@@ -1117,15 +1110,19 @@ class Server:
             if self.stream is not None:
                 self.stream.observe("queue_wait", now - live.submit_t)
             batch.append((slot, live))
-        with obs.span(
-            "prefill", admitted=len(batch), attention=self._attn_mode,
-            sampler=self._sampler,
-            # The admitted rids, as a LIST (a non-string attr stays out
-            # of the summary's label roll-up but lands in the trace
-            # args) — one request's lifeline is filterable in Perfetto.
-            rids=[live.req.rid for _, live in batch],
-            **self._kv_attrs,
-        ):
+        attrs = {}
+        if obs.enabled():  # a disabled span costs a tick nothing
+            attrs = dict(
+                admitted=len(batch), attention=self._attn_mode,
+                sampler=self._sampler,
+                # The admitted rids, as a LIST (a non-string attr stays
+                # out of the summary's label roll-up but lands in the
+                # trace args) — one request's lifeline is filterable in
+                # Perfetto.
+                rids=[live.req.rid for _, live in batch],
+                **self._kv_attrs,
+            )
+        with obs.span("prefill", **attrs):
             first = self.engine.prefill(
                 tokens, lens, admit, self._temp, self._topk
             )
@@ -1268,14 +1265,16 @@ class Server:
                     )
                     if pair is not None:
                         eng.copy_page(*pair)
-                        obs.counter("kv_cow_copies")
                         if self._ledger is not None:
                             self._ledger.event(
                                 live.req.rid, "cow_copy", tick=self.tick,
                                 src=pair[0], dst=pair[1], phase="spec",
                             )
         n_live = int(active.sum())
-        rids = [live.req.rid for live in self.live.values()]
+        rids = (
+            [live.req.rid for live in self.live.values()]
+            if obs.enabled() else None
+        )
         t0 = time.perf_counter()
         with obs.span(
             "decode", active=n_live, attention=self._attn_mode,
@@ -1325,7 +1324,7 @@ class Server:
         lens = np.asarray(
             [live.cache_fill() for live in self.live.values()]
         )
-        if self._attn_mode == "kernel":
+        if self._attn_mode == "kernel" and obs.enabled():
             # Same single-formula tile accounting as the plain tick,
             # at the verify's T = k+1 query width.
             bk = eng.decode_block_k
@@ -1392,21 +1391,35 @@ class Server:
                 )
                 if pair is not None:
                     self.engine.copy_page(*pair)
-                    obs.counter("kv_cow_copies")
                     if self._ledger is not None:
                         self._ledger.event(
                             live.req.rid, "cow_copy", tick=self.tick,
                             src=pair[0], dst=pair[1], phase="decode",
                         )
+        attrs = {}
+        if obs.enabled():  # a disabled span costs a tick nothing
+            attrs = dict(
+                active=len(self.live), attention=self._attn_mode,
+                sampler=self._sampler,
+                rids=[live.req.rid for live in self.live.values()],
+                # Live rows BEFORE the tick: what the kernel reads.
+                cache_rows=sum(
+                    live.cache_fill() for live in self.live.values()
+                ),
+                **self._kv_attrs,
+            )
         t0 = time.perf_counter()
-        with obs.span(
-            "decode", active=int(active.sum()), attention=self._attn_mode,
-            sampler=self._sampler,
-            rids=[live.req.rid for live in self.live.values()],
-            **self._kv_attrs,
-        ):
+        with obs.span("decode", **attrs):
             toks = self.engine.decode(active, self._temp, self._topk)
         now = time.perf_counter()
+        with obs.span("retire"):
+            self._account_decode(active, toks, t0, now)
+
+    def _account_decode(self, active, toks, t0: float, now: float) -> None:
+        """What a decode tick owes after its tokens are on the host:
+        counters, the rolling windows, the request ledger, the achieved
+        HBM bytes and the utilization watch, then each slot's token and
+        its retirement. The ``retire`` span times it."""
         if self.sentinel is not None:
             self.sentinel.observe_phases(self.tick, decode=now - t0)
         obs.counter("serve_tokens", float(active.sum()))
@@ -1427,7 +1440,8 @@ class Server:
         lens = np.asarray(
             [live.cache_fill() for live in self.live.values()]
         )
-        if self._attn_mode == "kernel":
+        if self._attn_mode == "kernel" and obs.enabled():
+            # Read by the recorder alone, so counted only for one.
             # Cache tiles the length-aware kernel skipped this tick —
             # ONE formula, num_kv_blocks, shared with the kernel's own
             # in-kernel bound (pinned against it in
@@ -1661,16 +1675,54 @@ class Server:
 
     def _run_tick(self) -> None:
         """One loop iteration: admit, prefill chunk (paged), gauges,
-        decode, SLO evaluation."""
-        if self._host_tier:
-            # Land last tick's dispatched spills (ISSUE 20): the
-            # device→host copies ran under the decode tick they were
-            # dispatched with (the Prefetcher's two-stage overlap);
-            # materializing here costs only the memcpy, never the wait.
-            self.engine.drain_spills()
-        self._admit()
-        if self._paged:
-            self._prefill_chunk_tick()
+        decode, SLO evaluation.
+
+        Spanned as a tree, by time on this thread: ``tick`` round the
+        whole, and inside it, in the order they run, ``admit``,
+        ``prefill`` (the paged chunk; a dense engine prefills inside
+        ``admit``), ``gauges``, ``decode`` and ``retire`` (the
+        accounting after the decode call). The engine's
+        ``*_dispatch`` / ``*_fetch`` spans lie inside ``prefill`` and
+        ``decode``; its page copies (``copy_page``, ``restore_page``,
+        ``spill_page``, ``drain_spills``) wherever they are enqueued.
+        ``tick`` less its children is the loop's own remainder."""
+        with obs.span("tick", tick=self.tick):
+            if self._host_tier:
+                # Land last tick's dispatched spills (ISSUE 20): the
+                # device→host copies ran under the decode tick they were
+                # dispatched with (the Prefetcher's two-stage overlap);
+                # materializing here costs only the memcpy, never the
+                # wait.
+                self.engine.drain_spills()
+            with obs.span("admit"):
+                self._admit()
+            if self._paged:
+                self._prefill_chunk_tick()
+            with obs.span("gauges"):
+                self._tick_gauges()
+            if self.live:
+                self._decode_tick()
+            if self.slo is not None:
+                transitions = self.slo.evaluate(tick=self.tick)
+                if (
+                    self._ledger is not None
+                    and getattr(self.slo, "sentinel", None) is None
+                ):
+                    # No sentinel wired: pin the in-flight set from the
+                    # monitor's returned transitions directly (with a
+                    # sentinel the on_note chain installed in __init__
+                    # already did it — never both, or breaches
+                    # double-pin).
+                    for tr in transitions:
+                        if tr.get("event") == "slo_breach":
+                            self._ledger.pin_inflight(
+                                "slo_breach", step=self.tick
+                            )
+        self.tick += 1
+
+    def _tick_gauges(self) -> None:
+        """Occupancy, queue depth and the cache-memory gauges of a tick,
+        after its admissions and before its decode."""
         busy = len(self.live) + len(self.prefilling)
         self._concurrency_peak = max(self._concurrency_peak, busy)
         occupancy = busy / self.engine.slots
@@ -1690,24 +1742,6 @@ class Server:
                         f"queue_depth_tier{tier}", float(depth)
                     )
         self._kv_gauges()
-        if self.live:
-            self._decode_tick()
-        if self.slo is not None:
-            transitions = self.slo.evaluate(tick=self.tick)
-            if (
-                self._ledger is not None
-                and getattr(self.slo, "sentinel", None) is None
-            ):
-                # No sentinel wired: pin the in-flight set from the
-                # monitor's returned transitions directly (with a
-                # sentinel the on_note chain installed in __init__
-                # already did it — never both, or breaches double-pin).
-                for tr in transitions:
-                    if tr.get("event") == "slo_breach":
-                        self._ledger.pin_inflight(
-                            "slo_breach", step=self.tick
-                        )
-        self.tick += 1
 
     def run(self, *, max_ticks: int = 1_000_000) -> list[Completed]:
         """Drive admit/decode until everything submitted has completed

@@ -304,7 +304,7 @@ def ship_kv_remote(buf, dst_device: int):
             dst_ref=dst_ref,
             send_sem=send_sem,
             recv_sem=recv_sem,
-            device_id=(dst_device,),
+            device_id=dst_device,
             device_id_type=pltpu.DeviceIdType.LOGICAL,
         )
         rdma.start()
@@ -312,11 +312,12 @@ def ship_kv_remote(buf, dst_device: int):
 
     return pl.pallas_call(
         _ship_kernel,
+        name="kv_ship",
         out_shape=jax.ShapeDtypeStruct(buf.shape, buf.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=0,
-            in_specs=[pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)],
-            out_specs=pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
             scratch_shapes=[pltpu.SemaphoreType.DMA] * 2,
         ),
     )(jnp.asarray(buf))

@@ -180,31 +180,48 @@ def make_train_step(
         # pre-summed) and the explicit reduction below would double-count.
         # See comm.collectives.vary.
         local_params = C.vary(state.params, axis)
-        if stateful:
-            def lf(p):
+
+        # The step's scope names (a device trace is summed by them):
+        # ``loss`` inside the differentiated function, so that autodiff
+        # writes ``jvp(loss)`` forward and ``transpose(jvp(loss))``
+        # backward; ``opt_update`` round the optimizer with, inside it,
+        # ``grad_sync`` and ``zero1_gather`` round the collectives
+        # (opt.sharded names its own).
+        def lf(p):
+            with jax.named_scope("loss"):
+                if not stateful:
+                    return loss_fn(p, batch)
                 loss, aux, new_extra = loss_fn(p, state.extra, batch)
                 return loss, (aux, new_extra)
 
+        if stateful:
             (loss, (aux, new_extra)), grads = jax.value_and_grad(
                 lf, has_aux=True
             )(local_params)
             new_extra = jax.tree.map(lambda e: lax.pmean(e, axis), new_extra)
         else:
-            (loss, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-                local_params, batch
+            (loss, aux), grads = jax.value_and_grad(lf, has_aux=True)(
+                local_params
             )
             new_extra = state.extra
 
-        if zero1:
-            # local grads in; reduce-scatter + shard-update + all-gather
-            # inside (mean semantics — stx was built with mean_grads=True).
-            updates, opt_state = stx.update(grads, state.opt_state, state.params)
-        else:
-            # Plain-DP sync — GradSync's pluggable wire (psum mode IS
-            # the seed lax.pmean, the ring modes flatten + bucket).
-            grads = gs.allreduce_grads(grads)
-            updates, opt_state = tx.update(grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("opt_update"):
+            if zero1:
+                # local grads in; reduce-scatter + shard-update +
+                # all-gather inside (mean semantics — stx was built with
+                # mean_grads=True).
+                updates, opt_state = stx.update(
+                    grads, state.opt_state, state.params
+                )
+            else:
+                # Plain-DP sync — GradSync's pluggable wire (psum mode IS
+                # the seed lax.pmean, the ring modes flatten + bucket).
+                with jax.named_scope("grad_sync"):
+                    grads = gs.allreduce_grads(grads)
+                updates, opt_state = tx.update(
+                    grads, state.opt_state, state.params
+                )
+            params = optax.apply_updates(state.params, updates)
 
         metrics = {"loss": loss, **aux}
         metrics = jax.tree.map(lambda m: lax.pmean(m, axis), metrics)
@@ -228,7 +245,13 @@ def make_train_step(
             in_specs=(specs, batch_spec),
             out_specs=(specs, P()),
         )
-        return jax.jit(f, donate_argnums=(0,) if donate else ())
+
+        # One stable module name, ``jit_train_step``, whatever the body:
+        # a trace's reduction tells the step's operations by it.
+        def train_step(state, batch):
+            return f(state, batch)
+
+        return jax.jit(train_step, donate_argnums=(0,) if donate else ())
 
     # step_fn lazily builds (and caches) the compiled step on first call,
     # keyed by state/batch structure.

@@ -1,0 +1,247 @@
+"""Every layer has a name on both clocks (ISSUE 24).
+
+Host: a serving tick is one ``tick`` span with its phases inside it, in
+the order they run, and the engine's ``*_dispatch`` / ``*_fetch`` inside
+``decode`` and ``prefill``; with no recorder installed a tick computes no
+span argument. Device: the jitted steps lower to text that holds each
+scope name, and every ``pallas_call`` carries its ``name``.
+"""
+
+from __future__ import annotations
+
+import functools
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import PartitionSpec as P
+
+import mpit_tpu
+from mpit_tpu import obs
+from mpit_tpu.models import GPT2, GPT2Config
+from mpit_tpu.obs import core as obs_core
+from mpit_tpu.serve import Engine, Request, Server
+from mpit_tpu.serve import engine as engine_mod
+from mpit_tpu.serve import scheduler as scheduler_mod
+
+CFG = GPT2Config.tiny(
+    vocab_size=64, max_seq_len=64, num_layers=2, num_heads=2, d_model=32,
+    dtype=jnp.float32,
+)
+ENGINES = {
+    "dense": dict(slots=2, max_len=32, prefill_len=8),
+    "paged": dict(slots=2, max_len=32, kv_pages=8, kv_page_size=8,
+                  prefill_chunk=4),
+}
+
+
+@pytest.fixture(scope="module")
+def params():
+    return GPT2(CFG).init(
+        jax.random.key(0), jnp.zeros((1, 8), jnp.int32)
+    )["params"]
+
+
+def _server(params, kind, **kw):
+    server = Server(Engine(CFG, params, **ENGINES[kind], **kw))
+    server.submit(Request(rid=1, prompt=[5, 9, 3, 7, 2, 8], max_new_tokens=4))
+    server.submit(Request(rid=2, prompt=[7, 4], max_new_tokens=3))
+    return server
+
+
+def _inside(child, parent) -> bool:
+    return parent[1] <= child[1] and child[2] <= parent[2]
+
+
+@pytest.mark.parametrize("kind", sorted(ENGINES))
+def test_every_tick_is_one_span_tree(params, kind):
+    rec = obs.Recorder()
+    with obs.local_recorder(rec):
+        server = _server(params, kind)
+        server.run()
+    spans = [(name, t0, t0 + dur, attrs or {})
+             for k, name, t0, dur, _tid, attrs in rec.snapshot()["events"]
+             if k == "X"]
+    ticks = [s for s in spans if s[0] == "tick"]
+    assert [s[3]["tick"] for s in ticks] == list(range(server.tick))
+    phases = ("admit", "prefill", "gauges", "decode", "retire")
+    decodes = 0
+    for tick in ticks:
+        kids = sorted((s for s in spans if s[0] in phases and _inside(s, tick)),
+                      key=lambda s: s[1])
+        names = [s[0] for s in kids]
+        assert names.count("admit") == names.count("gauges") == 1
+        if kind == "paged":
+            # Siblings, one after another, in the order they run.
+            assert names == [p for p in phases if p in names]
+            assert all(a[2] <= b[1] for a, b in zip(kids, kids[1:]))
+        else:
+            # A dense engine prefills whole prompts inside its admission.
+            rest = [s for s in kids if s[0] != "prefill"]
+            assert [s[0] for s in rest] == [
+                p for p in phases if p in names and p != "prefill"]
+            admit = next(s for s in kids if s[0] == "admit")
+            assert all(_inside(s, admit) for s in kids if s[0] == "prefill")
+        for outer in ("decode", "prefill"):
+            for span in (s for s in kids if s[0] == outer):
+                inner = sorted(
+                    (s for s in spans if _inside(s, span)
+                     and s[0] in (outer + "_dispatch", outer + "_fetch")),
+                    key=lambda s: s[1])
+                assert [s[0] for s in inner] == [
+                    outer + "_dispatch", outer + "_fetch"]
+        for d in (s for s in kids if s[0] == "decode"):
+            decodes += 1
+            assert d[3]["cache_rows"] >= d[3]["active"] >= 1
+            assert len(d[3]["rids"]) == d[3]["active"]
+            assert "retire" in names
+    assert decodes >= 2
+
+
+@pytest.mark.parametrize("kind", sorted(ENGINES))
+def test_disabled_tick_computes_no_span_argument(params, kind, monkeypatch):
+    assert not obs.enabled()
+    asked = []
+
+    def spy(name, **attrs):
+        asked.append((name, attrs, obs_core.span(name, **attrs)))
+        return asked[-1][2]
+
+    server = _server(params, kind)
+    monkeypatch.setattr(scheduler_mod.obs, "span", spy)
+    assert engine_mod.obs is scheduler_mod.obs
+    server.run()
+    names = {name for name, _, _ in asked}
+    assert {"tick", "admit", "prefill", "gauges", "decode", "retire",
+            "decode_dispatch", "decode_fetch", "prefill_dispatch",
+            "prefill_fetch"} <= names
+    noop = obs_core.span("x")
+    for name, attrs, got in asked:
+        assert got is noop, name
+        assert "rids" not in attrs and "cache_rows" not in attrs, name
+    assert all(not attrs for name, attrs, _ in asked if name != "tick")
+
+
+def _lowered_decode(params, kind):
+    eng = Engine(CFG, params, **ENGINES[kind], decode_attention="interpret",
+                 sample_block=32, sample_k_cap=16)
+    active = jnp.ones((eng.slots,), bool)
+    args = [eng.params, eng.cache, eng.last_token, active]
+    if kind == "paged":
+        args.append(jnp.asarray(eng.allocator.block_tables, jnp.int32))
+    args += [jax.random.key(0), jnp.zeros((eng.slots,), jnp.float32),
+             jnp.zeros((eng.slots,), jnp.int32)]
+    jit = eng._decode_paged_jit if kind == "paged" else eng._decode_jit
+    return jit, args
+
+
+def _lowered_train():
+    from mpit_tpu.opt import goo_adam
+    from mpit_tpu.train import make_train_step
+
+    cfg = GPT2Config.tiny(
+        vocab_size=64, max_seq_len=16, num_layers=1, num_heads=2, d_model=32,
+        dtype=jnp.float32,
+    )
+    model = GPT2(cfg)
+    prm = model.init(jax.random.key(0), jnp.zeros((1, 16), jnp.int32))["params"]
+    world = mpit_tpu.init({"data": -1})
+    loss = lambda p, batch: (GPT2.fused_loss_fn(model, p, batch), {})
+    init_fn, step_fn, _ = make_train_step(loss, goo_adam(1e-3), world)
+    state = init_fn(prm)
+    batch = jnp.zeros((world.axis_size("data") * 2, 17), jnp.int32)
+    return step_fn.build(state.params, state.extra), [state, batch]
+
+
+STEPS = {
+    "train": (_lowered_train, "jit_train_step",
+              ("loss", "grad_sync", "opt_update", "zero1_gather", "attn",
+               "mlp", "lm_head", "embed"), ()),
+    "decode_dense": (lambda p: _lowered_decode(p, "dense"), "jit_decode",
+                     ("kv_write", "kv_gather", "attn", "mlp", "lm_head",
+                      "sample", "embed"), ("decode_attn",)),
+    "decode_paged": (lambda p: _lowered_decode(p, "paged"), "jit_decode_paged",
+                     ("kv_write", "kv_gather", "attn", "mlp", "lm_head",
+                      "sample", "embed"), ("paged_decode_attn",)),
+}
+
+
+def _pallas_names(jaxpr) -> set:
+    """The ``name`` of every ``pallas_call`` a jaxpr reaches."""
+    found = set()
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.add(eqn.params["name"])
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found |= _pallas_names(sub)
+    return found
+
+
+@pytest.mark.parametrize("step", sorted(STEPS))
+def test_a_step_lowers_with_its_scope_and_kernel_names(params, step):
+    build, module, scopes, kernels = STEPS[step]
+    jit, args = build() if step == "train" else build(params)
+    text = jit.lower(*args).as_text(debug_info=True)
+    assert f"module @{module} " in text
+    for scope in scopes:
+        # A location is a name stack: "jit(decode)/GPT2/block_0/attn/..."
+        # or, inside a nested jit, one that starts with the scope.
+        assert re.search(rf'["/(]{scope}[/)]', text), scope
+    if step == "train":
+        assert "transpose(jvp(loss))" in text
+    assert set(kernels) <= _pallas_names(jax.make_jaxpr(jit)(*args).jaxpr)
+
+
+def _flash_jaxpr():
+    # Outside shard_map: the kernel's interpreter does not type-check
+    # under one, and the step above needs no kernel to show its scopes.
+    from mpit_tpu.ops import flash_attention
+
+    q = jnp.ones((1, 16, 2, 16), jnp.float32)
+    loss = lambda q, k, v: flash_attention(q, k, v, interpret=True).sum()
+    return jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, q)
+
+
+def _qmm_jaxpr():
+    from mpit_tpu.ops.quantized_matmul import quantize_tensor, quantized_matmul
+
+    w = quantize_tensor(jnp.ones((256, 128), jnp.float32))
+    return jax.make_jaxpr(functools.partial(
+        quantized_matmul, block_rows=128, interpret=True))(
+            jnp.ones((8, 256), jnp.float32), w)
+
+
+def _ring_jaxpr():
+    from mpit_tpu.ops.ring_collectives import ring_reduce_scatter
+
+    world = mpit_tpu.init(
+        {"data": 4}, devices=jax.devices()[:4], set_default=False)
+    f = world.shard_map(
+        lambda x: ring_reduce_scatter(x, "data", interpret=True),
+        in_specs=P("data"), out_specs=P("data"))
+    return jax.make_jaxpr(f)(jnp.ones((4 * 4 * 32 * 128,), jnp.float32))
+
+
+def _ship_jaxpr(monkeypatch):
+    from mpit_tpu.serve.shipment import ship_kv_remote
+
+    # The refusal off-TPU is the product's; tracing needs no device.
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    return jax.make_jaxpr(functools.partial(ship_kv_remote, dst_device=0))(
+        jnp.ones((8, 128), jnp.float32))
+
+
+KERNELS = {
+    "flash": (_flash_jaxpr, {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}),
+    "quantized_matmul": (_qmm_jaxpr, {"quantized_matmul"}),
+    "ring_step": (_ring_jaxpr, {"ring_step"}),
+    "kv_ship": (_ship_jaxpr, {"kv_ship"}),
+}
+
+
+@pytest.mark.parametrize("kernel", sorted(KERNELS))
+def test_the_other_kernels_carry_their_names(kernel, monkeypatch):
+    build, names = KERNELS[kernel]
+    jaxpr = build(monkeypatch) if kernel == "kv_ship" else build()
+    assert names <= _pallas_names(jaxpr.jaxpr)
