@@ -295,9 +295,12 @@ def _engine_logits(engine, prompt, first=None):
 
 def _paged_kernel_vs_reference(engine, seed: int) -> dict:
     """The paged flash-decode kernel alone, at the engine's geometry, on
-    random bf16 data and ragged lengths: output against the gather-dense
-    reference, and the kernel's own visited-tile count against the host
-    formula the scheduler's counters use."""
+    random data and ragged lengths, over a pool in the stored form
+    ([pages, page, H*Dh]; bf16 rows, and int8 rows with their scale
+    plane), for the three query widths the engine traces: a decode tick
+    (T=1), a ``spec_k=4`` verify (T=5) and a prefill chunk (T=64). Output
+    against the gather-dense reference, and the kernel's own visited-tile
+    count against the host formula the scheduler's counters use."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -307,32 +310,87 @@ def _paged_kernel_vs_reference(engine, seed: int) -> dict:
         num_kv_blocks,
         reference_paged_decode_attention,
     )
+    from mpit_tpu.ops.kv_quant import pack_heads, quantize_kv
 
     cfg, b, pps = engine.cfg, engine.slots, engine.pages_per_slot
     kq, kk, kv = jax.random.split(jax.random.key(seed), 3)
-    pool = (engine.num_pages, engine.page_size, cfg.num_heads, cfg.head_dim)
-    q = jax.random.normal(kq, (b, 1, *pool[2:]), jnp.bfloat16)
-    k = jax.random.normal(kk, pool, jnp.bfloat16)
-    v = jax.random.normal(kv, pool, jnp.bfloat16)
+    rows = (engine.num_pages, engine.page_size, cfg.num_heads, cfg.head_dim)
+    k = jax.random.normal(kk, rows, jnp.bfloat16)
+    v = jax.random.normal(kv, rows, jnp.bfloat16)
+    pools = {
+        "bf16": (pack_heads(k), pack_heads(v)),
+        "int8": (pack_heads(quantize_kv(k)), pack_heads(quantize_kv(v))),
+    }
     rng = np.random.RandomState(seed)
-    lengths = jnp.asarray(rng.randint(0, engine.max_len - 1, size=b), jnp.int32)
     table = jnp.asarray(
         rng.permutation(engine.num_pages)[: b * pps].reshape(b, pps), jnp.int32
     )
-    out, visited = flash_paged_decode_attention(
-        q, k, v, lengths, table, block_k=engine.decode_block_k,
-        interpret=True if engine.decode_attention == "interpret" else None,
-        return_visited=True,
-    )
-    want = reference_paged_decode_attention(q, k, v, lengths, table)
-    err = _max_abs(out, want)
-    tiles = num_kv_blocks(
-        np.asarray(lengths), 1, engine.max_len, engine.decode_block_k
-    )
-    assert np.array_equal(np.asarray(visited), tiles), (visited, tiles)
-    assert err <= 0.02, err  # bf16 rows of unit variance
-    return {"decode_kernel_err_vs_reference": err,
-            "visited_tiles": np.asarray(visited).tolist()}
+    errs, visited_t1 = {}, None
+    for t in sorted({1, 5, engine.prefill_chunk}):
+        q = jax.random.normal(kq, (b, t, *rows[2:]), jnp.bfloat16)
+        lengths = jnp.asarray(
+            rng.randint(0, engine.max_len - t, size=b), jnp.int32
+        )
+        tiles = num_kv_blocks(
+            np.asarray(lengths), t, engine.max_len, engine.decode_block_k
+        )
+        for name, (kp, vp) in pools.items():
+            out, visited = flash_paged_decode_attention(
+                q, kp, vp, lengths, table, block_k=engine.decode_block_k,
+                interpret=(
+                    True if engine.decode_attention == "interpret" else None
+                ),
+                return_visited=True,
+            )
+            want = reference_paged_decode_attention(q, kp, vp, lengths, table)
+            errs[f"{name}_T{t}"] = err = _max_abs(out, want)
+            assert np.array_equal(np.asarray(visited), tiles), (visited, tiles)
+            assert err <= 0.02, (name, t, err)  # rows of unit variance
+        if t == 1:
+            visited_t1 = tiles.tolist()
+    return {"decode_kernel_err_vs_reference": errs,
+            "visited_tiles": visited_t1,
+            "page_writer_equals_row_scatter": _page_writer_vs_scatter(
+                engine, pools, table, seed)}
+
+
+def _page_writer_vs_scatter(engine, pools, table, seed: int) -> list:
+    """A prefill chunk's rows into a pool buffer by pages (the kernel
+    ``paged_cache_update`` takes for them on the chip) against the same
+    rows scattered one position at a time, bf16 rows and int8 payload:
+    ragged starts, chunks cut short, one slot writing nothing."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from mpit_tpu.models.gpt2 import paged_cache_update
+    from mpit_tpu.ops.decode_attention import paged_write_pages
+
+    b, t = engine.slots, engine.prefill_chunk
+    interpret = True if engine.decode_attention == "interpret" else None
+    rng = np.random.RandomState(seed + 1)
+    starts = jnp.asarray(rng.randint(0, engine.max_len - t, size=b), jnp.int32)
+    cut = rng.randint(1, t + 1, size=b)
+    cut[0], cut[-1] = t, 0
+    valid = jnp.asarray(np.arange(t)[None, :] < cut[:, None])
+    checked = []
+    for name, pool in (("bf16", pools["bf16"][0]), ("int8", pools["int8"][0].q)):
+        new = jax.random.normal(
+            jax.random.key(seed + 2), (b, t, pool.shape[-1]), jnp.float32
+        ) * 20
+        got = paged_write_pages(
+            pool, new, starts, table, valid, interpret=interpret
+        )
+        want = pool
+        for i in range(t):  # one row a slot: the scatter's path
+            want = paged_cache_update(
+                want, new[:, i : i + 1], starts + i, table,
+                valid=valid[:, i : i + 1],
+            )
+        assert got.dtype == pool.dtype and bool(jnp.all(got == want)), name
+        assert not bool(jnp.all(got == pool)), name  # something was written
+        checked.append(name)
+    return checked
 
 
 def _max_abs(a, b) -> float:

@@ -327,11 +327,12 @@ def _contract_quantized_decode(ctx):
         jnp.zeros((slots,), jnp.float32), jnp.zeros((slots,), jnp.int32),
     )
     f32 = jnp.dtype(jnp.float32)
-    pool = (pages, ps, cfg.num_heads, cfg.head_dim)
     assert_no_intermediate(
         jx,
-        pool,                              # one layer's dequantized pool
-        (cfg.num_layers,) + pool,          # the stacked pools
+        # One layer's dequantized pool, as stored (rows packed) and
+        # head-split.
+        (pages, ps, cfg.num_heads * cfg.head_dim),
+        (pages, ps, cfg.num_heads, cfg.head_dim),
         (slots, eng.pages_per_slot * ps,   # a slot's gathered dense view
          cfg.num_heads, cfg.head_dim),
         what="quantized paged decode step",

@@ -33,11 +33,14 @@ import jax.numpy as jnp
 from flax.linen.dtypes import promote_dtype
 from jax import lax
 
+from mpit_tpu.ops.decode_attention import paged_write_pages, writes_by_pages
 from mpit_tpu.ops.kv_quant import (
     QuantizedKV,
     dequantize_kv,
     kv_stack,
+    pack_heads,
     quantize_kv,
+    unpack_heads,
 )
 from mpit_tpu.ops.quantized_matmul import (
     QuantizedTensor,
@@ -96,25 +99,34 @@ def cache_update(cache, new, lengths):
 
 
 def paged_cache_update(pool, new, lengths, block_table, valid=None):
-    """Write ``new`` [B, T, H, Dh] into the page pool [P, page_size, H,
-    Dh] at sequence positions ``lengths .. lengths+T-1``, indirected
-    through ``block_table`` [B, pages_per_slot] int32 (ISSUE 7).
+    """Write ``new`` [B, T, H*Dh] (rows packed as the projection made
+    them) into one layer's page pool [P, page_size, H*Dh] at sequence
+    positions ``lengths .. lengths+T-1``, indirected through
+    ``block_table`` [B, pages_per_slot] int32 (ISSUE 7).
 
     The paged analogue of :func:`cache_update` — but a scatter, not a
-    per-slot dynamic slice: each (b, t) resolves to flat pool row
-    ``bt[b, pos//ps] * ps + pos % ps``. ``valid`` [B, T] bool masks
+    per-slot dynamic slice: each (b, t) resolves to pool row
+    ``[bt[b, pos//ps], pos % ps]``. The scatter indexes the pool as it
+    is stored (no reshape of the pool on either side), so under a
+    donating jit it writes ``B*T`` rows of the caller's buffer in place
+    and moves no other byte. XLA's scatter lands a row at a time (146
+    ns a row on the v5e), which a decode tick's B rows do not feel and a
+    prefill chunk's B*64 do: on a TPU a write of a page's worth of rows
+    or more into lane-aligned rows goes through
+    :func:`~mpit_tpu.ops.decode_attention.paged_write_pages`, the same
+    write a page at a time. ``valid`` [B, T] bool masks
     rows that must NOT land (prefill padding past the real prompt, and
     positions below a shared-prefix write floor — shared pages are
-    immutable); masked rows scatter to an out-of-bounds index and are
+    immutable); masked rows scatter to an out-of-bounds page and are
     DROPPED, so — unlike the dense path, where junk writes stayed
     inside the slot's own row — a padded prefill can never touch a
     page the slot does not own.
 
     A :class:`~mpit_tpu.ops.kv_quant.QuantizedKV` pool (ISSUE 15)
-    quantizes on write and scatters the per-(row, head) scale blocks
-    through the SAME flat indices — the scale scatter rides the
-    existing block-table path, so COW/prefix/preemption semantics
-    cover scales by construction.
+    quantizes on write, per (row, head), and scatters the scale plane
+    [P, page_size, H] through the SAME indices — the scale scatter
+    rides the existing block-table path, so COW/prefix/preemption
+    semantics cover scales by construction.
     """
     p, ps = pool.shape[0], pool.shape[1]
     b, t = new.shape[0], new.shape[1]
@@ -123,44 +135,44 @@ def paged_cache_update(pool, new, lengths, block_table, valid=None):
         block_table, jnp.clip(pos // ps, 0, block_table.shape[1] - 1),
         axis=1,
     )
-    flat = page * ps + pos % ps
     # A position past the slot's virtual capacity must be DROPPED, not
     # clipped into its last page (padding rows can reach here even
     # before any explicit mask).
-    flat = jnp.where(pos < block_table.shape[1] * ps, flat, p * ps)
+    page = jnp.where(pos < block_table.shape[1] * ps, page, p)
     if valid is not None:
-        flat = jnp.where(valid, flat, p * ps)  # OOB -> dropped
+        page = jnp.where(valid, page, p)  # OOB -> dropped
+    page, off = page.reshape(-1), (pos % ps).reshape(-1)
 
     def scatter(pl, rows):
-        pool_flat = pl.reshape(p * ps, *pl.shape[2:])
-        pool_flat = pool_flat.at[flat.reshape(-1)].set(
-            rows.astype(pl.dtype).reshape(b * t, *rows.shape[2:]),
-            mode="drop",
+        if writes_by_pages(pl, t):
+            return paged_write_pages(pl, rows, lengths, block_table, valid)
+        return pl.at[page, off].set(
+            rows.astype(pl.dtype).reshape(b * t, -1), mode="drop"
         )
-        return pool_flat.reshape(pl.shape)
 
     if isinstance(pool, QuantizedKV):
-        qn = quantize_kv(new)
+        qn = pack_heads(quantize_kv(unpack_heads(new, pool.scale.shape[-1])))
         return QuantizedKV(
             q=scatter(pool.q, qn.q), scale=scatter(pool.scale, qn.scale)
         )
     return scatter(pool, new)
 
 
-def paged_gather(pool, block_table):
-    """Materialize each slot's dense cache view from the pool:
-    [P, page_size, H, Dh] gathered through [B, pages_per_slot] →
+def paged_gather(pool, block_table, num_heads):
+    """Materialize each slot's dense cache view from one layer's pool:
+    [P, page_size, H*Dh] gathered through [B, pages_per_slot] →
     [B, pages_per_slot·page_size, H, Dh]. Rows past a slot's fill are
     whatever the mapped (or stale) pages hold — garbage by design; the
     attention mask defines validity, exactly as in the dense cache. A
-    quantized pool gathers q and scale together (tree-mapped)."""
+    quantized pool gathers q and scale together (tree-mapped; the
+    scale comes back in the dense cache's keepdims form)."""
 
     def g1(pl):
-        g = pl[block_table]  # [B, n_ps, ps, H, Dh]
-        return g.reshape(g.shape[0], -1, *g.shape[3:])
+        g = pl[block_table]  # [B, n_ps, ps, H*Dh]
+        return g.reshape(g.shape[0], -1, g.shape[-1])
 
     with jax.named_scope("kv_gather"):
-        return jax.tree.map(g1, pool)
+        return unpack_heads(jax.tree.map(g1, pool), num_heads)
 
 
 def paged_cached_attention(q, k_pool, v_pool, lengths, block_table):
@@ -173,10 +185,11 @@ def paged_cached_attention(q, k_pool, v_pool, lengths, block_table):
     (:func:`mpit_tpu.ops.decode_attention.flash_paged_decode_attention`)
     never materializes this view — it DMAs only visited tiles, resolved
     per-tile through the block table."""
+    h = q.shape[2]
     return cached_attention(
         q,
-        paged_gather(k_pool, block_table),
-        paged_gather(v_pool, block_table),
+        paged_gather(k_pool, block_table, h),
+        paged_gather(v_pool, block_table, h),
         lengths,
     )
 
@@ -352,7 +365,8 @@ class Block(nn.Module):
         (:func:`cached_attention`) instead of ``cfg.attention_fn``;
         returns ``(x, (k, v))`` with the updated buffers. A 5-tuple
         ``(k_pool, v_pool, lengths, block_table, write_valid)`` selects
-        the PAGED cache path (ISSUE 7): appends scatter through the
+        the PAGED cache path (ISSUE 7), the pools this layer's own
+        [P, page_size, H*Dh] buffers: appends scatter through the
         block table (:func:`paged_cache_update`, ``write_valid`` [B, T]
         masking padding/shared-prefix rows) and attention runs
         ``cfg.paged_attention_fn`` (default the gather-dense
@@ -380,13 +394,12 @@ class Block(nn.Module):
             elif len(layer_cache) == 5:
                 k_pool, v_pool, lengths, block_table, write_valid = layer_cache
                 with jax.named_scope("kv_write"):
+                    # k, v are already the pool's packed rows [B, T, H*Dh].
                     k_pool = paged_cache_update(
-                        k_pool, split(k), lengths, block_table,
-                        valid=write_valid,
+                        k_pool, k, lengths, block_table, valid=write_valid
                     )
                     v_pool = paged_cache_update(
-                        v_pool, split(v), lengths, block_table,
-                        valid=write_valid,
+                        v_pool, v, lengths, block_table, valid=write_valid
                     )
                 attn_fn = cfg.paged_attention_fn or paged_cached_attention
                 attn = attn_fn(split(q), k_pool, v_pool, lengths, block_table)
@@ -442,15 +455,17 @@ class GPT2(nn.Module):
         ``targets``.
 
         ``paged_cache`` (serving; ISSUE 7): ``(k_pools, v_pools,
-        lengths, block_tables, write_valid)`` with pools
-        ``[num_layers, num_pages, page_size, H, Dh]``, ``block_tables``
-        [B, pages_per_slot] int32 and ``write_valid`` [B, T] bool — the
+        lengths, block_tables, write_valid)`` with pools a sequence of
+        ``num_layers`` buffers ``[num_pages, page_size, H*Dh]`` (layer
+        ``i`` writes and reads ``k_pools[i]`` and nothing else, so a
+        caller that donates them gets each back updated in place),
+        ``block_tables`` [B, pages_per_slot] int32 and ``write_valid`` [B, T] bool — the
         paged analogue of ``cache``: K/V appends scatter through each
         slot's block table (rows with ``write_valid`` False are
         dropped, never written), attention runs
         ``cfg.paged_attention_fn`` (default gather-dense reference),
         and the return becomes ``(logits_or_hidden, (new_k_pools,
-        new_v_pools))``. Mutually exclusive with ``cache``/``targets``.
+        new_v_pools))``, tuples of per-layer buffers again. Mutually exclusive with ``cache``/``targets``.
 
         ``return_hidden`` (serving; requires ``cache``/``paged_cache``):
         skip the LM-head matmul and return the final post-``ln_f``
@@ -529,16 +544,20 @@ class GPT2(nn.Module):
                 new_k.append(k_i)
                 new_v.append(v_i)
             elif paged_cache is not None:
-                with jax.named_scope("kv_write"):
-                    layer = (pool_k[i], pool_v[i])
                 x, (k_i, v_i) = block(cfg, name=f"block_{i}")(
-                    x, (*layer, cache_lengths, block_tables, write_valid)
+                    x,
+                    (pool_k[i], pool_v[i], cache_lengths, block_tables,
+                     write_valid),
                 )
                 new_k.append(k_i)
                 new_v.append(v_i)
             else:
                 x = block(cfg, name=f"block_{i}")(x)
-        if new_k:
+        if paged_cache is not None:
+            # One buffer a layer, each written by its own layer alone:
+            # nothing to take out of a stack and nothing to stack again.
+            new_kv = (tuple(new_k), tuple(new_v))
+        elif new_k:
             # A functional update hands the whole cache back: taking a
             # layer's buffer out of the stack (above) and stacking the
             # written ones again belong to the write.

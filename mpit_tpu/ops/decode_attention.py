@@ -84,6 +84,8 @@ from mpit_tpu.ops.ring_collectives import dequantize_blocks
 __all__ = [
     "flash_decode_attention",
     "flash_paged_decode_attention",
+    "paged_write_pages",
+    "writes_by_pages",
     "reference_decode_attention",
     "reference_paged_decode_attention",
     "num_kv_blocks",
@@ -333,23 +335,28 @@ def _lane_pad(n: int) -> int:
     return -(-n // 128) * 128
 
 
-def _kv_operands(k, v, h, pk):
+def _kv_operands(k, v, pk=lambda x: x):
     """The kernel's HBM operand list + matching double-buffer scratch
-    for one (K, V) pair — plain buffers or the quantized interleave
-    ``k, k_scale, v, v_scale`` (scales packed [.., H] from the stored
-    keepdims [.., H, 1] form). One helper serves the dense and paged
-    calls, so the operand order and the kernel's unpacking cannot
-    drift apart."""
+    dtypes for one (K, V) pair — plain buffers or the quantized
+    interleave ``k, k_scale, v, v_scale``. ``pk`` packs a leaf's rows
+    for the kernel (``[.., H, Dh]`` to ``[.., H*Dh]``, a stored
+    keepdims scale ``[.., H, 1]`` to ``[.., H]``); a page pool is
+    stored packed and takes the default, so its buffers go to the
+    kernel as they are. One helper serves the dense and paged calls,
+    so the operand order and the kernel's unpacking cannot drift
+    apart."""
     quantized = isinstance(k, QuantizedKV)
     if not quantized:
         return quantized, [pk(k), pk(v)], [k.dtype, v.dtype]
     # Mosaic DMAs whole 128-lane tiles: a [.., H] f32 plane narrower
     # than a lane tile is refused ("slice shape ... must be aligned to
     # tiling (128)"), so the scale operand is lane-padded here.
-    psc = lambda sc: jnp.pad(
-        sc.reshape(sc.shape[0], sc.shape[1], h),
-        ((0, 0), (0, 0), (0, _lane_pad(h) - h)),
-    )
+    def psc(sc):
+        sc = pk(sc)
+        return jnp.pad(
+            sc, ((0, 0), (0, 0), (0, _lane_pad(sc.shape[-1]) - sc.shape[-1]))
+        )
+
     ops = [pk(k.q), psc(k.scale), pk(v.q), psc(v.scale)]
     return quantized, ops, [jnp.int8, jnp.float32, jnp.int8, jnp.float32]
 
@@ -369,9 +376,9 @@ def _scratch_for(quantized, block_k, hd, h, dtypes):
 def _decode_call(q, k, v, lengths, *, block_k, interpret):
     b, t, h, d = q.shape
     hd = h * d
-    pk = lambda x: x.reshape(x.shape[0], x.shape[1], hd)  # free head-pack
+    pk = lambda x: x.reshape(x.shape[0], x.shape[1], -1)  # head-pack
     with jax.named_scope("kv_gather"):
-        quantized, kv_ops, kv_dtypes = _kv_operands(k, v, h, pk)
+        quantized, kv_ops, kv_dtypes = _kv_operands(k, v, pk)
     kern = functools.partial(
         _decode_kernel,
         block_k=block_k,
@@ -420,9 +427,9 @@ def _paged_decode_call(
 ):
     b, t, h, d = q.shape
     hd = h * d
-    pk = lambda x: x.reshape(x.shape[0], x.shape[1], hd)  # free head-pack
     with jax.named_scope("kv_gather"):
-        quantized, kv_ops, kv_dtypes = _kv_operands(k_pool, v_pool, h, pk)
+        # The pools are stored as the kernel reads them: no repacking.
+        quantized, kv_ops, kv_dtypes = _kv_operands(k_pool, v_pool)
     kern = functools.partial(
         _decode_kernel,
         block_k=block_k,
@@ -462,9 +469,185 @@ def _paged_decode_call(
     )(
         jnp.asarray(lengths, jnp.int32),
         jnp.asarray(block_table, jnp.int32),
-        pk(q), *kv_ops,
+        q.reshape(b, t, hd), *kv_ops,
     )
     return o.reshape(b, t, h, d), visited
+
+
+# ---------------------------------------------------------------------------
+# The pool's writer. A prefill chunk lands B*T rows in a layer's buffer;
+# XLA's scatter takes them a row at a time (146 ns a row on the v5e: 150
+# us a buffer for 16 x 64 rows, 72 buffers a step). Rows of one slot are
+# consecutive positions, so they fill whole pages but for the two ends:
+# this kernel reads the touched pages, lays the new rows over them and
+# writes them back, a page a DMA, every page of a group in flight at once.
+# ---------------------------------------------------------------------------
+
+_WRITE_GROUP_BYTES = 2 * 2**20  # pages held in VMEM at once, per buffer
+_SKIP, _WHOLE, _PART = 0, 1, 2  # what a touched page takes from the rows
+
+
+def _page_write_kernel(kind_ref, pidx_ref, keep_ref, rows_hbm, pool_hbm,
+                       out_hbm, old, new, sem, *, group):
+    """One group of touched pages. ``kind_ref`` / ``pidx_ref`` (SMEM, all
+    groups) say what each takes and which page it is; ``rows_hbm``
+    [pages, ps, W] holds the new rows in their places. A page that the
+    rows fill whole goes from there to the pool in one DMA, HBM to HBM;
+    a page they fill in part is read, overlaid where ``keep_ref``
+    [group, ps, 1] is 0, and written back. ``out_hbm`` is ``pool_hbm``'s
+    own buffer (aliased)."""
+    del pool_hbm
+    g0 = pl.program_id(0) * group
+
+    def whole(i):
+        return pltpu.make_async_copy(
+            rows_hbm.at[g0 + i], out_hbm.at[pidx_ref[g0 + i]], sem.at[0, i]
+        )
+
+    def part(i, what):
+        page = out_hbm.at[pidx_ref[g0 + i]]
+        src, dst, row = {
+            "old": (page, old.at[i], 0),
+            "new": (rows_hbm.at[g0 + i], new.at[i], 1),
+            "back": (old.at[i], page, 0),
+        }[what]
+        return pltpu.make_async_copy(src, dst, sem.at[row, i])
+
+    def each(kind, body):
+        def step(i, carry):
+            pl.when(kind_ref[g0 + i] == kind)(lambda: body(i))
+            return carry
+
+        lax.fori_loop(0, group, step, 0)
+
+    each(_WHOLE, lambda i: whole(i).start())
+
+    def fetch(i):
+        part(i, "old").start()
+        part(i, "new").start()
+
+    each(_PART, fetch)
+
+    def merge(i):
+        part(i, "old").wait()
+        part(i, "new").wait()
+        wide = jnp.float32 if jnp.issubdtype(old.dtype, jnp.floating) else (
+            jnp.int32)  # a select on 32-bit lanes: exact for bf16 and int8
+        old[i] = jnp.where(
+            keep_ref[i] != 0, old[i].astype(wide), new[i].astype(wide)
+        ).astype(old.dtype)
+        part(i, "back").start()
+
+    each(_PART, merge)
+    each(_PART, lambda i: part(i, "back").wait())
+    each(_WHOLE, lambda i: whole(i).wait())
+
+
+def _paged_write_frames(new, lengths, block_table, valid, num_pages,
+                        page_size):
+    """The pages a write touches, one entry a (slot, page): what each
+    takes [B*n] (nothing, every row, some rows), page ids [B*n], the new
+    rows in their places of those pages [B*n, ps, W] and a flag
+    [B*n, ps] that is 1 where the page's old row stays."""
+    b, t = new.shape[0], new.shape[1]
+    n = (t + page_size - 2) // page_size + 1  # pages T rows can span
+    first, shift = lengths // page_size, lengths % page_size
+    idx = first[:, None] + jnp.arange(n)[None, :]
+    pidx = jnp.take_along_axis(
+        block_table, jnp.clip(idx, 0, block_table.shape[1] - 1), axis=1
+    )
+
+    def frame(x):
+        # Row r of a slot's frame is x[r - shift]: a window a slot of x
+        # padded at both ends (a batched dynamic slice: XLA makes a loop
+        # over the slots of it).
+        lead = ((0, 0), (page_size, n * page_size - t))
+        padded = jnp.pad(x, lead + ((0, 0),) * (x.ndim - 2))
+        take = lambda rows, s: lax.dynamic_slice_in_dim(
+            rows, page_size - s, n * page_size, axis=0
+        )
+        return jax.vmap(take)(padded, shift)
+
+    lands = frame(jnp.ones((b, t), bool) if valid is None else valid)
+    # Past the slot's table there is no page to write: dropped.
+    lands = lands.reshape(b, n, page_size) & (
+        idx < block_table.shape[1])[:, :, None]
+    kind = jnp.where(
+        lands.all(-1), _WHOLE, jnp.where(lands.any(-1), _PART, _SKIP)
+    )
+    return (
+        kind.reshape(-1).astype(jnp.int32),
+        jnp.clip(pidx, 0, num_pages - 1).reshape(-1).astype(jnp.int32),
+        frame(new).reshape(b * n, page_size, -1),
+        (~lands).reshape(b * n, page_size),
+    )
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _paged_write_call(pool, new, lengths, block_table, valid, *, interpret):
+    num_pages, ps, w = pool.shape
+    kind, pidx, rows, keep = _paged_write_frames(
+        new.astype(pool.dtype), jnp.asarray(lengths, jnp.int32),
+        jnp.asarray(block_table, jnp.int32), valid, num_pages, ps,
+    )
+    n = pidx.shape[0]
+    # The most pages whose rows fit the budget, in equal groups.
+    fit = max(1, _WRITE_GROUP_BYTES // (ps * w * pool.dtype.itemsize))
+    group = max(g for g in range(1, n + 1) if n % g == 0 and g <= fit)
+    kern = functools.partial(_page_write_kernel, group=group)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    return pl.pallas_call(
+        kern,
+        name="paged_kv_write",
+        grid=(n // group,),
+        in_specs=[
+            smem, smem,  # kinds and page ids, whole
+            pl.BlockSpec((group, ps, 1), lambda g: (g, 0, 0),
+                         memory_space=pltpu.VMEM),
+            hbm, hbm,  # the rows and the pool stay in HBM
+        ],
+        out_specs=hbm,
+        out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype, vma=_vma(new)),
+        input_output_aliases={4: 0},  # written in place
+        scratch_shapes=[
+            pltpu.VMEM((group, ps, w), pool.dtype),
+            pltpu.VMEM((group, ps, w), pool.dtype),
+            pltpu.SemaphoreType.DMA((2, group)),
+        ],
+        interpret=bool(interpret),
+    )(kind, pidx, keep.astype(jnp.int32)[:, :, None], rows, pool)
+
+
+def writes_by_pages(pool, t: int) -> bool:
+    """Whether :func:`paged_write_pages` is the way to land ``t`` rows a
+    slot in ``pool`` [P, page_size, W]: on a TPU, for a page's worth of
+    rows or more (fewer cost a row scatter less than the pages' round
+    trip), into rows of whole lane tiles (an int8 pool's [.., H] scale
+    plane cannot be DMA'd)."""
+    return (
+        _use_kernel(None) and t >= pool.shape[1]
+        and pool.shape[-1] % 128 == 0
+    )
+
+
+def paged_write_pages(pool, new, lengths, block_table, valid=None, *,
+                      interpret: bool | None = None):
+    """Write ``new`` [B, T, W] into one page-pool buffer [P, page_size, W]
+    at positions ``lengths .. lengths+T-1`` of each slot's block table,
+    rows with ``valid`` False (and positions past the table) dropped:
+    what :func:`mpit_tpu.models.gpt2.paged_cache_update`'s scatter does,
+    a page at a time. Under a donating jit the pool is updated in place
+    (``input_output_aliases``) and only the touched pages move: at most
+    ``(T + page_size - 2) // page_size + 1`` a slot, each read, overlaid
+    and written back whole. A page is written by one slot alone (a shared
+    page is copied before it is written), so whole-page writes of
+    different slots never meet; kept rows are rewritten with what was
+    just read. ``interpret`` as in :func:`flash_decode_attention`; there
+    is no lax twin here, the scatter is it."""
+    return _paged_write_call(
+        pool, new, lengths, block_table, valid, interpret=bool(interpret)
+    )
 
 
 def flash_paged_decode_attention(
@@ -478,10 +661,15 @@ def flash_paged_decode_attention(
     interpret: bool | None = None,
     return_visited: bool = False,
 ):
-    """Length-aware attention against the PAGED KV pool (ISSUE 7):
-    ``[B, T, H, Dh]`` queries vs ``[num_pages, page_size, H, Dh]``
-    pools, each slot's pages named by ``block_table``
-    [B, pages_per_slot] int32.
+    """Length-aware attention against one layer's PAGED KV pool (ISSUE
+    7): ``[B, T, H, Dh]`` queries vs ``[num_pages, page_size, H*Dh]``
+    pools (rows packed head-major, the form
+    :class:`~mpit_tpu.serve.kvcache.PagedKVCache` stores: the kernel
+    DMAs its tiles straight out of the caller's buffer, with no copy or
+    relayout of the pool before it), each slot's pages named by
+    ``block_table`` [B, pages_per_slot] int32. A quantized pool is a
+    :class:`~mpit_tpu.ops.kv_quant.QuantizedKV` of int8 rows in that
+    shape and a ``[num_pages, page_size, H]`` scale plane.
 
     Drop-in for :func:`mpit_tpu.models.gpt2.paged_cached_attention`
     (plug in as ``GPT2Config.paged_attention_fn``). The tile loop and

@@ -28,9 +28,13 @@ quantized once, when written, and never rescaled by a later append.
 
 :class:`QuantizedKV` is the container: a registered pytree ``(q int8,
 scale f32)`` that drops into every ``KVCache.k`` / ``PagedKVCache.k``
-seat. The scale keeps a trailing size-1 axis (``[..., H, 1]`` vs the
-buffer's ``[..., H, Dh]``) so both leaves share rank and the engine's
-slot-select masks broadcast over either through one ``tree.map``.
+seat. In the dense cache the scale keeps a trailing size-1 axis
+(``[..., H, 1]`` vs the buffer's ``[..., H, Dh]``) so both leaves share
+rank and the engine's slot-select masks broadcast over either through
+one ``tree.map``. A page pool holds rows PACKED, as the decode kernel
+reads them (``q`` ``[pages, page_size, H*Dh]``, ``scale`` ``[pages,
+page_size, H]``, equal rank again): :func:`pack_heads` /
+:func:`unpack_heads` move between the two forms.
 """
 
 from __future__ import annotations
@@ -50,6 +54,8 @@ __all__ = [
     "QuantizedKV",
     "quantize_kv",
     "dequantize_kv",
+    "pack_heads",
+    "unpack_heads",
     "kv_stack",
     "kv_wire_bytes_per_row",
 ]
@@ -63,7 +69,8 @@ SCALE_BYTES = 4
 class QuantizedKV:
     """One quantized K (or V) buffer: ``q`` int8 ``[..., H, Dh]`` plus
     ``scale`` f32 ``[..., H, 1]`` (keepdims — equal rank, so masks and
-    shardings written for the buffer broadcast/apply to both leaves).
+    shardings written for the buffer broadcast/apply to both leaves);
+    in a page pool the packed pair ``[..., H*Dh]`` / ``[..., H]``.
     A pytree: it passes through jit/shard_map/device_put whole, and
     ``jax.tree.map`` over a cache touches q and scale together."""
 
@@ -106,6 +113,21 @@ def dequantize_kv(kv: QuantizedKV):
     flash-decode kernel never calls this on a whole buffer — it
     dequantizes per visited tile in VMEM)."""
     return dequantize_blocks(kv.q, kv.scale)
+
+
+def pack_heads(rows):
+    """Head-split rows ``[..., H, Dh]`` (scale leaves ``[..., H, 1]``)
+    to the packed row form ``[..., H*Dh]`` (``[..., H]``) a page pool
+    stores. Plain arrays and :class:`QuantizedKV` alike."""
+    return jax.tree.map(lambda a: a.reshape(*a.shape[:-2], -1), rows)
+
+
+def unpack_heads(rows, num_heads: int):
+    """Inverse of :func:`pack_heads`: ``[..., H*Dh]`` back to ``[..., H,
+    Dh]``, and a packed scale plane ``[..., H]`` to ``[..., H, 1]``."""
+    return jax.tree.map(
+        lambda a: a.reshape(*a.shape[:-1], num_heads, -1), rows
+    )
 
 
 def kv_stack(buffers):

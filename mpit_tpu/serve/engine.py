@@ -79,7 +79,7 @@ from mpit_tpu.models.gpt2 import (
     paged_cache_update,
     paged_cached_attention,
 )
-from mpit_tpu.ops.kv_quant import kv_stack
+from mpit_tpu.ops.kv_quant import kv_stack, pack_heads, unpack_heads
 from mpit_tpu.ops.quantized_matmul import (
     QuantizedTensor,
     dequantize_tensor,
@@ -144,18 +144,40 @@ _DTYPE_SHORT = {"float32": "f32", "bfloat16": "bf16", "int8": "int8"}
 _WEIGHT_DTYPES = ("f32", "int8")
 
 
-def _jit_as(name: str, step):
+def _jit_as(name: str, step, donate=()):
     """``jax.jit(step)`` under the stable module name ``jit_<name>``
     (a bound method would give ``jit__paged_decode_step``): a device
     trace names every operation's module, and a reduction tells the
     tick's own operations from the RNG split's and the page copies' by
-    it."""
+    it. ``donate``: positions of the page-pool caches the step consumes.
+    The paged steps donate them, so each layer's buffer is updated in
+    place and one pool lives, not two; the caller must drop its
+    reference and keep the cache the step returns (``Engine`` assigns
+    ``self.cache`` from every such call)."""
 
     def named(*args):
         return step(*args)
 
     named.__name__ = named.__qualname__ = name
-    return jax.jit(named)
+    return jax.jit(named, donate_argnums=tuple(donate))
+
+
+def _zeroed(cache):
+    """Zeros in the place of ``cache``: its buffers are freed before the
+    new ones are made, so that two caches never live at once."""
+    # Placed as the old one was (a buffer that was never committed to a
+    # device stays so: the jitted steps key their cache on it).
+    like = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=a.sharding if a.committed else None
+        ),
+        cache,
+    )
+    for leaf in jax.tree.leaves(cache):
+        leaf.delete()
+    return jax.tree.map(
+        lambda s: jnp.zeros(s.shape, s.dtype, device=s.sharding), like
+    )
 
 
 def _kv_where(mask, new, old):
@@ -351,9 +373,10 @@ def _tp_paged_forward(
 ):
     """Paged-cache TP forward (ISSUE 7): :func:`_tp_forward_body` with
     the per-slot dense buffers swapped for this device's H/P head shard
-    of the page pool — K/V appends scatter through the (replicated)
-    block tables with ``write_valid``-masked rows dropped, attention
-    runs ``attn_fn`` (default the gather-dense
+    of the page pool (each layer's buffer ``[P, ps, H/P * Dh]``: the
+    rank's contiguous slice of the packed rows) — K/V appends scatter
+    through the (replicated) block tables with ``write_valid``-masked
+    rows dropped, attention runs ``attn_fn`` (default the gather-dense
     :func:`paged_cached_attention`; the serving engine plugs the paged
     flash kernel) against the pool. Numerics per position are identical
     to the dense TP forward — the pool is just a different placement of
@@ -362,11 +385,11 @@ def _tp_paged_forward(
     def layer_kv(i, q, k, v):
         with jax.named_scope("kv_write"):
             k_i = paged_cache_update(
-                cache.k[i], k, cache.lengths, block_tables,
+                cache.k[i], pack_heads(k), cache.lengths, block_tables,
                 valid=write_valid,
             )
             v_i = paged_cache_update(
-                cache.v[i], v, cache.lengths, block_tables,
+                cache.v[i], pack_heads(v), cache.lengths, block_tables,
                 valid=write_valid,
             )
         attn = (attn_fn or paged_cached_attention)(
@@ -378,10 +401,9 @@ def _tp_paged_forward(
         params, tokens, cache.lengths, cfg=cfg, axis=axis,
         layer_kv=layer_kv, with_head=with_head, clip_positions=True,
     )
-    with jax.named_scope("kv_write"):
-        return out, PagedKVCache(
-            k=kv_stack(new_k), v=kv_stack(new_v), lengths=cache.lengths
-        )
+    return out, PagedKVCache(
+        k=tuple(new_k), v=tuple(new_v), lengths=cache.lengths
+    )
 
 
 def _trimmed_sharding(world, spec):
@@ -756,9 +778,12 @@ class Engine:
                 ),
             )
             if self.paged:
-                cs = paged_cache_specs(tp_axis, quantized=self.kv_quantized)
+                cs = paged_cache_specs(
+                    tp_axis, num_layers=cfg.num_layers,
+                    quantized=self.kv_quantized,
+                )
                 sharding = _trimmed_sharding(
-                    world, cs.k.q if self.kv_quantized else cs.k
+                    world, cs.k[0].q if self.kv_quantized else cs.k[0]
                 )
                 rep = jax.sharding.PartitionSpec()
                 fwd = world.shard_map(
@@ -889,27 +914,37 @@ class Engine:
                 sharding=sharding, dtype=self._cache_dtype,
                 quantized=self.kv_quantized,
             )
+            # Every step that writes the pool donates it (argument
+            # positions of the cache and, on a speculative engine, the
+            # draft cache): the scatter of a tick's rows is then the
+            # only write the pool sees. The gather of a spill only reads.
+            draft = bool(self.spec_k)
             self._prefill_paged_jit = _jit_as(
-                "prefill_paged", self._paged_prefill_step
+                "prefill_paged", self._paged_prefill_step,
+                donate=(1, 13) if draft else (1,),
             )
             if self.spec_k:
                 self._spec_draft_jit = _jit_as(
-                    "spec_draft", self._spec_draft_step
+                    "spec_draft", self._spec_draft_step, donate=(1,)
                 )
                 self._spec_verify_jit = _jit_as(
-                    "spec_verify", self._spec_verify_step
+                    "spec_verify", self._spec_verify_step, donate=(1,)
                 )
             else:
                 self._decode_paged_jit = _jit_as(
-                    "decode_paged", self._paged_decode_step
+                    "decode_paged", self._paged_decode_step, donate=(1,)
                 )
-            self._copy_page_jit = _jit_as("copy_page", self._copy_page_step)
+            self._copy_page_jit = _jit_as(
+                "copy_page", self._copy_page_step,
+                donate=(0, 3) if draft else (0,),
+            )
             if self.host_pages:
                 self._gather_page_jit = _jit_as(
                     "gather_page", self._gather_page_step
                 )
                 self._scatter_page_jit = _jit_as(
-                    "scatter_page", self._scatter_page_step
+                    "scatter_page", self._scatter_page_step,
+                    donate=(0, 3) if draft else (0,),
                 )
         else:
             self.allocator = None
@@ -977,7 +1012,8 @@ class Engine:
         # honesty: the visited-tile sweep DMAs int8 tiles + scales, so
         # that is what decode_hbm_util_pct / GB-s figures must count).
         self._kv_row_bytes = kv_wire_bytes_per_row(
-            self.cfg.num_heads, self.cfg.head_dim, self.cache.k.dtype
+            self.cfg.num_heads, self.cfg.head_dim,
+            jax.tree.leaves(self.cache.k)[0].dtype,
         )
         # ISSUE 18: the byte-exact HBM ledger. Every buffer this
         # constructor pinned to the device registers ONCE — the weight
@@ -1446,52 +1482,56 @@ class Engine:
                 v=_kv_where(sel, new.v, cache.v),
                 lengths=lens + n_emit,
             )
-        return out_cache, new_last, emit, n_emit, n_acc
+        # The fill a second time, for the draft cache: two outputs are
+        # two buffers, and a donating step must not find one buffer in
+        # both caches.
+        return out_cache, new_last, emit, n_emit, n_acc, out_cache.lengths
 
     def _copy_page_step(self, cache, src, dst, dcache=None):
-        """Copy pool page ``src`` → ``dst`` across every layer, K and V
-        — the device half of a copy-on-write remap (the allocator
-        already repointed the block table at ``dst``). A speculative
-        engine's draft pool shares the block tables, so the same remap
-        copies its page too."""
+        """Copy pool page ``src`` → ``dst`` in every layer's buffer, K
+        and V — the device half of a copy-on-write remap (the allocator
+        already repointed the block table at ``dst``). One page read and
+        one written in place a buffer (the cache is donated). A
+        speculative engine's draft pool shares the block tables, so the
+        same remap copies its page too."""
 
-        def cp(pool):
-            # tree-mapped: a quantized pool copies its int8 page AND
-            # the page's scale block in the same remap (ISSUE 15 —
-            # COW carries the scales with the pages).
-            def cp1(pl):
-                page = jax.lax.dynamic_index_in_dim(
-                    pl, src, axis=1, keepdims=True
-                )
-                return jax.lax.dynamic_update_slice_in_dim(
-                    pl, page, dst, axis=1
-                )
+        def cp1(pl):
+            # Per leaf: a quantized layer copies its int8 page AND the
+            # page's scale block in the same remap (ISSUE 15 — COW
+            # carries the scales with the pages).
+            page = jax.lax.dynamic_index_in_dim(
+                pl, src, axis=0, keepdims=True
+            )
+            return jax.lax.dynamic_update_slice_in_dim(
+                pl, page, dst, axis=0
+            )
 
-            return jax.tree.map(cp1, pool)
-
-        out = PagedKVCache(
-            k=cp(cache.k), v=cp(cache.v), lengths=cache.lengths
+        cp = lambda c: PagedKVCache(
+            k=jax.tree.map(cp1, c.k), v=jax.tree.map(cp1, c.v),
+            lengths=c.lengths,
         )
         if not self.spec_k:
-            return out
-        return out, PagedKVCache(
-            k=cp(dcache.k), v=cp(dcache.v), lengths=dcache.lengths
-        )
+            return cp(cache)
+        return cp(cache), cp(dcache)
 
     def _gather_page_step(self, cache, page, dcache=None):
         """Pull pool page ``page`` (all layers, K and V; the draft pool
-        too on a speculative engine) into fresh [L, 1, ps, H, ·]
-        buffers — the device half of a spill. The page id rides as a
+        too on a speculative engine) into fresh [L, ps, ·] buffers,
+        the layers' pages stacked so that a spill is one array a pool to
+        fetch — the device half of a spill. The page id rides as a
         traced scalar (one compile serves every spill) and a quantized
         pool gathers its int8 page AND the page's scale block in the
         same pass (ISSUE 20: payload + scales travel as one unit)."""
 
         def gp(pool):
             return jax.tree.map(
-                lambda pl: jax.lax.dynamic_index_in_dim(
-                    pl, page, axis=1, keepdims=True
-                ),
-                pool,
+                lambda *layers: jnp.concatenate([
+                    jax.lax.dynamic_index_in_dim(
+                        pl, page, axis=0, keepdims=True
+                    )
+                    for pl in layers
+                ]),
+                *pool,
             )
 
         out = (gp(cache.k), gp(cache.v))
@@ -1501,18 +1541,22 @@ class Engine:
 
     def _scatter_page_step(self, cache, dst, payload, dcache=None):
         """Write a previously gathered page payload into pool page
-        ``dst`` — the device half of a restream. ``payload`` is the
+        ``dst`` of every layer's buffer, in place (the cache is
+        donated) — the device half of a restream. ``payload`` is the
         tuple :meth:`_gather_page_step` produced (round-tripped through
         host numpy), so shapes/dtypes are fixed and only the page id is
         traced: one compile serves every restore, and int8 payloads
         land with their scale blocks in the same pass."""
 
         def sp(pool, pay):
-            return jax.tree.map(
-                lambda pl, pg: jax.lax.dynamic_update_slice_in_dim(
-                    pl, pg, dst, axis=1
-                ),
-                pool, pay,
+            return tuple(
+                jax.tree.map(
+                    lambda pl, pg: jax.lax.dynamic_update_slice_in_dim(
+                        pl, pg[i : i + 1], dst, axis=0
+                    ),
+                    layer, pay,
+                )
+                for i, layer in enumerate(pool)
             )
 
         out = PagedKVCache(
@@ -1792,7 +1836,7 @@ class Engine:
                 jnp.asarray(self.allocator.block_tables, jnp.int32),
                 jnp.asarray(self.allocator.mapped_tokens(), jnp.int32),
             ]
-        self.cache, self.last_token, emit, n_emit, n_acc = (
+        self.cache, self.last_token, emit, n_emit, n_acc, fill = (
             self.compile_watch.call(
                 "spec_verify", self._spec_verify_jit, *args
             )
@@ -1800,9 +1844,7 @@ class Engine:
         # The draft cache's fill mirrors the target's — ONE lengths
         # assignment applies the acceptance rollback to both.
         dc = self.draft_cache
-        self.draft_cache = type(dc)(
-            k=dc.k, v=dc.v, lengths=self.cache.lengths
-        )
+        self.draft_cache = type(dc)(k=dc.k, v=dc.v, lengths=fill)
         # The verify step's deliberate completion fence (docstring
         # contract).
         # analysis: allow(host-sync-in-hot-seam)
@@ -1991,22 +2033,12 @@ class Engine:
 
     def reset(self, seed: int = 0) -> None:
         """Clear all slots (bench warmup path); compiled steps survive."""
-        zeros = lambda kv: jax.tree.map(jnp.zeros_like, kv)
-        cls = PagedKVCache if self.paged else KVCache
-        self.cache = cls(
-            k=zeros(self.cache.k),
-            v=zeros(self.cache.v),
-            lengths=jnp.zeros_like(self.cache.lengths),
-        )
+        self.cache = _zeroed(self.cache)
+        if self.draft_cache is not None:
+            self.draft_cache = _zeroed(self.draft_cache)
         self.last_token = jnp.zeros_like(self.last_token)
         self._key = jax.random.key(seed)
         self._spec_state = None
-        if self.draft_cache is not None:
-            self.draft_cache = type(self.draft_cache)(
-                k=zeros(self.draft_cache.k),
-                v=zeros(self.draft_cache.v),
-                lengths=jnp.zeros_like(self.draft_cache.lengths),
-            )
         if self.paged:
             if self.host_pages:
                 # The host tier empties with the pool: drop payloads
@@ -2051,15 +2083,20 @@ class Engine:
                 self.allocator.block_tables[slot, :npages], np.int32
             )
 
-            def rows(buf):
-                arr = np.asarray(buf[:, pages])  # [L, npages, ps, H, last]
-                nl, n, p, h, last = arr.shape
-                return arr.reshape(nl, n * p, h, last)[:, :length].copy()
+            def rows(*layers):
+                # The slot's pages of every layer, stacked on the device
+                # so that one array comes to the host: [L, npages, ps, ·].
+                arr = np.asarray(jnp.stack([buf[pages] for buf in layers]))
+                arr = arr.reshape(len(layers), npages * ps, -1)
+                return arr[:, :length].copy()
 
-        else:
+            return tuple(
+                unpack_heads(jax.tree.map(rows, *pool), self.cfg.num_heads)
+                for pool in (self.cache.k, self.cache.v)
+            )
 
-            def rows(buf):
-                return np.asarray(buf[:, slot, :length])
+        def rows(buf):
+            return np.asarray(buf[:, slot, :length])
 
         return (
             jax.tree.map(rows, self.cache.k),
@@ -2080,8 +2117,7 @@ class Engine:
         layout — raw arrays, or objects with ``.q``/``.scale`` for a
         quantized cache (any container with those attributes works;
         leaves are rebuilt positionally)."""
-        quantized = hasattr(self.cache.k, "q")
-        if quantized:
+        if self.kv_quantized:
             # Rebuild as the cache's own pytree type so tree.map pairs
             # leaves positionally whatever container shipped them.
             k_rows = QuantizedKV(q=k_rows.q, scale=k_rows.scale)
@@ -2093,25 +2129,42 @@ class Engine:
                 self.allocator.block_tables[slot, :npages], np.int32
             )
 
-            def put(buf, rows):
-                rows = jnp.asarray(np.asarray(rows), buf.dtype)
-                for i in range(npages):
-                    n = min(ps, length - i * ps)
-                    buf = buf.at[:, int(pages[i]), :n].set(
-                        rows[:, i * ps : i * ps + n]
+            def put(pool, rows):
+                # Canonical rows [L, length, H, ·] to the pool's packed
+                # form, then whole pages (the tail page as far as filled)
+                # into each layer's buffer. Not a jitted step: a
+                # shipment lands once a request, not once a tick.
+                rows = jax.tree.map(np.asarray, pack_heads(rows))
+
+                def put1(buf, layer_rows):
+                    layer_rows = jnp.asarray(layer_rows, buf.dtype)
+                    for i in range(npages):
+                        n = min(ps, length - i * ps)
+                        buf = buf.at[int(pages[i]), :n].set(
+                            layer_rows[i * ps : i * ps + n]
+                        )
+                    return buf
+
+                return tuple(
+                    jax.tree.map(
+                        put1, layer, jax.tree.map(lambda r: r[i], rows)
                     )
-                return buf
+                    for i, layer in enumerate(pool)
+                )
 
         else:
 
-            def put(buf, rows):
-                return buf.at[:, slot, :length].set(
-                    jnp.asarray(np.asarray(rows), buf.dtype)
+            def put(cache_kv, rows):
+                return jax.tree.map(
+                    lambda buf, r: buf.at[:, slot, :length].set(
+                        jnp.asarray(np.asarray(r), buf.dtype)
+                    ),
+                    cache_kv, rows,
                 )
 
         self.cache = type(self.cache)(
-            k=jax.tree.map(put, self.cache.k, k_rows),
-            v=jax.tree.map(put, self.cache.v, v_rows),
+            k=put(self.cache.k, k_rows),
+            v=put(self.cache.v, v_rows),
             lengths=self.cache.lengths.at[slot].set(int(length)),
         )
         self.last_token = self.last_token.at[slot].set(int(first_token))

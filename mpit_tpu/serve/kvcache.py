@@ -29,20 +29,28 @@ with ``slots × max_len`` whether or not the tokens exist: a slot holding
 30 cached tokens pays for 1024, slot count is the hard concurrency
 ceiling, and two requests sharing a system prompt store identical K/V
 twice. :class:`PagedKVCache` breaks the buffers into a fixed pool of
-``page_size``-token pages (``[layers, num_pages, page_size, heads,
-head_dim]``) indirected by a per-slot int32 block table: HBM scales with
-tokens actually held, and a page mapped into two block tables IS prefix
-sharing. The device side stays dumb — pages are just rows, the pool
-never moves — while :class:`PageAllocator` (pure host) owns the free
-list, per-page refcounts, the rolling-hash prefix index and the
-copy-on-write bookkeeping. Validity still comes from ``lengths`` + the
-attention mask, never from buffer contents, so freed pages are recycled
-without zeroing.
+``page_size``-token pages indirected by a per-slot int32 block table:
+HBM scales with tokens actually held, and a page mapped into two block
+tables IS prefix sharing. The pool is held as the decode kernel reads
+it and as a step can update it in place: ONE BUFFER PER LAYER (``k`` and
+``v`` are tuples of ``num_layers`` arrays), each ``[num_pages,
+page_size, heads*head_dim]`` — rows packed head-major to full 128-lane
+tiles, so a cached token costs its logical bytes on the device and the
+kernel DMAs tiles straight out of the buffer. The jitted paged steps
+DONATE the cache: each layer's buffer has one writer (the scatter of
+the new rows) and one reader (that layer's kernel call) per step, and
+comes back as the same memory. The device side stays dumb — pages are
+just rows, the pool never moves — while :class:`PageAllocator` (pure
+host) owns the free list, per-page refcounts, the rolling-hash prefix
+index and the copy-on-write bookkeeping. Validity still comes from
+``lengths`` + the attention mask, never from buffer contents, so freed
+pages are recycled without zeroing.
 
 QUANTIZED pools (ISSUE 15). ``quantized=True`` on the alloc/specs
 builders puts a :class:`~mpit_tpu.ops.kv_quant.QuantizedKV` (int8
 payload + per-(row, head) f32 scale blocks, equal rank) in every K/V
-seat: the page's scale block ``[page_size, H]`` lives in the same
+seat (of every layer, in a pool): the page's scale block
+``[page_size, H]`` lives in the same
 pytree as its int8 rows, so the allocator, COW remaps, prefix sharing
 and preemption carry scales with the pages WITHOUT learning about them
 — a block-table indirection or page copy applies to both leaves. Bytes
@@ -100,15 +108,16 @@ __all__ = [
 ]
 
 
-def _alloc_kv(shape, dtype, quantized, kw):
+def _alloc_kv(shape, dtype, quantized, kw, scale_width=1):
     """One K (or V) buffer: a zeroed dense array, or the quantized pair
-    (int8 payload + keepdims f32 scale — zero scales dequantize the
-    zeroed payload to exact zeros, matching the dense init)."""
+    (int8 payload + f32 scale, keepdims unless a pool's packed rows give
+    it ``scale_width`` heads — zero scales dequantize the zeroed payload
+    to exact zeros, matching the dense init)."""
     if not quantized:
         return jnp.zeros(shape, dtype, **kw)
     return QuantizedKV(
         q=jnp.zeros(shape, jnp.int8, **kw),
-        scale=jnp.zeros(shape[:-1] + (1,), jnp.float32, **kw),
+        scale=jnp.zeros(shape[:-1] + (scale_width,), jnp.float32, **kw),
     )
 
 
@@ -194,9 +203,15 @@ def cache_specs(axis: str = "model", *, quantized: bool = False) -> KVCache:
 class PagedKVCache:
     """Paged decode state: one shared page pool + per-slot fill counts.
 
-    ``k``/``v``: [num_layers, num_pages, page_size, heads, head_dim];
-    ``lengths``: [slots] int32. The per-slot page→position mapping (the
-    block table) is NOT device state — it lives host-side on the
+    ``k``/``v``: tuples of ``num_layers`` per-layer buffers, each
+    ``[num_pages, page_size, heads*head_dim]`` (a
+    :class:`~mpit_tpu.ops.kv_quant.QuantizedKV` layer holds int8 rows of
+    that shape plus a ``[num_pages, page_size, heads]`` f32 scale
+    plane); ``lengths``: [slots] int32. Page ``p`` is row-block ``p`` of
+    every layer's buffer. A jitted step that takes the cache donates
+    it, so a ``PagedKVCache`` handed to a step is spent: use the one the
+    step returns. The per-slot page→position mapping (the block table)
+    is NOT device state — it lives host-side on the
     :class:`PageAllocator` and rides into each jitted step as a tiny
     [slots, pages_per_slot] int32 argument, so COW remaps and admissions
     never touch the pool.
@@ -214,12 +229,16 @@ class PagedKVCache:
         return cls(*children)
 
     @property
+    def num_layers(self) -> int:
+        return len(self.k)
+
+    @property
     def num_pages(self) -> int:
-        return self.k.shape[1]
+        return self.k[0].shape[0]
 
     @property
     def page_size(self) -> int:
-        return self.k.shape[2]
+        return self.k[0].shape[1]
 
     @property
     def slots(self) -> int:
@@ -236,34 +255,43 @@ def alloc_paged_cache(
     sharding=None,
     quantized: bool = False,
 ) -> PagedKVCache:
-    """Allocate the zeroed page pool. HBM cost is ``num_pages ×
+    """Allocate the zeroed page pool: per layer one K and one V buffer
+    ``[num_pages, page_size, heads*head_dim]``. HBM cost is ``num_pages ×
     page_size`` cache rows — chosen by budget, independent of ``slots``
-    (the batch width) and of any per-slot ``max_len``. ``quantized``
-    (ISSUE 15): int8 pages + per-(row, head) scale blocks — a page
-    costs ``page_size × kv_wire_bytes_per_row(H, Dh, "int8")`` bytes,
-    so the same budget holds ~2× the pages of a bf16 pool."""
-    shape = (cfg.num_layers, num_pages, page_size, cfg.num_heads,
-             cfg.head_dim)
+    (the batch width) and of any per-slot ``max_len`` — and the packed
+    rows are whole 128-lane tiles at GPT-2's widths, so the device holds
+    the bytes ``.nbytes`` counts and no more. ``quantized`` (ISSUE 15):
+    int8 pages + per-(row, head) scale planes ``[num_pages, page_size,
+    heads]`` — a page costs ``page_size × kv_wire_bytes_per_row(H, Dh,
+    "int8")`` bytes, so the same budget holds ~2× the pages of a bf16
+    pool."""
     dt = dtype or cfg.dtype
     kw = {"device": sharding} if sharding is not None else {}
+    shape = (num_pages, page_size, cfg.num_heads * cfg.head_dim)
+    layers = lambda: tuple(
+        _alloc_kv(shape, dt, quantized, kw, scale_width=cfg.num_heads)
+        for _ in range(cfg.num_layers)
+    )
     return PagedKVCache(
-        k=_alloc_kv(shape, dt, quantized, kw),
-        v=_alloc_kv(shape, dt, quantized, kw),
-        lengths=jnp.zeros((slots,), jnp.int32),
+        k=layers(), v=layers(), lengths=jnp.zeros((slots,), jnp.int32)
     )
 
 
 def paged_cache_specs(
-    axis: str = "model", *, quantized: bool = False
+    axis: str = "model", *, num_layers: int, quantized: bool = False
 ) -> PagedKVCache:
-    """TP PartitionSpecs for the pool: heads (axis 3 of [L, P, ps, H,
-    Dh]) shard exactly as the dense cache's; pages are replicated-id
-    shared state, lengths replicated. Quantized pools shard the scale
-    blocks on the same head axis."""
-    kv = P(None, None, None, axis, None)
+    """TP PartitionSpecs for the pool: each layer's buffer shards its
+    packed last axis (``[P, ps, H*Dh]`` is head-major, so a rank's
+    ``H/P`` heads are one contiguous ``H/P * Dh`` slice of it, exactly
+    the heads the dense cache's head axis gives that rank); pages are
+    replicated-id shared state, lengths replicated. Quantized pools
+    shard the scale plane ``[P, ps, H]`` on the same axis."""
+    kv = P(None, None, axis)
     if quantized:
         kv = QuantizedKV(q=kv, scale=kv)
-    return PagedKVCache(k=kv, v=kv, lengths=P())
+    return PagedKVCache(
+        k=(kv,) * num_layers, v=(kv,) * num_layers, lengths=P()
+    )
 
 
 def pages_needed(prompt_len: int, max_new_tokens: int, page_size: int) -> int:

@@ -121,7 +121,15 @@ def test_flash_decode_dense(v5e, kv_dtype, t):
 @pytest.mark.parametrize("t", [1, 64, 5])
 @pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
 def test_flash_decode_paged(v5e, kv_dtype, t):
-    pool = _kv((B * S // PAGE, PAGE, H, D), kv_dtype)
+    # One layer's pool as stored: rows packed [pages, page, H*D], an
+    # int8 pool's scale plane [pages, page, H].
+    pages = B * S // PAGE
+    pool = _sds((pages, PAGE, H * D), jnp.bfloat16)
+    if kv_dtype == "int8":
+        pool = QuantizedKV(
+            q=_sds((pages, PAGE, H * D), jnp.int8),
+            scale=_sds((pages, PAGE, H), jnp.float32),
+        )
     _compile_on_chip(
         v5e,
         lambda q, k, v, n, bt: flash_paged_decode_attention(
@@ -129,6 +137,24 @@ def test_flash_decode_paged(v5e, kv_dtype, t):
         ),
         _sds((B, t, H, D), jnp.bfloat16), pool, pool,
         _sds((B,), jnp.int32), _sds((B, S // PAGE), jnp.int32),
+    )
+
+
+# The pool's writer: a prefill chunk's rows over the slot batch, and a
+# page's worth; bf16 rows and an int8 pool's payload.
+@pytest.mark.parametrize("t", [64, PAGE])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.int8])
+def test_paged_write_pages(v5e, dtype, t):
+    from mpit_tpu.ops.decode_attention import paged_write_pages
+
+    _compile_on_chip(
+        v5e,
+        lambda pool, new, n, bt, ok: paged_write_pages(
+            pool, new, n, bt, ok, interpret=False
+        ),
+        _sds((B * S // PAGE, PAGE, H * D), dtype), _sds((B, t, H * D), dtype),
+        _sds((B,), jnp.int32), _sds((B, S // PAGE), jnp.int32),
+        _sds((B, t), jnp.bool_),
     )
 
 
@@ -173,3 +199,209 @@ def test_grad_sync_default_bucket_over_four_chips(v5e, mode, monkeypatch):
     )
     text = f.lower(x).compile().as_text()
     assert text.count("tpu_custom_call") == 4  # 2 buckets x (RS + AG)
+
+
+# ---------------------------------------------------------------------------
+# A paged serving step is O(rows): it writes a tick's rows into the page
+# pool and reads the tiles the kernel visits, and no operation in it
+# grows with the pool (no copy of a layer's buffer, no stacking of the
+# layers, no relayout before the kernel).
+# ---------------------------------------------------------------------------
+
+# GPT-2 large's widths: 20 heads of 64; 16-position pages, chunks of 64.
+LARGE = dict(num_heads=20, d_model=1280, num_layers=3, max_seq_len=1024)
+SLOTS, CHUNK = 4, 64
+POOL_PAGES = 1024  # a buffer (42 MB in bf16) far above a step's activations
+
+
+def _entry_parameters(text):
+    """{parameter number: "dtype[dims]"} of a compiled module's ENTRY."""
+    import re
+
+    entry = text[text.index("\nENTRY "):]
+    entry = entry[: entry.index("\n}")]
+    return {
+        int(n): shape
+        for shape, n in re.findall(
+            r"= (\w+\[[\d,]*\])\S* parameter\((\d+)\)", entry
+        )
+    }
+
+
+def _aliased_parameters(text):
+    import re
+
+    header = text[: text.index("\n")]
+    aliases = header[header.index("input_output_alias={"):]
+    aliases = aliases[: aliases.index("entry_computation_layout")]
+    return {int(n) for n in re.findall(r"\((\d+), \{\}", aliases)}
+
+
+@pytest.fixture(scope="module")
+def large_engines(v5e):
+    """A paged engine a KV dtype at GPT-2 large's widths, its attention
+    steered to the compiled kernel as on the chip (the platform here is
+    the CPU), with what it takes to lower a step for the described chip."""
+    from mpit_tpu.models import GPT2, GPT2Config
+    from mpit_tpu.ops import decode_attention
+    from mpit_tpu.serve import Engine
+
+    cfg = GPT2Config(vocab_size=2048, dtype=jnp.bfloat16, **LARGE)
+    params = jax.tree.map(
+        lambda a: a.astype(jnp.bfloat16),
+        jax.jit(GPT2(cfg).init)(
+            jax.random.key(0), jnp.zeros((1, 8), jnp.int32)
+        )["params"],
+    )
+    one = SingleDeviceSharding(v5e.devices[0])
+    on_chip = lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one), tree
+    )
+    built = {}
+
+    def build(kv_dtype):
+        if kv_dtype not in built:
+            eng = Engine(
+                cfg, params, slots=SLOTS, max_len=1024,
+                kv_pages=POOL_PAGES, kv_page_size=PAGE, prefill_chunk=CHUNK,
+                kv_dtype=kv_dtype,
+            )
+            built[kv_dtype] = eng
+        return built[kv_dtype]
+
+    was = decode_attention._use_kernel
+    decode_attention._use_kernel = lambda interpret: True
+    yield build, on_chip
+    decode_attention._use_kernel = was
+
+
+def _paged_step(eng, step):
+    s = eng.slots
+    i32, f32 = jnp.zeros((s,), jnp.int32), jnp.zeros((s,), jnp.float32)
+    msk = jnp.zeros((s,), bool)
+    bt = jnp.zeros((s, eng.pages_per_slot), jnp.int32)
+    key = jax.random.key(0)
+    if step == "decode":
+        return eng._decode_paged_jit, (
+            eng.params, eng.cache, eng.last_token, msk, bt, key, f32, i32)
+    toks = jnp.zeros((s, eng.prefill_chunk), jnp.int32)
+    return eng._prefill_paged_jit, (
+        eng.params, eng.cache, eng.last_token, toks, i32, i32, i32, msk, bt,
+        key, f32, i32)
+
+
+@pytest.mark.parametrize("step", ["decode", "prefill"])
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+def test_paged_step_is_o_rows(large_engines, kv_dtype, step):
+    build, on_chip = large_engines
+    eng = build(kv_dtype)
+    jit, args = _paged_step(eng, step)
+    compiled = jit.lower(*on_chip(args)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text  # (c) the kernel, not the lax fallback
+    # (a) Every buffer of the pool is updated in place: each K and V
+    # buffer of each layer (an int8 layer's scale plane too) is a
+    # parameter that the executable aliases to an output.
+    pool = jax.tree.leaves((eng.cache.k, eng.cache.v))
+    want = {}
+    for leaf in pool:
+        shape = f"{leaf.dtype.name}[{','.join(map(str, leaf.shape))}]"
+        shape = shape.replace("bfloat16", "bf16").replace("float32", "f32")
+        shape = shape.replace("int8", "s8")
+        want[shape] = want.get(shape, 0) + 1
+    params, aliased = _entry_parameters(text), _aliased_parameters(text)
+    got = {}
+    for n in aliased:
+        got[params[n]] = got.get(params[n], 0) + 1
+    assert {s: got.get(s, 0) for s in want} == want
+    # (b) What the step needs beside its arguments is less than ONE
+    # buffer of the pool: no copy of a layer's K or V, no stack of the
+    # layers and no relayout of a buffer for the kernel can hide in it.
+    mem = compiled.memory_analysis()
+    limit = min(leaf.nbytes for leaf in pool if leaf.dtype != jnp.float32)
+    if kv_dtype == "int8":
+        # Known and allowed (ROADMAP A3): the kernel's DMA wants whole
+        # 128-lane tiles, so each call pads a layer's K and V scale
+        # planes [pages, page, 20] to 128 lanes beside the pool.
+        limit += 2 * eng.num_pages * PAGE * 128 * 4
+    assert mem.temp_size_in_bytes < limit, (mem.temp_size_in_bytes, limit)
+    assert mem.alias_size_in_bytes >= sum(leaf.nbytes for leaf in pool)
+
+
+@pytest.mark.parametrize("t", [1, CHUNK], ids=["decode", "prefill"])
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+def test_tp_paged_forward_compiles(v5e, kv_dtype, t, monkeypatch):
+    """The tensor-parallel paged forward, two ways over GPT-2 large's
+    heads, as the engine composes it: each rank holds its 640 lanes of
+    every pool buffer, and both kernels (the page writer for a chunk's
+    rows, the paged decode attention) are traced on that slice under
+    ``shard_map``. No engine can be built on a described mesh (it places
+    arrays), so the forward is lowered from shapes alone."""
+    import functools
+
+    from mpit_tpu.models import GPT2, GPT2Config
+    from mpit_tpu.ops import decode_attention
+    from mpit_tpu.parallel.megatron import repack_qkv
+    from mpit_tpu.serve.engine import _tp_paged_forward, _tp_param_specs
+    from mpit_tpu.serve.kvcache import alloc_paged_cache, paged_cache_specs
+
+    monkeypatch.setattr(decode_attention, "_use_kernel", lambda _: True)
+    cfg = GPT2Config(vocab_size=2048, dtype=jnp.bfloat16, **LARGE)
+    params = jax.eval_shape(
+        lambda: {
+            k: repack_qkv(v, 2) if k.startswith("block_") else v
+            for k, v in GPT2(cfg).init(
+                jax.random.key(0), jnp.zeros((1, 8), jnp.int32)
+            )["params"].items()
+        }
+    )
+    quantized = kv_dtype == "int8"
+    cache = jax.eval_shape(
+        lambda: alloc_paged_cache(
+            cfg, SLOTS, POOL_PAGES, PAGE, dtype=jnp.bfloat16,
+            quantized=quantized,
+        )
+    )
+    world = topology_world({"data": 2, "model": 2}, "v5e:2x2")
+    rep = P()
+    specs = (
+        _tp_param_specs(cfg, params, "model"), rep,
+        paged_cache_specs(
+            "model", num_layers=cfg.num_layers, quantized=quantized
+        ),
+        rep, rep,
+    )
+    fwd = world.shard_map(
+        functools.partial(
+            _tp_paged_forward, cfg=cfg, axis="model",
+            attn_fn=functools.partial(
+                flash_paged_decode_attention, interpret=None
+            ),
+        ),
+        in_specs=specs, out_specs=(rep, specs[2]),
+    )
+    args = abstractify(
+        (params, _sds((SLOTS, t), jnp.int32), cache,
+         _sds((SLOTS, 1024 // PAGE), jnp.int32), _sds((SLOTS, t), bool)),
+        world.mesh, specs,
+    )
+    compiled = jax.jit(fwd, donate_argnums=(2,)).lower(*args).compile()
+    text = compiled.as_text()
+    # Attention a layer; a chunk's rows also take the writer, K and V.
+    assert text.count("tpu_custom_call") == cfg.num_layers * (
+        1 if t == 1 else 3
+    )
+    one = min(
+        leaf.size * leaf.dtype.itemsize // 2
+        for leaf in jax.tree.leaves((cache.k, cache.v))
+        if leaf.dtype != jnp.float32
+    )  # a rank's half of one buffer
+    if quantized:
+        one += 2 * POOL_PAGES * PAGE * 128 * 4  # as in the test above
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < one
+    # Donated and written in place on every rank: its half of the pool.
+    assert mem.alias_size_in_bytes >= sum(
+        leaf.size * leaf.dtype.itemsize // 2
+        for leaf in jax.tree.leaves((cache.k, cache.v))
+    )

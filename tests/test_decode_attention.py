@@ -23,6 +23,7 @@ shapes in the slow-marked ``TestDecodeKernelCompiles``.
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -44,6 +45,7 @@ from mpit_tpu.ops.decode_attention import (
     flash_decode_attention,
     flash_paged_decode_attention,
     num_kv_blocks,
+    paged_write_pages,
     pick_block_k,
     reference_decode_attention,
     reference_paged_decode_attention,
@@ -151,8 +153,9 @@ def _paged_setup(B=3, T=1, H=2, D=16, n_pages=12, ps=8, pages_per_slot=4,
     between slots) — the mapping indirection is the thing under test."""
     ks = jax.random.split(jax.random.key(seed), 3)
     q = jax.random.normal(ks[0], (B, T, H, D), dtype)
-    kp = jax.random.normal(ks[1], (n_pages, ps, H, D), dtype)
-    vp = jax.random.normal(ks[2], (n_pages, ps, H, D), dtype)
+    # One layer's pool in the stored form: rows packed head-major.
+    kp = jax.random.normal(ks[1], (n_pages, ps, H * D), dtype)
+    vp = jax.random.normal(ks[2], (n_pages, ps, H * D), dtype)
     rng = np.random.RandomState(seed)
     bt = rng.randint(0, n_pages, size=(B, pages_per_slot)).astype(np.int32)
     bt[2] = bt[0]  # slot 2 maps slot 0's pages (prefix sharing shape)
@@ -170,14 +173,16 @@ class TestPagedFlashDecode:
         B, T, H, D, ps = 2, 3, 2, 4, 4
         bt = jnp.asarray([[3, 1, 6, 0], [2, 5, 7, 4]], jnp.int32)
         dense = jnp.zeros((B, 16, H, D))
-        pool = jnp.zeros((8, ps, H, D))
+        pool = jnp.zeros((8, ps, H * D))
         new = jnp.asarray(rng.randn(B, T, H, D), jnp.float32)
         lens = jnp.asarray([2, 13], jnp.int32)
         d2 = cache_update(dense, new, lens)
         p2 = paged_cache_update(
-            pool, new, lens, bt, valid=jnp.ones((B, T), bool)
+            pool, new.reshape(B, T, H * D), lens, bt,
+            valid=jnp.ones((B, T), bool),
         )
-        assert jnp.all(paged_gather(p2, bt) == d2)
+        assert p2.shape == pool.shape  # written as stored: no reshape
+        assert jnp.all(paged_gather(p2, bt, H) == d2)
 
     def test_masked_rows_are_dropped_not_written(self):
         """A write-masked row must not land ANYWHERE in the pool — the
@@ -185,8 +190,8 @@ class TestPagedFlashDecode:
         can never touch a page another slot owns."""
         B, T, H, D, ps = 2, 4, 2, 4, 4
         bt = jnp.asarray([[0, 1], [2, 3]], jnp.int32)
-        pool = jnp.full((4, ps, H, D), 7.0)
-        new = jnp.ones((B, T, H, D))
+        pool = jnp.full((4, ps, H * D), 7.0)
+        new = jnp.ones((B, T, H * D))
         valid = jnp.asarray([[True, True, False, False],
                              [False, False, False, False]])
         out = paged_cache_update(
@@ -199,12 +204,80 @@ class TestPagedFlashDecode:
     def test_positions_past_virtual_capacity_dropped(self):
         """lengths + T past pages_per_slot×ps must drop, not wrap into
         the slot's last page."""
-        pool = jnp.zeros((4, 4, 1, 2))
+        pool = jnp.zeros((4, 4, 2))
         bt = jnp.asarray([[0, 1]], jnp.int32)  # capacity 8
         out = paged_cache_update(
-            pool, jnp.ones((1, 2, 1, 2)), jnp.asarray([7], jnp.int32), bt
+            pool, jnp.ones((1, 2, 2)), jnp.asarray([7], jnp.int32), bt
         )
         assert float(out.sum()) == 2.0  # position 7 landed, 8 dropped
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, jnp.int8])
+    @pytest.mark.parametrize("t", [1, 5, 8, 20])
+    def test_page_write_kernel_is_the_scatter(self, t, dtype):
+        """The pool's writer a page at a time (the path a prefill chunk
+        takes on the chip) lands exactly what the row scatter lands:
+        ragged starts, a masked row here and there, starts so late that
+        rows run past the slot's table (dropped), whole and part pages."""
+        P_, ps, w, B = 24, 8, 128, 3
+        rng = np.random.RandomState(t)
+        bt = jnp.asarray(rng.permutation(P_)[: B * 6].reshape(B, 6), jnp.int32)
+        pool = jnp.asarray(rng.randint(-5, 5, size=(P_, ps, w)), dtype)
+        new = jnp.asarray(rng.randint(-100, 100, size=(B, t, w)), dtype)
+        ragged = rng.randint(0, 6 * ps - t + 3, size=B)
+        some = jnp.asarray(rng.rand(B, t) < 0.8)
+        # Starts anywhere, and every start on a page (a chunked prefill's
+        # usual case); a mask, and none.
+        for lens in (ragged, ragged // ps * ps):
+            lens = jnp.asarray(lens, jnp.int32)
+            for valid in (some, None):
+                want = paged_cache_update(pool, new, lens, bt, valid=valid)
+                got = paged_write_pages(
+                    pool, new, lens, bt, valid, interpret=True
+                )
+                assert got.dtype == pool.dtype
+                np.testing.assert_array_equal(
+                    np.asarray(got, np.float32), np.asarray(want, np.float32)
+                )
+
+    def test_update_takes_the_page_writer_for_a_chunk_only(self, monkeypatch):
+        """``paged_cache_update`` chooses by what it can see: on a TPU
+        (steered here, the writer through the interpreter) a page's
+        worth of lane-aligned rows goes by pages (kernel), fewer rows or
+        a narrow scale plane by the scatter."""
+        from mpit_tpu.models import gpt2
+        from mpit_tpu.ops import decode_attention
+        from mpit_tpu.ops.kv_quant import QuantizedKV
+
+        ps, w, h = 8, 256, 2
+        bt = jnp.asarray([[0, 1], [2, 3]], jnp.int32)
+        lens = jnp.asarray([3, 0], jnp.int32)
+        pool = QuantizedKV(
+            q=jnp.zeros((4, ps, w), jnp.int8),
+            scale=jnp.zeros((4, ps, h), jnp.float32),
+        )
+
+        def kernels(t):
+            new = jnp.ones((2, t, w), jnp.float32)
+            jx = jax.make_jaxpr(
+                lambda p, n: paged_cache_update(p, n, lens, bt)
+            )(pool, new)
+            return str(jx).count("paged_kv_write")
+
+        new = jnp.asarray(
+            np.random.RandomState(0).randn(2, ps, w), jnp.float32
+        )
+        assert kernels(ps) == 0  # the platform here is the CPU
+        by_row = paged_cache_update(pool, new, lens, bt)
+        monkeypatch.setattr(decode_attention, "_use_kernel", lambda _: True)
+        monkeypatch.setattr(
+            gpt2, "paged_write_pages",
+            functools.partial(paged_write_pages, interpret=True),
+        )
+        assert kernels(ps) == 1  # the payload; the scale plane scatters
+        assert kernels(ps - 1) == 0
+        by_page = paged_cache_update(pool, new, lens, bt)
+        assert jnp.all(by_page.q == by_row.q)
+        assert jnp.all(by_page.scale == by_row.scale)
 
     @pytest.mark.parametrize("block_k", [4, 8, None])
     def test_kernel_matches_reference_ragged_lengths(self, block_k):
@@ -247,7 +320,7 @@ class TestPagedFlashDecode:
         q, kp, vp, bt = _paged_setup()
         lengths = jnp.asarray([3, 17, 30], jnp.int32)
         dense_out = flash_decode_attention(
-            q, paged_gather(kp, bt), paged_gather(vp, bt), lengths,
+            q, paged_gather(kp, bt, 2), paged_gather(vp, bt, 2), lengths,
             block_k=8, interpret=True,
         )
         paged_out = flash_paged_decode_attention(
@@ -532,8 +605,8 @@ class TestDecodeKernelCompiles:
         )
         step.lower(
             mk((8 * B, 1, h, d), jnp.bfloat16, P("data")),
-            mk((n_pages, ps, h, d), jnp.bfloat16, P()),
-            mk((n_pages, ps, h, d), jnp.bfloat16, P()),
+            mk((n_pages, ps, h * d), jnp.bfloat16, P()),
+            mk((n_pages, ps, h * d), jnp.bfloat16, P()),
             mk((8 * B,), jnp.int32, P("data")),
             mk((8 * B, per_slot), jnp.int32, P("data")),
         ).compile()

@@ -776,12 +776,16 @@ class TestPagedServing:
             world=world, tp_axis="model",
             kv_pages=24, kv_page_size=8, decode_attention="interpret",
         )
-        # [L, P, ps, H, Dh] with H split over the 2-way model axis.
+        # A buffer a layer, [P, ps, H*Dh], the packed rows split over
+        # the 2-way model axis: each rank's H/2 heads, contiguous.
+        assert len(engine.cache.k) == len(engine.cache.v) == CFG.num_layers
         shard_shapes = {
-            s.data.shape for s in engine.cache.k.addressable_shards
+            s.data.shape
+            for buf in engine.cache.k + engine.cache.v
+            for s in buf.addressable_shards
         }
         assert shard_shapes == {
-            (CFG.num_layers, 24, 8, CFG.num_heads // 2, CFG.head_dim)
+            (24, 8, CFG.num_heads // 2 * CFG.head_dim)
         }
         server = Server(engine)
         for i, (p, n) in enumerate(zip(PROMPTS[:4], MAX_NEW[:4])):

@@ -43,10 +43,10 @@ def params():
 
 def _tiered_engine(params, **kw):
     kw.setdefault("kv_host_pages", 8)
+    kw.setdefault("decode_attention", "reference")
     return Engine(
         CFG, params, slots=2, max_len=64, prefill_len=32, kv_pages=16,
-        kv_page_size=8, prefill_chunk=8, decode_attention="reference",
-        **kw,
+        kv_page_size=8, prefill_chunk=8, **kw,
     )
 
 
@@ -308,3 +308,115 @@ class TestPrefixHitRateUnderPressure:
         assert tiered["host_prefix_hits"] >= 6
         assert tiered["prefix_hit_rate"] > untiered["prefix_hit_rate"]
         assert tiered["prefix_hit_rate"] >= 0.5
+
+
+class TestDonatedPoolLifetimes:
+    """The paged steps donate the page pool (a buffer a layer, updated
+    in place). One server run through every step that takes the cache:
+    admit, chunked prefill, decode, a copy-on-write remap, a spill with
+    its drain and restore, a preempt and resume. What is pinned is the
+    buffers' lifetimes: the tokens are the parent commit's for the same
+    seed (the greedy request's are the dense reference engine's too),
+    and a cache that a step has taken is spent."""
+
+    # Recorded from the parent of the PR that brought donation in
+    # (afb8e94), this scenario, engine seed 5: "a" greedy, "b" sampled at
+    # temperature 0.8, top-k 20. bf16 and int8 pools read the same here.
+    PARENT_TOKENS = {
+        "a": [239, 239, 239, 458, 182, 182, 182, 458, 182, 182, 174, 179,
+              458, 458, 182, 174, 174, 179, 174, 174],
+        "b": [25, 182, 181, 0, 114, 15, 78, 365],
+    }
+
+    @staticmethod
+    def _run(engine, cfg=CFG):
+        """The scenario; returns the server, the finished tokens by rid,
+        the shared prefix and the cache the first steps took."""
+        rng = np.random.RandomState(13)
+        prefix = rng.randint(0, cfg.vocab_size, size=10).tolist()
+        server = Server(engine, policy=SchedulingPolicy())
+        server.submit(_req("a", prefix, new=20, priority=1))
+        server.run(max_ticks=5)  # two prefill chunks, then decode ticks
+        spent = engine.cache
+        server.submit(Request(
+            rid="b", prompt=prefix + [3, 4], max_new_tokens=8, priority=1,
+            temperature=0.8, top_k=20,
+        ))
+        server.run(max_ticks=7)  # b shares a's partial page: a COW remap
+        slot_b = next(s for s, l in server.live.items() if l.req.rid == "b")
+        server._preempt(slot_b)  # spills b's pages: gathers, not donated
+        assert engine._pending_spills or engine._host_store
+        done = {c.rid: c.tokens for c in server.run()}  # drain, restore
+        assert engine.allocator.cow_copies >= 2
+        assert server.stats()["host_restreamed_pages"] >= 1
+        assert server.resume_durations["restream"]
+        _assert_tier_conservation(server)
+        return done, prefix, spent
+
+    @pytest.mark.parametrize("kv_dtype", [None, "int8"])
+    def test_every_donating_step_in_one_run_matches_parent(
+        self, params, kv_dtype
+    ):
+        engine = _tiered_engine(
+            params, kv_host_pages=3, decode_attention="interpret", seed=5,
+            kv_dtype=kv_dtype,
+        )
+        done, prefix, spent = self._run(engine)
+        # The cache handed to the first steps is gone, every buffer of
+        # it, and reading it raises rather than returning old rows.
+        leaves = jax.tree.leaves((spent.k, spent.v))
+        assert len(leaves) == 2 * CFG.num_layers * (2 if kv_dtype else 1)
+        assert all(leaf.is_deleted() for leaf in leaves)
+        with pytest.raises(RuntimeError, match="deleted"):
+            np.asarray(leaves[0])
+        assert not any(
+            leaf.is_deleted() for leaf in jax.tree.leaves(engine.cache)
+        )
+        assert done == self.PARENT_TOKENS
+        ref = _reference_tokens(engine, [_req("a", prefix, new=20)])
+        assert done["a"] == ref["a"]
+
+    @pytest.mark.parametrize("kv_dtype", [None, "int8"])
+    def test_page_writer_inside_the_donating_steps(
+        self, kv_dtype, monkeypatch
+    ):
+        """The same run with a chunk's rows written as on the chip: the
+        ``paged_kv_write`` kernel (through the interpreter; the choice
+        steered here, never by a product option) aliasing a buffer that
+        the step has been given. Rows of 128 lanes, so that the writer
+        takes them; the tokens are those of the row scatter."""
+        import functools
+
+        from mpit_tpu.models import gpt2
+        from mpit_tpu.ops import decode_attention
+
+        cfg = GPT2Config.tiny(max_seq_len=128, num_layers=2, d_model=128)
+        wide = jax.jit(GPT2(cfg).init)(
+            jax.random.key(1), jnp.zeros((1, 8), jnp.int32)
+        )["params"]
+        build = lambda: Engine(
+            cfg, wide, slots=2, max_len=64, prefill_len=32, kv_pages=16,
+            kv_page_size=8, prefill_chunk=8, kv_host_pages=3, seed=5,
+            decode_attention="interpret", kv_dtype=kv_dtype,
+        )
+        by_rows, _, _ = self._run(build(), cfg)
+        monkeypatch.setattr(decode_attention, "_use_kernel", lambda _: True)
+        monkeypatch.setattr(
+            gpt2, "paged_write_pages",
+            functools.partial(decode_attention.paged_write_pages,
+                              interpret=True),
+        )
+        engine = build()
+        by_pages, _, spent = self._run(engine, cfg)
+        assert all(
+            leaf.is_deleted() for leaf in jax.tree.leaves((spent.k, spent.v))
+        )
+        args = (engine.params, engine.cache, engine.last_token,
+                jnp.zeros((2, 8), jnp.int32), *[jnp.zeros((2,), jnp.int32)] * 3,
+                jnp.zeros((2,), bool),
+                jnp.zeros((2, engine.pages_per_slot), jnp.int32),
+                jax.random.key(0), jnp.zeros((2,), jnp.float32),
+                jnp.zeros((2,), jnp.int32))
+        steps = str(jax.make_jaxpr(engine._prefill_paged_jit)(*args))
+        assert "paged_kv_write" in steps
+        assert by_pages == by_rows

@@ -157,13 +157,15 @@ def _lowered_train():
 STEPS = {
     "train": (_lowered_train, "jit_train_step",
               ("loss", "grad_sync", "opt_update", "zero1_gather", "attn",
-               "mlp", "lm_head", "embed"), ()),
+               "mlp", "lm_head", "embed"), (), ()),
     "decode_dense": (lambda p: _lowered_decode(p, "dense"), "jit_decode",
                      ("kv_write", "kv_gather", "attn", "mlp", "lm_head",
-                      "sample", "embed"), ("decode_attn",)),
+                      "sample", "embed"), ("decode_attn",), ()),
+    # The page pool is stored as the kernel reads it: nothing is left to
+    # lower under kv_gather (an int8 pool still pads its scale plane there).
     "decode_paged": (lambda p: _lowered_decode(p, "paged"), "jit_decode_paged",
-                     ("kv_write", "kv_gather", "attn", "mlp", "lm_head",
-                      "sample", "embed"), ("paged_decode_attn",)),
+                     ("kv_write", "attn", "mlp", "lm_head", "sample",
+                      "embed"), ("paged_decode_attn",), ("kv_gather",)),
 }
 
 
@@ -180,7 +182,7 @@ def _pallas_names(jaxpr) -> set:
 
 @pytest.mark.parametrize("step", sorted(STEPS))
 def test_a_step_lowers_with_its_scope_and_kernel_names(params, step):
-    build, module, scopes, kernels = STEPS[step]
+    build, module, scopes, kernels, absent = STEPS[step]
     jit, args = build() if step == "train" else build(params)
     text = jit.lower(*args).as_text(debug_info=True)
     assert f"module @{module} " in text
@@ -188,6 +190,8 @@ def test_a_step_lowers_with_its_scope_and_kernel_names(params, step):
         # A location is a name stack: "jit(decode)/GPT2/block_0/attn/..."
         # or, inside a nested jit, one that starts with the scope.
         assert re.search(rf'["/(]{scope}[/)]', text), scope
+    for scope in absent:
+        assert not re.search(rf'["/(]{scope}[/)]', text), scope
     if step == "train":
         assert "transpose(jvp(loss))" in text
     assert set(kernels) <= _pallas_names(jax.make_jaxpr(jit)(*args).jaxpr)
