@@ -24,6 +24,12 @@ raises — there is no handler that lets the run end 0):
                  full forward pass.
 - ``serve_int8`` the same requests on int8 KV + int8 weights; logits against
                  the f32-weight engine; which matmuls ran the Pallas kernel.
+- ``serve_xing4`` ``python -m mpit_tpu.serve --family xing4`` at the widths of
+                 ``benchmark/configs/xing4-29b-a4b-6of40.json`` (few slots):
+                 the latent decode kernel against its gather-dense
+                 composition, and a prompt served in chunks and then decoded
+                 through the latent page pool against the model's plain
+                 forward, logits both times; ``ok`` only if both hold.
 - ``dp4``        (``--chips 4`` only, and then the only phase) ZeRO-1 data
                  parallel over four chips vs the same global batch and seed
                  on one of them; then ``grad_sync=ring`` and ``ring_q8``.
@@ -54,6 +60,14 @@ TOL_INT8_VS_F32 = 0.25  # tests/test_weights_quant.py's weight-store bound
 TOL_DP4_VS_ONE_CHIP = 0.01
 TOL_RING_Q8_VS_PSUM = 0.05
 RING_TIMEOUT_S = 300.0  # the ring kernels' protocol has only run interpreted
+# xing4 (logits of a random-init model: standard deviation 1.2). The
+# kernel's weighted latents are of order 0.1-1 in bf16. The served logits
+# are judged at the median position: routing is discontinuous, and at a
+# position where a near tie between two experts falls the other way in the
+# two bf16 orders of operation the logits differ by whole units (3.3 at
+# the worst of 704 positions; my chip run, PR 26, call 7).
+TOL_X4_KERNEL_VS_GATHER = 0.03
+TOL_X4_PAGED_VS_PLAIN = 0.15
 
 FULL = dict(
     model=["--num-layers", "12", "--d-model", "768", "--num-heads", "12",
@@ -64,6 +78,12 @@ FULL = dict(
            "16", "--prefill-chunk", "64", "--requests", "6", "--prompt-len",
            "200", "--max-new-tokens", "16"],
     decode_attention="kernel", probe_len=96,
+    xing4=["--family", "xing4", "--model-config",
+           "benchmark/configs/xing4-29b-a4b-6of40.json", "--slots", "2",
+           "--max-len", "2048", "--prefill-len", "2048", "--kv-pages", "16",
+           "--kv-page-size", "256", "--prefill-chunk", "512", "--requests",
+           "3", "--prompt-len", "600", "--max-new-tokens", "8"],
+    xing4_probe=(700, 4),  # a prompt of two chunks, then decode ticks
 )
 TINY = dict(
     model=["--num-layers", "2", "--d-model", "64", "--num-heads", "4",
@@ -74,6 +94,11 @@ TINY = dict(
            "16", "--prefill-chunk", "16", "--requests", "4", "--prompt-len",
            "40", "--max-new-tokens", "4"],
     decode_attention="interpret", probe_len=24,
+    xing4=["--family", "xing4", "--model", "tiny", "--slots", "2",
+           "--max-len", "128", "--prefill-len", "128", "--kv-pages", "16",
+           "--kv-page-size", "16", "--prefill-chunk", "16", "--requests",
+           "3", "--prompt-len", "40", "--max-new-tokens", "4"],
+    xing4_probe=(27, 3),
 )
 
 
@@ -521,6 +546,121 @@ def phase_serve_int8(sz, seed: int, rehearse: bool, probe) -> None:
 # ---------------------------------------------------------------------------
 
 
+def _xing4_kernel_vs_gather(engine, seed: int) -> float:
+    """The latent decode kernel on a random pool at the engine's widths
+    against the gather-dense composition: max abs difference."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from mpit_tpu.ops import mla_attention as mla
+
+    cfg, b, pps = engine.cfg, engine.slots, engine.pages_per_slot
+    ks = jax.random.split(jax.random.key(seed), 4)
+    dt = engine.cache.k[0].dtype
+    ckv = jax.random.normal(ks[0], engine.cache.k[0].shape, dt)
+    kr = jax.random.normal(ks[1], engine.cache.v[0].shape, dt)
+    kr = kr.at[..., cfg.qk_rope_head_dim:].set(0)
+    h = cfg.num_attention_heads
+    qa = jax.random.normal(ks[2], (b, h, cfg.kv_lora_rank), dt)
+    qr = jax.random.normal(ks[3], (b, h, cfg.qk_rope_head_dim), dt)
+    table = jnp.asarray(np.random.RandomState(seed).permutation(
+        engine.num_pages)[: b * pps].reshape(b, pps), jnp.int32)
+    lengths = jnp.asarray(
+        [engine.max_len - 1] + [engine.page_size + 3] * (b - 1), jnp.int32)
+    args = (qa, qr, ckv, kr, lengths, table)
+    interp = True if engine.decode_attention == "interpret" else None
+    got = mla.mla_paged_decode_attention(
+        *args, scale=cfg.softmax_scale, interpret=interp)
+    want = mla.reference_mla_paged_decode_attention(
+        *args, scale=cfg.softmax_scale)
+    return _max_abs(got, want)
+
+
+def _xing4_paged_vs_plain(engine, seed: int, prompt: int, ticks: int) -> tuple:
+    """A prompt in chunks and ``ticks`` decode steps through the engine's
+    model and latent pool (the forward the jitted steps run), against the
+    model's plain forward of the whole sequence: ``(per-position max abs
+    logit difference at the median, the 90th percentile and the worst
+    position, the logits' standard deviation)``."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from mpit_tpu.models.xing4 import forward_plain
+    from mpit_tpu.serve.kvcache import PagedKVCache
+
+    cfg, chunk = engine.cfg, engine.prefill_chunk
+    seq = np.random.RandomState(seed).randint(
+        0, cfg.vocab_size, size=prompt + ticks)
+    engine.reset(seed)
+    engine.allocator.admit(0, seq[:prompt].tolist(), ticks + 1)
+    table = jnp.asarray(engine.allocator.block_tables, jnp.int32)
+    forward = jax.jit(lambda p, t, c, rows: engine.model.forward_paged(
+        p, t, c, table, rows, return_hidden=False, row_valid=rows)[:2])
+    cache, got = engine.cache, []
+    starts = list(range(0, prompt, chunk)) + list(range(prompt, len(seq)))
+    for base in starts:
+        width = chunk if base < prompt else 1
+        n = min(width, prompt - base) if base < prompt else 1
+        tokens = np.zeros((engine.slots, width), np.int32)
+        tokens[0, :n] = seq[base:base + n]
+        rows = np.zeros((engine.slots, width), bool)
+        rows[0, :n] = True
+        lengths = jnp.zeros((engine.slots,), jnp.int32).at[0].set(base)
+        logits, (k, v) = forward(
+            engine.params, jnp.asarray(tokens),
+            PagedKVCache(k=cache.k, v=cache.v, lengths=lengths),
+            jnp.asarray(rows))
+        cache = PagedKVCache(k=k, v=v, lengths=lengths)
+        got.append(logits[0, :n])
+    engine.cache = cache
+    plain = jax.jit(lambda p, t: forward_plain(p, t, cfg))(
+        engine.params, jnp.asarray(seq)[None])[0]
+    engine.reset(seed)
+    err = np.asarray(jnp.max(jnp.abs(
+        jnp.concatenate(got).astype(jnp.float32) - plain), axis=-1))
+    return ([float(np.percentile(err, q)) for q in (50, 90, 100)],
+            float(jnp.std(plain)))
+
+
+def phase_serve_xing4(sz, seed: int, rehearse: bool) -> None:
+    import gc
+
+    from mpit_tpu.asyncsgd.config import from_argv
+    from mpit_tpu.serve import __main__ as serve_cli
+    from mpit_tpu.serve import warm_engine
+
+    argv = [*sz["xing4"], "--decode-attention", sz["decode_attention"],
+            "--seed", str(seed)]
+    scfg = from_argv(serve_cli.ServeConfig, argv)
+    engine, mcfg = serve_cli._build_engine(scfg)
+    assert engine.model.family == "xing4"
+    assert engine.decode_attention_mode == "kernel"
+    t0 = time.perf_counter()
+    warm_engine(engine)
+    compile_s = time.perf_counter() - t0
+    kernels = _decode_step_kernels(engine)
+    assert rehearse or kernels, "no kernel in the compiled decode step"
+    ran = _run_requests(engine, scfg, mcfg.vocab_size)
+    kernel_err = _xing4_kernel_vs_gather(engine, seed)
+    logit_err, logit_std = _xing4_paged_vs_plain(
+        engine, seed, *sz["xing4_probe"])
+    emit(
+        "serve_xing4", **ran, compile_s=round(compile_s, 2),
+        custom_calls_in_decode_step=kernels, layers=mcfg.num_hidden_layers,
+        experts=mcfg.n_routed_experts, streams=mcfg.hc_mult,
+        page_bytes=engine.page_bytes, prefill_counts=engine._prefill_counts,
+        kernel_vs_gather_err=kernel_err, tolerance=TOL_X4_KERNEL_VS_GATHER,
+        paged_vs_plain_logit_err_p50_p90_max=logit_err, logit_std=logit_std,
+        logit_tolerance_at_the_median=TOL_X4_PAGED_VS_PLAIN,
+    )
+    assert kernel_err <= TOL_X4_KERNEL_VS_GATHER, kernel_err
+    assert logit_err[0] <= TOL_X4_PAGED_VS_PLAIN, logit_err
+    del engine
+    gc.collect()
+
+
 def _holds_a_shard_each(state, devices) -> dict:
     """ZeRO-1 optimizer state must span every device, a shard on each."""
     import jax
@@ -626,6 +766,8 @@ def main(argv=None) -> int:
         phase_train(sz, args.seed, args.rehearse)
         probe = phase_serve(sz, args.seed, args.rehearse)
         phase_serve_int8(sz, args.seed, args.rehearse, probe)
+        del probe
+        phase_serve_xing4(sz, args.seed, args.rehearse)
         ok = True
     if args.rehearse:
         # A rehearsal is never a pass: it says what it ran on, and 3.

@@ -25,6 +25,7 @@ Built TPU-first and parallelism-aware:
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable
 
 import flax.linen as nn
@@ -33,6 +34,7 @@ import jax.numpy as jnp
 from flax.linen.dtypes import promote_dtype
 from jax import lax
 
+from mpit_tpu.models.serving import CacheLayout, ServeModel
 from mpit_tpu.ops.decode_attention import paged_write_pages, writes_by_pages
 from mpit_tpu.ops.kv_quant import (
     QuantizedKV,
@@ -293,6 +295,11 @@ class GPT2Config:
     @property
     def ff_dim(self) -> int:
         return self.d_ff or 4 * self.d_model
+
+    def serve_model(self) -> "GPT2ServeModel":
+        """This configuration as the serving engine takes it
+        (:mod:`mpit_tpu.models.serving`)."""
+        return GPT2ServeModel(self)
 
     @staticmethod
     def small(**kw) -> "GPT2Config":
@@ -627,3 +634,69 @@ class GPT2(nn.Module):
             {"params": params}, tokens[:, :-1], targets=tokens[:, 1:]
         )
         return jnp.mean(losses)
+
+
+class GPT2ServeModel(ServeModel):
+    """GPT-2 behind the serving engine's model interface: the flax
+    forward above, asked through :class:`~mpit_tpu.models.serving.ServeModel`.
+    Every engine mode is supported; the arithmetic is the module's own."""
+
+    family = "gpt2"
+
+    def __init__(self, cfg: GPT2Config):
+        self.cfg = cfg
+        self._module = GPT2(cfg)
+
+    def cache_layout(self) -> CacheLayout:
+        cfg = self.cfg
+        width = cfg.num_heads * cfg.head_dim
+        return CacheLayout(width, width, cfg.num_layers, cfg.dtype,
+                           scale_width=cfg.num_heads)
+
+    def kv_row_bytes(self, dtype) -> float:
+        from mpit_tpu.ops.kv_quant import kv_wire_bytes_per_row
+
+        return kv_wire_bytes_per_row(
+            self.cfg.num_heads, self.cfg.head_dim, dtype)
+
+    def with_decode_attention(self, *, paged, block_k, interpret,
+                              page_size=None):
+        del page_size  # the engine's tile is the kernel's
+        from mpit_tpu.ops.decode_attention import (
+            flash_decode_attention,
+            flash_paged_decode_attention,
+        )
+
+        attn_fn = functools.partial(
+            flash_paged_decode_attention if paged else flash_decode_attention,
+            block_k=block_k,
+            interpret=interpret,
+        )
+        field = "paged_attention_fn" if paged else "cache_attention_fn"
+        return GPT2ServeModel(dataclasses.replace(self.cfg, **{field: attn_fn}))
+
+    def with_quant_matmul(self, fn):
+        return GPT2ServeModel(dataclasses.replace(self.cfg, quant_matmul_fn=fn))
+
+    def forward_cached(self, params, tokens, cache, *, return_hidden):
+        return self._module.apply(
+            {"params": params},
+            tokens,
+            cache=(cache.k, cache.v, cache.lengths),
+            return_hidden=return_hidden,
+        )
+
+    def forward_paged(self, params, tokens, cache, block_tables, write_valid,
+                      *, return_hidden, row_valid=None):
+        del row_valid  # every row of the batch is computed
+        out, kv = self._module.apply(
+            {"params": params},
+            tokens,
+            paged_cache=(cache.k, cache.v, cache.lengths,
+                         block_tables, write_valid),
+            return_hidden=return_hidden,
+        )
+        return out, kv, None
+
+    def head_table(self, params):
+        return params["head"] if "head" in params else params["wte"]

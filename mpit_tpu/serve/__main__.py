@@ -72,6 +72,16 @@ policy block (preemptions, resumes, admission sheds, tier depths) plus
 the per-tenant roll-up and cause-split shed counts from
 ``Server.stats()``.
 
+``--family xing4`` (ISSUE 26) serves the second model family behind the
+engine's model interface (``models/serving.py``): latent attention over
+a latent page pool, sigmoid-routed experts with no dropped token,
+hyper-connected residual streams (``models/xing4.py``), on random
+weights from ``--seed``: ``--model tiny`` or ``published``, or
+``--model-config FILE`` with the keys of a published ``config.json``. It
+needs ``--kv-pages`` (the paged engine) and raises, by name, for what the
+family lacks: the dense cache, ``--mesh``, ``--kv-dtype int8``,
+``--weights-dtype int8``, ``--spec-k``, ``--kv-host-pages``, ``--ckpt``.
+
 Config follows the ``asyncsgd.config`` pattern: one dataclass, argparse
 generated from its fields.
 """
@@ -93,7 +103,11 @@ class ServeConfig:
     """Options for the serving CLI (the ``opt`` table analogue)."""
 
     ckpt: str = ""  # dense .npz from --save-dense ("" = random init)
-    model: str = "tiny"  # random-init size: tiny | small
+    family: str = "gpt2"  # the model family: gpt2 | xing4
+    model: str = "tiny"  # random-init size: tiny | small (xing4: published)
+    # xing4: a JSON file with the keys of the published config.json (as
+    # benchmark/configs/xing4-29b-a4b-6of40.json holds them); "" = --model.
+    model_config: str = ""
     num_heads: int = 0  # ckpt head-count override (0 = d_model//64)
     slots: int = 4  # concurrent KV-cache slots
     max_len: int = 96  # per-slot cache length (prompt + generation)
@@ -196,6 +210,32 @@ class ServeConfig:
         return parse_mesh(self.mesh)
 
 
+def _xing4_model(cfg: ServeConfig):
+    """Random weights of the ``xing4`` family (``models/xing4.py``): the
+    published sizes, those of ``--model-config``'s file, or the tiny
+    preset. The family serves through the paged engine on one chip in
+    bf16 or f32; the engine raises, by name, for what it lacks (the dense
+    cache, ``--mesh``, ``--kv-dtype int8``, ``--weights-dtype int8``,
+    ``--spec-k``, ``--kv-host-pages``)."""
+    import jax
+
+    from mpit_tpu.models.xing4 import Xing4Config, init_params
+
+    if cfg.ckpt:
+        raise SystemExit(
+            "--family xing4 has no checkpoint loader yet: it serves random "
+            "weights from --seed")
+    if cfg.model_config:
+        with open(cfg.model_config) as f:
+            mcfg = Xing4Config.from_dict(
+                json.load(f), max_seq_len=max(cfg.max_len, 128))
+    elif cfg.model == "tiny":
+        mcfg = Xing4Config.tiny(max_seq_len=max(cfg.max_len, 128))
+    else:
+        mcfg = Xing4Config(max_seq_len=max(cfg.max_len, 128))
+    return init_params(mcfg, jax.random.key(cfg.seed)), mcfg
+
+
 def _build_engine(cfg: ServeConfig):
     import jax
     import jax.numpy as jnp
@@ -245,7 +285,11 @@ def _build_engine(cfg: ServeConfig):
             "fused-dequant matmuls"
         )
 
-    if cfg.ckpt:
+    if cfg.family == "xing4":
+        params, mcfg = _xing4_model(cfg)
+    elif cfg.family != "gpt2":
+        raise SystemExit(f"--family {cfg.family!r}: expected gpt2 or xing4")
+    elif cfg.ckpt:
         params, mcfg = load_gpt2_params(cfg.ckpt, num_heads=cfg.num_heads)
     else:
         mcfg = (

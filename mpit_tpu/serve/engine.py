@@ -72,13 +72,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from mpit_tpu.models.gpt2 import (
-    GPT2,
-    GPT2Config,
     cache_update,
     cached_attention,
     paged_cache_update,
     paged_cached_attention,
 )
+from mpit_tpu.models.serving import as_serve_model
 from mpit_tpu.ops.kv_quant import kv_stack, pack_heads, unpack_heads
 from mpit_tpu.ops.quantized_matmul import (
     QuantizedTensor,
@@ -112,7 +111,6 @@ from mpit_tpu.serve.kvcache import (
     alloc_cache,
     alloc_paged_cache,
     cache_specs,
-    kv_wire_bytes_per_row,
     paged_cache_specs,
 )
 from mpit_tpu.serve.weights import (
@@ -162,6 +160,15 @@ def _jit_as(name: str, step, donate=()):
     return jax.jit(named, donate_argnums=tuple(donate))
 
 
+@jax.jit
+def _split_pair(key):
+    """``jax.random.split(key)`` as its two keys, in one dispatch: taking
+    the pair apart on the host is two more (0.80 ms against 0.42 on the
+    v5e's host, in every tick)."""
+    pair = jax.random.split(key)
+    return pair[0], pair[1]
+
+
 def _zeroed(cache):
     """Zeros in the place of ``cache``: its buffers are freed before the
     new ones are made, so that two caches never live at once."""
@@ -173,6 +180,10 @@ def _zeroed(cache):
         ),
         cache,
     )
+    # A step that still runs holds its buffers: deleted under it they
+    # would go only when it ends, after the zeros below were made (a
+    # second pool beside the first: 16.2 GB of 16.9 at 32 latent slots).
+    jax.block_until_ready(cache)
     for leaf in jax.tree.leaves(cache):
         leaf.delete()
     return jax.tree.map(
@@ -195,6 +206,15 @@ def _kv_where(mask, new, old):
 # cached_attention + materialized-logits sampling), kept as the parity
 # oracle and the perf comparison baseline.
 _DECODE_MODES = ("kernel", "interpret", "reference")
+
+# Rows of a prefill chunk tick (slots x prefill_chunk) up to which the one
+# full-batch step stays: GPT-2 large's 16 x 64 and every rehearsal size.
+# Past it the tick is compacted to the slots that take part, in steps of
+# at most _COMPACT_ROWS rows (the size of a step's activations: 2,048 rows
+# of the widest model served here take 0.5 GB of temporaries, and every
+# compiled count keeps its own beside the weights and the pool).
+_FULL_BATCH_ROWS = 1024
+_COMPACT_ROWS = 2048
 
 
 def sample_tokens(logits, key, temperature, top_k):
@@ -460,7 +480,12 @@ def _tp_param_specs(cfg, params, axis: str):
 
 
 class Engine:
-    """Slot-batched KV-cache inference over one GPT-2 param tree.
+    """Slot-batched KV-cache inference over one model's param tree.
+
+    ``model`` is a :class:`~mpit_tpu.models.serving.ServeModel`, or a
+    configuration that names one (``GPT2Config`` does): the engine asks
+    it for the cache row layout, the forward through the cache, the head
+    and what the family cannot do yet, and names no family itself.
 
     Device state lives on the engine (cache + per-slot last token);
     ``active``/sampling arrays are passed per call by the scheduler.
@@ -471,7 +496,7 @@ class Engine:
 
     def __init__(
         self,
-        cfg: GPT2Config,
+        model,
         params,
         *,
         slots: int = 8,
@@ -490,10 +515,18 @@ class Engine:
         prefill_chunk: int | None = None,
         spec_k: int = 0,
         draft_params=None,
-        draft_cfg: GPT2Config | None = None,
+        draft_cfg=None,
         kv_dtype: str | None = None,
         weights_dtype: str | None = None,
     ):
+        model = as_serve_model(model)
+        cfg = model.cfg
+        # What this family does not have yet fails here, by name.
+        model.check_supported(
+            paged=kv_pages is not None, tp=tp_axis is not None,
+            kv_dtype=kv_dtype, weights_dtype=weights_dtype,
+            spec_k=int(spec_k or 0), host_pages=int(kv_host_pages or 0),
+        )
         if decode_attention not in _DECODE_MODES:
             raise ValueError(
                 f"decode_attention must be one of {_DECODE_MODES}, got "
@@ -505,6 +538,8 @@ class Engine:
         self.prefill_len = min(prefill_len or self.max_len, self.max_len)
         self.tp_axis = tp_axis
         self._key = jax.random.key(seed)
+        self._sub = None  # the next subkey, where it was split ahead
+        self._staged = {}  # a decode tick's small inputs as last staged
 
         # -- KV cache wire dtype (ISSUE 15 tentpole) --------------------------
         # None = the historical default (cache in cfg.dtype) — the path
@@ -604,6 +639,9 @@ class Engine:
         self.prefill_chunk = min(
             prefill_chunk or self.prefill_len, self.prefill_len
         )
+        # Counts of participants a compacted chunk tick is compiled for
+        # (the paged engine sets them below; empty = the full-batch step).
+        self._prefill_counts: tuple = ()
 
         # -- speculative decoding (ISSUE 13 tentpole) ------------------------
         # spec_k > 0 swaps the decode tick for per-slot draft-then-
@@ -686,6 +724,8 @@ class Engine:
             self._blocked_head = False
         else:
             interp = True if decode_attention == "interpret" else None
+            # The TP forward below takes the kernel by itself; the
+            # one-chip forward takes it through the model.
             attn_fn = functools.partial(
                 flash_paged_decode_attention
                 if self.paged
@@ -712,14 +752,17 @@ class Engine:
         # attention=reference + sampler=dense is the true PR 4 path.
         self.decode_sampler = "blocked" if self._blocked_head else "dense"
         if attn_fn is not None:
-            cfg = dataclasses.replace(
-                cfg,
-                **{
-                    "paged_attention_fn"
-                    if self.paged
-                    else "cache_attention_fn": attn_fn
-                },
+            model = model.with_decode_attention(
+                paged=self.paged, block_k=self.decode_block_k,
+                interpret=interp,
+                page_size=self.page_size if self.paged else None,
             )
+            # A family's kernel may tile the cache its own way; the
+            # tile-count accounting follows what really runs.
+            self.decode_block_k = getattr(
+                model, "decode_block_k", self.decode_block_k
+            )
+            cfg = model.cfg
             self.cfg = cfg  # what the forward really runs, kernel included
         if self.weights_quantized:
             # The quantized matmul the model's dense layers run (the
@@ -742,7 +785,8 @@ class Engine:
                         True if decode_attention == "interpret" else None
                     ),
                 )
-            cfg = dataclasses.replace(cfg, quant_matmul_fn=qmm)
+            model = model.with_quant_matmul(qmm)
+            cfg = model.cfg
             self.cfg = cfg
             if tp_axis is None:
                 params = quantize_gpt2_params(params)
@@ -808,34 +852,28 @@ class Engine:
                     out_specs=(jax.sharding.PartitionSpec(), cs),
                 )
         elif self.paged:
-            model = GPT2(cfg)
 
             def fwd(prms, tokens, cache: PagedKVCache, block_tables,
-                    write_valid):
-                out, (k2, v2) = model.apply(
-                    {"params": prms},
-                    tokens,
-                    paged_cache=(cache.k, cache.v, cache.lengths,
-                                 block_tables, write_valid),
-                    return_hidden=self._blocked_head,
+                    write_valid, row_valid=None):
+                out, (k2, v2), aux = model.forward_paged(
+                    prms, tokens, cache, block_tables, write_valid,
+                    return_hidden=self._blocked_head, row_valid=row_valid,
                 )
-                return out, PagedKVCache(k=k2, v=v2, lengths=cache.lengths)
+                new = PagedKVCache(k=k2, v=v2, lengths=cache.lengths)
+                return (out, new) if aux is None else (out, new, aux)
 
         else:
-            model = GPT2(cfg)
 
             def fwd(prms, tokens, cache: KVCache):
                 # Blocked head: the forward ends at ln_f and the step
                 # samples from hiddens; dense: logits as in PR 4.
-                out, (k2, v2) = model.apply(
-                    {"params": prms},
-                    tokens,
-                    cache=(cache.k, cache.v, cache.lengths),
-                    return_hidden=self._blocked_head,
+                out, (k2, v2) = model.forward_cached(
+                    prms, tokens, cache, return_hidden=self._blocked_head,
                 )
                 return out, KVCache(k=k2, v=v2, lengths=cache.lengths)
 
-        self.params = params
+        self.model = model
+        self.params = model.place(params) if tp_axis is None else params
         # Draft model + its cache (ISSUE 13). The draft always runs the
         # reference attention and materializes its (tiny) logits — the
         # proposal distribution q is part of the acceptance contract.
@@ -863,7 +901,7 @@ class Engine:
         self.draft_cfg = draft_cfg
         self._spec_state = None  # device-side (drafted, q_x, q_probs)
         if self.spec_k:
-            self._draft_model = GPT2(draft_cfg)
+            self._draft_model = as_serve_model(draft_cfg)
             drep = None
             if tp_axis is not None:
                 # Pin the draft replicated across the mesh AT
@@ -910,7 +948,7 @@ class Engine:
                 host_pages=self.host_pages,
             )
             self.cache = alloc_paged_cache(
-                cfg, slots, self.num_pages, self.page_size,
+                model, slots, self.num_pages, self.page_size,
                 sharding=sharding, dtype=self._cache_dtype,
                 quantized=self.kv_quantized,
             )
@@ -923,6 +961,27 @@ class Engine:
                 "prefill_paged", self._paged_prefill_step,
                 donate=(1, 13) if draft else (1,),
             )
+            # A chunk tick computes [slots, prefill_chunk] rows whoever
+            # takes part. Up to _FULL_BATCH_ROWS that is cheap and the one
+            # step stays; past it the step runs over the participants
+            # only, compiled once for each power-of-two count of them
+            # whose rows fit _COMPACT_ROWS (more participants than the
+            # largest count go in several calls of a tick).
+            if (
+                slots * self.prefill_chunk > _FULL_BATCH_ROWS
+                and not self.spec_k and tp_axis is None
+            ):
+                n, counts = 1, []
+                while n <= slots and (
+                    not counts or n * self.prefill_chunk <= _COMPACT_ROWS
+                ):
+                    counts.append(n)
+                    n *= 2
+                self._prefill_counts = tuple(counts)
+                self._prefill_compact_jit = _jit_as(
+                    "prefill_paged", self._paged_prefill_compact_step,
+                    donate=(1,),
+                )
             if self.spec_k:
                 self._spec_draft_jit = _jit_as(
                     "spec_draft", self._spec_draft_step, donate=(1,)
@@ -996,7 +1055,9 @@ class Engine:
         self.compile_watch = _roofline.CompileWatch(
             expected=(3 if self.paged else 2)
             + (1 if self.spec_k else 0)
-            + (2 if self.host_pages else 0),
+            + (2 if self.host_pages else 0)
+            # one prefill step a count of participants (compacted ticks)
+            + max(0, len(self._prefill_counts) - 1),
             scope="engine",
         )
         # Per-execution modeled costs (set by register_roofline).
@@ -1011,9 +1072,8 @@ class Engine:
         # int8 rows carry their scale blocks (ISSUE 15 roofline
         # honesty: the visited-tile sweep DMAs int8 tiles + scales, so
         # that is what decode_hbm_util_pct / GB-s figures must count).
-        self._kv_row_bytes = kv_wire_bytes_per_row(
-            self.cfg.num_heads, self.cfg.head_dim,
-            jax.tree.leaves(self.cache.k)[0].dtype,
+        self._kv_row_bytes = model.kv_row_bytes(
+            jax.tree.leaves(self.cache.k)[0].dtype
         )
         # ISSUE 18: the byte-exact HBM ledger. Every buffer this
         # constructor pinned to the device registers ONCE — the weight
@@ -1122,7 +1182,7 @@ class Engine:
                 return sample_tokens(
                     row.astype(jnp.float32), key, temp, topk
                 )
-            head = params["head"] if "head" in params else params["wte"]
+            head = self.model.head_table(params)
             return lm_head_sample(
                 row, head, key, temp, topk,
                 block_size=self._sample_block,
@@ -1137,11 +1197,8 @@ class Engine:
         construction; its whole cost is the speculation overhead the
         acceptance rate must beat). ``with_head=False`` (prefill) stops
         at ln_f: the draft never samples at prefill."""
-        out, (k2, v2) = self._draft_model.apply(
-            {"params": dparams},
-            tokens,
-            cache=(dcache.k, dcache.v, dcache.lengths),
-            return_hidden=not with_head,
+        out, (k2, v2) = self._draft_model.forward_cached(
+            dparams, tokens, dcache, return_hidden=not with_head,
         )
         return out, KVCache(k=k2, v=v2, lengths=dcache.lengths)
 
@@ -1153,11 +1210,8 @@ class Engine:
         page geometry and indirects through the SAME block tables, so
         prefix sharing, COW remaps and preemption free/remap draft K/V
         together with the target's."""
-        out, (k2, v2) = self._draft_model.apply(
-            {"params": dparams},
-            tokens,
-            paged_cache=(dcache.k, dcache.v, dcache.lengths,
-                         block_tables, write_valid),
+        out, (k2, v2), _ = self._draft_model.forward_paged(
+            dparams, tokens, dcache, block_tables, write_valid,
             return_hidden=not with_head,
         )
         return out, PagedKVCache(k=k2, v=v2, lengths=dcache.lengths)
@@ -1254,8 +1308,9 @@ class Engine:
             k=cache.k, v=cache.v,
             lengths=jnp.where(participates, base, 0),
         )
-        out, new = self._forward(
-            params, tokens, work, block_tables, write_valid
+        out, new, *aux = self._forward(
+            params, tokens, work, block_tables, write_valid,
+            *self._rows_arg(lambda: t_idx < chunk_lens[:, None]),
         )
         tok = self._sample_last(
             params, out, jnp.maximum(chunk_lens - 1, 0), key, temp, topk
@@ -1269,7 +1324,7 @@ class Engine:
         )
         new_last = jnp.where(sample_mask, tok, last)
         if not self.spec_k:
-            return new_cache, new_last
+            return (new_cache, new_last, *aux)
         # Draft prefill rides the same chunk: same slices, same write
         # mask (floor included — shared pages already hold draft K/V
         # from the slot that registered the prefix), the draft pool's
@@ -1293,8 +1348,9 @@ class Engine:
         block table; inactive rows dropped), attend, sample the next."""
         lens = jnp.where(active, cache.lengths, 0)
         work = PagedKVCache(k=cache.k, v=cache.v, lengths=lens)
-        out, new = self._forward(
-            params, last[:, None], work, block_tables, active[:, None]
+        out, new, *aux = self._forward(
+            params, last[:, None], work, block_tables, active[:, None],
+            *self._rows_arg(lambda: active[:, None]),
         )
         tok = self._sample_last(
             params, out,
@@ -1306,7 +1362,57 @@ class Engine:
                 lengths=jnp.where(active, lens + 1, lens),
             ),
             jnp.where(active, tok, last),
+            *aux,
         )
+
+    def _rows_arg(self, rows) -> tuple:
+        """The forward's ``row_valid`` argument, for a model that skips
+        the rows that are no tokens (padding of a chunk, idle slots);
+        nothing, and nothing traced, for one that computes them all."""
+        return (rows(),) if self.model.skips_invalid_rows else ()
+
+    def _paged_prefill_compact_step(
+        self, params, cache, last, slot_idx, tokens, base, chunk_lens,
+        floor, sample_mask, block_tables, key, temp, topk,
+    ):
+        """:meth:`_paged_prefill_step` over the slots that take part
+        only: row ``i`` of ``tokens`` / ``base`` / ``chunk_lens`` /
+        ``floor`` / ``sample_mask`` belongs to slot ``slot_idx[i]``, whose
+        block table, sampling settings, length and last token are found
+        through it. ``slot_idx`` past the slots marks padding up to the
+        compiled count (its ``chunk_lens`` is 0): nothing of it is
+        written anywhere. The device work follows ``len(slot_idx) x
+        chunk`` rows, not ``slots x chunk``."""
+        s = cache.lengths.shape[0]
+        at = jnp.minimum(slot_idx, s - 1)
+        t_idx = jnp.arange(tokens.shape[1])[None, :]
+        pos = base[:, None] + t_idx
+        rows = t_idx < chunk_lens[:, None]
+        write_valid = rows & (pos >= floor[:, None])
+        participates = chunk_lens > 0
+        work = PagedKVCache(
+            k=cache.k, v=cache.v, lengths=jnp.where(participates, base, 0)
+        )
+        out, new, *aux = self._forward(
+            params, tokens, work, block_tables[at], write_valid,
+            *self._rows_arg(lambda: rows),
+        )
+        tok = self._sample_last(
+            params, out, jnp.maximum(chunk_lens - 1, 0), key, temp[at],
+            topk[at],
+        )
+        # A scatter past the slots is dropped: padding and the slots
+        # that sample nothing leave no mark.
+        new_cache = PagedKVCache(
+            k=new.k, v=new.v,
+            lengths=cache.lengths.at[
+                jnp.where(participates, slot_idx, s)
+            ].set(base + chunk_lens, mode="drop"),
+        )
+        new_last = last.at[jnp.where(sample_mask, slot_idx, s)].set(
+            tok, mode="drop"
+        )
+        return (new_cache, new_last, *aux)
 
     # -- speculative tick bodies (ISSUE 13) ---------------------------------
     def _spec_draft_step(
@@ -1572,8 +1678,34 @@ class Engine:
 
     # -- host surface (the scheduler's API) ---------------------------------
     def _split(self):
-        self._key, sub = jax.random.split(self._key)
+        """The next subkey of the engine's one stream."""
+        if self._sub is None:
+            self._split_ahead()
+        sub, self._sub = self._sub, None
         return sub
+
+    def _split_ahead(self) -> None:
+        """Split the next subkey off now. A decode tick does so once its
+        step is enqueued: the split's dispatch then passes while the
+        device runs the step, not while the device waits for it."""
+        if self._sub is None:
+            self._key, self._sub = _split_pair(self._key)
+
+    def _stage(self, name: str, value, dtype):
+        """``value`` on the device as ``dtype``: the copy staged for the
+        last tick while the content is the same. ``active``, ``temp``,
+        ``topk`` and the block tables change when a slot is admitted,
+        retires or takes a page, not from tick to tick, and a transfer
+        costs the host 0.27 ms each while the device waits."""
+        host = np.asarray(value, dtype)
+        held = self._staged.get(name)
+        if (
+            held is None
+            or held[0].shape != host.shape
+            or not np.array_equal(held[0], host)
+        ):
+            held = self._staged[name] = (host.copy(), jnp.asarray(host))
+        return held[1]
 
     def prefill(self, tokens, prompt_lens, admit, temp, topk) -> np.ndarray:
         """Admit requests: ``tokens`` [slots, prefill_len] int32 (padded),
@@ -1626,6 +1758,11 @@ class Engine:
         ``sample_mask`` is set) as host numpy."""
         if not self.paged:
             raise ValueError("prefill_paged requires Engine(kv_pages=...)")
+        if self._prefill_counts:
+            return self._prefill_paged_compact(
+                tokens, base, chunk_lens, floor, sample_mask, temp, topk
+            )
+        aux = ()
         with obs.span("prefill_dispatch"):  # staging and enqueue
             args = [
                 self.params,
@@ -1649,14 +1786,111 @@ class Engine:
                     )
                 )
             else:
-                self.cache, self.last_token = self.compile_watch.call(
+                self.cache, self.last_token, *aux = self.compile_watch.call(
                     "prefill", self._prefill_paged_jit, *args
                 )
         with obs.span("prefill_fetch"):  # the wait and the copy back
             # The step's one deliberate completion fence (docstring
             # contract: the fetch closes the caller's span).
             # analysis: allow(host-sync-in-hot-seam)
-            return np.asarray(self.last_token)
+            toks = np.asarray(self.last_token)
+        if obs.enabled():
+            chunk_lens = np.asarray(chunk_lens)
+            self._note_prefill_rows(
+                chunk_lens.size * self.prefill_chunk, int(chunk_lens.sum())
+            )
+            self._note_aux("prefill", aux)
+        return toks
+
+    def _prefill_paged_compact(
+        self, tokens, base, chunk_lens, floor, sample_mask, temp, topk
+    ) -> np.ndarray:
+        """A chunk tick over its participants only (see
+        :meth:`_paged_prefill_compact_step`): the slots with a chunk are
+        taken ``_prefill_counts[-1]`` at a time, each group padded to the
+        next compiled count."""
+        chunk_lens = np.asarray(chunk_lens)
+        takers = np.flatnonzero(chunk_lens > 0)
+        most, computed, aux_all = self._prefill_counts[-1], 0, []
+        with obs.span("prefill_dispatch"):  # staging and enqueue
+            bt = jnp.asarray(self.allocator.block_tables, jnp.int32)
+            temp = jnp.asarray(temp, jnp.float32)
+            topk = jnp.asarray(topk, jnp.int32)
+            for g0 in range(0, len(takers), most):
+                group = takers[g0 : g0 + most]
+                n = next(c for c in self._prefill_counts if c >= len(group))
+
+                def pad(a, fill=0):  # the group's rows, then padding
+                    a = np.asarray(a)
+                    tail = np.full((n - len(group), *a.shape[1:]), fill,
+                                   a.dtype)
+                    return np.concatenate([a[group], tail])
+
+                self.cache, self.last_token, *aux = self.compile_watch.call(
+                    "prefill", self._prefill_compact_jit,
+                    self.params, self.cache, self.last_token,
+                    jnp.asarray(pad(np.arange(self.slots), self.slots),
+                                jnp.int32),
+                    jnp.asarray(pad(tokens), jnp.int32),
+                    jnp.asarray(pad(base), jnp.int32),
+                    jnp.asarray(pad(chunk_lens), jnp.int32),
+                    jnp.asarray(pad(floor), jnp.int32),
+                    jnp.asarray(pad(sample_mask), bool),
+                    bt, self._split(), temp, topk,
+                )
+                computed += n * self.prefill_chunk
+                aux_all.append(aux)
+        with obs.span("prefill_fetch"):  # the wait and the copy back
+            # analysis: allow(host-sync-in-hot-seam)
+            toks = np.asarray(self.last_token)
+        if obs.enabled():
+            self._note_prefill_rows(computed, int(chunk_lens.sum()))
+            for aux in aux_all:
+                self._note_aux("prefill", aux)
+        return toks
+
+    def warm_prefill_counts(self) -> None:
+        """Compile the compacted prefill step for every count of
+        participants a tick can meet (``warm_engine`` calls this), with
+        padding alone: nothing is written."""
+        w = self.prefill_chunk
+        for n in self._prefill_counts:
+            i32 = jnp.zeros((n,), jnp.int32)
+            self.cache, self.last_token, *_ = self.compile_watch.call(
+                "prefill", self._prefill_compact_jit,
+                self.params, self.cache, self.last_token,
+                jnp.full((n,), self.slots, jnp.int32),
+                jnp.zeros((n, w), jnp.int32), i32, i32, i32,
+                jnp.zeros((n,), bool),
+                jnp.asarray(self.allocator.block_tables, jnp.int32),
+                self._split(), jnp.zeros((self.slots,), jnp.float32),
+                jnp.zeros((self.slots,), jnp.int32),
+            )
+
+    @staticmethod
+    def _note_prefill_rows(computed: int, valid: int) -> None:
+        """Rows a chunk tick computed and those of them that were prompt
+        tokens (gauges; the benchmark reads the waste from them)."""
+        obs.gauge("prefill_rows_computed", float(computed))
+        obs.gauge("prefill_rows_valid", float(valid))
+
+    @staticmethod
+    def _note_aux(phase: str, aux) -> None:
+        """What the model counted in a step, as counters and gauges:
+        per-layer, per-expert token counts ``[layers, experts]`` give
+        ``moe_expert_tokens`` (by layer), ``moe_experts_hit`` (experts with
+        a token, mean over layers) and ``moe_load_max_over_mean``. Called
+        after the step's tokens are on the host, so it waits for nothing."""
+        if not aux:
+            return
+        counts = np.asarray(aux[0])
+        for layer, row in enumerate(counts):
+            obs.counter("moe_expert_tokens", float(row.sum()), layer=layer)
+        obs.gauge("moe_experts_hit",
+                  float((counts > 0).sum(axis=1).mean()), phase=phase)
+        mean = np.maximum(counts.mean(axis=1), 1e-9)
+        obs.gauge("moe_load_max_over_mean",
+                  float((counts.max(axis=1) / mean).mean()), phase=phase)
 
     def copy_page(self, src: int, dst: int) -> None:
         """Device half of a COW remap: copy pool page ``src`` → ``dst``
@@ -1863,27 +2097,33 @@ class Engine:
                 self.params,
                 self.cache,
                 self.last_token,
-                jnp.asarray(active, bool),
+                self._stage("active", active, bool),
             ]
             if self.paged:
                 args.append(
-                    jnp.asarray(self.allocator.block_tables, jnp.int32)
+                    self._stage(
+                        "block_tables", self.allocator.block_tables, np.int32
+                    )
                 )
             args += [
                 self._split(),
-                jnp.asarray(temp, jnp.float32),
-                jnp.asarray(topk, jnp.int32),
+                self._stage("temp", temp, np.float32),
+                self._stage("topk", topk, np.int32),
             ]
-            self.cache, self.last_token = self.compile_watch.call(
+            self.cache, self.last_token, *aux = self.compile_watch.call(
                 "decode",
                 self._decode_paged_jit if self.paged else self._decode_jit,
                 *args,
             )
+            self._split_ahead()
         with obs.span("decode_fetch"):  # the wait and the copy back
             # The step's one deliberate completion fence (docstring
             # contract: the fetch closes the caller's span).
             # analysis: allow(host-sync-in-hot-seam)
-            return np.asarray(self.last_token)
+            toks = np.asarray(self.last_token)
+        if aux and obs.enabled():
+            self._note_aux("decode", aux)
+        return toks
 
     # -- roofline accounting (ISSUE 8) --------------------------------------
     def register_roofline(self) -> dict:
@@ -1919,6 +2159,13 @@ class Engine:
                      i32, i32, msk, bt, key, f32, i32, *spec_tail),
                 ),
             }
+            if self._prefill_counts:  # the step of one participant
+                steps["prefill"] = (
+                    self._prefill_compact_jit,
+                    (self.params, self.cache, self.last_token, i32[:1],
+                     toks[:1], i32[:1], i32[:1], i32[:1], msk[:1], bt,
+                     key, f32, i32),
+                )
             if self.spec_k:
                 k = self.spec_k
                 steps["spec_draft"] = (
@@ -2037,7 +2284,7 @@ class Engine:
         if self.draft_cache is not None:
             self.draft_cache = _zeroed(self.draft_cache)
         self.last_token = jnp.zeros_like(self.last_token)
-        self._key = jax.random.key(seed)
+        self._key, self._sub = jax.random.key(seed), None
         self._spec_state = None
         if self.paged:
             if self.host_pages:
@@ -2074,6 +2321,7 @@ class Engine:
         the slot's block-table pages and trims the tail pad — so a
         fleet shipment packed from either injects into either. Returns
         ``(k_rows, v_rows)``."""
+        self.model.check_shipment()
         if length <= 0:
             raise ValueError(f"export_kv_rows needs length > 0, got {length}")
         if self.paged:
@@ -2117,6 +2365,7 @@ class Engine:
         layout — raw arrays, or objects with ``.q``/``.scale`` for a
         quantized cache (any container with those attributes works;
         leaves are rebuilt positionally)."""
+        self.model.check_shipment()
         if self.kv_quantized:
             # Rebuild as the cache's own pytree type so tree.map pairs
             # leaves positionally whatever container shipped them.

@@ -91,6 +91,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from mpit_tpu.models.serving import as_serve_model
 from mpit_tpu.ops.kv_quant import QuantizedKV, kv_wire_bytes_per_row
 
 __all__ = [
@@ -265,15 +266,20 @@ def alloc_paged_cache(
     heads]`` — a page costs ``page_size × kv_wire_bytes_per_row(H, Dh,
     "int8")`` bytes, so the same budget holds ~2× the pages of a bf16
     pool."""
-    dt = dtype or cfg.dtype
+    # The row a layer caches is the model's to say: GPT-2's K and V of
+    # ``heads*head_dim`` each, a latent-attention model's shared latent
+    # and its key's rotary part (``models.serving.CacheLayout``).
+    layout = as_serve_model(cfg).cache_layout()
+    dt = dtype or layout.dtype
     kw = {"device": sharding} if sharding is not None else {}
-    shape = (num_pages, page_size, cfg.num_heads * cfg.head_dim)
-    layers = lambda: tuple(
-        _alloc_kv(shape, dt, quantized, kw, scale_width=cfg.num_heads)
-        for _ in range(cfg.num_layers)
+    layers = lambda width: tuple(
+        _alloc_kv((num_pages, page_size, width), dt, quantized, kw,
+                  scale_width=layout.scale_width)
+        for _ in range(layout.num_layers)
     )
     return PagedKVCache(
-        k=layers(), v=layers(), lengths=jnp.zeros((slots,), jnp.int32)
+        k=layers(layout.k_width), v=layers(layout.v_width),
+        lengths=jnp.zeros((slots,), jnp.int32),
     )
 
 

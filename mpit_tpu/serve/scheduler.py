@@ -159,6 +159,10 @@ def warm_engine(engine, *, register_costs: bool = False) -> None:
             # here or the first real divergence pays it inside the
             # timed window.
             engine.copy_page(0, 0)
+            if getattr(engine, "_prefill_counts", ()):
+                # A compacted chunk tick has one step a count of
+                # participants; the warm request met the first only.
+                engine.warm_prefill_counts()
             if getattr(engine, "host_pages", 0):
                 # The host tier's gather/scatter pair likewise: pay
                 # both compiles with a page-0 round trip (restore

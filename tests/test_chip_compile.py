@@ -405,3 +405,138 @@ def test_tp_paged_forward_compiles(v5e, kv_dtype, t, monkeypatch):
         leaf.size * leaf.dtype.itemsize // 2
         for leaf in jax.tree.leaves((cache.k, cache.v))
     )
+
+
+# ---------------------------------------------------------------------------
+# The xing4 family at its published widths (benchmark/configs/
+# xing4-29b-a4b-6of40.json): 32 heads against one shared latent row of
+# 512 + 64 values (the rotary part stored in 128 lanes), 64 experts of
+# width 1,024, 4 residual streams of 3,584, a vocabulary of 131,072; 32
+# slots of 13,312 positions in pages of 256, prefill chunks of 2,048.
+# ---------------------------------------------------------------------------
+
+X4_SLOTS, X4_POSITIONS, X4_PAGE, X4_CHUNK = 32, 13312, 256, 2048
+V5E_HBM = 15.75e9  # what loads on a v5e
+
+
+def _x4_config():
+    from mpit_tpu.models.xing4 import Xing4Config
+
+    return Xing4Config(num_hidden_layers=6, first_k_dense_replace=1,
+                       max_seq_len=X4_POSITIONS)
+
+
+def test_mla_decode_kernel(v5e):
+    from mpit_tpu.ops.mla_attention import mla_paged_decode_attention
+
+    cfg = _x4_config()
+    h, c, r = cfg.num_attention_heads, cfg.kv_lora_rank, 128
+    pages, pps = 2 * X4_POSITIONS // X4_PAGE, X4_POSITIONS // X4_PAGE
+    bf = jnp.bfloat16
+    text = _compile_on_chip(
+        v5e,
+        lambda qa, qr, ckv, kr, lens, bt: mla_paged_decode_attention(
+            qa, qr, ckv, kr, lens, bt, scale=cfg.softmax_scale,
+            interpret=False),
+        _sds((X4_SLOTS, h, c), bf), _sds((X4_SLOTS, h, cfg.qk_rope_head_dim), bf),
+        _sds((pages, X4_PAGE, c), bf), _sds((pages, X4_PAGE, r), bf),
+        _sds((X4_SLOTS,), jnp.int32), _sds((X4_SLOTS, pps), jnp.int32),
+    )
+    assert "mla_paged_decode_attn" in text
+
+
+@pytest.mark.parametrize("width", [512, 128], ids=["latent", "rope"])
+def test_paged_write_pages_latent_layout(v5e, width):
+    """A chunk's rows into a buffer of the latent pool, a page at a time."""
+    from mpit_tpu.ops.decode_attention import paged_write_pages
+
+    pages, pps = 2 * X4_POSITIONS // X4_PAGE, X4_POSITIONS // X4_PAGE
+    text = _compile_on_chip(
+        v5e,
+        lambda pool, new, lens, bt, valid: paged_write_pages(
+            pool, new, lens, bt, valid, interpret=False),
+        _sds((pages, X4_PAGE, width), jnp.bfloat16),
+        _sds((2, X4_CHUNK, width), jnp.bfloat16),
+        _sds((2,), jnp.int32), _sds((2, pps), jnp.int32),
+        _sds((2, X4_CHUNK), bool),
+    )
+    assert "paged_kv_write" in text
+
+
+@pytest.fixture(scope="module")
+def xing4_engine(v5e):
+    """The configuration's engine on shapes alone: parameters by
+    ``eval_shape`` (9.6 GB are never made) and a two-slot pool; the steps
+    are then lowered for the pool of all 32 slots."""
+    from mpit_tpu.models.xing4 import init_params
+    from mpit_tpu.ops import decode_attention
+    from mpit_tpu.serve import Engine
+
+    cfg = _x4_config()
+    params = jax.eval_shape(lambda: init_params(cfg, jax.random.key(0)))
+    pps = X4_POSITIONS // X4_PAGE
+    was = decode_attention._use_kernel
+    decode_attention._use_kernel = lambda interpret: True
+    eng = Engine(cfg, params, slots=X4_SLOTS, max_len=X4_POSITIONS,
+                 kv_pages=2 * pps, kv_page_size=X4_PAGE,
+                 prefill_chunk=X4_CHUNK)
+    one = SingleDeviceSharding(v5e.devices[0])
+    yield eng, lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one), tree)
+    decode_attention._use_kernel = was
+
+
+@pytest.mark.parametrize("step", ["decode", "prefill"])
+def test_xing4_paged_steps_fit_and_update_in_place(xing4_engine, step):
+    """Both paged steps of the configuration, pool of 32 x 13,312
+    positions: every buffer of the latent pool aliased to an output, and
+    arguments + temporaries under what loads on the chip (this is how 32
+    against 24 slots was decided before any chip time was spent)."""
+    from mpit_tpu.serve.kvcache import PagedKVCache
+
+    eng, on_chip = xing4_engine
+    assert eng._prefill_counts == (1,)  # a chunk is the step's 2,048 rows
+    pages = X4_SLOTS * eng.pages_per_slot
+    full = lambda bufs: tuple(
+        jax.ShapeDtypeStruct((pages, *b.shape[1:]), b.dtype) for b in bufs)
+    cache = PagedKVCache(k=full(eng.cache.k), v=full(eng.cache.v),
+                         lengths=eng.cache.lengths)
+    s = eng.slots
+    i32, f32 = jnp.zeros((s,), jnp.int32), jnp.zeros((s,), jnp.float32)
+    bt, key = jnp.zeros((s, eng.pages_per_slot), jnp.int32), jax.random.key(0)
+    if step == "decode":
+        jit, args = eng._decode_paged_jit, (
+            eng.params, cache, eng.last_token, jnp.zeros((s,), bool), bt,
+            key, f32, i32)
+    else:
+        n = eng._prefill_counts[-1]  # the largest step a tick can meet
+        z = jnp.zeros((n,), jnp.int32)
+        jit, args = eng._prefill_compact_jit, (
+            eng.params, cache, eng.last_token, z,
+            jnp.zeros((n, X4_CHUNK), jnp.int32), z, z, z,
+            jnp.zeros((n,), bool), bt, key, f32, i32)
+    compiled = jit.lower(*on_chip(args)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert ("mla_paged_decode_attn" if step == "decode"
+            else "paged_kv_write") in text
+    pool = jax.tree.leaves((cache.k, cache.v))
+    want = {}
+    for leaf in pool:
+        shape = f"bf16[{','.join(map(str, leaf.shape))}]"
+        want[shape] = want.get(shape, 0) + 1
+    params, aliased = _entry_parameters(text), _aliased_parameters(text)
+    got = {}
+    for n_ in aliased:
+        got[params[n_]] = got.get(params[n_], 0) + 1
+    assert {s_: got.get(s_, 0) for s_ in want} == want
+    mem = compiled.memory_analysis()
+    pool_bytes = sum(l.size * l.dtype.itemsize for l in pool)
+    assert mem.alias_size_in_bytes >= pool_bytes
+    held = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert held < V5E_HBM, held
+    if step == "decode":
+        # A tick's temporaries are O(rows): under the smallest buffer.
+        assert mem.temp_size_in_bytes < min(
+            l.size * l.dtype.itemsize for l in pool)

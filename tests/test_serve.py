@@ -249,6 +249,75 @@ class TestEngineMechanics:
                 model, params, PROMPTS[rid], len(toks)
             )
 
+    def test_key_stream_is_plain_split_whenever_it_is_split(
+        self, model_and_params
+    ):
+        """A decode tick splits the next subkey off ahead, in one
+        dispatch; the stream is ``jax.random.split`` taken apart, after
+        a reset too."""
+        _, params = model_and_params
+        engine = Engine(CFG, params, slots=2, max_len=32, prefill_len=8,
+                        seed=7)
+
+        def plain(seed, n):
+            key, subs = jax.random.key(seed), []
+            for _ in range(n):
+                key, sub = jax.random.split(key)
+                subs.append(jax.random.key_data(sub))
+            return np.stack(subs)
+
+        got = []
+        for i in range(6):
+            if i % 2:
+                engine._split_ahead()
+                engine._split_ahead()  # the second finds one held
+            got.append(jax.random.key_data(engine._split()))
+        np.testing.assert_array_equal(np.stack(got), plain(7, 6))
+        engine._split_ahead()
+        engine.reset(seed=9)  # the subkey held is the old seed's
+        np.testing.assert_array_equal(
+            jax.random.key_data(engine._split()), plain(9, 1)[0]
+        )
+
+    def test_decode_restages_an_input_only_when_it_changed(
+        self, model_and_params
+    ):
+        """``active``, ``temp``, ``topk`` go to the device when their
+        content changed, by value: an array the caller changes in place
+        is staged again, and the tokens are those of fresh transfers."""
+        _, params = model_and_params
+
+        def ticks(stage_always):
+            engine = Engine(CFG, params, slots=2, max_len=32, prefill_len=8,
+                            seed=3)
+            if stage_always:
+                engine._stage = lambda name, value, dtype: jnp.asarray(
+                    value, dtype
+                )
+            toks = np.zeros((2, 8), np.int32)
+            toks[0, :3], toks[1, :2] = [5, 9, 3], [7, 1]
+            temp = np.zeros(2, np.float32)
+            topk = np.zeros(2, np.int32)
+            engine.prefill(toks, np.array([3, 2]), np.ones(2, bool), temp,
+                           topk)
+            active, out, staged = np.ones(2, bool), [], []
+            for i in range(6):
+                if i == 2:
+                    temp[1] = 1.5  # in place, as a scheduler's rows are
+                if i == 4:
+                    active[0] = False
+                out.append(engine.decode(active, temp, topk).copy())
+                staged.append({k: id(v[1]) for k, v in engine._staged.items()})
+            return np.stack(out), staged
+
+        got, staged = ticks(False)
+        want, _ = ticks(True)
+        np.testing.assert_array_equal(got, want)
+        assert staged[0] == staged[1] and staged[2] == staged[3]
+        assert staged[1]["temp"] != staged[2]["temp"]
+        assert staged[1]["active"] == staged[3]["active"] != staged[4]["active"]
+        assert staged[0]["topk"] == staged[5]["topk"]
+
 
 class TestServeObservability:
     def test_summary_carries_request_histograms(self, model_and_params):
