@@ -323,19 +323,23 @@ def _paged_kernel_vs_reference(engine, seed: int) -> dict:
     random data and ragged lengths, over a pool in the stored form
     ([pages, page, H*Dh]; bf16 rows, and int8 rows with their scale
     plane), for the three query widths the engine traces: a decode tick
-    (T=1), a ``spec_k=4`` verify (T=5) and a prefill chunk (T=64). Output
-    against the gather-dense reference, and the kernel's own visited-tile
-    count against the host formula the scheduler's counters use."""
+    (T=1) and a ``spec_k=4`` verify (T=5), which take all heads as the
+    rows of one product a step over a bf16 pool, and a prefill chunk
+    (T=64), a head at a time; each call's form and rows a step are in
+    the record. Output against the gather-dense reference, and the
+    kernel's own visited-tile count against the host formula the
+    scheduler's counters use."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from mpit_tpu.ops.decode_attention import (
+        decode_tiling,
         flash_paged_decode_attention,
         num_kv_blocks,
         reference_paged_decode_attention,
     )
-    from mpit_tpu.ops.kv_quant import pack_heads, quantize_kv
+    from mpit_tpu.ops.kv_quant import QuantizedKV, pack_heads, quantize_kv
 
     cfg, b, pps = engine.cfg, engine.slots, engine.pages_per_slot
     kq, kk, kv = jax.random.split(jax.random.key(seed), 3)
@@ -350,7 +354,7 @@ def _paged_kernel_vs_reference(engine, seed: int) -> dict:
     table = jnp.asarray(
         rng.permutation(engine.num_pages)[: b * pps].reshape(b, pps), jnp.int32
     )
-    errs, visited_t1 = {}, None
+    errs, forms, visited_t1 = {}, {}, None
     for t in sorted({1, 5, engine.prefill_chunk}):
         q = jax.random.normal(kq, (b, t, *rows[2:]), jnp.bfloat16)
         lengths = jnp.asarray(
@@ -369,11 +373,19 @@ def _paged_kernel_vs_reference(engine, seed: int) -> dict:
             )
             want = reference_paged_decode_attention(q, kp, vp, lengths, table)
             errs[f"{name}_T{t}"] = err = _max_abs(out, want)
+            quantized = isinstance(kp, QuantizedKV)
+            tiling = decode_tiling(
+                t, cfg.num_heads, kp.q.dtype if quantized else kp.dtype,
+                block_k=engine.decode_block_k, page_size=engine.page_size,
+                quantized=quantized,
+            )
+            forms[f"{name}_T{t}"] = [tiling.form, tiling.rows]
             assert np.array_equal(np.asarray(visited), tiles), (visited, tiles)
             assert err <= 0.02, (name, t, err)  # rows of unit variance
         if t == 1:
             visited_t1 = tiles.tolist()
     return {"decode_kernel_err_vs_reference": errs,
+            "decode_kernel_form_and_rows_a_step": forms,
             "visited_tiles": visited_t1,
             "page_writer_equals_row_scatter": _page_writer_vs_scatter(
                 engine, pools, table, seed)}

@@ -23,10 +23,11 @@ Four rules over ``ops/ring_collectives.py`` / ``ops/decode_attention.py``
 - ``kernel-plan-geometry`` (host math, no tracing): the planner's tile
   answers hold over a sweep of payload sizes, device counts and wire
   dtypes (``padded_rows`` a sublane multiple, chunk layout contiguous
-  and covering, ``pick_block_k`` always divides the cache length), the
-  divisibility preconditions the kernels rely on are actually raised
-  by the host wrappers, and the VMEM footprint of one ring call at the
-  default GradSync bucket — input + output + the ``_sum_scratch`` /
+  and covering, ``pick_block_k`` always divides the cache length, the
+  decode kernel's tile of pages is whole pages of whole VMEM tiles and
+  fits), the divisibility preconditions the kernels rely on are
+  actually raised by the host wrappers, and the VMEM footprint of one
+  ring call at the default GradSync bucket — input + output + the ``_sum_scratch`` /
   ``_q8_scratch`` staging buffers, computed from the very shapes the
   ``pallas_call`` passes — fits the chip's VMEM with the planner's
   own numbers (the tile math and the scratch shapes cannot drift
@@ -394,11 +395,55 @@ def check_plan_geometry() -> list:
                 f"{n} — the kernel clamp and host mirror disagree"
             )
 
+    # The decode kernel's tile (a paged step gathers pages): a piece
+    # divides the page, several pieces a step only of whole pages that
+    # are whole VMEM tiles of the pool's dtype (a DMA lands on a tile
+    # boundary of a larger buffer), heads as rows only within the bound
+    # and never over an int8 pool, and what a step holds in VMEM (the
+    # double-buffered K and V tiles with their scale planes, computed
+    # from the scratch shapes the pallas_call passes, plus the float32
+    # accumulator of the heads-as-rows form) fits, GPT-2 XL's row wide.
+    import jax.numpy as jnp
+
+    from mpit_tpu.ops import decode_attention as da
+
+    for page, dt, t, h, quant in itertools.product(
+        (4, 8, 16, 32, 64, 256, 1024), ("float32", "bfloat16", "int8"),
+        (1, 5, 8, 64), (10, 20, 25), (False, True),
+    ):
+        if quant != (dt == "int8"):
+            continue
+        til = da.decode_tiling(
+            t, h, dt, block_k=pick_block_k(page), page_size=page,
+            quantized=quant,
+        )
+        what = f"decode_tiling(T={t}, H={h}, {dt}, page={page}) = {til}"
+        sub = rc.sublane_for(dt)
+        if page % til.piece_rows or til.pieces < 1:
+            bad(f"{what}: a piece does not divide the page")
+        if til.pieces > 1 and (til.piece_rows != page or page % sub):
+            bad(f"{what}: several pieces a step that are not whole pages "
+                f"of whole {sub}-row tiles")
+        if til.rows > max(da._TILE_ROWS, til.piece_rows):
+            bad(f"{what}: a step of {til.rows} rows, past the tile")
+        as_rows = til.form == "heads_as_rows"
+        head_rows = t * -(-h // sub) * sub
+        if as_rows and (quant or head_rows > da._HEAD_ROWS):
+            bad(f"{what}: heads as rows over {head_rows} rows"
+                + (" of an int8 pool" if quant else ""))
+        dts = (
+            [jnp.int8, jnp.float32] * 2 if quant else [jnp.dtype(dt)] * 2
+        )
+        held = sum(
+            _spec_bytes(spec)
+            for spec in da._scratch_for(quant, til.rows, h * 64, h, dts)
+        ) + (head_rows * h * 64 * 4 if as_rows else 0)
+        if held > _VMEM_FILL_CAP * _VMEM_BYTES:
+            bad(f"{what}: {held} B of VMEM a step exceeds the cap")
+
     # VMEM footprint of one ring call at the default GradSync bucket
     # (4 MB, f32 wire and q8 wire), computed from the ACTUAL scratch
     # shapes the pallas_call would allocate.
-    import jax.numpy as jnp
-
     bucket_elems = (4 * 2 ** 20) // 4
     for p in (4, 8):
         plan = rc.plan_ring(bucket_elems, p, jnp.float32)

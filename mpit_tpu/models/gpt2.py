@@ -675,6 +675,15 @@ class GPT2ServeModel(ServeModel):
         field = "paged_attention_fn" if paged else "cache_attention_fn"
         return GPT2ServeModel(dataclasses.replace(self.cfg, **{field: attn_fn}))
 
+    def attention_tiling(self, t_q, *, block_k, page_size, kv_dtype, tp=1):
+        from mpit_tpu.ops.decode_attention import decode_tiling
+
+        tiling = decode_tiling(
+            t_q, self.cfg.num_heads // tp, kv_dtype, block_k=block_k,
+            page_size=page_size, quantized=jnp.dtype(kv_dtype) == jnp.int8,
+        )
+        return {"attention_form": tiling.form, "attention_rows": tiling.rows}
+
     def with_quant_matmul(self, fn):
         return GPT2ServeModel(dataclasses.replace(self.cfg, quant_matmul_fn=fn))
 

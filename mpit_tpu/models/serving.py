@@ -87,6 +87,13 @@ class ServeModel:
         in ``decode_block_k`` of what it returns."""
         raise NotImplementedError
 
+    def attention_tiling(self, t_q: int, **how) -> dict:
+        """What the spans of a step of ``t_q`` query rows say about the
+        injected attention kernel (``how``: the engine's ``block_k``,
+        ``page_size``, ``kv_dtype``, ``tp``): static per
+        compiled step. Nothing for a family that does not say."""
+        return {}
+
     def with_quant_matmul(self, fn) -> "ServeModel":
         raise NotImplementedError
 

@@ -9,21 +9,33 @@ when the slots hold 30-token contexts. This kernel makes the tick cost
 scale with the *context actually cached*:
 
 - **Blocked over the cache length with online softmax.** The kernel
-  streams ``block_k``-sized K/V tiles through a ``fori_loop``, carrying
-  the flash running max/denominator/accumulator in f32 (the same
-  structure as :mod:`mpit_tpu.ops.flash_attention`); the ``[T, S]``
-  score matrix never exists — only a ``[T, block_k]`` f32 tile.
-- **Per-slot length-aware block skipping.** The k-loop bound is derived
-  from the slot's ``lengths`` entry (an SMEM scalar): a slot holding
-  ``L`` tokens visits ``ceil((L+T)/block_k)`` tiles, not
-  ``max_len/block_k``. Because K/V stay in **HBM** (``memory_space=ANY``)
-  and the kernel DMAs tiles in itself (double-buffered, overlap with
-  compute), skipped tiles cost neither FLOPs *nor* HBM reads — the
-  BlockSpec-prefetch form would have copied the whole padded row.
-- **Heads-local.** One grid program per slot computes every head it was
-  given (python-unrolled over the packed ``[rows, H·D]`` lane layout of
-  the training kernel), so the TP engine calls it unchanged on its
-  H/P head shard.
+  streams K/V tiles through a ``fori_loop``, carrying the flash running
+  max/denominator/accumulator in f32 (the same structure as
+  :mod:`mpit_tpu.ops.flash_attention`); the ``[T, S]`` score matrix
+  never exists — only a ``[rows of queries, rows of a tile]`` f32 tile.
+- **Per-slot length-aware skipping.** The loop bound is derived from
+  the slot's ``lengths`` entry (an SMEM scalar): a slot holding ``L``
+  tokens reads the rows up to ``L + T`` (to the end of their
+  ``block_k``-row block on the dense path, of their page on the paged
+  one), not ``max_len``. Because K/V stay in **HBM**
+  (``memory_space=ANY``) and the kernel DMAs tiles in itself
+  (double-buffered, overlap with compute), skipped rows cost neither
+  FLOPs *nor* HBM reads — the BlockSpec-prefetch form would have copied
+  the whole padded row. ``block_k`` is the unit the skipping is counted
+  in (``visited``, :func:`num_kv_blocks`).
+- **A step does a lane tile's worth of work** (ISSUE 27,
+  :func:`decode_tiling`). One grid program per slot computes every head
+  it was given over the packed ``[rows, H·D]`` lane layout of the
+  training kernel, so the TP engine calls it unchanged on its H/P head
+  shard. At few query rows (a decode tick, a speculative verify) the
+  heads are the ROWS of one product a step: the block-diagonal query
+  ``[T·Hp, H·D]`` against the tile's K gives every head's scores at
+  once, one online-softmax update serves all, and ``p @ V`` accumulates
+  ``[T·Hp, H·D]`` of which head ``h`` keeps its own lanes. The matrix
+  unit multiplies ``H`` times the useful terms, all exact zeros, and
+  the step is bound by the tile's bytes. At a prefill chunk's rows
+  (``T·Hp`` past a bound) and over an int8 pool the loop is
+  python-unrolled over heads, a product a head.
 - **Small-T prefill tail.** ``T`` is static per trace; the engine's
   padded prefill (``T = prefill_len``, ``lengths = 0``) and its decode
   tick (``T = 1``) are two traces of the same kernel.
@@ -45,12 +57,17 @@ Pallas interpreter (the CPU-mesh test path, like the training kernel).
 the same length-aware flash loop against a PAGED pool
 (``[num_pages, page_size, H·D]``) instead of a dense per-slot buffer:
 the slot's int32 block table rides in SMEM next to ``lengths`` (scalar
-prefetch), and each k-tile's DMA source is resolved per tile —
-``page = bt[b, (ki·block_k)//page_size]``, offset ``(ki·block_k) %
-page_size`` — so the tile loop indirects through the table with zero
-extra HBM traffic (``page_size`` must be a multiple of ``block_k``:
-a tile never straddles pages). Skipped tiles still cost neither FLOPs
-nor HBM reads, and the heads-local/TP calling convention is unchanged.
+prefetch), and a loop step GATHERS consecutive pages of the table into
+one VMEM tile (16 pages of 16 positions: 256 rows), a DMA a page and
+buffer, each source resolved by one SMEM lookup, all of a step's DMAs in
+flight together and the next step's behind them — the gather costs zero
+extra HBM traffic. The slot's last step fetches only the pages that hold
+a visible key and zeroes the V rows of the others (a buffer's old
+content, NaN included, must not reach ``p @ V``). Pages larger than the
+tile are read in equal parts; ``page_size`` must be a multiple of
+``block_k``, the unit of the visited count. Skipped rows still cost
+neither FLOPs nor HBM reads, and the heads-local/TP calling convention
+is unchanged.
 
 **Quantized variant (ISSUE 15).** Passing
 :class:`~mpit_tpu.ops.kv_quant.QuantizedKV` buffers (int8 payload +
@@ -71,6 +88,7 @@ kernel's numerical oracle, so tier-1 pins the per-tile dequant on CPU.
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -79,7 +97,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from mpit_tpu.ops.kv_quant import QuantizedKV
-from mpit_tpu.ops.ring_collectives import dequantize_blocks
+from mpit_tpu.ops.ring_collectives import dequantize_blocks, sublane_for
 
 __all__ = [
     "flash_decode_attention",
@@ -90,6 +108,8 @@ __all__ = [
     "reference_paged_decode_attention",
     "num_kv_blocks",
     "pick_block_k",
+    "DecodeTiling",
+    "decode_tiling",
 ]
 
 _NEG_INF = -1e30  # large-but-finite; exp underflows to exactly 0.0 in f32
@@ -160,9 +180,70 @@ def num_kv_blocks(lengths, t_q: int, s: int, block_k: int):
 
 
 # ---------------------------------------------------------------------------
-# Kernel. One grid program per slot; K/V stay in HBM and are DMA'd
-# tile-by-tile (double-buffered) so skipped tiles are never read.
+# Kernel. One grid program per slot; K/V stay in HBM and are DMA'd a tile
+# of one or several pieces a step (double-buffered) so skipped rows are
+# never read.
 # ---------------------------------------------------------------------------
+
+# Cache rows a loop step aims for (a paged step gathers pages). On the v5e
+# at GPT-2 large's 1,280 lanes, 16 slots of 64-950 rows, tiles of 128, 256
+# and 512 rows run a decode tick's call in 0.111 / 0.110 / 0.112 ms and a
+# 64-row chunk's in 0.340 / 0.232 / 0.228 (PERF.md, PR 27): 256 it is,
+# 1.25 MB of K and V a step, twice that double-buffered.
+_TILE_ROWS = 256
+# Most rows (T x heads padded to a VMEM tile) the heads-as-rows product
+# takes. Same measurement: T = 1 / 2 / 4 / 5 / 8 (32 to 256 rows) take
+# 0.110 / 0.115 / 0.136 / 0.149 / 0.191 ms a call as rows of one product
+# and T = 12 / 16 (384 / 512 rows) 0.252 / 0.318, against 0.25-0.28 a head
+# at a time whatever T: the forms meet near 430 rows. 256 keeps a
+# speculative verify of up to eight rows on the fast side and the float32
+# accumulator [rows, H*D] at 1.25 MB.
+_HEAD_ROWS = 256
+
+
+class DecodeTiling(NamedTuple):
+    """What one step of the kernel's loop over the cache fetches and how
+    it multiplies. ``piece_rows`` cache rows come by one DMA a buffer,
+    ``pieces`` of them make a step's tile; ``form`` is ``heads_as_rows``
+    (all heads the rows of one product a step) or ``per_head``."""
+
+    form: str
+    piece_rows: int
+    pieces: int
+
+    @property
+    def rows(self) -> int:
+        return self.piece_rows * self.pieces
+
+
+def decode_tiling(t_q: int, num_heads: int, dtype, *, block_k: int,
+                  page_size: int | None = None,
+                  quantized: bool = False) -> DecodeTiling:
+    """The tiling the kernel runs for a call of this shape, from the
+    shape alone. Dense: a step is ``block_k`` contiguous rows. Paged: a
+    step gathers consecutive pages of the slot's block table up to
+    :data:`_TILE_ROWS` rows, each page its own DMA into its rows of the
+    tile (a DMA's rows in a larger buffer start and end on a VMEM tile,
+    8 rows of float32, 16 of bf16, 32 of int8: a pool whose page is not
+    whole tiles keeps one page a step; a page
+    larger than the tile is read in equal parts). At few query rows the
+    heads are the rows of one product (``T`` groups of the heads padded
+    to a VMEM tile, :data:`_HEAD_ROWS` rows at most); above that, and for
+    an int8 pool (whose scales are per row and head), a product a head."""
+    sub = sublane_for(dtype)
+    if page_size is None:
+        piece, pieces = block_k, 1
+    else:
+        piece = min(page_size, _TILE_ROWS)
+        while page_size % piece:
+            piece -= 1
+        fits = piece == page_size and page_size % sub == 0
+        pieces = _TILE_ROWS // piece if fits else 1
+    head_rows = t_q * -(-num_heads // sub) * sub
+    as_rows = not quantized and head_rows <= _HEAD_ROWS
+    return DecodeTiling(
+        "heads_as_rows" if as_rows else "per_head", piece, pieces
+    )
 
 
 def _decode_kernel(
@@ -171,6 +252,7 @@ def _decode_kernel(
     num_heads,
     head_dim,
     scale,
+    tiling,
     page_size=None,
     quantized=False,
 ):
@@ -182,16 +264,33 @@ def _decode_kernel(
     ``b`` written by program ``b``), scratch. Paged adds ``bt_ref``
     [B, pages_per_slot] int32 SMEM after ``lengths_ref`` and the HBM
     operands become the [num_pages, page_size, H·D] pool — the ONLY
-    other difference is the DMA source: tile ``ki`` is resolved through
-    the block table instead of being a contiguous row slice. The flash
-    loop, masks and accumulators are byte-for-byte the same code.
+    other difference is the DMA source: a piece is resolved through the
+    block table instead of being a contiguous row slice. The flash
+    loop, masks and accumulators are the same code.
+
+    A loop step takes one tile of ``tiling.pieces`` pieces of
+    ``tiling.piece_rows`` rows, each piece one DMA a buffer, all of a
+    step's DMAs in flight together and the next step's behind them
+    (double buffer). Pieces past the slot's last visible key are not
+    fetched; their rows of the VALUE buffers are zeroed instead (what a
+    buffer held before is anything, NaN included, and ``0 x NaN`` in the
+    ``p @ V`` product is NaN; their scores are masked, which a select
+    does whatever K holds). ``block_k`` only counts: ``visited`` is the
+    number of ``block_k``-row blocks with a visible key.
+
+    ``tiling.form`` is ``heads_as_rows``: the block-diagonal query
+    ``[T·Hp, H·D]`` (row ``t·Hp + h`` holds ``q[t]``'s head ``h`` in its
+    own lanes, zero elsewhere) is built once, and a step is one scores
+    product, one online-softmax update and one ``p @ V`` product for all
+    heads: the added terms are exact zeros. Or ``per_head``: a product a
+    head over the same tile, each with its own statistics.
 
     ``quantized`` (ISSUE 15): the HBM operand list interleaves scale
     planes — ``k, k_scale, v, v_scale`` with scales [B, S, Hp] (dense)
     or [num_pages, page_size, Hp] (paged) f32, Hp = H lane-padded to 128
-    (:func:`_kv_operands`) — and the scratch grows matching
-    [2, block_k, Hp] double buffers on two extra DMA channels.
-    Each visited tile dequantizes in VMEM, per head, through the shared
+    (:func:`_kv_operands`) — and the scratch grows matching double
+    buffers on two extra DMA channels. Each visited tile dequantizes in
+    VMEM, per head, through the shared
     :func:`~mpit_tpu.ops.ring_collectives.dequantize_blocks`; the rest
     of the loop is identical, in f32 operands.
     """
@@ -221,65 +320,192 @@ def _decode_kernel(
     t_q = q_ref.shape[1]
     h_n, d = num_heads, head_dim
     length = lengths_ref[b]
+    piece, g = tiling.piece_rows, tiling.pieces
+    rows = piece * g
 
-    # Tiles with >= 1 visible key: ceil((L + T)/block_k), clamped to the
-    # buffer (a stale/retired slot's length can never overrun it; in the
-    # paged case the clamp also bounds the block-table index, so a stale
-    # table entry past the mapped pages is never resolved).
-    n_k = jnp.clip((length + t_q + block_k - 1) // block_k, 1, s // block_k)
-    visited_ref[b] = n_k
+    def blocks(unit):
+        # Units of ``unit`` rows with >= 1 visible key: ceil((L + T)/unit),
+        # clamped to the buffer (a stale/retired slot's length can never
+        # overrun it; in the paged case the clamp also bounds the
+        # block-table index, so a stale table entry past the mapped pages
+        # is never resolved).
+        return jnp.clip((length + t_q + unit - 1) // unit, 1, s // unit)
 
-    def dma(which_hbm, which_buf, sem_row, slot, ki):
+    visited_ref[b] = blocks(block_k)
+    n_pieces = blocks(piece)
+    n_steps = (n_pieces + g - 1) // g
+
+    def dma(which_hbm, which_buf, sem_row, slot, pi, j):
+        # Piece ``pi`` of the slot's cache into rows ``j * piece ..`` of
+        # the tile. ``piece`` divides the page (a piece never straddles
+        # pages), so one SMEM lookup names its page.
         if bt_ref is None:
-            src = which_hbm.at[b, pl.ds(ki * block_k, block_k)]
+            src = which_hbm.at[b, pl.ds(pi * piece, piece)]
+        elif piece == page_size:
+            src = which_hbm.at[bt_ref[b, pi]]
         else:
-            # page_size % block_k == 0 (validated at the call): a tile
-            # never straddles pages, so one SMEM lookup names its page.
-            page = bt_ref[b, (ki * block_k) // page_size]
-            src = which_hbm.at[page, pl.ds((ki * block_k) % page_size,
-                                           block_k)]
+            src = which_hbm.at[bt_ref[b, (pi * piece) // page_size],
+                               pl.ds((pi * piece) % page_size, piece)]
         return pltpu.make_async_copy(
-            src, which_buf.at[slot], sem.at[sem_row, slot]
+            src, which_buf.at[slot, rows_of(j)], sem.at[sem_row, slot]
         )
 
-    # The per-tile DMA channel set: K and V always; their scale planes
+    def rows_of(j):
+        start = j * piece
+        return pl.ds(
+            start if g == 1 else pl.multiple_of(start, piece), piece)
+
+    def each_piece(lo, hi, body):
+        """``body(j)`` for the tile's pieces ``lo <= j < hi``: a loop on
+        the device, or the one piece of a one-piece tile (whose bounds
+        are static)."""
+        if g == 1:
+            for j in range(lo, hi):
+                body(j)
+            return
+
+        def step(j, carry):
+            body(j)
+            return carry
+
+        lax.fori_loop(lo, hi, step, 0)
+
+    # The per-piece DMA channel set: K and V always; their scale planes
     # ride two more channels of the same double buffer when quantized.
     channels = [(k_hbm, k_buf, 0), (v_hbm, v_buf, 1)]
+    values = [v_buf]  # what reaches the p @ V product as it is
     if quantized:
         channels += [(ks_hbm, ks_buf, 2), (vs_hbm, vs_buf, 3)]
+        values += [vs_buf]
 
-    for hbm, buf, row in channels:
-        dma(hbm, buf, row, 0, 0).start()
+    def held(si):
+        """Pieces of step ``si`` that hold a visible key (all but in the
+        slot's last step)."""
+        return 1 if g == 1 else jnp.minimum(g, n_pieces - si * g)
 
-    t_pos = length + lax.broadcasted_iota(jnp.int32, (t_q, block_k), 0)
-
-    def body(ki, carry):
-        slot = lax.rem(ki, 2)
-
-        @pl.when(ki + 1 < n_k)
-        def _prefetch():
+    def fetch(si, slot):
+        """Start step ``si``'s DMAs into ``slot``; zero the value rows of
+        the pieces it does not fetch."""
+        def start(j):
             for hbm, buf, row in channels:
-                dma(hbm, buf, row, 1 - slot, ki + 1).start()
+                dma(hbm, buf, row, slot, si * g + j, j).start()
 
-        for hbm, buf, row in channels:
-            dma(hbm, buf, row, slot, ki).wait()
+        def zero(j):
+            for buf in values:
+                buf[slot, rows_of(j), :] = jnp.zeros(
+                    (piece, buf.shape[2]), buf.dtype)
 
-        k_pos = ki * block_k + lax.broadcasted_iota(
-            jnp.int32, (t_q, block_k), 1
+        each_piece(0, held(si), start)
+        each_piece(held(si), g, zero)
+
+    def arrive(si, slot):
+        """Wait for what :func:`fetch` started for step ``si``."""
+        def wait(j):
+            for hbm, buf, row in channels:
+                dma(hbm, buf, row, slot, si * g + j, j).wait()
+
+        each_piece(0, held(si), wait)
+
+    fetch(0, 0)
+
+    def tile(si):
+        """The body's first half, shared by both forms: the next step's
+        DMAs go out, this step's arrive."""
+        slot = lax.rem(si, 2)
+
+        @pl.when(si + 1 < n_steps)
+        def _prefetch():
+            fetch(si + 1, 1 - slot)
+
+        arrive(si, slot)
+        return slot
+
+    def k_pos(si, m):
+        return si * rows + lax.broadcasted_iota(jnp.int32, (m, rows), 1)
+
+    def update(m, l, acc, sc, v_blk):
+        """One online-softmax step on f32 scores ``sc`` (masked already):
+        statistics ``m``, ``l`` [M, 1], accumulator ``acc``."""
+        m_new = jnp.maximum(m, jnp.max(sc, axis=1, keepdims=True))
+        p = jnp.exp(sc - m_new)  # masked cols: exactly 0.0
+        alpha = jnp.exp(m - m_new)
+        l_new = alpha * l + jnp.sum(p, axis=1, keepdims=True)
+        acc_new = alpha * acc + lax.dot_general(
+            p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
         )
-        vis = t_pos >= k_pos  # key j visible to query t iff j <= L + t
-        out = []
-        for h in range(h_n):
-            m, l, acc = carry[3 * h], carry[3 * h + 1], carry[3 * h + 2]
+        return m_new, l_new, acc_new
+
+    def stats(m):
+        return (jnp.full((m, 1), _NEG_INF, jnp.float32),
+                jnp.zeros((m, 1), jnp.float32))
+
+    if tiling.form == "heads_as_rows":
+        w = h_n * d
+        sub = sublane_for(q_ref.dtype)
+        hp = -(-h_n // sub) * sub
+        m_rows = t_q * hp
+        # own[h, c]: lane c belongs to head h (rows past H own nothing).
+        lane = lax.broadcasted_iota(jnp.int32, (hp, w), 1)
+        head = lax.broadcasted_iota(jnp.int32, (hp, w), 0)
+        own = (lane >= head * d) & (lane < (head + 1) * d)
+        # The select runs on 32-bit lanes (a mask has float32's tiling)
+        # and is exact; the operand goes back to the input dtype.
+        q = q_ref[0].astype(jnp.float32)
+        qbd = jnp.concatenate([
+            jnp.where(own, jnp.broadcast_to(q[t : t + 1], (hp, w)), 0.0)
+            for t in range(t_q)
+        ], axis=0).astype(q_ref.dtype)  # [T*Hp, W]
+        # Row t*Hp + h is query t: visible keys are j <= L + t.
+        row = lax.broadcasted_iota(jnp.int32, (m_rows, rows), 0)
+        t_pos = length + sum(
+            ((row >= t * hp).astype(jnp.int32) for t in range(1, t_q)),
+            jnp.zeros((m_rows, rows), jnp.int32),
+        )
+
+        def body(si, carry):
+            slot = tile(si)
             # Matmul operands stay in the INPUT dtype (bf16 serving path)
             # with f32 accumulation; softmax statistics stay f32 and the
             # scale folds into the f32 scores (training-kernel idiom).
+            sc = lax.dot_general(
+                qbd, k_buf[slot], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale  # [T*Hp, rows] f32
+            sc = jnp.where(t_pos >= k_pos(si, m_rows), sc, _NEG_INF)
+            return update(*carry, sc, v_buf[slot])
+
+        init = stats(m_rows) + (jnp.zeros((m_rows, w), jnp.float32),)
+        _, l, acc = lax.fori_loop(0, n_steps, body, init)
+        # Key 0 is visible to every query (L >= 0), so no row of a real
+        # head is ever fully masked; the guard only keeps a malformed
+        # call finite.
+        l = jnp.where(l == 0.0, 1.0, l)
+        for t in range(t_q):
+            part = slice(t * hp, (t + 1) * hp)
+            # Head h's output is row h of its own lanes: the other rows'
+            # entries in those lanes are other heads' weights on this
+            # head's values, dropped here.
+            num = jnp.sum(jnp.where(own, acc[part], 0.0), axis=0,
+                          keepdims=True)
+            den = jnp.sum(jnp.where(own, l[part], 0.0), axis=0,
+                          keepdims=True)
+            o_ref[0, t : t + 1, :] = (num / den).astype(o_ref.dtype)
+        return
+
+    t_pos = length + lax.broadcasted_iota(jnp.int32, (t_q, rows), 0)
+
+    def body(si, carry):
+        slot = tile(si)
+        vis = t_pos >= k_pos(si, t_q)  # key j visible to query t iff j <= L + t
+        out = []
+        for h in range(h_n):
             q = q_ref[0, :, h * d : (h + 1) * d]  # [T, d]
-            k_blk = k_buf[slot, :, h * d : (h + 1) * d]  # [bk, d]
+            k_blk = k_buf[slot, :, h * d : (h + 1) * d]  # [rows, d]
             v_blk = v_buf[slot, :, h * d : (h + 1) * d]
             if quantized:
                 # Fused per-tile dequant (ISSUE 15): the int8 tile and
-                # its [bk, H] scale block are already in VMEM; the f32
+                # its [rows, H] scale block are already in VMEM; the f32
                 # view exists only at tile size, per head — the shared
                 # PR 9 contract's inverse, operands f32 from here on.
                 k_blk = dequantize_blocks(
@@ -292,37 +518,20 @@ def _decode_kernel(
             sc = lax.dot_general(
                 q, k_blk, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
-            ) * scale  # [T, bk] f32
+            ) * scale  # [T, rows] f32
             sc = jnp.where(vis, sc, _NEG_INF)
-            m_new = jnp.maximum(m, jnp.max(sc, axis=1))
-            p = jnp.exp(sc - m_new[:, None])  # masked cols: exactly 0.0
-            alpha = jnp.exp(m - m_new)
-            l_new = alpha * l + jnp.sum(p, axis=1)
-            acc_new = alpha[:, None] * acc + lax.dot_general(
-                p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            out += [m_new, l_new, acc_new]
+            out += update(*carry[3 * h : 3 * h + 3], sc, v_blk)
         return tuple(out)
 
     init = []
     for _ in range(h_n):
-        init += [
-            jnp.full((t_q,), _NEG_INF, jnp.float32),
-            jnp.zeros((t_q,), jnp.float32),
-            jnp.zeros((t_q, d), jnp.float32),
-        ]
-    carry = lax.fori_loop(0, n_k, body, tuple(init))
+        init += stats(t_q) + (jnp.zeros((t_q, d), jnp.float32),)
+    carry = lax.fori_loop(0, n_steps, body, tuple(init))
 
     for h in range(h_n):
-        l = carry[3 * h + 1]
-        acc = carry[3 * h + 2]
-        # Key 0 is visible to every query (L >= 0), so no row is ever
-        # fully masked; the guard only keeps a malformed call finite.
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, :, h * d : (h + 1) * d] = (
-            acc / l_safe[:, None]
-        ).astype(o_ref.dtype)
+        l, acc = carry[3 * h + 1 : 3 * h + 3]
+        l_safe = jnp.where(l == 0.0, 1.0, l)  # as above
+        o_ref[0, :, h * d : (h + 1) * d] = (acc / l_safe).astype(o_ref.dtype)
 
 
 def _vma(x):
@@ -361,13 +570,14 @@ def _kv_operands(k, v, pk=lambda x: x):
     return quantized, ops, [jnp.int8, jnp.float32, jnp.int8, jnp.float32]
 
 
-def _scratch_for(quantized, block_k, hd, h, dtypes):
-    """Double-buffer VMEM scratch matching :func:`_kv_operands`' order
-    (+ the DMA semaphore array sized to the channel count)."""
+def _scratch_for(quantized, rows, hd, h, dtypes):
+    """Double-buffer VMEM scratch matching :func:`_kv_operands`' order,
+    a step's tile of ``rows`` each (+ the DMA semaphore array sized to
+    the channel count)."""
     hp = _lane_pad(h)
     widths = [hd, hp, hd, hp] if quantized else [hd, hd]
     bufs = [
-        pltpu.VMEM((2, block_k, w), dt) for w, dt in zip(widths, dtypes)
+        pltpu.VMEM((2, rows, w), dt) for w, dt in zip(widths, dtypes)
     ]
     return bufs + [pltpu.SemaphoreType.DMA((len(widths), 2))]
 
@@ -379,12 +589,16 @@ def _decode_call(q, k, v, lengths, *, block_k, interpret):
     pk = lambda x: x.reshape(x.shape[0], x.shape[1], -1)  # head-pack
     with jax.named_scope("kv_gather"):
         quantized, kv_ops, kv_dtypes = _kv_operands(k, v, pk)
+    tiling = decode_tiling(
+        t, h, kv_dtypes[0], block_k=block_k, quantized=quantized
+    )
     kern = functools.partial(
         _decode_kernel,
         block_k=block_k,
         num_heads=h,
         head_dim=d,
         scale=1.0 / (d ** 0.5),
+        tiling=tiling,
         quantized=quantized,
     )
     o, visited = pl.pallas_call(
@@ -412,7 +626,7 @@ def _decode_call(q, k, v, lengths, *, block_k, interpret):
             jax.ShapeDtypeStruct((b, t, hd), q.dtype, vma=_vma(q)),
             jax.ShapeDtypeStruct((b,), jnp.int32, vma=_vma(q)),
         ],
-        scratch_shapes=_scratch_for(quantized, block_k, hd, h, kv_dtypes),
+        scratch_shapes=_scratch_for(quantized, tiling.rows, hd, h, kv_dtypes),
         interpret=bool(interpret),
     )(jnp.asarray(lengths, jnp.int32), pk(q), *kv_ops)
     return o.reshape(b, t, h, d), visited
@@ -430,12 +644,17 @@ def _paged_decode_call(
     with jax.named_scope("kv_gather"):
         # The pools are stored as the kernel reads them: no repacking.
         quantized, kv_ops, kv_dtypes = _kv_operands(k_pool, v_pool)
+    tiling = decode_tiling(
+        t, h, kv_dtypes[0], block_k=block_k, page_size=page_size,
+        quantized=quantized,
+    )
     kern = functools.partial(
         _decode_kernel,
         block_k=block_k,
         num_heads=h,
         head_dim=d,
         scale=1.0 / (d ** 0.5),
+        tiling=tiling,
         page_size=page_size,
         quantized=quantized,
     )
@@ -464,7 +683,7 @@ def _paged_decode_call(
             jax.ShapeDtypeStruct((b, t, hd), q.dtype, vma=_vma(q)),
             jax.ShapeDtypeStruct((b,), jnp.int32, vma=_vma(q)),
         ],
-        scratch_shapes=_scratch_for(quantized, block_k, hd, h, kv_dtypes),
+        scratch_shapes=_scratch_for(quantized, tiling.rows, hd, h, kv_dtypes),
         interpret=bool(interpret),
     )(
         jnp.asarray(lengths, jnp.int32),
@@ -672,12 +891,13 @@ def flash_paged_decode_attention(
     shape and a ``[num_pages, page_size, H]`` scale plane.
 
     Drop-in for :func:`mpit_tpu.models.gpt2.paged_cached_attention`
-    (plug in as ``GPT2Config.paged_attention_fn``). The tile loop and
+    (plug in as ``GPT2Config.paged_attention_fn``). The loop and
     skipping are exactly :func:`flash_decode_attention`'s over the
-    slot's virtual ``pages_per_slot × page_size`` cache; only the DMA
-    source indirects through the table. ``block_k`` defaults to the
-    largest :func:`pick_block_k` choice for ``page_size`` and must
-    divide it (a tile never straddles pages). ``interpret`` /
+    slot's virtual ``pages_per_slot × page_size`` cache; a step's tile
+    is gathered through the table, several pages at once
+    (:func:`decode_tiling`). ``block_k``, the unit ``visited`` counts
+    in, defaults to the :func:`pick_block_k` choice for ``page_size``
+    and must divide it. ``interpret`` /
     ``return_visited`` as in :func:`flash_decode_attention` (the
     non-TPU fallback is the gather-dense reference)."""
     page_size = k_pool.shape[1]

@@ -537,6 +537,7 @@ class Engine:
         self.max_len = min(max_len or cfg.max_seq_len, cfg.max_seq_len)
         self.prefill_len = min(prefill_len or self.max_len, self.max_len)
         self.tp_axis = tp_axis
+        self._tp_ways = 1
         self._key = jax.random.key(seed)
         self._sub = None  # the next subkey, where it was split ahead
         self._staged = {}  # a decode tick's small inputs as last staged
@@ -690,8 +691,9 @@ class Engine:
         # -- serving hot-loop shape (ISSUE 5): attention kernel + head --
         self.decode_attention = decode_attention
         if self.paged:
-            # Tiles must never straddle pages: block_k divides page_size
-            # (one SMEM block-table lookup names a tile's page).
+            # block_k, the unit the visited count and the bytes model
+            # count in, divides page_size (the kernel's own tile is
+            # several whole pages: ops.decode_attention.decode_tiling).
             self.decode_block_k = pick_block_k(self.page_size, decode_block_k)
             if self.page_size % self.decode_block_k:
                 raise ValueError(
@@ -800,7 +802,7 @@ class Engine:
                 raise ValueError("tp_axis requires a World")
             from mpit_tpu.parallel.megatron import repack_qkv
 
-            p = world.axis_size(tp_axis)
+            p = self._tp_ways = world.axis_size(tp_axis)
             if cfg.num_heads % p:
                 raise ValueError(
                     f"num_heads ({cfg.num_heads}) must divide TP={p}"
@@ -2236,6 +2238,23 @@ class Engine:
             out[phase] = cost
         self.roofline_costs = out
         return out
+
+    def attention_tiling(self, t_q: int) -> dict:
+        """Span attributes of a step of ``t_q`` query rows a slot: which
+        form of the decode kernel it compiled to (``attention_form``:
+        ``heads_as_rows`` or ``per_head``) and the cache rows one step of
+        its loop takes (``attention_rows``). Static per compiled step.
+        Empty where no kernel runs (the reference attention, the lax twin
+        off the TPU) or the family's kernel does not say."""
+        if self.decode_attention_mode != "kernel":
+            return {}
+        return self.model.attention_tiling(
+            t_q, block_k=self.decode_block_k,
+            page_size=self.page_size if self.paged else None,
+            kv_dtype=jnp.int8 if self.kv_quantized else (
+                self._cache_dtype or self.cfg.dtype),
+            tp=self._tp_ways,
+        )
 
     def decode_achieved_hbm_bytes(
         self, live_lens, t_q: int = 1, *, include_params: bool = True

@@ -381,6 +381,16 @@ class Server:
         # accepted_tokens_per_tick / draft_acceptance_rate (what the
         # gpt2_serve record line carries).
         self._spec = int(getattr(engine, "spec_k", 0) or 0)
+        # Which form of the attention kernel each kind of step compiled
+        # to and the cache rows a step of its loop takes (static per
+        # compiled step; empty where no kernel runs): a trace says
+        # whether the fast tiling engaged.
+        tiling = getattr(engine, "attention_tiling", lambda t_q: {})
+        self._decode_tiling = tiling(self._spec + 1)
+        self._prefill_tiling = tiling(
+            engine.prefill_chunk if self._paged
+            else getattr(engine, "prefill_len", 1)
+        )
         self._spec_emitted = 0
         self._spec_active_ticks = 0
         self._spec_drafted = 0
@@ -983,7 +993,7 @@ class Server:
                 attention=self._attn_mode,
                 sampler=self._sampler,
                 rids=[live.req.rid for live in self.prefilling.values()],
-                **self._kv_attrs,
+                **self._kv_attrs, **self._prefill_tiling,
             )
         with obs.span("prefill", **attrs):
             first = eng.prefill_paged(
@@ -1124,7 +1134,7 @@ class Server:
                 # trace args) — one request's lifeline is filterable in
                 # Perfetto.
                 rids=[live.req.rid for _, live in batch],
-                **self._kv_attrs,
+                **self._kv_attrs, **self._prefill_tiling,
             )
         with obs.span("prefill", **attrs):
             first = self.engine.prefill(
@@ -1283,7 +1293,7 @@ class Server:
         with obs.span(
             "decode", active=n_live, attention=self._attn_mode,
             sampler=self._sampler, spec_k=k, rids=rids,
-            **self._kv_attrs,
+            **self._kv_attrs, **self._decode_tiling,
         ):
             with obs.span(
                 "spec_draft", active=n_live, attention=self._attn_mode,
@@ -1294,6 +1304,7 @@ class Server:
             with obs.span(
                 "spec_verify", active=n_live, attention=self._attn_mode,
                 sampler=self._sampler, rids=rids, **self._kv_attrs,
+                **self._decode_tiling,
             ):
                 emit, n_emit, n_acc = eng.spec_verify(
                     active, self._temp, self._topk, budget, eos
@@ -1410,7 +1421,7 @@ class Server:
                 cache_rows=sum(
                     live.cache_fill() for live in self.live.values()
                 ),
-                **self._kv_attrs,
+                **self._kv_attrs, **self._decode_tiling,
             )
         t0 = time.perf_counter()
         with obs.span("decode", **attrs):

@@ -118,25 +118,37 @@ def test_flash_decode_dense(v5e, kv_dtype, t):
     )
 
 
-@pytest.mark.parametrize("t", [1, 64, 5])
+# The serve CLI's shape above, and `gpt2l-serve-offline-decode`'s own:
+# GPT-2 large's 20 heads of 64 over 16 slots of 64 pages (a decode tick
+# multiplies all heads at once over a tile of 16 pages, a chunk a head at
+# a time): Mosaic's verdict on the page DMAs into a tile's rows and on
+# the VMEM the tile takes, before the chip is asked.
+_PAGED_SHAPES = {"small": (B, H, S // PAGE), "large": (16, 20, 64)}
+
+
+@pytest.mark.parametrize(
+    "shape,t",
+    [("small", 1), ("small", 64), ("small", 5), ("large", 1), ("large", 64)],
+)
 @pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
-def test_flash_decode_paged(v5e, kv_dtype, t):
+def test_flash_decode_paged(v5e, kv_dtype, shape, t):
     # One layer's pool as stored: rows packed [pages, page, H*D], an
     # int8 pool's scale plane [pages, page, H].
-    pages = B * S // PAGE
-    pool = _sds((pages, PAGE, H * D), jnp.bfloat16)
+    b, h, pps = _PAGED_SHAPES[shape]
+    pages = b * pps
+    pool = _sds((pages, PAGE, h * D), jnp.bfloat16)
     if kv_dtype == "int8":
         pool = QuantizedKV(
-            q=_sds((pages, PAGE, H * D), jnp.int8),
-            scale=_sds((pages, PAGE, H), jnp.float32),
+            q=_sds((pages, PAGE, h * D), jnp.int8),
+            scale=_sds((pages, PAGE, h), jnp.float32),
         )
     _compile_on_chip(
         v5e,
         lambda q, k, v, n, bt: flash_paged_decode_attention(
             q, k, v, n, bt, interpret=False, return_visited=True
         ),
-        _sds((B, t, H, D), jnp.bfloat16), pool, pool,
-        _sds((B,), jnp.int32), _sds((B, S // PAGE), jnp.int32),
+        _sds((b, t, h, D), jnp.bfloat16), pool, pool,
+        _sds((b,), jnp.int32), _sds((b, pps), jnp.int32),
     )
 
 

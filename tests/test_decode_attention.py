@@ -42,6 +42,7 @@ from mpit_tpu.models.gpt2 import (
 )
 from mpit_tpu.ops import lm_head_sample
 from mpit_tpu.ops.decode_attention import (
+    decode_tiling,
     flash_decode_attention,
     flash_paged_decode_attention,
     num_kv_blocks,
@@ -160,6 +161,20 @@ def _paged_setup(B=3, T=1, H=2, D=16, n_pages=12, ps=8, pages_per_slot=4,
     bt = rng.randint(0, n_pages, size=(B, pages_per_slot)).astype(np.int32)
     bt[2] = bt[0]  # slot 2 maps slot 0's pages (prefix sharing shape)
     return q, kp, vp, jnp.asarray(bt)
+
+
+# name: (query rows T, heads of 64 lanes, pool dtype, the form the kernel
+# takes). 20 heads are GPT-2 large's row, 10 a TP rank's half of it.
+_TILE_CASES = {
+    "T1-h20-f32": (1, 20, jnp.float32, "heads_as_rows"),
+    "T4-h20-f32": (4, 20, jnp.float32, "heads_as_rows"),
+    "T64-h20-f32": (64, 20, jnp.float32, "per_head"),
+    "T1-h10-f32": (1, 10, jnp.float32, "heads_as_rows"),
+    "T64-h10-f32": (64, 10, jnp.float32, "per_head"),
+    "T1-h20-bf16": (1, 20, jnp.bfloat16, "heads_as_rows"),
+    "T4-h20-bf16": (4, 20, jnp.bfloat16, "heads_as_rows"),
+    "T64-h20-bf16": (64, 20, jnp.bfloat16, "per_head"),
+}
 
 
 class TestPagedFlashDecode:
@@ -290,6 +305,61 @@ class TestPagedFlashDecode:
         np.testing.assert_allclose(
             np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5
         )
+
+    @pytest.mark.parametrize("nan_pages", [False, True], ids=["", "nan"])
+    @pytest.mark.parametrize("case", sorted(_TILE_CASES))
+    def test_kernel_matches_reference_on_a_tile_of_pages(
+        self, case, nan_pages
+    ):
+        """A step of the loop gathers several pages into one tile (16
+        pages of 16 rows here) and, at few query rows, multiplies all
+        heads at once. Contexts that end inside a page, at a page's end,
+        inside and at the end of a tile, and in the first page; pages out
+        of order and shared between slots; GPT-2 large's row of 1,280
+        lanes and a TP rank's 640. With ``nan_pages`` every page that
+        holds no visible key of a slot that maps it is NaN: what the
+        kernel does not fetch must not reach a product as the buffer
+        held it (``0 x NaN``), on the first program least of all, whose
+        buffers nothing has written yet."""
+        t, h, dtype, form = _TILE_CASES[case]
+        ps, pps = 16, 36
+        # L + T visible keys: 4 (first program: untouched buffers), a
+        # page's end, inside a page, a tile's end, inside the second
+        # tile, the second tile's end, the whole table, one key.
+        ends = [4, 32, 23, 256, 300, 512, ps * pps, 1]
+        lengths = jnp.asarray([max(e - t, 0) for e in ends], jnp.int32)
+        q, kp, vp, bt = _paged_setup(
+            B=len(ends), T=t, H=h, D=64, n_pages=150, ps=ps,
+            pages_per_slot=pps, seed=t, dtype=dtype,
+        )
+        kp, vp = kp * 0.25, vp  # scores of unit scale over 64 lanes
+        if nan_pages:
+            seen = np.zeros(kp.shape[0], bool)
+            n_vis = -(-(np.asarray(lengths) + t) // ps)
+            for row, n in zip(np.asarray(bt), n_vis):
+                seen[row[:n]] = True
+            hole = jnp.asarray(~seen)[:, None, None]
+            kp = jnp.where(hole, jnp.nan, kp)
+            vp = jnp.where(hole, jnp.nan, vp)
+        tiling = decode_tiling(t, h, dtype, block_k=8, page_size=ps)
+        assert (tiling.form, tiling.rows) == (form, 256)
+        # The reference gathers whole tables: judge it on a finite pool
+        # (the masked rows weigh exactly 0 there, as here).
+        ref = reference_paged_decode_attention(
+            q, jnp.nan_to_num(kp), jnp.nan_to_num(vp), lengths, bt
+        )
+        out, visited = flash_paged_decode_attention(
+            q, kp, vp, lengths, bt, block_k=8, interpret=True,
+            return_visited=True,
+        )
+        tol = 2e-5 if dtype == jnp.float32 else 0.05
+        assert np.isfinite(np.asarray(out, np.float32)).all()
+        np.testing.assert_allclose(
+            np.asarray(out, np.float32), np.asarray(ref, np.float32),
+            rtol=tol, atol=tol,
+        )
+        host = num_kv_blocks(np.asarray(lengths), t, ps * pps, 8)
+        assert list(np.asarray(visited)) == list(host)
 
     def test_kernel_prefill_tail_small_t(self):
         q, kp, vp, bt = _paged_setup(T=4)

@@ -610,6 +610,50 @@ class TestServeKernelObservability:
         # Short contexts in a 32-row cache must have skipped tiles.
         assert summ["counters"]["decode_blocks_skipped"] > 0
 
+    @pytest.mark.parametrize(
+        "how,decode,prefill",
+        [
+            # Dense: a step is block_k contiguous rows; two heads padded
+            # to a float32 tile are 8 rows a query, 64 for a prefill of 8.
+            ({}, ("heads_as_rows", 8), ("heads_as_rows", 8)),
+            # Paged: a step gathers pages up to 256 rows; a chunk of 40
+            # rows is 320 rows of heads, past the bound.
+            (dict(kv_pages=12, kv_page_size=8, prefill_chunk=4),
+             ("heads_as_rows", 256), ("heads_as_rows", 256)),
+            (dict(kv_pages=12, kv_page_size=8, prefill_len=40, max_len=48),
+             ("heads_as_rows", 256), ("per_head", 256)),
+            # An int8 pool dequantises a head at a time, and its page of
+            # 8 rows is no int8 tile: one page a step.
+            (dict(kv_pages=12, kv_page_size=8, kv_dtype="int8"),
+             ("per_head", 8), ("per_head", 8)),
+        ],
+        ids=["dense", "paged", "paged-long-chunk", "paged-int8"],
+    )
+    def test_spans_say_which_form_of_the_kernel_ran(
+        self, model_and_params, how, decode, prefill
+    ):
+        """Beside ``attention`` the ``decode`` and ``prefill`` spans say
+        which tiling their step compiled to and the cache rows a step of
+        its loop takes: static per compiled step."""
+        _, params = model_and_params
+        rec = obs.Recorder()
+        with obs.local_recorder(rec):
+            engine = Engine(
+                CFG, params, decode_attention="interpret",
+                **{**dict(slots=2, max_len=32, prefill_len=8), **how},
+            )
+            server = Server(engine)
+            server.submit(Request(rid=0, prompt=PROMPTS[0], max_new_tokens=3))
+            server.run()
+            labels = {
+                name: rec.summary()["phases"][name]["labels"]
+                for name in ("decode", "prefill")
+            }
+        for name, (form, rows) in (("decode", decode), ("prefill", prefill)):
+            assert labels[name]["attention_form"] == [form]
+            spans = [e for e in rec.snapshot()["events"] if e[1] == name]
+            assert {e[5]["attention_rows"] for e in spans} == {rows}
+
     def test_reference_mode_labels_and_no_skip_counter(
         self, model_and_params
     ):
@@ -628,6 +672,7 @@ class TestServeKernelObservability:
             "reference"
         ]
         assert summ["phases"]["decode"]["labels"]["sampler"] == ["dense"]
+        assert "attention_form" not in summ["phases"]["decode"]["labels"]
         assert "decode_blocks_skipped" not in summ["counters"]
 
 
