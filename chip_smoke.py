@@ -376,8 +376,7 @@ def _paged_kernel_vs_reference(engine, seed: int) -> dict:
             quantized = isinstance(kp, QuantizedKV)
             tiling = decode_tiling(
                 t, cfg.num_heads, kp.q.dtype if quantized else kp.dtype,
-                block_k=engine.decode_block_k, page_size=engine.page_size,
-                quantized=quantized,
+                page_size=engine.page_size, quantized=quantized,
             )
             forms[f"{name}_T{t}"] = [tiling.form, tiling.rows]
             assert np.array_equal(np.asarray(visited), tiles), (visited, tiles)

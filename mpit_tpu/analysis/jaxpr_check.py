@@ -228,50 +228,9 @@ def _tiny_model():
 
 def _contract_decode_blocked(ctx):
     """Blocked head + flash decode: the [slots, vocab] f32 logits and
-    the dense [slots, H, 1, max_len] score tensor never exist in the
-    decode jaxpr — and the dense reference DOES produce them (the pin
-    is non-vacuous). Also: no host-transfer primitives in the step."""
-    import jax
-    import jax.numpy as jnp
-
-    from mpit_tpu.serve import Engine
-
-    cfg, params = ctx["model"]
-    slots, max_len = 2, 32
-    eng = Engine(
-        cfg, params, slots=slots, max_len=max_len, prefill_len=8,
-        decode_attention="interpret", sample_block=32, sample_k_cap=16,
-    )
-    jx = jax.make_jaxpr(eng._decode_step)(
-        eng.params, eng.cache, eng.last_token,
-        jnp.ones((slots,), bool), jax.random.key(0),
-        jnp.zeros((slots,), jnp.float32), jnp.zeros((slots,), jnp.int32),
-    )
-    assert_no_intermediate(
-        jx,
-        (slots, cfg.vocab_size),
-        (slots, 1, cfg.vocab_size),
-        (slots, cfg.num_heads, 1, max_len),
-        what="blocked decode step",
-    )
-    assert_no_transfer(jx, what="blocked decode step")
-    ref = Engine(
-        cfg, params, slots=slots, max_len=max_len, prefill_len=8,
-        decode_attention="reference",
-    )
-    jx_ref = jax.make_jaxpr(ref._decode_step)(
-        ref.params, ref.cache, ref.last_token,
-        jnp.ones((slots,), bool), jax.random.key(0),
-        jnp.zeros((slots,), jnp.float32), jnp.zeros((slots,), jnp.int32),
-    )
-    assert_intermediate(
-        jx_ref, (slots, 1, cfg.vocab_size), what="dense reference decode"
-    )
-
-
-def _contract_paged_decode_blocked(ctx):
-    """The blocked-logits pin survives paging (ISSUE 7 regression
-    surface: the paged decode step is a different trace)."""
+    the gathered [slots, H, 1, max_len] score tensor never exist in the
+    decode jaxpr — and the reference engine DOES produce the logits (the
+    pin is non-vacuous). Also: no host-transfer primitives in the step."""
     import jax
     import jax.numpy as jnp
 
@@ -279,25 +238,38 @@ def _contract_paged_decode_blocked(ctx):
 
     cfg, params = ctx["model"]
     slots = 2
+
+    def decode_jaxpr(eng):
+        return jax.make_jaxpr(eng._paged_decode_step)(
+            eng.params, eng.cache, eng.last_token,
+            jnp.ones((slots,), bool),
+            jnp.zeros((slots, eng.pages_per_slot), jnp.int32),
+            jax.random.key(0),
+            jnp.zeros((slots,), jnp.float32), jnp.zeros((slots,), jnp.int32),
+        )
+
     eng = Engine(
         cfg, params, slots=slots, max_len=40, prefill_len=8,
         kv_pages=24, kv_page_size=8, decode_attention="interpret",
         sample_block=32, sample_k_cap=16,
     )
-    bt = jnp.zeros((slots, eng.pages_per_slot), jnp.int32)
-    jx = jax.make_jaxpr(eng._paged_decode_step)(
-        eng.params, eng.cache, eng.last_token,
-        jnp.ones((slots,), bool), bt, jax.random.key(0),
-        jnp.zeros((slots,), jnp.float32), jnp.zeros((slots,), jnp.int32),
-    )
+    jx = decode_jaxpr(eng)
     assert_no_intermediate(
         jx,
         (slots, cfg.vocab_size),
         (slots, 1, cfg.vocab_size),
         (slots, cfg.num_heads, 1, eng.max_len),
-        what="paged decode step",
+        what="blocked decode step",
     )
-    assert_no_transfer(jx, what="paged decode step")
+    assert_no_transfer(jx, what="blocked decode step")
+    ref = Engine(
+        cfg, params, slots=slots, max_len=40, prefill_len=8,
+        kv_pages=24, kv_page_size=8, decode_attention="reference",
+    )
+    assert_intermediate(
+        decode_jaxpr(ref), (slots, 1, cfg.vocab_size),
+        what="reference decode",
+    )
 
 
 def _contract_quantized_decode(ctx):
@@ -359,25 +331,6 @@ def _contract_quantized_decode(ctx):
         what="quantized reference decode (dequant oracle)",
         dtype=f32,
     )
-    # Dense form: the quantized dense step never materializes the f32
-    # per-slot buffer either (its int8 buffer carries the shape).
-    dense = Engine(
-        cfg, params, slots=slots, max_len=32, prefill_len=8,
-        decode_attention="interpret", sample_block=32, sample_k_cap=16,
-        kv_dtype="int8",
-    )
-    jxd = jax.make_jaxpr(dense._decode_step)(
-        dense.params, dense.cache, dense.last_token,
-        jnp.ones((slots,), bool), jax.random.key(0),
-        jnp.zeros((slots,), jnp.float32), jnp.zeros((slots,), jnp.int32),
-    )
-    assert_no_intermediate(
-        jxd,
-        (slots, 32, cfg.num_heads, cfg.head_dim),
-        (cfg.num_layers, slots, 32, cfg.num_heads, cfg.head_dim),
-        what="quantized dense decode step",
-        dtype=f32,
-    )
 
 
 def _contract_quantized_weights(ctx):
@@ -413,10 +366,13 @@ def _contract_quantized_weights(ctx):
         (cfg.vocab_size, cfg.d_model),   # wte / tied head
     )
 
+    block_tables = lambda eng: jnp.zeros(
+        (slots, eng.pages_per_slot), jnp.int32)
+
     def decode_jaxpr(eng):
-        return jax.make_jaxpr(eng._decode_step)(
+        return jax.make_jaxpr(eng._paged_decode_step)(
             eng.params, eng.cache, eng.last_token,
-            jnp.ones((slots,), bool), jax.random.key(0),
+            jnp.ones((slots,), bool), block_tables(eng), jax.random.key(0),
             jnp.zeros((slots,), jnp.float32),
             jnp.zeros((slots,), jnp.int32),
         )
@@ -440,6 +396,7 @@ def _contract_quantized_weights(ctx):
         spec.draft_params, spec.draft_cache, spec.last_token,
         jnp.ones((slots,), bool), jax.random.key(0),
         jnp.zeros((slots,), jnp.float32), jnp.zeros((slots,), jnp.int32),
+        block_tables(spec), jnp.zeros((slots,), jnp.int32),
     )
     assert_no_intermediate(
         jxd, *weights, what="int8-weights spec_draft step", dtype=f32
@@ -541,7 +498,6 @@ def _contract_train_step_donation(ctx):
 
 CONTRACTS = {
     "decode-blocked": _contract_decode_blocked,
-    "paged-decode-blocked": _contract_paged_decode_blocked,
     "quantized-decode": _contract_quantized_decode,
     "quantized-weights": _contract_quantized_weights,
     "lm-head-sample": _contract_lm_head_sample,

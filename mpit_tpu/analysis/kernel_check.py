@@ -413,10 +413,7 @@ def check_plan_geometry() -> list:
     ):
         if quant != (dt == "int8"):
             continue
-        til = da.decode_tiling(
-            t, h, dt, block_k=pick_block_k(page), page_size=page,
-            quantized=quant,
-        )
+        til = da.decode_tiling(t, h, dt, page_size=page, quantized=quant)
         what = f"decode_tiling(T={t}, H={h}, {dt}, page={page}) = {til}"
         sub = rc.sublane_for(dt)
         if page % til.piece_rows or til.pieces < 1:
@@ -467,29 +464,28 @@ def check_plan_geometry() -> list:
                 f"default 4 MB bucket (p={p}) exceeds the cap"
             )
 
-    # The host wrappers actually raise the divisibility preconditions
-    # the kernels rely on (a tile must never straddle a page).
+    # The host wrapper actually raises the divisibility precondition
+    # the kernel relies on (a tile must never straddle a page).
     import inspect
 
     from mpit_tpu.ops import decode_attention as da
 
-    for fname in ("flash_decode_attention", "flash_paged_decode_attention"):
-        src = inspect.getsource(getattr(da, fname))
-        tree = ast.parse(src)
-        has_guard = any(
-            isinstance(n, ast.If)
-            and any(isinstance(r, ast.Raise) for r in ast.walk(n))
-            and any(
-                isinstance(b, ast.BinOp) and isinstance(b.op, ast.Mod)
-                for b in ast.walk(n.test)
-            )
-            for n in ast.walk(tree)
+    src = inspect.getsource(da.flash_paged_decode_attention)
+    has_guard = any(
+        isinstance(n, ast.If)
+        and any(isinstance(r, ast.Raise) for r in ast.walk(n))
+        and any(
+            isinstance(b, ast.BinOp) and isinstance(b.op, ast.Mod)
+            for b in ast.walk(n.test)
         )
-        if not has_guard:
-            bad(
-                f"{fname} no longer raises on a non-dividing block_k — "
-                "the kernel's tile loop would straddle tiles/pages"
-            )
+        for n in ast.walk(ast.parse(src))
+    )
+    if not has_guard:
+        bad(
+            "flash_paged_decode_attention no longer raises on a "
+            "non-dividing block_k — the kernel's tile loop would "
+            "straddle pages"
+        )
     return out
 
 

@@ -176,7 +176,6 @@ DEFAULT_CONFIG = LintConfig(
             "Server._run_tick",
         },
         "mpit_tpu/serve/engine.py": {
-            "Engine.prefill",
             "Engine.prefill_paged",
             "Engine.decode",
             "Engine.spec_draft",
@@ -196,8 +195,7 @@ DEFAULT_CONFIG = LintConfig(
     ledger_seams={
         "mpit_tpu/serve/scheduler.py": {
             "Server.submit",
-            "Server._admit_paged",
-            "Server._admit_dense",
+            "Server._admit",
             "Server._preempt",
             "Server._prefill_chunk_tick",
             "Server._decode_tick",

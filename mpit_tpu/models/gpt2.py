@@ -17,7 +17,7 @@ Built TPU-first and parallelism-aware:
   layernorms in float32;
 - ``jax.named_scope`` at the seams a device trace is read by
   (``embed``; per block ``attn`` with ``kv_write`` / ``kv_gather`` inside
-  it on the cache paths, and ``mlp``; ``lm_head``): an operation's
+  it on the cache path, and ``mlp``; ``lm_head``): an operation's
   ``op_name`` carries them beside the flax module names, so a reduction
   of the profiler's trace can sum device time by what the code does.
 """
@@ -39,7 +39,6 @@ from mpit_tpu.ops.decode_attention import paged_write_pages, writes_by_pages
 from mpit_tpu.ops.kv_quant import (
     QuantizedKV,
     dequantize_kv,
-    kv_stack,
     pack_heads,
     quantize_kv,
     unpack_heads,
@@ -70,46 +69,16 @@ def default_attention(q, k, v, *, causal: bool = True):
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
-def cache_update(cache, new, lengths):
-    """Write ``new`` [B, T, H, Dh] into ``cache`` [B, S, H, Dh] at
-    sequence positions ``lengths .. lengths+T-1`` (per-slot start).
-
-    The KV-cache append (ISSUE 4): prefill calls it with ``lengths = 0``
-    (T = padded prompt length — positions past the real prompt are
-    overwritten one-by-one by later decode appends before any attention
-    mask ever exposes them), decode with T = 1 at the slot's current
-    length. Dynamic per-slot starts via a vmapped dynamic_update_slice.
-
-    A :class:`~mpit_tpu.ops.kv_quant.QuantizedKV` cache (ISSUE 15)
-    quantizes on write: the new rows go through the shared per-(row,
-    head) ``amax/127`` contract once, here, and the scale rows land at
-    the same per-slot positions as their int8 rows.
-    """
-
-    def write(c, n, start):
-        return jax.lax.dynamic_update_slice_in_dim(
-            c, n.astype(c.dtype), start, axis=0
-        )
-
-    if isinstance(cache, QuantizedKV):
-        qn = quantize_kv(new)
-        return QuantizedKV(
-            q=jax.vmap(write)(cache.q, qn.q, lengths),
-            scale=jax.vmap(write)(cache.scale, qn.scale, lengths),
-        )
-    return jax.vmap(write)(cache, new, lengths)
-
-
 def paged_cache_update(pool, new, lengths, block_table, valid=None):
     """Write ``new`` [B, T, H*Dh] (rows packed as the projection made
     them) into one layer's page pool [P, page_size, H*Dh] at sequence
     positions ``lengths .. lengths+T-1``, indirected through
     ``block_table`` [B, pages_per_slot] int32 (ISSUE 7).
 
-    The paged analogue of :func:`cache_update` — but a scatter, not a
-    per-slot dynamic slice: each (b, t) resolves to pool row
-    ``[bt[b, pos//ps], pos % ps]``. The scatter indexes the pool as it
-    is stored (no reshape of the pool on either side), so under a
+    The KV-cache append, a scatter: each (b, t) resolves to pool row
+    ``[bt[b, pos//ps], pos % ps]``. A prefill chunk calls it with T =
+    the chunk width at the slot's fill, decode with T = 1. The scatter
+    indexes the pool as it is stored (no reshape of the pool on either side), so under a
     donating jit it writes ``B*T`` rows of the caller's buffer in place
     and moves no other byte. XLA's scatter lands a row at a time (146
     ns a row on the v5e), which a decode tick's B rows do not feel and a
@@ -120,9 +89,8 @@ def paged_cache_update(pool, new, lengths, block_table, valid=None):
     rows that must NOT land (prefill padding past the real prompt, and
     positions below a shared-prefix write floor — shared pages are
     immutable); masked rows scatter to an out-of-bounds page and are
-    DROPPED, so — unlike the dense path, where junk writes stayed
-    inside the slot's own row — a padded prefill can never touch a
-    page the slot does not own.
+    DROPPED, so a padded prefill can never touch a page the slot does
+    not own.
 
     A :class:`~mpit_tpu.ops.kv_quant.QuantizedKV` pool (ISSUE 15)
     quantizes on write, per (row, head), and scatters the scale plane
@@ -165,9 +133,9 @@ def paged_gather(pool, block_table, num_heads):
     [P, page_size, H*Dh] gathered through [B, pages_per_slot] →
     [B, pages_per_slot·page_size, H, Dh]. Rows past a slot's fill are
     whatever the mapped (or stale) pages hold — garbage by design; the
-    attention mask defines validity, exactly as in the dense cache. A
-    quantized pool gathers q and scale together (tree-mapped; the
-    scale comes back in the dense cache's keepdims form)."""
+    attention mask defines validity. A quantized pool gathers q and
+    scale together (tree-mapped; the scale comes back in the keepdims
+    form ``[..., H, 1]``)."""
 
     def g1(pl):
         g = pl[block_table]  # [B, n_ps, ps, H*Dh]
@@ -179,11 +147,9 @@ def paged_gather(pool, block_table, num_heads):
 
 def paged_cached_attention(q, k_pool, v_pool, lengths, block_table):
     """Reference paged attention: gather the dense per-slot view, then
-    the exact :func:`cached_attention` math. The gathered view has the
-    same length and contents (at visible positions) as the dense
-    engine's buffer, and masked keys contribute exact zeros — so greedy
-    decode through the paged path bit-matches the dense reference
-    engine. The serving kernel path
+    the exact :func:`cached_attention` math: masked keys contribute
+    exact zeros, so greedy decode through this path matches the
+    no-cache forward. The serving kernel path
     (:func:`mpit_tpu.ops.decode_attention.flash_paged_decode_attention`)
     never materializes this view — it DMAs only visited tiles, resolved
     per-tile through the block table."""
@@ -200,8 +166,9 @@ def cached_attention(q, k, v, lengths):
     """Causal attention of new queries against a padded KV cache.
 
     ``q`` [B, T, H, Dh] are the T newest positions (global position of
-    row ``t`` is ``lengths + t``); ``k``/``v`` [B, S, H, Dh] are the full
-    cache buffers (new tokens already written via :func:`cache_update`).
+    row ``t`` is ``lengths + t``); ``k``/``v`` [B, S, H, Dh] are a
+    slot's whole cache view (new tokens already written; the paged path
+    gathers it through :func:`paged_gather`).
     Key ``j`` is visible to query ``t`` iff ``j <= lengths + t`` — the
     same causal rule :func:`default_attention` applies, extended over the
     padded buffer, with the identical einsum/f32-softmax structure so
@@ -258,16 +225,11 @@ class GPT2Config:
     # stage 0 while a tied head's would live on every stage, and the two
     # contributions cannot be combined per-leaf after AD.
     tie_head: bool = True
-    # Attention used on the CACHE path (serving). None = the dense
-    # reference :func:`cached_attention`; the serving engine plugs in
-    # :func:`mpit_tpu.ops.flash_decode_attention` here (ISSUE 5) —
-    # same ``(q, k_cache, v_cache, lengths)`` signature. The training
-    # path (``attention_fn``) is untouched by this field.
-    cache_attention_fn: Any = None
-    # Attention on the PAGED cache path (ISSUE 7): ``(q, k_pool,
-    # v_pool, lengths, block_table)``. None = the gather-dense
-    # reference :func:`paged_cached_attention`; the paged engine plugs
-    # in :func:`mpit_tpu.ops.decode_attention.flash_paged_decode_attention`.
+    # Attention on the cache path (serving): ``(q, k_pool, v_pool,
+    # lengths, block_table)``. None = the gather-dense reference
+    # :func:`paged_cached_attention`; the serving engine plugs in
+    # :func:`mpit_tpu.ops.decode_attention.flash_paged_decode_attention`.
+    # The training path (``attention_fn``) is untouched by this field.
     paged_attention_fn: Any = None
     # Matmul used when a Dense kernel seat holds a
     # :class:`~mpit_tpu.ops.quantized_matmul.QuantizedTensor` (ISSUE
@@ -275,7 +237,7 @@ class GPT2Config:
     # :func:`~mpit_tpu.ops.quantized_matmul.quantized_matmul` (Pallas
     # fused-dequant kernel on TPU, blocked lax oracle elsewhere); the
     # serving engine injects its interpret/reference choice here — the
-    # ``cache_attention_fn`` idiom. Irrelevant (never called) while
+    # ``paged_attention_fn`` idiom. Irrelevant (never called) while
     # params are plain arrays.
     quant_matmul_fn: Any = None
     # Contraction/vocab row-block for the quantized matmuls; 0 = the
@@ -366,19 +328,17 @@ class Block(nn.Module):
 
     @nn.compact
     def __call__(self, x, layer_cache=None):
-        """``layer_cache`` (serving): ``(k, v, lengths)`` with k/v
-        [B, S_max, H, Dh] and lengths [B] — the new tokens' K/V are
-        appended at ``lengths`` and attention runs against the cache
-        (:func:`cached_attention`) instead of ``cfg.attention_fn``;
-        returns ``(x, (k, v))`` with the updated buffers. A 5-tuple
-        ``(k_pool, v_pool, lengths, block_table, write_valid)`` selects
-        the PAGED cache path (ISSUE 7), the pools this layer's own
-        [P, page_size, H*Dh] buffers: appends scatter through the
-        block table (:func:`paged_cache_update`, ``write_valid`` [B, T]
-        masking padding/shared-prefix rows) and attention runs
-        ``cfg.paged_attention_fn`` (default the gather-dense
-        :func:`paged_cached_attention`). ``None`` (training): the
-        historical single-output signature, untouched.
+        """``layer_cache`` (serving): ``(k_pool, v_pool, lengths,
+        block_table, write_valid)``, the pools this layer's own
+        [P, page_size, H*Dh] buffers and lengths [B] — the new tokens'
+        K/V are appended at ``lengths`` through the block table
+        (:func:`paged_cache_update`, ``write_valid`` [B, T] masking
+        padding/shared-prefix rows) and attention runs against the pool
+        (``cfg.paged_attention_fn``, default the gather-dense
+        :func:`paged_cached_attention`) instead of ``cfg.attention_fn``;
+        returns ``(x, (k_pool, v_pool))`` with the updated buffers.
+        ``None`` (training): the historical single-output signature,
+        untouched.
         """
         cfg = self.cfg
         dense = lambda features, name: QuantDense(
@@ -398,7 +358,7 @@ class Block(nn.Module):
                     split(q), split(k), split(v), causal=True
                 )
                 new_cache = None
-            elif len(layer_cache) == 5:
+            else:
                 k_pool, v_pool, lengths, block_table, write_valid = layer_cache
                 with jax.named_scope("kv_write"):
                     # k, v are already the pool's packed rows [B, T, H*Dh].
@@ -411,14 +371,6 @@ class Block(nn.Module):
                 attn_fn = cfg.paged_attention_fn or paged_cached_attention
                 attn = attn_fn(split(q), k_pool, v_pool, lengths, block_table)
                 new_cache = (k_pool, v_pool)
-            else:
-                k_cache, v_cache, lengths = layer_cache
-                with jax.named_scope("kv_write"):
-                    k_cache = cache_update(k_cache, split(k), lengths)
-                    v_cache = cache_update(v_cache, split(v), lengths)
-                attn_fn = cfg.cache_attention_fn or cached_attention
-                attn = attn_fn(split(q), k_cache, v_cache, lengths)
-                new_cache = (k_cache, v_cache)
             attn = attn.reshape(*attn.shape[:-2], cfg.d_model)
             x = x + dense(cfg.d_model, "proj")(attn)
 
@@ -435,8 +387,8 @@ class GPT2(nn.Module):
 
     @nn.compact
     def __call__(
-        self, tokens, positions=None, targets=None, cache=None,
-        paged_cache=None, return_hidden=False,
+        self, tokens, positions=None, targets=None, paged_cache=None,
+        return_hidden=False,
     ):
         """tokens [B, T] int32 → logits [B, T, vocab] float32.
 
@@ -451,30 +403,25 @@ class GPT2(nn.Module):
         — the [B, T, vocab] f32 logits array is never materialized.
         Matmul operand dtype follows ``cfg.head_dtype`` on both paths.
 
-        ``cache`` (serving; :mod:`mpit_tpu.serve`): ``(k, v, lengths)``
-        with k/v ``[num_layers, B, S_max, H, Dh]`` stacked per-layer KV
-        buffers and ``lengths`` [B] int32, the per-slot token count
-        already cached. The T new tokens are appended at ``lengths`` and
-        attended causally against the cache; positions default to
-        ``lengths + arange(T)``; the return becomes ``(logits,
-        (new_k, new_v))``. Prefill = call with ``lengths = 0`` and the
-        padded prompt; decode = call with T = 1. Mutually exclusive with
-        ``targets``.
+        ``paged_cache`` (serving; :mod:`mpit_tpu.serve`): ``(k_pools,
+        v_pools, lengths, block_tables, write_valid)`` with pools a
+        sequence of ``num_layers`` buffers ``[num_pages, page_size,
+        H*Dh]`` (layer ``i`` writes and reads ``k_pools[i]`` and nothing
+        else, so a caller that donates them gets each back updated in
+        place), ``lengths`` [B] int32 the per-slot token count already
+        cached, ``block_tables`` [B, pages_per_slot] int32 and
+        ``write_valid`` [B, T] bool. The T new tokens are appended at
+        ``lengths`` — K/V appends scatter through each slot's block
+        table (rows with ``write_valid`` False are dropped, never
+        written) — and attended causally against the cache
+        (``cfg.paged_attention_fn``, default the gather-dense
+        reference); positions default to ``lengths + arange(T)``; the
+        return becomes ``(logits_or_hidden, (new_k_pools,
+        new_v_pools))``, tuples of per-layer buffers again. A prefill
+        chunk = call with T = the chunk width; decode = call with T = 1.
+        Mutually exclusive with ``targets``.
 
-        ``paged_cache`` (serving; ISSUE 7): ``(k_pools, v_pools,
-        lengths, block_tables, write_valid)`` with pools a sequence of
-        ``num_layers`` buffers ``[num_pages, page_size, H*Dh]`` (layer
-        ``i`` writes and reads ``k_pools[i]`` and nothing else, so a
-        caller that donates them gets each back updated in place),
-        ``block_tables`` [B, pages_per_slot] int32 and ``write_valid`` [B, T] bool — the
-        paged analogue of ``cache``: K/V appends scatter through each
-        slot's block table (rows with ``write_valid`` False are
-        dropped, never written), attention runs
-        ``cfg.paged_attention_fn`` (default gather-dense reference),
-        and the return becomes ``(logits_or_hidden, (new_k_pools,
-        new_v_pools))``, tuples of per-layer buffers again. Mutually exclusive with ``cache``/``targets``.
-
-        ``return_hidden`` (serving; requires ``cache``/``paged_cache``):
+        ``return_hidden`` (serving; requires ``paged_cache``):
         skip the LM-head matmul and return the final post-``ln_f``
         hidden states ``[B, T, d_model]`` in place of logits — the
         blocked decode head (:func:`mpit_tpu.ops.lm_head.lm_head_sample`)
@@ -482,18 +429,14 @@ class GPT2(nn.Module):
         logits array never exists in the decode step.
         """
         cfg = self.cfg
-        if return_hidden and cache is None and paged_cache is None:
+        if return_hidden and paged_cache is None:
             raise ValueError(
                 "return_hidden is the serving decode-head path; it "
-                "requires cache= or paged_cache="
+                "requires paged_cache="
             )
-        if paged_cache is not None and cache is not None:
-            raise ValueError("cache and paged_cache are mutually exclusive")
-        if (cache is not None or paged_cache is not None) and (
-            targets is not None
-        ):
+        if paged_cache is not None and targets is not None:
             raise ValueError(
-                "cache and targets are mutually exclusive: the fused "
+                "paged_cache and targets are mutually exclusive: the fused "
                 "xent head never materializes the logits decode needs"
             )
         if paged_cache is not None:
@@ -509,12 +452,6 @@ class GPT2(nn.Module):
                     + jnp.arange(tokens.shape[-1])[None, :],
                     cfg.max_seq_len - 1,
                 )
-        if cache is not None:
-            cache_k, cache_v, cache_lengths = cache
-            if positions is None:
-                positions = cache_lengths[:, None] + jnp.arange(
-                    tokens.shape[-1]
-                )[None, :]
         wte = self.param(
             "wte",
             nn.initializers.normal(0.02),
@@ -542,15 +479,7 @@ class GPT2(nn.Module):
             block = nn.remat(Block)
         new_k, new_v = [], []
         for i in range(cfg.num_layers):
-            if cache is not None:
-                with jax.named_scope("kv_write"):
-                    layer = (cache_k[i], cache_v[i])
-                x, (k_i, v_i) = block(cfg, name=f"block_{i}")(
-                    x, (*layer, cache_lengths)
-                )
-                new_k.append(k_i)
-                new_v.append(v_i)
-            elif paged_cache is not None:
+            if paged_cache is not None:
                 x, (k_i, v_i) = block(cfg, name=f"block_{i}")(
                     x,
                     (pool_k[i], pool_v[i], cache_lengths, block_tables,
@@ -564,12 +493,6 @@ class GPT2(nn.Module):
             # One buffer a layer, each written by its own layer alone:
             # nothing to take out of a stack and nothing to stack again.
             new_kv = (tuple(new_k), tuple(new_v))
-        elif new_k:
-            # A functional update hands the whole cache back: taking a
-            # layer's buffer out of the stack (above) and stacking the
-            # written ones again belong to the write.
-            with jax.named_scope("kv_write"):
-                new_kv = (kv_stack(new_k), kv_stack(new_v))
         with jax.named_scope("lm_head"):
             x = nn.LayerNorm(dtype=cfg.ln_out_dtype, name="ln_f")(x)
         if return_hidden:
@@ -614,7 +537,7 @@ class GPT2(nn.Module):
                     head.astype(cfg.head_dtype),
                     preferred_element_type=jnp.float32,
                 )
-        if cache is not None or paged_cache is not None:
+        if paged_cache is not None:
             return logits, new_kv
         return logits
 
@@ -659,41 +582,27 @@ class GPT2ServeModel(ServeModel):
         return kv_wire_bytes_per_row(
             self.cfg.num_heads, self.cfg.head_dim, dtype)
 
-    def with_decode_attention(self, *, paged, block_k, interpret,
-                              page_size=None):
+    def with_decode_attention(self, *, block_k, interpret, page_size):
         del page_size  # the engine's tile is the kernel's
-        from mpit_tpu.ops.decode_attention import (
-            flash_decode_attention,
-            flash_paged_decode_attention,
-        )
+        from mpit_tpu.ops.decode_attention import flash_paged_decode_attention
 
         attn_fn = functools.partial(
-            flash_paged_decode_attention if paged else flash_decode_attention,
-            block_k=block_k,
-            interpret=interpret,
+            flash_paged_decode_attention, block_k=block_k, interpret=interpret
         )
-        field = "paged_attention_fn" if paged else "cache_attention_fn"
-        return GPT2ServeModel(dataclasses.replace(self.cfg, **{field: attn_fn}))
+        return GPT2ServeModel(
+            dataclasses.replace(self.cfg, paged_attention_fn=attn_fn))
 
-    def attention_tiling(self, t_q, *, block_k, page_size, kv_dtype, tp=1):
+    def attention_tiling(self, t_q, *, page_size, kv_dtype, tp=1):
         from mpit_tpu.ops.decode_attention import decode_tiling
 
         tiling = decode_tiling(
-            t_q, self.cfg.num_heads // tp, kv_dtype, block_k=block_k,
-            page_size=page_size, quantized=jnp.dtype(kv_dtype) == jnp.int8,
+            t_q, self.cfg.num_heads // tp, kv_dtype, page_size=page_size,
+            quantized=jnp.dtype(kv_dtype) == jnp.int8,
         )
         return {"attention_form": tiling.form, "attention_rows": tiling.rows}
 
     def with_quant_matmul(self, fn):
         return GPT2ServeModel(dataclasses.replace(self.cfg, quant_matmul_fn=fn))
-
-    def forward_cached(self, params, tokens, cache, *, return_hidden):
-        return self._module.apply(
-            {"params": params},
-            tokens,
-            cache=(cache.k, cache.v, cache.lengths),
-            return_hidden=return_hidden,
-        )
 
     def forward_paged(self, params, tokens, cache, block_tables, write_valid,
                       *, return_hidden, row_valid=None):

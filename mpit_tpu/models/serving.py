@@ -12,11 +12,10 @@ asks it, and nothing else, for what differs between families:
   rotary part of its key;
 - the **forward through the cache**: embed, then per layer attention
   given that layer's cache handle and the MLP, then the final norm
-  (:meth:`ServeModel.forward_paged`, :meth:`ServeModel.forward_cached`).
-  It returns the hidden states the head samples from, the layers' updated
-  buffers, and whatever the family counts a step (``aux``; ``None`` for a
-  family that counts nothing, and the step's outputs are then as they
-  always were);
+  (:meth:`ServeModel.forward_paged`). It returns the hidden states the
+  head samples from, the layers' updated buffers, and whatever the
+  family counts a step (``aux``; ``None`` for a family that counts
+  nothing, and the step's outputs are then as they always were);
 - the **head** (:meth:`ServeModel.head_table`): the ``[vocab, d]`` table
   the blocked sampler streams;
 - the **parameter tree's placement** and what the family cannot do yet
@@ -71,15 +70,15 @@ class ServeModel:
     # -- what the family cannot do yet ----------------------------------------
     def check_supported(self, **modes) -> None:
         """Raise ``ValueError`` for an engine mode this family lacks.
-        ``modes``: ``paged``, ``tp``, ``kv_dtype``, ``weights_dtype``,
-        ``spec_k``, ``host_pages``."""
+        ``modes``: ``tp``, ``kv_dtype``, ``weights_dtype``, ``spec_k``,
+        ``host_pages``."""
 
     def check_shipment(self) -> None:
         """Raise if cache rows of this family cannot be exported."""
 
     # -- the injected kernels -------------------------------------------------
-    def with_decode_attention(self, *, paged: bool, block_k: int,
-                              interpret, page_size=None) -> "ServeModel":
+    def with_decode_attention(self, *, block_k: int, interpret,
+                              page_size: int) -> "ServeModel":
         """This model with its cache attention through the kernel path
         (``interpret``: None = kernel on a TPU and the lax twin elsewhere,
         True = the Pallas interpreter). ``block_k`` is the engine's tile
@@ -89,19 +88,15 @@ class ServeModel:
 
     def attention_tiling(self, t_q: int, **how) -> dict:
         """What the spans of a step of ``t_q`` query rows say about the
-        injected attention kernel (``how``: the engine's ``block_k``,
-        ``page_size``, ``kv_dtype``, ``tp``): static per
-        compiled step. Nothing for a family that does not say."""
+        injected attention kernel (``how``: the engine's ``page_size``,
+        ``kv_dtype``, ``tp``): static per compiled step. Nothing for a
+        family that does not say."""
         return {}
 
     def with_quant_matmul(self, fn) -> "ServeModel":
         raise NotImplementedError
 
     # -- the forward ------------------------------------------------------------
-    def forward_cached(self, params, tokens, cache, *, return_hidden):
-        """Dense per-slot cache: ``(out, (k, v))``."""
-        raise NotImplementedError
-
     def forward_paged(self, params, tokens, cache, block_tables, write_valid,
                       *, return_hidden, row_valid=None):
         """Page pool: ``(out, (k, v), aux)``. ``row_valid`` [B, T] marks

@@ -470,10 +470,10 @@ def init_params(cfg: Xing4Config, key, dtype=None) -> dict:
 
 
 class Xing4ServeModel(ServeModel):
-    """The family behind the engine's model interface: the paged engine
-    on one chip, bf16 or f32, greedy / temperature / top-k. The dense
-    cache, tensor parallelism, int8 weights or cache, speculative steps,
-    the host tier and fleet shipment are not built for it and raise."""
+    """The family behind the engine's model interface: one chip, bf16 or
+    f32, greedy / temperature / top-k. Tensor parallelism, int8 weights
+    or cache, speculative steps, the host tier and fleet shipment are not
+    built for it and raise."""
 
     family = "xing4"
     skips_invalid_rows = True
@@ -496,10 +496,9 @@ class Xing4ServeModel(ServeModel):
         lay = self.cache_layout()
         return (lay.k_width + lay.v_width) / 2 * jnp.dtype(dtype).itemsize
 
-    def check_supported(self, *, paged, tp, kv_dtype, weights_dtype, spec_k,
+    def check_supported(self, *, tp, kv_dtype, weights_dtype, spec_k,
                         host_pages) -> None:
         lacks = [
-            (not paged, "the dense KVCache path (pass kv_pages=)"),
             (tp, "tensor parallelism (tp_axis)"),
             (kv_dtype == "int8", "an int8 cache (kv_dtype='int8')"),
             (weights_dtype == "int8", "int8 weights (weights_dtype='int8')"),
@@ -510,16 +509,15 @@ class Xing4ServeModel(ServeModel):
             if lacking:
                 raise ValueError(
                     f"the xing4 family does not have {what} yet: it serves "
-                    "through the paged engine on one chip (ROADMAP.md B1)")
+                    "on one chip (ROADMAP.md B1)")
 
     def check_shipment(self) -> None:
         raise ValueError(
             "the xing4 family's latent cache rows cannot be shipped between "
             "engines yet (export_kv_rows / inject_kv_rows; ROADMAP.md B1)")
 
-    def with_decode_attention(self, *, paged, block_k, interpret,
-                              page_size=None):
-        del paged, block_k  # the family has the paged path alone
+    def with_decode_attention(self, *, block_k, interpret, page_size):
+        del block_k
         # The engine's tile (GPT-2's: 64 positions of a 256-position
         # page) would make 80 KB DMAs of the latent rows: the kernel
         # takes whole pages up to 512 positions, and says so.

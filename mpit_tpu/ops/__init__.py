@@ -22,11 +22,11 @@ hand-scheduled TPU kernels below the XLA tier:
   materializes the [B, T, vocab] f32 logits), plus the blocked decode
   head ``lm_head_sample`` (greedy/top-k/temperature sampling with a
   running top-k merge across vocab blocks — the serving analogue).
-- :mod:`mpit_tpu.ops.decode_attention` — flash-decode against the padded
-  per-slot KV cache: blocked over the cache length with online softmax
-  and per-slot length-aware block skipping (K/V stay in HBM; a slot
-  holding L tokens pays ceil((L+T)/block_k) tiles, not max_len/block_k)
-  — the serving hot-loop kernel (ISSUE 5).
+- :mod:`mpit_tpu.ops.decode_attention` — flash-decode against the paged
+  KV pool: blocked over the cache length with online softmax and
+  per-slot length-aware skipping (K/V stay in HBM and are read in place
+  through the block table; a slot holding L tokens pays the rows up to
+  L+T, not max_len) — the serving hot-loop kernel.
 
 Every kernel has an ``interpret`` path so its semantics are testable on
 the CPU fake mesh (SURVEY.md §6 "race detection" row), and an XLA
@@ -34,9 +34,9 @@ fallback for non-TPU backends.
 """
 
 from mpit_tpu.ops.decode_attention import (
-    flash_decode_attention,
+    flash_paged_decode_attention,
     num_kv_blocks,
-    reference_decode_attention,
+    reference_paged_decode_attention,
 )
 from mpit_tpu.ops.flash_attention import (
     flash_attention,
@@ -67,11 +67,11 @@ from mpit_tpu.ops.ring_collectives import (
 __all__ = [
     "flash_attention",
     "flash_attention_block",
-    "flash_decode_attention",
+    "flash_paged_decode_attention",
     "merge_attention",
     "num_kv_blocks",
     "reference_attention",
-    "reference_decode_attention",
+    "reference_paged_decode_attention",
     "lm_head_sample",
     "lm_head_xent",
     "ring_allreduce",

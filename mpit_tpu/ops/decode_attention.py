@@ -1,12 +1,12 @@
-"""Pallas flash-decode — length-aware attention against the padded KV cache.
+"""Pallas flash-decode — length-aware attention against the paged KV pool.
 
-The serving hot path (ISSUE 5 tentpole). PR 4's engine decodes with
-:func:`mpit_tpu.models.gpt2.cached_attention`: a dense XLA attention that
-scores every query against the **entire padded cache buffer**
-``[slots, max_len]`` and materializes the f32 ``[B, H, T, S]`` score
-tensor — so a decode tick costs O(max_len) HBM traffic and FLOPs even
-when the slots hold 30-token contexts. This kernel makes the tick cost
-scale with the *context actually cached*:
+The serving hot path. The reference,
+:func:`mpit_tpu.models.gpt2.paged_cached_attention`, gathers each slot's
+whole virtual cache ``[slots, max_len]`` out of the pool and materializes
+the f32 ``[B, H, T, S]`` score tensor — so a decode tick costs O(max_len)
+HBM traffic and FLOPs even when the slots hold 30-token contexts.
+:func:`flash_paged_decode_attention` makes the tick cost scale with the
+*context actually cached*:
 
 - **Blocked over the cache length with online softmax.** The kernel
   streams K/V tiles through a ``fori_loop``, carrying the flash running
@@ -15,14 +15,24 @@ scale with the *context actually cached*:
   never exists — only a ``[rows of queries, rows of a tile]`` f32 tile.
 - **Per-slot length-aware skipping.** The loop bound is derived from
   the slot's ``lengths`` entry (an SMEM scalar): a slot holding ``L``
-  tokens reads the rows up to ``L + T`` (to the end of their
-  ``block_k``-row block on the dense path, of their page on the paged
-  one), not ``max_len``. Because K/V stay in **HBM**
-  (``memory_space=ANY``) and the kernel DMAs tiles in itself
-  (double-buffered, overlap with compute), skipped rows cost neither
-  FLOPs *nor* HBM reads — the BlockSpec-prefetch form would have copied
-  the whole padded row. ``block_k`` is the unit the skipping is counted
-  in (``visited``, :func:`num_kv_blocks`).
+  tokens reads the rows up to ``L + T`` (to the end of their page), not
+  ``max_len``. Because K/V stay in **HBM** (``memory_space=ANY``) and
+  the kernel DMAs tiles in itself (double-buffered, overlap with
+  compute), skipped rows cost neither FLOPs *nor* HBM reads — the
+  BlockSpec-prefetch form would have copied the whole padded row.
+  ``block_k`` is the unit the skipping is counted in (``visited``,
+  :func:`num_kv_blocks`).
+- **The pool is read in place.** The pool is ``[num_pages, page_size,
+  H·D]``; the slot's int32 block table rides in SMEM next to ``lengths``
+  (scalar prefetch), and a loop step GATHERS consecutive pages of the
+  table into one VMEM tile (16 pages of 16 positions: 256 rows), a DMA a
+  page and buffer, each source resolved by one SMEM lookup, all of a
+  step's DMAs in flight together and the next step's behind them — the
+  gather costs zero extra HBM traffic. The slot's last step fetches only
+  the pages that hold a visible key and zeroes the V rows of the others
+  (a buffer's old content, NaN included, must not reach ``p @ V``).
+  Pages larger than the tile are read in equal parts; ``page_size`` must
+  be a multiple of ``block_k``, the unit of the visited count.
 - **A step does a lane tile's worth of work** (ISSUE 27,
   :func:`decode_tiling`). One grid program per slot computes every head
   it was given over the packed ``[rows, H·D]`` lane layout of the
@@ -37,43 +47,27 @@ scale with the *context actually cached*:
   (``T·Hp`` past a bound) and over an int8 pool the loop is
   python-unrolled over heads, a product a head.
 - **Small-T prefill tail.** ``T`` is static per trace; the engine's
-  padded prefill (``T = prefill_len``, ``lengths = 0``) and its decode
-  tick (``T = 1``) are two traces of the same kernel.
+  prefill chunk (``T = prefill_chunk``) and its decode tick (``T = 1``)
+  are two traces of the same kernel.
 
 Parity contract: visibility is ``key j visible to query t iff
 j <= lengths + t`` — exactly :func:`~mpit_tpu.models.gpt2.cached_attention`
-(the reference), whose masked rows contribute exact zeros. Masked
+(the reference's math), whose masked rows contribute exact zeros. Masked
 positions inside a visited boundary tile score ``-1e30``; ``exp``
 underflows to exactly 0.0 in f32, and tiles past the loop bound are
 never read — so the kernel's masked-key contribution is exactly zero
-too, and greedy decode through it preserves the PR 4 bit-match
-invariant at the token level.
+too, and greedy decode through it keeps the token-level match with the
+no-cache forward.
 
 On non-TPU backends (``interpret=None``) the same math runs as the
 reference XLA path; ``interpret=True`` forces the kernel through the
 Pallas interpreter (the CPU-mesh test path, like the training kernel).
 
-**Paged variant (ISSUE 7).** :func:`flash_paged_decode_attention` runs
-the same length-aware flash loop against a PAGED pool
-(``[num_pages, page_size, H·D]``) instead of a dense per-slot buffer:
-the slot's int32 block table rides in SMEM next to ``lengths`` (scalar
-prefetch), and a loop step GATHERS consecutive pages of the table into
-one VMEM tile (16 pages of 16 positions: 256 rows), a DMA a page and
-buffer, each source resolved by one SMEM lookup, all of a step's DMAs in
-flight together and the next step's behind them — the gather costs zero
-extra HBM traffic. The slot's last step fetches only the pages that hold
-a visible key and zeroes the V rows of the others (a buffer's old
-content, NaN included, must not reach ``p @ V``). Pages larger than the
-tile are read in equal parts; ``page_size`` must be a multiple of
-``block_k``, the unit of the visited count. Skipped rows still cost
-neither FLOPs nor HBM reads, and the heads-local/TP calling convention
-is unchanged.
-
 **Quantized variant (ISSUE 15).** Passing
 :class:`~mpit_tpu.ops.kv_quant.QuantizedKV` buffers (int8 payload +
 per-(row, head) f32 scales) selects the FUSED-DEQUANT form of the same
 kernel: what crosses HBM→VMEM per visited tile is the int8 K/V tile
-plus its ``[block_k, H]`` scale block (two extra DMA channels on the
+plus its ``[rows, H]`` scale block (two extra DMA channels on the
 same double buffer), and the dequant
 (:func:`~mpit_tpu.ops.ring_collectives.dequantize_blocks` — the PR 9
 rounding contract's inverse) runs in VMEM per tile, per head. The f32
@@ -100,11 +94,9 @@ from mpit_tpu.ops.kv_quant import QuantizedKV
 from mpit_tpu.ops.ring_collectives import dequantize_blocks, sublane_for
 
 __all__ = [
-    "flash_decode_attention",
     "flash_paged_decode_attention",
     "paged_write_pages",
     "writes_by_pages",
-    "reference_decode_attention",
     "reference_paged_decode_attention",
     "num_kv_blocks",
     "pick_block_k",
@@ -126,27 +118,14 @@ def _use_kernel(interpret: bool | None) -> bool:
 # ---------------------------------------------------------------------------
 
 
-def reference_decode_attention(q, k, v, lengths):
-    """Dense cached attention, [B, T, H, Dh] vs padded [B, S, H, Dh].
-
-    Delegates to :func:`mpit_tpu.models.gpt2.cached_attention` — the
-    kernel's oracle and the non-TPU fallback ARE the serving reference,
-    one implementation, so a numerics change there cannot silently
-    desynchronize this module (the bitwise pin in
-    ``tests/test_decode_attention.py`` now guards only the signature).
-    Imported lazily: ops sits below models in the layering, and the
-    models package must not load just because ops does.
-    """
-    from mpit_tpu.models.gpt2 import cached_attention
-
-    return cached_attention(q, k, v, lengths)
-
-
 def reference_paged_decode_attention(q, k_pool, v_pool, lengths, block_table):
     """Gather-dense paged attention — delegates to
-    :func:`mpit_tpu.models.gpt2.paged_cached_attention` (one
-    implementation, same rationale as the dense reference above). The
-    paged kernel's oracle and the non-TPU fallback."""
+    :func:`mpit_tpu.models.gpt2.paged_cached_attention`: the kernel's
+    oracle and the non-TPU fallback ARE the serving reference, one
+    implementation, so a numerics change there cannot silently
+    desynchronize this module. Imported lazily: ops sits below models in
+    the layering, and the models package must not load just because ops
+    does."""
     from mpit_tpu.models.gpt2 import paged_cached_attention
 
     return paged_cached_attention(q, k_pool, v_pool, lengths, block_table)
@@ -216,13 +195,11 @@ class DecodeTiling(NamedTuple):
         return self.piece_rows * self.pieces
 
 
-def decode_tiling(t_q: int, num_heads: int, dtype, *, block_k: int,
-                  page_size: int | None = None,
+def decode_tiling(t_q: int, num_heads: int, dtype, *, page_size: int,
                   quantized: bool = False) -> DecodeTiling:
     """The tiling the kernel runs for a call of this shape, from the
-    shape alone. Dense: a step is ``block_k`` contiguous rows. Paged: a
-    step gathers consecutive pages of the slot's block table up to
-    :data:`_TILE_ROWS` rows, each page its own DMA into its rows of the
+    shape alone. A step gathers consecutive pages of the slot's block
+    table up to :data:`_TILE_ROWS` rows, each page its own DMA into its rows of the
     tile (a DMA's rows in a larger buffer start and end on a VMEM tile,
     8 rows of float32, 16 of bf16, 32 of int8: a pool whose page is not
     whole tiles keeps one page a step; a page
@@ -231,14 +208,11 @@ def decode_tiling(t_q: int, num_heads: int, dtype, *, block_k: int,
     to a VMEM tile, :data:`_HEAD_ROWS` rows at most); above that, and for
     an int8 pool (whose scales are per row and head), a product a head."""
     sub = sublane_for(dtype)
-    if page_size is None:
-        piece, pieces = block_k, 1
-    else:
-        piece = min(page_size, _TILE_ROWS)
-        while page_size % piece:
-            piece -= 1
-        fits = piece == page_size and page_size % sub == 0
-        pieces = _TILE_ROWS // piece if fits else 1
+    piece = min(page_size, _TILE_ROWS)
+    while page_size % piece:
+        piece -= 1
+    fits = piece == page_size and page_size % sub == 0
+    pieces = _TILE_ROWS // piece if fits else 1
     head_rows = t_q * -(-num_heads // sub) * sub
     as_rows = not quantized and head_rows <= _HEAD_ROWS
     return DecodeTiling(
@@ -253,20 +227,16 @@ def _decode_kernel(
     head_dim,
     scale,
     tiling,
-    page_size=None,
+    page_size,
     quantized=False,
 ):
-    """Flash-decode body, dense or paged, plain or fused-dequant.
+    """Flash-decode body, plain or fused-dequant.
 
-    Dense (``page_size=None``) refs: ``lengths_ref`` [B] int32 SMEM,
-    ``q_ref`` [1, T, H·D] VMEM, ``k_hbm``/``v_hbm`` [B, S, H·D]
-    ANY/HBM, ``o_ref``, ``visited_ref`` (whole [B] int32 SMEM, entry
-    ``b`` written by program ``b``), scratch. Paged adds ``bt_ref``
-    [B, pages_per_slot] int32 SMEM after ``lengths_ref`` and the HBM
-    operands become the [num_pages, page_size, H·D] pool — the ONLY
-    other difference is the DMA source: a piece is resolved through the
-    block table instead of being a contiguous row slice. The flash
-    loop, masks and accumulators are the same code.
+    Refs: ``lengths_ref`` [B] int32 SMEM, ``bt_ref`` [B, pages_per_slot]
+    int32 SMEM, ``q_ref`` [1, T, H·D] VMEM, ``k_hbm``/``v_hbm``
+    [num_pages, page_size, H·D] ANY/HBM (the pool), ``o_ref``,
+    ``visited_ref`` (whole [B] int32 SMEM, entry ``b`` written by
+    program ``b``), scratch.
 
     A loop step takes one tile of ``tiling.pieces`` pieces of
     ``tiling.piece_rows`` rows, each piece one DMA a buffer, all of a
@@ -286,8 +256,8 @@ def _decode_kernel(
     head over the same tile, each with its own statistics.
 
     ``quantized`` (ISSUE 15): the HBM operand list interleaves scale
-    planes — ``k, k_scale, v, v_scale`` with scales [B, S, Hp] (dense)
-    or [num_pages, page_size, Hp] (paged) f32, Hp = H lane-padded to 128
+    planes — ``k, k_scale, v, v_scale`` with scales
+    [num_pages, page_size, Hp] f32, Hp = H lane-padded to 128
     (:func:`_kv_operands`) — and the scratch grows matching double
     buffers on two extra DMA channels. Each visited tile dequantizes in
     VMEM, per head, through the shared
@@ -296,7 +266,7 @@ def _decode_kernel(
     """
     refs = list(refs)
     lengths_ref = refs.pop(0)
-    bt_ref = refs.pop(0) if page_size is not None else None
+    bt_ref = refs.pop(0)
     q_ref = refs.pop(0)
     if quantized:
         k_hbm, ks_hbm, v_hbm, vs_hbm = refs[:4]
@@ -312,10 +282,7 @@ def _decode_kernel(
     else:
         (k_buf, v_buf, sem) = refs
         ks_buf = vs_buf = None
-    if page_size is None:
-        s = k_hbm.shape[1]
-    else:
-        s = bt_ref.shape[1] * page_size  # virtual per-slot cache length
+    s = bt_ref.shape[1] * page_size  # virtual per-slot cache length
     b = pl.program_id(0)
     t_q = q_ref.shape[1]
     h_n, d = num_heads, head_dim
@@ -325,8 +292,8 @@ def _decode_kernel(
 
     def blocks(unit):
         # Units of ``unit`` rows with >= 1 visible key: ceil((L + T)/unit),
-        # clamped to the buffer (a stale/retired slot's length can never
-        # overrun it; in the paged case the clamp also bounds the
+        # clamped to the slot's virtual cache (a stale/retired slot's
+        # length can never overrun it; the clamp also bounds the
         # block-table index, so a stale table entry past the mapped pages
         # is never resolved).
         return jnp.clip((length + t_q + unit - 1) // unit, 1, s // unit)
@@ -339,9 +306,7 @@ def _decode_kernel(
         # Piece ``pi`` of the slot's cache into rows ``j * piece ..`` of
         # the tile. ``piece`` divides the page (a piece never straddles
         # pages), so one SMEM lookup names its page.
-        if bt_ref is None:
-            src = which_hbm.at[b, pl.ds(pi * piece, piece)]
-        elif piece == page_size:
+        if piece == page_size:
             src = which_hbm.at[bt_ref[b, pi]]
         else:
             src = which_hbm.at[bt_ref[b, (pi * piece) // page_size],
@@ -544,29 +509,23 @@ def _lane_pad(n: int) -> int:
     return -(-n // 128) * 128
 
 
-def _kv_operands(k, v, pk=lambda x: x):
+def _kv_operands(k, v):
     """The kernel's HBM operand list + matching double-buffer scratch
-    dtypes for one (K, V) pair — plain buffers or the quantized
-    interleave ``k, k_scale, v, v_scale``. ``pk`` packs a leaf's rows
-    for the kernel (``[.., H, Dh]`` to ``[.., H*Dh]``, a stored
-    keepdims scale ``[.., H, 1]`` to ``[.., H]``); a page pool is
-    stored packed and takes the default, so its buffers go to the
-    kernel as they are. One helper serves the dense and paged calls,
-    so the operand order and the kernel's unpacking cannot drift
-    apart."""
+    dtypes for one (K, V) pair of pool buffers — plain, or the quantized
+    interleave ``k, k_scale, v, v_scale``. The pool is stored packed, as
+    the kernel reads it, so its buffers go to the kernel as they are."""
     quantized = isinstance(k, QuantizedKV)
     if not quantized:
-        return quantized, [pk(k), pk(v)], [k.dtype, v.dtype]
+        return quantized, [k, v], [k.dtype, v.dtype]
     # Mosaic DMAs whole 128-lane tiles: a [.., H] f32 plane narrower
     # than a lane tile is refused ("slice shape ... must be aligned to
     # tiling (128)"), so the scale operand is lane-padded here.
     def psc(sc):
-        sc = pk(sc)
         return jnp.pad(
             sc, ((0, 0), (0, 0), (0, _lane_pad(sc.shape[-1]) - sc.shape[-1]))
         )
 
-    ops = [pk(k.q), psc(k.scale), pk(v.q), psc(v.scale)]
+    ops = [k.q, psc(k.scale), v.q, psc(v.scale)]
     return quantized, ops, [jnp.int8, jnp.float32, jnp.int8, jnp.float32]
 
 
@@ -582,56 +541,6 @@ def _scratch_for(quantized, rows, hd, h, dtypes):
     return bufs + [pltpu.SemaphoreType.DMA((len(widths), 2))]
 
 
-@functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
-def _decode_call(q, k, v, lengths, *, block_k, interpret):
-    b, t, h, d = q.shape
-    hd = h * d
-    pk = lambda x: x.reshape(x.shape[0], x.shape[1], -1)  # head-pack
-    with jax.named_scope("kv_gather"):
-        quantized, kv_ops, kv_dtypes = _kv_operands(k, v, pk)
-    tiling = decode_tiling(
-        t, h, kv_dtypes[0], block_k=block_k, quantized=quantized
-    )
-    kern = functools.partial(
-        _decode_kernel,
-        block_k=block_k,
-        num_heads=h,
-        head_dim=d,
-        scale=1.0 / (d ** 0.5),
-        tiling=tiling,
-        quantized=quantized,
-    )
-    o, visited = pl.pallas_call(
-        kern,
-        name="decode_attn",
-        grid=(b,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),  # lengths, whole [B]
-            pl.BlockSpec(
-                (1, t, hd), lambda i: (i, 0, 0), memory_space=pltpu.VMEM
-            ),
-        ]
-        # K/V (+ scale planes when quantized) stay in HBM; the kernel
-        # DMAs visited tiles itself.
-        + [pl.BlockSpec(memory_space=pl.ANY) for _ in kv_ops],
-        out_specs=[
-            pl.BlockSpec(
-                (1, t, hd), lambda i: (i, 0, 0), memory_space=pltpu.VMEM
-            ),
-            # Whole [B] counter in SMEM, each program writing its own
-            # entry: Mosaic refuses a blocked (1, 1) SMEM output.
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, t, hd), q.dtype, vma=_vma(q)),
-            jax.ShapeDtypeStruct((b,), jnp.int32, vma=_vma(q)),
-        ],
-        scratch_shapes=_scratch_for(quantized, tiling.rows, hd, h, kv_dtypes),
-        interpret=bool(interpret),
-    )(jnp.asarray(lengths, jnp.int32), pk(q), *kv_ops)
-    return o.reshape(b, t, h, d), visited
-
-
 @functools.partial(
     jax.jit, static_argnames=("block_k", "page_size", "interpret")
 )
@@ -645,8 +554,7 @@ def _paged_decode_call(
         # The pools are stored as the kernel reads them: no repacking.
         quantized, kv_ops, kv_dtypes = _kv_operands(k_pool, v_pool)
     tiling = decode_tiling(
-        t, h, kv_dtypes[0], block_k=block_k, page_size=page_size,
-        quantized=quantized,
+        t, h, kv_dtypes[0], page_size=page_size, quantized=quantized
     )
     kern = functools.partial(
         _decode_kernel,
@@ -862,8 +770,8 @@ def paged_write_pages(pool, new, lengths, block_table, valid=None, *,
     and written back whole. A page is written by one slot alone (a shared
     page is copied before it is written), so whole-page writes of
     different slots never meet; kept rows are rewritten with what was
-    just read. ``interpret`` as in :func:`flash_decode_attention`; there
-    is no lax twin here, the scatter is it."""
+    just read. ``interpret`` as in :func:`flash_paged_decode_attention`;
+    there is no lax twin here, the scatter is it."""
     return _paged_write_call(
         pool, new, lengths, block_table, valid, interpret=bool(interpret)
     )
@@ -880,8 +788,9 @@ def flash_paged_decode_attention(
     interpret: bool | None = None,
     return_visited: bool = False,
 ):
-    """Length-aware attention against one layer's PAGED KV pool (ISSUE
-    7): ``[B, T, H, Dh]`` queries vs ``[num_pages, page_size, H*Dh]``
+    """Length-aware attention against one layer's paged KV pool:
+    ``[B, T, H, Dh]`` queries (the T newest positions, global position
+    ``lengths + t``) vs ``[num_pages, page_size, H*Dh]``
     pools (rows packed head-major, the form
     :class:`~mpit_tpu.serve.kvcache.PagedKVCache` stores: the kernel
     DMAs its tiles straight out of the caller's buffer, with no copy or
@@ -891,18 +800,30 @@ def flash_paged_decode_attention(
     shape and a ``[num_pages, page_size, H]`` scale plane.
 
     Drop-in for :func:`mpit_tpu.models.gpt2.paged_cached_attention`
-    (plug in as ``GPT2Config.paged_attention_fn``). The loop and
-    skipping are exactly :func:`flash_decode_attention`'s over the
-    slot's virtual ``pages_per_slot × page_size`` cache; a step's tile
-    is gathered through the table, several pages at once
-    (:func:`decode_tiling`). ``block_k``, the unit ``visited`` counts
-    in, defaults to the :func:`pick_block_k` choice for ``page_size``
-    and must divide it. ``interpret`` /
-    ``return_visited`` as in :func:`flash_decode_attention` (the
-    non-TPU fallback is the gather-dense reference)."""
+    (plug in as ``GPT2Config.paged_attention_fn``). The loop runs over
+    the slot's virtual ``pages_per_slot × page_size`` cache; a step's
+    tile is gathered through the table, several pages at once
+    (:func:`decode_tiling`); a slot holding ``L`` tokens visits
+    ``ceil((L+T)/block_k)`` blocks. ``block_k``, the unit ``visited``
+    counts in, defaults to the :func:`pick_block_k` choice for
+    ``page_size`` and must divide it.
+
+    ``interpret``: ``None`` = Pallas kernel on TPU, the gather-dense
+    reference XLA path elsewhere; ``True`` = force the kernel through the
+    interpreter (the CPU test path); ``False`` = force it compiled.
+
+    ``return_visited``: also return the per-slot visited-block count
+    ``[B] int32`` — on the kernel path this is written by the kernel
+    itself (what the loop actually ran), on the reference path it is the
+    host formula :func:`num_kv_blocks`; tests pin the two against each
+    other."""
     page_size = k_pool.shape[1]
     bk = pick_block_k(page_size, block_k)
     if page_size % bk:
+        # Validated on EVERY platform (the reference fallback could run
+        # any block_k, but its visited-block accounting would describe a
+        # tiling the kernel can't execute — code passing off-TPU must
+        # not first fail at TPU deploy).
         raise ValueError(
             f"page_size {page_size} must be divisible by block_k={bk}"
         )
@@ -919,60 +840,6 @@ def flash_paged_decode_attention(
     out, visited = _paged_decode_call(
         q, k_pool, v_pool, lengths, block_table,
         block_k=bk, page_size=page_size,
-        interpret=bool(interpret) if interpret is not None else False,
-    )
-    return (out, visited) if return_visited else out
-
-
-def flash_decode_attention(
-    q,
-    k,
-    v,
-    lengths,
-    *,
-    block_k: int | None = None,
-    interpret: bool | None = None,
-    return_visited: bool = False,
-):
-    """Length-aware cached attention: ``[B, T, H, Dh]`` queries (the T
-    newest positions, global position ``lengths + t``) against padded
-    ``[B, S, H, Dh]`` K/V cache buffers.
-
-    Drop-in for :func:`mpit_tpu.models.gpt2.cached_attention` (plug in
-    as ``GPT2Config.cache_attention_fn``). ``block_k`` tiles the cache
-    length (default via :func:`pick_block_k`: largest power of two
-    ≤ 256 dividing S that yields at least 4 tiles, floor 8); a slot
-    holding ``L`` tokens visits ``ceil((L+T)/block_k)`` tiles.
-
-    ``interpret``: ``None`` = Pallas kernel on TPU, reference XLA path
-    elsewhere; ``True`` = force the kernel through the interpreter (the
-    CPU test path); ``False`` = force it compiled.
-
-    ``return_visited``: also return the per-slot visited-tile count
-    ``[B] int32`` — on the kernel path this is written by the kernel
-    itself (what the loop actually ran), on the reference path it is the
-    host formula :func:`num_kv_blocks`; tests pin the two against each
-    other.
-    """
-    s = k.shape[1]
-    bk = pick_block_k(s, block_k)
-    if s % bk:
-        # Validated on EVERY platform (the reference fallback could run
-        # any block_k, but its visited-tile accounting would describe a
-        # tiling the kernel can't execute — code passing off-TPU must
-        # not first fail at TPU deploy).
-        raise ValueError(
-            f"cache length {s} must be divisible by block_k={bk}"
-        )
-    if not _use_kernel(interpret):
-        out = reference_decode_attention(q, k, v, lengths)
-        if return_visited:
-            return out, num_kv_blocks(
-                jnp.asarray(lengths, jnp.int32), q.shape[1], s, bk
-            )
-        return out
-    out, visited = _decode_call(
-        q, k, v, lengths, block_k=bk,
         interpret=bool(interpret) if interpret is not None else False,
     )
     return (out, visited) if return_visited else out

@@ -27,14 +27,14 @@ Per-row grain is what makes append-only writes exact: a row is
 quantized once, when written, and never rescaled by a later append.
 
 :class:`QuantizedKV` is the container: a registered pytree ``(q int8,
-scale f32)`` that drops into every ``KVCache.k`` / ``PagedKVCache.k``
-seat. In the dense cache the scale keeps a trailing size-1 axis
-(``[..., H, 1]`` vs the buffer's ``[..., H, Dh]``) so both leaves share
-rank and the engine's slot-select masks broadcast over either through
-one ``tree.map``. A page pool holds rows PACKED, as the decode kernel
-reads them (``q`` ``[pages, page_size, H*Dh]``, ``scale`` ``[pages,
-page_size, H]``, equal rank again): :func:`pack_heads` /
-:func:`unpack_heads` move between the two forms.
+scale f32)`` that drops into every ``PagedKVCache.k`` seat. Head-split
+rows (what :func:`quantize_kv` takes, what a fleet shipment carries)
+keep a trailing size-1 axis on the scale (``[..., H, 1]`` vs the rows'
+``[..., H, Dh]``) so both leaves share rank and one ``tree.map`` serves
+either. A page pool holds rows PACKED, as the decode kernel reads them
+(``q`` ``[pages, page_size, H*Dh]``, ``scale`` ``[pages, page_size,
+H]``, equal rank again): :func:`pack_heads` / :func:`unpack_heads` move
+between the two forms.
 """
 
 from __future__ import annotations
@@ -56,7 +56,6 @@ __all__ = [
     "dequantize_kv",
     "pack_heads",
     "unpack_heads",
-    "kv_stack",
     "kv_wire_bytes_per_row",
 ]
 
@@ -128,13 +127,6 @@ def unpack_heads(rows, num_heads: int):
     return jax.tree.map(
         lambda a: a.reshape(*a.shape[:-1], num_heads, -1), rows
     )
-
-
-def kv_stack(buffers):
-    """``jnp.stack`` over a list of per-layer cache buffers, plain
-    arrays or :class:`QuantizedKV` alike (tree-mapped, so q and scale
-    stack together)."""
-    return jax.tree.map(lambda *ls: jnp.stack(ls), *buffers)
 
 
 def kv_wire_bytes_per_row(num_heads: int, head_dim: int, dtype) -> float:

@@ -198,7 +198,7 @@ def mla_paged_decode_attention(
     ``q_rope`` [B, H, R'] against the pools ``[P, ps, C]`` / ``[P, ps, R]``
     through ``block_table`` [B, pages_per_slot]; returns the weighted
     latents ``[B, H, C]``. ``interpret`` as in
-    :func:`~mpit_tpu.ops.decode_attention.flash_decode_attention`."""
+    :func:`~mpit_tpu.ops.decode_attention.flash_paged_decode_attention`."""
     if not _da._use_kernel(interpret):
         return reference_mla_paged_decode_attention(
             q_abs, q_rope, ckv_pool, kr_pool, lengths, block_table,

@@ -4,25 +4,29 @@ The reference's pserver is a request-serving loop — receive a tagged
 message, act on shared state, reply (SURVEY.md §3.2 A1). Training
 collapsed that protocol into SPMD steps (``mpit_tpu.train``); serving
 re-grows it as the north star demands ("serves heavy traffic"): a
-batched GPT-2 inference engine where the shared state is a preallocated
-per-slot KV cache and the request loop is continuous batching.
+batched inference engine where the shared state is a page pool of
+cached K/V and the request loop is continuous batching.
 
-- :mod:`~mpit_tpu.serve.kvcache` — ``[layers, slots, max_len, heads,
-  head_dim]`` K/V buffers + per-slot lengths; head-dim sharding specs
-  for tensor parallelism.
-- :mod:`~mpit_tpu.serve.engine` — ONE jitted prefill step + ONE jitted
-  decode step over the whole slot batch (fixed shapes, two compiles for
-  the engine's lifetime); per-slot greedy/temperature/top-k sampling
-  jitted with the step; a TP variant reusing the ``parallel.megatron``
-  block rules. Greedy outputs bit-match the no-cache ``models.gpt2``
-  forward. The hot loop is kernel-shaped (ISSUE 5): attention runs the
-  Pallas flash-decode kernel (:mod:`mpit_tpu.ops.decode_attention` —
-  blocked over the cache length, per-slot length-aware tile skipping)
-  and sampling streams the LM head per vocab block
+- :mod:`~mpit_tpu.serve.kvcache` — the page pool: per layer one K and
+  one V buffer ``[pages, page_size, heads*head_dim]`` + per-slot
+  lengths, the host ``PageAllocator`` (block tables, refcounts, prefix
+  index, copy-on-write, the host tier); head-axis sharding specs for
+  tensor parallelism.
+- :mod:`~mpit_tpu.serve.engine` — ONE jitted prefill-chunk step + ONE
+  jitted decode step over the whole slot batch (fixed shapes, three
+  compiles with the page copy for the engine's lifetime); per-slot
+  greedy/temperature/top-k sampling jitted with the step; a TP variant
+  reusing the ``parallel.megatron`` block rules. Greedy outputs match
+  the no-cache ``models.gpt2`` forward. The hot loop is kernel-shaped:
+  attention runs the Pallas flash-decode kernel
+  (:mod:`mpit_tpu.ops.decode_attention` — the pool read in place,
+  blocked over the cache length, per-slot length-aware skipping) and
+  sampling streams the LM head per vocab block
   (:func:`mpit_tpu.ops.lm_head.lm_head_sample`) — the decode step
   never materializes ``[slots, vocab]`` logits or ``[slots, H, T,
-  max_len]`` scores; ``Engine(decode_attention="reference")`` keeps
-  the dense PR 4 path as the parity oracle.
+  max_len]`` scores; ``Engine(decode_attention="reference")`` runs the
+  gather-dense attention and the whole-logits sampler as the parity
+  oracle.
 - :mod:`~mpit_tpu.serve.scheduler` — the continuous-batching loop:
   queue → admit into freed slots between decode ticks → per-slot
   retirement (EOS / max tokens / cache full), with full ``obs``
@@ -78,13 +82,10 @@ from mpit_tpu.serve.fleet import (
     run_fleet,
 )
 from mpit_tpu.serve.kvcache import (
-    KVCache,
     PageAllocator,
     PagedKVCache,
     QuantizedKV,
-    alloc_cache,
     alloc_paged_cache,
-    cache_specs,
     kv_wire_bytes_per_row,
     paged_cache_specs,
     pages_needed,
@@ -127,7 +128,6 @@ __all__ = [
     "Completed",
     "Engine",
     "FleetConfig",
-    "KVCache",
     "KVShipment",
     "LoadSpec",
     "PageAllocator",
@@ -142,9 +142,7 @@ __all__ = [
     "parse_fleet_spec",
     "parse_policy_spec",
     "run_fleet",
-    "alloc_cache",
     "alloc_paged_cache",
-    "cache_specs",
     "paged_cache_specs",
     "pages_needed",
     "draft_from_target",

@@ -19,9 +19,10 @@ Two drive modes (ISSUE 6):
   req/s, tokens/s, occupancy, queue depth) fed from the
   ``obs.stream`` registry — not from the Recorder's bounded buffer.
 
-``--kv-pages N`` (ISSUE 7) selects the PAGED engine: a fixed pool of
-``--kv-page-size``-token pages shared by all slots (HBM scales with
-tokens actually held, not slots × max-len), copy-on-write prefix
+``--kv-pages N`` sizes the page pool: N pages of ``--kv-page-size``
+tokens shared by all slots (HBM scales with the pool, not slots ×
+max-len; ``--kv-pages 0``, the default, is a pool for every slot at
+``--max-len``: slots × max-len / page-size pages), copy-on-write prefix
 sharing keyed on prompt prefixes (drive it with ``--loadgen
 "...,prefix=32"``), and ``--prefill-chunk`` slicing long admits across
 decode ticks; the live stats line grows ``kv=`` (pool occupancy),
@@ -33,7 +34,7 @@ per-(row, head) scale blocks in HBM, dequantized per visited tile
 inside the decode kernel — the dominant decode HBM sweep shrinks ~2×
 vs bf16 and the same pool budget holds ~2× the tokens. The stats line
 shows the wire dtype (``kvd=``); ``--kv-dtype f32|bf16`` simply pin
-the dense cache dtype. Rejected with ``--decode-attention reference``
+the pool's dtype. Rejected with ``--decode-attention reference``
 (the oracle path dequantizes the whole cache per tick).
 
 ``--weights-dtype int8`` (ISSUE 17) quantizes the OTHER ~92% of the
@@ -78,9 +79,8 @@ a latent page pool, sigmoid-routed experts with no dropped token,
 hyper-connected residual streams (``models/xing4.py``), on random
 weights from ``--seed``: ``--model tiny`` or ``published``, or
 ``--model-config FILE`` with the keys of a published ``config.json``. It
-needs ``--kv-pages`` (the paged engine) and raises, by name, for what the
-family lacks: the dense cache, ``--mesh``, ``--kv-dtype int8``,
-``--weights-dtype int8``, ``--spec-k``, ``--kv-host-pages``, ``--ckpt``.
+raises, by name, for what the family lacks: ``--mesh``,
+``--kv-dtype int8``, ``--weights-dtype int8``, ``--spec-k``, ``--kv-host-pages``, ``--ckpt``.
 
 Config follows the ``asyncsgd.config`` pattern: one dataclass, argparse
 generated from its fields.
@@ -119,36 +119,36 @@ class ServeConfig:
     top_k: int = 0  # 0 = full vocab
     # Serving hot-loop implementation (ISSUE 5): kernel = Pallas
     # flash-decode + blocked LM-head sampling (reference fallback off
-    # TPU); reference = the dense PR 4 path; interpret = force the
+    # TPU); reference = gather-dense attention + whole-logits
+    # sampling, the parity oracle; interpret = force the
     # kernel through the Pallas interpreter (CPU testing).
     decode_attention: str = "kernel"
     # Blocked sampler's candidate-buffer width — bounds --top-k under
     # kernel/interpret modes (submit rejects top_k > this). Grown here
     # so the remedy the rejection names is reachable from the CLI.
     sample_k_cap: int = 128
-    # Paged KV cache (ISSUE 7). kv_pages > 0 selects the paged engine:
-    # HBM holds kv_pages × kv_page_size cache rows shared by all slots
-    # (max_len becomes a per-slot VIRTUAL capacity), prompts sharing a
-    # prefix map the same pages copy-on-write, and prefill_chunk > 0
-    # slices long admits across ticks so they can't head-of-line-block
-    # decode (0 = whole-prompt chunks).
+    # The page pool. HBM holds kv_pages × kv_page_size cache rows
+    # shared by all slots (max_len is a per-slot VIRTUAL capacity;
+    # kv_pages 0 = a pool for every slot at max_len: slots × max_len /
+    # kv_page_size pages), prompts sharing a prefix map the same pages
+    # copy-on-write, and prefill_chunk > 0 slices long admits across
+    # ticks so they can't head-of-line-block decode (0 = chunks of
+    # prefill_len: whole prompts).
     kv_pages: int = 0
     kv_page_size: int = 16
     prefill_chunk: int = 0
-    # Host KV tier (ISSUE 20). kv_host_pages > 0 gives the paged
-    # engine a host-RAM page store: preemption victims park their
-    # pages there (resume restreams instead of re-prefilling) and
-    # dying sole-reader prefix entries migrate there (admission hits
-    # keep working after their HBM pages are reclaimed). Passed
-    # through unconditionally so --kv-host-pages without --kv-pages
-    # surfaces the Engine's "paged-engine knob" rejection.
+    # Host KV tier (ISSUE 20). kv_host_pages > 0 gives the engine a
+    # host-RAM page store: preemption victims park their pages there
+    # (resume restreams instead of re-prefilling) and dying
+    # sole-reader prefix entries migrate there (admission hits keep
+    # working after their HBM pages are reclaimed).
     kv_host_pages: int = 0
     # KV cache wire dtype (ISSUE 15). "" = the model dtype (default
     # path, byte-identical); f32|bf16 pin the cache dtype; int8 stores
     # quantized rows + per-(row, head) scales and fuses the dequant
     # into the decode kernel's per-tile DMA loop — ~2x fewer decode
     # HBM bytes than bf16, ~2x tokens at the same pool budget.
-    # Rejected with --decode-attention reference: the dense reference
+    # Rejected with --decode-attention reference: the reference
     # path dequantizes the WHOLE cache per tick (it exists as the
     # parity oracle, not a serving path — the perf the flag buys needs
     # the fused per-tile dequant of kernel/interpret).
@@ -213,10 +213,9 @@ class ServeConfig:
 def _xing4_model(cfg: ServeConfig):
     """Random weights of the ``xing4`` family (``models/xing4.py``): the
     published sizes, those of ``--model-config``'s file, or the tiny
-    preset. The family serves through the paged engine on one chip in
-    bf16 or f32; the engine raises, by name, for what it lacks (the dense
-    cache, ``--mesh``, ``--kv-dtype int8``, ``--weights-dtype int8``,
-    ``--spec-k``, ``--kv-host-pages``)."""
+    preset. The family serves on one chip in bf16 or f32; the engine
+    raises, by name, for what it lacks (``--mesh``, ``--kv-dtype int8``,
+    ``--weights-dtype int8``, ``--spec-k``, ``--kv-host-pages``)."""
     import jax
 
     from mpit_tpu.models.xing4 import Xing4Config, init_params
@@ -257,7 +256,7 @@ def _build_engine(cfg: ServeConfig):
             f"--kv-dtype {cfg.kv_dtype!r}: expected f32, bf16 or int8"
         )
     if cfg.kv_dtype == "int8" and cfg.decode_attention == "reference":
-        # Precise submit-time rejection (ISSUE 15 satellite): the dense
+        # Precise submit-time rejection (ISSUE 15 satellite): the
         # reference engine HAS the dequant hooks (it is the parity
         # oracle) but dequantizes the whole cache every tick — serving
         # int8 through it pays quantization error for MORE bytes moved,
@@ -347,19 +346,6 @@ def _build_engine(cfg: ServeConfig):
                 f"state.npz, --draft-config tiny, or --draft-config "
                 f"truncate:N (got draft_config={cfg.draft_config!r})"
             )
-        if not cfg.kv_pages:
-            # The dense verify needs spec_k-1 rows of headroom past
-            # prompt + max_new (dynamic_update_slice clamps, it does
-            # not drop); reject the geometry here, not per request.
-            need = cfg.prompt_len + cfg.max_new_tokens + cfg.spec_k - 1
-            if not cfg.loadgen and need > cfg.max_len:
-                raise SystemExit(
-                    f"--spec-k {cfg.spec_k}: prompt_len + max_new_tokens"
-                    f" + spec_k - 1 = {need} > --max-len {cfg.max_len} "
-                    "on the dense engine; shrink the stream, lower "
-                    "--spec-k, grow --max-len, or use --kv-pages "
-                    "(the paged engine drops out-of-range draft rows)"
-                )
     elif cfg.draft_ckpt or cfg.draft_config:
         raise SystemExit(
             "--draft-ckpt/--draft-config require --spec-k >= 1"
@@ -378,9 +364,6 @@ def _build_engine(cfg: ServeConfig):
         kv_pages=cfg.kv_pages or None,
         kv_page_size=cfg.kv_page_size,
         kv_host_pages=cfg.kv_host_pages or None,
-        # Passed through unconditionally: --prefill-chunk without
-        # --kv-pages must surface the Engine's "paged-engine knob"
-        # rejection, not silently run whole-prompt prefills.
         prefill_chunk=cfg.prefill_chunk or None,
         spec_k=cfg.spec_k,
         draft_params=draft_params,
@@ -444,12 +427,12 @@ def _live_line(registry, monitor, server, now: float) -> str:
     )
     if server.policy is not None:
         line += f" pre={server.policy.preemptions}"
-    if getattr(server.engine, "kv_dtype_explicit", False):
+    if server.engine.kv_dtype_explicit:
         # The cache WIRE dtype (ISSUE 15): what the decode sweep
         # actually moves — shown whenever it was explicitly chosen, so
         # an int8 run's hbmbw= figure is attributable from the line.
         line += f" kvd={server.engine.kv_dtype}"
-    if getattr(server.engine, "weights_dtype_explicit", False):
+    if server.engine.weights_dtype_explicit:
         # The weight store's wire dtype (ISSUE 17): the param term of
         # the same sweep.
         line += f" wd={server.engine.weights_dtype}"
@@ -481,7 +464,7 @@ def _live_line(registry, monitor, server, now: float) -> str:
         # platform-labeled roofline block instead.
         line += f" hbmbw={bw / 1e9:.2f}GB/s"
         fl = r.get("decode_flops", {}).get("rate_per_s", 0.0)
-        if fl and getattr(server.engine, "platform", "") == "tpu":
+        if fl and server.engine.platform == "tpu":
             from mpit_tpu.obs.roofline import chip_peaks
 
             peak = chip_peaks(platform="tpu")["peak_flops"]
@@ -611,18 +594,7 @@ def main(argv: list[str] | None = None) -> dict:
                     f"--loadgen class {klass.name!r}: prefix + prompt_max "
                     f"+ new_max = {need} > --max-len {cfg.max_len}"
                 )
-            if cfg.spec_k and not cfg.kv_pages and (
-                need + cfg.spec_k - 1 > cfg.max_len
-            ):
-                raise SystemExit(
-                    f"--loadgen class {klass.name!r} + --spec-k "
-                    f"{cfg.spec_k}: the dense verify needs spec_k-1 "
-                    f"rows of headroom — prefix + prompt_max + new_max "
-                    f"+ spec_k - 1 = {need + cfg.spec_k - 1} > "
-                    f"--max-len {cfg.max_len}; lower --spec-k, grow "
-                    "--max-len, or use --kv-pages"
-                )
-        # Warm the engine's two compiles OUTSIDE the timed window — an
+        # Warm the engine's compiles OUTSIDE the timed window — an
         # open-loop harness that pays multi-second XLA compiles inside
         # its first arrivals' TTFT measures the compiler, not the
         # server. register_costs: the steps' cost_analysis lands in the
@@ -680,7 +652,7 @@ def main(argv: list[str] | None = None) -> dict:
         server.run()
         wall = time.perf_counter() - t0
 
-    if getattr(engine, "roofline_costs", None) is None:
+    if engine.roofline_costs is None:
         # Closed-loop path (no warm): register the step costs now —
         # registration is time-independent, so doing it after the run
         # still yields the full roofline roll-up below.

@@ -1,19 +1,30 @@
-"""Batched GPT-2 inference engine: ONE jitted prefill + ONE jitted decode.
+"""Batched inference engine over a page pool: ONE jitted prefill chunk +
+ONE jitted decode (+ the tiny page copy).
 
-The execution contract (ISSUE 4 tentpole):
+The execution contract:
 
 - **Fixed shapes, no per-request recompiles.** Both steps run over the
-  whole slot batch — prefill on ``[slots, prefill_len]`` padded prompts
-  with a per-slot admit mask (non-admitted slots compute and are
-  discarded by ``jnp.where``; the FLOP waste buys exactly two compiled
-  programs for the engine's whole lifetime), decode on ``[slots, 1]``.
-- **Prefill writes the cache** from position 0 of each admitted slot and
-  samples the request's FIRST output token from the logits at
-  ``prompt_len - 1``; **decode appends one token** per active slot at
-  its current length. Greedy outputs bit-match the no-cache
-  ``models.gpt2`` forward (parity-pinned in ``tests/test_serve.py``):
-  the cached attention is the same einsum/f32-softmax computation with
-  masked cache rows contributing exact zeros.
+  whole slot batch — a prefill chunk on ``[slots, prefill_chunk]`` padded
+  prompt slices (slots without a chunk compute and are discarded; past
+  ``_FULL_BATCH_ROWS`` rows the tick is compacted to its participants),
+  decode on ``[slots, 1]``.
+- **The cache is a page pool** (``serve.kvcache.PagedKVCache`` + the host
+  ``PageAllocator``): ``kv_pages`` pages of ``kv_page_size`` positions
+  shared by all slots, indirected by per-slot block tables. K/V appends
+  scatter through the tables (masked rows dropped, so a padded chunk can
+  never touch a page the slot does not own), attention runs the paged
+  flash-decode kernel (or the gather-dense reference), and ``max_len``
+  is a VIRTUAL per-slot capacity — HBM scales with ``kv_pages x
+  kv_page_size``. ``kv_pages=None`` sizes the pool so that every slot
+  can reach ``max_len``: ``slots x (max_len // kv_page_size)``.
+  ``prefill_chunk`` fixes the traced prefill width so the scheduler can
+  slice long admits across ticks (chunked prefill). The steps donate the
+  pool, so one lives.
+- **A chunk writes the cache** at each participating slot's fill and,
+  for the slots whose last prompt token rides it, samples the request's
+  FIRST output token; **decode appends one token** per active slot at
+  its current length. Greedy outputs match the no-cache ``models.gpt2``
+  forward (parity-pinned in ``tests/test_serve.py``).
 - **Sampling is jitted with the step**: per-slot greedy / temperature /
   top-k arrays, so heterogeneous requests batch together.
 
@@ -21,40 +32,27 @@ Tensor parallelism: ``Engine(..., world=w, tp_axis="model")`` swaps the
 flax forward for a hand-placed shard_map forward that reuses the
 ``parallel.megatron`` block rules — column-parallel qkv/fc,
 row-parallel proj/out closing on a psum, ``repack_qkv`` for contiguous
-head shards, ``tp_block_specs`` for the param placement — with the KV
-cache sharded on the head dim (``kvcache.cache_specs``). Embeddings and
-the LM head stay replicated (decode is latency-bound on the blocks; the
-head matmul at T=1 is negligible).
-
-Paged engine (ISSUE 7): ``Engine(kv_pages=N, kv_page_size=ps)`` swaps
-the dense per-slot cache for the shared page pool
-(``serve.kvcache.PagedKVCache`` + host ``PageAllocator``): K/V appends
-scatter through per-slot block tables (masked rows dropped, so a padded
-chunk can never touch a page the slot does not own), attention runs the
-paged flash-decode kernel (or the gather-dense reference), and
-``max_len`` becomes a VIRTUAL per-slot capacity — HBM scales with
-``kv_pages × kv_page_size``, not ``slots × max_len``. ``prefill_chunk``
-fixes the traced prefill width so the scheduler can slice long admits
-across ticks (chunked prefill); still exactly two compiles (+ the tiny
-COW page-copy). Same step count, same calling convention under TP.
+head shards, ``tp_block_specs`` for the param placement — with each
+layer's pool buffer sharded on its packed head axis
+(``kvcache.paged_cache_specs``). Embeddings and the LM head stay
+replicated (decode is latency-bound on the blocks; the head matmul at
+T=1 is negligible).
 
 Speculative decoding (ISSUE 13): ``Engine(spec_k=k, draft_params=...,
 draft_cfg=...)`` swaps the decode tick for draft-then-verify — a draft
-model (own KV cache; the paged draft pool mirrors the target's page
-geometry and shares its block tables, so COW/prefix-sharing/preemption
-carry draft K/V for free) proposes ``k`` tokens per slot, the target
-scores all ``k+1`` positions in ONE T=k+1 pass through the same
-forward (flash-decode small-T trace included), and cache lengths
-advance by the accepted count only (the rollback). Greedy speculative
-output bit-matches the plain engine per request; temperature/top-k go
-through exact rejection sampling against the blocked LM head
-(``ops.lm_head.lm_head_verify``; the reference engine verifies on
-materialized logits — the oracle). Compile count stays fixed for the
-engine's lifetime: prefill (draft fused), ``spec_draft``,
-``spec_verify`` (+ the COW copy on the paged engine).
+model (its pool mirrors the target's page geometry and shares its block
+tables, so COW/prefix-sharing/preemption carry draft K/V for free)
+proposes ``k`` tokens per slot, the target scores all ``k+1`` positions
+in ONE T=k+1 pass through the same forward (flash-decode small-T trace
+included), and cache lengths advance by the accepted count only (the
+rollback). Greedy speculative output bit-matches the plain engine per
+request; temperature/top-k go through exact rejection sampling against
+the blocked LM head (``ops.lm_head.lm_head_verify``; the reference
+engine verifies on materialized logits — the oracle). Compile count
+stays fixed for the engine's lifetime: prefill (draft fused),
+``spec_draft``, ``spec_verify``, the COW copy.
 
-Host surface: :meth:`Engine.prefill` (dense) /
-:meth:`Engine.prefill_paged` + :meth:`Engine.copy_page` (paged) /
+Host surface: :meth:`Engine.prefill_paged` + :meth:`Engine.copy_page` /
 :meth:`Engine.decode`, or :meth:`Engine.spec_draft` +
 :meth:`Engine.spec_verify` on a speculative engine — the scheduler
 (``serve.scheduler``) owns queueing, admission (page allocation, COW,
@@ -71,14 +69,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from mpit_tpu.models.gpt2 import (
-    cache_update,
-    cached_attention,
-    paged_cache_update,
-    paged_cached_attention,
-)
+from mpit_tpu.models.gpt2 import paged_cache_update, paged_cached_attention
 from mpit_tpu.models.serving import as_serve_model
-from mpit_tpu.ops.kv_quant import kv_stack, pack_heads, unpack_heads
+from mpit_tpu.ops.kv_quant import pack_heads, unpack_heads
 from mpit_tpu.ops.quantized_matmul import (
     QuantizedTensor,
     dequantize_tensor,
@@ -89,7 +82,6 @@ from mpit_tpu.ops.quantized_matmul import (
 from mpit_tpu import obs
 from mpit_tpu.obs import roofline as _roofline
 from mpit_tpu.ops.decode_attention import (
-    flash_decode_attention,
     flash_paged_decode_attention,
     num_kv_blocks,
     pick_block_k,
@@ -104,13 +96,10 @@ from mpit_tpu.serve.spec import (
     verify_reference,
 )
 from mpit_tpu.serve.kvcache import (
-    KVCache,
     PageAllocator,
     PagedKVCache,
     QuantizedKV,
-    alloc_cache,
     alloc_paged_cache,
-    cache_specs,
     paged_cache_specs,
 )
 from mpit_tpu.serve.weights import (
@@ -127,7 +116,7 @@ __all__ = ["Engine", "sample_tokens"]
 # writes quantize through the shared ring-collectives rounding
 # contract, the flash-decode kernel dequantizes per visited tile in
 # VMEM, and the reference path dequantizes through the same helpers
-# (the oracle). "f32"/"bf16" simply pin the dense cache dtype.
+# (the oracle). "f32"/"bf16" simply pin the pool's dtype.
 _KV_DTYPES = {"f32": jnp.float32, "bf16": jnp.bfloat16, "int8": None}
 _DTYPE_SHORT = {"float32": "f32", "bfloat16": "bf16", "int8": "int8"}
 
@@ -191,20 +180,13 @@ def _zeroed(cache):
     )
 
 
-def _kv_where(mask, new, old):
-    """Per-slot select over a K (or V) buffer — one ``jnp.where`` on a
-    plain array, the same where over int8 payload AND scale blocks on a
-    quantized buffer (equal rank by construction, so one broadcast mask
-    serves both leaves)."""
-    return jax.tree.map(lambda a, b: jnp.where(mask, a, b), new, old)
-
 # Engine.decode_attention values. "kernel" = the Pallas flash-decode path
 # (ISSUE 5) where available — on non-TPU backends the kernel call falls
 # back to the reference math, and decode_attention_mode says so;
 # "interpret" forces the kernel through the Pallas interpreter (the CPU
-# parity-test path); "reference" = the PR 4 hot loop unchanged (dense
-# cached_attention + materialized-logits sampling), kept as the parity
-# oracle and the perf comparison baseline.
+# parity-test path); "reference" = the gather-dense
+# paged_cached_attention + materialized-logits sampling, kept as the
+# parity oracle.
 _DECODE_MODES = ("kernel", "interpret", "reference")
 
 # Rows of a prefill chunk tick (slots x prefill_chunk) up to which the one
@@ -242,40 +224,41 @@ def sample_tokens(logits, key, temperature, top_k):
 # ---------------------------------------------------------------------------
 
 
-def _tp_forward_body(
-    params, tokens, lengths, *, cfg, axis, layer_kv, with_head,
-    clip_positions=False,
+def _tp_paged_forward(
+    params, tokens, cache: PagedKVCache, block_tables, write_valid, *,
+    cfg, axis, attn_fn=None, with_head=True,
 ):
-    """The shared cache-aware GPT-2 transformer loop INSIDE shard_map
-    over the TP axis — dense and paged differ ONLY in how a layer's
-    fresh K/V lands in the cache and what attention reads, injected as
-    ``layer_kv(i, q, k, v) -> (k_i, v_i, attn)`` (heads-local
-    [B, T, H/P, Dh] operands). Everything else — embeddings, the
-    megatron column/row-parallel block structure, ln_f, the optional
-    replicated head — is one implementation, so the dense/paged
-    bit-match parity the tests pin cannot silently diverge.
+    """The cache-aware GPT-2 transformer loop INSIDE shard_map over the
+    TP axis, against this device's H/P head shard of the page pool (each
+    layer's buffer ``[P, ps, H/P * Dh]``: the rank's contiguous slice of
+    the packed rows).
 
     The per-device view: block matmul kernels arrive sharded per
-    ``megatron.tp_block_specs`` (qkv in ``repack_qkv`` layout), the KV
-    cache carries this device's H/P heads, embeddings/LayerNorms/head
+    ``megatron.tp_block_specs`` (qkv in ``repack_qkv`` layout), the pool
+    carries this device's H/P heads, embeddings/LayerNorms/head
     replicated. Numerics mirror ``models.gpt2`` block-for-block —
     ``megatron.layernorm`` is the parity-tested nn.LayerNorm
-    equivalent; each half closes on a psum (row-parallel proj/out).
-    ``clip_positions`` (paged chunking): padding rows past a slot's
-    chunk can push past max_seq_len — clip; their embeddings are
-    write-masked / never attended anyway. Returns replicated
-    logits-or-hiddens + per-layer (k, v) lists.
-    """
+    equivalent; each half closes on a psum (row-parallel proj/out). K/V
+    appends scatter through the (replicated) block tables with
+    ``write_valid``-masked rows dropped; attention runs ``attn_fn``
+    (default the gather-dense :func:`paged_cached_attention`; the
+    serving engine plugs the paged flash kernel) against the pool.
+    Padding rows past a slot's chunk can push past max_seq_len — their
+    positions are clipped; their embeddings are write-masked / never
+    attended anyway. Returns replicated logits (or, ``with_head=False``,
+    the post-ln_f hiddens the blocked head samples from) + this device's
+    updated pool shard."""
     from jax import lax
 
     from mpit_tpu.parallel import megatron as M
 
     p = lax.axis_size(axis)
     heads_local = cfg.num_heads // p
+    lengths = cache.lengths
     t = tokens.shape[-1]
-    positions = lengths[:, None] + jnp.arange(t)[None, :]
-    if clip_positions:
-        positions = jnp.minimum(positions, cfg.max_seq_len - 1)
+    positions = jnp.minimum(
+        lengths[:, None] + jnp.arange(t)[None, :], cfg.max_seq_len - 1
+    )
     with jax.named_scope("embed"):
         emb = params["wte"][tokens]
         if isinstance(emb, QuantizedTensor):
@@ -307,7 +290,17 @@ def _tp_forward_body(
                 h, wdt(blk["qkv"]["kernel"]), blk["qkv"]["bias"].astype(dt)
             )
             q, k, v = jnp.split(qkv, 3, axis=-1)
-            k_i, v_i, attn = layer_kv(i, split(q), split(k), split(v))
+            with jax.named_scope("kv_write"):
+                # k, v are already the pool's packed rows [B, T, H/P*Dh].
+                k_i = paged_cache_update(
+                    cache.k[i], k, lengths, block_tables, valid=write_valid
+                )
+                v_i = paged_cache_update(
+                    cache.v[i], v, lengths, block_tables, valid=write_valid
+                )
+            attn = (attn_fn or paged_cached_attention)(
+                split(q), k_i, v_i, lengths, block_tables
+            )
             attn = attn.reshape(*attn.shape[:-2], -1)
             x = x + M.row_parallel_dense(
                 attn,
@@ -333,13 +326,14 @@ def _tp_forward_body(
         new_k.append(k_i)
         new_v.append(v_i)
 
+    new = PagedKVCache(k=tuple(new_k), v=tuple(new_v), lengths=lengths)
     with jax.named_scope("lm_head"):
         x = M.layernorm(x, params["ln_f"]["scale"], params["ln_f"]["bias"])
     if not with_head:
         # Blocked decode head: the replicated post-ln_f hiddens go back
         # to the jitted step, which samples via lm_head_sample — no
         # [B, T, vocab] logits here either.
-        return x, (new_k, new_v)
+        return x, new
     head = params.get("head", params["wte"])
     with jax.named_scope("lm_head"):
         if isinstance(head, QuantizedTensor):
@@ -357,83 +351,17 @@ def _tp_forward_body(
                 head.astype(cfg.head_dtype),
                 preferred_element_type=jnp.float32,
             )
-    return logits, (new_k, new_v)
-
-
-def _tp_cache_forward(
-    params, tokens, cache: KVCache, *, cfg, axis, attn_fn=None,
-    with_head=True,
-):
-    """Dense-cache TP forward: :func:`_tp_forward_body` with per-slot
-    buffer appends at ``lengths``. Returns replicated logits (or
-    hiddens) + this device's updated cache shard."""
-
-    def layer_kv(i, q, k, v):
-        with jax.named_scope("kv_write"):
-            k_i = cache_update(cache.k[i], k, cache.lengths)
-            v_i = cache_update(cache.v[i], v, cache.lengths)
-        # Heads-local by construction (kernel or reference): this
-        # device's H/P head shard of the cache goes in unchanged.
-        attn = (attn_fn or cached_attention)(q, k_i, v_i, cache.lengths)
-        return k_i, v_i, attn
-
-    out, (new_k, new_v) = _tp_forward_body(
-        params, tokens, cache.lengths, cfg=cfg, axis=axis,
-        layer_kv=layer_kv, with_head=with_head,
-    )
-    with jax.named_scope("kv_write"):
-        return out, KVCache(
-            k=kv_stack(new_k), v=kv_stack(new_v), lengths=cache.lengths
-        )
-
-
-def _tp_paged_forward(
-    params, tokens, cache: PagedKVCache, block_tables, write_valid, *,
-    cfg, axis, attn_fn=None, with_head=True,
-):
-    """Paged-cache TP forward (ISSUE 7): :func:`_tp_forward_body` with
-    the per-slot dense buffers swapped for this device's H/P head shard
-    of the page pool (each layer's buffer ``[P, ps, H/P * Dh]``: the
-    rank's contiguous slice of the packed rows) — K/V appends scatter
-    through the (replicated) block tables with ``write_valid``-masked
-    rows dropped, attention runs ``attn_fn`` (default the gather-dense
-    :func:`paged_cached_attention`; the serving engine plugs the paged
-    flash kernel) against the pool. Numerics per position are identical
-    to the dense TP forward — the pool is just a different placement of
-    the same rows."""
-
-    def layer_kv(i, q, k, v):
-        with jax.named_scope("kv_write"):
-            k_i = paged_cache_update(
-                cache.k[i], pack_heads(k), cache.lengths, block_tables,
-                valid=write_valid,
-            )
-            v_i = paged_cache_update(
-                cache.v[i], pack_heads(v), cache.lengths, block_tables,
-                valid=write_valid,
-            )
-        attn = (attn_fn or paged_cached_attention)(
-            q, k_i, v_i, cache.lengths, block_tables
-        )
-        return k_i, v_i, attn
-
-    out, (new_k, new_v) = _tp_forward_body(
-        params, tokens, cache.lengths, cfg=cfg, axis=axis,
-        layer_kv=layer_kv, with_head=with_head, clip_positions=True,
-    )
-    return out, PagedKVCache(
-        k=tuple(new_k), v=tuple(new_v), lengths=cache.lengths
-    )
+    return logits, new
 
 
 def _trimmed_sharding(world, spec):
     """NamedSharding for ``spec`` with trailing Nones dropped. jit keys
     on the canonical form — the steps' outputs come back as
-    ``P(..., axis)`` while ``cache_specs`` spells ``P(..., axis, None)``
-    — and a construction-vs-output sharding mismatch is one silent
-    recompile on the second admission wave. Deriving from the spec (not
-    a hardcoded literal) keeps cache_specs the single owner of the
-    cache's sharded-axis position."""
+    ``P(..., axis)`` whatever trailing Nones ``paged_cache_specs``
+    spells — and a construction-vs-output sharding mismatch is one
+    silent recompile on the second admission wave. Deriving from the
+    spec (not a hardcoded literal) keeps paged_cache_specs the single
+    owner of the pool's sharded-axis position."""
     parts = list(spec)
     while parts and parts[-1] is None:
         parts.pop()
@@ -480,7 +408,8 @@ def _tp_param_specs(cfg, params, axis: str):
 
 
 class Engine:
-    """Slot-batched KV-cache inference over one model's param tree.
+    """Slot-batched inference through a page pool, over one model's
+    param tree.
 
     ``model`` is a :class:`~mpit_tpu.models.serving.ServeModel`, or a
     configuration that names one (``GPT2Config`` does): the engine asks
@@ -523,7 +452,7 @@ class Engine:
         cfg = model.cfg
         # What this family does not have yet fails here, by name.
         model.check_supported(
-            paged=kv_pages is not None, tp=tp_axis is not None,
+            tp=tp_axis is not None,
             kv_dtype=kv_dtype, weights_dtype=weights_dtype,
             spec_k=int(spec_k or 0), host_pages=int(kv_host_pages or 0),
         )
@@ -546,8 +475,8 @@ class Engine:
         # None = the historical default (cache in cfg.dtype) — the path
         # stays byte-identical, pinned by the greedy-parity suite.
         # "int8" = quantized storage + in-kernel fused dequant; the
-        # engine's whole step surface (dense/paged/TP/chunked/spec)
-        # carries the dtype, still at the pinned lifetime compile count.
+        # engine's whole step surface (TP/chunked/spec) carries the
+        # dtype, still at the pinned lifetime compile count.
         if kv_dtype is not None and kv_dtype not in _KV_DTYPES:
             raise ValueError(
                 f"kv_dtype must be one of "
@@ -576,8 +505,8 @@ class Engine:
         # proj/fc/out kernels, wte, head — biases and LayerNorms stay
         # f32; they are ~0.1% of the bytes and additive precision is
         # cheap) and runs the blocked fused-dequant matmul everywhere:
-        # dense/paged/TP/chunked-prefill/speculative, at the same
-        # pinned lifetime compile count.
+        # TP/chunked-prefill/speculative, at the same pinned lifetime
+        # compile count.
         if weights_dtype is not None and weights_dtype not in _WEIGHT_DTYPES:
             raise ValueError(
                 f"weights_dtype must be one of {list(_WEIGHT_DTYPES)} (or "
@@ -591,14 +520,26 @@ class Engine:
         self.weights_dtype_explicit = weights_dtype is not None
         self.weights_dtype = weights_dtype or "f32"
 
-        # -- paged KV pool (ISSUE 7 tentpole) --------------------------------
-        # kv_pages selects the paged engine: HBM holds a fixed pool of
-        # page_size-token pages shared by all slots, indirected by the
-        # host allocator's per-slot block tables; max_len becomes the
-        # per-slot VIRTUAL capacity (pages_per_slot × page_size), not an
-        # HBM reservation. prefill_chunk splits long admits into chunk
-        # slices interleaved with decode ticks (scheduler-driven).
-        self.paged = kv_pages is not None
+        # -- the page pool ---------------------------------------------------
+        # HBM holds a fixed pool of page_size-token pages shared by all
+        # slots, indirected by the host allocator's per-slot block
+        # tables; max_len is the per-slot VIRTUAL capacity
+        # (pages_per_slot × page_size), not an HBM reservation.
+        # kv_pages is the pool's capacity and nothing else: None = every
+        # slot can reach max_len. prefill_chunk splits long admits into
+        # chunk slices interleaved with decode ticks (scheduler-driven).
+        if kv_page_size < 1 or self.max_len % kv_page_size:
+            raise ValueError(
+                f"kv_page_size {kv_page_size} must divide "
+                f"max_len={self.max_len} (pages_per_slot must be whole)"
+            )
+        self.page_size = kv_page_size
+        self.pages_per_slot = self.max_len // kv_page_size
+        if kv_pages is None:
+            kv_pages = slots * self.pages_per_slot
+        if kv_pages < 1:
+            raise ValueError(f"kv_pages must be >= 1, got {kv_pages}")
+        self.num_pages = kv_pages
         # ISSUE 20: host-RAM KV tier — host_pages page-sized spill
         # seats whose payloads live as numpy pytrees on this engine.
         # 0/None = no tier (every path byte-identical to pre-tiering).
@@ -606,29 +547,6 @@ class Engine:
         if self.host_pages < 0:
             raise ValueError(
                 f"kv_host_pages must be >= 0, got {kv_host_pages}"
-            )
-        if self.host_pages and not self.paged:
-            raise ValueError(
-                "kv_host_pages is the paged engine's host KV tier; the "
-                "dense cache spills whole slots via export_kv_rows "
-                "(pass kv_pages=)"
-            )
-        if self.paged:
-            if kv_pages < 1:
-                raise ValueError(f"kv_pages must be >= 1, got {kv_pages}")
-            if kv_page_size < 1 or self.max_len % kv_page_size:
-                raise ValueError(
-                    f"kv_page_size {kv_page_size} must divide "
-                    f"max_len={self.max_len} (pages_per_slot must be whole)"
-                )
-            self.page_size = kv_page_size
-            self.num_pages = kv_pages
-            self.pages_per_slot = self.max_len // kv_page_size
-        elif prefill_chunk is not None:
-            raise ValueError(
-                "prefill_chunk is the paged engine's chunked-prefill "
-                "knob; the dense cache prefills whole prompts (pass "
-                "kv_pages=)"
             )
         if prefill_chunk is not None and prefill_chunk < 1:
             raise ValueError(
@@ -641,22 +559,22 @@ class Engine:
             prefill_chunk or self.prefill_len, self.prefill_len
         )
         # Counts of participants a compacted chunk tick is compiled for
-        # (the paged engine sets them below; empty = the full-batch step).
+        # (set below; empty = the full-batch step).
         self._prefill_counts: tuple = ()
 
         # -- speculative decoding (ISSUE 13 tentpole) ------------------------
         # spec_k > 0 swaps the decode tick for per-slot draft-then-
-        # verify: a draft model (own KV cache — dense per-slot, or a
-        # page pool MIRRORING the target's page geometry so block
-        # tables, COW remaps and prefix sharing carry draft K/V for
-        # free) proposes k tokens per slot, the target scores all k+1
-        # positions in ONE T=k+1 pass through the existing forward
+        # verify: a draft model (its own page pool MIRRORING the
+        # target's page geometry so block tables, COW remaps and prefix
+        # sharing carry draft K/V for free) proposes k tokens per slot,
+        # the target scores all k+1 positions in ONE T=k+1 pass through
+        # the existing forward
         # (flash-decode small-T trace included), and cache lengths
         # advance by the accepted count only — rejected drafts' rows
         # become junk past the watermark, which the mask hides and the
         # next append overwrites (the rollback). Still a fixed compile
         # count for the engine's lifetime: prefill (draft fused),
-        # spec_draft, spec_verify (+ copy_page on the paged engine).
+        # spec_draft, spec_verify, copy_page.
         self.spec_k = int(spec_k or 0)
         if self.spec_k < 0:
             raise ValueError(f"spec_k must be >= 0, got {spec_k}")
@@ -690,29 +608,16 @@ class Engine:
 
         # -- serving hot-loop shape (ISSUE 5): attention kernel + head --
         self.decode_attention = decode_attention
-        if self.paged:
-            # block_k, the unit the visited count and the bytes model
-            # count in, divides page_size (the kernel's own tile is
-            # several whole pages: ops.decode_attention.decode_tiling).
-            self.decode_block_k = pick_block_k(self.page_size, decode_block_k)
-            if self.page_size % self.decode_block_k:
-                raise ValueError(
-                    f"decode_block_k={self.decode_block_k} does not divide "
-                    f"kv_page_size={self.page_size}; pick a divisor or omit "
-                    "it for the auto choice"
-                )
-        else:
-            self.decode_block_k = pick_block_k(self.max_len, decode_block_k)
-            if self.max_len % self.decode_block_k:
-                # Fail at construction, not at the first traced prefill —
-                # and never let the reference fallback run with tile
-                # accounting (skip counter, bench kv_blocks_*) that doesn't
-                # describe a real tiling.
-                raise ValueError(
-                    f"decode_block_k={self.decode_block_k} does not divide "
-                    f"max_len={self.max_len}; pick a divisor or omit it for "
-                    "the auto choice"
-                )
+        # block_k, the unit the visited count and the bytes model
+        # count in, divides page_size (the kernel's own tile is
+        # several whole pages: ops.decode_attention.decode_tiling).
+        self.decode_block_k = pick_block_k(self.page_size, decode_block_k)
+        if self.page_size % self.decode_block_k:
+            raise ValueError(
+                f"decode_block_k={self.decode_block_k} does not divide "
+                f"kv_page_size={self.page_size}; pick a divisor or omit "
+                "it for the auto choice"
+            )
         self._sample_block = sample_block
         platform = jax.devices()[0].platform
         # Where this engine's measurements are recorded — the label that
@@ -721,7 +626,7 @@ class Engine:
         # the recording platform IS the chip.
         self.platform = platform
         if decode_attention == "reference":
-            attn_fn = None  # cached_attention — the PR 4 path verbatim
+            attn_fn = None  # the gather-dense paged_cached_attention
             self.decode_attention_mode = "reference"
             self._blocked_head = False
         else:
@@ -729,9 +634,7 @@ class Engine:
             # The TP forward below takes the kernel by itself; the
             # one-chip forward takes it through the model.
             attn_fn = functools.partial(
-                flash_paged_decode_attention
-                if self.paged
-                else flash_decode_attention,
+                flash_paged_decode_attention,
                 block_k=self.decode_block_k,
                 interpret=interp,
             )
@@ -744,20 +647,19 @@ class Engine:
             )
             self._blocked_head = True
         # Blocked sampling bounds top_k by the static candidate-buffer
-        # width; the scheduler validates at submit. None = dense path,
-        # no bound.
+        # width; the scheduler validates at submit. None = the dense
+        # sampler, no bound.
         self.sample_k_cap = sample_k_cap if self._blocked_head else None
         # The head is pure XLA, so off-TPU "kernel" mode keeps the
         # blocked sampler even though attention falls back — the mode
         # label alone does NOT pin the whole hot-loop shape, this does:
         # attention=reference + sampler=blocked is the fallback engine,
-        # attention=reference + sampler=dense is the true PR 4 path.
+        # attention=reference + sampler=dense is the reference engine.
         self.decode_sampler = "blocked" if self._blocked_head else "dense"
         if attn_fn is not None:
             model = model.with_decode_attention(
-                paged=self.paged, block_k=self.decode_block_k,
-                interpret=interp,
-                page_size=self.page_size if self.paged else None,
+                block_k=self.decode_block_k, interpret=interp,
+                page_size=self.page_size,
             )
             # A family's kernel may tile the cache its own way; the
             # tile-count accounting follows what really runs.
@@ -823,56 +725,34 @@ class Engine:
                     ),
                 ),
             )
-            if self.paged:
-                cs = paged_cache_specs(
-                    tp_axis, num_layers=cfg.num_layers,
-                    quantized=self.kv_quantized,
-                )
-                sharding = _trimmed_sharding(
-                    world, cs.k[0].q if self.kv_quantized else cs.k[0]
-                )
-                rep = jax.sharding.PartitionSpec()
-                fwd = world.shard_map(
-                    functools.partial(
-                        _tp_paged_forward, cfg=cfg, axis=tp_axis,
-                        attn_fn=attn_fn, with_head=not self._blocked_head,
-                    ),
-                    in_specs=(self._specs, rep, cs, rep, rep),
-                    out_specs=(rep, cs),
-                )
-            else:
-                cs = cache_specs(tp_axis, quantized=self.kv_quantized)
-                sharding = _trimmed_sharding(
-                    world, cs.k.q if self.kv_quantized else cs.k
-                )
-                fwd = world.shard_map(
-                    functools.partial(
-                        _tp_cache_forward, cfg=cfg, axis=tp_axis,
-                        attn_fn=attn_fn, with_head=not self._blocked_head,
-                    ),
-                    in_specs=(self._specs, jax.sharding.PartitionSpec(), cs),
-                    out_specs=(jax.sharding.PartitionSpec(), cs),
-                )
-        elif self.paged:
+            cs = paged_cache_specs(
+                tp_axis, num_layers=cfg.num_layers,
+                quantized=self.kv_quantized,
+            )
+            sharding = _trimmed_sharding(
+                world, cs.k[0].q if self.kv_quantized else cs.k[0]
+            )
+            rep = jax.sharding.PartitionSpec()
+            fwd = world.shard_map(
+                functools.partial(
+                    _tp_paged_forward, cfg=cfg, axis=tp_axis,
+                    attn_fn=attn_fn, with_head=not self._blocked_head,
+                ),
+                in_specs=(self._specs, rep, cs, rep, rep),
+                out_specs=(rep, cs),
+            )
+        else:
 
             def fwd(prms, tokens, cache: PagedKVCache, block_tables,
                     write_valid, row_valid=None):
+                # Blocked head: the forward ends at ln_f and the step
+                # samples from hiddens; the reference engine: logits.
                 out, (k2, v2), aux = model.forward_paged(
                     prms, tokens, cache, block_tables, write_valid,
                     return_hidden=self._blocked_head, row_valid=row_valid,
                 )
                 new = PagedKVCache(k=k2, v=v2, lengths=cache.lengths)
                 return (out, new) if aux is None else (out, new, aux)
-
-        else:
-
-            def fwd(prms, tokens, cache: KVCache):
-                # Blocked head: the forward ends at ln_f and the step
-                # samples from hiddens; dense: logits as in PR 4.
-                out, (k2, v2) = model.forward_cached(
-                    prms, tokens, cache, return_hidden=self._blocked_head,
-                )
-                return out, KVCache(k=k2, v=v2, lengths=cache.lengths)
 
         self.model = model
         self.params = model.place(params) if tp_axis is None else params
@@ -915,23 +795,17 @@ class Engine:
                     draft_params,
                     jax.tree.map(lambda _: drep, draft_params),
                 )
-            if self.paged:
-                # The draft pool mirrors the target's page geometry AND
-                # its wire dtype (ISSUE 15): shared block tables carry
-                # quantized draft K/V + scales through COW / prefix
-                # sharing / preemption exactly as the target's.
-                self.draft_cache = alloc_paged_cache(
-                    draft_cfg, slots, self.num_pages, self.page_size,
-                    sharding=drep, dtype=self._cache_dtype,
-                    quantized=self.kv_quantized,
-                )
-            else:
-                self.draft_cache = alloc_cache(
-                    draft_cfg, slots, self.max_len, sharding=drep,
-                    dtype=self._cache_dtype, quantized=self.kv_quantized,
-                )
+            # The draft pool mirrors the target's page geometry AND
+            # its wire dtype (ISSUE 15): shared block tables carry
+            # quantized draft K/V + scales through COW / prefix
+            # sharing / preemption exactly as the target's.
+            self.draft_cache = alloc_paged_cache(
+                draft_cfg, slots, self.num_pages, self.page_size,
+                sharding=drep, dtype=self._cache_dtype,
+                quantized=self.kv_quantized,
+            )
             if drep is not None:
-                # lengths too — alloc_* shards only K/V, but a later
+                # lengths too — the alloc shards only K/V, but a later
                 # tick hands back mesh-replicated lengths, and a
                 # sharding change on ANY prefill operand is a recompile.
                 self.draft_cache = jax.device_put(
@@ -941,88 +815,71 @@ class Engine:
         else:
             self.draft_cache = None
         self.draft_params = draft_params
-        if self.paged:
-            # Host-side page bookkeeping: free list, refcounts, prefix
-            # index, COW reservations, per-slot block tables (the tables
-            # ride into every jitted step as a tiny int32 argument).
-            self.allocator = PageAllocator(
-                self.num_pages, self.page_size, self.pages_per_slot, slots,
-                host_pages=self.host_pages,
-            )
-            self.cache = alloc_paged_cache(
-                model, slots, self.num_pages, self.page_size,
-                sharding=sharding, dtype=self._cache_dtype,
-                quantized=self.kv_quantized,
-            )
-            # Every step that writes the pool donates it (argument
-            # positions of the cache and, on a speculative engine, the
-            # draft cache): the scatter of a tick's rows is then the
-            # only write the pool sees. The gather of a spill only reads.
-            draft = bool(self.spec_k)
-            self._prefill_paged_jit = _jit_as(
-                "prefill_paged", self._paged_prefill_step,
-                donate=(1, 13) if draft else (1,),
-            )
-            # A chunk tick computes [slots, prefill_chunk] rows whoever
-            # takes part. Up to _FULL_BATCH_ROWS that is cheap and the one
-            # step stays; past it the step runs over the participants
-            # only, compiled once for each power-of-two count of them
-            # whose rows fit _COMPACT_ROWS (more participants than the
-            # largest count go in several calls of a tick).
-            if (
-                slots * self.prefill_chunk > _FULL_BATCH_ROWS
-                and not self.spec_k and tp_axis is None
+        # Host-side page bookkeeping: free list, refcounts, prefix
+        # index, COW reservations, per-slot block tables (the tables
+        # ride into every jitted step as a tiny int32 argument).
+        self.allocator = PageAllocator(
+            self.num_pages, self.page_size, self.pages_per_slot, slots,
+            host_pages=self.host_pages,
+        )
+        self.cache = alloc_paged_cache(
+            model, slots, self.num_pages, self.page_size,
+            sharding=sharding, dtype=self._cache_dtype,
+            quantized=self.kv_quantized,
+        )
+        # Every step that writes the pool donates it (argument
+        # positions of the cache and, on a speculative engine, the
+        # draft cache): the scatter of a tick's rows is then the
+        # only write the pool sees. The gather of a spill only reads.
+        draft = bool(self.spec_k)
+        self._prefill_paged_jit = _jit_as(
+            "prefill_paged", self._paged_prefill_step,
+            donate=(1, 13) if draft else (1,),
+        )
+        # A chunk tick computes [slots, prefill_chunk] rows whoever
+        # takes part. Up to _FULL_BATCH_ROWS that is cheap and the one
+        # step stays; past it the step runs over the participants
+        # only, compiled once for each power-of-two count of them
+        # whose rows fit _COMPACT_ROWS (more participants than the
+        # largest count go in several calls of a tick).
+        if (
+            slots * self.prefill_chunk > _FULL_BATCH_ROWS
+            and not self.spec_k and tp_axis is None
+        ):
+            n, counts = 1, []
+            while n <= slots and (
+                not counts or n * self.prefill_chunk <= _COMPACT_ROWS
             ):
-                n, counts = 1, []
-                while n <= slots and (
-                    not counts or n * self.prefill_chunk <= _COMPACT_ROWS
-                ):
-                    counts.append(n)
-                    n *= 2
-                self._prefill_counts = tuple(counts)
-                self._prefill_compact_jit = _jit_as(
-                    "prefill_paged", self._paged_prefill_compact_step,
-                    donate=(1,),
-                )
-            if self.spec_k:
-                self._spec_draft_jit = _jit_as(
-                    "spec_draft", self._spec_draft_step, donate=(1,)
-                )
-                self._spec_verify_jit = _jit_as(
-                    "spec_verify", self._spec_verify_step, donate=(1,)
-                )
-            else:
-                self._decode_paged_jit = _jit_as(
-                    "decode_paged", self._paged_decode_step, donate=(1,)
-                )
-            self._copy_page_jit = _jit_as(
-                "copy_page", self._copy_page_step,
+                counts.append(n)
+                n *= 2
+            self._prefill_counts = tuple(counts)
+            self._prefill_compact_jit = _jit_as(
+                "prefill_paged", self._paged_prefill_compact_step,
+                donate=(1,),
+            )
+        if self.spec_k:
+            self._spec_draft_jit = _jit_as(
+                "spec_draft", self._spec_draft_step, donate=(1,)
+            )
+            self._spec_verify_jit = _jit_as(
+                "spec_verify", self._spec_verify_step, donate=(1,)
+            )
+        else:
+            self._decode_paged_jit = _jit_as(
+                "decode_paged", self._paged_decode_step, donate=(1,)
+            )
+        self._copy_page_jit = _jit_as(
+            "copy_page", self._copy_page_step,
+            donate=(0, 3) if draft else (0,),
+        )
+        if self.host_pages:
+            self._gather_page_jit = _jit_as(
+                "gather_page", self._gather_page_step
+            )
+            self._scatter_page_jit = _jit_as(
+                "scatter_page", self._scatter_page_step,
                 donate=(0, 3) if draft else (0,),
             )
-            if self.host_pages:
-                self._gather_page_jit = _jit_as(
-                    "gather_page", self._gather_page_step
-                )
-                self._scatter_page_jit = _jit_as(
-                    "scatter_page", self._scatter_page_step,
-                    donate=(0, 3) if draft else (0,),
-                )
-        else:
-            self.allocator = None
-            self.cache = alloc_cache(
-                cfg, slots, self.max_len, sharding=sharding,
-                dtype=self._cache_dtype, quantized=self.kv_quantized,
-            )
-            self._prefill_jit = _jit_as("prefill", self._prefill_step)
-            if self.spec_k:
-                self._spec_draft_jit = _jit_as(
-                    "spec_draft", self._spec_draft_step
-                )
-                self._spec_verify_jit = _jit_as(
-                    "spec_verify", self._spec_verify_step
-                )
-            else:
-                self._decode_jit = _jit_as("decode", self._decode_step)
         self.last_token = jnp.zeros((slots,), jnp.int32)
         if tp_axis is not None:
             # Pin the slot-width control state (lengths, last token)
@@ -1032,16 +889,16 @@ class Engine:
             # operand sharding — one silent extra compile per TP
             # engine, caught by the CompileWatch pin.
             rep = world.sharding()
-            self.cache = type(self.cache)(
+            self.cache = PagedKVCache(
                 k=self.cache.k,
                 v=self.cache.v,
                 lengths=jax.device_put(self.cache.lengths, rep),
             )
             self.last_token = jax.device_put(self.last_token, rep)
         self._forward = fwd
-        # Engine-lifetime compile accounting (ISSUE 8): the "two
-        # compiles (dense) / three (paged: + copy_page), zero
-        # per-request recompiles" claim as a runtime-guarded metric.
+        # Engine-lifetime compile accounting (ISSUE 8): the "three
+        # compiles (prefill chunk, decode, copy_page), zero per-request
+        # recompiles" claim as a runtime-guarded metric.
         # Every jitted-step invocation below routes through the watch;
         # growth past `expected` is an unexpected recompile (instant +
         # sentinel note — the Server attaches its sentinel; with a
@@ -1055,7 +912,7 @@ class Engine:
         # scatter_page — page ids traced, payload shapes fixed), still
         # zero per-request recompiles (ISSUE 20).
         self.compile_watch = _roofline.CompileWatch(
-            expected=(3 if self.paged else 2)
+            expected=3
             + (1 if self.spec_k else 0)
             + (2 if self.host_pages else 0)
             # one prefill step a count of participants (compacted ticks)
@@ -1118,64 +975,52 @@ class Engine:
         self.memledger.grant(
             "step_buffers", self.last_token.nbytes, kind="last_token"
         )
-        self.slot_bytes = 0
-        self.page_bytes = 0
-        if self.paged:
-            # What one granted page occupies across ALL layers, K and
-            # V, target AND draft pool (shared block tables mean a page
-            # grant maps rows in both buffers) — the allocator's unit
-            # for the nested kv_pages / kv_cow_reserve decomposition.
-            self.page_bytes = (kv_buf + draft_kv) // self.num_pages
+        # What one granted page occupies across ALL layers, K and
+        # V, target AND draft pool (shared block tables mean a page
+        # grant maps rows in both buffers) — the allocator's unit
+        # for the nested kv_pages / kv_cow_reserve decomposition.
+        self.page_bytes = (kv_buf + draft_kv) // self.num_pages
+        self.memledger.register(
+            "kv_pages",
+            capacity_bytes=self.num_pages * self.page_bytes,
+            nested_in="kv_pool",
+        )
+        self.memledger.register("kv_cow_reserve", nested_in="kv_pool")
+        self.allocator.memledger = self.memledger
+        self.allocator.page_bytes = self.page_bytes
+        if self.host_pages:
+            # ISSUE 20: the host-RAM page store. Charged at spill
+            # dispatch, refunded at restream / promotion / cold
+            # eviction / reset — the engine's spill/restore seam is
+            # the ONLY writer (the tier-seam lint pins this).
+            # nested_in="host_ram" keeps host bytes out of held()'s
+            # HBM total while per-tier conservation still holds.
             self.memledger.register(
-                "kv_pages",
-                capacity_bytes=self.num_pages * self.page_bytes,
-                nested_in="kv_pool",
+                "kv_host_pages",
+                capacity_bytes=self.host_pages * self.page_bytes,
+                nested_in="host_ram",
             )
-            self.memledger.register("kv_cow_reserve", nested_in="kv_pool")
-            self.allocator.memledger = self.memledger
-            self.allocator.page_bytes = self.page_bytes
-            if self.host_pages:
-                # ISSUE 20: the host-RAM page store. Charged at spill
-                # dispatch, refunded at restream / promotion / cold
-                # eviction / reset — the engine's spill/restore seam is
-                # the ONLY writer (the tier-seam lint pins this).
-                # nested_in="host_ram" keeps host bytes out of held()'s
-                # HBM total while per-tier conservation still holds.
-                self.memledger.register(
-                    "kv_host_pages",
-                    capacity_bytes=self.host_pages * self.page_bytes,
-                    nested_in="host_ram",
-                )
-                # host page id -> numpy pytree of one page's rows (K +
-                # V, every layer, int8 payload + scale blocks together,
-                # draft pool included on a speculative engine).
-                self._host_store: dict[int, Any] = {}
-                # Dispatched-but-undrained spills: (host_page, device
-                # pytree). The gather runs async under the decode tick
-                # it overlapped with (the Prefetcher's two-stage
-                # discipline); drain_spills() materializes at the next
-                # tick boundary or on demand before a restore.
-                self._pending_spills: list = []
-                self.host_spilled_pages = 0
-                self.host_restreamed_pages = 0
-                self.host_spill_bytes = 0
-                self.host_restream_bytes = 0
-        else:
-            # Dense: capacity is slot-granular; the scheduler grants/
-            # frees one slot reservation per admission/retirement.
-            self.slot_bytes = (kv_buf + draft_kv) // self.slots
-            self.memledger.register(
-                "kv_slots",
-                capacity_bytes=self.slots * self.slot_bytes,
-                nested_in="kv_pool",
-            )
+            # host page id -> numpy pytree of one page's rows (K +
+            # V, every layer, int8 payload + scale blocks together,
+            # draft pool included on a speculative engine).
+            self._host_store: dict[int, Any] = {}
+            # Dispatched-but-undrained spills: (host_page, device
+            # pytree). The gather runs async under the decode tick
+            # it overlapped with (the Prefetcher's two-stage
+            # discipline); drain_spills() materializes at the next
+            # tick boundary or on demand before a restore.
+            self._pending_spills: list = []
+            self.host_spilled_pages = 0
+            self.host_restreamed_pages = 0
+            self.host_spill_bytes = 0
+            self.host_restream_bytes = 0
 
     # -- jitted step bodies -------------------------------------------------
     def _sample_last(self, params, out, gather_idx, key, temp, topk):
         """Token per slot from the forward's output at ``gather_idx``
         — blocked path: gather the HIDDEN row and stream the head
-        (:func:`lm_head_sample`, no [slots, vocab] array); dense path:
-        gather the logits row and sample as in PR 4."""
+        (:func:`lm_head_sample`, no [slots, vocab] array); the reference
+        engine: gather the logits row and sample it whole."""
         with jax.named_scope("sample"):
             row = jnp.take_along_axis(
                 out, gather_idx[:, None, None], axis=1
@@ -1193,22 +1038,15 @@ class Engine:
             )
 
     # -- draft forwards (ISSUE 13) ------------------------------------------
-    def _draft_forward(self, dparams, tokens, dcache: KVCache, *, with_head):
-        """The draft model's dense cache-aware forward — reference
-        attention, materialized logits (the draft is small by
-        construction; its whole cost is the speculation overhead the
-        acceptance rate must beat). ``with_head=False`` (prefill) stops
-        at ln_f: the draft never samples at prefill."""
-        out, (k2, v2) = self._draft_model.forward_cached(
-            dparams, tokens, dcache, return_hidden=not with_head,
-        )
-        return out, KVCache(k=k2, v=v2, lengths=dcache.lengths)
-
     def _draft_forward_paged(
         self, dparams, tokens, dcache: PagedKVCache, block_tables,
         write_valid, *, with_head,
     ):
-        """Paged draft forward: the draft pool mirrors the target's
+        """The draft model's cache-aware forward — reference attention,
+        materialized logits (the draft is small by construction; its
+        whole cost is the speculation overhead the acceptance rate must
+        beat). ``with_head=False`` (prefill) stops at ln_f: the draft
+        never samples at prefill. The draft pool mirrors the target's
         page geometry and indirects through the SAME block tables, so
         prefix sharing, COW remaps and preemption free/remap draft K/V
         together with the target's."""
@@ -1218,71 +1056,6 @@ class Engine:
         )
         return out, PagedKVCache(k=k2, v=v2, lengths=dcache.lengths)
 
-    def _prefill_step(
-        self, params, cache, last, tokens, prompt_lens, admit, key, temp,
-        topk, dparams=None, dcache=None,
-    ):
-        """Whole-slot-batch prefill: every slot computes on the padded
-        [slots, prefill_len] buffer from position 0; only admitted
-        slots' cache writes / length resets / first tokens stick.
-        Speculative engines fuse the DRAFT prefill into the same step
-        (same tokens, the draft's own cache, no sampling) — the draft
-        cache fill mirrors the target's from the first tick."""
-        fresh = KVCache(
-            k=cache.k, v=cache.v, lengths=jnp.zeros_like(cache.lengths)
-        )
-        out, new = self._forward(params, tokens, fresh)
-        tok = self._sample_last(
-            params, out, jnp.maximum(prompt_lens - 1, 0), key, temp, topk
-        )
-        sel = admit[None, :, None, None, None]
-        new_cache = KVCache(
-            k=_kv_where(sel, new.k, cache.k),
-            v=_kv_where(sel, new.v, cache.v),
-            lengths=jnp.where(admit, prompt_lens, cache.lengths),
-        )
-        new_last = jnp.where(admit, tok, last)
-        if not self.spec_k:
-            return new_cache, new_last
-        dfresh = KVCache(
-            k=dcache.k, v=dcache.v, lengths=jnp.zeros_like(dcache.lengths)
-        )
-        _, dnew = self._draft_forward(
-            dparams, tokens, dfresh, with_head=False
-        )
-        return new_cache, new_last, KVCache(
-            k=_kv_where(sel, dnew.k, dcache.k),
-            v=_kv_where(sel, dnew.v, dcache.v),
-            lengths=new_cache.lengths,
-        )
-
-    def _decode_step(self, params, cache, last, active, key, temp, topk):
-        """One decode tick: append each active slot's last token at its
-        current length, sample the next from the new final output row."""
-        # Inactive slots are FREE slots (every live slot is active every
-        # tick) — clamp their lengths to 0 before the forward, or the
-        # length-aware kernel keeps paying a retired request's
-        # near-full-context tiles for an empty slot on every tick. Their
-        # compute was always discarded (write-back below is masked);
-        # this makes it 1 tile instead of ceil(stale_L/block_k).
-        lens = jnp.where(active, cache.lengths, 0)
-        cache = KVCache(k=cache.k, v=cache.v, lengths=lens)
-        out, new = self._forward(params, last[:, None], cache)
-        tok = self._sample_last(
-            params, out,
-            jnp.zeros((out.shape[0],), jnp.int32), key, temp, topk,
-        )
-        sel = active[None, :, None, None, None]
-        return (
-            KVCache(
-                k=_kv_where(sel, new.k, cache.k),
-                v=_kv_where(sel, new.v, cache.v),
-                lengths=jnp.where(active, lens + 1, lens),
-            ),
-            jnp.where(active, tok, last),
-        )
-
-    # -- paged jitted step bodies (ISSUE 7) ---------------------------------
     def _paged_prefill_step(
         self, params, cache, last, tokens, base, chunk_lens, floor,
         sample_mask, block_tables, key, temp, topk,
@@ -1419,7 +1192,7 @@ class Engine:
     # -- speculative tick bodies (ISSUE 13) ---------------------------------
     def _spec_draft_step(
         self, dparams, dcache, last, active, key, temp, topk,
-        block_tables=None, write_cap=None,
+        block_tables, write_cap,
     ):
         """Phase 1 of the speculative tick: k unrolled T=1 draft-model
         steps from each active slot's last token through the draft's
@@ -1439,23 +1212,17 @@ class Engine:
         drafted, qx, qprobs = [], [], []
         for j in range(k):
             lens_j = lens0 + j
-            if self.paged:
-                # Rows past the slot's mapped pages are DROPPED (the
-                # block table has no entry to scatter them through) and
-                # inactive slots' stale tables are never followed.
-                wv = active[:, None] & (
-                    lens_j[:, None] < write_cap[:, None]
-                )
-                work = PagedKVCache(k=dk, v=dv, lengths=lens_j)
-                out, new = self._draft_forward_paged(
-                    dparams, cur[:, None], work, block_tables, wv,
-                    with_head=True,
-                )
-            else:
-                work = KVCache(k=dk, v=dv, lengths=lens_j)
-                out, new = self._draft_forward(
-                    dparams, cur[:, None], work, with_head=True
-                )
+            # Rows past the slot's mapped pages are DROPPED (the
+            # block table has no entry to scatter them through) and
+            # inactive slots' stale tables are never followed.
+            wv = active[:, None] & (
+                lens_j[:, None] < write_cap[:, None]
+            )
+            work = PagedKVCache(k=dk, v=dv, lengths=lens_j)
+            out, new = self._draft_forward_paged(
+                dparams, cur[:, None], work, block_tables, wv,
+                with_head=True,
+            )
             dk, dv = new.k, new.v
             logits = out[:, 0].astype(jnp.float32)
             probs, scaled = draft_distribution(logits, temp, topk)
@@ -1483,21 +1250,14 @@ class Engine:
         # On a rejected tick the row sits past the watermark, masked,
         # like every other rejected draft row.
         lens_k = lens0 + k
-        if self.paged:
-            wv = active[:, None] & (lens_k[:, None] < write_cap[:, None])
-            work = PagedKVCache(k=dk, v=dv, lengths=lens_k)
-            _, new = self._draft_forward_paged(
-                dparams, cur[:, None], work, block_tables, wv,
-                with_head=False,
-            )
-        else:
-            work = KVCache(k=dk, v=dv, lengths=lens_k)
-            _, new = self._draft_forward(
-                dparams, cur[:, None], work, with_head=False
-            )
-        cls = PagedKVCache if self.paged else KVCache
+        wv = active[:, None] & (lens_k[:, None] < write_cap[:, None])
+        work = PagedKVCache(k=dk, v=dv, lengths=lens_k)
+        _, new = self._draft_forward_paged(
+            dparams, cur[:, None], work, block_tables, wv,
+            with_head=False,
+        )
         return (
-            cls(k=new.k, v=new.v, lengths=dcache.lengths),
+            PagedKVCache(k=new.k, v=new.v, lengths=dcache.lengths),
             jnp.stack(drafted, axis=1),  # [S, k] int32
             jnp.stack(qx, axis=1),       # [S, k] f32
             jnp.stack(qprobs, axis=1),   # [S, k, V] f32
@@ -1505,7 +1265,7 @@ class Engine:
 
     def _spec_verify_step(
         self, params, cache, last, active, drafted, qx, qprobs, key,
-        temp, topk, budget, eos, block_tables=None, write_cap=None,
+        temp, topk, budget, eos, block_tables, write_cap,
     ):
         """Phase 2: ONE T=k+1 target pass over ``[last, d_1..d_k]``
         (the flash-decode kernel's small-T trace — k+1 query rows, the
@@ -1515,18 +1275,14 @@ class Engine:
         is), then longest-accepted-prefix emission. Cache lengths
         advance by the accepted count ONLY: rejected drafts' K/V rows
         sit past the new watermark, masked, overwritten by the next
-        append — the rollback, dense and paged alike."""
+        append — the rollback."""
         k = self.spec_k
         lens = jnp.where(active, cache.lengths, 0)
         feed = jnp.concatenate([last[:, None], drafted], axis=1)
-        if self.paged:
-            pos = lens[:, None] + jnp.arange(k + 1, dtype=jnp.int32)[None]
-            wv = active[:, None] & (pos < write_cap[:, None])
-            work = PagedKVCache(k=cache.k, v=cache.v, lengths=lens)
-            out, new = self._forward(params, feed, work, block_tables, wv)
-        else:
-            work = KVCache(k=cache.k, v=cache.v, lengths=lens)
-            out, new = self._forward(params, feed, work)
+        pos = lens[:, None] + jnp.arange(k + 1, dtype=jnp.int32)[None]
+        wv = active[:, None] & (pos < write_cap[:, None])
+        work = PagedKVCache(k=cache.k, v=cache.v, lengths=lens)
+        out, new = self._forward(params, feed, work, block_tables, wv)
         s = out.shape[0]
         nrows = s * (k + 1)
         vkey, ukey = jax.random.split(key)
@@ -1579,17 +1335,7 @@ class Engine:
             )[:, 0],
             last,
         )
-        if self.paged:
-            out_cache = PagedKVCache(
-                k=new.k, v=new.v, lengths=lens + n_emit
-            )
-        else:
-            sel = active[None, :, None, None, None]
-            out_cache = KVCache(
-                k=_kv_where(sel, new.k, cache.k),
-                v=_kv_where(sel, new.v, cache.v),
-                lengths=lens + n_emit,
-            )
+        out_cache = PagedKVCache(k=new.k, v=new.v, lengths=lens + n_emit)
         # The fill a second time, for the draft cache: two outputs are
         # two buffers, and a donating step must not find one buffer in
         # both caches.
@@ -1709,57 +1455,15 @@ class Engine:
             held = self._staged[name] = (host.copy(), jnp.asarray(host))
         return held[1]
 
-    def prefill(self, tokens, prompt_lens, admit, temp, topk) -> np.ndarray:
-        """Admit requests: ``tokens`` [slots, prefill_len] int32 (padded),
-        ``prompt_lens``/``admit``/``temp``/``topk`` [slots]. Returns the
-        per-slot last token (the first OUTPUT token for admitted slots)
-        as host numpy — the fetch is the step's completion fence."""
-        if self.paged:
-            raise ValueError(
-                "the paged engine prefills through prefill_paged (block-"
-                "table writes + chunking); the dense prefill has no pages"
-            )
-        with obs.span("prefill_dispatch"):  # staging and enqueue
-            args = [
-                self.params,
-                self.cache,
-                self.last_token,
-                jnp.asarray(tokens, jnp.int32),
-                jnp.asarray(prompt_lens, jnp.int32),
-                jnp.asarray(admit, bool),
-                self._split(),
-                jnp.asarray(temp, jnp.float32),
-                jnp.asarray(topk, jnp.int32),
-            ]
-            if self.spec_k:
-                args += [self.draft_params, self.draft_cache]
-                self.cache, self.last_token, self.draft_cache = (
-                    self.compile_watch.call(
-                        "prefill", self._prefill_jit, *args
-                    )
-                )
-            else:
-                self.cache, self.last_token = self.compile_watch.call(
-                    "prefill", self._prefill_jit, *args
-                )
-        with obs.span("prefill_fetch"):  # the wait and the copy back
-            # The step's one deliberate completion fence (docstring
-            # contract: the fetch closes the caller's span).
-            # analysis: allow(host-sync-in-hot-seam)
-            return np.asarray(self.last_token)
-
     def prefill_paged(
         self, tokens, base, chunk_lens, floor, sample_mask, temp, topk
     ) -> np.ndarray:
-        """One prefill chunk over the slot batch (paged engine):
-        ``tokens`` [slots, prefill_chunk] int32 (padded slices),
-        ``base``/``chunk_lens``/``floor`` [slots] int32 and
+        """One prefill chunk over the slot batch: ``tokens``
+        [slots, prefill_chunk] int32 (padded slices), ``base``/``chunk_lens``/``floor`` [slots] int32 and
         ``sample_mask`` [slots] bool per :meth:`_paged_prefill_step`.
         Block tables come from the engine's allocator. Returns the
         per-slot last token (the first OUTPUT token for slots whose
         ``sample_mask`` is set) as host numpy."""
-        if not self.paged:
-            raise ValueError("prefill_paged requires Engine(kv_pages=...)")
         if self._prefill_counts:
             return self._prefill_paged_compact(
                 tokens, base, chunk_lens, floor, sample_mask, temp, topk
@@ -2027,11 +1731,10 @@ class Engine:
             jnp.asarray(temp, jnp.float32),
             jnp.asarray(topk, jnp.int32),
         ]
-        if self.paged:
-            args += [
-                jnp.asarray(self.allocator.block_tables, jnp.int32),
-                jnp.asarray(self.allocator.mapped_tokens(), jnp.int32),
-            ]
+        args += [
+            jnp.asarray(self.allocator.block_tables, jnp.int32),
+            jnp.asarray(self.allocator.mapped_tokens(), jnp.int32),
+        ]
         self.draft_cache, drafted, qx, qprobs = self.compile_watch.call(
             "spec_draft", self._spec_draft_jit, *args
         )
@@ -2067,11 +1770,10 @@ class Engine:
             jnp.asarray(budget, jnp.int32),
             jnp.asarray(eos, jnp.int32),
         ]
-        if self.paged:
-            args += [
-                jnp.asarray(self.allocator.block_tables, jnp.int32),
-                jnp.asarray(self.allocator.mapped_tokens(), jnp.int32),
-            ]
+        args += [
+            jnp.asarray(self.allocator.block_tables, jnp.int32),
+            jnp.asarray(self.allocator.mapped_tokens(), jnp.int32),
+        ]
         self.cache, self.last_token, emit, n_emit, n_acc, fill = (
             self.compile_watch.call(
                 "spec_verify", self._spec_verify_jit, *args
@@ -2080,7 +1782,7 @@ class Engine:
         # The draft cache's fill mirrors the target's — ONE lengths
         # assignment applies the acceptance rollback to both.
         dc = self.draft_cache
-        self.draft_cache = type(dc)(k=dc.k, v=dc.v, lengths=fill)
+        self.draft_cache = PagedKVCache(k=dc.k, v=dc.v, lengths=fill)
         # The verify step's deliberate completion fence (docstring
         # contract).
         # analysis: allow(host-sync-in-hot-seam)
@@ -2100,22 +1802,15 @@ class Engine:
                 self.cache,
                 self.last_token,
                 self._stage("active", active, bool),
-            ]
-            if self.paged:
-                args.append(
-                    self._stage(
-                        "block_tables", self.allocator.block_tables, np.int32
-                    )
-                )
-            args += [
+                self._stage(
+                    "block_tables", self.allocator.block_tables, np.int32
+                ),
                 self._split(),
                 self._stage("temp", temp, np.float32),
                 self._stage("topk", topk, np.int32),
             ]
             self.cache, self.last_token, *aux = self.compile_watch.call(
-                "decode",
-                self._decode_paged_jit if self.paged else self._decode_jit,
-                *args,
+                "decode", self._decode_paged_jit, *args
             )
             self._split_ahead()
         with obs.span("decode_fetch"):  # the wait and the copy back
@@ -2151,75 +1846,43 @@ class Engine:
         spec_tail = (
             [self.draft_params, self.draft_cache] if self.spec_k else []
         )
-        if self.paged:
-            toks = jnp.zeros((s, self.prefill_chunk), jnp.int32)
-            bt = jnp.zeros((s, self.pages_per_slot), jnp.int32)
-            steps = {
-                "prefill": (
-                    self._prefill_paged_jit,
-                    (self.params, self.cache, self.last_token, toks, i32,
-                     i32, i32, msk, bt, key, f32, i32, *spec_tail),
-                ),
-            }
-            if self._prefill_counts:  # the step of one participant
-                steps["prefill"] = (
-                    self._prefill_compact_jit,
-                    (self.params, self.cache, self.last_token, i32[:1],
-                     toks[:1], i32[:1], i32[:1], i32[:1], msk[:1], bt,
-                     key, f32, i32),
-                )
-            if self.spec_k:
-                k = self.spec_k
-                steps["spec_draft"] = (
-                    self._spec_draft_jit,
-                    (self.draft_params, self.draft_cache,
-                     self.last_token, msk, key, f32, i32, bt, i32),
-                )
-                steps["spec_verify"] = (
-                    self._spec_verify_jit,
-                    (self.params, self.cache, self.last_token, msk,
-                     jnp.zeros((s, k), jnp.int32),
-                     jnp.zeros((s, k), jnp.float32),
-                     jnp.zeros((s, k, self.cfg.vocab_size), jnp.float32),
-                     key, f32, i32, i32, i32, bt, i32),
-                )
-            else:
-                steps["decode"] = (
-                    self._decode_paged_jit,
-                    (self.params, self.cache, self.last_token, msk, bt,
-                     key, f32, i32),
-                )
+        toks = jnp.zeros((s, self.prefill_chunk), jnp.int32)
+        bt = jnp.zeros((s, self.pages_per_slot), jnp.int32)
+        steps = {
+            "prefill": (
+                self._prefill_paged_jit,
+                (self.params, self.cache, self.last_token, toks, i32,
+                 i32, i32, msk, bt, key, f32, i32, *spec_tail),
+            ),
+        }
+        if self._prefill_counts:  # the step of one participant
+            steps["prefill"] = (
+                self._prefill_compact_jit,
+                (self.params, self.cache, self.last_token, i32[:1],
+                 toks[:1], i32[:1], i32[:1], i32[:1], msk[:1], bt,
+                 key, f32, i32),
+            )
+        if self.spec_k:
+            k = self.spec_k
+            steps["spec_draft"] = (
+                self._spec_draft_jit,
+                (self.draft_params, self.draft_cache,
+                 self.last_token, msk, key, f32, i32, bt, i32),
+            )
+            steps["spec_verify"] = (
+                self._spec_verify_jit,
+                (self.params, self.cache, self.last_token, msk,
+                 jnp.zeros((s, k), jnp.int32),
+                 jnp.zeros((s, k), jnp.float32),
+                 jnp.zeros((s, k, self.cfg.vocab_size), jnp.float32),
+                 key, f32, i32, i32, i32, bt, i32),
+            )
         else:
-            toks = jnp.zeros((s, self.prefill_len), jnp.int32)
-            steps = {
-                "prefill": (
-                    self._prefill_jit,
-                    (self.params, self.cache, self.last_token, toks,
-                     jnp.ones((s,), jnp.int32), msk, key, f32, i32,
-                     *spec_tail),
-                ),
-            }
-            if self.spec_k:
-                k = self.spec_k
-                steps["spec_draft"] = (
-                    self._spec_draft_jit,
-                    (self.draft_params, self.draft_cache,
-                     self.last_token, msk, key, f32, i32),
-                )
-                steps["spec_verify"] = (
-                    self._spec_verify_jit,
-                    (self.params, self.cache, self.last_token, msk,
-                     jnp.zeros((s, k), jnp.int32),
-                     jnp.zeros((s, k), jnp.float32),
-                     jnp.zeros((s, k, self.cfg.vocab_size), jnp.float32),
-                     key, f32, i32, i32, i32),
-                )
-            else:
-                steps["decode"] = (
-                    self._decode_jit,
-                    (self.params, self.cache, self.last_token, msk, key,
-                     f32, i32),
-                )
+            steps["decode"] = (
+                self._decode_paged_jit,
+                (self.params, self.cache, self.last_token, msk, bt,
+                 key, f32, i32),
+            )
         out = {}
         for phase, (fn, args) in steps.items():
             try:
@@ -2249,8 +1912,7 @@ class Engine:
         if self.decode_attention_mode != "kernel":
             return {}
         return self.model.attention_tiling(
-            t_q, block_k=self.decode_block_k,
-            page_size=self.page_size if self.paged else None,
+            t_q, page_size=self.page_size,
             kv_dtype=jnp.int8 if self.kv_quantized else (
                 self._cache_dtype or self.cfg.dtype),
             tp=self._tp_ways,
@@ -2274,7 +1936,7 @@ class Engine:
         ``include_params=False`` drops the (dtype-independent) param
         read — the KV-sweep-only figure the bench's kv-dtype A/B
         ratios, since the sweep is the term quantization shrinks.
-        ``None`` on the dense reference engine (no tiling claim to
+        ``None`` on the reference engine (no tiling claim to
         account); on the off-TPU kernel fallback the figure is the
         MODEL of the kernel path (the platform label on the registered
         cost marks it modeled)."""
@@ -2305,69 +1967,52 @@ class Engine:
         self.last_token = jnp.zeros_like(self.last_token)
         self._key, self._sub = jax.random.key(seed), None
         self._spec_state = None
-        if self.paged:
-            if self.host_pages:
-                # The host tier empties with the pool: drop payloads
-                # (pending dispatches included) and refund every byte
-                # still charged, keeping per-tier conservation exact.
-                self._pending_spills.clear()
-                self._host_store.clear()
-                held = self.memledger.held("kv_host_pages")
-                if held:
-                    self.memledger.free("kv_host_pages", held, kind="reset")
-                self.host_spilled_pages = 0
-                self.host_restreamed_pages = 0
-                self.host_spill_bytes = 0
-                self.host_restream_bytes = 0
-            self.allocator.reset()
-        else:
-            # Dense slot reservations are the scheduler's grants; a
-            # reset drops them all (the paged arm's allocator.reset
-            # emits the equivalent kv_pages frees itself).
-            held = self.memledger.held("kv_slots")
+        if self.host_pages:
+            # The host tier empties with the pool: drop payloads
+            # (pending dispatches included) and refund every byte
+            # still charged, keeping per-tier conservation exact.
+            self._pending_spills.clear()
+            self._host_store.clear()
+            held = self.memledger.held("kv_host_pages")
             if held:
-                self.memledger.free("kv_slots", held, kind="reset")
+                self.memledger.free("kv_host_pages", held, kind="reset")
+            self.host_spilled_pages = 0
+            self.host_restreamed_pages = 0
+            self.host_spill_bytes = 0
+            self.host_restream_bytes = 0
+        self.allocator.reset()
         # Owner recency and exhaustion forensics describe the LAST run;
         # static buffer grants persist (the buffers do too).
         self.memledger.reset_transients()
 
     def export_kv_rows(self, slot: int, length: int):
         """Host copy of ``slot``'s first ``length`` cached KV rows in
-        the canonical dense row layout ``[L, length, H, Dh]`` (scale
-        leaves ``[L, length, H, 1]`` on a quantized cache — jax.tree.map
-        descends the QuantizedKV pair). Dense and paged engines yield
-        identical arrays for identical fills — a paged export gathers
-        the slot's block-table pages and trims the tail pad — so a
-        fleet shipment packed from either injects into either. Returns
+        the canonical row layout ``[L, length, H, Dh]`` (scale leaves
+        ``[L, length, H, 1]`` on a quantized cache — jax.tree.map
+        descends the QuantizedKV pair): the slot's block-table pages,
+        gathered, with the tail pad trimmed. Whatever the page geometry,
+        identical fills yield identical arrays, so a fleet shipment
+        packed on one engine injects into any other. Returns
         ``(k_rows, v_rows)``."""
         self.model.check_shipment()
         if length <= 0:
             raise ValueError(f"export_kv_rows needs length > 0, got {length}")
-        if self.paged:
-            ps = self.page_size
-            npages = -(-length // ps)
-            pages = np.asarray(
-                self.allocator.block_tables[slot, :npages], np.int32
-            )
+        ps = self.page_size
+        npages = -(-length // ps)
+        pages = np.asarray(
+            self.allocator.block_tables[slot, :npages], np.int32
+        )
 
-            def rows(*layers):
-                # The slot's pages of every layer, stacked on the device
-                # so that one array comes to the host: [L, npages, ps, ·].
-                arr = np.asarray(jnp.stack([buf[pages] for buf in layers]))
-                arr = arr.reshape(len(layers), npages * ps, -1)
-                return arr[:, :length].copy()
+        def rows(*layers):
+            # The slot's pages of every layer, stacked on the device
+            # so that one array comes to the host: [L, npages, ps, ·].
+            arr = np.asarray(jnp.stack([buf[pages] for buf in layers]))
+            arr = arr.reshape(len(layers), npages * ps, -1)
+            return arr[:, :length].copy()
 
-            return tuple(
-                unpack_heads(jax.tree.map(rows, *pool), self.cfg.num_heads)
-                for pool in (self.cache.k, self.cache.v)
-            )
-
-        def rows(buf):
-            return np.asarray(buf[:, slot, :length])
-
-        return (
-            jax.tree.map(rows, self.cache.k),
-            jax.tree.map(rows, self.cache.v),
+        return tuple(
+            unpack_heads(jax.tree.map(rows, *pool), self.cfg.num_heads)
+            for pool in (self.cache.k, self.cache.v)
         )
 
     def inject_kv_rows(
@@ -2376,10 +2021,9 @@ class Engine:
         """Inverse of :meth:`export_kv_rows`: install ``length`` rows of
         shipped KV state into ``slot`` and arm it for decode —
         ``lengths[slot] = length``, ``last_token[slot] = first_token``
-        (the token the shipping side sampled at prefill end). On a
-        paged engine the caller has already run ``allocator.admit`` for
-        the slot (all-or-nothing, no ``register_prefix`` — injected
-        pages are private, never prefix-shared); rows scatter into the
+        (the token the shipping side sampled at prefill end). The
+        caller has already run ``allocator.admit`` for the slot
+        (all-or-nothing, no ``register_prefix`` — injected pages are private, never prefix-shared); rows scatter into the
         slot's mapped pages. ``k_rows``/``v_rows`` match the export
         layout — raw arrays, or objects with ``.q``/``.scale`` for a
         quantized cache (any container with those attributes works;
@@ -2390,47 +2034,36 @@ class Engine:
             # leaves positionally whatever container shipped them.
             k_rows = QuantizedKV(q=k_rows.q, scale=k_rows.scale)
             v_rows = QuantizedKV(q=v_rows.q, scale=v_rows.scale)
-        if self.paged:
-            ps = self.page_size
-            npages = -(-length // ps)
-            pages = np.asarray(
-                self.allocator.block_tables[slot, :npages], np.int32
+        ps = self.page_size
+        npages = -(-length // ps)
+        pages = np.asarray(
+            self.allocator.block_tables[slot, :npages], np.int32
+        )
+
+        def put(pool, rows):
+            # Canonical rows [L, length, H, ·] to the pool's packed
+            # form, then whole pages (the tail page as far as filled)
+            # into each layer's buffer. Not a jitted step: a
+            # shipment lands once a request, not once a tick.
+            rows = jax.tree.map(np.asarray, pack_heads(rows))
+
+            def put1(buf, layer_rows):
+                layer_rows = jnp.asarray(layer_rows, buf.dtype)
+                for i in range(npages):
+                    n = min(ps, length - i * ps)
+                    buf = buf.at[int(pages[i]), :n].set(
+                        layer_rows[i * ps : i * ps + n]
+                    )
+                return buf
+
+            return tuple(
+                jax.tree.map(
+                    put1, layer, jax.tree.map(lambda r: r[i], rows)
+                )
+                for i, layer in enumerate(pool)
             )
 
-            def put(pool, rows):
-                # Canonical rows [L, length, H, ·] to the pool's packed
-                # form, then whole pages (the tail page as far as filled)
-                # into each layer's buffer. Not a jitted step: a
-                # shipment lands once a request, not once a tick.
-                rows = jax.tree.map(np.asarray, pack_heads(rows))
-
-                def put1(buf, layer_rows):
-                    layer_rows = jnp.asarray(layer_rows, buf.dtype)
-                    for i in range(npages):
-                        n = min(ps, length - i * ps)
-                        buf = buf.at[int(pages[i]), :n].set(
-                            layer_rows[i * ps : i * ps + n]
-                        )
-                    return buf
-
-                return tuple(
-                    jax.tree.map(
-                        put1, layer, jax.tree.map(lambda r: r[i], rows)
-                    )
-                    for i, layer in enumerate(pool)
-                )
-
-        else:
-
-            def put(cache_kv, rows):
-                return jax.tree.map(
-                    lambda buf, r: buf.at[:, slot, :length].set(
-                        jnp.asarray(np.asarray(r), buf.dtype)
-                    ),
-                    cache_kv, rows,
-                )
-
-        self.cache = type(self.cache)(
+        self.cache = PagedKVCache(
             k=put(self.cache.k, k_rows),
             v=put(self.cache.v, v_rows),
             lengths=self.cache.lengths.at[slot].set(int(length)),

@@ -19,9 +19,9 @@ shape with the collectives embedded in the serving dataflow:
   :mod:`~mpit_tpu.serve.shipment` on the dedicated
   ``Comm_dup("fleet-kv")`` channel.
 - **ranks P+1..P+D, decode workers**: admit shipments into their own
-  slots/pages (paged: an all-or-nothing ``allocator.admit``; dense: a
-  memledger-granted slot), inject the KV rows, and stream decode ticks
-  until EOS/max-tokens, reporting completions to the router.
+  slots and pages (an all-or-nothing ``allocator.admit``), inject the
+  KV rows, and stream decode ticks until EOS/max-tokens, reporting
+  completions to the router.
 
 Every worker runs the elastic heartbeat-thread idiom (bind_thread +
 the rank's own recorder); a killed worker (``FaultPlan.kill_at``)
@@ -468,10 +468,9 @@ def _fleet_router(requests, cfg: FleetConfig, ctl) -> dict:
 
 def _prefill_one(engine, msg: dict, ledger) -> tuple[KVShipment, float]:
     """Run one request's prefill on slot 0 of a freshly-reset engine
-    and package the shipment. Paged engines replay the scheduler's
-    chunked-prefill host loop exactly (same chunk widths → identical
-    KV rows → the decode side bit-matches the single-engine run);
-    dense engines take the whole prompt in one call."""
+    and package the shipment: the scheduler's chunked-prefill host
+    loop, replayed exactly (same chunk widths → identical KV rows → the
+    decode side bit-matches the single-engine run)."""
     rid = msg["rid"]
     prompt = [int(t) for t in msg["prompt"]]
     engine.reset()
@@ -481,43 +480,29 @@ def _prefill_one(engine, msg: dict, ledger) -> tuple[KVShipment, float]:
     temp[0] = float(msg["temperature"])
     topk[0] = int(msg["top_k"])
     t0 = time.perf_counter()
-    if engine.paged:
-        plan = engine.allocator.admit(0, prompt, 1, owner=rid, tick=0)
-        if plan is None:
-            raise RuntimeError(
-                f"fleet prefill worker cannot page prompt of {len(prompt)} "
-                "tokens — size the worker's kv_pages for the trace"
-            )
-        w = engine.prefill_chunk
-        base, first = 0, None
-        while base < len(prompt):
-            n = min(w, len(prompt) - base)
-            tk = np.zeros((S, w), np.int32)
-            tk[0, :n] = prompt[base : base + n]
-            ba = np.zeros((S,), np.int32)
-            ba[0] = base
-            cl = np.zeros((S,), np.int32)
-            cl[0] = n
-            fl = np.zeros((S,), np.int32)
-            sm = np.zeros((S,), bool)
-            sm[0] = base + n == len(prompt)
-            out = engine.prefill_paged(tk, ba, cl, fl, sm, temp, topk)
-            if sm[0]:
-                first = int(out[0])
-            base += n
-    else:
-        if len(prompt) > engine.prefill_len:
-            raise RuntimeError(
-                f"fleet dense prefill worker caps prompts at "
-                f"{engine.prefill_len} tokens, got {len(prompt)}"
-            )
-        toks = np.zeros((S, engine.prefill_len), np.int32)
-        toks[0, : len(prompt)] = prompt
-        lens = np.ones((S,), np.int32)
-        lens[0] = len(prompt)
-        admit = np.zeros((S,), bool)
-        admit[0] = True
-        first = int(engine.prefill(toks, lens, admit, temp, topk)[0])
+    plan = engine.allocator.admit(0, prompt, 1, owner=rid, tick=0)
+    if plan is None:
+        raise RuntimeError(
+            f"fleet prefill worker cannot page prompt of {len(prompt)} "
+            "tokens — size the worker's kv_pages for the trace"
+        )
+    w = engine.prefill_chunk
+    base, first = 0, None
+    while base < len(prompt):
+        n = min(w, len(prompt) - base)
+        tk = np.zeros((S, w), np.int32)
+        tk[0, :n] = prompt[base : base + n]
+        ba = np.zeros((S,), np.int32)
+        ba[0] = base
+        cl = np.zeros((S,), np.int32)
+        cl[0] = n
+        fl = np.zeros((S,), np.int32)
+        sm = np.zeros((S,), bool)
+        sm[0] = base + n == len(prompt)
+        out = engine.prefill_paged(tk, ba, cl, fl, sm, temp, topk)
+        if sm[0]:
+            first = int(out[0])
+        base += n
     prefill_s = time.perf_counter() - t0
     k, v = engine.export_kv_rows(0, len(prompt))
     ledger.event(rid, "fleet_prefill", dur_s=prefill_s)
@@ -630,12 +615,7 @@ def _decode_worker(rank, engine_factory, cfg: FleetConfig, fault_plan,
     def _finish(slot: int):
         nonlocal completed
         lv = live.pop(slot)
-        if engine.paged:
-            engine.allocator.free_slot(slot)
-        else:
-            engine.memledger.free("kv_slots", engine.slot_bytes,
-                                  owner=lv.rid, kind="retire")
-            engine.memledger.forget(lv.rid)
+        engine.allocator.free_slot(slot)
         free.append(slot)
         _send_json(
             {
@@ -653,18 +633,12 @@ def _decode_worker(rank, engine_factory, cfg: FleetConfig, fault_plan,
         if not free:
             return False
         slot = free[0]
-        if engine.paged:
-            plan = engine.allocator.admit(
-                slot, ship.prompt, ship.max_new_tokens, owner=ship.rid,
-                tick=ticks,
-            )
-            if plan is None:
-                return False  # pool full — stays in backlog
-        else:
-            engine.memledger.grant(
-                "kv_slots", engine.slot_bytes, owner=ship.rid,
-                tick=ticks, kind="admit",
-            )
+        plan = engine.allocator.admit(
+            slot, ship.prompt, ship.max_new_tokens, owner=ship.rid,
+            tick=ticks,
+        )
+        if plan is None:
+            return False  # pool full — stays in backlog
         free.popleft()
         inject_shipment(engine, slot, ship, ledger=ledger)
         live[slot] = _DecodeLive(

@@ -1,50 +1,33 @@
-"""Preallocated per-slot KV cache for continuous-batching decode.
+"""The serving engine's KV cache: a page pool and its host bookkeeping.
 
-The serving engine (ISSUE 4) never reshapes per request: one fixed
-``[num_layers, slots, max_len, heads, head_dim]`` K and V buffer pair is
-allocated up front, requests are *admitted into slots*, and every jitted
-step runs over the whole slot batch. Layout rationale:
+HBM cost should scale with the tokens that exist, not with ``slots ×
+max_len``: a slot holding 30 cached tokens must not pay for 1024, slot
+count must not be the hard concurrency ceiling, and two requests sharing
+a system prompt must not store identical K/V twice.
+:class:`PagedKVCache` is a fixed pool of ``page_size``-token pages
+indirected by a per-slot int32 block table: HBM scales with tokens
+actually held, and a page mapped into two block tables IS prefix
+sharing. The pool is held as the decode kernel reads it and as a step
+can update it in place: ONE BUFFER PER LAYER (``k`` and ``v`` are tuples
+of ``num_layers`` arrays), each ``[num_pages, page_size,
+heads*head_dim]`` — rows packed head-major to full 128-lane tiles, so a
+cached token costs its logical bytes on the device and the kernel DMAs
+tiles straight out of the buffer. The jitted steps DONATE the cache:
+each layer's buffer has one writer (the scatter of the new rows) and one
+reader (that layer's kernel call) per step, and comes back as the same
+memory. The device side stays dumb — pages are just rows, the pool never
+moves — while :class:`PageAllocator` (pure host) owns the free list,
+per-page refcounts, the rolling-hash prefix index and the copy-on-write
+bookkeeping. ``lengths`` [slots] int32 is the single source of truth for
+both the append position and the attention visibility mask (key ``j``
+visible iff ``j <= lengths + t``): validity comes from ``lengths`` + the
+mask, never from buffer contents, so a slot's history can never leak
+into another request and freed pages are recycled without zeroing.
 
-- layers lead so the per-layer view ``cache.k[i]`` hands each
-  transformer block a ``[slots, max_len, H, Dh]`` buffer — exactly the
-  sequence-major ``[B, T, H, Dh]`` layout
-  :func:`mpit_tpu.models.gpt2.default_attention` (and the flash/ring
-  kernels) already use;
-- slots are the batch dim: admission/retirement is a per-slot mask, no
-  data movement — a freed slot's stale rows are simply overwritten by
-  the next prefill (`jnp.where` on the slot dim selects whose writes
-  stick);
-- ``lengths`` [slots] int32 is the single source of truth for both the
-  append position (:func:`mpit_tpu.models.gpt2.cache_update` writes at
-  ``lengths``) and the attention visibility mask (key ``j`` visible iff
-  ``j <= lengths + t``) — a slot's history can never leak into another
-  request because the mask, not the buffer contents, defines validity.
-
-Under tensor parallelism the head dim shards over the TP axis
-(:func:`cache_specs`) — each device holds its H/P heads' cache, matching
-the Megatron column-sharded qkv layout (``parallel.megatron``).
-
-PAGED cache (ISSUE 7 tentpole). The dense layout makes HBM cost scale
-with ``slots × max_len`` whether or not the tokens exist: a slot holding
-30 cached tokens pays for 1024, slot count is the hard concurrency
-ceiling, and two requests sharing a system prompt store identical K/V
-twice. :class:`PagedKVCache` breaks the buffers into a fixed pool of
-``page_size``-token pages indirected by a per-slot int32 block table:
-HBM scales with tokens actually held, and a page mapped into two block
-tables IS prefix sharing. The pool is held as the decode kernel reads
-it and as a step can update it in place: ONE BUFFER PER LAYER (``k`` and
-``v`` are tuples of ``num_layers`` arrays), each ``[num_pages,
-page_size, heads*head_dim]`` — rows packed head-major to full 128-lane
-tiles, so a cached token costs its logical bytes on the device and the
-kernel DMAs tiles straight out of the buffer. The jitted paged steps
-DONATE the cache: each layer's buffer has one writer (the scatter of
-the new rows) and one reader (that layer's kernel call) per step, and
-comes back as the same memory. The device side stays dumb — pages are
-just rows, the pool never moves — while :class:`PageAllocator` (pure
-host) owns the free list, per-page refcounts, the rolling-hash prefix
-index and the copy-on-write bookkeeping. Validity still comes from
-``lengths`` + the attention mask, never from buffer contents, so freed
-pages are recycled without zeroing.
+Under tensor parallelism each layer's buffer shards its packed head axis
+over the TP axis (:func:`paged_cache_specs`) — each device holds its H/P
+heads' rows, matching the Megatron column-sharded qkv layout
+(``parallel.megatron``).
 
 QUANTIZED pools (ISSUE 15). ``quantized=True`` on the alloc/specs
 builders puts a :class:`~mpit_tpu.ops.kv_quant.QuantizedKV` (int8
@@ -95,9 +78,6 @@ from mpit_tpu.models.serving import as_serve_model
 from mpit_tpu.ops.kv_quant import QuantizedKV, kv_wire_bytes_per_row
 
 __all__ = [
-    "KVCache",
-    "alloc_cache",
-    "cache_specs",
     "PagedKVCache",
     "alloc_paged_cache",
     "paged_cache_specs",
@@ -109,11 +89,10 @@ __all__ = [
 ]
 
 
-def _alloc_kv(shape, dtype, quantized, kw, scale_width=1):
-    """One K (or V) buffer: a zeroed dense array, or the quantized pair
-    (int8 payload + f32 scale, keepdims unless a pool's packed rows give
-    it ``scale_width`` heads — zero scales dequantize the zeroed payload
-    to exact zeros, matching the dense init)."""
+def _alloc_kv(shape, dtype, quantized, kw, scale_width):
+    """One K (or V) buffer of a layer: a zeroed array, or the quantized
+    pair (int8 payload + an f32 scale plane of ``scale_width`` columns a
+    row — zero scales dequantize the zeroed payload to exact zeros)."""
     if not quantized:
         return jnp.zeros(shape, dtype, **kw)
     return QuantizedKV(
@@ -124,85 +103,9 @@ def _alloc_kv(shape, dtype, quantized, kw, scale_width=1):
 
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass
-class KVCache:
-    """The engine's decode state: KV buffers + per-slot fill counts.
-
-    ``k``/``v``: [num_layers, slots, max_len, heads, head_dim];
-    ``lengths``: [slots] int32, tokens currently cached per slot.
-    A pytree, so it passes through jit/shard_map boundaries whole.
-    """
-
-    k: Any
-    v: Any
-    lengths: Any
-
-    def tree_flatten(self):
-        return (self.k, self.v, self.lengths), None
-
-    @classmethod
-    def tree_unflatten(cls, _aux, children):
-        return cls(*children)
-
-    @property
-    def slots(self) -> int:
-        return self.k.shape[1]
-
-    @property
-    def max_len(self) -> int:
-        return self.k.shape[2]
-
-
-def alloc_cache(
-    cfg,
-    slots: int,
-    max_len: int,
-    *,
-    dtype=None,
-    sharding=None,
-    quantized: bool = False,
-) -> KVCache:
-    """Allocate the zeroed cache for ``slots`` concurrent requests.
-
-    ``dtype`` defaults to the model's activation dtype (``cfg.dtype``) —
-    the K/V written by the blocks arrive in it. ``sharding``: optional
-    ``NamedSharding`` for the buffers (the TP engine passes the
-    head-sharded one from :func:`cache_specs`). ``quantized`` (ISSUE
-    15): int8 + per-(row, head) scale buffers instead — writes
-    quantize, reads dequantize per tile.
-    """
-    shape = (cfg.num_layers, slots, max_len, cfg.num_heads, cfg.head_dim)
-    dt = dtype or cfg.dtype
-    kw = {"device": sharding} if sharding is not None else {}
-    return KVCache(
-        k=_alloc_kv(shape, dt, quantized, kw),
-        v=_alloc_kv(shape, dt, quantized, kw),
-        lengths=jnp.zeros((slots,), jnp.int32),
-    )
-
-
-def cache_specs(axis: str = "model", *, quantized: bool = False) -> KVCache:
-    """PartitionSpecs for a :class:`KVCache` under tensor parallelism:
-    K/V sharded on the HEAD dim (axis 3 of [L, S, T, H, Dh]) — each TP
-    rank caches exactly its column-sharded qkv heads — lengths
-    replicated. Shaped as a KVCache so it drops into shard_map
-    ``in_specs``/``out_specs`` positionally. Quantized caches shard the
-    scale blocks on the SAME head axis (axis 3 of [L, S, T, H, 1]) —
-    each rank's heads carry their own scales."""
-    kv = P(None, None, None, axis, None)
-    if quantized:
-        kv = QuantizedKV(q=kv, scale=kv)
-    return KVCache(k=kv, v=kv, lengths=P())
-
-
-# ---------------------------------------------------------------------------
-# Paged pool (ISSUE 7): fixed-size pages + per-slot block tables.
-# ---------------------------------------------------------------------------
-
-
-@jax.tree_util.register_pytree_node_class
-@dataclasses.dataclass
 class PagedKVCache:
-    """Paged decode state: one shared page pool + per-slot fill counts.
+    """The engine's decode state: one shared page pool + per-slot fill
+    counts. A pytree, so it passes through jit/shard_map boundaries whole.
 
     ``k``/``v``: tuples of ``num_layers`` per-layer buffers, each
     ``[num_pages, page_size, heads*head_dim]`` (a
@@ -274,7 +177,7 @@ def alloc_paged_cache(
     kw = {"device": sharding} if sharding is not None else {}
     layers = lambda width: tuple(
         _alloc_kv((num_pages, page_size, width), dt, quantized, kw,
-                  scale_width=layout.scale_width)
+                  layout.scale_width)
         for _ in range(layout.num_layers)
     )
     return PagedKVCache(
@@ -289,7 +192,7 @@ def paged_cache_specs(
     """TP PartitionSpecs for the pool: each layer's buffer shards its
     packed last axis (``[P, ps, H*Dh]`` is head-major, so a rank's
     ``H/P`` heads are one contiguous ``H/P * Dh`` slice of it, exactly
-    the heads the dense cache's head axis gives that rank); pages are
+    its column-sharded qkv heads); pages are
     replicated-id shared state, lengths replicated. Quantized pools
     shard the scale plane ``[P, ps, H]`` on the same axis."""
     kv = P(None, None, axis)
@@ -484,7 +387,7 @@ class PageAllocator:
     @property
     def pages_shared(self) -> int:
         """Pages mapped by more than one slot — each unit here is one
-        page of K/V the dense cache would have stored twice."""
+        page of K/V a per-slot cache would have stored twice."""
         return int(np.maximum(self.refcount - 1, 0).sum())
 
     @property

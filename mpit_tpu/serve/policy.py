@@ -43,7 +43,6 @@ fairness and admission instead of tag matching:
   Pinned invariant: a preempted-then-resumed greedy request bit-matches
   its un-preempted output (the resume prefill computes exactly the
   decode tick it displaced — same cache rows, same logits row).
-  Paged engines only (a dense slot has no pages to free);
   ``max_preemptions`` bounds thrash per request.
 
 The policy is pure host bookkeeping — no device state, no jax. The
@@ -78,9 +77,8 @@ class PolicyConfig:
     maps tenant id → weight (missing tenants get 1.0). ``admission``
     enables projected-TTFT shedding; a request is shed when the
     projection exceeds ``admission_factor ×`` its TTFT target.
-    ``preempt`` enables eviction of lower-tier live generations (paged
-    engines only); one request is preempted at most
-    ``max_preemptions`` times. ``projection_quantile``/``min_samples``
+    ``preempt`` enables eviction of lower-tier live generations; one
+    request is preempted at most ``max_preemptions`` times. ``projection_quantile``/``min_samples``
     shape the estimator (see :class:`TTFTProjector`).
     """
 

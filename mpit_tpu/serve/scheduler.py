@@ -67,9 +67,8 @@ the same anomaly report as tick-duration spikes. ``stats()`` carries
 ``engine_compiles`` (the pinned lifetime count) and
 ``decode_hbm_bytes_modeled``.
 
-ISSUE 7 (paged engine): admission becomes a PAGE grant, not just a slot
-grant — the head of the queue gets a free slot plus its whole page
-requirement (fresh pages + shared-prefix mappings + COW reserve,
+Admission is a PAGE grant, not just a slot grant — the head of the
+queue gets a free slot plus its whole page requirement (fresh pages + shared-prefix mappings + COW reserve,
 all-or-nothing) or waits; prompts feed the device ``prefill_chunk``
 tokens per tick interleaved with decode (``prefilling`` state — a long
 admit cannot head-of-line-block TTFT for live slots); a finished prompt
@@ -86,7 +85,7 @@ replaces the FIFO deque with the policy tier (``serve.policy``) —
 priority-ordered tenant-fair queues consulted at every admit boundary,
 projected-TTFT admission shedding at submit (``shed_admission``,
 distinct from ``max_queue``'s ``shed_queue_full`` in every counter /
-instant / stats key), and preemption on the paged engine: when the
+instant / stats key), and preemption: when the
 best queued tier's head is projected to miss its TTFT target and
 nothing frees, a lower-tier live generation is PARKED — pages freed
 back to the allocator, generated-so-far tokens kept host-side — and
@@ -111,9 +110,8 @@ lists never diverge). The tick is spanned ``decode`` with nested
 three); ``accepted_tokens_per_tick`` (emitted per slot-tick, 1.0 =
 plain decode) and ``draft_acceptance_rate`` feed the rolling windows
 and ``stats()`` — the ``gpt2_serve`` record line carries the former.
-Submit validation grows the dense-engine headroom check (the verify
-writes ``k+1`` rows at the fill; ``prompt + max_new + k - 1`` must fit
-``max_len`` — the paged engine instead DROPS out-of-range rows).
+The verify writes ``k+1`` rows at the fill; rows past a slot's mapped
+pages are scatter-DROPPED, so a request needs no headroom for them.
 """
 
 from __future__ import annotations
@@ -153,23 +151,22 @@ def warm_engine(engine, *, register_costs: bool = False) -> None:
         warm = Server(engine)
         warm.submit(Request(rid="warm", prompt=[1, 2, 3], max_new_tokens=2))
         warm.run()
-        if getattr(engine, "paged", False):
-            # The COW device copy is its own (tiny) compile — a lone
-            # warm request never diverges from a shared page, so pay it
-            # here or the first real divergence pays it inside the
-            # timed window.
-            engine.copy_page(0, 0)
-            if getattr(engine, "_prefill_counts", ()):
-                # A compacted chunk tick has one step a count of
-                # participants; the warm request met the first only.
-                engine.warm_prefill_counts()
-            if getattr(engine, "host_pages", 0):
-                # The host tier's gather/scatter pair likewise: pay
-                # both compiles with a page-0 round trip (restore
-                # rewrites exactly what spill read — a semantic no-op).
-                engine.spill_page(0, 0)
-                engine.drain_spills()
-                engine.restore_page(0, 0, release=True, kind="warm")
+        # The COW device copy is its own (tiny) compile — a lone
+        # warm request never diverges from a shared page, so pay it
+        # here or the first real divergence pays it inside the
+        # timed window.
+        engine.copy_page(0, 0)
+        if engine._prefill_counts:
+            # A compacted chunk tick has one step a count of
+            # participants; the warm request met the first only.
+            engine.warm_prefill_counts()
+        if engine.host_pages:
+            # The host tier's gather/scatter pair likewise: pay
+            # both compiles with a page-0 round trip (restore
+            # rewrites exactly what spill read — a semantic no-op).
+            engine.spill_page(0, 0)
+            engine.drain_spills()
+            engine.restore_page(0, 0, release=True, kind="warm")
         if register_costs:
             engine.register_roofline()
     engine.reset()
@@ -226,7 +223,7 @@ class _Live:
     submit_t: float
     first_token_t: float = 0.0
     tokens: list = dataclasses.field(default_factory=list)
-    # Paged-engine prefill state (ISSUE 7): ``base`` = prompt tokens
+    # Prefill state: ``base`` = prompt tokens
     # already cached (advanced per chunk), ``floor`` = the shared-prefix
     # write floor granted at admission (positions below it live in
     # immutable shared pages).
@@ -354,43 +351,36 @@ class Server:
         # obs satellite). Both labels matter: off-TPU "kernel" mode runs
         # reference ATTENTION but keeps the blocked SAMPLER, so
         # attention=reference alone does not identify the PR 4 path.
-        self._attn_mode = getattr(
-            engine, "decode_attention_mode", "reference"
-        )
-        self._sampler = getattr(engine, "decode_sampler", "dense")
+        self._attn_mode = engine.decode_attention_mode
+        self._sampler = engine.decode_sampler
         # kv_dtype rides decode/prefill spans via the attention= idiom
         # (ISSUE 15 satellite) — but only when the engine's wire dtype
         # was EXPLICITLY chosen: default engines' spans stay
         # byte-identical to HEAD, like grad_sync='s unlabeled psum.
         self._kv_attrs = (
             {"kv_dtype": engine.kv_dtype}
-            if getattr(engine, "kv_dtype_explicit", False)
+            if engine.kv_dtype_explicit
             else {}
         )
         # weights_dtype rides the same spans under the same rule
         # (ISSUE 17): the int8 weight store halves the decode sweep, so
         # a why-slow trace must say which wire the tick paid for — but
         # only explicitly-chosen engines get the label.
-        if getattr(engine, "weights_dtype_explicit", False):
+        if engine.weights_dtype_explicit:
             self._kv_attrs = dict(
                 self._kv_attrs, weights_dtype=engine.weights_dtype
             )
-        self._paged = bool(getattr(engine, "paged", False))
         # Speculative decoding (ISSUE 13): spec_k > 0 swaps the decode
         # tick for draft-then-verify; the accumulators feed stats()'s
         # accepted_tokens_per_tick / draft_acceptance_rate (what the
         # gpt2_serve record line carries).
-        self._spec = int(getattr(engine, "spec_k", 0) or 0)
+        self._spec = engine.spec_k
         # Which form of the attention kernel each kind of step compiled
         # to and the cache rows a step of its loop takes (static per
         # compiled step; empty where no kernel runs): a trace says
         # whether the fast tiling engaged.
-        tiling = getattr(engine, "attention_tiling", lambda t_q: {})
-        self._decode_tiling = tiling(self._spec + 1)
-        self._prefill_tiling = tiling(
-            engine.prefill_chunk if self._paged
-            else getattr(engine, "prefill_len", 1)
-        )
+        self._decode_tiling = engine.attention_tiling(self._spec + 1)
+        self._prefill_tiling = engine.attention_tiling(engine.prefill_chunk)
         self._spec_emitted = 0
         self._spec_active_ticks = 0
         self._spec_drafted = 0
@@ -399,9 +389,8 @@ class Server:
         # engine recompile and a sustained collapse of the decode HBM
         # rate both land in THIS server's sentinel report, next to the
         # tick-duration findings.
-        watch = getattr(engine, "compile_watch", None)
-        if watch is not None and sentinel is not None:
-            watch.sentinel = sentinel
+        if sentinel is not None:
+            engine.compile_watch.sentinel = sentinel
         self._util_watch = (
             obs.roofline.UtilizationWatch(sentinel=sentinel)
             if sentinel is not None
@@ -410,7 +399,7 @@ class Server:
         self._decode_hbm_bytes = 0.0  # length-aware modeled bytes moved
         self.queue: deque[_Live] = deque()
         self.live: dict[int, _Live] = {}  # slot -> in-flight request
-        # Paged engine: slots whose prompt is still being written, one
+        # Slots whose prompt is still being written, one
         # prefill_chunk slice per tick (chunked prefill — a 1024-token
         # admit can't head-of-line-block decode for every live slot).
         self.prefilling: dict[int, _Live] = {}
@@ -431,7 +420,7 @@ class Server:
         # buffer at construction; the server reads headroom at every
         # admission verdict, tracks the run's peak/min watermarks, and
         # rolls the whole byte decomposition into stats()["memory"].
-        self._memledger = getattr(engine, "memledger", None)
+        self._memledger = engine.memledger
         self._held_peak = 0
         self._headroom_min_pct: float | None = None
         # Host KV tier (ISSUE 20): preemption victims park their pages
@@ -439,7 +428,7 @@ class Server:
         # prefix entries migrate there instead of dying with their HBM
         # pages. Per-mode resume durations feed the p95
         # restream-vs-recompute comparison on the bench record line.
-        self._host_tier = self._paged and getattr(engine, "host_pages", 0) > 0
+        self._host_tier = engine.host_pages > 0
         self._host_held_peak = 0
         self.resume_durations: dict[str, list] = {
             "restream": [], "recompute": [],
@@ -489,40 +478,21 @@ class Server:
                 f"({len(req.prompt)} + {req.max_new_tokens}) exceeds the "
                 f"engine's max_len {self.engine.max_len}"
             )
-        if self._spec and not self._paged:
-            # The dense verify writes k+1 rows at the current fill via
-            # dynamic_update_slice, whose start CLAMPS at the buffer
-            # edge — without headroom the window would shift backwards
-            # and silently corrupt earlier rows inside the jitted step.
-            # (The paged engine needs none: rows past a slot's mapped
-            # pages are scatter-DROPPED.) Raise the precise error here,
-            # at submit (ISSUE 13 satellite).
-            need = len(req.prompt) + req.max_new_tokens + self._spec - 1
-            if need > self.engine.max_len:
-                raise ValueError(
-                    f"request {req.rid!r}: speculative decode (spec_k="
-                    f"{self._spec}) writes draft rows past the fill — "
-                    f"prompt + max_new_tokens + spec_k - 1 = {need} "
-                    f"exceeds the dense cache's max_len "
-                    f"{self.engine.max_len}; shrink the request, lower "
-                    f"spec_k, or grow max_len"
-                )
-        if self._paged:
-            # A request the POOL could never hold is a caller bug, like
-            # the max_len checks above — raise at submit, not when the
-            # admit loop discovers it can never stop waiting. (The
-            # per-slot virtual capacity is already covered: prompt +
-            # max_new_tokens <= max_len = pages_per_slot × page_size.)
-            alloc = self.engine.allocator
-            need = alloc.pages_for(len(req.prompt), req.max_new_tokens)
-            if need > alloc.num_pages:
-                raise ValueError(
-                    f"request {req.rid!r}: needs {need} pages of "
-                    f"{alloc.page_size} tokens but the pool holds only "
-                    f"{alloc.num_pages}; shrink prompt + max_new_tokens "
-                    f"or grow Engine(kv_pages=...)"
-                )
-        k_cap = getattr(self.engine, "sample_k_cap", None)
+        # A request the POOL could never hold is a caller bug, like
+        # the max_len checks above — raise at submit, not when the
+        # admit loop discovers it can never stop waiting. (The
+        # per-slot virtual capacity is already covered: prompt +
+        # max_new_tokens <= max_len = pages_per_slot × page_size.)
+        alloc = self.engine.allocator
+        need = alloc.pages_for(len(req.prompt), req.max_new_tokens)
+        if need > alloc.num_pages:
+            raise ValueError(
+                f"request {req.rid!r}: needs {need} pages of "
+                f"{alloc.page_size} tokens but the pool holds only "
+                f"{alloc.num_pages}; shrink prompt + max_new_tokens "
+                f"or grow Engine(kv_pages=...)"
+            )
+        k_cap = self.engine.sample_k_cap
         if k_cap is not None and req.top_k > k_cap:
             raise ValueError(
                 f"request {req.rid!r}: top_k {req.top_k} exceeds the "
@@ -628,16 +598,7 @@ class Server:
 
     # -- the loop -----------------------------------------------------------
     def _admit(self) -> None:
-        """Move queued requests into free slots and start their
-        prefill: dense = one batched whole-prompt call; paged = map
-        pages and enter the per-tick chunk pipeline."""
-        if self._paged:
-            self._admit_paged()
-        else:
-            self._admit_dense()
-
-    def _admit_paged(self) -> None:
-        """Paged admission (ISSUE 7): grant the next queued request
+        """Admission: grant the next queued request
         (FIFO head, or the policy's tier/DRR choice) a free slot AND
         its whole page requirement (fresh pages + shared-prefix
         mappings + COW reserve, all-or-nothing in the allocator) or
@@ -770,13 +731,13 @@ class Server:
             self.prefilling[slot] = live
             self.admissions += 1
 
-    # -- preemption (ISSUE 12, paged engines only) ---------------------------
+    # -- preemption (ISSUE 12) -------------------------------------------------
     def _try_preempt(self, now: float) -> bool:
         """Park one lower-tier live generation when the policy says the
         best queued tier's head would otherwise miss its TTFT target.
         Returns True when a victim was evicted (a slot + its pages are
         now free)."""
-        if self.policy is None or not self._paged:
+        if self.policy is None:
             return False
         priority = self.policy.wants_preemption(now)
         if priority is None:
@@ -1078,100 +1039,13 @@ class Server:
         if req.tenant:
             self.stream.observe(f"request_ttft_tenant:{req.tenant}", ttft)
 
-    def _admit_dense(self) -> None:
-        """Move queued requests into free slots and prefill them (one
-        batched call however many were admitted this tick) — FIFO
-        order, or the policy's tier/DRR order (no preemption on the
-        dense engine: a slot has no pages to free)."""
-        if not self._qdepth() or not self.free:
-            return
-        s, plen = self.engine.slots, self.engine.prefill_len
-        tokens = np.zeros((s, plen), np.int32)
-        lens = np.ones((s,), np.int32)
-        admit = np.zeros((s,), bool)
-        batch: list[tuple[int, _Live]] = []
-        now = time.perf_counter()
-        while self.free:
-            live = self._next_queued()
-            if live is None:
-                break
-            slot = self.free.pop()
-            p = live.req.prompt
-            tokens[slot, : len(p)] = p
-            lens[slot] = len(p)
-            admit[slot] = True
-            self._temp[slot] = live.req.temperature
-            self._topk[slot] = live.req.top_k
-            live.last_touch = self.tick
-            if self._memledger is not None:
-                # Dense capacity is slot-granular (ISSUE 18): one slot
-                # reservation granted per admission, freed at retire —
-                # the dense twin of the allocator's page grants.
-                self._memledger.grant(
-                    "kv_slots", self.engine.slot_bytes,
-                    owner=live.req.rid, tenant=live.req.tenant or None,
-                    tick=self.tick, kind="admit",
-                )
-            if self._ledger is not None:
-                self._ledger.event(
-                    live.req.rid, "slot_bind", slot=slot, tick=self.tick,
-                    resumed=False, t=now,
-                )
-            obs.span_at(
-                "queue_wait", live.submit_t, now,
-                **self._span_attrs(live.req),
-            )
-            if self.stream is not None:
-                self.stream.observe("queue_wait", now - live.submit_t)
-            batch.append((slot, live))
-        attrs = {}
-        if obs.enabled():  # a disabled span costs a tick nothing
-            attrs = dict(
-                admitted=len(batch), attention=self._attn_mode,
-                sampler=self._sampler,
-                # The admitted rids, as a LIST (a non-string attr stays
-                # out of the summary's label roll-up but lands in the
-                # trace args) — one request's lifeline is filterable in
-                # Perfetto.
-                rids=[live.req.rid for _, live in batch],
-                **self._kv_attrs, **self._prefill_tiling,
-            )
-        with obs.span("prefill", **attrs):
-            first = self.engine.prefill(
-                tokens, lens, admit, self._temp, self._topk
-            )
-        t_first = time.perf_counter()
-        self.admissions += len(batch)
-        if self.sentinel is not None:
-            self.sentinel.observe_phases(
-                self.tick, prefill=t_first - now
-            )
-        if self.stream is not None:
-            self.stream.observe("prefill_tick", t_first - now)
-        if self._ledger is not None:
-            # Dense prefill is one whole-prompt chunk; the shared batch
-            # wall is each admitted request's prefill-compute share.
-            for slot, live in batch:
-                self._ledger.event(
-                    live.req.rid, "prefill_chunk", tick=self.tick,
-                    chunk=len(live.req.prompt), dur_s=t_first - now,
-                    t=t_first,
-                )
-        for slot, live in batch:
-            live.first_token_t = t_first
-            live.tokens = [int(first[slot])]
-            self._record_ttft(live, t_first)
-            self.live[slot] = live
-            self._maybe_retire(slot, t_first)
-
     def _maybe_retire(self, slot: int, now: float) -> None:
         """Retire ``slot`` if its newest token finished the request."""
         live = self.live[slot]
         req = live.req
         tok = live.tokens[-1]
         # The next decode would write at the fill position — at max_len
-        # the slot must retire or it would overrun the buffer (dense) /
-        # its mapped pages (paged).
+        # the slot must retire or it would overrun its mapped pages.
         full = live.cache_fill() >= self.engine.max_len
         done = (
             (req.eos_id is not None and tok == req.eos_id)
@@ -1181,20 +1055,14 @@ class Server:
         if not done:
             return
         del self.live[slot]
-        if self._paged:
-            # Unmap the slot's pages: refcounts drop, sole-owner pages
-            # return to the free list (recycled WITHOUT zeroing — the
-            # mask defines validity), prefix-index entries whose pages
-            # died are invalidated — unless the host tier catches them
-            # first (ISSUE 20: a sole-reader prefix migrates instead of
-            # dying, so the index survives HBM reclaim).
-            self._spill_dying_prefixes(slot, owner=req.rid)
-            self.engine.allocator.free_slot(slot)
-        elif self._memledger is not None:
-            self._memledger.free(
-                "kv_slots", self.engine.slot_bytes,
-                owner=req.rid, kind="retire",
-            )
+        # Unmap the slot's pages: refcounts drop, sole-owner pages
+        # return to the free list (recycled WITHOUT zeroing — the
+        # mask defines validity), prefix-index entries whose pages
+        # died are invalidated — unless the host tier catches them
+        # first (ISSUE 20: a sole-reader prefix migrates instead of
+        # dying, so the index survives HBM reclaim).
+        self._spill_dying_prefixes(slot, owner=req.rid)
+        self.engine.allocator.free_slot(slot)
         if self._memledger is not None:
             self._memledger.forget(req.rid)
         self.free.append(slot)
@@ -1262,28 +1130,27 @@ class Server:
             budget[slot] = live.remaining_new()
             if live.req.eos_id is not None:
                 eos[slot] = live.req.eos_id
-        if self._paged:
-            # Every page the verify span [fill, fill+k] can write must
-            # be privately owned BEFORE the step — the plain tick's COW
-            # probe, once per page in the span. Only the shared-prefix
-            # partial page can actually be shared, so at most one copy
-            # runs; the rest are no-op refcount probes.
-            ps = eng.page_size
-            caps = eng.allocator.mapped_tokens()
-            for slot, live in self.live.items():
-                fill = live.cache_fill()
-                last_pos = min(fill + k, int(caps[slot]) - 1)
-                for page_idx in range(fill // ps, last_pos // ps + 1):
-                    pair = eng.allocator.cow_before_write(
-                        slot, max(fill, page_idx * ps)
-                    )
-                    if pair is not None:
-                        eng.copy_page(*pair)
-                        if self._ledger is not None:
-                            self._ledger.event(
-                                live.req.rid, "cow_copy", tick=self.tick,
-                                src=pair[0], dst=pair[1], phase="spec",
-                            )
+        # Every page the verify span [fill, fill+k] can write must
+        # be privately owned BEFORE the step — the plain tick's COW
+        # probe, once per page in the span. Only the shared-prefix
+        # partial page can actually be shared, so at most one copy
+        # runs; the rest are no-op refcount probes.
+        ps = eng.page_size
+        caps = eng.allocator.mapped_tokens()
+        for slot, live in self.live.items():
+            fill = live.cache_fill()
+            last_pos = min(fill + k, int(caps[slot]) - 1)
+            for page_idx in range(fill // ps, last_pos // ps + 1):
+                pair = eng.allocator.cow_before_write(
+                    slot, max(fill, page_idx * ps)
+                )
+                if pair is not None:
+                    eng.copy_page(*pair)
+                    if self._ledger is not None:
+                        self._ledger.event(
+                            live.req.rid, "cow_copy", tick=self.tick,
+                            src=pair[0], dst=pair[1], phase="spec",
+                        )
         n_live = int(active.sum())
         rids = (
             [live.req.rid for live in self.live.values()]
@@ -1354,7 +1221,7 @@ class Server:
         if ach is not None:
             self._decode_hbm_bytes += ach
             obs.roofline.work("spec_verify", hbm_bytes=ach)
-            costs = getattr(eng, "roofline_costs", None) or {}
+            costs = eng.roofline_costs or {}
             flops = costs.get("spec_verify", {}).get("flops", 0.0)
             if self.stream is not None:
                 self.stream.inc("decode_hbm_bytes", ach)
@@ -1394,23 +1261,22 @@ class Server:
         active = np.zeros((self.engine.slots,), bool)
         for slot in self.live:
             active[slot] = True
-        if self._paged:
-            # This tick appends one K/V row per live slot at its fill
-            # position — a slot whose fill still lands in a SHARED page
-            # (full-prompt prefix reuse of a partial last page) must
-            # copy it out first; later ticks find the page private and
-            # this is a no-op refcount probe.
-            for slot, live in self.live.items():
-                pair = self.engine.allocator.cow_before_write(
-                    slot, live.cache_fill()
-                )
-                if pair is not None:
-                    self.engine.copy_page(*pair)
-                    if self._ledger is not None:
-                        self._ledger.event(
-                            live.req.rid, "cow_copy", tick=self.tick,
-                            src=pair[0], dst=pair[1], phase="decode",
-                        )
+        # This tick appends one K/V row per live slot at its fill
+        # position — a slot whose fill still lands in a SHARED page
+        # (full-prompt prefix reuse of a partial last page) must
+        # copy it out first; later ticks find the page private and
+        # this is a no-op refcount probe.
+        for slot, live in self.live.items():
+            pair = self.engine.allocator.cow_before_write(
+                slot, live.cache_fill()
+            )
+            if pair is not None:
+                self.engine.copy_page(*pair)
+                if self._ledger is not None:
+                    self._ledger.event(
+                        live.req.rid, "cow_copy", tick=self.tick,
+                        src=pair[0], dst=pair[1], phase="decode",
+                    )
         attrs = {}
         if obs.enabled():  # a disabled span costs a tick nothing
             attrs = dict(
@@ -1485,12 +1351,11 @@ class Server:
         # the summary's decode utilization uses it, mirrored into the
         # rolling stream windows (the CLI's hbmbw=/mfu= fields) and the
         # sustained-collapse watch.
-        ach = getattr(self.engine, "decode_achieved_hbm_bytes", None)
-        ach = ach(lens) if ach is not None else None
+        ach = self.engine.decode_achieved_hbm_bytes(lens)
         if ach is not None:
             self._decode_hbm_bytes += ach
             obs.roofline.work("decode", hbm_bytes=ach)
-            costs = getattr(self.engine, "roofline_costs", None) or {}
+            costs = self.engine.roofline_costs or {}
             flops = costs.get("decode", {}).get("flops", 0.0)
             if self.stream is not None:
                 self.stream.inc("decode_hbm_bytes", ach)
@@ -1507,7 +1372,7 @@ class Server:
 
     def _pending(self) -> bool:
         """Work outstanding: queued (FIFO deque or policy tiers),
-        mid-prefill (paged chunking) or live — the loop-termination and
+        mid-prefill (chunking) or live — the loop-termination and
         truncation predicate."""
         return bool(self._qdepth() or self.prefilling or self.live)
 
@@ -1515,8 +1380,8 @@ class Server:
         """Cache-memory efficiency gauges (ISSUE 7 satellite):
         ``kv_tokens_cached`` = tokens actually held device-side (live
         fills + prefill progress — what a token-proportional cache pays
-        for), plus pool occupancy and shared-page count on the paged
-        engine. Recorder gauges AND the rolling stream windows."""
+        for), plus pool occupancy and shared-page count. Recorder
+        gauges AND the rolling stream windows."""
         kv_tokens = float(
             sum(l.cache_fill() for l in self.live.values())
             + sum(l.base for l in self.prefilling.values())
@@ -1525,8 +1390,6 @@ class Server:
         if self.stream is not None:
             self.stream.set_gauge("kv_tokens_cached", kv_tokens)
         self._memory_gauges(kv_tokens)
-        if not self._paged:
-            return
         alloc = self.engine.allocator
         occ = alloc.occupancy
         shared = alloc.pages_shared
@@ -1561,10 +1424,7 @@ class Server:
             host_held = int(ml.held("kv_host_pages"))
             self._host_held_peak = max(self._host_held_peak, host_held)
             gauges["host_held_bytes"] = float(host_held)
-        sub = "kv_pages" if self._paged else "kv_slots"
-        kv_held = ml.held(sub) + (
-            ml.held("kv_cow_reserve") if self._paged else 0.0
-        )
+        kv_held = ml.held("kv_pages") + ml.held("kv_cow_reserve")
         gauges["kv_held_bytes"] = float(kv_held)
         if "kv_headroom_pct" in head:
             pct = head["kv_headroom_pct"]
@@ -1574,14 +1434,13 @@ class Server:
                 else min(self._headroom_min_pct, pct)
             )
             gauges["kv_headroom_pct"] = pct
-        if self._paged:
-            in_use = self.engine.allocator.pages_in_use
-            granted_tokens = in_use * self.engine.page_size
-            gauges["kv_frag_pct"] = (
-                round(100.0 * (1.0 - kv_tokens / granted_tokens), 2)
-                if granted_tokens
-                else 0.0
-            )
+        in_use = self.engine.allocator.pages_in_use
+        granted_tokens = in_use * self.engine.page_size
+        gauges["kv_frag_pct"] = (
+            round(100.0 * (1.0 - kv_tokens / granted_tokens), 2)
+            if granted_tokens
+            else 0.0
+        )
         for name, val in gauges.items():
             obs.gauge(name, val)
             if self.stream is not None:
@@ -1590,19 +1449,16 @@ class Server:
     def _kv_headroom(self) -> dict:
         """KV capacity headroom RIGHT NOW — the bytes an admission
         verdict had to work with (annotated onto sheds and blocked
-        admits). Paged: free grantable pages × page bytes (COW reserve
-        excluded — those bytes are promised). Dense: free slot
-        reservations. Empty when the engine has no ledger."""
+        admits): free grantable pages × page bytes (COW reserve
+        excluded — those bytes are promised). Empty when the engine has
+        no ledger."""
         ml = self._memledger
         if ml is None:
             return {}
-        sub = "kv_pages" if self._paged else "kv_slots"
-        cap = ml.capacity(sub)
+        cap = ml.capacity("kv_pages")
         if not cap:
             return {}
-        held = ml.held(sub) + (
-            ml.held("kv_cow_reserve") if self._paged else 0.0
-        )
+        held = ml.held("kv_pages") + ml.held("kv_cow_reserve")
         headroom = cap - held
         return {
             "kv_headroom_bytes": int(headroom),
@@ -1689,13 +1545,12 @@ class Server:
         return sole, dead
 
     def _run_tick(self) -> None:
-        """One loop iteration: admit, prefill chunk (paged), gauges,
-        decode, SLO evaluation.
+        """One loop iteration: admit, prefill chunk, gauges, decode, SLO
+        evaluation.
 
         Spanned as a tree, by time on this thread: ``tick`` round the
         whole, and inside it, in the order they run, ``admit``,
-        ``prefill`` (the paged chunk; a dense engine prefills inside
-        ``admit``), ``gauges``, ``decode`` and ``retire`` (the
+        ``prefill`` (the chunk), ``gauges``, ``decode`` and ``retire`` (the
         accounting after the decode call). The engine's
         ``*_dispatch`` / ``*_fetch`` spans lie inside ``prefill`` and
         ``decode``; its page copies (``copy_page``, ``restore_page``,
@@ -1711,8 +1566,7 @@ class Server:
                 self.engine.drain_spills()
             with obs.span("admit"):
                 self._admit()
-            if self._paged:
-                self._prefill_chunk_tick()
+            self._prefill_chunk_tick()
             with obs.span("gauges"):
                 self._tick_gauges()
             if self.live:
@@ -1914,7 +1768,7 @@ class Server:
                             "tier": "host" if live.req.rid in parked
                             else "none",
                         })
-        if self._paged and pb:
+        if pb:
             alloc = self.engine.allocator
             for slot, live in self.live.items():
                 owned, _ = alloc.slot_page_stats(slot)
@@ -1964,16 +1818,6 @@ class Server:
                     "last_touch_tick": alloc._prefix_touch.get(key, 0),
                     "tier": "host",
                 })
-        elif not self._paged and self.engine.slot_bytes:
-            for live in self.live.values():
-                out.append({
-                    "kind": "idle_tail",
-                    "rid": live.req.rid,
-                    "tenant": live.req.tenant or "",
-                    "bytes": int(self.engine.slot_bytes),
-                    "last_touch_tick": live.last_touch,
-                    "tier": "hbm",
-                })
         out.sort(key=lambda c: (c["last_touch_tick"],
                                 str(c.get("rid", c.get("key", "")))))
         return out[:cap]
@@ -1999,8 +1843,7 @@ class Server:
             "held_by_subsystem": ml.decompose(),
             "conservation": ml.conservation(),
         }
-        sub = "kv_pages" if self._paged else "kv_slots"
-        cap = ml.capacity(sub)
+        cap = ml.capacity("kv_pages")
         if cap:
             out["kv_capacity_bytes"] = int(cap)
             out.update(self._kv_headroom())
@@ -2022,7 +1865,7 @@ class Server:
             out["restream_bytes"] = int(eng.host_restream_bytes)
         per_req: dict[str, dict] = {}
         per_tenant: dict[str, int] = {}
-        if self._paged and self.engine.page_bytes:
+        if self.engine.page_bytes:
             alloc = self.engine.allocator
             pb = self.engine.page_bytes
             for slot, live in list(self.live.items()) + list(
@@ -2036,13 +1879,6 @@ class Server:
                 }
             shared_pages = int((alloc.refcount >= 2).sum())
             out["shared_bytes"] = int(shared_pages * pb)
-        elif not self._paged and self.engine.slot_bytes:
-            for live in self.live.values():
-                per_req[str(live.req.rid)] = {
-                    "bytes": int(self.engine.slot_bytes),
-                    "shared_pages": 0,
-                    "tenant": live.req.tenant or "",
-                }
         for e in per_req.values():
             t = e["tenant"]
             per_tenant[t] = per_tenant.get(t, 0) + e["bytes"]
@@ -2057,7 +1893,7 @@ class Server:
         if ev:
             out["eviction_candidates"] = ev
         device = None
-        if getattr(self.engine, "platform", None) == "tpu":
+        if self.engine.platform == "tpu":
             import jax
 
             device = jax.devices()[0]
@@ -2086,28 +1922,22 @@ class Server:
             # work still queued or live is PARTIAL — indistinguishable
             # from finished without this flag (ISSUE 6 satellite).
             "truncated": self._truncated,
-            # Most requests simultaneously resident (live + prefilling)
-            # — the capacity number the paged-vs-dense bench pins.
+            # Most requests simultaneously resident (live + prefilling).
             "concurrency_peak": self._concurrency_peak,
         }
         # The cache's wire dtype (ISSUE 15): what a cached row occupies
         # HBM as — "int8" on the quantized engines, the model dtype
         # otherwise. Always reported: capacity and bandwidth figures
         # are uninterpretable without it.
-        kv_dtype = getattr(self.engine, "kv_dtype", None)
-        if kv_dtype is not None:
-            out["kv_dtype"] = kv_dtype
+        out["kv_dtype"] = self.engine.kv_dtype
         # The weight store's wire dtype (ISSUE 17), same rule: "int8"
         # when the matmul weights live as int8+scales, "f32" otherwise.
-        weights_dtype = getattr(self.engine, "weights_dtype", None)
-        if weights_dtype is not None:
-            out["weights_dtype"] = weights_dtype
-        watch = getattr(self.engine, "compile_watch", None)
-        if watch is not None:
-            # The runtime-guarded compile claim (ISSUE 8): 2 for the
-            # dense engine's lifetime (3 paged, + copy_page) — anything
-            # above is an unexpected recompile the watch also flagged.
-            out["engine_compiles"] = watch.compiles
+        out["weights_dtype"] = self.engine.weights_dtype
+        # The runtime-guarded compile claim (ISSUE 8): 3 for the
+        # engine's lifetime (prefill chunk, decode, copy_page) —
+        # anything above is an unexpected recompile the watch also
+        # flagged.
+        out["engine_compiles"] = self.engine.compile_watch.compiles
         if self._decode_hbm_bytes:
             out["decode_hbm_bytes_modeled"] = round(
                 self._decode_hbm_bytes, 1
@@ -2126,42 +1956,41 @@ class Server:
                 out["draft_acceptance_rate"] = round(
                     self._spec_accepted / max(self._spec_drafted, 1), 4
                 )
-        if self._paged:
-            alloc = self.engine.allocator
+        alloc = self.engine.allocator
+        out.update(
+            kv_page_size=alloc.page_size,
+            kv_pool_pages=alloc.num_pages,
+            kv_pool_occupancy_mean=round(
+                self._kv_occ_sum / max(self.tick, 1), 4
+            ),
+            kv_pool_occupancy_peak=round(self._kv_occ_peak, 4),
+            prefix_hit_rate=round(alloc.hit_rate, 4),
+            prefix_hits=alloc.prefix_hits,
+            prefix_pages_shared_peak=self._pages_shared_peak,
+            kv_cow_copies=alloc.cow_copies,
+        )
+        if self._host_tier:
+            # Host-tier roll-up (ISSUE 20): tier occupancy plus the
+            # spill/restream traffic and where prefix hits landed.
+            eng = self.engine
             out.update(
-                kv_page_size=alloc.page_size,
-                kv_pool_pages=alloc.num_pages,
-                kv_pool_occupancy_mean=round(
-                    self._kv_occ_sum / max(self.tick, 1), 4
-                ),
-                kv_pool_occupancy_peak=round(self._kv_occ_peak, 4),
-                prefix_hit_rate=round(alloc.hit_rate, 4),
-                prefix_hits=alloc.prefix_hits,
-                prefix_pages_shared_peak=self._pages_shared_peak,
-                kv_cow_copies=alloc.cow_copies,
+                kv_host_pages=alloc.host_pages,
+                kv_host_pages_in_use=alloc.host_pages_in_use,
+                host_spilled_pages=eng.host_spilled_pages,
+                host_restreamed_pages=eng.host_restreamed_pages,
+                host_prefix_hits=alloc.host_prefix_hits,
+                parked_spills=alloc.parked_spills,
+                spilled_prefix_entries=alloc.spilled_prefix_entries,
+                promoted_entries=alloc.promoted_entries,
             )
-            if self._host_tier:
-                # Host-tier roll-up (ISSUE 20): tier occupancy plus the
-                # spill/restream traffic and where prefix hits landed.
-                eng = self.engine
-                out.update(
-                    kv_host_pages=alloc.host_pages,
-                    kv_host_pages_in_use=alloc.host_pages_in_use,
-                    host_spilled_pages=eng.host_spilled_pages,
-                    host_restreamed_pages=eng.host_restreamed_pages,
-                    host_prefix_hits=alloc.host_prefix_hits,
-                    parked_spills=alloc.parked_spills,
-                    spilled_prefix_entries=alloc.spilled_prefix_entries,
-                    promoted_entries=alloc.promoted_entries,
+        # Resume-path p95s (ISSUE 20 headline): recorded for every
+        # server — an untiered run yields the recompute p95
+        # the bench compares the restream p95 against.
+        for mode, durs in sorted(self.resume_durations.items()):
+            if durs:
+                out[f"resume_{mode}_p95_s"] = round(
+                    float(np.percentile(np.asarray(durs), 95)), 6
                 )
-            # Resume-path p95s (ISSUE 20 headline): recorded for every
-            # paged server — an untiered run yields the recompute p95
-            # the bench compares the restream p95 against.
-            for mode, durs in sorted(self.resume_durations.items()):
-                if durs:
-                    out[f"resume_{mode}_p95_s"] = round(
-                        float(np.percentile(np.asarray(durs), 95)), 6
-                    )
         if self.shed:
             # Cause breakdown (ISSUE 16 satellite): ``requests_shed``
             # is a dict — total plus the two named reasons (bounded

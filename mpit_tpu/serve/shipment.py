@@ -268,7 +268,7 @@ def recv_shipment(
 def inject_shipment(engine, slot: int, ship: KVShipment, *, ledger=None):
     """Install a received shipment into ``slot`` of a decode engine:
     KV rows, fill length, and ``last_token`` (= the shipped first
-    token). The caller has already admitted the slot (paged: an
+    token). The caller has already admitted the slot (an
     all-or-nothing ``allocator.admit`` — no ``register_prefix``;
     injected pages are private, never prefix-shared)."""
     engine.inject_kv_rows(

@@ -8,7 +8,7 @@ draft model proposes ``k`` tokens per slot, the target scores all
 small-T trace), and per slot the longest verified prefix is emitted —
 cache lengths simply do not advance past it, which IS the rollback (row
 validity comes from ``lengths`` + the attention mask, never from buffer
-contents, dense and paged alike).
+contents).
 
 This module holds the engine-agnostic pieces:
 
@@ -68,8 +68,8 @@ def register_draft_store(
     target store against the device allocator), while a separately
     quantized or separately checkpointed draft holds its own buffers —
     so the grant counts only leaves NOT aliasing ``target_params``.
-    The draft KV cache (``kv_bytes``) is always its own buffer — paged
-    drafts mirror the target pool's page geometry (same block tables,
+    The draft KV cache (``kv_bytes``) is always its own buffer — the
+    draft pool mirrors the target pool's page geometry (same block tables,
     separate arrays) — and lands on the ``kv_pool`` line, where the
     per-page ``page_bytes`` already carries the draft term. Returns
     the granted draft-weight bytes; ``memledger=None`` is the unwired

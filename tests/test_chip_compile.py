@@ -26,7 +26,6 @@ from jax.sharding import SingleDeviceSharding
 
 from mpit_tpu.ops import ring_collectives
 from mpit_tpu.ops.decode_attention import (
-    flash_decode_attention,
     flash_paged_decode_attention,
 )
 from mpit_tpu.ops.flash_attention import flash_attention
@@ -83,16 +82,6 @@ def _sds(shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
-def _kv(shape, kv_dtype):
-    """A K (or V) buffer spec: bf16 rows, or int8 rows + per-(row, head)
-    f32 scales in the stored keepdims form."""
-    if kv_dtype == "bf16":
-        return _sds(shape, jnp.bfloat16)
-    return QuantizedKV(
-        q=_sds(shape, jnp.int8), scale=_sds((*shape[:-1], 1), jnp.float32)
-    )
-
-
 def test_flash_attention_fwd_bwd(v5e):
     qkv = _sds((B, S, H, D), jnp.bfloat16)
 
@@ -104,43 +93,37 @@ def test_flash_attention_fwd_bwd(v5e):
     assert text.count("tpu_custom_call") >= 3  # fwd, dq, dkv
 
 
+# (slots, heads of 64, pages a slot, rows a page). The serve CLI's shape
+# (GPT-2 small's 12 heads), and `gpt2l-serve-offline-decode`'s own: GPT-2
+# large's 20 heads over 16 slots of 64 pages (a decode tick multiplies all
+# heads at once over a tile of 16 pages, a chunk a head at a time): Mosaic's
+# verdict on the page DMAs into a tile's rows and on the VMEM the tile
+# takes, before the chip is asked. Then the other tilings `decode_tiling`
+# can choose at GPT-2 large's row: four 64-row pages a step, and a page of
+# 256 rows that is the step's whole tile.
+_PAGED_SHAPES = {
+    "small": (B, H, S // PAGE, PAGE), "large": (16, 20, 64, PAGE),
+    "page64": (16, 20, 16, 64), "page256": (16, 20, 4, 256),
+}
+
+
 # T: one decode token, the CLI's prefill chunk, a spec_k=4 verify.
-@pytest.mark.parametrize("t", [1, 64, 5])
-@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
-def test_flash_decode_dense(v5e, kv_dtype, t):
-    kv = _kv((B, S, H, D), kv_dtype)
-    _compile_on_chip(
-        v5e,
-        lambda q, k, v, n: flash_decode_attention(
-            q, k, v, n, interpret=False, return_visited=True
-        ),
-        _sds((B, t, H, D), jnp.bfloat16), kv, kv, _sds((B,), jnp.int32),
-    )
-
-
-# The serve CLI's shape above, and `gpt2l-serve-offline-decode`'s own:
-# GPT-2 large's 20 heads of 64 over 16 slots of 64 pages (a decode tick
-# multiplies all heads at once over a tile of 16 pages, a chunk a head at
-# a time): Mosaic's verdict on the page DMAs into a tile's rows and on
-# the VMEM the tile takes, before the chip is asked.
-_PAGED_SHAPES = {"small": (B, H, S // PAGE), "large": (16, 20, 64)}
-
-
 @pytest.mark.parametrize(
     "shape,t",
-    [("small", 1), ("small", 64), ("small", 5), ("large", 1), ("large", 64)],
+    [("small", 1), ("small", 64), ("small", 5), ("large", 1), ("large", 64),
+     ("large", 5), ("page64", 1), ("page64", 64), ("page256", 1)],
 )
 @pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
 def test_flash_decode_paged(v5e, kv_dtype, shape, t):
     # One layer's pool as stored: rows packed [pages, page, H*D], an
     # int8 pool's scale plane [pages, page, H].
-    b, h, pps = _PAGED_SHAPES[shape]
+    b, h, pps, page = _PAGED_SHAPES[shape]
     pages = b * pps
-    pool = _sds((pages, PAGE, h * D), jnp.bfloat16)
+    pool = _sds((pages, page, h * D), jnp.bfloat16)
     if kv_dtype == "int8":
         pool = QuantizedKV(
-            q=_sds((pages, PAGE, h * D), jnp.int8),
-            scale=_sds((pages, PAGE, h), jnp.float32),
+            q=_sds((pages, page, h * D), jnp.int8),
+            scale=_sds((pages, page, h), jnp.float32),
         )
     _compile_on_chip(
         v5e,

@@ -4,8 +4,9 @@ The serving hot loop's two new ops, tested in isolation (the engine-level
 acceptance — greedy bit-match through the kernel on the staggered
 continuous-batching run — lives in ``tests/test_serve.py``):
 
-- ``ops/decode_attention.py``: parity vs the dense ``cached_attention``
-  reference across ragged per-slot lengths (including 0 just after
+- ``ops/decode_attention.py``: parity of the paged kernel vs the
+  ``cached_attention`` math over the gathered view across ragged
+  per-slot lengths (including 0 just after
   admit, ``max_len - 1``, and stale retired-slot lengths), odd head
   counts, small-T prefill tails, and the TP head-shard call; the
   per-slot visited-tile count must be length-dependent (the in-kernel
@@ -34,7 +35,6 @@ from jax.sharding import PartitionSpec as P
 
 import mpit_tpu
 from mpit_tpu.models.gpt2 import (
-    cache_update,
     cached_attention,
     paged_cache_update,
     paged_cached_attention,
@@ -43,20 +43,19 @@ from mpit_tpu.models.gpt2 import (
 from mpit_tpu.ops import lm_head_sample
 from mpit_tpu.ops.decode_attention import (
     decode_tiling,
-    flash_decode_attention,
     flash_paged_decode_attention,
     num_kv_blocks,
     paged_write_pages,
     pick_block_k,
-    reference_decode_attention,
     reference_paged_decode_attention,
 )
 
 
 def _qkv_cache(B=4, T=1, H=3, D=16, S=40, seed=0, dtype=jnp.float32):
-    """Random queries + a FULLY random cache — rows past each slot's
-    length are garbage on purpose: validity comes from the mask, never
-    the buffer contents (the slot-isolation invariant)."""
+    """Random queries + a FULLY random cache of ``S`` positions a slot —
+    rows past each slot's length are garbage on purpose: validity comes
+    from the mask, never the buffer contents (the slot-isolation
+    invariant)."""
     ks = jax.random.split(jax.random.key(seed), 3)
     q = jax.random.normal(ks[0], (B, T, H, D), dtype)
     k = jax.random.normal(ks[1], (B, S, H, D), dtype)
@@ -64,87 +63,16 @@ def _qkv_cache(B=4, T=1, H=3, D=16, S=40, seed=0, dtype=jnp.float32):
     return q, k, v
 
 
-class TestFlashDecodeParity:
-    def test_reference_matches_cached_attention_bitwise(self):
-        """The in-module reference IS models.gpt2.cached_attention —
-        pinned bitwise so the two cannot drift."""
-        q, k, v = _qkv_cache()
-        lengths = jnp.asarray([0, 5, 17, 39], jnp.int32)
-        a = reference_decode_attention(q, k, v, lengths)
-        b = cached_attention(q, k, v, lengths)
-        assert jnp.all(a == b)
-
-    @pytest.mark.parametrize("block_k", [8, 16, None])
-    def test_kernel_matches_reference_ragged_lengths(self, block_k):
-        """Ragged lengths incl. 0 (just-admitted), max_len-1 (one free
-        row), block boundaries, and a stale mid value (retired slot)."""
-        q, k, v = _qkv_cache(B=6, S=32)
-        lengths = jnp.asarray([0, 7, 8, 9, 31, 13], jnp.int32)
-        ref = cached_attention(q, k, v, lengths)
-        out = flash_decode_attention(
-            q, k, v, lengths, block_k=block_k, interpret=True
-        )
-        np.testing.assert_allclose(
-            np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5
-        )
-
-    def test_kernel_prefill_tail_small_t(self):
-        """T > 1 (the prefill-tail trace): query t sees keys <= L + t."""
-        q, k, v = _qkv_cache(B=3, T=4, S=24)
-        lengths = jnp.asarray([0, 5, 20], jnp.int32)
-        ref = cached_attention(q, k, v, lengths)
-        out = flash_decode_attention(q, k, v, lengths, interpret=True)
-        np.testing.assert_allclose(
-            np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5
-        )
-
-    def test_odd_head_count_and_head_dim(self):
-        q, k, v = _qkv_cache(B=2, H=5, D=12, S=16)
-        lengths = jnp.asarray([3, 15], jnp.int32)
-        ref = cached_attention(q, k, v, lengths)
-        out = flash_decode_attention(q, k, v, lengths, interpret=True)
-        np.testing.assert_allclose(
-            np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5
-        )
-
-    def test_non_tpu_fallback_is_reference_bitwise(self):
-        """interpret=None on CPU routes to the reference path — exact
-        (the engine's "kernel" mode off-TPU keeps the PR 4 bit-match)."""
-        q, k, v = _qkv_cache()
-        lengths = jnp.asarray([0, 5, 17, 39], jnp.int32)
-        out = flash_decode_attention(q, k, v, lengths)
-        assert jnp.all(out == cached_attention(q, k, v, lengths))
-
-    def test_bf16_kernel_close(self):
-        q, k, v = _qkv_cache(S=32, dtype=jnp.bfloat16)
-        lengths = jnp.asarray([0, 9, 16, 31], jnp.int32)
-        ref = cached_attention(q, k, v, lengths)
-        out = flash_decode_attention(q, k, v, lengths, interpret=True)
-        np.testing.assert_allclose(
-            np.asarray(out, np.float32), np.asarray(ref, np.float32),
-            rtol=0.05, atol=0.05,
-        )
-
-    def test_tp_head_shard_call(self, world_2d):
-        """The kernel on an H/P head shard inside shard_map (the TP
-        engine's exact call) merges back to the full-head reference."""
-        q, k, v = _qkv_cache(B=2, H=4, D=16, S=16)
-        lengths = jnp.asarray([2, 11], jnp.int32)
-        ref = cached_attention(q, k, v, lengths)
-
-        f = world_2d.shard_map(
-            lambda q, k, v: flash_decode_attention(
-                q, k, v, lengths, interpret=True
-            ),
-            in_specs=(P(None, None, "model"), P(None, None, "model"),
-                      P(None, None, "model")),
-            out_specs=P(None, None, "model"),
-            check_vma=False,
-        )
-        out = jax.jit(f)(q, k, v)
-        np.testing.assert_allclose(
-            np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5
-        )
+def _as_pool(k, v, ps):
+    """Per-slot buffers ``[B, S, H, D]`` as a page pool of ``ps``-row
+    pages ``[B*S/ps, ps, H*D]`` and the block table that maps slot ``b``
+    to its own pages, in REVERSED order in the pool (so a kernel that
+    ignored the table would read the wrong rows)."""
+    B, S, H, D = k.shape
+    n = S // ps
+    pool = lambda a: a.reshape(B * n, ps, H * D)[::-1]
+    bt = (B * n - 1 - jnp.arange(B * n, dtype=jnp.int32)).reshape(B, n)
+    return pool(k), pool(v), bt
 
 
 def _paged_setup(B=3, T=1, H=2, D=16, n_pages=12, ps=8, pages_per_slot=4,
@@ -183,15 +111,17 @@ class TestPagedFlashDecode:
 
     def test_paged_update_and_gather_match_dense(self):
         """Writing through a permuted block table then gathering the
-        dense view reproduces the dense cache_update exactly."""
+        dense view puts each slot's rows at its positions and nothing
+        anywhere else."""
         rng = np.random.RandomState(0)
         B, T, H, D, ps = 2, 3, 2, 4, 4
         bt = jnp.asarray([[3, 1, 6, 0], [2, 5, 7, 4]], jnp.int32)
-        dense = jnp.zeros((B, 16, H, D))
         pool = jnp.zeros((8, ps, H * D))
         new = jnp.asarray(rng.randn(B, T, H, D), jnp.float32)
         lens = jnp.asarray([2, 13], jnp.int32)
-        d2 = cache_update(dense, new, lens)
+        d2 = np.zeros((B, 16, H, D), np.float32)
+        for b, start in enumerate(np.asarray(lens)):
+            d2[b, start : start + T] = np.asarray(new[b])
         p2 = paged_cache_update(
             pool, new.reshape(B, T, H * D), lens, bt,
             valid=jnp.ones((B, T), bool),
@@ -294,6 +224,16 @@ class TestPagedFlashDecode:
         assert jnp.all(by_page.q == by_row.q)
         assert jnp.all(by_page.scale == by_row.scale)
 
+    def test_reference_is_cached_attention_over_the_gathered_view(self):
+        """The in-module reference IS models.gpt2.cached_attention over
+        each slot's gathered pages — pinned bitwise so the two cannot
+        drift (the oracle every kernel test below leans on)."""
+        q, k, v = _qkv_cache()
+        kp, vp, bt = _as_pool(k, v, 8)
+        lengths = jnp.asarray([0, 5, 17, 39], jnp.int32)
+        a = reference_paged_decode_attention(q, kp, vp, lengths, bt)
+        assert jnp.all(a == cached_attention(q, k, v, lengths))
+
     @pytest.mark.parametrize("block_k", [4, 8, None])
     def test_kernel_matches_reference_ragged_lengths(self, block_k):
         q, kp, vp, bt = _paged_setup()
@@ -304,6 +244,38 @@ class TestPagedFlashDecode:
         )
         np.testing.assert_allclose(
             np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5
+        )
+
+    # name: (_qkv_cache's shape, page size, lengths, tolerance). Against
+    # cached_attention over the slots' own buffers, the pool made of them.
+    _SHAPES = {
+        # Ragged lengths incl. 0 (just-admitted), S-1 (one free row),
+        # page boundaries, and a stale mid value (retired slot).
+        "ragged-16-row-pages": (
+            dict(B=6, S=32), 16, [0, 7, 8, 9, 31, 13], 2e-5),
+        "ragged-one-page-a-slot": (
+            dict(B=6, S=32), 32, [0, 7, 8, 9, 31, 13], 2e-5),
+        # T > 1 (the prefill-tail trace): query t sees keys <= L + t.
+        "tail-T4": (dict(B=3, T=4, S=24), 8, [0, 5, 20], 2e-5),
+        "odd-heads-and-head-dim": (
+            dict(B=2, H=5, D=12, S=16), 8, [3, 15], 2e-5),
+        "bf16": (
+            dict(S=32, dtype=jnp.bfloat16), 16, [0, 9, 16, 31], 0.05),
+    }
+
+    @pytest.mark.parametrize("case", sorted(_SHAPES))
+    def test_kernel_matches_cached_attention_at_shapes(self, case):
+        shape, ps, lengths, tol = self._SHAPES[case]
+        q, k, v = _qkv_cache(**shape)
+        kp, vp, bt = _as_pool(k, v, ps)
+        lengths = jnp.asarray(lengths, jnp.int32)
+        ref = cached_attention(q, k, v, lengths)
+        out = flash_paged_decode_attention(
+            q, kp, vp, lengths, bt, interpret=True
+        )
+        np.testing.assert_allclose(
+            np.asarray(out, np.float32), np.asarray(ref, np.float32),
+            rtol=tol, atol=tol,
         )
 
     @pytest.mark.parametrize("nan_pages", [False, True], ids=["", "nan"])
@@ -341,7 +313,7 @@ class TestPagedFlashDecode:
             hole = jnp.asarray(~seen)[:, None, None]
             kp = jnp.where(hole, jnp.nan, kp)
             vp = jnp.where(hole, jnp.nan, vp)
-        tiling = decode_tiling(t, h, dtype, block_k=8, page_size=ps)
+        tiling = decode_tiling(t, h, dtype, page_size=ps)
         assert (tiling.form, tiling.rows) == (form, 256)
         # The reference gathers whole tables: judge it on a finite pool
         # (the masked rows weigh exactly 0 there, as here).
@@ -384,23 +356,6 @@ class TestPagedFlashDecode:
         )
         assert jnp.all(out[0] == out[2])
 
-    def test_paged_matches_dense_through_gather(self):
-        """The paged kernel vs the DENSE kernel on the gathered view:
-        same math, different placement."""
-        q, kp, vp, bt = _paged_setup()
-        lengths = jnp.asarray([3, 17, 30], jnp.int32)
-        dense_out = flash_decode_attention(
-            q, paged_gather(kp, bt, 2), paged_gather(vp, bt, 2), lengths,
-            block_k=8, interpret=True,
-        )
-        paged_out = flash_paged_decode_attention(
-            q, kp, vp, lengths, bt, block_k=8, interpret=True
-        )
-        np.testing.assert_allclose(
-            np.asarray(paged_out), np.asarray(dense_out),
-            rtol=2e-5, atol=2e-5,
-        )
-
     def test_non_tpu_fallback_is_reference_bitwise(self):
         q, kp, vp, bt = _paged_setup()
         lengths = jnp.asarray([2, 11, 27], jnp.int32)
@@ -422,8 +377,10 @@ class TestPagedFlashDecode:
         assert list(np.asarray(visited)) == list(host) == [1, 4, 8]
 
     def test_block_k_must_divide_page_size(self):
-        """A tile must never straddle pages — validated on every
-        platform, like the dense divisibility check."""
+        """A tile must never straddle pages — validated HERE, on the
+        CPU fallback too, not first at TPU deploy (and the fallback's
+        visited-block accounting must never describe a tiling the kernel
+        can't run)."""
         q, kp, vp, bt = _paged_setup(ps=8)
         with pytest.raises(ValueError, match="divisible"):
             flash_paged_decode_attention(
@@ -461,8 +418,9 @@ class TestLengthDependence:
         S, bk = 64, 8
         q, k, v = _qkv_cache(B=4, S=S)
         lengths = jnp.asarray([0, 7, 30, 63], jnp.int32)
-        _, visited = flash_decode_attention(
-            q, k, v, lengths, block_k=bk, interpret=True,
+        kp, vp, bt = _as_pool(k, v, 16)
+        _, visited = flash_paged_decode_attention(
+            q, kp, vp, lengths, bt, block_k=bk, interpret=True,
             return_visited=True,
         )
         total = S // bk
@@ -470,24 +428,21 @@ class TestLengthDependence:
         assert list(np.asarray(visited)) == want
         assert int(visited[0]) < total and int(visited[1]) < total
 
-    def test_in_kernel_bound_matches_host_formula(self):
+    @pytest.mark.parametrize("interpret", [True, None],
+                             ids=["kernel", "reference"])
+    def test_bound_matches_host_formula(self, interpret):
+        """The in-kernel bound and, off the TPU, what the reference path
+        reports: one host formula."""
         S, bk, T = 48, 8, 3
         q, k, v = _qkv_cache(B=5, T=T, S=S)
+        kp, vp, bt = _as_pool(k, v, 16)
         lengths = jnp.asarray([0, 4, 8, 21, 45], jnp.int32)
-        _, visited = flash_decode_attention(
-            q, k, v, lengths, block_k=bk, interpret=True,
+        _, visited = flash_paged_decode_attention(
+            q, kp, vp, lengths, bt, block_k=bk, interpret=interpret,
             return_visited=True,
         )
         host = num_kv_blocks(np.asarray(lengths), T, S, bk)
         assert list(np.asarray(visited)) == list(host)
-
-    def test_reference_path_reports_host_formula(self):
-        q, k, v = _qkv_cache(B=2, S=32)
-        lengths = jnp.asarray([3, 17], jnp.int32)
-        _, visited = flash_decode_attention(
-            q, k, v, lengths, block_k=8, return_visited=True
-        )
-        assert list(np.asarray(visited)) == [1, 3]
 
     def test_pick_block_k(self):
         assert pick_block_k(1024) == 256
@@ -498,17 +453,6 @@ class TestLengthDependence:
         # nothing divides: one whole-buffer tile (no skipping, still
         # correct)
         assert pick_block_k(7) == 7
-
-    def test_non_divisor_block_k_rejected_on_every_platform(self):
-        """An explicit block_k that doesn't tile the buffer must raise
-        HERE, on the CPU fallback too — not first at TPU deploy (and the
-        fallback's visited-tile accounting must never describe a tiling
-        the kernel can't run)."""
-        q = jnp.zeros((1, 1, 2, 8), jnp.float32)
-        kv = jnp.zeros((1, 128, 2, 8), jnp.float32)
-        lengths = jnp.zeros((1,), jnp.int32)
-        with pytest.raises(ValueError, match="divisible"):
-            flash_decode_attention(q, kv, kv, lengths, block_k=48)
 
 
 class TestLMHeadSample:
@@ -617,45 +561,15 @@ class TestDecodeKernelCompiles:
     kernel at the serving shapes against a described v5e topology
     (conftest's ``v5e_world``)."""
 
-    @pytest.mark.parametrize(
-        "t,h,d,s", [(1, 12, 64, 1024), (64, 12, 64, 1024), (1, 6, 64, 2048)]
-    )
-    def test_kernel_compiles_at_serving_shapes(self, v5e_world, t, h, d, s):
-        from mpit_tpu.utils.aot import abstractify
-
-        world = v5e_world
-
-        def f(q, k, v, lengths):
-            return flash_decode_attention(
-                q, k, v, lengths, interpret=False
-            )
-
-        step = jax.jit(
-            world.shard_map(
-                f,
-                in_specs=(P("data"), P("data"), P("data"), P("data")),
-                out_specs=P("data"),
-            )
-        )
-        B = 8  # one slot-batch per device
-        mk = lambda shp, dt: abstractify(
-            jax.ShapeDtypeStruct(shp, dt), world.mesh, P("data")
-        )
-        step.lower(
-            mk((8 * B, t, h, d), jnp.bfloat16),
-            mk((8 * B, s, h, d), jnp.bfloat16),
-            mk((8 * B, s, h, d), jnp.bfloat16),
-            mk((8 * B,), jnp.int32),
-        ).compile()
-
-    def test_paged_kernel_compiles_at_serving_shapes(self, v5e_world):
-        """The ISSUE 7 paged variant through the real compiler: SMEM
+    @pytest.mark.parametrize("t,h", [(1, 12), (64, 12), (1, 6)])
+    def test_paged_kernel_compiles_at_serving_shapes(self, v5e_world, t, h):
+        """The kernel through the real compiler: SMEM
         block-table indirection + per-tile DMA source resolution at a
         production-ish pool geometry."""
         from mpit_tpu.utils.aot import abstractify
 
         world = v5e_world
-        h, d, ps, n_pages, per_slot = 12, 64, 64, 2048, 16
+        d, ps, n_pages, per_slot = 64, 64, 2048, 16
 
         def f(q, kp, vp, lengths, bt):
             return flash_paged_decode_attention(
@@ -674,7 +588,7 @@ class TestDecodeKernelCompiles:
             jax.ShapeDtypeStruct(shp, dt), world.mesh, spec
         )
         step.lower(
-            mk((8 * B, 1, h, d), jnp.bfloat16, P("data")),
+            mk((8 * B, t, h, d), jnp.bfloat16, P("data")),
             mk((n_pages, ps, h * d), jnp.bfloat16, P()),
             mk((n_pages, ps, h * d), jnp.bfloat16, P()),
             mk((8 * B,), jnp.int32, P("data")),

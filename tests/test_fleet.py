@@ -72,7 +72,7 @@ def model_and_params():
 
 @pytest.fixture(scope="module")
 def single_engine_tokens(model_and_params):
-    """The oracle: the same request set through ONE dense engine's
+    """The oracle: the same request set through ONE engine's
     continuous-batching Server — the run the fleet must bit-match."""
     _, params = model_and_params
     engine = Engine(CFG, params, slots=2, max_len=32, prefill_len=8)
@@ -82,7 +82,7 @@ def single_engine_tokens(model_and_params):
     return {str(c.rid): list(c.tokens) for c in server.run()}
 
 
-def _dense_factory(params):
+def _engine_factory(params):
     def factory(role, rank):
         return Engine(CFG, params, slots=2, max_len=32, prefill_len=8)
 
@@ -193,7 +193,7 @@ class TestShipmentWire:
 
 
 class TestFleetE2E:
-    def test_dense_fleet_bitmatches_single_engine(
+    def test_fleet_bitmatches_single_engine(
         self, model_and_params, single_engine_tokens
     ):
         """THE acceptance run: 1 router + 1 prefill + 2 decode workers,
@@ -205,7 +205,7 @@ class TestFleetE2E:
         # stall (loaded CI box) spuriously evict a LIVE worker and
         # break the strict zero-churn pin below.
         out = run_fleet(
-            _dense_factory(params), _requests(), prefill=1, decode=2,
+            _engine_factory(params), _requests(), prefill=1, decode=2,
             heartbeat_s=0.05, lease_s=5.0,
         )
         assert out["shed"] == []
@@ -238,7 +238,7 @@ class TestFleetE2E:
         _, params = model_and_params
         plan = FaultPlan(seed=0, kill_at={3: 2})  # decode rank 3, tick 2
         out = run_fleet(
-            _dense_factory(params), _requests(), prefill=1, decode=2,
+            _engine_factory(params), _requests(), prefill=1, decode=2,
             heartbeat_s=0.05, lease_s=0.75, fault_plan=plan,
         )
         assert out["fault_events"] == (("kill", 3, 2),)
@@ -259,7 +259,7 @@ class TestFleetE2E:
             Request(rid="same", prompt=[7], max_new_tokens=2),
         ]
         with pytest.raises(ValueError, match="unique rids"):
-            run_fleet(_dense_factory(params), dup, prefill=1, decode=1)
+            run_fleet(_engine_factory(params), dup, prefill=1, decode=1)
 
 
 @pytest.mark.slow
@@ -300,7 +300,7 @@ class TestFleetHeavy:
         _, params = model_and_params
         plan = FaultPlan(seed=0, kill_at={1: 1, 4: 3})
         out = run_fleet(
-            _dense_factory(params), _requests(), prefill=2, decode=2,
+            _engine_factory(params), _requests(), prefill=2, decode=2,
             heartbeat_s=0.05, lease_s=0.75, fault_plan=plan,
         )
         assert set(e[:2] for e in out["fault_events"]) == {

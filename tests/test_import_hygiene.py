@@ -93,3 +93,45 @@ class TestHotPathImportHygiene:
         )
         assert [x.t for x in a] == [x.t for x in b]
         assert [x.request.prompt for x in a] == [x.request.prompt for x in b]
+
+
+class TestOneServingEngine:
+    """ISSUE 28: the package holds no second engine. ``Engine`` serves
+    through the page pool and nothing selects that; the oracle of a
+    serving test is the no-cache forward (``tests/test_serve.py::
+    ref_greedy``) or ``benchmark/reference.py``, never a cache of another
+    layout kept "just as a reference"."""
+
+    GONE = ("KVCache", "alloc_cache", "cache_specs")
+
+    def test_serve_exports_no_per_slot_cache(self):
+        import mpit_tpu.serve as serve
+        from mpit_tpu.serve import kvcache
+
+        for name in self.GONE:
+            assert not hasattr(serve, name), name
+            assert not hasattr(kvcache, name), name
+            assert name not in serve.__all__ + kvcache.__all__
+        assert {"PagedKVCache", "alloc_paged_cache", "paged_cache_specs",
+                "PageAllocator"} <= set(serve.__all__)
+
+    def test_nothing_selects_the_engine(self):
+        import inspect
+
+        from mpit_tpu.models.serving import ServeModel
+        from mpit_tpu.ops import decode_attention
+        from mpit_tpu.serve import Engine, Server
+
+        import re
+
+        for cls in (Engine, Server):
+            # An attribute named so, not a method like ``_paged_decode_step``.
+            assert not re.search(r"self\._?paged\b", inspect.getsource(cls))
+        assert not hasattr(Engine, "prefill")  # the whole-prompt step
+        options = inspect.signature(Engine.__init__).parameters
+        assert "paged" not in options and options["kv_pages"].default is None
+        assert not hasattr(ServeModel, "forward_cached")
+        for method in (ServeModel.with_decode_attention,
+                       ServeModel.check_supported):
+            assert "paged" not in inspect.signature(method).parameters
+        assert not hasattr(decode_attention, "flash_decode_attention")

@@ -37,16 +37,17 @@ from mpit_tpu.models.gpt2 import cached_attention
 from mpit_tpu.ops.kv_quant import (
     QuantizedKV,
     dequantize_kv,
-    kv_stack,
     kv_wire_bytes_per_row,
+    pack_heads,
     quantize_kv,
+    unpack_heads,
 )
 from mpit_tpu.ops.ring_collectives import (
     dequantize_blocks,
     quantize_blocks,
     quantize_chunk,
 )
-from mpit_tpu.serve import Engine, Request, Server, alloc_cache
+from mpit_tpu.serve import Engine, Request, Server, alloc_paged_cache
 
 CFG = GPT2Config.tiny(
     vocab_size=64, max_seq_len=64, num_layers=2, num_heads=2, d_model=32,
@@ -77,7 +78,7 @@ _ORACLE_MEMO: dict = {}
 
 def _isolated_int8(params, prompt, n):
     """The self-consistency oracle: the same request alone through the
-    int8 dense-reference engine (every other int8 path must agree with
+    int8 reference engine (every other int8 path must agree with
     it token-for-token). ONE engine, reset between requests, results
     memoized — fresh-engine-per-call would re-pay two XLA compiles per
     oracle query and dominate the suite wall (isolation comes from the
@@ -87,7 +88,7 @@ def _isolated_int8(params, prompt, n):
         return _ORACLE_MEMO[key]
     if not _ORACLE_ENGINE:
         _ORACLE_ENGINE.append(Engine(
-            CFG, params, slots=2, max_len=40, prefill_len=16,
+            CFG, params, slots=2, max_len=40, kv_page_size=8, prefill_len=16,
             kv_dtype="int8", decode_attention="reference",
         ))
     eng = _ORACLE_ENGINE[0]
@@ -145,11 +146,12 @@ class TestQuantizedKVContainer:
         assert isinstance(back, QuantizedKV)
         sub = kv[0]
         assert sub.q.shape == (4, 3, 8) and sub.scale.shape == (4, 3, 1)
-        stacked = kv_stack([kv, kv])
-        assert stacked.q.shape == (2, 2, 4, 3, 8)
-        # kv_stack on plain arrays == jnp.stack
-        plain = kv_stack([x, x])
-        assert plain.shape == (2,) + x.shape
+        # The pool's packed form and back: q and scale together.
+        packed = pack_heads(kv)
+        assert packed.q.shape == (2, 4, 24) and packed.scale.shape == (2, 4, 3)
+        again = unpack_heads(packed, 3)
+        assert jnp.all(again.q == kv.q) and jnp.all(again.scale == kv.scale)
+        assert pack_heads(x).shape == (2, 4, 24)
 
     def test_dequant_round_trip_bound(self):
         x = jnp.asarray(np.random.RandomState(4).randn(3, 5, 2, 16))
@@ -169,14 +171,14 @@ class TestQuantizedKVContainer:
         assert r(4, 64, "int8") / r(4, 64, jnp.float32) <= 0.28
 
 
-class TestQuantizedDenseServing:
+class TestQuantizedServing:
     def test_staggered_int8_bitmatches_isolated_int8(self, params):
-        """Self-consistency on the dense engine: slot reuse, admits and
+        """Self-consistency with a pool for every slot: slot reuse, admits and
         retires interleaved — every request's int8 output equals its
         isolated int8 run (per-row quantization depends only on the
         row's own values, so batching must not change anything)."""
         eng = Engine(
-            CFG, params, slots=2, max_len=40, prefill_len=16,
+            CFG, params, slots=2, max_len=40, kv_page_size=8, prefill_len=16,
             kv_dtype="int8", decode_attention="reference",
         )
         done, server = _run(eng, list(zip(PROMPTS, MAX_NEW)))
@@ -184,20 +186,27 @@ class TestQuantizedDenseServing:
         for rid, (p, n) in enumerate(zip(PROMPTS, MAX_NEW)):
             assert done[rid] == _isolated_int8(params, p, n), rid
 
-    def test_interpret_kernel_matches_reference_int8(self, params):
+    @pytest.mark.parametrize(
+        "pool", [{}, dict(kv_pages=24)],
+        ids=["pool-for-every-slot", "small-pool"],
+    )
+    def test_interpret_kernel_matches_reference_int8(self, params, pool):
         """The fused-dequant kernel (interpret mode) agrees with the
         whole-buffer-dequant reference token-for-token — the per-tile
-        dequant is the same math as the oracle's — at the pinned dense
-        lifetime compile count (2: prefill + decode, quantized or not)."""
+        dequant is the same math as the oracle's — at the pinned
+        compile count (prefill + decode, and the page copy once one
+        ran; quantized or not)."""
         eng = Engine(
-            CFG, params, slots=2, max_len=40, prefill_len=16,
-            kv_dtype="int8", decode_attention="interpret",
+            CFG, params, slots=2, max_len=40, kv_page_size=8, prefill_len=16,
+            kv_dtype="int8", decode_attention="interpret", **pool,
         )
         assert eng.decode_attention_mode == "kernel"
         done, _ = _run(eng, list(zip(PROMPTS, MAX_NEW)))
         for rid, (p, n) in enumerate(zip(PROMPTS, MAX_NEW)):
             assert done[rid] == _isolated_int8(params, p, n), rid
         assert eng.compile_watch.compiles == 2
+        eng.copy_page(0, 0)
+        assert eng.compile_watch.compiles == 3
         assert eng.compile_watch.unexpected == 0
 
     def test_logit_error_bounded_and_nonzero(self, params):
@@ -208,17 +217,19 @@ class TestQuantizedDenseServing:
         prompt = [5, 9, 3, 1, 7, 2]
         padded = np.zeros((2, 8), np.int32)
         padded[0, : len(prompt)] = prompt
-        c_f = alloc_cache(CFG, slots=2, max_len=16)
-        c_q = alloc_cache(CFG, slots=2, max_len=16, quantized=True)
-        lf, _ = model.apply(
+        c_f = alloc_paged_cache(CFG, slots=2, num_pages=4, page_size=8)
+        c_q = alloc_paged_cache(CFG, slots=2, num_pages=4, page_size=8,
+                                quantized=True)
+        through = lambda c: model.apply(
             {"params": params}, jnp.asarray(padded),
-            cache=(c_f.k, c_f.v, c_f.lengths),
+            paged_cache=(c.k, c.v, c.lengths,
+                         jnp.asarray([[0, 1], [2, 3]], jnp.int32),
+                         jnp.ones((2, 8), bool)),
         )
-        lq, (k2, _v2) = model.apply(
-            {"params": params}, jnp.asarray(padded),
-            cache=(c_q.k, c_q.v, c_q.lengths),
-        )
-        assert isinstance(k2, QuantizedKV) and k2.dtype == jnp.int8
+        lf, _ = through(c_f)
+        lq, (k2, _v2) = through(c_q)
+        assert all(isinstance(k, QuantizedKV) and k.dtype == jnp.int8
+                   for k in k2)
         d = np.abs(
             np.asarray(lf[0, : len(prompt)], np.float32)
             - np.asarray(lq[0, : len(prompt)], np.float32)
@@ -230,25 +241,34 @@ class TestQuantizedDenseServing:
         """Anti-vacuity at the cache level: the int8 engine's stored
         rows round-trip to values that DIFFER from the f32 engine's —
         quantization really ran, token agreement notwithstanding."""
-        e_f = Engine(CFG, params, slots=1, max_len=40, prefill_len=8)
-        e_q = Engine(CFG, params, slots=1, max_len=40, prefill_len=8,
+        e_f = Engine(CFG, params, slots=1, max_len=40, kv_page_size=8, prefill_len=8)
+        e_q = Engine(CFG, params, slots=1, max_len=40, kv_page_size=8, prefill_len=8,
                      kv_dtype="int8")
         _run(e_f, [(PROMPTS[0], 4)])
         _run(e_q, [(PROMPTS[0], 4)])
-        kf = np.asarray(e_f.cache.k[:, 0, :7], np.float32)
-        kq = np.asarray(dequantize_kv(e_q.cache.k)[:, 0, :7], np.float32)
-        assert kq.shape == kf.shape
-        assert not np.array_equal(kq, kf)
-        assert np.abs(kq - kf).max() < 0.1  # ...but by quantization, not drift
+        # Both allocators gave slot 0 the same first page. The prompt's
+        # rows are judged: later rows follow the tokens each engine
+        # sampled, which a random tiny model need not keep equal.
+        fill = len(PROMPTS[0])
+        page = int(e_f.allocator.block_tables[0, 0])
+        assert page == int(e_q.allocator.block_tables[0, 0])
+        for kf, kq in zip(e_f.cache.k, e_q.cache.k):
+            kf = np.asarray(kf[page, :fill], np.float32)
+            kq = np.asarray(pack_heads(dequantize_kv(
+                unpack_heads(kq, CFG.num_heads)))[page, :fill], np.float32)
+            assert kq.shape == kf.shape
+            assert not np.array_equal(kq, kf)
+            # ...but by quantization, not drift
+            assert np.abs(kq - kf).max() < 0.1
 
     def test_default_engine_unchanged_without_kv_dtype(self, params):
-        """kv_dtype unset: model-dtype dense cache (no QuantizedKV
+        """kv_dtype unset: model-dtype pool (no QuantizedKV
         anywhere), kv_dtype reported but NOT stamped on spans."""
-        eng = Engine(CFG, params, slots=2, max_len=40, prefill_len=8)
+        eng = Engine(CFG, params, slots=2, max_len=40, kv_page_size=8, prefill_len=8)
         assert not eng.kv_quantized and not eng.kv_dtype_explicit
         assert eng.kv_dtype == "f32"  # CFG.dtype is f32
-        assert eng.cache.k.dtype == jnp.float32
-        assert not isinstance(eng.cache.k, QuantizedKV)
+        assert all(k.dtype == jnp.float32 for k in eng.cache.k)
+        assert not any(isinstance(k, QuantizedKV) for k in eng.cache.k)
         rec = obs.Recorder()
         with obs.local_recorder(rec):
             _run(eng, [(PROMPTS[0], 3)])
@@ -256,7 +276,7 @@ class TestQuantizedDenseServing:
         assert "kv_dtype" not in labels
 
     def test_explicit_kv_dtype_stamped_on_spans_and_stats(self, params):
-        eng = Engine(CFG, params, slots=2, max_len=40, prefill_len=8,
+        eng = Engine(CFG, params, slots=2, max_len=40, kv_page_size=8, prefill_len=8,
                      kv_dtype="int8")
         rec = obs.Recorder()
         with obs.local_recorder(rec):
@@ -267,15 +287,15 @@ class TestQuantizedDenseServing:
         assert server.stats()["kv_dtype"] == "int8"
 
     def test_bf16_and_f32_pin_cache_dtype(self, params):
-        e16 = Engine(CFG, params, slots=1, max_len=40, prefill_len=8,
+        e16 = Engine(CFG, params, slots=1, max_len=40, kv_page_size=8, prefill_len=8,
                      kv_dtype="bf16")
-        assert e16.cache.k.dtype == jnp.bfloat16
+        assert e16.cache.k[0].dtype == jnp.bfloat16
         assert e16.kv_dtype == "bf16" and e16.kv_dtype_explicit
-        e32 = Engine(CFG, params, slots=1, max_len=40, prefill_len=8,
+        e32 = Engine(CFG, params, slots=1, max_len=40, kv_page_size=8, prefill_len=8,
                      kv_dtype="f32")
-        assert e32.cache.k.dtype == jnp.float32
+        assert e32.cache.k[0].dtype == jnp.float32
         with pytest.raises(ValueError, match="kv_dtype"):
-            Engine(CFG, params, slots=1, max_len=40, prefill_len=8,
+            Engine(CFG, params, slots=1, max_len=40, kv_page_size=8, prefill_len=8,
                    kv_dtype="int4")
 
 
@@ -331,19 +351,6 @@ class TestQuantizedPagedServing:
         for rid, (p, n) in enumerate(reqs):
             assert done[rid] == _isolated_int8(params, p, n), rid
 
-    def test_paged_interpret_kernel_int8_bitmatch(self, params):
-        """Paged fused-dequant kernel parity + the paged compile pin
-        (3: prefill + decode + copy_page, quantized or not)."""
-        eng = self._paged(
-            params, kv_page_size=8, decode_attention="interpret"
-        )
-        done, _ = _run(eng, list(zip(PROMPTS, MAX_NEW)))
-        for rid, (p, n) in enumerate(zip(PROMPTS, MAX_NEW)):
-            assert done[rid] == _isolated_int8(params, p, n), rid
-        eng.copy_page(0, 0)
-        assert eng.compile_watch.compiles == 3
-        assert eng.compile_watch.unexpected == 0
-
     def test_preempt_resume_int8_bitmatch(self, params):
         """Park a mid-generation int8 request (pages + scale blocks
         freed), resume through chunked prefill — output identical to
@@ -370,73 +377,60 @@ class TestQuantizedSpeculative:
     # slow tier; this container replays tier-1 ~13% slower than the
     # PR-16 recording and the guard fired (the PR-14 remedy).
     @pytest.mark.slow
-    def test_spec_int8_bitmatches_plain_int8(self, params):
+    @pytest.mark.parametrize(
+        "pool", [{}, dict(kv_pages=24)],
+        ids=["pool-for-every-slot", "small-pool"],
+    )
+    def test_spec_int8_bitmatches_plain_int8(self, params, pool):
         """Draft-then-verify with BOTH pools quantized (the draft
-        mirrors the target's wire dtype): greedy output equals the
-        plain int8 oracle's, at the speculative compile pin (3 dense:
-        prefill + spec_draft + spec_verify)."""
+        mirrors the target's wire dtype and shares its block tables):
+        rollback retreats both fills past page boundaries without
+        corrupting scales, and greedy output equals the plain int8
+        oracle's, at the speculative compile pin (prefill + spec_draft
+        + spec_verify)."""
         from mpit_tpu.serve import draft_from_target
 
         dp, dcfg = draft_from_target(params, CFG, 1)
         reqs = list(zip(PROMPTS[:3], MAX_NEW[:3]))
         eng = Engine(
-            CFG, params, slots=2, max_len=40, prefill_len=16,
+            CFG, params, slots=2, max_len=40, kv_page_size=8, prefill_len=16,
             spec_k=2, draft_params=dp, draft_cfg=dcfg,
-            kv_dtype="int8", decode_attention="interpret",
+            kv_dtype="int8", decode_attention="interpret", **pool,
         )
-        assert isinstance(eng.draft_cache.k, QuantizedKV)
+        assert all(isinstance(k, QuantizedKV) for k in eng.draft_cache.k)
         spec, _ = _run(eng, reqs)
         for rid, (p, n) in enumerate(reqs):
             assert spec[rid] == _isolated_int8(params, p, n), rid
         assert eng.compile_watch.compiles == 3
 
-    @pytest.mark.slow
-    def test_spec_int8_paged_bitmatches_plain_int8(self, params):
-        """The paged speculative form: quantized target AND draft pools
-        share block tables; rollback retreats both fills past page
-        boundaries without corrupting scales."""
-        from mpit_tpu.serve import draft_from_target
-
-        dp, dcfg = draft_from_target(params, CFG, 1)
-        reqs = list(zip(PROMPTS[:3], MAX_NEW[:3]))
-        peng = Engine(
-            CFG, params, slots=2, max_len=40, prefill_len=16,
-            kv_pages=24, kv_page_size=8, spec_k=2,
-            draft_params=dp, draft_cfg=dcfg,
-            kv_dtype="int8", decode_attention="interpret",
-        )
-        pspec, _ = _run(peng, reqs)
-        for rid, (p, n) in enumerate(reqs):
-            assert pspec[rid] == _isolated_int8(params, p, n), rid
-
 
 @pytest.mark.slow
 class TestQuantizedTensorParallel:
-    def test_tp_int8_bitmatches_dense_int8(self, params):
+    def test_tp_int8_bitmatches_single_device_int8(self, params):
         """data=4 × model=2 fake mesh: int8 pools + scale blocks both
         sharded on the head axis; greedy output equals the
         single-device int8 engine's."""
         world = mpit_tpu.init({"data": 4, "model": 2}, set_default=False)
         reqs = list(zip(PROMPTS[:3], MAX_NEW[:3]))
         ref, _ = _run(
-            Engine(CFG, params, slots=2, max_len=40, prefill_len=16,
+            Engine(CFG, params, slots=2, max_len=40, kv_page_size=8, prefill_len=16,
                    kv_dtype="int8", decode_attention="interpret"),
             reqs,
         )
         eng = Engine(
-            CFG, params, slots=2, max_len=40, prefill_len=16,
+            CFG, params, slots=2, max_len=40, kv_page_size=8, prefill_len=16,
             world=world, tp_axis="model",
             kv_dtype="int8", decode_attention="interpret",
         )
-        # int8 payload AND scale shards split the head dim.
-        q_shapes = {s.data.shape for s in eng.cache.k.q.addressable_shards}
-        s_shapes = {
-            s.data.shape for s in eng.cache.k.scale.addressable_shards
-        }
-        assert q_shapes == {
-            (CFG.num_layers, 2, 40, CFG.num_heads // 2, CFG.head_dim)
-        }
-        assert s_shapes == {(CFG.num_layers, 2, 40, CFG.num_heads // 2, 1)}
+        # A buffer a layer: the int8 payload [P, ps, H*Dh] AND its
+        # scales split over the 2-way model axis, each rank's H/2 heads.
+        q_shapes = {s.data.shape for k in eng.cache.k
+                    for s in k.q.addressable_shards}
+        s_shapes = {s.data.shape for k in eng.cache.k
+                    for s in k.scale.addressable_shards}
+        half = CFG.num_heads // 2
+        assert q_shapes == {(eng.num_pages, 8, half * CFG.head_dim)}
+        assert s_shapes == {(eng.num_pages, 8, half)}
         done, _ = _run(eng, reqs)
         assert done == ref
 

@@ -93,6 +93,41 @@ class TestCapacity:
         assert list(a.block_tables[0][1:]) == [0] * 7
 
 
+class TestPoolForEverySlot:
+    """``Engine(kv_pages=None)`` sizes the pool at ``slots x
+    pages_per_slot`` (ISSUE 28): admission then never waits for pages,
+    whatever is shared."""
+
+    @pytest.mark.parametrize("page_size", [4, 16, 32])
+    def test_every_slot_at_max_len_always_fits(self, page_size):
+        """Requests that fill their slot to the last position, with
+        prefixes shared at every kind of boundary (whole pages, part of a
+        page, the whole prompt), admitted, written to the end and retired
+        in turn over many rounds: no admit comes back ``None``, and every
+        copy-on-write finds its page."""
+        slots, max_len = 4, 64
+        pps = max_len // page_size
+        a = PageAllocator(slots * pps, page_size, pps, slots)
+        rng = np.random.RandomState(page_size)
+        system = rng.randint(0, 50, size=40).tolist()
+        for round_ in range(12):
+            share = [0, page_size, page_size + 3, 40][round_ % 4]
+            for slot in range(slots):
+                own = rng.randint(50, 99, size=48 - share).tolist()
+                prompt = system[:share] + (own if slot % 2 else own[::-1])
+                prompt = prompt[: 20 + 7 * slot]
+                plan = a.admit(slot, prompt, max_len - len(prompt))
+                assert plan is not None, (round_, slot)
+                a.register_prefix(slot, prompt)
+                # Every position from the floor to the end gets written.
+                for pos in range(plan.shared_tokens, max_len - 1):
+                    a.cow_before_write(slot, pos)
+            assert a.free_pages >= 0 and a.pages_in_use <= a.num_pages
+            for slot in rng.permutation(slots):
+                a.free_slot(int(slot))
+            assert a.pages_in_use == 0 and a.reserved == 0
+
+
 class TestPrefixSharing:
     def test_registered_prefix_is_mapped_refcounted(self):
         a = _alloc()

@@ -18,8 +18,8 @@
 - reconciliation honesty: off-TPU reports carry the platform label and
   ledger-modeled bytes, never fabricated device numbers.
 
-Wall discipline: ONE compiled paged engine (int8 weights) + ONE dense
-spec engine for the whole module, reset per test (the test_trace
+Wall discipline: ONE compiled engine with int8 weights + ONE spec
+engine (a pool for every slot) for the whole module, reset per test (the test_trace
 idiom).
 """
 
@@ -76,8 +76,8 @@ def paged_engine(params):
 
 @pytest.fixture(scope="module")
 def spec_engine():
-    """ONE dense spec engine (separate draft checkpoint — its weights
-    are a REAL second store, not an alias)."""
+    """ONE spec engine, a pool for every slot (separate draft
+    checkpoint — its weights are a REAL second store, not an alias)."""
     sparams = jax.jit(GPT2(SCFG).init)(
         jax.random.key(0), jnp.zeros((1, 8), jnp.int32)
     )["params"]
@@ -85,7 +85,7 @@ def spec_engine():
         jax.random.key(1), jnp.zeros((1, 8), jnp.int32)
     )["params"]
     return Engine(
-        SCFG, sparams, slots=2, max_len=40, prefill_len=8,
+        SCFG, sparams, slots=2, max_len=40, kv_page_size=8, prefill_len=8,
         spec_k=2, draft_params=sdparams, draft_cfg=SDCFG,
     )
 
@@ -573,14 +573,14 @@ class TestExhaustionForensics:
         server.run()
 
 
-class TestSpecAndDense:
+class TestSpecAndReset:
     def test_spec_engine_conserves_with_separate_draft_store(
         self, spec_engine
     ):
-        """Spec decode (dense engine, separate draft checkpoint): the
-        draft weights are a REAL second ledger line, the kv_pool line
-        covers target + draft caches, kv_slots grants/frees conserve
-        across accept/rollback, and retirement returns the slots."""
+        """Spec decode (separate draft checkpoint): the draft weights
+        are a REAL second ledger line, the kv_pool line covers target +
+        draft pools, kv_pages grants/frees conserve across
+        accept/rollback, and retirement returns the pages."""
         engine = spec_engine
         engine.reset()
         ml = engine.memledger
@@ -593,22 +593,28 @@ class TestSpecAndDense:
         assert len(done) == 2
         assert server.stats()["spec_accepted_tokens"] >= 0
         _assert_conserved(engine)
-        assert ml.held("kv_slots") == 0
+        pages = ml.conservation()["subsystems"]["kv_pages"]
+        assert pages["granted_bytes"] > 0 and pages["held_bytes"] == 0
 
-    def test_dense_memory_stats_block(self, spec_engine):
+    def test_memory_stats_block_of_the_default_pool(self, spec_engine):
+        """No ``kv_pages`` given: the pool holds every slot at max_len,
+        a page's bytes count the draft pool too, and a live request
+        holds the pages of its prompt + budget."""
         engine = spec_engine
         engine.reset()
+        assert engine.num_pages == 2 * (40 // 8)
         server = Server(engine)
         server.submit(_req("d1", [5, 9, 3], new=12))
         server.run(max_ticks=2)
-        assert server.live  # still decoding: the slot grant is held
+        assert server.live  # still decoding: the page grant is held
+        held = 2 * engine.page_bytes  # 15 positions in pages of 8
         mem = server.stats()["memory"]
         assert mem["source"] == "memledger"
-        assert mem["held_by_subsystem"]["kv_slots"] == engine.slot_bytes
-        assert mem["kv_capacity_bytes"] == 2 * engine.slot_bytes
-        assert mem["per_request"]["d1"]["bytes"] == engine.slot_bytes
+        assert mem["held_by_subsystem"]["kv_pages"] == held
+        assert mem["kv_capacity_bytes"] == engine.num_pages * engine.page_bytes
+        assert mem["per_request"]["d1"]["bytes"] == held
         server.run()
-        assert engine.memledger.held("kv_slots") == 0
+        assert engine.memledger.held("kv_pages") == 0
 
     def test_engine_reset_returns_every_kv_byte(self, paged_engine):
         """reset() mid-flight conserves: live slots' pages are freed

@@ -61,7 +61,7 @@ def _paged_engine(params, *, slots=2, kv_pages=16, page_size=8,
     )
 
 
-def _dense_engine(params, *, slots=2):
+def _full_pool_engine(params, *, slots=2):
     return Engine(CFG, params, slots=slots, max_len=48, prefill_len=16,
                   decode_attention="reference")
 
@@ -72,20 +72,23 @@ def _req(rid, prompt, *, new=3, priority=0, tenant="", target=0.0):
 
 
 class TestPolicyOrdering:
-    def test_tier_order_beats_submit_order(self, params):
+    @pytest.mark.parametrize(
+        "make", [_full_pool_engine, _paged_engine],
+        ids=["pool-for-every-slot", "small-pool-chunked"],
+    )
+    def test_tier_order_beats_submit_order(self, params, make):
         """Priority 0 admits before priority 1 even when submitted
-        last — on both engines."""
-        for engine in (_dense_engine(params), _paged_engine(params)):
-            pol = SchedulingPolicy(PolicyConfig(preempt=False))
-            server = Server(engine, policy=pol)
-            for i in range(4):
-                server.submit(_req(f"low{i}", [1 + i] * 4, priority=1))
-            server.submit(_req("hi", [9] * 4, priority=0))
-            server.run()
-            assert pol.admitted[0][0] == "hi", pol.admitted
+        last — whether or not pages are what admission waits for."""
+        pol = SchedulingPolicy(PolicyConfig(preempt=False))
+        server = Server(make(params), policy=pol)
+        for i in range(4):
+            server.submit(_req(f"low{i}", [1 + i] * 4, priority=1))
+        server.submit(_req("hi", [9] * 4, priority=0))
+        server.run()
+        assert pol.admitted[0][0] == "hi", pol.admitted
 
     def test_fifo_within_tier_single_tenant(self, params):
-        engine = _dense_engine(params)
+        engine = _full_pool_engine(params)
         pol = SchedulingPolicy()
         server = Server(engine, policy=pol)
         for i in range(5):
@@ -125,7 +128,7 @@ class TestFairness:
         10× tenant B's load; equal weights ⇒ while B has work queued,
         DRR serves them ~alternately, so B's requests all land in the
         earliest admissions instead of behind A's burst."""
-        engine = _dense_engine(params, slots=1)  # serialized admits
+        engine = _full_pool_engine(params, slots=1)  # serialized admits
         pol = SchedulingPolicy(PolicyConfig(quantum=1.0, preempt=False))
         server = Server(engine, policy=pol)
         for i in range(20):
@@ -144,7 +147,7 @@ class TestFairness:
     def test_weight_ratio_bounds_service_share(self, params):
         """With weight 2:1, the heavy tenant gets ~2/3 of admissions
         while both have backlog (the configured ratio, ±1 quantum)."""
-        engine = _dense_engine(params, slots=1)
+        engine = _full_pool_engine(params, slots=1)
         pol = SchedulingPolicy(PolicyConfig(
             quantum=1.0, preempt=False, tenant_weights={"A": 2.0},
         ))
@@ -195,7 +198,7 @@ class TestFairness:
 
 class TestShedCauses:
     def test_queue_full_vs_admission_distinct(self, params):
-        engine = _dense_engine(params)
+        engine = _full_pool_engine(params)
         rec = obs.Recorder()
         with obs.local_recorder(rec):
             reg = StreamRegistry()
@@ -254,7 +257,7 @@ class TestShedCauses:
     def test_admission_abstains_on_cold_windows(self, params):
         """No evidence, no shedding: a cold projector admits even a
         microscopic target."""
-        engine = _dense_engine(params)
+        engine = _full_pool_engine(params)
         pol = SchedulingPolicy(SchedulingPolicy().cfg)
         server = Server(engine, policy=pol)
         assert server.submit(_req("r", [1] * 3, target=1e-6)) is True
@@ -278,7 +281,7 @@ class TestProjector:
     def test_registry_autocreated_and_bound(self, params):
         """Server(policy=) without a stream still projects — a private
         registry is created and bound."""
-        engine = _dense_engine(params)
+        engine = _full_pool_engine(params)
         pol = SchedulingPolicy()
         server = Server(engine, policy=pol)
         assert server.stream is not None
@@ -411,20 +414,26 @@ class TestPreemption:
         live[0].preempts = 0
         assert pol2.pick_victim(live, 1) is None
 
-    def test_dense_engine_never_preempts(self, params):
-        """No pages to free on the dense engine: _try_preempt is inert
-        even with a starving interactive head."""
-        engine = _dense_engine(params, slots=1)
+    def test_slot_pressure_preempts_with_pages_to_spare(self, params):
+        """A pool that holds every slot at max_len never runs out of
+        pages, but its one slot is taken: a starving interactive head
+        still parks the batch generation, and the resumed request's
+        tokens are those of its un-preempted run."""
+        def run(policy):
+            server = Server(_full_pool_engine(params, slots=1), policy=policy)
+            server.submit(_req("long", [1] * 4, new=12, priority=1))
+            server.run(max_ticks=4)
+            server.submit(
+                _req("hi", [2] * 3, new=2, priority=0, target=1e-6))
+            return {c.rid: c.tokens for c in server.run()}
+
         pol = SchedulingPolicy(
             PolicyConfig(min_samples=1, admission=False)
         )
-        server = Server(engine, policy=pol)
-        server.submit(_req("long", [1] * 4, new=12, priority=1))
-        server.run(max_ticks=4)
-        server.submit(_req("hi", [2] * 3, new=2, priority=0, target=1e-6))
-        done = server.run()
-        assert pol.preemptions == 0
-        assert {c.rid for c in done} == {"long", "hi"}
+        done = run(pol)
+        assert pol.preemptions >= 1
+        assert set(done) == {"long", "hi"}
+        assert done == run(None)
 
 
 class TestLoadgenPolicySatellite:
@@ -484,7 +493,7 @@ class TestLoadgenPolicySatellite:
             parse_load_spec("rate=8,priority=-1")
 
     def test_negative_priority_rejected_at_submit(self, params):
-        server = Server(_dense_engine(params))
+        server = Server(_full_pool_engine(params))
         with pytest.raises(ValueError, match="priority"):
             server.submit(Request(rid=0, prompt=[1], priority=-1))
 
@@ -515,7 +524,7 @@ class TestPolicyTelemetry:
         """Per-tier TTFT series feed the registry (what a tier-scoped
         SLO reads) and per-tier queue-depth gauges read 0 once a tier
         drains."""
-        engine = _dense_engine(params)
+        engine = _full_pool_engine(params)
         reg = StreamRegistry()
         pol = SchedulingPolicy(PolicyConfig(preempt=False), reg)
         server = Server(engine, stream=reg, policy=pol)
@@ -528,7 +537,7 @@ class TestPolicyTelemetry:
         assert reg.gauge("queue_depth_tier1") == 0.0
 
     def test_tenant_rollup_in_stats(self, params):
-        engine = _dense_engine(params)
+        engine = _full_pool_engine(params)
         reg = StreamRegistry()
         server = Server(engine, stream=reg, max_queue=1)
         server.submit(_req("a", [1] * 3, tenant="t0"))

@@ -171,7 +171,7 @@ class TestVisitedTileBytesParity:
         import jax
 
         from mpit_tpu.ops.decode_attention import (
-            flash_decode_attention,
+            flash_paged_decode_attention,
             num_kv_blocks,
         )
 
@@ -179,10 +179,11 @@ class TestVisitedTileBytesParity:
         lengths = np.asarray([0, 3, 16, 33, 63], np.int32)
         key = jax.random.key(0)
         q = jax.random.normal(key, (b, 1, h, d), "float32")
-        k = jax.random.normal(key, (b, s, h, d), "float32")
-        v = jax.random.normal(key, (b, s, h, d), "float32")
-        _, visited = flash_decode_attention(
-            q, k, v, lengths, block_k=bk, interpret=True,
+        # A pool of 16-row pages, four a slot, in slot order.
+        pool = jax.random.normal(key, (b * s // bk, bk, h * d), "float32")
+        bt = np.arange(b * s // bk, dtype=np.int32).reshape(b, s // bk)
+        _, visited = flash_paged_decode_attention(
+            q, pool, pool, lengths, bt, block_k=bk, interpret=True,
             return_visited=True,
         )
         kernel_bytes = R.kv_tile_read_bytes(

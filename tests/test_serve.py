@@ -79,19 +79,21 @@ MAX_NEW = [6, 4, 8, 3, 5, 7]
 class TestKVCacheParity:
     def test_prefill_logits_match_full_forward(self, model_and_params):
         """The cache-aware forward at lengths=0 IS the plain forward:
-        same logits at every real prompt position (padded batch)."""
+        same logits at every real prompt position (padded batch), through
+        a pool of one slot's pages a slot."""
         model, params = model_and_params
-        from mpit_tpu.serve import alloc_cache
+        from mpit_tpu.serve import alloc_paged_cache
 
         prompt = [5, 9, 3, 1]
-        cache = alloc_cache(CFG, slots=2, max_len=16)
+        cache = alloc_paged_cache(CFG, slots=2, num_pages=4, page_size=8)
         padded = np.zeros((2, 8), np.int32)
         padded[0, : len(prompt)] = prompt
-        logits, (k2, v2) = model.apply(
-            {"params": params},
-            jnp.asarray(padded),
-            cache=(cache.k, cache.v, cache.lengths),
+        logits, (k2, v2), aux = CFG.serve_model().forward_paged(
+            params, jnp.asarray(padded), cache,
+            jnp.asarray([[0, 1], [2, 3]], jnp.int32),
+            jnp.ones((2, 8), bool), return_hidden=False,
         )
+        assert aux is None  # GPT-2 counts nothing a step
         full = model.apply(
             {"params": params}, jnp.asarray([prompt], jnp.int32)
         )
@@ -101,7 +103,9 @@ class TestKVCacheParity:
             rtol=1e-5,
             atol=1e-6,
         )
-        assert k2.shape == cache.k.shape and v2.shape == cache.v.shape
+        assert [b.shape for b in k2 + v2] == [
+            b.shape for b in cache.k + cache.v
+        ]
 
     def test_single_request_greedy_bitmatch(self, model_and_params):
         model, params = model_and_params
@@ -111,13 +115,23 @@ class TestKVCacheParity:
         (done,) = server.run()
         assert done.tokens == ref_greedy(model, params, [5, 9, 3], 6)
 
-    def test_staggered_continuous_batching_bitmatch(self, model_and_params):
+    @pytest.mark.parametrize(
+        "pool",
+        [dict(kv_page_size=8),
+         dict(kv_pages=24, kv_page_size=4, decode_attention="reference")],
+        ids=["pool-for-every-slot", "small-pool"],
+    )
+    def test_staggered_continuous_batching_bitmatch(
+        self, model_and_params, pool
+    ):
         """THE acceptance run: 6 requests of heterogeneous prompt/output
         lengths through 2 slots — admits ride later prefills as slots
-        retire, and every request's greedy output equals its isolated
-        no-cache run."""
+        retire, pages are recycled between requests, and every request's
+        greedy output equals its isolated no-cache run; with a page for
+        every position and with a pool that never held all six at once."""
         model, params = model_and_params
-        engine = Engine(CFG, params, slots=2, max_len=40, prefill_len=8)
+        engine = Engine(CFG, params, slots=2, max_len=40, prefill_len=8,
+                        **pool)
         server = Server(engine)
         for i, (p, n) in enumerate(zip(PROMPTS, MAX_NEW)):
             server.submit(Request(rid=i, prompt=p, max_new_tokens=n))
@@ -130,20 +144,31 @@ class TestKVCacheParity:
             assert c.tokens == ref_greedy(
                 model, params, c.prompt, len(c.tokens)
             ), f"request {c.rid} diverged from its isolated run"
+        # Retirement gave every page back.
+        assert engine.allocator.pages_in_use == 0
 
-    def test_slot_state_isolated_across_reuse(self, model_and_params):
-        """A slot's previous occupant must not leak: run the same
-        request before and after an unrelated long request churned
-        through every slot."""
+    @pytest.mark.parametrize(
+        "pool",
+        [dict(max_len=40, kv_page_size=8),
+         dict(max_len=24, kv_pages=6, kv_page_size=4,
+              decode_attention="reference")],
+        ids=["pool-for-every-slot", "small-pool"],
+    )
+    def test_slot_state_isolated_across_reuse(self, model_and_params, pool):
+        """A slot's previous occupant must not leak, nor a retired
+        request's recycled pages (handed out WITHOUT zeroing): the same
+        probe request bit-matches before and after an unrelated long
+        request churned through the slot and every page."""
         model, params = model_and_params
-        engine = Engine(CFG, params, slots=1, max_len=40, prefill_len=8)
-        probe = Request(rid="a", prompt=[9, 9], max_new_tokens=4)
+        engine = Engine(CFG, params, slots=1, prefill_len=8, **pool)
         server = Server(engine)
-        server.submit(probe)
-        server.submit(Request(rid="mid", prompt=[1, 2, 3], max_new_tokens=8))
+        server.submit(Request(rid="a", prompt=[9, 9], max_new_tokens=4))
+        server.submit(Request(rid="mid", prompt=[1, 2, 3, 4, 5, 6, 7],
+                              max_new_tokens=12))
         server.submit(Request(rid="b", prompt=[9, 9], max_new_tokens=4))
         done = {c.rid: c.tokens for c in server.run()}
         assert done["a"] == done["b"]
+        assert done["a"] == ref_greedy(model, params, [9, 9], 4)
 
 
 class TestEngineMechanics:
@@ -160,23 +185,17 @@ class TestEngineMechanics:
         assert done.tokens == full[:3]  # EOS included, then retired
 
     def test_cache_full_retires_truncated(self, model_and_params):
-        """The cache-overrun guard is defense in depth: submit()
-        validation makes it unreachable, so inject past it — a request
-        whose budget exceeds the buffer must retire at the last
+        """The cache-overrun guard is defense in depth: submit() and the
+        allocator's own validation make it unreachable, so raise a live
+        request's budget past its slot — it must retire at the last
         writable position, flagged truncated, not overrun."""
         _, params = model_and_params
-        from mpit_tpu.serve.scheduler import _Live
-
-        engine = Engine(CFG, params, slots=1, max_len=8, prefill_len=6)
+        engine = Engine(CFG, params, slots=1, max_len=8, kv_page_size=8,
+                        prefill_len=6)
         server = Server(engine)
-        import time
-
-        server.queue.append(
-            _Live(
-                Request(rid=0, prompt=[1, 2, 3, 4], max_new_tokens=10),
-                time.perf_counter(),
-            )
-        )
+        server.submit(Request(rid=0, prompt=[1, 2, 3, 4], max_new_tokens=4))
+        server.run(max_ticks=1)  # admitted, prefilled, first token out
+        server.live[0].req.max_new_tokens = 10
         (done,) = server.run()
         # prefill caches 4; each decode tick writes one more; the slot
         # retires when the NEXT write would hit max_len=8 -> 4 + 5 - 1
@@ -203,16 +222,18 @@ class TestEngineMechanics:
         self, model_and_params
     ):
         model, params = model_and_params
-        from mpit_tpu.serve import alloc_cache
+        from mpit_tpu.serve import alloc_paged_cache
 
-        cache = alloc_cache(CFG, slots=1, max_len=8)
+        cache = alloc_paged_cache(CFG, slots=1, num_pages=1, page_size=8)
         toks = jnp.zeros((1, 4), jnp.int32)
         with pytest.raises(ValueError, match="mutually exclusive"):
             model.apply(
                 {"params": params},
                 toks,
                 targets=toks,
-                cache=(cache.k, cache.v, cache.lengths),
+                paged_cache=(cache.k, cache.v, cache.lengths,
+                             jnp.zeros((1, 1), jnp.int32),
+                             jnp.ones((1, 4), bool)),
             )
 
     def test_sampling_modes_run_and_are_seeded(self, model_and_params):
@@ -298,8 +319,11 @@ class TestEngineMechanics:
             toks[0, :3], toks[1, :2] = [5, 9, 3], [7, 1]
             temp = np.zeros(2, np.float32)
             topk = np.zeros(2, np.int32)
-            engine.prefill(toks, np.array([3, 2]), np.ones(2, bool), temp,
-                           topk)
+            for slot, n in enumerate((3, 2)):
+                assert engine.allocator.admit(slot, toks[slot, :n], 8)
+            engine.prefill_paged(toks, np.zeros(2, np.int32), np.array([3, 2]),
+                                 np.zeros(2, np.int32), np.ones(2, bool),
+                                 temp, topk)
             active, out, staged = np.ones(2, bool), [], []
             for i in range(6):
                 if i == 2:
@@ -324,7 +348,8 @@ class TestServeObservability:
         _, params = model_and_params
         rec = obs.Recorder()
         with obs.local_recorder(rec):
-            engine = Engine(CFG, params, slots=2, max_len=40, prefill_len=8)
+            engine = Engine(CFG, params, slots=2, max_len=40, kv_page_size=8,
+                            prefill_len=8)
             server = Server(engine)
             for i, (p, n) in enumerate(zip(PROMPTS, MAX_NEW)):
                 server.submit(Request(rid=i, prompt=p, max_new_tokens=n))
@@ -351,7 +376,7 @@ class TestServeObservability:
 
     def test_server_stats_shape(self, model_and_params):
         _, params = model_and_params
-        engine = Engine(CFG, params, slots=2, max_len=40, prefill_len=8)
+        engine = Engine(CFG, params, slots=2, max_len=40, kv_page_size=8, prefill_len=8)
         server = Server(engine)
         for i in range(3):
             server.submit(Request(rid=i, prompt=[1 + i], max_new_tokens=3))
@@ -366,14 +391,14 @@ class TestServeObservability:
 
 
 class TestTensorParallelEngine:
-    def test_tp_engine_matches_dense_greedy(self, model_and_params):
+    def test_tp_engine_matches_plain_greedy(self, model_and_params):
         """The megatron-rules TP engine (column qkv/fc, row proj/out,
-        head-sharded cache) produces the same greedy tokens as the
+        head-sharded pool) produces the same greedy tokens as the
         isolated no-cache runs on a data=4,model=2 mesh."""
         model, params = model_and_params
         world = mpit_tpu.init({"data": 4, "model": 2}, set_default=False)
         engine = Engine(
-            CFG, params, slots=2, max_len=40, prefill_len=8,
+            CFG, params, slots=2, max_len=40, kv_page_size=8, prefill_len=8,
             world=world, tp_axis="model",
         )
         server = Server(engine)
@@ -393,40 +418,23 @@ class TestTensorParallelEngine:
             CFG, params, slots=2, max_len=16, prefill_len=8,
             world=world, tp_axis="model",
         )
-        # [L, S, T, H, Dh] with H split over the 2-way model axis.
+        # Each layer's [pages, page, H*Dh] with the packed heads split
+        # over the 2-way model axis.
+        assert len(engine.cache.k) == CFG.num_layers
         shard_shapes = {
-            s.data.shape for s in engine.cache.k.addressable_shards
+            s.data.shape
+            for buf in engine.cache.k + engine.cache.v
+            for s in buf.addressable_shards
         }
         assert shard_shapes == {
-            (CFG.num_layers, 2, 16, CFG.num_heads // 2, CFG.head_dim)
+            (engine.num_pages, 16, CFG.num_heads // 2 * CFG.head_dim)
         }
 
 
 class TestFlashDecodeServing:
-    """ISSUE 5 acceptance: the PR 4 invariants survive the hot-loop swap
-    (flash-decode kernel + blocked LM-head sampling), and the decode
-    step's shape actually changed."""
-
-    def test_staggered_bitmatch_through_kernel(self, model_and_params):
-        """THE acceptance run again, forced through the Pallas kernel
-        (interpret mode on CPU) + blocked sampling: every request's
-        greedy output still equals its isolated no-cache run."""
-        model, params = model_and_params
-        engine = Engine(
-            CFG, params, slots=2, max_len=40, prefill_len=8,
-            decode_attention="interpret",
-        )
-        assert engine.decode_attention_mode == "kernel"
-        server = Server(engine)
-        for i, (p, n) in enumerate(zip(PROMPTS, MAX_NEW)):
-            server.submit(Request(rid=i, prompt=p, max_new_tokens=n))
-        done = server.run()
-        assert len(done) == len(PROMPTS)
-        assert server.admissions == len(PROMPTS) > engine.slots
-        for c in done:
-            assert c.tokens == ref_greedy(
-                model, params, c.prompt, len(c.tokens)
-            ), f"request {c.rid} diverged through the kernel"
+    """ISSUE 5 acceptance: the hot loop (flash-decode kernel + blocked
+    LM-head sampling) and what it clamps, labels and bounds; the greedy
+    runs through the kernel and the jaxpr pin live in TestPagedServing."""
 
     def test_decode_clamps_free_slot_lengths(self, model_and_params):
         """A freed slot's stale cache length must not survive into the
@@ -436,7 +444,7 @@ class TestFlashDecodeServing:
         anyway), so a free slot costs exactly 1 tile."""
         _, params = model_and_params
         engine = Engine(
-            CFG, params, slots=2, max_len=40, prefill_len=8,
+            CFG, params, slots=2, max_len=40, kv_page_size=8, prefill_len=8,
             decode_attention="interpret",
         )
         server = Server(engine)
@@ -448,24 +456,6 @@ class TestFlashDecodeServing:
         # clamped, not left at the retired request's fill.
         assert int(np.asarray(engine.cache.lengths)[1]) <= 1
 
-    def test_tp_engine_bitmatch_through_kernel(self, model_and_params):
-        """data=4 x model=2 fake mesh, kernel on the H/P head shard."""
-        model, params = model_and_params
-        world = mpit_tpu.init({"data": 4, "model": 2}, set_default=False)
-        engine = Engine(
-            CFG, params, slots=2, max_len=40, prefill_len=8,
-            world=world, tp_axis="model", decode_attention="interpret",
-        )
-        server = Server(engine)
-        for i, (p, n) in enumerate(zip(PROMPTS[:4], MAX_NEW[:4])):
-            server.submit(Request(rid=i, prompt=p, max_new_tokens=n))
-        done = server.run()
-        assert len(done) == 4
-        for c in done:
-            assert c.tokens == ref_greedy(
-                model, params, c.prompt, len(c.tokens)
-            ), f"TP request {c.rid} diverged through the kernel"
-
     def test_kernel_mode_on_cpu_labels_reference_fallback(
         self, model_and_params
     ):
@@ -475,69 +465,25 @@ class TestFlashDecodeServing:
         engine = Engine(CFG, params, slots=1, max_len=16, prefill_len=4)
         assert engine.decode_attention == "kernel"
         assert engine.decode_attention_mode == "reference"
-        # The fallback is NOT the PR 4 engine: the blocked sampler (pure
+        # The fallback is NOT the reference engine: the blocked sampler (pure
         # XLA) stays active, and decode_sampler is the attribute that
         # distinguishes the two "reference"-attention configurations.
         assert engine.decode_sampler == "blocked"
         # The cfg the engine stores is the cfg the forward runs — the
         # kernel plug-in must be visible on it, not just traced in.
-        assert engine.cfg.cache_attention_fn is not None
+        assert engine.cfg.paged_attention_fn is not None
         eng_ref = Engine(
             CFG, params, slots=1, max_len=16, prefill_len=4,
             decode_attention="reference",
         )
         assert eng_ref.sample_k_cap is None  # dense head: no k bound
         assert eng_ref.decode_sampler == "dense"
-        assert eng_ref.cfg.cache_attention_fn is None
+        assert eng_ref.cfg.paged_attention_fn is None
         with pytest.raises(ValueError, match="decode_attention"):
             Engine(
                 CFG, params, slots=1, max_len=16, prefill_len=4,
                 decode_attention="pallas",
             )
-
-    def test_decode_step_never_materializes_slot_vocab_logits(
-        self, model_and_params
-    ):
-        """The jaxpr pin (same style as the training LM-head): with the
-        blocked head, no [slots, vocab] (or [slots, 1, vocab]) f32
-        intermediate exists anywhere in the decode step — and no dense
-        [slots, H, 1, max_len] score tensor either on the kernel path.
-        The sampler's vocab block and candidate buffer are forced below
-        the (tiny test) vocab so the pin tests the BLOCKED shape — at
-        the real 50257 vocab the defaults (8192/128) are already sub-
-        vocab."""
-        _, params = model_and_params
-        from mpit_tpu.analysis.jaxpr_check import find_avals as _avals_with_shape
-
-        slots, max_len = 2, 32
-        engine = Engine(
-            CFG, params, slots=slots, max_len=max_len, prefill_len=8,
-            decode_attention="interpret", sample_block=32, sample_k_cap=16,
-        )
-        jx = jax.make_jaxpr(engine._decode_step)(
-            engine.params, engine.cache, engine.last_token,
-            jnp.ones((slots,), bool), jax.random.key(0),
-            jnp.zeros((slots,), jnp.float32), jnp.zeros((slots,), jnp.int32),
-        )
-        for shape in (
-            (slots, CFG.vocab_size),
-            (slots, 1, CFG.vocab_size),
-            (slots, CFG.num_heads, 1, max_len),
-        ):
-            hits = _avals_with_shape(jx.jaxpr, shape)
-            assert not hits, f"decode step materializes {shape}: {hits}"
-        # The dense reference DOES materialize both — the pin means
-        # something.
-        eng_ref = Engine(
-            CFG, params, slots=slots, max_len=max_len, prefill_len=8,
-            decode_attention="reference",
-        )
-        jx_ref = jax.make_jaxpr(eng_ref._decode_step)(
-            eng_ref.params, eng_ref.cache, eng_ref.last_token,
-            jnp.ones((slots,), bool), jax.random.key(0),
-            jnp.zeros((slots,), jnp.float32), jnp.zeros((slots,), jnp.int32),
-        )
-        assert _avals_with_shape(jx_ref.jaxpr, (slots, 1, CFG.vocab_size))
 
     @pytest.mark.slow
     def test_sampling_modes_through_blocked_head(self, model_and_params):
@@ -613,11 +559,8 @@ class TestServeKernelObservability:
     @pytest.mark.parametrize(
         "how,decode,prefill",
         [
-            # Dense: a step is block_k contiguous rows; two heads padded
-            # to a float32 tile are 8 rows a query, 64 for a prefill of 8.
-            ({}, ("heads_as_rows", 8), ("heads_as_rows", 8)),
-            # Paged: a step gathers pages up to 256 rows; a chunk of 40
-            # rows is 320 rows of heads, past the bound.
+            # A step gathers pages up to 256 rows; a chunk of 40 rows is
+            # 320 rows of heads, past the bound.
             (dict(kv_pages=12, kv_page_size=8, prefill_chunk=4),
              ("heads_as_rows", 256), ("heads_as_rows", 256)),
             (dict(kv_pages=12, kv_page_size=8, prefill_len=40, max_len=48),
@@ -627,7 +570,7 @@ class TestServeKernelObservability:
             (dict(kv_pages=12, kv_page_size=8, kv_dtype="int8"),
              ("per_head", 8), ("per_head", 8)),
         ],
-        ids=["dense", "paged", "paged-long-chunk", "paged-int8"],
+        ids=["short-chunk", "long-chunk", "int8"],
     )
     def test_spans_say_which_form_of_the_kernel_ran(
         self, model_and_params, how, decode, prefill
@@ -680,17 +623,25 @@ class TestServeRoofline:
     """ISSUE 8: compile-count pinning, warmup/compile span visibility,
     cost registration and the length-aware decode-bytes feed."""
 
-    def test_engine_lifetime_compiles_pinned_at_two(self, model_and_params):
-        """The acceptance pin: the dense engine compiles exactly TWICE
-        for its lifetime (prefill + decode) — a recorded metric, and
-        further requests add zero."""
+    @pytest.mark.parametrize(
+        "pool", [{}, dict(max_len=16, kv_pages=12, kv_page_size=4)],
+        ids=["pool-for-every-slot", "small-pool"],
+    )
+    def test_engine_lifetime_compiles_pinned_at_three(
+        self, model_and_params, pool
+    ):
+        """The acceptance pin: the engine compiles exactly THREE times
+        for its lifetime (prefill chunk + decode + the page copy, all
+        paid by the warm-up) — a recorded metric, and further requests
+        add zero."""
         _, params = model_and_params
         rec = obs.Recorder()
         with obs.local_recorder(rec):
-            engine = Engine(CFG, params, slots=2, max_len=32,
-                            prefill_len=8)
+            engine = Engine(
+                CFG, params, **{**dict(slots=2, max_len=32, prefill_len=8),
+                                **pool})
             warm_engine(engine)
-            assert engine.compile_watch.compiles == 2
+            assert engine.compile_watch.compiles == 3
             server = Server(engine)
             for i in range(5):
                 server.submit(
@@ -698,21 +649,10 @@ class TestServeRoofline:
                             max_new_tokens=3)
                 )
             server.run()
-        assert engine.compile_watch.compiles == 2  # zero per-request
+        assert engine.compile_watch.compiles == 3  # zero per-request
         assert engine.compile_watch.unexpected == 0
-        assert server.stats()["engine_compiles"] == 2
-        assert rec.snapshot()["gauges"][("engine_compiles", ())] == 2.0
-
-    def test_paged_engine_compiles_pinned_at_three(self, model_and_params):
-        _, params = model_and_params
-        engine = Engine(CFG, params, slots=2, max_len=16, prefill_len=8,
-                        kv_pages=12, kv_page_size=4)
-        warm_engine(engine)  # warm pays prefill + decode + copy_page
-        assert engine.compile_watch.compiles == 3
-        server = Server(engine)
-        server.submit(Request(rid=0, prompt=[5, 9, 3], max_new_tokens=4))
-        server.run()
-        assert engine.compile_watch.compiles == 3
+        assert server.stats()["engine_compiles"] == 3
+        assert rec.snapshot()["gauges"][("engine_compiles", ())] == 3.0
 
     def test_forced_recompile_trips_sentinel_anomaly(
         self, model_and_params
@@ -728,17 +668,17 @@ class TestServeRoofline:
             warm_engine(engine)
             sent = obs.Sentinel(phases=("decode", "prefill"), warmup=2)
             server = Server(engine, sentinel=sent)
-            engine._decode_jit.clear_cache()  # the injection
+            engine._decode_paged_jit.clear_cache()  # the injection
             server.submit(Request(rid=0, prompt=[5, 9], max_new_tokens=3))
             server.run()
-        assert engine.compile_watch.compiles == 3
+        assert engine.compile_watch.compiles == 4
         assert engine.compile_watch.unexpected == 1
         rep = sent.report()
         assert not rep["clean"]
         assert rep["anomaly_counts"]["unexpected_recompile"] == 1
         (a,) = [x for x in rep["anomalies"]
                 if x["kind"] == "unexpected_recompile"]
-        assert a["metric"] == "decode" and a["expected"] == 2
+        assert a["metric"] == "decode" and a["expected"] == 3
 
     def test_warm_engine_emits_warmup_and_compile_spans(
         self, model_and_params
@@ -754,8 +694,8 @@ class TestServeRoofline:
             warm_engine(engine)
             summ = rec.summary()
         assert summ["phases"]["warmup"]["count"] == 1
-        assert summ["phases"]["compile"]["count"] == 2
-        assert summ["counters"]["compiles"] == 2.0
+        assert summ["phases"]["compile"]["count"] == 3
+        assert summ["counters"]["compiles"] == 3.0
         # The compile spans sit INSIDE the warmup wall (overlay rule).
         assert (
             summ["phases"]["compile"]["total_s"]
@@ -819,7 +759,7 @@ class TestServeRoofline:
 
 class TestPagedServing:
     """ISSUE 7 acceptance: greedy decode through the PAGED cache path
-    bit-matches the dense reference engine — staggered multi-request
+    bit-matches the no-cache forward — staggered multi-request
     runs (slot AND page reuse), the interpret-mode paged kernel, the TP
     variant, chunked prefill, prefix sharing and COW divergence all
     preserve the PR 4 invariant; the allocator's capacity gates surface
@@ -833,28 +773,6 @@ class TestPagedServing:
         kw.setdefault("kv_page_size", 4)
         kw.setdefault("decode_attention", "reference")
         return Engine(CFG, params, **kw)
-
-    def test_staggered_bitmatch_through_paged_reference(
-        self, model_and_params
-    ):
-        """THE acceptance run on the paged pool: admits/retirements
-        interleaved, pages recycled between requests, every greedy
-        output equals its isolated no-cache run."""
-        model, params = model_and_params
-        engine = self._paged_engine(params)
-        server = Server(engine)
-        for i, (p, n) in enumerate(zip(PROMPTS, MAX_NEW)):
-            server.submit(Request(rid=i, prompt=p, max_new_tokens=n))
-        done = server.run()
-        assert len(done) == len(PROMPTS)
-        assert server.admissions == len(PROMPTS) > engine.slots
-        for c in done:
-            assert c.tokens == ref_greedy(
-                model, params, c.prompt, len(c.tokens)
-            ), f"paged request {c.rid} diverged from its isolated run"
-        # Pages actually cycled: the pool never held all six requests
-        # at once, so retirement freed pages that later admits reused.
-        assert engine.allocator.pages_in_use == 0
 
     def test_staggered_bitmatch_through_paged_kernel(
         self, model_and_params
@@ -997,24 +915,6 @@ class TestPagedServing:
         assert done["a"].tokens == want
         assert done["b"].tokens == want[:5]
 
-    def test_freed_page_reuse_isolation(self, model_and_params):
-        """A retired request's recycled pages (handed out WITHOUT
-        zeroing) must not leak into a new occupant: the same probe
-        request bit-matches before and after unrelated churn through
-        every page."""
-        model, params = model_and_params
-        engine = self._paged_engine(
-            params, slots=1, kv_pages=6, kv_page_size=4, max_len=24
-        )
-        server = Server(engine)
-        server.submit(Request(rid="a", prompt=[9, 9], max_new_tokens=4))
-        server.submit(Request(rid="mid", prompt=[1, 2, 3, 4, 5, 6, 7],
-                              max_new_tokens=12))
-        server.submit(Request(rid="b", prompt=[9, 9], max_new_tokens=4))
-        done = {c.rid: c.tokens for c in server.run()}
-        assert done["a"] == done["b"]
-        assert done["a"] == ref_greedy(model, params, [9, 9], 4)
-
     def test_pool_exhaustion_queues_then_completes(self, model_and_params):
         """More slots than pages can serve at once: admission stops at
         the pool (all-or-nothing), the overflow request WAITS (not an
@@ -1057,15 +957,15 @@ class TestPagedServing:
         with pytest.raises(ValueError, match="kv_page_size"):
             Engine(CFG, params, slots=1, max_len=40, prefill_len=8,
                    kv_pages=8, kv_page_size=7)
+        with pytest.raises(ValueError, match="kv_page_size"):
+            # The default page of 16 positions does not divide 40 either.
+            Engine(CFG, params, slots=1, max_len=40, prefill_len=8)
+        with pytest.raises(ValueError, match="kv_pages"):
+            Engine(CFG, params, slots=1, max_len=40, kv_page_size=8,
+                   prefill_len=8, kv_pages=0)
         with pytest.raises(ValueError, match="prefill_chunk"):
-            Engine(CFG, params, slots=1, max_len=40, prefill_len=8,
-                   prefill_chunk=4)  # chunking is a paged-engine knob
-        with pytest.raises(ValueError, match="prefill_paged"):
-            self._paged_engine(params).prefill(
-                np.zeros((2, 8), np.int32), np.ones((2,), np.int32),
-                np.ones((2,), bool), np.zeros((2,), np.float32),
-                np.zeros((2,), np.int32),
-            )
+            Engine(CFG, params, slots=1, max_len=40, kv_page_size=8,
+                   prefill_len=8, prefill_chunk=0)
 
     def test_paged_decode_step_never_materializes_logits(
         self, model_and_params
@@ -1078,7 +978,8 @@ class TestPagedServing:
 
         slots = 2
         # sample_block/k_cap forced below the tiny test vocab so the
-        # pin tests the BLOCKED shape (as in the dense-step pin).
+        # pin tests the BLOCKED shape — at the real 50257 vocab the
+        # defaults (8192/128) are already sub-vocab.
         eng2 = Engine(
             CFG, params, slots=slots, max_len=40, prefill_len=8,
             kv_pages=24, kv_page_size=8, decode_attention="interpret",
@@ -1097,6 +998,18 @@ class TestPagedServing:
         ):
             hits = _avals_with_shape(jx.jaxpr, shape)
             assert not hits, f"paged decode step materializes {shape}"
+        # The reference engine DOES materialize the logits — the pin
+        # means something.
+        eng_ref = Engine(
+            CFG, params, slots=slots, max_len=40, prefill_len=8,
+            kv_pages=24, kv_page_size=8, decode_attention="reference",
+        )
+        jx_ref = jax.make_jaxpr(eng_ref._paged_decode_step)(
+            eng_ref.params, eng_ref.cache, eng_ref.last_token,
+            jnp.ones((slots,), bool), bt, jax.random.key(0),
+            jnp.zeros((slots,), jnp.float32), jnp.zeros((slots,), jnp.int32),
+        )
+        assert _avals_with_shape(jx_ref.jaxpr, (slots, 1, CFG.vocab_size))
 
     def test_kv_gauges_and_stats(self, model_and_params):
         """ISSUE 7 satellite: kv_tokens_cached / kv_pool_occupancy /
@@ -1128,14 +1041,6 @@ class TestPagedServing:
         assert stats["prefix_pages_shared_peak"] >= 1
         assert stats["kv_cow_copies"] >= 1
         assert stats["concurrency_peak"] == 2
-        # The dense engine reports the shared gauges but no pool block.
-        engine_d = Engine(CFG, params, slots=2, max_len=40, prefill_len=8)
-        server_d = Server(engine_d)
-        server_d.submit(Request(rid=0, prompt=[5], max_new_tokens=2))
-        server_d.run()
-        sd = server_d.stats()
-        assert "kv_page_size" not in sd
-        assert sd["concurrency_peak"] == 1
 
     @pytest.mark.slow
     def test_cli_paged_smoke(self):
@@ -1153,6 +1058,127 @@ class TestPagedServing:
         assert out["kv_page_size"] == 8
         assert out["kv_pool_pages"] == 16
         assert out["decode_tokens_per_sec"] > 0
+
+
+class TestOneEngine:
+    """ISSUE 28: ``Engine`` is the paged engine and nothing selects it;
+    ``kv_pages`` is a capacity and nothing else."""
+
+    def test_no_kv_pages_is_a_pool_for_every_slot(self, model_and_params):
+        """``Engine(cfg, params, slots=s, max_len=m)`` builds a pool of
+        ``s * m / page`` pages, and a server over it admits ``s``
+        requests of ``m`` positions each without shedding or queueing
+        one for pages."""
+        model, params = model_and_params
+        s, m = 3, 32
+        engine = Engine(CFG, params, slots=s, max_len=m)
+        assert engine.page_size == 16 and engine.num_pages == s * m // 16
+        assert engine.allocator.num_pages == engine.num_pages
+        assert engine.cache.k[0].shape[:2] == (s * m // 16, 16)
+        assert engine.prefill_chunk == engine.prefill_len == m
+        rec = obs.Recorder()
+        with obs.local_recorder(rec):
+            server = Server(engine)
+            for i in range(s):
+                assert server.submit(Request(
+                    rid=i, prompt=[1 + i] * 20, max_new_tokens=m - 20))
+            server.run(max_ticks=1)
+            # All of them hold their slot and every page they can reach.
+            assert len(server.live) == s and not server.queue
+            assert engine.allocator.free_pages == 0
+            done = server.run()
+        assert not server.shed and len(done) == s
+        assert not any(e[1] == "kv_pool_exhausted"
+                       for e in rec.snapshot()["events"])
+        for c in done:
+            assert len(c.tokens) == m - 20 and not c.truncated
+            assert c.tokens == ref_greedy(model, params, c.prompt, m - 20)
+
+    def test_cli_without_kv_pages_serves_that_pool(self):
+        """The serve CLI with no ``--kv-pages``: the pool holds every
+        slot at ``--max-len``, and what it serves greedily is the
+        no-cache forward's tokens."""
+        from mpit_tpu.asyncsgd.config import from_argv
+        from mpit_tpu.serve import __main__ as cli
+
+        argv = ["--requests", "3", "--slots", "2", "--max-len", "48",
+                "--prefill-len", "8", "--max-new-tokens", "4"]
+        out = cli.main(argv)
+        assert out["kv_page_size"] == 16
+        assert out["kv_pool_pages"] == 2 * 48 // 16
+        assert out["requests_completed"] == 3
+        scfg = from_argv(cli.ServeConfig, argv)
+        assert scfg.kv_pages == 0
+        engine, mcfg = cli._build_engine(scfg)
+        assert engine.num_pages == out["kv_pool_pages"]
+        server = Server(engine)
+        for r in cli.synthetic_requests(scfg, mcfg.vocab_size):
+            server.submit(r)
+        model = GPT2(mcfg)
+        for c in server.run():
+            assert c.tokens == ref_greedy(
+                model, engine.params, c.prompt, len(c.tokens))
+
+    @pytest.mark.parametrize("family", ["gpt2", "xing4"])
+    def test_family_conforms_to_the_model_interface(self, family):
+        """What the engine asks of a family, asked of each: the cache
+        row layout times the pool's dtype is ``Engine.page_bytes``,
+        ``forward_paged`` hands back buffers of the layout's shapes, and
+        a mode the family lacks raises from ``check_supported`` by the
+        mode's name."""
+        from mpit_tpu.serve import alloc_paged_cache
+
+        if family == "gpt2":
+            cfg = CFG
+            params = GPT2(CFG).init(
+                jax.random.key(0), jnp.zeros((1, 8), jnp.int32))["params"]
+            lacks = {}
+        else:
+            from mpit_tpu.models.xing4 import Xing4Config, init_params
+
+            cfg = Xing4Config.tiny(max_seq_len=64, dtype=jnp.float32)
+            params = init_params(cfg, jax.random.key(0))
+            lacks = {
+                "tp": (True, "tensor parallelism"),
+                "kv_dtype": ("int8", "int8 cache"),
+                "weights_dtype": ("int8", "int8 weights"),
+                "spec_k": (2, "speculative"),
+                "host_pages": (2, "host KV tier"),
+            }
+        model = cfg.serve_model()
+        assert model.family == family
+        modes = dict(tp=False, kv_dtype=None, weights_dtype=None, spec_k=0,
+                     host_pages=0)
+        model.check_supported(**modes)  # what every family has
+        for mode, (value, what) in lacks.items():
+            with pytest.raises(ValueError, match=what):
+                model.check_supported(**{**modes, mode: value})
+        lay = model.cache_layout()
+        slots, pages, ps = 2, 8, 16
+        engine = Engine(cfg, params, slots=slots, max_len=64, kv_pages=pages,
+                        kv_page_size=ps)
+        itemsize = jnp.dtype(lay.dtype).itemsize
+        assert engine.page_bytes == (
+            ps * (lay.k_width + lay.v_width) * itemsize * lay.num_layers)
+        assert model.kv_row_bytes(lay.dtype) == (
+            (lay.k_width + lay.v_width) / 2 * itemsize)
+        cache = alloc_paged_cache(cfg, slots, pages, ps)
+        want = lambda w: [(pages, ps, w)] * lay.num_layers
+        assert [b.shape for b in cache.k] == want(lay.k_width)
+        assert [b.shape for b in cache.v] == want(lay.v_width)
+        t = 4
+        out, (k2, v2), aux = model.forward_paged(
+            params, jnp.ones((slots, t), jnp.int32), cache,
+            jnp.arange(slots * 4, dtype=jnp.int32).reshape(slots, 4),
+            jnp.ones((slots, t), bool), return_hidden=True,
+            row_valid=jnp.ones((slots, t), bool),
+        )
+        assert out.shape[:2] == (slots, t)
+        assert model.head_table(params).shape[1] == out.shape[-1]
+        assert [b.shape for b in k2] == want(lay.k_width)
+        assert [b.shape for b in v2] == want(lay.v_width)
+        assert all(b.dtype == lay.dtype for b in (*k2, *v2))
+        assert (aux is None) == (family == "gpt2")
 
 
 class TestServeCLI:
@@ -1440,7 +1466,7 @@ def loadgen_default_mix():
 
 
 def _warmed_engine(params, *, slots=2):
-    engine = Engine(CFG, params, slots=slots, max_len=40, prefill_len=8)
+    engine = Engine(CFG, params, slots=slots, max_len=40, kv_page_size=8, prefill_len=8)
     warm_engine(engine)
     return engine
 
@@ -1497,7 +1523,7 @@ class TestRunTimed:
         _, params = model_and_params
         rec = obs.Recorder()
         with obs.local_recorder(rec):
-            engine = Engine(CFG, params, slots=2, max_len=40,
+            engine = Engine(CFG, params, slots=2, max_len=40, kv_page_size=8,
                             prefill_len=8)
             reg = StreamRegistry()
             server = Server(engine, stream=reg, max_queue=2)
@@ -1531,7 +1557,7 @@ class TestRunTimed:
         _, params = model_and_params
         rec = obs.Recorder()
         with obs.local_recorder(rec):
-            engine = Engine(CFG, params, slots=2, max_len=40,
+            engine = Engine(CFG, params, slots=2, max_len=40, kv_page_size=8,
                             prefill_len=8)
             server = Server(engine)
             server.submit(Request(rid=42, prompt=[5, 9], max_new_tokens=3,
@@ -1556,7 +1582,7 @@ class TestRunTimed:
         """ISSUE 6 satellite: a run() that hit the tick cap must not be
         indistinguishable from a finished run."""
         _, params = model_and_params
-        engine = Engine(CFG, params, slots=2, max_len=40, prefill_len=8)
+        engine = Engine(CFG, params, slots=2, max_len=40, kv_page_size=8, prefill_len=8)
         server = Server(engine)
         for i in range(4):
             server.submit(Request(rid=i, prompt=[1 + i], max_new_tokens=8))
@@ -1572,7 +1598,7 @@ class TestRunTimed:
 
     def test_slo_requires_stream(self, model_and_params):
         _, params = model_and_params
-        engine = Engine(CFG, params, slots=2, max_len=40, prefill_len=8)
+        engine = Engine(CFG, params, slots=2, max_len=40, kv_page_size=8, prefill_len=8)
         reg = StreamRegistry()
         mon = SLOMonitor([SLO.ttft_p95(1.0)], reg)
         with pytest.raises(ValueError, match="stream"):

@@ -4,8 +4,8 @@ The pinned invariants:
 
 - **Greedy parity** — per-request speculative greedy output bit-matches
   the NON-speculative engine (itself oracle-pinned against the no-cache
-  forward in ``tests/test_serve.py``) on the dense, paged, chunked,
-  and TP engines, with a random draft (correctness must not depend on
+  forward in ``tests/test_serve.py``) over a pool for every slot and a
+  small one, with chunked prefill and over the TP engine, with a random draft (correctness must not depend on
   what the draft proposes);
 - **Rollback edges (paged)** — reject across a page boundary (the fill
   watermark retreats over a page), reject into a COW-shared page, and
@@ -79,21 +79,32 @@ def baseline(params):
     """The non-speculative reference outputs (oracle-pinned in
     tests/test_serve.py) every parity test below compares against."""
     out, _ = _run_stream(
-        Engine(CFG, params, slots=2, max_len=40, prefill_len=8)
+        Engine(CFG, params, slots=2, max_len=40, kv_page_size=8, prefill_len=8)
     )
     return out
 
 
 class TestSpecGreedyParity:
-    def test_dense_staggered_bitmatch(self, params, dparams, baseline):
+    @pytest.mark.parametrize(
+        "pool",
+        [
+            {},
+            # tier-1 wall guard (round 18): the second soak rides slow
+            pytest.param(dict(kv_pages=16), marks=pytest.mark.slow),
+        ],
+        ids=["pool-for-every-slot", "small-pool"],
+    )
+    def test_staggered_bitmatch(self, params, dparams, baseline, pool):
         """THE tentpole pin: 6 heterogeneous greedy requests through 2
         slots with draft-then-verify — admits, retirements and slot
-        reuse interleaved with speculation — equal the plain engine's
-        outputs per request, with a RANDOM draft (parity cannot depend
-        on the draft's quality, only throughput can)."""
+        reuse interleaved with speculation, identical leading prompts
+        mapping shared pages (draft pool included) — equal the plain
+        engine's outputs per request, with a RANDOM draft (parity cannot
+        depend on the draft's quality, only throughput can); with a
+        page for every position and with a pool that recycles pages."""
         out, server = _run_stream(
-            Engine(CFG, params, slots=2, max_len=40, prefill_len=8,
-                   **_spec_kw(dparams))
+            Engine(CFG, params, slots=2, max_len=40, kv_page_size=8, prefill_len=8,
+                   **pool, **_spec_kw(dparams))
         )
         assert out == baseline
         st = server.stats()
@@ -102,7 +113,7 @@ class TestSpecGreedyParity:
 
     def test_reference_engine_spec_bitmatch(self, params, dparams, baseline):
         out, _ = _run_stream(
-            Engine(CFG, params, slots=2, max_len=40, prefill_len=8,
+            Engine(CFG, params, slots=2, max_len=40, kv_page_size=8, prefill_len=8,
                    decode_attention="reference", **_spec_kw(dparams))
         )
         assert out == baseline
@@ -131,19 +142,6 @@ class TestSpecGreedyParity:
         )
         assert out == ref
 
-    @pytest.mark.slow  # tier-1 wall guard (round 18): parity soak
-    def test_paged_spec_bitmatch_with_prefix_sharing(
-        self, params, dparams, baseline
-    ):
-        """Paged engine + speculation + COW prefix sharing: identical
-        leading prompts map shared pages (draft pool included); greedy
-        outputs still bit-match the dense non-speculative engine."""
-        out, server = _run_stream(
-            Engine(CFG, params, slots=2, max_len=40, prefill_len=8,
-                   kv_pages=16, kv_page_size=8, **_spec_kw(dparams))
-        )
-        assert out == baseline
-
     def test_paged_chunked_spec_bitmatch(self, params, dparams, baseline):
         out, _ = _run_stream(
             Engine(CFG, params, slots=2, max_len=40, prefill_len=8,
@@ -162,8 +160,8 @@ class TestSpecGreedyParity:
         cannot catch a corrupted draft context (verify corrects the
         output regardless); sustained acceptance can: a missing K/V row
         after a fully-accepted tick poisons the draft's window and
-        collapses acceptance from 1.0 (caught here, dense AND paged)."""
-        for kw in ({}, {"kv_pages": 16, "kv_page_size": 8}):
+        collapses acceptance from 1.0 (caught here, over both pools)."""
+        for kw in ({"kv_page_size": 8}, {"kv_pages": 16, "kv_page_size": 8}):
             eng = Engine(CFG, params, slots=2, max_len=40, prefill_len=8,
                          spec_k=3, draft_params=params, draft_cfg=CFG,
                          **kw)
@@ -179,7 +177,7 @@ class TestSpecGreedyParity:
         """Parity is k-independent (a different k only changes how much
         is drafted per tick, never what is emitted)."""
         out, _ = _run_stream(
-            Engine(CFG, params, slots=2, max_len=40, prefill_len=8,
+            Engine(CFG, params, slots=2, max_len=40, kv_page_size=8, prefill_len=8,
                    **_spec_kw(dparams, k=3))
         )
         assert out == baseline
@@ -188,11 +186,11 @@ class TestSpecGreedyParity:
 @pytest.mark.slow
 class TestSpecTPParity:
     """TP engines carry the same pin — heavier (mesh compiles), so the
-    e2e rides the slow tier; the dense/paged pins above stay tier-1."""
+    e2e rides the slow tier; the single-device pins above stay tier-1."""
 
     def test_tp_spec_bitmatch(self, params, dparams, baseline, world_2d):
         out, server = _run_stream(
-            Engine(CFG, params, slots=2, max_len=40, prefill_len=8,
+            Engine(CFG, params, slots=2, max_len=40, kv_page_size=8, prefill_len=8,
                    world=world_2d, tp_axis="model", **_spec_kw(dparams))
         )
         assert out == baseline
@@ -483,7 +481,7 @@ class TestExactSampling:
         """Temperature/top-k speculation end to end: token counts,
         device-vs-host fill mirror, and retirement all stay coherent
         (no parity claim — sampling is stochastic by design)."""
-        eng = Engine(CFG, params, slots=2, max_len=40, prefill_len=8,
+        eng = Engine(CFG, params, slots=2, max_len=40, kv_page_size=8, prefill_len=8,
                      **_spec_kw(dparams))
         server = Server(eng)
         server.submit(Request(rid=0, prompt=[5, 9, 3], max_new_tokens=6,
@@ -505,7 +503,8 @@ class TestSpecObsAndStats:
         rec = obs.Recorder()
         registry = StreamRegistry()
         with obs.local_recorder(rec):
-            eng = Engine(CFG, params, slots=2, max_len=40, prefill_len=8,
+            eng = Engine(CFG, params, slots=2, max_len=40, kv_page_size=8,
+                         prefill_len=8,
                          **_spec_kw(dparams))
             server = Server(eng, stream=registry)
             for i, (p, n) in enumerate(zip(PROMPTS[:3], MAX_NEW[:3])):
@@ -535,7 +534,7 @@ class TestSpecObsAndStats:
             assert k in st
 
     def test_compile_pins(self, params, dparams):
-        eng = Engine(CFG, params, slots=2, max_len=40, prefill_len=8,
+        eng = Engine(CFG, params, slots=2, max_len=40, kv_page_size=8, prefill_len=8,
                      **_spec_kw(dparams))
         _, server = _run_stream(eng)
         assert server.stats()["engine_compiles"] == 3
@@ -547,7 +546,7 @@ class TestSpecObsAndStats:
         assert peng.compile_watch.unexpected == 0
 
     def test_roofline_registers_spec_steps(self, params, dparams):
-        eng = Engine(CFG, params, slots=2, max_len=40, prefill_len=8,
+        eng = Engine(CFG, params, slots=2, max_len=40, kv_page_size=8, prefill_len=8,
                      **_spec_kw(dparams))
         costs = eng.register_roofline()
         assert set(costs) == {"prefill", "spec_draft", "spec_verify"}
@@ -556,11 +555,11 @@ class TestSpecObsAndStats:
 class TestSpecValidation:
     def test_spec_k_requires_draft(self, params):
         with pytest.raises(ValueError, match="draft_params and draft_cfg"):
-            Engine(CFG, params, slots=2, max_len=40, spec_k=2)
+            Engine(CFG, params, slots=2, max_len=40, kv_page_size=8, spec_k=2)
 
     def test_draft_without_spec_k(self, params, dparams):
         with pytest.raises(ValueError, match="without spec_k"):
-            Engine(CFG, params, slots=2, max_len=40,
+            Engine(CFG, params, slots=2, max_len=40, kv_page_size=8,
                    draft_params=dparams, draft_cfg=DCFG)
 
     def test_draft_vocab_mismatch(self, params):
@@ -572,7 +571,7 @@ class TestSpecValidation:
             jax.random.key(2), jnp.zeros((1, 8), jnp.int32)
         )["params"]
         with pytest.raises(ValueError, match="vocab"):
-            Engine(CFG, params, slots=2, max_len=40, spec_k=2,
+            Engine(CFG, params, slots=2, max_len=40, kv_page_size=8, spec_k=2,
                    draft_params=bad, draft_cfg=bad_cfg)
 
     def test_draft_positions_must_cover_max_len(self, params, dparams):
@@ -580,36 +579,32 @@ class TestSpecValidation:
 
         short = dataclasses.replace(DCFG, max_seq_len=16)
         with pytest.raises(ValueError, match="max_seq_len"):
-            Engine(CFG, params, slots=2, max_len=40, spec_k=2,
+            Engine(CFG, params, slots=2, max_len=40, kv_page_size=8, spec_k=2,
                    draft_params=dparams, draft_cfg=short)
 
-    def test_dense_submit_rejects_missing_headroom(self, params, dparams):
-        """The satellite's poster case: a request whose verify would
-        clamp-write past the dense buffer raises a PRECISE error at
-        submit, never corruption inside the jitted step."""
+    @pytest.mark.parametrize(
+        "pool", [{}, dict(kv_pages=16, kv_page_size=4)],
+        ids=["pool-for-every-slot", "small-pages"],
+    )
+    def test_submit_needs_no_headroom(self, params, dparams, pool):
+        """The verify writes k+1 rows at the fill; those past the slot's
+        mapped pages are scatter-DROPPED — prompt + max_new == max_len
+        stays admissible, runs to its budget and emits what the plain
+        engine emits."""
+        req = lambda: Request(rid=0, prompt=[1] * 8, max_new_tokens=8)
         eng = Engine(CFG, params, slots=2, max_len=16, prefill_len=8,
-                     **_spec_kw(dparams, k=3))
+                     **pool, **_spec_kw(dparams, k=3))
         server = Server(eng)
-        with pytest.raises(ValueError, match="spec_k"):
-            server.submit(Request(rid=0, prompt=[1] * 8,
-                                  max_new_tokens=8))
-        # The same request FITS without speculation headroom pressure.
-        ok = Request(rid=1, prompt=[1] * 6, max_new_tokens=8)
-        assert server.submit(ok)
-
-    def test_paged_submit_needs_no_headroom(self, params, dparams):
-        """Out-of-range draft rows are scatter-DROPPED on the paged
-        engine — prompt + max_new == max_len stays admissible."""
-        eng = Engine(CFG, params, slots=2, max_len=16, prefill_len=8,
-                     kv_pages=16, kv_page_size=4, **_spec_kw(dparams, k=3))
-        server = Server(eng)
-        assert server.submit(Request(rid=0, prompt=[1] * 8,
-                                     max_new_tokens=8))
+        assert server.submit(req())
         (done,) = server.run()
         assert len(done.tokens) == 8
+        plain = Server(Engine(CFG, params, slots=2, max_len=16,
+                              prefill_len=8, **pool))
+        plain.submit(req())
+        assert done.tokens == plain.run()[0].tokens
 
     def test_decode_raises_on_spec_engine(self, params, dparams):
-        eng = Engine(CFG, params, slots=2, max_len=40, prefill_len=8,
+        eng = Engine(CFG, params, slots=2, max_len=40, kv_page_size=8, prefill_len=8,
                      **_spec_kw(dparams))
         with pytest.raises(ValueError, match="spec_draft"):
             eng.decode(np.zeros(2, bool), np.zeros(2), np.zeros(2, np.int32))

@@ -219,37 +219,37 @@ class TestRestreamResumeBitmatch:
         assert by_rid["b"] == ref["b"]
 
 
-class TestDenseSpillRestream:
-    def test_dense_export_evict_inject_resume_bitmatch(self, params):
-        """The dense cache's spill unit is the whole slot: export the
-        rows host-side mid-generation, evict (reset), inject, keep
-        decoding — the continuation bit-matches the uninterrupted
-        run. (This is the fleet shipment path doing tier duty; the
-        paged engine's page-granular tier builds on the same
-        gather-to-host discipline.)"""
+class TestWholeSlotSpillRestream:
+    def test_export_evict_inject_resume_bitmatch(self, params):
+        """The whole slot as the spill unit: export the rows host-side
+        mid-generation, evict (reset), map fresh pages, inject, keep
+        decoding — the continuation bit-matches the uninterrupted run.
+        (This is the fleet shipment path doing tier duty; the
+        page-granular tier builds on the same gather-to-host
+        discipline.)"""
         eng = Engine(CFG, params, slots=2, max_len=64, prefill_len=32,
                      decode_attention="reference")
         rng = np.random.RandomState(19)
         prompt = rng.randint(0, CFG.vocab_size, size=12).tolist()
         S = eng.slots
+        greedy_t = np.zeros((S,), np.float32)
+        full_k = np.zeros((S,), np.int32)
 
         def prefill(prompt):
-            toks = np.zeros((S, eng.prefill_len), np.int32)
+            assert eng.allocator.admit(0, prompt, 8) is not None
+            toks = np.zeros((S, eng.prefill_chunk), np.int32)
             toks[0, : len(prompt)] = prompt
-            lens = np.ones((S,), np.int32)
+            lens = np.zeros((S,), np.int32)
             lens[0] = len(prompt)
-            admit = np.zeros((S,), bool)
-            admit[0] = True
-            greedy_t = np.zeros((S,), np.float32)
-            full_k = np.zeros((S,), np.int32)
-            return int(eng.prefill(toks, lens, admit, greedy_t,
-                                   full_k)[0])
+            first = np.zeros((S,), bool)
+            first[0] = True
+            zeros = np.zeros((S,), np.int32)
+            return int(eng.prefill_paged(toks, zeros, lens, zeros, first,
+                                         greedy_t, full_k)[0])
 
         def decode_n(n):
             active = np.zeros((S,), bool)
             active[0] = True
-            greedy_t = np.zeros((S,), np.float32)
-            full_k = np.zeros((S,), np.int32)
             return [int(eng.decode(active, greedy_t, full_k)[0])
                     for _ in range(n)]
 
@@ -263,7 +263,10 @@ class TestDenseSpillRestream:
         head = [first2] + decode_n(3)
         fill = len(prompt) + 3  # prompt rows + one per decoded tick
         k_rows, v_rows = eng.export_kv_rows(0, fill)
-        eng.reset()  # the eviction: cache gone, lengths zeroed
+        eng.reset()  # the eviction: pages freed, lengths zeroed
+        # Slot 1 takes the first pages now: the rows land elsewhere.
+        assert eng.allocator.admit(1, [1, 2, 3], 4) is not None
+        assert eng.allocator.admit(0, prompt, 8) is not None
         eng.inject_kv_rows(0, k_rows, v_rows, fill, head[-1])
         tail = decode_n(3)
         assert head + tail == ref

@@ -388,7 +388,7 @@ class TestServerLedger:
         spec_tick events carrying drafted/accepted/emitted counts and
         the attribution still reconciles."""
         engine = Engine(
-            SCFG, sparams, slots=2, max_len=40, prefill_len=8,
+            SCFG, sparams, slots=2, max_len=40, kv_page_size=8, prefill_len=8,
             spec_k=2, draft_params=sdparams, draft_cfg=SDCFG,
         )
         led = Ledger(mode="full", exemplar_k=8)

@@ -30,10 +30,21 @@ CFG = GPT2Config.tiny(
     dtype=jnp.float32,
 )
 ENGINES = {
-    "dense": dict(slots=2, max_len=32, prefill_len=8),
-    "paged": dict(slots=2, max_len=32, kv_pages=8, kv_page_size=8,
-                  prefill_chunk=4),
+    # A pool for every slot, prompts whole in one chunk; a small pool of
+    # small pages, prompts in chunks of 4.
+    "whole-prompt": dict(slots=2, max_len=32, prefill_len=8),
+    "chunked": dict(slots=2, max_len=32, kv_pages=8, kv_page_size=8,
+                    prefill_chunk=4),
 }
+
+
+@pytest.fixture(autouse=True)
+def _obs_disabled_by_default():
+    # A recorder another module's test left on in this worker would make
+    # every span here a real one (the idiom of test_obs / test_roofline).
+    obs.disable()
+    yield
+    obs.disable()
 
 
 @pytest.fixture(scope="module")
@@ -72,17 +83,9 @@ def test_every_tick_is_one_span_tree(params, kind):
                       key=lambda s: s[1])
         names = [s[0] for s in kids]
         assert names.count("admit") == names.count("gauges") == 1
-        if kind == "paged":
-            # Siblings, one after another, in the order they run.
-            assert names == [p for p in phases if p in names]
-            assert all(a[2] <= b[1] for a, b in zip(kids, kids[1:]))
-        else:
-            # A dense engine prefills whole prompts inside its admission.
-            rest = [s for s in kids if s[0] != "prefill"]
-            assert [s[0] for s in rest] == [
-                p for p in phases if p in names and p != "prefill"]
-            admit = next(s for s in kids if s[0] == "admit")
-            assert all(_inside(s, admit) for s in kids if s[0] == "prefill")
+        # Siblings, one after another, in the order they run.
+        assert names == [p for p in phases if p in names]
+        assert all(a[2] <= b[1] for a, b in zip(kids, kids[1:]))
         for outer in ("decode", "prefill"):
             for span in (s for s in kids if s[0] == outer):
                 inner = sorted(
@@ -123,17 +126,19 @@ def test_disabled_tick_computes_no_span_argument(params, kind, monkeypatch):
     assert all(not attrs for name, attrs, _ in asked if name != "tick")
 
 
-def _lowered_decode(params, kind):
-    eng = Engine(CFG, params, **ENGINES[kind], decode_attention="interpret",
-                 sample_block=32, sample_k_cap=16)
-    active = jnp.ones((eng.slots,), bool)
-    args = [eng.params, eng.cache, eng.last_token, active]
-    if kind == "paged":
-        args.append(jnp.asarray(eng.allocator.block_tables, jnp.int32))
-    args += [jax.random.key(0), jnp.zeros((eng.slots,), jnp.float32),
-             jnp.zeros((eng.slots,), jnp.int32)]
-    jit = eng._decode_paged_jit if kind == "paged" else eng._decode_jit
-    return jit, args
+def _lowered_serve_step(params, step):
+    eng = Engine(CFG, params, **ENGINES["chunked"],
+                 decode_attention="interpret", sample_block=32,
+                 sample_k_cap=16)
+    s = eng.slots
+    i32, mask = jnp.zeros((s,), jnp.int32), jnp.ones((s,), bool)
+    tail = [jnp.asarray(eng.allocator.block_tables, jnp.int32),
+            jax.random.key(0), jnp.zeros((s,), jnp.float32), i32]
+    head = [eng.params, eng.cache, eng.last_token]
+    if step == "decode":
+        return eng._decode_paged_jit, head + [mask] + tail
+    toks = jnp.zeros((s, eng.prefill_chunk), jnp.int32)
+    return eng._prefill_paged_jit, head + [toks, i32, i32 + 2, i32, mask] + tail
 
 
 def _lowered_train():
@@ -158,14 +163,16 @@ STEPS = {
     "train": (_lowered_train, "jit_train_step",
               ("loss", "grad_sync", "opt_update", "zero1_gather", "attn",
                "mlp", "lm_head", "embed"), (), ()),
-    "decode_dense": (lambda p: _lowered_decode(p, "dense"), "jit_decode",
-                     ("kv_write", "kv_gather", "attn", "mlp", "lm_head",
-                      "sample", "embed"), ("decode_attn",), ()),
     # The page pool is stored as the kernel reads it: nothing is left to
     # lower under kv_gather (an int8 pool still pads its scale plane there).
-    "decode_paged": (lambda p: _lowered_decode(p, "paged"), "jit_decode_paged",
+    "decode_paged": (lambda p: _lowered_serve_step(p, "decode"),
+                     "jit_decode_paged",
                      ("kv_write", "attn", "mlp", "lm_head", "sample",
                       "embed"), ("paged_decode_attn",), ("kv_gather",)),
+    "prefill_paged": (lambda p: _lowered_serve_step(p, "prefill"),
+                      "jit_prefill_paged",
+                      ("kv_write", "attn", "mlp", "lm_head", "sample",
+                       "embed"), ("paged_decode_attn",), ("kv_gather",)),
 }
 
 

@@ -16,7 +16,7 @@ The done-criteria:
   speculative acceptance is neutral with int8 on BOTH draft and
   target;
 - the full step surface bit-matches the whole-dequant reference
-  oracle — dense, paged + chunked prefill, speculative, TP (slow) —
+  oracle — whole prefill, chunked prefill, speculative, TP (slow) —
   at the unchanged lifetime compile pins;
 - the default path stays byte-identical: an engine constructed without
   ``weights_dtype`` holds plain dense params and its spans carry no
@@ -105,13 +105,13 @@ def trained():
 
 @pytest.fixture(scope="module")
 def engines(trained):
-    """ONE shared f32/int8 dense engine pair (compiles paid once;
+    """ONE shared f32/int8 engine pair (compiles paid once;
     tests ``reset()`` before use — cleared cache, compiled steps
     kept)."""
     params, _ = trained
     return {
         dt: Engine(
-            CFG, params, slots=2, max_len=40, prefill_len=16,
+            CFG, params, slots=2, max_len=40, kv_page_size=8, prefill_len=16,
             weights_dtype=dt,
         )
         for dt in ("f32", "int8")
@@ -131,7 +131,7 @@ _ORACLE_MEMO: dict = {}
 
 def _isolated_int8w(params, prompt, n):
     """The self-consistency oracle: the same request alone through the
-    int8-weight dense-REFERENCE engine (whole-dequant matmuls — the
+    int8-weight REFERENCE engine (whole-dequant matmuls — the
     parity baseline every blocked path must match token-for-token).
     ONE engine, reset between requests, results memoized (the
     test_kv_quant wall discipline)."""
@@ -140,7 +140,7 @@ def _isolated_int8w(params, prompt, n):
         return _ORACLE_MEMO[key]
     if not _ORACLE_ENGINE:
         _ORACLE_ENGINE.append(Engine(
-            CFG, params, slots=2, max_len=40, prefill_len=16,
+            CFG, params, slots=2, max_len=40, kv_page_size=8, prefill_len=16,
             weights_dtype="int8", decode_attention="reference",
         ))
     eng = _ORACLE_ENGINE[0]
@@ -306,8 +306,9 @@ class TestQuantizedWeightServing:
         pins (the wall discipline): on the trained checkpoint the
         blocked int8-weight engine's greedy outputs (a) equal the f32
         engine's token for token, and (b) bit-match the whole-dequant
-        reference oracle per isolated request — at the pinned dense
-        lifetime compile count (2, quantized or not)."""
+        reference oracle per isolated request — at the pinned
+        lifetime compile count (prefill + decode, and the page copy
+        once one ran; quantized or not)."""
         params, _ = trained
         reqs = list(zip(PROMPTS, MAX_NEW))
         outs = {}
@@ -318,13 +319,14 @@ class TestQuantizedWeightServing:
         for rid, (p, n) in enumerate(reqs):
             assert outs["int8"][rid] == _isolated_int8w(params, p, n), rid
         eng = engines["int8"]
-        assert eng.compile_watch.compiles == 2
+        eng.copy_page(0, 0)
+        assert eng.compile_watch.compiles == 3
         assert eng.compile_watch.unexpected == 0
 
     def test_logit_bound_and_antivacuity(self, trained):
         """Prefill logits through the int8 store sit within a bound of
         the f32-weight oracle — and are NOT identical (the lossy path
-        executed). Same (dense f32) cache both sides: the delta is
+        executed). Same (f32) cache both sides: the delta is
         weight quantization and nothing else."""
         params, loss = trained
         assert loss < 0.5  # trained, not random — the gates are real
@@ -343,7 +345,7 @@ class TestQuantizedWeightServing:
         """weights_dtype unset: plain dense params (no QuantizedTensor
         anywhere), weights_dtype reported but NOT stamped on spans."""
         params, _ = trained
-        eng = Engine(CFG, params, slots=2, max_len=40, prefill_len=8)
+        eng = Engine(CFG, params, slots=2, max_len=40, kv_page_size=8, prefill_len=8)
         assert not eng.weights_quantized
         assert not eng.weights_dtype_explicit
         assert eng.weights_dtype == "f32"
@@ -376,7 +378,7 @@ class TestQuantizedWeightServing:
     def test_rejects_unknown_weights_dtype(self, trained):
         params, _ = trained
         with pytest.raises(ValueError, match="weights_dtype"):
-            Engine(CFG, params, slots=1, max_len=40, prefill_len=8,
+            Engine(CFG, params, slots=1, max_len=40, kv_page_size=8, prefill_len=8,
                    weights_dtype="int4")
 
     def test_wire_honesty_param_bytes_and_decode_bytes(self, trained,
@@ -440,14 +442,15 @@ class TestQuantizedWeightsPagedSpec:
         target (the engine quantizes the draft store too): greedy
         output equals the plain int8 oracle's, and acceptance equals
         the f32 pair's (delta ≈ 0) — at the speculative compile pin
-        (3 dense: prefill + spec_draft + spec_verify)."""
+        (prefill + spec_draft + spec_verify, and the page copy once a
+        shared page diverged)."""
         params, _ = trained
         dp, dcfg = draft_from_target(params, CFG, 1)
         reqs = list(zip(PROMPTS[:3], MAX_NEW[:3]))
         acc = {}
         for dt in ("f32", "int8"):
             eng = Engine(
-                CFG, params, slots=2, max_len=40, prefill_len=16,
+                CFG, params, slots=2, max_len=40, kv_page_size=8, prefill_len=16,
                 spec_k=2, draft_params=dp, draft_cfg=dcfg,
                 weights_dtype=dt,
             )
@@ -458,7 +461,8 @@ class TestQuantizedWeightsPagedSpec:
                                   QuantizedTensor)
                 for rid, (p, n) in enumerate(reqs):
                     assert done[rid] == _isolated_int8w(params, p, n), rid
-                assert eng.compile_watch.compiles == 3
+                eng.copy_page(0, 0)
+                assert eng.compile_watch.compiles == 4
         assert acc["f32"] is not None and acc["int8"] is not None
         assert abs(acc["int8"] - acc["f32"]) <= 0.05
 
@@ -478,7 +482,7 @@ class TestQuantizedWeightsPagedSpec:
         )
         done, _ = _run(eng, reqs)
         oracle = Engine(
-            CFG, params, slots=2, max_len=40, prefill_len=16,
+            CFG, params, slots=2, max_len=40, kv_page_size=8, prefill_len=16,
             weights_dtype="int8", kv_dtype="int8",
             decode_attention="reference",
         )
@@ -490,7 +494,7 @@ class TestQuantizedWeightsPagedSpec:
 
 @pytest.mark.slow
 class TestQuantizedWeightsTensorParallel:
-    def test_tp_int8_bitmatches_dense_int8(self, trained):
+    def test_tp_int8_bitmatches_single_device_int8(self, trained):
         """data=4 × model=2 fake mesh: column kernels shard the int8
         payload on the feature axis with REPLICATED scales (rows are
         the replicated contraction dim); row kernels shard payload AND
@@ -500,12 +504,12 @@ class TestQuantizedWeightsTensorParallel:
         world = mpit_tpu.init({"data": 4, "model": 2}, set_default=False)
         reqs = list(zip(PROMPTS[:3], MAX_NEW[:3]))
         ref, _ = _run(
-            Engine(CFG, params, slots=2, max_len=40, prefill_len=16,
+            Engine(CFG, params, slots=2, max_len=40, kv_page_size=8, prefill_len=16,
                    weights_dtype="int8"),
             reqs,
         )
         eng = Engine(
-            CFG, params, slots=2, max_len=40, prefill_len=16,
+            CFG, params, slots=2, max_len=40, kv_page_size=8, prefill_len=16,
             world=world, tp_axis="model", weights_dtype="int8",
         )
         blk = eng.params["block_0"]

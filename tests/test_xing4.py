@@ -303,7 +303,6 @@ def test_compacted_tick_reports_its_rows(tiny, monkeypatch):
 
 
 @pytest.mark.parametrize("kw, what", [
-    (dict(kv_pages=None), "dense KVCache"),
     (dict(kv_dtype="int8"), "int8 cache"),
     (dict(weights_dtype="int8"), "int8 weights"),
     (dict(kv_host_pages=2), "host KV tier"),
