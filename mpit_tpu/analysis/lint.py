@@ -174,10 +174,25 @@ DEFAULT_CONFIG = LintConfig(
             "Server._spec_tick",
             "Server._prefill_chunk_tick",
             "Server._run_tick",
+            # One tick of steps in flight (ISSUE 29): what enqueues,
+            # fetches and settles a step. The fences themselves are the
+            # engine's two fetch halves.
+            "Server._stage_chunk",
+            "Server._chunk_enqueued",
+            "Server._fetch",
+            "Server._land",
+            "Server._drain",
+            "Server._settle_prefill",
+            "Server._settle_decode",
         },
         "mpit_tpu/serve/engine.py": {
             "Engine.prefill_paged",
+            "Engine.prefill_dispatch",
+            "Engine._prefill_compact_dispatch",
+            "Engine.prefill_fetch",
             "Engine.decode",
+            "Engine.decode_dispatch",
+            "Engine.decode_fetch",
             "Engine.spec_draft",
             "Engine.spec_verify",
             "Engine.copy_page",
@@ -197,10 +212,12 @@ DEFAULT_CONFIG = LintConfig(
             "Server.submit",
             "Server._admit",
             "Server._preempt",
-            "Server._prefill_chunk_tick",
+            "Server._stage_chunk",
+            "Server._chunk_enqueued",
             "Server._decode_tick",
+            "Server._settle_decode",
             "Server._spec_tick",
-            "Server._maybe_retire",
+            "Server._complete",
         },
         "mpit_tpu/serve/policy.py": {"SchedulingPolicy.should_shed"},
     },

@@ -1441,10 +1441,14 @@ class Engine:
 
     def _stage(self, name: str, value, dtype):
         """``value`` on the device as ``dtype``: the copy staged for the
-        last tick while the content is the same. ``active``, ``temp``,
+        last step while the content is the same. ``active``, ``temp``,
         ``topk`` and the block tables change when a slot is admitted,
         retires or takes a page, not from tick to tick, and a transfer
-        costs the host 0.27 ms each while the device waits."""
+        costs the host 0.27 ms each. The transfer is made from a copy
+        the engine keeps and never writes to: the scheduler changes its
+        arrays in place while the step that took them is still in flight,
+        and ``jnp.asarray`` of host memory may alias it (on the CPU it
+        does, wherever the buffer is aligned)."""
         host = np.asarray(value, dtype)
         held = self._staged.get(name)
         if (
@@ -1452,7 +1456,8 @@ class Engine:
             or held[0].shape != host.shape
             or not np.array_equal(held[0], host)
         ):
-            held = self._staged[name] = (host.copy(), jnp.asarray(host))
+            mine = host.copy()
+            held = self._staged[name] = (mine, jnp.asarray(mine))
         return held[1]
 
     def prefill_paged(
@@ -1463,9 +1468,23 @@ class Engine:
         ``sample_mask`` [slots] bool per :meth:`_paged_prefill_step`.
         Block tables come from the engine's allocator. Returns the
         per-slot last token (the first OUTPUT token for slots whose
-        ``sample_mask`` is set) as host numpy."""
+        ``sample_mask`` is set) as host numpy: :meth:`prefill_dispatch`
+        and then :meth:`prefill_fetch`, for a caller that wants the
+        tokens before it does anything else."""
+        return self.prefill_fetch(self.prefill_dispatch(
+            tokens, base, chunk_lens, floor, sample_mask, temp, topk
+        ))
+
+    def prefill_dispatch(
+        self, tokens, base, chunk_lens, floor, sample_mask, temp, topk
+    ):
+        """Stage and enqueue one prefill chunk (arguments as
+        :meth:`prefill_paged`) and return what the step left on the
+        device, for :meth:`prefill_fetch`: nothing here waits for the
+        step, so the caller may enqueue more behind it first."""
+        chunk_lens = np.asarray(chunk_lens)
         if self._prefill_counts:
-            return self._prefill_paged_compact(
+            return self._prefill_compact_dispatch(
                 tokens, base, chunk_lens, floor, sample_mask, temp, topk
             )
         aux = ()
@@ -1479,10 +1498,12 @@ class Engine:
                 jnp.asarray(chunk_lens, jnp.int32),
                 jnp.asarray(floor, jnp.int32),
                 jnp.asarray(sample_mask, bool),
-                jnp.asarray(self.allocator.block_tables, jnp.int32),
+                self._stage(
+                    "block_tables", self.allocator.block_tables, np.int32
+                ),
                 self._split(),
-                jnp.asarray(temp, jnp.float32),
-                jnp.asarray(topk, jnp.int32),
+                self._stage("temp", temp, np.float32),
+                self._stage("topk", topk, np.int32),
             ]
             if self.spec_k:
                 args += [self.draft_params, self.draft_cache]
@@ -1495,33 +1516,27 @@ class Engine:
                 self.cache, self.last_token, *aux = self.compile_watch.call(
                     "prefill", self._prefill_paged_jit, *args
                 )
-        with obs.span("prefill_fetch"):  # the wait and the copy back
-            # The step's one deliberate completion fence (docstring
-            # contract: the fetch closes the caller's span).
-            # analysis: allow(host-sync-in-hot-seam)
-            toks = np.asarray(self.last_token)
-        if obs.enabled():
-            chunk_lens = np.asarray(chunk_lens)
-            self._note_prefill_rows(
-                chunk_lens.size * self.prefill_chunk, int(chunk_lens.sum())
-            )
-            self._note_aux("prefill", aux)
-        return toks
+        return (
+            self.last_token, [aux], chunk_lens.size * self.prefill_chunk,
+            int(chunk_lens.sum()),
+        )
 
-    def _prefill_paged_compact(
+    def _prefill_compact_dispatch(
         self, tokens, base, chunk_lens, floor, sample_mask, temp, topk
-    ) -> np.ndarray:
+    ):
         """A chunk tick over its participants only (see
         :meth:`_paged_prefill_compact_step`): the slots with a chunk are
         taken ``_prefill_counts[-1]`` at a time, each group padded to the
-        next compiled count."""
-        chunk_lens = np.asarray(chunk_lens)
+        next compiled count. Every group's step is enqueued; the last
+        one's tokens hold them all."""
         takers = np.flatnonzero(chunk_lens > 0)
         most, computed, aux_all = self._prefill_counts[-1], 0, []
         with obs.span("prefill_dispatch"):  # staging and enqueue
-            bt = jnp.asarray(self.allocator.block_tables, jnp.int32)
-            temp = jnp.asarray(temp, jnp.float32)
-            topk = jnp.asarray(topk, jnp.int32)
+            bt = self._stage(
+                "block_tables", self.allocator.block_tables, np.int32
+            )
+            temp = self._stage("temp", temp, np.float32)
+            topk = self._stage("topk", topk, np.int32)
             for g0 in range(0, len(takers), most):
                 group = takers[g0 : g0 + most]
                 n = next(c for c in self._prefill_counts if c >= len(group))
@@ -1546,11 +1561,19 @@ class Engine:
                 )
                 computed += n * self.prefill_chunk
                 aux_all.append(aux)
+        return self.last_token, aux_all, computed, int(chunk_lens.sum())
+
+    def prefill_fetch(self, step) -> np.ndarray:
+        """The tokens of a chunk :meth:`prefill_dispatch` enqueued, as
+        host numpy: the wait for the step and the copy back."""
+        last, aux_all, computed, valid = step
         with obs.span("prefill_fetch"):  # the wait and the copy back
+            # The step's one deliberate completion fence (docstring
+            # contract: the fetch closes the caller's span).
             # analysis: allow(host-sync-in-hot-seam)
-            toks = np.asarray(self.last_token)
+            toks = np.asarray(last)
         if obs.enabled():
-            self._note_prefill_rows(computed, int(chunk_lens.sum()))
+            self._note_prefill_rows(computed, valid)
             for aux in aux_all:
                 self._note_aux("prefill", aux)
         return toks
@@ -1788,9 +1811,32 @@ class Engine:
         # analysis: allow(host-sync-in-hot-seam)
         return np.asarray(emit), np.asarray(n_emit), np.asarray(n_acc)
 
-    def decode(self, active, temp, topk) -> np.ndarray:
+    def decode(self, active=None, temp=None, topk=None, *,
+               step=None) -> np.ndarray:
         """One decode tick over the slot batch; returns the per-slot
-        next token (host numpy; stale for inactive slots)."""
+        next token (host numpy; stale for inactive slots):
+        :meth:`decode_dispatch`, then the wait for the step and the copy
+        back. Every decode token the engine hands out comes through this
+        call: a caller that enqueued the step ahead gives it back as
+        ``step`` (:meth:`decode_fetch`) and gets that second half alone."""
+        if step is None:
+            step = self.decode_dispatch(active, temp, topk)
+        last, aux = step
+        with obs.span("decode_fetch"):  # the wait and the copy back
+            # The step's one deliberate completion fence (docstring
+            # contract: the fetch closes the caller's span).
+            # analysis: allow(host-sync-in-hot-seam)
+            toks = np.asarray(last)
+        if aux and obs.enabled():
+            self._note_aux("decode", aux)
+        return toks
+
+    def decode_dispatch(self, active, temp, topk):
+        """Stage and enqueue one decode tick and return what the step
+        left on the device, for :meth:`decode_fetch`. Nothing here waits
+        for the step: the next one reads ``last_token`` on the device,
+        so the caller may enqueue it before this one's tokens are
+        fetched."""
         if self.spec_k:
             raise ValueError(
                 "a speculative engine ticks through spec_draft + "
@@ -1813,14 +1859,13 @@ class Engine:
                 "decode", self._decode_paged_jit, *args
             )
             self._split_ahead()
-        with obs.span("decode_fetch"):  # the wait and the copy back
-            # The step's one deliberate completion fence (docstring
-            # contract: the fetch closes the caller's span).
-            # analysis: allow(host-sync-in-hot-seam)
-            toks = np.asarray(self.last_token)
-        if aux and obs.enabled():
-            self._note_aux("decode", aux)
-        return toks
+        return self.last_token, aux
+
+    def decode_fetch(self, step) -> np.ndarray:
+        """The tokens of a tick :meth:`decode_dispatch` enqueued, as host
+        numpy: the second half of :meth:`decode`, and through it, so that
+        whatever wraps ``decode`` sees every served token."""
+        return self.decode(step=step)
 
     # -- roofline accounting (ISSUE 8) --------------------------------------
     def register_roofline(self) -> dict:

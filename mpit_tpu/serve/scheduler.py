@@ -11,21 +11,24 @@ cache-full, freeing the slot for the next queue entry immediately.
 Observability (``mpit_tpu.obs``) is first-class, not bolted on:
 
 - spans: ``prefill`` (per admission batch) and ``decode`` (per tick) —
-  both close on the host fetch of the sampled tokens, so their wall
-  clock covers real device completion. They are two nodes of a tree,
-  nested by time on the loop's thread: ``tick`` round every iteration,
-  ``admit``, ``prefill``, ``gauges``, ``decode`` and ``retire`` inside
-  it in the order they run (:meth:`Server._run_tick`), and the engine's
-  ``decode_dispatch`` / ``decode_fetch`` (``prefill_*`` likewise)
-  inside the two calls. No argument of a span is computed when no
-  recorder is installed;
+  each enqueues this tick's step and closes on the host fetch of the
+  LAST tick's tokens (one tick of steps is kept in flight, ISSUE 29:
+  :meth:`Server._run_tick`), so with a step always queued their wall
+  clock is the device's period. They are two nodes of a tree, nested by
+  time on the loop's thread: ``tick`` round every iteration, ``admit``,
+  ``prefill``, ``gauges``, ``decode`` and ``retire`` inside it in the
+  order they run, and the engine's ``decode_dispatch`` /
+  ``decode_fetch`` (``prefill_*`` likewise) inside the two. No argument
+  of a span is computed when no recorder is installed;
 - per-request intervals recorded with explicit timestamps
   (``obs.span_at``): ``queue_wait`` (submit → admit), ``request_ttft``
   (submit → first token) and ``request_latency`` (submit → retire) —
   the summary's per-phase p50/p95 roll-up then IS the latency/TTFT
   histogram, and the Chrome trace shows every request as a bar;
 - ``slot_occupancy`` gauge + ``serve_tokens``/``serve_requests``
-  counters each tick.
+  counters each tick; ``serve_steps_overlapped`` (a step enqueued while
+  an older one was unfetched) and ``serve_steps_drained`` (a fetch with
+  nothing enqueued behind it) say how often the overlap engaged.
 
 An optional :class:`mpit_tpu.obs.Sentinel` (``phases=("decode",
 "prefill")``) watches the tick stream for spikes/sustained degradation
@@ -249,6 +252,20 @@ class _Live:
     # post-resume token), closing the per-mode duration sample.
     resume_mode: str = ""
     resume_t: float = 0.0
+    # One tick of steps in flight (ISSUE 29): ``tokens`` holds the
+    # values that have reached the host; ``issued`` counts the tokens
+    # asked of the device so far, those of steps still in flight
+    # included. Everything the host decides before a step runs (the
+    # cache fill, the budget left, retirement by count) reads the count.
+    issued: int = 0
+    # The slot and its pages went back by count while the last tokens
+    # were still in flight: the request stays in ``Server.live`` (its
+    # landed tokens stay visible) until the next tick begins or its
+    # ``Completed`` is written, whichever comes first.
+    released: bool = False
+    # ``Completed`` is written (or an EOS stopped it): whatever a step
+    # still in flight computed for it is dropped.
+    done: bool = False
 
     def feed_tokens(self) -> list:
         """What prefill feeds the device: the prompt, or the resume
@@ -260,7 +277,7 @@ class _Live:
         term (full ``max_new_tokens`` before the first token; the
         resume admission re-plans with the already-generated tokens
         moved into the feed, so the page watermark is unchanged)."""
-        return self.req.max_new_tokens - len(self.tokens)
+        return self.req.max_new_tokens - self.issued
 
     def cache_fill(self) -> int:
         """Host mirror of the device cache fill for a LIVE slot — THE
@@ -270,8 +287,23 @@ class _Live:
         skipping). Prefill cached the prompt; each decode tick appends
         ONE token; the newest sampled token is NOT yet written — so the
         fill is ``prompt + generated - 1``, and the next decode append
-        lands exactly here."""
-        return len(self.req.prompt) + len(self.tokens) - 1
+        lands exactly here. ``generated`` is the count of tokens asked
+        for (``issued``): the fill after every step already enqueued,
+        whether or not its token has been fetched."""
+        return len(self.req.prompt) + self.issued - 1
+
+
+@dataclasses.dataclass
+class _Step:
+    """One enqueued step whose tokens are still on the device."""
+
+    kind: str  # "prefill" | "decode": the span its fetch lies inside
+    pending: Any  # what the engine's dispatch half returned
+    takers: list  # (slot, _Live) pairs that get a token from it
+    tick: int  # the tick that enqueued it
+    lens: Any = None  # decode: every taker's cache fill before the step
+    toks: Any = None  # host numpy, once fetched
+    t_land: float = 0.0  # when they reached the host
 
 
 class Server:
@@ -379,8 +411,19 @@ class Server:
         # to and the cache rows a step of its loop takes (static per
         # compiled step; empty where no kernel runs): a trace says
         # whether the fast tiling engaged.
+        # A prefill / decode span's fixed labels: the same on the span
+        # that enqueues a step and on one that only fetches it.
+        labels = dict(
+            attention=self._attn_mode, sampler=self._sampler,
+            **self._kv_attrs,
+        )
         self._decode_tiling = engine.attention_tiling(self._spec + 1)
-        self._prefill_tiling = engine.attention_tiling(engine.prefill_chunk)
+        self._labels = {
+            "prefill": dict(
+                labels, **engine.attention_tiling(engine.prefill_chunk)
+            ),
+            "decode": dict(labels, **self._decode_tiling),
+        }
         self._spec_emitted = 0
         self._spec_active_ticks = 0
         self._spec_drafted = 0
@@ -404,6 +447,14 @@ class Server:
         # admit can't head-of-line-block decode for every live slot).
         self.prefilling: dict[int, _Live] = {}
         self.free: list[int] = list(range(engine.slots))[::-1]  # pop() = slot 0 first
+        # Steps enqueued whose tokens have not been fetched, in the
+        # device's order: at most one tick's (a chunk step, then a decode
+        # step) beside the tick being enqueued. A speculative engine
+        # keeps none: how many tokens its step yields is known only once
+        # it has run, and every count here follows from that.
+        self._in_flight: deque[_Step] = deque()
+        self.steps_overlapped = 0  # enqueued while an older step was unfetched
+        self.steps_drained = 0  # fetched with nothing enqueued behind
         self.completed: list[Completed] = []
         self.shed: list[Request] = []
         self.shed_causes: dict[str, int] = {}  # cause -> count (ISSUE 12)
@@ -742,6 +793,9 @@ class Server:
         priority = self.policy.wants_preemption(now)
         if priority is None:
             return False
+        # The victim is chosen by the tokens it has left and parked with
+        # those it has: both are values, so what is in flight lands first.
+        self._drain()
         victim = self.policy.pick_victim(self.live, priority)
         if victim is None:
             return False
@@ -757,7 +811,13 @@ class Server:
         the FRONT of its own tier. The resume path is the normal
         chunked prefill over ``prompt + tokens`` — its final row
         recomputes the displaced decode tick, so the resumed greedy
-        output bit-matches the un-preempted one (test-pinned)."""
+        output bit-matches the un-preempted one (test-pinned).
+
+        The feed is made of values, so every step in flight is fetched
+        first; an EOS among them may have retired the slot already."""
+        self._drain()
+        if slot not in self.live:
+            return
         live = self.live.pop(slot)
         alloc = self.engine.allocator
         owned, shared = alloc.slot_page_stats(slot)
@@ -904,14 +964,133 @@ class Server:
         live.floor = fill
         return True
 
+    # -- one tick of steps in flight (ISSUE 29) --------------------------------
+    def _decoding(self) -> list:
+        """``(slot, live)`` of the slots a decode step would serve: what
+        is in ``live`` less the entries whose slot has gone back already."""
+        return [(s, l) for s, l in self.live.items() if not l.released]
+
+    def _older(self, kind: str, before: int | None = None) -> bool:
+        """Whether the oldest step in flight is a ``kind`` step that a
+        tick before ``before`` (this one) enqueued: the one this tick's
+        ``kind`` phase fetches."""
+        head = self._in_flight[0] if self._in_flight else None
+        return (
+            head is not None and head.kind == kind
+            and head.tick < (self.tick if before is None else before)
+        )
+
+    def _enqueued(self, step: _Step) -> None:
+        """``step`` joins what is in flight, behind whatever is unfetched."""
+        if self._in_flight:
+            self.steps_overlapped += 1
+            obs.counter("serve_steps_overlapped")
+        self._in_flight.append(step)
+
+    def _fetch(self) -> _Step:
+        """Take the oldest step in flight off the device: wait for its
+        tokens and stamp them with the time they reached the host. The
+        caller has a span of the step's kind open round this."""
+        step = self._in_flight.popleft()
+        fetch = (
+            self.engine.decode_fetch if step.kind == "decode"
+            else self.engine.prefill_fetch
+        )
+        # A chunk in which no slot finished its prompt is fetched too,
+        # for the wait: it is what holds the host to one tick ahead of
+        # the device where no decode step does (a lone long prompt would
+        # otherwise be enqueued whole, and a later arrival's first chunk
+        # would queue behind all of it instead of riding the next one).
+        step.toks = fetch(step.pending)
+        step.t_land = time.perf_counter()
+        step.pending = None
+        if not self._in_flight:
+            self.steps_drained += 1
+            obs.counter("serve_steps_drained")
+        return step
+
+    def _land(self, kind: str, before: int) -> list:
+        """Fetch the ``kind`` steps at the head of what is in flight that
+        ticks before ``before`` enqueued (inside the caller's ``kind``
+        span); they are settled by the caller once the span has closed."""
+        landed = []
+        while self._older(kind, before):
+            landed.append(self._fetch())
+        return landed
+
+    def _drain(self) -> None:
+        """Fetch and settle every step in flight, oldest first, with
+        nothing enqueued behind them: what a preemption does first (its
+        feed is made of values), and what a tick does at its end when
+        nothing is left that could be enqueued. Each fetch lies inside a
+        span of its step's kind, as every fetch does."""
+        while self._in_flight:
+            head = self._in_flight[0]
+            attrs = {}
+            if obs.enabled():
+                attrs = dict(
+                    drained=True,
+                    rids=[live.req.rid for _, live in head.takers],
+                    **self._labels[head.kind],
+                )
+            t0 = time.perf_counter()
+            with obs.span(head.kind, **attrs):
+                step = self._fetch()
+            if step.kind == "decode":
+                with obs.span("retire"):
+                    self._settle_decode(step, t0)
+            else:
+                self._settle_prefill(step)
+
     def _prefill_chunk_tick(self) -> None:
-        """Advance every prefilling slot by ONE prompt chunk (one
-        batched call). Slots whose final prompt token rides this chunk
-        sample their first output token, register their prompt in the
-        prefix index (only now — an index entry must never advertise
-        K/V not yet on the device) and go live."""
-        if not self.prefilling:
+        """Enqueue ONE prompt chunk for every prefilling slot (one
+        batched call), then fetch the chunk the last tick enqueued.
+
+        Slots whose final prompt token rides this chunk register their
+        prompt in the prefix index (only now — an index entry must never
+        advertise K/V not yet on the device, which holds in the device's
+        order: whatever reads those pages is enqueued behind this step)
+        and go live BY COUNT; their first output token is a value and
+        arrives with the fetch, a tick later (:meth:`_settle_prefill`).
+        A speculative engine fetches its chunk at once."""
+        if not self.prefilling and not self._older("prefill"):
             return
+        now = time.perf_counter()
+        attrs = {}
+        chunk = bool(self.prefilling)
+        if chunk:
+            tokens, base, chunk_lens, floor, sample_mask, finishing = (
+                self._stage_chunk()
+            )
+            if obs.enabled():  # a disabled span costs a tick nothing
+                attrs = dict(
+                    admitted=len(finishing),
+                    chunks=int((chunk_lens > 0).sum()),
+                    rids=[live.req.rid for live in self.prefilling.values()],
+                )
+        if obs.enabled():
+            attrs.update(self._labels["prefill"])
+        with obs.span("prefill", **attrs):
+            if chunk:
+                self._enqueued(_Step(
+                    "prefill",
+                    self.engine.prefill_dispatch(
+                        tokens, base, chunk_lens, floor, sample_mask,
+                        self._temp, self._topk,
+                    ),
+                    finishing, self.tick,
+                ))
+            landed = self._land("prefill", self.tick + bool(self._spec))
+        t_end = time.perf_counter()
+        if chunk:
+            self._chunk_enqueued(chunk_lens, finishing, t_end - now, t_end)
+        for step in landed:
+            self._settle_prefill(step)
+
+    def _stage_chunk(self):
+        """The next chunk of every prefilling slot as the step's host
+        arrays, the page copies its writes need enqueued before it, and
+        the slots whose final prompt token rides it."""
         eng = self.engine
         alloc = eng.allocator
         s, w = eng.slots, eng.prefill_chunk
@@ -921,7 +1100,6 @@ class Server:
         floor = np.zeros((s,), np.int32)
         sample_mask = np.zeros((s,), bool)
         finishing: list[tuple[int, _Live]] = []
-        now = time.perf_counter()
         for slot, live in self.prefilling.items():
             p = live.feed_tokens()
             n = min(w, len(p) - live.base)
@@ -946,43 +1124,37 @@ class Server:
             if live.base + n == len(p):
                 sample_mask[slot] = True
                 finishing.append((slot, live))
-        attrs = {}
-        if obs.enabled():  # a disabled span costs a tick nothing
-            attrs = dict(
-                admitted=len(finishing),
-                chunks=int((chunk_lens > 0).sum()),
-                attention=self._attn_mode,
-                sampler=self._sampler,
-                rids=[live.req.rid for live in self.prefilling.values()],
-                **self._kv_attrs, **self._prefill_tiling,
-            )
-        with obs.span("prefill", **attrs):
-            first = eng.prefill_paged(
-                tokens, base, chunk_lens, floor, sample_mask,
-                self._temp, self._topk,
-            )
-        t_first = time.perf_counter()
+        return tokens, base, chunk_lens, floor, sample_mask, finishing
+
+    def _chunk_enqueued(self, chunk_lens, finishing, dur: float,
+                        t_end: float) -> None:
+        """What the host knows of a chunk step without its values: every
+        slot's progress through its prompt, and for a slot that finished
+        it the prefix registration, its place among the live slots and,
+        where one token is all it was to have, the slot's return. ``dur``
+        is the wall of the ``prefill`` phase (this chunk's staging and
+        enqueue and the wait for the last one's tokens): what the
+        sentinel, the policy's projector and the ledger take as a chunk
+        tick's cost."""
+        alloc = self.engine.allocator
         if self.sentinel is not None:
-            self.sentinel.observe_phases(self.tick, prefill=t_first - now)
+            self.sentinel.observe_phases(self.tick, prefill=dur)
         if self.stream is not None:
             # The policy projector's per-chunk cost basis (ISSUE 12).
-            self.stream.observe("prefill_tick", t_first - now)
-        if self._ledger is not None:
-            # One event per slot that actually advanced — the chunk
-            # length and the tick wall feed prefill_compute_s in the
-            # why-slow attribution.
-            for slot, live in self.prefilling.items():
-                n = int(chunk_lens[slot])
-                if n:
+            self.stream.observe("prefill_tick", dur)
+        for slot, live in self.prefilling.items():
+            n = int(chunk_lens[slot])
+            live.base += n
+            if n:
+                live.last_touch = self.tick
+                if self._ledger is not None:
+                    # One event per slot that actually advanced — the
+                    # chunk length and the tick wall feed
+                    # prefill_compute_s in the why-slow attribution.
                     self._ledger.event(
                         live.req.rid, "prefill_chunk", tick=self.tick,
-                        chunk=n, dur_s=t_first - now, t=t_first,
+                        chunk=n, dur_s=dur, t=t_end,
                     )
-        for slot in self.prefilling:
-            live = self.prefilling[slot]
-            live.base += int(chunk_lens[slot])
-            if chunk_lens[slot]:
-                live.last_touch = self.tick
         for slot, live in finishing:
             del self.prefilling[slot]
             promoted = alloc.register_prefix(
@@ -995,11 +1167,25 @@ class Server:
                 self.engine.host_free(
                     hp, kind="promote", owner=live.req.rid
                 )
+            # A resumed request's tokens are all on the host (its
+            # preemption drained first): this chunk's sampled token is
+            # the decode step the eviction displaced.
+            live.issued = len(live.tokens) + 1
+            self.live[slot] = live
+            if self._spent(live):
+                self._release(slot, live)
+
+    def _settle_prefill(self, step: _Step) -> None:
+        """What a finished prompt owes once its chunk's tokens are on
+        the host: the first token and its time (stamped when this
+        step's own array landed, not when a step behind it did)."""
+        t_first = step.t_land
+        for slot, live in step.takers:
+            tok = int(step.toks[slot])
             if live.tokens:
-                # Resumed after a preemption: this chunk's sampled
-                # token IS the decode step the eviction displaced —
-                # append it; TTFT was already delivered before the park.
-                live.tokens.append(int(first[slot]))
+                # Resumed after a preemption: append; TTFT was already
+                # delivered before the park.
+                live.tokens.append(tok)
                 if live.resume_mode:
                     # Close the resume: admission → first post-resume
                     # token, by rebuild mode (ISSUE 20 — the p95
@@ -1015,10 +1201,9 @@ class Server:
                     live.resume_mode = ""
             else:
                 live.first_token_t = t_first
-                live.tokens = [int(first[slot])]
+                live.tokens = [tok]
                 self._record_ttft(live, t_first)
-            self.live[slot] = live
-            self._maybe_retire(slot, t_first)
+            self._token_landed(slot, live, t_first)
 
     def _record_ttft(self, live: _Live, t_first: float) -> None:
         """First-token bookkeeping: the request_ttft span + rolling
@@ -1039,35 +1224,61 @@ class Server:
         if req.tenant:
             self.stream.observe(f"request_ttft_tenant:{req.tenant}", ttft)
 
-    def _maybe_retire(self, slot: int, now: float) -> None:
-        """Retire ``slot`` if its newest token finished the request."""
-        live = self.live[slot]
-        req = live.req
-        tok = live.tokens[-1]
-        # The next decode would write at the fill position — at max_len
-        # the slot must retire or it would overrun its mapped pages.
-        full = live.cache_fill() >= self.engine.max_len
-        done = (
-            (req.eos_id is not None and tok == req.eos_id)
-            or len(live.tokens) >= req.max_new_tokens
-            or full
+    def _spent(self, live: _Live) -> bool:
+        """No further step will be asked for ``live``, by count: its
+        budget of tokens is issued, or the next decode would write at
+        ``max_len`` and overrun the slot's mapped pages."""
+        return (
+            live.issued >= live.req.max_new_tokens
+            or live.cache_fill() >= self.engine.max_len
         )
-        if not done:
-            return
-        del self.live[slot]
+
+    def _release(self, slot: int, live: _Live) -> None:
+        """Give ``slot`` and its pages back. By count this happens in
+        the tick that enqueues the request's last step, before that
+        step's token is fetched; the request stays in ``live`` (flagged
+        ``released``) so that its landed tokens stay visible until its
+        ``Completed`` is written."""
         # Unmap the slot's pages: refcounts drop, sole-owner pages
         # return to the free list (recycled WITHOUT zeroing — the
         # mask defines validity), prefix-index entries whose pages
         # died are invalidated — unless the host tier catches them
         # first (ISSUE 20: a sole-reader prefix migrates instead of
-        # dying, so the index survives HBM reclaim).
-        self._spill_dying_prefixes(slot, owner=req.rid)
+        # dying, so the index survives HBM reclaim). A step still in
+        # flight may write one more row into these pages: whatever is
+        # given them next is enqueued behind it.
+        self._spill_dying_prefixes(slot, owner=live.req.rid)
         self.engine.allocator.free_slot(slot)
         if self._memledger is not None:
-            self._memledger.forget(req.rid)
+            self._memledger.forget(live.req.rid)
         self.free.append(slot)
         self._temp[slot] = 0.0
         self._topk[slot] = 0
+        live.released = True
+
+    def _token_landed(self, slot: int, live: _Live, now: float) -> None:
+        """``live``'s newest token has reached the host at ``now``:
+        finish the request if that token is its EOS (found out a tick
+        after the step ran, so one more token may be in flight: it is
+        dropped) or the last one its count allowed."""
+        req = live.req
+        eos = req.eos_id is not None and live.tokens[-1] == req.eos_id
+        if not eos and not (
+            live.released and len(live.tokens) >= live.issued
+        ):
+            return
+        if not live.released:
+            self._release(slot, live)
+        self._complete(slot, live, now)
+
+    def _complete(self, slot: int, live: _Live, now: float) -> None:
+        """Write ``live``'s ``Completed``: its last token is on the host."""
+        req = live.req
+        tok = live.tokens[-1]
+        live.done = True
+        if self.live.get(slot) is live:
+            del self.live[slot]
+        full = len(req.prompt) + len(live.tokens) - 1 >= self.engine.max_len
         obs.span_at(
             "request_latency", live.submit_t, now, **self._span_attrs(req)
         )
@@ -1246,81 +1457,117 @@ class Server:
                     dur_s=now - t0, drafted=k, t=now,
                     accepted=int(n_acc[slot]), emitted=int(n_emit[slot]),
                 )
-        for slot in list(self.live):
+        # How many tokens the step yields is known only now, so nothing
+        # of this tick was enqueued ahead: counts and values coincide.
+        self.steps_drained += 1
+        obs.counter("serve_steps_drained")
+        for slot, live in list(self.live.items()):
             n = int(n_emit[slot])
-            self.live[slot].tokens.extend(
-                int(t) for t in emit[slot, :n]
-            )
-            self.live[slot].last_touch = self.tick
-            self._maybe_retire(slot, now)
+            live.tokens.extend(int(t) for t in emit[slot, :n])
+            live.issued = len(live.tokens)
+            live.last_touch = self.tick
+            if self._spent(live):
+                self._release(slot, live)
+            self._token_landed(slot, live, now)
 
     def _decode_tick(self) -> None:
+        """Enqueue this tick's decode step from counts, then fetch the
+        one the last tick enqueued: the device has the next step queued
+        while the host waits for, and works on, the last one's tokens.
+        The ``decode`` span covers both and ends with the older step's
+        tokens on the host (``rids`` lists the requests they belong to;
+        ``active`` and ``cache_rows`` describe the step enqueued); the
+        ``retire`` span after it settles those tokens and gives back the
+        slots that the new step finishes by count."""
+        decoding = self._decoding()
         if self._spec:
-            self._spec_tick()
+            if decoding:
+                self._spec_tick()
             return
-        active = np.zeros((self.engine.slots,), bool)
-        for slot in self.live:
-            active[slot] = True
-        # This tick appends one K/V row per live slot at its fill
-        # position — a slot whose fill still lands in a SHARED page
-        # (full-prompt prefix reuse of a partial last page) must
-        # copy it out first; later ticks find the page private and
-        # this is a no-op refcount probe.
-        for slot, live in self.live.items():
-            pair = self.engine.allocator.cow_before_write(
-                slot, live.cache_fill()
-            )
-            if pair is not None:
-                self.engine.copy_page(*pair)
-                if self._ledger is not None:
-                    self._ledger.event(
-                        live.req.rid, "cow_copy", tick=self.tick,
-                        src=pair[0], dst=pair[1], phase="decode",
-                    )
+        older = self._older("decode")
+        if not decoding and not older:
+            return
         attrs = {}
+        if decoding:
+            active = np.zeros((self.engine.slots,), bool)
+            # This tick appends one K/V row per live slot at its fill
+            # position — a slot whose fill still lands in a SHARED page
+            # (full-prompt prefix reuse of a partial last page) must
+            # copy it out first; later ticks find the page private and
+            # this is a no-op refcount probe.
+            for slot, live in decoding:
+                active[slot] = True
+                pair = self.engine.allocator.cow_before_write(
+                    slot, live.cache_fill()
+                )
+                if pair is not None:
+                    self.engine.copy_page(*pair)
+                    if self._ledger is not None:
+                        self._ledger.event(
+                            live.req.rid, "cow_copy", tick=self.tick,
+                            src=pair[0], dst=pair[1], phase="decode",
+                        )
+            # Live rows BEFORE the step: what the kernel reads.
+            lens = np.asarray([live.cache_fill() for _, live in decoding])
         if obs.enabled():  # a disabled span costs a tick nothing
             attrs = dict(
-                active=len(self.live), attention=self._attn_mode,
-                sampler=self._sampler,
-                rids=[live.req.rid for live in self.live.values()],
-                # Live rows BEFORE the tick: what the kernel reads.
-                cache_rows=sum(
-                    live.cache_fill() for live in self.live.values()
-                ),
-                **self._kv_attrs, **self._decode_tiling,
+                active=len(decoding),
+                rids=[
+                    live.req.rid for _, live in self._in_flight[0].takers
+                ] if older else [],
+                cache_rows=int(lens.sum()) if decoding else 0,
+                **self._labels["decode"],
             )
         t0 = time.perf_counter()
         with obs.span("decode", **attrs):
-            toks = self.engine.decode(active, self._temp, self._topk)
-        now = time.perf_counter()
+            if decoding:
+                self._enqueued(_Step(
+                    "decode",
+                    self.engine.decode_dispatch(
+                        active, self._temp, self._topk
+                    ),
+                    decoding, self.tick, lens=lens,
+                ))
+                for _, live in decoding:
+                    live.issued += 1
+                    live.last_touch = self.tick
+            landed = self._land("decode", self.tick)
         with obs.span("retire"):
-            self._account_decode(active, toks, t0, now)
+            for step in landed:
+                self._settle_decode(step, t0)
+            for slot, live in decoding:
+                if not live.released and self._spent(live):
+                    self._release(slot, live)
 
-    def _account_decode(self, active, toks, t0: float, now: float) -> None:
-        """What a decode tick owes after its tokens are on the host:
+    def _settle_decode(self, step: _Step, t0: float) -> None:
+        """What a decode step owes once its tokens are on the host:
         counters, the rolling windows, the request ledger, the achieved
-        HBM bytes and the utilization watch, then each slot's token and
-        its retirement. The ``retire`` span times it."""
+        HBM bytes and the utilization watch, then each slot's token and,
+        with it, the end of a request it finishes. ``t0`` is when the
+        phase that fetched it began: with a step always queued behind
+        the one being fetched, ``t_land - t0`` is the device's period."""
+        now = step.t_land
+        dur = now - t0
+        # A request an EOS has stopped since was computed one token too
+        # many: the token is dropped here and counted nowhere.
+        kept = [(slot, live) for slot, live in step.takers if not live.done]
         if self.sentinel is not None:
-            self.sentinel.observe_phases(self.tick, decode=now - t0)
-        obs.counter("serve_tokens", float(active.sum()))
+            self.sentinel.observe_phases(self.tick, decode=dur)
+        obs.counter("serve_tokens", float(len(kept)))
         if self.stream is not None:
-            self.stream.inc("serve_tokens", float(active.sum()))
+            self.stream.inc("serve_tokens", float(len(kept)))
             # The policy projector's decode-tick term (ISSUE 12).
-            self.stream.observe("decode_tick", now - t0)
+            self.stream.observe("decode_tick", dur)
         if self._ledger is not None:
             # Decode-tick MEMBERSHIP: the tick wall is every resident
             # request's latency cost (the tick is shared; the slot is
             # occupied for all of it) — decode_compute_share_s.
-            n_live = int(active.sum())
-            for live in self.live.values():
+            for _, live in kept:
                 self._ledger.event(
-                    live.req.rid, "decode_tick", tick=self.tick,
-                    dur_s=now - t0, active=n_live, t=now,
+                    live.req.rid, "decode_tick", tick=step.tick,
+                    dur_s=dur, active=len(step.takers), t=now,
                 )
-        lens = np.asarray(
-            [live.cache_fill() for live in self.live.values()]
-        )
+        lens = step.lens
         if self._attn_mode == "kernel" and obs.enabled():
             # Read by the recorder alone, so counted only for one.
             # Cache tiles the length-aware kernel skipped this tick —
@@ -1361,20 +1608,22 @@ class Server:
                 self.stream.inc("decode_hbm_bytes", ach)
                 if flops:
                     self.stream.inc("decode_flops", flops)
-            if self._util_watch is not None and now > t0:
+            if self._util_watch is not None and dur > 0:
                 self._util_watch.observe(
-                    "decode_hbm_gbps", self.tick, ach / (now - t0) / 1e9
+                    "decode_hbm_gbps", self.tick, ach / dur / 1e9
                 )
-        for slot in list(self.live):
-            self.live[slot].tokens.append(int(toks[slot]))
-            self.live[slot].last_touch = self.tick
-            self._maybe_retire(slot, now)
+        for slot, live in kept:
+            live.tokens.append(int(step.toks[slot]))
+            self._token_landed(slot, live, now)
 
     def _pending(self) -> bool:
         """Work outstanding: queued (FIFO deque or policy tiers),
-        mid-prefill (chunking) or live — the loop-termination and
-        truncation predicate."""
-        return bool(self._qdepth() or self.prefilling or self.live)
+        mid-prefill (chunking), live, or enqueued with its tokens still
+        to fetch — the loop-termination and truncation predicate."""
+        return bool(
+            self._qdepth() or self.prefilling or self.live
+            or self._in_flight
+        )
 
     def _kv_gauges(self) -> None:
         """Cache-memory efficiency gauges (ISSUE 7 satellite):
@@ -1383,7 +1632,7 @@ class Server:
         for), plus pool occupancy and shared-page count. Recorder
         gauges AND the rolling stream windows."""
         kv_tokens = float(
-            sum(l.cache_fill() for l in self.live.values())
+            sum(l.cache_fill() for _, l in self._decoding())
             + sum(l.base for l in self.prefilling.values())
         )
         obs.gauge("kv_tokens_cached", kv_tokens)
@@ -1476,7 +1725,7 @@ class Server:
         alloc = self.engine.allocator
         pb = self.engine.page_bytes
         holders = []
-        for slot, live in list(self.live.items()) + list(
+        for slot, live in self._decoding() + list(
             self.prefilling.items()
         ):
             owned, shared = alloc.slot_page_stats(slot)
@@ -1545,18 +1794,32 @@ class Server:
         return sole, dead
 
     def _run_tick(self) -> None:
-        """One loop iteration: admit, prefill chunk, gauges, decode, SLO
-        evaluation.
+        """One loop iteration: admit, enqueue this tick's steps (the
+        prefill chunk, then the decode step) from counts and fetch the
+        last tick's, gauges, SLO evaluation.
+
+        One tick of steps is kept in flight: what a step needs of the
+        host (block tables, ``active``, the chunk's rows) follows from
+        counts the host knows before the step runs, and the token a
+        decode step feeds on stays on the device, so tick n+1 is enqueued
+        before tick n's tokens are fetched and the device does not wait
+        for the host. What needs values waits a tick: ``_Live.tokens``,
+        first-token and finish times, ``Completed``, an EOS (the token
+        computed behind it is dropped). A speculative engine's steps
+        decide their own counts, so its ticks fetch what they enqueue.
 
         Spanned as a tree, by time on this thread: ``tick`` round the
-        whole, and inside it, in the order they run, ``admit``,
-        ``prefill`` (the chunk), ``gauges``, ``decode`` and ``retire`` (the
-        accounting after the decode call). The engine's
-        ``*_dispatch`` / ``*_fetch`` spans lie inside ``prefill`` and
-        ``decode``; its page copies (``copy_page``, ``restore_page``,
-        ``spill_page``, ``drain_spills``) wherever they are enqueued.
+        whole (``in_flight``: the steps unfetched as it begins), and
+        inside it, in the order they run, ``admit``, ``prefill`` (the
+        chunk), ``gauges``, ``decode`` and ``retire`` (the accounting
+        after the decode phase). The engine's ``*_dispatch`` span of this
+        tick's step and then the ``*_fetch`` span of the last tick's lie
+        inside ``prefill`` and ``decode``; a drain (:meth:`_drain`) is a
+        ``prefill`` or ``decode`` span with a fetch alone inside it. The
+        engine's page copies (``copy_page``, ``restore_page``,
+        ``spill_page``, ``drain_spills``) lie wherever they are enqueued.
         ``tick`` less its children is the loop's own remainder."""
-        with obs.span("tick", tick=self.tick):
+        with obs.span("tick", tick=self.tick, in_flight=len(self._in_flight)):
             if self._host_tier:
                 # Land last tick's dispatched spills (ISSUE 20): the
                 # device→host copies ran under the decode tick they were
@@ -1564,13 +1827,19 @@ class Server:
                 # materializing here costs only the memcpy, never the
                 # wait.
                 self.engine.drain_spills()
+            # Requests whose slot went back last tick live on in the
+            # steps that hold their last tokens.
+            for slot in [s for s, l in self.live.items() if l.released]:
+                del self.live[slot]
             with obs.span("admit"):
                 self._admit()
             self._prefill_chunk_tick()
             with obs.span("gauges"):
                 self._tick_gauges()
-            if self.live:
-                self._decode_tick()
+            self._decode_tick()
+            if self._in_flight and not (self.prefilling or self._decoding()):
+                # Nothing is left that could be enqueued behind them.
+                self._drain()
             if self.slo is not None:
                 transitions = self.slo.evaluate(tick=self.tick)
                 if (
@@ -1592,7 +1861,7 @@ class Server:
     def _tick_gauges(self) -> None:
         """Occupancy, queue depth and the cache-memory gauges of a tick,
         after its admissions and before its decode."""
-        busy = len(self.live) + len(self.prefilling)
+        busy = len(self._decoding()) + len(self.prefilling)
         self._concurrency_peak = max(self._concurrency_peak, busy)
         occupancy = busy / self.engine.slots
         self._occupancy_sum += occupancy
@@ -1617,7 +1886,11 @@ class Server:
         (then return ALL completions so far, in finish order). Hitting
         ``max_ticks`` with work still queued/live sets the
         ``truncated`` flag ``stats()`` reports — partial completions
-        must not read as a finished run."""
+        must not read as a finished run. A run that ends because nothing
+        is pending has fetched every step it enqueued; one that stops at
+        ``max_ticks`` returns with its last tick's steps in flight, and
+        the next call (or a caller that ticks with ``max_ticks=tick +
+        1``) fetches them behind the steps it enqueues."""
         # Each call is a fresh verdict: a prior max_ticks-capped run
         # (e.g. a staggered prime before more submits) must not latch
         # ``truncated`` onto a follow-up run that drains everything.
@@ -1770,7 +2043,7 @@ class Server:
                         })
         if pb:
             alloc = self.engine.allocator
-            for slot, live in self.live.items():
+            for slot, live in self._decoding():
                 owned, _ = alloc.slot_page_stats(slot)
                 out.append({
                     "kind": "idle_tail",
@@ -1868,7 +2141,7 @@ class Server:
         if self.engine.page_bytes:
             alloc = self.engine.allocator
             pb = self.engine.page_bytes
-            for slot, live in list(self.live.items()) + list(
+            for slot, live in self._decoding() + list(
                 self.prefilling.items()
             ):
                 owned, shared = alloc.slot_page_stats(slot)
@@ -1924,6 +2197,13 @@ class Server:
             "truncated": self._truncated,
             # Most requests simultaneously resident (live + prefilling).
             "concurrency_peak": self._concurrency_peak,
+            # How often a step was enqueued behind one still unfetched,
+            # and how often a fetch had nothing enqueued behind it (the
+            # first tick, an idle server, a preemption, a speculative
+            # engine): the share overlapped is how often the device had
+            # work queued while the host handled tokens.
+            "steps_overlapped": self.steps_overlapped,
+            "steps_drained": self.steps_drained,
         }
         # The cache's wire dtype (ISSUE 15): what a cached row occupies
         # HBM as — "int8" on the quantized engines, the model dtype

@@ -360,8 +360,10 @@ class TestServeObservability:
             assert phases[name]["count"] == len(PROMPTS)
             assert phases[name]["p50_s"] <= phases[name]["p95_s"]
         # One prefill span per admission BATCH (continuous batching
-        # coalesces same-tick admits), one decode span per tick.
-        assert 1 <= phases["prefill"]["count"] <= server.admissions
+        # coalesces same-tick admits) and, since a chunk's tokens are
+        # fetched the tick after it is enqueued, at most one more that
+        # holds the fetch alone; one decode span per tick.
+        assert 1 <= phases["prefill"]["count"] <= 2 * server.admissions
         assert phases["decode"]["count"] >= max(MAX_NEW) - 1
         # TTFT <= end-to-end latency, per construction of the intervals.
         assert (

@@ -2,8 +2,9 @@
 
 Host: a serving tick is one ``tick`` span with its phases inside it, in
 the order they run, and the engine's ``*_dispatch`` / ``*_fetch`` inside
-``decode`` and ``prefill``; with no recorder installed a tick computes no
-span argument. Device: the jitted steps lower to text that holds each
+``decode`` and ``prefill``: the dispatch of this tick's step, then the
+fetch of the last tick's (ISSUE 29), and a drain as a span with a fetch
+alone; with no recorder installed a tick computes no span argument. Device: the jitted steps lower to text that holds each
 scope name, and every ``pallas_call`` carries its ``name``.
 """
 
@@ -76,30 +77,69 @@ def test_every_tick_is_one_span_tree(params, kind):
              if k == "X"]
     ticks = [s for s in spans if s[0] == "tick"]
     assert [s[3]["tick"] for s in ticks] == list(range(server.tick))
+    # One tick of steps in flight: none as the first tick begins, some
+    # as every tick after it does, none when ``run()`` returns.
+    assert ticks[0][3]["in_flight"] == 0
+    assert all(t[3]["in_flight"] >= 1 for t in ticks[1:])
+    assert not server._in_flight
     phases = ("admit", "prefill", "gauges", "decode", "retire")
-    decodes = 0
+    decodes = drains = 0
+    fetched = {"prefill": 0, "decode": 0}
+    dispatched = {"prefill": 0, "decode": 0}
     for tick in ticks:
         kids = sorted((s for s in spans if s[0] in phases and _inside(s, tick)),
                       key=lambda s: s[1])
-        names = [s[0] for s in kids]
-        assert names.count("admit") == names.count("gauges") == 1
-        # Siblings, one after another, in the order they run.
-        assert names == [p for p in phases if p in names]
+        # Siblings, one after another.
         assert all(a[2] <= b[1] for a, b in zip(kids, kids[1:]))
+        # The phases in the order they run; then, where nothing is left
+        # to enqueue, the drain: spans with a fetch alone, oldest first.
+        first_drain = next((i for i, s in enumerate(kids)
+                            if s[3].get("drained")), len(kids))
+        names = [s[0] for s in kids[:first_drain]]
+        assert names.count("admit") == names.count("gauges") == 1
+        assert names == [p for p in phases if p in names]
+        tail = [s for s in kids[first_drain:] if s[0] != "retire"]
+        assert all(s[3].get("drained") for s in tail)
+        drains += len(tail)
         for outer in ("decode", "prefill"):
             for span in (s for s in kids if s[0] == outer):
-                inner = sorted(
+                inner = [s[0] for s in sorted(
                     (s for s in spans if _inside(s, span)
                      and s[0] in (outer + "_dispatch", outer + "_fetch")),
-                    key=lambda s: s[1])
-                assert [s[0] for s in inner] == [
-                    outer + "_dispatch", outer + "_fetch"]
-        for d in (s for s in kids if s[0] == "decode"):
+                    key=lambda s: s[1])]
+                # Enqueue this tick's step, then fetch the last tick's.
+                assert inner in (
+                    [outer + "_dispatch", outer + "_fetch"],
+                    [outer + "_dispatch"], [outer + "_fetch"]), inner
+                if span[3].get("drained"):
+                    assert inner == [outer + "_fetch"]
+                dispatched[outer] += inner.count(outer + "_dispatch")
+                fetched[outer] += inner.count(outer + "_fetch")
+        for d in (s for s in kids[:first_drain] if s[0] == "decode"):
             decodes += 1
-            assert d[3]["cache_rows"] >= d[3]["active"] >= 1
-            assert len(d[3]["rids"]) == d[3]["active"]
+            # ``active`` and ``cache_rows`` describe the step enqueued,
+            # ``rids`` the requests whose tokens the span's end brings.
+            assert d[3]["cache_rows"] >= d[3]["active"] >= 0
             assert "retire" in names
-    assert decodes >= 2
+    assert decodes >= 2 and drains >= 1
+    # Every step enqueued is fetched, once (a chunk in which no slot
+    # finished its prompt too: the wait holds the host a tick ahead).
+    assert fetched["decode"] == dispatched["decode"] >= 2
+    assert fetched["prefill"] == dispatched["prefill"] >= 1
+    # The first decode step has nothing older to fetch; every later one
+    # brings the tokens of the requests the step before it served.
+    plain = [s for s in spans if s[0] == "decode" and not s[3].get("drained")]
+    assert plain[0][3]["rids"] == [] and plain[0][3]["active"] >= 1
+    assert all(len(b[3]["rids"]) == a[3]["active"]
+               for a, b in zip(plain, plain[1:]))
+    # The two counters of the overlap: how often it engaged, how often not.
+    steps = dispatched["decode"] + dispatched["prefill"]
+    overlapped = rec.counter_total("serve_steps_overlapped")
+    drained = rec.counter_total("serve_steps_drained")
+    assert overlapped == server.steps_overlapped >= 1
+    assert drained == server.steps_drained >= 1
+    assert overlapped < steps and drained < steps
+    assert server.stats()["steps_overlapped"] == server.steps_overlapped
 
 
 @pytest.mark.parametrize("kind", sorted(ENGINES))
