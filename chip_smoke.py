@@ -30,6 +30,12 @@ raises — there is no handler that lets the run end 0):
                  composition, and a prompt served in chunks and then decoded
                  through the latent page pool against the model's plain
                  forward, logits both times; ``ok`` only if both hold.
+- ``gdn_kernels`` the gated delta rule's chunk and step kernels
+                 (``ops/gated_delta.py``) against their lax twins, and the
+                 paged attention kernel at 30 heads of 128 against the
+                 gather-dense reference, at the shapes of
+                 ``benchmark/configs/olmo-hybrid-7b-8of32.json``; each
+                 kernel's call timed. ``--phases`` picks one-chip phases.
 - ``dp4``        (``--chips 4`` only, and then the only phase) ZeRO-1 data
                  parallel over four chips vs the same global batch and seed
                  on one of them; then ``grad_sync=ring`` and ``ring_q8``.
@@ -68,6 +74,11 @@ RING_TIMEOUT_S = 300.0  # the ring kernels' protocol has only run interpreted
 # the worst of 704 positions; my chip run, PR 26, call 7).
 TOL_X4_KERNEL_VS_GATHER = 0.03
 TOL_X4_PAGED_VS_PLAIN = 0.15
+# The gated delta rule's kernels against their lax twins on bf16 operands:
+# outputs of order 0.1-1, states of order 1 after a chunk from a random
+# state. The attention kernel at 30 heads of 128 against the gather-dense
+# reference: GPT-2's tolerance, same weights and dtype, other op order.
+TOL_GDN_KERNEL_VS_TWIN = 0.02
 
 FULL = dict(
     model=["--num-layers", "12", "--d-model", "768", "--num-heads", "12",
@@ -84,6 +95,11 @@ FULL = dict(
            "--kv-page-size", "256", "--prefill-chunk", "512", "--requests",
            "3", "--prompt-len", "600", "--max-new-tokens", "8"],
     xing4_probe=(700, 4),  # a prompt of two chunks, then decode ticks
+    # The olmoh cell's shapes: heads, key and value widths, a chunk's
+    # tokens and participants, the slots of a tick, a slot's positions
+    # and the page.
+    gdn=dict(h=30, dk=96, dv=192, chunk=512, seqs=2, slots=64,
+             positions=4096, page=128, interpret=None),
 )
 TINY = dict(
     model=["--num-layers", "2", "--d-model", "64", "--num-heads", "4",
@@ -99,6 +115,8 @@ TINY = dict(
            "--kv-page-size", "16", "--prefill-chunk", "16", "--requests",
            "3", "--prompt-len", "40", "--max-new-tokens", "4"],
     xing4_probe=(27, 3),
+    gdn=dict(h=3, dk=12, dv=24, chunk=100, seqs=2, slots=3, positions=256,
+             page=16, interpret=True),
 )
 
 
@@ -619,7 +637,7 @@ def _xing4_paged_vs_plain(engine, seed: int, prompt: int, ticks: int) -> tuple:
         rows = np.zeros((engine.slots, width), bool)
         rows[0, :n] = True
         lengths = jnp.zeros((engine.slots,), jnp.int32).at[0].set(base)
-        logits, (k, v) = forward(
+        logits, (k, v, _) = forward(
             engine.params, jnp.asarray(tokens),
             PagedKVCache(k=cache.k, v=cache.v, lengths=lengths),
             jnp.asarray(rows))
@@ -670,6 +688,91 @@ def phase_serve_xing4(sz, seed: int, rehearse: bool) -> None:
     assert logit_err[0] <= TOL_X4_PAGED_VS_PLAIN, logit_err
     del engine
     gc.collect()
+
+
+def _median_ms(fn, *args, reps: int = 5) -> float:
+    """Median wall time of ``fn(*args)`` to completion, after one call
+    that compiles."""
+    import jax
+
+    jax.block_until_ready(fn(*args))
+    times = []
+    for _ in range(reps):
+        t0 = time.perf_counter()
+        jax.block_until_ready(fn(*args))
+        times.append(time.perf_counter() - t0)
+    return round(1e3 * sorted(times)[len(times) // 2], 3)
+
+
+def phase_gdn_kernels(sz, seed: int, rehearse: bool) -> None:
+    """The gated delta rule's two kernels against their lax twins, and the
+    paged attention kernel at 128-wide heads against the gather-dense
+    reference, at the olmoh cell's shapes; each kernel's call timed."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from mpit_tpu.models.gpt2 import paged_cached_attention
+    from mpit_tpu.ops import gated_delta as gd
+    from mpit_tpu.ops.decode_attention import flash_paged_decode_attention
+
+    g = sz["gdn"]
+    h, dk, dv, interp = g["h"], g["dk"], g["dv"], g["interpret"]
+    dt = jnp.float32 if rehearse else jnp.bfloat16
+
+    def rule_inputs(key, lead):
+        ks = jax.random.split(key, 6)
+        unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+        q = unit(jax.random.normal(ks[0], (*lead, h, dk))) * dk ** -0.5
+        k = unit(jax.random.normal(ks[1], (*lead, h, dk)))
+        v = jax.random.normal(ks[2], (*lead, h, dv))
+        gl = -jax.random.uniform(ks[3], (*lead, h), minval=1e-4, maxval=0.1)
+        beta = jax.random.uniform(ks[4], (*lead, h), minval=0.0, maxval=2.0)
+        state = jax.random.normal(ks[5], (lead[0], h, dk, dv))
+        return q.astype(dt), k.astype(dt), v.astype(dt), gl, beta, state
+
+    key = jax.random.key(seed)
+    out = {}
+    chunk_k = jax.jit(lambda *a: gd.gdn_chunk(*a, interpret=interp))
+    args = rule_inputs(jax.random.fold_in(key, 1), (g["seqs"], g["chunk"]))
+    got, want = chunk_k(*args), jax.jit(gd.gdn_chunk_lax)(*args)
+    out["chunk_o_err"], out["chunk_state_err"] = (
+        _max_abs(got[0], want[0]), _max_abs(got[1], want[1]))
+    out["chunk_kernel_ms"] = _median_ms(chunk_k, *args)
+    # The step's kernel updates the state in place: a fresh copy a call.
+    step_k = jax.jit(lambda *a: gd.gdn_step(*a, interpret=interp))
+    args = rule_inputs(jax.random.fold_in(key, 2), (g["slots"],))
+    got, want = step_k(*args), jax.jit(gd.gdn_step_lax)(*args)
+    out["step_o_err"], out["step_state_err"] = (
+        _max_abs(got[0], want[0]), _max_abs(got[1], want[1]))
+    out["step_kernel_ms"] = _median_ms(step_k, *args)
+    out["step_twin_ms"] = _median_ms(jax.jit(gd.gdn_step_lax), *args)
+    out["step_state_mb"] = round(args[5].nbytes / 1e6, 1)
+    # Attention at 30 heads of 128: a tick's row and a chunk's 64.
+    b, hd = g["slots"], 128 if not rehearse else 16
+    pps = g["positions"] // g["page"]
+    ks = jax.random.split(jax.random.fold_in(key, 3), 3)
+    pool = lambda k: jax.random.normal(
+        k, (b * pps, g["page"], h * hd), dt)
+    k_pool, v_pool = pool(ks[0]), pool(ks[1])
+    table = jnp.asarray(np.random.RandomState(seed).permutation(
+        b * pps).reshape(b, pps), jnp.int32)
+    lengths = jnp.asarray(np.random.RandomState(seed + 1).randint(
+        g["positions"] // 4, g["positions"] - 64, size=b), jnp.int32)
+    for t in (1, 64):
+        q = jax.random.normal(ks[2], (b, t, h, hd), dt)
+        kern = jax.jit(lambda *a: flash_paged_decode_attention(
+            *a, interpret=interp))
+        args = (q, k_pool, v_pool, lengths, table)
+        if t == 1 or rehearse:  # the dense view of 64 x 4,096 rows is large
+            out[f"attn_t{t}_err"] = _max_abs(
+                kern(*args), jax.jit(paged_cached_attention)(*args))
+        out[f"attn_t{t}_kernel_ms"] = _median_ms(kern, *args)
+    out["attn_rows_visited"] = int(lengths.sum())
+    emit("gdn_kernels", **out, shapes=g, tolerance=TOL_GDN_KERNEL_VS_TWIN)
+    for name, err in out.items():
+        if name.endswith("_err"):
+            assert err <= TOL_GDN_KERNEL_VS_TWIN, (name, err)
 
 
 def _holds_a_shard_each(state, devices) -> dict:
@@ -761,7 +864,15 @@ def main(argv=None) -> int:
     parser.add_argument("--chips", type=int, choices=(1, 4), default=1)
     parser.add_argument("--seed", type=int, default=0)
     parser.add_argument("--rehearse", action="store_true")
+    # One-chip phases to run, by name (all: every one, in this order).
+    one_chip = ("train", "serve", "serve_xing4", "gdn_kernels")
+    parser.add_argument("--phases", default="all",
+                        help="comma list of " + ", ".join(one_chip))
     args = parser.parse_args(argv)
+    phases = one_chip if args.phases == "all" else tuple(
+        args.phases.split(","))
+    if set(phases) - set(one_chip):
+        parser.error(f"--phases: expected some of {one_chip}")
     if args.rehearse:
         # Sizes and the platform, before jax starts.
         os.environ["JAX_PLATFORMS"] = "cpu"
@@ -774,11 +885,16 @@ def main(argv=None) -> int:
     if args.chips == 4:
         ok = phase_dp4(sz, args.seed, args.rehearse)
     else:
-        phase_train(sz, args.seed, args.rehearse)
-        probe = phase_serve(sz, args.seed, args.rehearse)
-        phase_serve_int8(sz, args.seed, args.rehearse, probe)
-        del probe
-        phase_serve_xing4(sz, args.seed, args.rehearse)
+        if "train" in phases:
+            phase_train(sz, args.seed, args.rehearse)
+        if "serve" in phases:
+            probe = phase_serve(sz, args.seed, args.rehearse)
+            phase_serve_int8(sz, args.seed, args.rehearse, probe)
+            del probe
+        if "serve_xing4" in phases:
+            phase_serve_xing4(sz, args.seed, args.rehearse)
+        if "gdn_kernels" in phases:
+            phase_gdn_kernels(sz, args.seed, args.rehearse)
         ok = True
     if args.rehearse:
         # A rehearsal is never a pass: it says what it ran on, and 3.

@@ -34,7 +34,7 @@ import jax.numpy as jnp
 from flax.linen.dtypes import promote_dtype
 from jax import lax
 
-from mpit_tpu.models.serving import CacheLayout, ServeModel
+from mpit_tpu.models.serving import CacheLayout, PageLayer, ServeModel
 from mpit_tpu.ops.decode_attention import paged_write_pages, writes_by_pages
 from mpit_tpu.ops.kv_quant import (
     QuantizedKV,
@@ -573,8 +573,8 @@ class GPT2ServeModel(ServeModel):
     def cache_layout(self) -> CacheLayout:
         cfg = self.cfg
         width = cfg.num_heads * cfg.head_dim
-        return CacheLayout(width, width, cfg.num_layers, cfg.dtype,
-                           scale_width=cfg.num_heads)
+        return CacheLayout((PageLayer(width, width),) * cfg.num_layers,
+                           cfg.dtype, scale_width=cfg.num_heads)
 
     def kv_row_bytes(self, dtype) -> float:
         from mpit_tpu.ops.kv_quant import kv_wire_bytes_per_row
@@ -605,16 +605,16 @@ class GPT2ServeModel(ServeModel):
         return GPT2ServeModel(dataclasses.replace(self.cfg, quant_matmul_fn=fn))
 
     def forward_paged(self, params, tokens, cache, block_tables, write_valid,
-                      *, return_hidden, row_valid=None):
-        del row_valid  # every row of the batch is computed
-        out, kv = self._module.apply(
+                      *, return_hidden, row_valid=None, slot_index=None):
+        del row_valid, slot_index  # every row is computed; no slot's state
+        out, (k, v) = self._module.apply(
             {"params": params},
             tokens,
             paged_cache=(cache.k, cache.v, cache.lengths,
                          block_tables, write_valid),
             return_hidden=return_hidden,
         )
-        return out, kv, None
+        return out, (k, v, cache.state), None
 
     def head_table(self, params):
         return params["head"] if "head" in params else params["wte"]

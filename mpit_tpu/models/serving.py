@@ -5,15 +5,19 @@ constructor's signature, ``GPT2(cfg).apply`` inside the jitted steps, a
 cache row of ``heads x head_dim``. It now takes a :class:`ServeModel` and
 asks it, and nothing else, for what differs between families:
 
-- the **cache row layout** of a layer (:meth:`ServeModel.cache_layout`):
-  the widths of the two buffers a page pool keeps a layer, and their
-  dtype. GPT-2 caches a key and a value of ``heads x head_dim`` each; a
-  latent-attention model caches one latent row all heads share and the
-  rotary part of its key;
+- the **cache layout** (:meth:`ServeModel.cache_layout`): for each of
+  the model's layers, whether it keeps pages (the widths of the two
+  buffers a page pool keeps it) or a fixed state a slot (the shapes and
+  dtypes of what a sequence keeps between steps). GPT-2 caches a key and
+  a value of ``heads x head_dim`` each; a latent-attention model caches
+  one latent row all heads share and the rotary part of its key; a
+  linear-attention layer keeps a matrix a head and the tail of its
+  convolution, whatever the sequence's length;
 - the **forward through the cache**: embed, then per layer attention
   given that layer's cache handle and the MLP, then the final norm
   (:meth:`ServeModel.forward_paged`). It returns the hidden states the
-  head samples from, the layers' updated buffers, and whatever the
+  head samples from, the layers' updated buffers (pages, and the state
+  pool where the layout has one), and whatever the
   family counts a step (``aux``; ``None`` for a family that counts
   nothing, and the step's outputs are then as they always were);
 - the **head** (:meth:`ServeModel.head_table`): the ``[vocab, d]`` table
@@ -30,23 +34,75 @@ no family.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any
 
-__all__ = ["CacheLayout", "ServeModel", "as_serve_model"]
+import numpy as np
+
+__all__ = ["CacheLayout", "PageLayer", "StateLayer", "ServeModel",
+           "as_serve_model"]
+
+
+@dataclasses.dataclass(frozen=True)
+class PageLayer:
+    """A layer that keeps pages: ``k_width`` and ``v_width`` values a
+    cached position in the pool's two seats (what they hold is the
+    family's business)."""
+
+    k_width: int
+    v_width: int
+
+
+@dataclasses.dataclass(frozen=True)
+class StateLayer:
+    """A layer that keeps a fixed state a slot, whatever the sequence's
+    length: ``buffers`` is ``((name, shape, dtype), ...)`` of what ONE
+    slot keeps between steps; the pool holds each as ``[slots, *shape]``."""
+
+    buffers: tuple
+
+    def slot_bytes(self) -> int:
+        return sum(math.prod(shape) * np.dtype(dtype).itemsize
+                   for _, shape, dtype in self.buffers)
 
 
 @dataclasses.dataclass(frozen=True)
 class CacheLayout:
-    """A layer's cache row: ``k_width`` and ``v_width`` values a cached
-    position in the pool's two seats (what they hold is the family's
-    business), ``dtype`` unless the engine pins another, and for an int8
-    pool the scale columns a row."""
+    """What a sequence keeps, a model layer at a time: ``layers[i]`` is a
+    :class:`PageLayer` or a :class:`StateLayer`. ``dtype`` is the page
+    rows' unless the engine pins another, ``scale_width`` the scale
+    columns a row of an int8 pool."""
 
-    k_width: int
-    v_width: int
-    num_layers: int
+    layers: tuple
     dtype: Any
     scale_width: int = 1
+
+    @property
+    def page_layers(self) -> tuple:
+        return tuple(l for l in self.layers if isinstance(l, PageLayer))
+
+    @property
+    def state_layers(self) -> tuple:
+        return tuple(l for l in self.layers if isinstance(l, StateLayer))
+
+    @property
+    def prefix_shareable(self) -> bool:
+        """Whether pages mapped from another sequence's prefix are all
+        the sequence needs: not where a layer's state at that boundary
+        would have to be restored too."""
+        return not self.state_layers
+
+    def page_bytes(self, page_size: int, dtype, quantized: bool) -> int:
+        """One page across every page-holding layer and both seats, as
+        the pool stores it (an int8 pool: payload and float32 scales)."""
+        item = 1 if quantized else np.dtype(dtype).itemsize
+        scales = 2 * 4 * self.scale_width if quantized else 0
+        return page_size * sum(
+            (l.k_width + l.v_width) * item + scales for l in self.page_layers)
+
+    def state_slot_bytes(self) -> int:
+        """What one slot keeps in the state pool, every layer."""
+        return sum(l.slot_bytes() for l in self.state_layers)
 
 
 class ServeModel:
@@ -76,6 +132,10 @@ class ServeModel:
     def check_shipment(self) -> None:
         """Raise if cache rows of this family cannot be exported."""
 
+    def check_preemption(self) -> None:
+        """Raise if a live slot of this family cannot be evicted and
+        resumed (what it keeps beside pages would be lost)."""
+
     # -- the injected kernels -------------------------------------------------
     def with_decode_attention(self, *, block_k: int, interpret,
                               page_size: int) -> "ServeModel":
@@ -98,9 +158,14 @@ class ServeModel:
 
     # -- the forward ------------------------------------------------------------
     def forward_paged(self, params, tokens, cache, block_tables, write_valid,
-                      *, return_hidden, row_valid=None):
-        """Page pool: ``(out, (k, v), aux)``. ``row_valid`` [B, T] marks
-        the rows that are real tokens (a family may skip the others)."""
+                      *, return_hidden, row_valid=None, slot_index=None):
+        """``(out, (k, v, state), aux)``: the page buffers and the state
+        pool as the step leaves them (``cache.state`` where the layout
+        has no state layer). ``row_valid`` [B, T] marks the rows that are
+        real tokens (a family may skip the others). ``slot_index`` [B]
+        says which slot's seat in the state pool each batch row reads
+        and leaves (an index past the slots: a padding row, nothing
+        written); None where row ``i`` is slot ``i``."""
         raise NotImplementedError
 
     def head_table(self, params):

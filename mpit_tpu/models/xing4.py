@@ -45,7 +45,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from mpit_tpu.models.serving import CacheLayout, ServeModel
+from mpit_tpu.models.serving import CacheLayout, PageLayer, ServeModel
 from mpit_tpu.ops import mla_attention as mla
 from mpit_tpu.parallel.moe_serve import expert_layer, gated_mlp
 
@@ -488,13 +488,12 @@ class Xing4ServeModel(ServeModel):
         # The latent and, in a buffer of its own padded to whole lane
         # tiles, the key's rotary part: 512 + 128 values a position.
         cfg = self.cfg
-        return CacheLayout(cfg.kv_lora_rank,
-                           mla.lane_pad(cfg.qk_rope_head_dim),
-                           cfg.num_hidden_layers, cfg.dtype)
+        row = PageLayer(cfg.kv_lora_rank, mla.lane_pad(cfg.qk_rope_head_dim))
+        return CacheLayout((row,) * cfg.num_hidden_layers, cfg.dtype)
 
     def kv_row_bytes(self, dtype) -> float:
-        lay = self.cache_layout()
-        return (lay.k_width + lay.v_width) / 2 * jnp.dtype(dtype).itemsize
+        row = self.cache_layout().layers[0]
+        return (row.k_width + row.v_width) / 2 * jnp.dtype(dtype).itemsize
 
     def check_supported(self, *, tp, kv_dtype, weights_dtype, spec_k,
                         host_pages) -> None:
@@ -538,7 +537,8 @@ class Xing4ServeModel(ServeModel):
             qa, qr, ckv_pool, kr_pool, lengths, block_table, scale=scale)
 
     def forward_paged(self, params, tokens, cache, block_tables, write_valid,
-                      *, return_hidden, row_valid=None):
+                      *, return_hidden, row_valid=None, slot_index=None):
+        del slot_index  # no layer keeps a slot's state
         # Late: models sits below serve, and gpt2 owns the pool's writer.
         from mpit_tpu.models.gpt2 import paged_cache_update
 
@@ -592,4 +592,4 @@ class Xing4ServeModel(ServeModel):
                 h = jnp.einsum("btd,vd->btv", h, params["head"],
                                preferred_element_type=jnp.float32)
         aux = jnp.stack(counts) if counts else None
-        return h, (tuple(ks), tuple(vs)), aux
+        return h, (tuple(ks), tuple(vs), cache.state), aux

@@ -82,6 +82,20 @@ weights from ``--seed``: ``--model tiny`` or ``published``, or
 raises, by name, for what the family lacks: ``--mesh``,
 ``--kv-dtype int8``, ``--weights-dtype int8``, ``--spec-k``, ``--kv-host-pages``, ``--ckpt``.
 
+``--family olmo_hybrid`` (ISSUE 32) serves the third: gated-delta
+linear-attention layers that keep a fixed state a slot (the engine's state
+pool) beside full-attention layers that keep pages
+(``models/olmo_hybrid.py``), on random weights from ``--seed``: ``--model
+tiny`` or ``published``, or ``--model-config FILE``. It raises, by name,
+for what would move or roll back a slot's state: ``--mesh``, ``--kv-dtype
+int8``, ``--weights-dtype int8``, ``--spec-k``, ``--kv-host-pages``, a
+``--policy`` that preempts (``preempt=0`` runs), ``--fleet`` shipment,
+``--ckpt``. A prompt that repeats an earlier one is computed whole (the
+prefix hit is passed up and counted: ``prefix_hits_passed_up``).
+``--sample-block`` is the blocked sampler's tile of the head's rows: one
+that divides the vocabulary (7168 for this family's 100,352) spares a
+padded copy of the table a step.
+
 Config follows the ``asyncsgd.config`` pattern: one dataclass, argparse
 generated from its fields.
 """
@@ -103,8 +117,8 @@ class ServeConfig:
     """Options for the serving CLI (the ``opt`` table analogue)."""
 
     ckpt: str = ""  # dense .npz from --save-dense ("" = random init)
-    family: str = "gpt2"  # the model family: gpt2 | xing4
-    model: str = "tiny"  # random-init size: tiny | small (xing4: published)
+    family: str = "gpt2"  # the model family: gpt2 | xing4 | olmo_hybrid
+    model: str = "tiny"  # random-init size: tiny | small (families: published)
     # xing4: a JSON file with the keys of the published config.json (as
     # benchmark/configs/xing4-29b-a4b-6of40.json holds them); "" = --model.
     model_config: str = ""
@@ -137,6 +151,7 @@ class ServeConfig:
     kv_pages: int = 0
     kv_page_size: int = 16
     prefill_chunk: int = 0
+    sample_block: int = 8192  # rows of the head a step of the sampler takes
     # Host KV tier (ISSUE 20). kv_host_pages > 0 gives the engine a
     # host-RAM page store: preemption victims park their pages there
     # (resume restreams instead of re-prefilling) and dying
@@ -216,23 +231,40 @@ def _xing4_model(cfg: ServeConfig):
     preset. The family serves on one chip in bf16 or f32; the engine
     raises, by name, for what it lacks (``--mesh``, ``--kv-dtype int8``,
     ``--weights-dtype int8``, ``--spec-k``, ``--kv-host-pages``)."""
-    import jax
-
     from mpit_tpu.models.xing4 import Xing4Config, init_params
+
+    return _family_model(cfg, Xing4Config, init_params)
+
+
+def _family_model(cfg: ServeConfig, config_cls, init_params):
+    """Random weights of a family that has no checkpoint loader: the
+    published sizes, those of ``--model-config``'s file, or the tiny
+    preset."""
+    import jax
 
     if cfg.ckpt:
         raise SystemExit(
-            "--family xing4 has no checkpoint loader yet: it serves random "
-            "weights from --seed")
+            f"--family {cfg.family} has no checkpoint loader yet: it serves "
+            "random weights from --seed")
+    longest = max(cfg.max_len, 128)
     if cfg.model_config:
         with open(cfg.model_config) as f:
-            mcfg = Xing4Config.from_dict(
-                json.load(f), max_seq_len=max(cfg.max_len, 128))
+            mcfg = config_cls.from_dict(json.load(f), max_seq_len=longest)
     elif cfg.model == "tiny":
-        mcfg = Xing4Config.tiny(max_seq_len=max(cfg.max_len, 128))
+        mcfg = config_cls.tiny(max_seq_len=longest)
     else:
-        mcfg = Xing4Config(max_seq_len=max(cfg.max_len, 128))
+        mcfg = config_cls(max_seq_len=longest)
     return init_params(mcfg, jax.random.key(cfg.seed)), mcfg
+
+
+def _olmo_hybrid_model(cfg: ServeConfig):
+    """Random weights of the ``olmo_hybrid`` family
+    (``models/olmo_hybrid.py``). The family serves on one chip in bf16 or
+    f32; the engine and the server raise, by name, for what would move or
+    roll back a slot's recurrent state."""
+    from mpit_tpu.models.olmo_hybrid import OlmoHybridConfig, init_params
+
+    return _family_model(cfg, OlmoHybridConfig, init_params)
 
 
 def _build_engine(cfg: ServeConfig):
@@ -286,8 +318,11 @@ def _build_engine(cfg: ServeConfig):
 
     if cfg.family == "xing4":
         params, mcfg = _xing4_model(cfg)
+    elif cfg.family == "olmo_hybrid":
+        params, mcfg = _olmo_hybrid_model(cfg)
     elif cfg.family != "gpt2":
-        raise SystemExit(f"--family {cfg.family!r}: expected gpt2 or xing4")
+        raise SystemExit(
+            f"--family {cfg.family!r}: expected gpt2, xing4 or olmo_hybrid")
     elif cfg.ckpt:
         params, mcfg = load_gpt2_params(cfg.ckpt, num_heads=cfg.num_heads)
     else:
@@ -361,6 +396,7 @@ def _build_engine(cfg: ServeConfig):
         seed=cfg.seed,
         decode_attention=cfg.decode_attention,
         sample_k_cap=max(cfg.sample_k_cap, cfg.top_k),
+        sample_block=cfg.sample_block,
         kv_pages=cfg.kv_pages or None,
         kv_page_size=cfg.kv_page_size,
         kv_host_pages=cfg.kv_host_pages or None,

@@ -20,6 +20,13 @@ The execution contract:
   ``prefill_chunk`` fixes the traced prefill width so the scheduler can
   slice long admits across ticks (chunked prefill). The steps donate the
   pool, so one lives.
+- **Beside the pages, a state pool** for a model whose layout has
+  layers that keep a fixed state a slot (``PagedKVCache.state``; empty
+  otherwise, and the steps are then what they were): the three paged
+  steps carry it as they carry the pages, the compacted chunk step tells
+  the model which slot each of its rows is, and ``page_bytes`` /
+  ``slot_state_bytes`` (the memory ledger's ``kv_pages`` / ``kv_state``)
+  come from the layout.
 - **A chunk writes the cache** at each participating slot's fill and,
   for the slots whose last prompt token rides it, samples the request's
   FIRST output token; **decode appends one token** per active slot at
@@ -744,14 +751,16 @@ class Engine:
         else:
 
             def fwd(prms, tokens, cache: PagedKVCache, block_tables,
-                    write_valid, row_valid=None):
+                    write_valid, row_valid=None, slot_index=None):
                 # Blocked head: the forward ends at ln_f and the step
                 # samples from hiddens; the reference engine: logits.
-                out, (k2, v2), aux = model.forward_paged(
+                out, (k2, v2, state2), aux = model.forward_paged(
                     prms, tokens, cache, block_tables, write_valid,
                     return_hidden=self._blocked_head, row_valid=row_valid,
+                    slot_index=slot_index,
                 )
-                new = PagedKVCache(k=k2, v=v2, lengths=cache.lengths)
+                new = PagedKVCache(k=k2, v=v2, lengths=cache.lengths,
+                                   state=state2)
                 return (out, new) if aux is None else (out, new, aux)
 
         self.model = model
@@ -818,9 +827,11 @@ class Engine:
         # Host-side page bookkeeping: free list, refcounts, prefix
         # index, COW reservations, per-slot block tables (the tables
         # ride into every jitted step as a tiny int32 argument).
+        layout = model.cache_layout()
         self.allocator = PageAllocator(
             self.num_pages, self.page_size, self.pages_per_slot, slots,
             host_pages=self.host_pages,
+            prefix_shareable=layout.prefix_shareable,
         )
         self.cache = alloc_paged_cache(
             model, slots, self.num_pages, self.page_size,
@@ -889,10 +900,8 @@ class Engine:
             # operand sharding — one silent extra compile per TP
             # engine, caught by the CompileWatch pin.
             rep = world.sharding()
-            self.cache = PagedKVCache(
-                k=self.cache.k,
-                v=self.cache.v,
-                lengths=jax.device_put(self.cache.lengths, rep),
+            self.cache = dataclasses.replace(
+                self.cache, lengths=jax.device_put(self.cache.lengths, rep)
             )
             self.last_token = jax.device_put(self.last_token, rep)
         self._forward = fwd
@@ -947,25 +956,23 @@ class Engine:
         # allocated, not what arithmetic predicts.
         self.memledger = MemLedger(platform=platform)
         register_param_store(self.memledger, self.params)
-        kv_buf = sum(
-            leaf.nbytes
-            for leaf in jax.tree.leaves((self.cache.k, self.cache.v))
-        )
+        nbytes = lambda tree: sum(l.nbytes for l in jax.tree.leaves(tree))
+        kv_buf = nbytes((self.cache.k, self.cache.v))
+        # The state pool (what the layout's recurrent layers keep a
+        # slot; nothing for a model whose layers all keep pages).
+        state_buf = nbytes(self.cache.state)
         lengths_bytes = self.cache.lengths.nbytes
         draft_kv = 0
         if self.draft_cache is not None:
-            draft_kv = sum(
-                leaf.nbytes
-                for leaf in jax.tree.leaves(
-                    (self.draft_cache.k, self.draft_cache.v)
-                )
-            )
+            draft_kv = nbytes((self.draft_cache.k, self.draft_cache.v))
             lengths_bytes += self.draft_cache.lengths.nbytes
         self.memledger.register(
-            "kv_pool", capacity_bytes=kv_buf + draft_kv + lengths_bytes
+            "kv_pool",
+            capacity_bytes=kv_buf + state_buf + draft_kv + lengths_bytes,
         )
         self.memledger.grant(
-            "kv_pool", kv_buf + lengths_bytes, kind="cache_buffers"
+            "kv_pool", kv_buf + state_buf + lengths_bytes,
+            kind="cache_buffers",
         )
         if self.spec_k:
             register_draft_store(
@@ -979,15 +986,34 @@ class Engine:
         # V, target AND draft pool (shared block tables mean a page
         # grant maps rows in both buffers) — the allocator's unit
         # for the nested kv_pages / kv_cow_reserve decomposition.
-        self.page_bytes = (kv_buf + draft_kv) // self.num_pages
+        # From the layouts, the draft's beside the target's: what the
+        # buffers above hold, a page of them.
+        kv_item = jax.tree.leaves(self.cache.k)[0].dtype
+        self.page_bytes = layout.page_bytes(
+            self.page_size, kv_item, self.kv_quantized
+        ) + (
+            self._draft_model.cache_layout().page_bytes(
+                self.page_size, kv_item, self.kv_quantized
+            ) if self.spec_k else 0
+        )
+        assert self.page_bytes * self.num_pages == kv_buf + draft_kv
         self.memledger.register(
             "kv_pages",
             capacity_bytes=self.num_pages * self.page_bytes,
             nested_in="kv_pool",
         )
         self.memledger.register("kv_cow_reserve", nested_in="kv_pool")
+        # A slot's seat in the state pool, held from admission to
+        # release as its pages are.
+        self.slot_state_bytes = layout.state_slot_bytes()
+        if self.slot_state_bytes:
+            assert self.slot_state_bytes * slots == state_buf
+            self.memledger.register(
+                "kv_state", capacity_bytes=state_buf, nested_in="kv_pool"
+            )
         self.allocator.memledger = self.memledger
         self.allocator.page_bytes = self.page_bytes
+        self.allocator.slot_state_bytes = self.slot_state_bytes
         if self.host_pages:
             # ISSUE 20: the host-RAM page store. Charged at spill
             # dispatch, refunded at restream / promotion / cold
@@ -1050,7 +1076,7 @@ class Engine:
         page geometry and indirects through the SAME block tables, so
         prefix sharing, COW remaps and preemption free/remap draft K/V
         together with the target's."""
-        out, (k2, v2), _ = self._draft_model.forward_paged(
+        out, (k2, v2, _state), _ = self._draft_model.forward_paged(
             dparams, tokens, dcache, block_tables, write_valid,
             return_hidden=not with_head,
         )
@@ -1079,9 +1105,8 @@ class Engine:
         # shape) attend at length 0 — their compute is discarded and the
         # length-aware kernel pays 1 tile, not their real context.
         participates = chunk_lens > 0
-        work = PagedKVCache(
-            k=cache.k, v=cache.v,
-            lengths=jnp.where(participates, base, 0),
+        work = dataclasses.replace(
+            cache, lengths=jnp.where(participates, base, 0)
         )
         out, new, *aux = self._forward(
             params, tokens, work, block_tables, write_valid,
@@ -1090,9 +1115,8 @@ class Engine:
         tok = self._sample_last(
             params, out, jnp.maximum(chunk_lens - 1, 0), key, temp, topk
         )
-        new_cache = PagedKVCache(
-            k=new.k,
-            v=new.v,
+        new_cache = dataclasses.replace(
+            new,
             lengths=jnp.where(
                 participates, base + chunk_lens, cache.lengths
             ),
@@ -1122,7 +1146,7 @@ class Engine:
         slot's last token at its fill position (scatter through its
         block table; inactive rows dropped), attend, sample the next."""
         lens = jnp.where(active, cache.lengths, 0)
-        work = PagedKVCache(k=cache.k, v=cache.v, lengths=lens)
+        work = dataclasses.replace(cache, lengths=lens)
         out, new, *aux = self._forward(
             params, last[:, None], work, block_tables, active[:, None],
             *self._rows_arg(lambda: active[:, None]),
@@ -1132,9 +1156,8 @@ class Engine:
             jnp.zeros((out.shape[0],), jnp.int32), key, temp, topk,
         )
         return (
-            PagedKVCache(
-                k=new.k, v=new.v,
-                lengths=jnp.where(active, lens + 1, lens),
+            dataclasses.replace(
+                new, lengths=jnp.where(active, lens + 1, lens)
             ),
             jnp.where(active, tok, last),
             *aux,
@@ -1165,12 +1188,12 @@ class Engine:
         rows = t_idx < chunk_lens[:, None]
         write_valid = rows & (pos >= floor[:, None])
         participates = chunk_lens > 0
-        work = PagedKVCache(
-            k=cache.k, v=cache.v, lengths=jnp.where(participates, base, 0)
+        work = dataclasses.replace(
+            cache, lengths=jnp.where(participates, base, 0)
         )
         out, new, *aux = self._forward(
             params, tokens, work, block_tables[at], write_valid,
-            *self._rows_arg(lambda: rows),
+            *self._rows_arg(lambda: rows), slot_index=slot_idx,
         )
         tok = self._sample_last(
             params, out, jnp.maximum(chunk_lens - 1, 0), key, temp[at],
@@ -1178,8 +1201,8 @@ class Engine:
         )
         # A scatter past the slots is dropped: padding and the slots
         # that sample nothing leave no mark.
-        new_cache = PagedKVCache(
-            k=new.k, v=new.v,
+        new_cache = dataclasses.replace(
+            new,
             lengths=cache.lengths.at[
                 jnp.where(participates, slot_idx, s)
             ].set(base + chunk_lens, mode="drop"),
@@ -1360,9 +1383,8 @@ class Engine:
                 pl, page, dst, axis=0
             )
 
-        cp = lambda c: PagedKVCache(
-            k=jax.tree.map(cp1, c.k), v=jax.tree.map(cp1, c.v),
-            lengths=c.lengths,
+        cp = lambda c: dataclasses.replace(
+            c, k=jax.tree.map(cp1, c.k), v=jax.tree.map(cp1, c.v)
         )
         if not self.spec_k:
             return cp(cache)
@@ -1413,9 +1435,8 @@ class Engine:
                 for i, layer in enumerate(pool)
             )
 
-        out = PagedKVCache(
-            k=sp(cache.k, payload[0]), v=sp(cache.v, payload[1]),
-            lengths=cache.lengths,
+        out = dataclasses.replace(
+            cache, k=sp(cache.k, payload[0]), v=sp(cache.v, payload[1])
         )
         if not self.spec_k:
             return out
@@ -1996,10 +2017,10 @@ class Engine:
             total_tiles,
             block_k=self.decode_block_k,
             kv_row_bytes=self._kv_row_bytes,
-            num_layers=self.cfg.num_layers,
+            num_layers=len(self.cache.k),  # the layers that keep pages
             param_bytes=self._param_bytes if include_params else 0.0,
             appended_rows=lens.size * t_q,
-        )
+        ) + 2.0 * lens.size * self.slot_state_bytes  # seats read, written
 
     def lengths(self) -> np.ndarray:
         return np.asarray(self.cache.lengths)
@@ -2108,7 +2129,8 @@ class Engine:
                 for i, layer in enumerate(pool)
             )
 
-        self.cache = PagedKVCache(
+        self.cache = dataclasses.replace(
+            self.cache,
             k=put(self.cache.k, k_rows),
             v=put(self.cache.v, v_rows),
             lengths=self.cache.lengths.at[slot].set(int(length)),

@@ -44,6 +44,20 @@ share). Recycled pages need no scale scrubbing for the same reason
 rows need no zeroing: the mask defines validity, and every valid row's
 scale was written by that row's own quantize-on-write.
 
+THE STATE POOL (ISSUE 32). A model's layout
+(:class:`~mpit_tpu.models.serving.CacheLayout`) says, a layer at a time,
+whether the layer keeps pages or a fixed state a slot (a recurrent or
+linear-attention layer: a matrix a head and the tail of its convolution,
+whatever the sequence's length). ``k`` / ``v`` hold a buffer for each
+page-holding layer; ``state`` holds, for each state-holding layer, a dict
+of ``[slots, ...]`` buffers, donated and returned by the steps as the
+pages are. A slot's seat is never cleared: a slot's first chunk starts
+from zeros (the step sees its fill at 0) and rows that are no tokens
+leave it as it was. Nothing moves a seat yet, so the allocator of such a
+model finds a registered prefix, counts it (``prefix_hits_passed_up``)
+and maps nothing shared: pages without the state at that boundary would
+serve wrong tokens with no error.
+
 HOST TIER (ISSUE 20). HBM pages are the scarce resource; host RAM is
 the next 10×. ``host_pages > 0`` gives the allocator a second page
 namespace — host page ids are bookkeeping handles whose PAYLOADS live
@@ -107,7 +121,8 @@ class PagedKVCache:
     """The engine's decode state: one shared page pool + per-slot fill
     counts. A pytree, so it passes through jit/shard_map boundaries whole.
 
-    ``k``/``v``: tuples of ``num_layers`` per-layer buffers, each
+    ``k``/``v``: tuples of per-layer buffers, one for each layer that
+    keeps pages (``num_layers`` of them where every layer does), each
     ``[num_pages, page_size, heads*head_dim]`` (a
     :class:`~mpit_tpu.ops.kv_quant.QuantizedKV` layer holds int8 rows of
     that shape plus a ``[num_pages, page_size, heads]`` f32 scale
@@ -124,9 +139,14 @@ class PagedKVCache:
     k: Any
     v: Any
     lengths: Any
+    # The state pool: for each state-holding layer of the model's layout
+    # a dict of ``[slots, ...]`` buffers (what a recurrent layer keeps a
+    # sequence whatever its length), donated and returned by the steps
+    # as the pages are. Empty for a model whose layers all keep pages.
+    state: Any = ()
 
     def tree_flatten(self):
-        return (self.k, self.v, self.lengths), None
+        return (self.k, self.v, self.lengths, self.state), None
 
     @classmethod
     def tree_unflatten(cls, _aux, children):
@@ -175,14 +195,19 @@ def alloc_paged_cache(
     layout = as_serve_model(cfg).cache_layout()
     dt = dtype or layout.dtype
     kw = {"device": sharding} if sharding is not None else {}
-    layers = lambda width: tuple(
-        _alloc_kv((num_pages, page_size, width), dt, quantized, kw,
-                  layout.scale_width)
-        for _ in range(layout.num_layers)
-    )
+    seat = lambda width: _alloc_kv(
+        (num_pages, page_size, width), dt, quantized, kw, layout.scale_width)
     return PagedKVCache(
-        k=layers(layout.k_width), v=layers(layout.v_width),
+        k=tuple(seat(l.k_width) for l in layout.page_layers),
+        v=tuple(seat(l.v_width) for l in layout.page_layers),
         lengths=jnp.zeros((slots,), jnp.int32),
+        # A recurrent layer's seats, a slot each: zeros, though a slot's
+        # first chunk starts from zeros whatever its seat holds.
+        state=tuple(
+            {name: jnp.zeros((slots, *shape), sdt)
+             for name, shape, sdt in l.buffers}
+            for l in layout.state_layers
+        ),
     )
 
 
@@ -305,12 +330,17 @@ class PageAllocator:
 
     def __init__(self, num_pages: int, page_size: int,
                  pages_per_slot: int, slots: int, *,
-                 host_pages: int = 0):
+                 host_pages: int = 0, prefix_shareable: bool = True):
         if num_pages < 1:
             raise ValueError(f"num_pages must be >= 1, got {num_pages}")
         if host_pages < 0:
             raise ValueError(f"host_pages must be >= 0, got {host_pages}")
         self.host_pages = host_pages
+        # False for a model that keeps more than pages a sequence (the
+        # layout says: ``CacheLayout.prefix_shareable``): pages mapped
+        # from another sequence's prefix would come without the state at
+        # that boundary, so a hit is found, counted and passed up.
+        self.prefix_shareable = prefix_shareable
         self.num_pages = num_pages
         self.page_size = page_size
         self.pages_per_slot = pages_per_slot
@@ -324,6 +354,9 @@ class PageAllocator:
         # (standalone allocator tests) — a no-op, not a crash.
         self.memledger = None
         self.page_bytes = 0.0
+        # What a slot's seat in the state pool holds (0: no state pool);
+        # granted with the slot's pages and freed with them.
+        self.slot_state_bytes = 0
         self.reset()
 
     def reset(self) -> None:
@@ -338,6 +371,12 @@ class PageAllocator:
                 self.memledger.free(
                     "kv_cow_reserve", self.reserved * self.page_bytes,
                     kind="reset",
+                )
+        if self.memledger is not None and self.slot_state_bytes:
+            seated = len(self._slot_pages)
+            if seated:
+                self.memledger.free(
+                    "kv_state", seated * self.slot_state_bytes, kind="reset"
                 )
         self.block_tables[:] = 0
         self.refcount = np.zeros(self.num_pages, np.int64)
@@ -362,6 +401,7 @@ class PageAllocator:
         # Stats (the scheduler's kv gauges + bench's prefix_hit_rate).
         self.cow_copies = 0
         self.prefix_hits = 0
+        self.prefix_hits_passed_up = 0  # found, not usable (a state pool)
         self.admissions = 0
         self.shared_tokens_total = 0
         self.prompt_tokens_total = 0
@@ -452,6 +492,9 @@ class PageAllocator:
                 f"shrink prompt + max_new_tokens or grow num_pages"
             )
         shared_tokens, entry = self._find_shared_prefix(prompt)
+        if entry is not None and not self.prefix_shareable:
+            self.prefix_hits_passed_up += 1
+            shared_tokens, entry = 0, None
         # ISSUE 20: a host-tier hit maps NO shared pages — the prefix
         # K/V restreams into fresh private pages (refcounts and COW
         # never span tiers), so the full page count is an "own" need
@@ -499,6 +542,11 @@ class PageAllocator:
                     "kv_cow_reserve", self.page_bytes,
                     owner=owner, tenant=tenant, tick=tick,
                     kind="cow_reserve",
+                )
+            if self.slot_state_bytes:
+                self.memledger.grant(
+                    "kv_state", self.slot_state_bytes,
+                    owner=owner, tenant=tenant, tick=tick, kind="admit",
                 )
         self.admissions += 1
         restream = ()
@@ -806,6 +854,12 @@ class PageAllocator:
         advertised K/V is about to be recycled)."""
         owner, _ = self._slot_owner.pop(slot, (None, None))
         released = 0
+        if (self.memledger is not None and self.slot_state_bytes
+                and slot in self._slot_pages):
+            self.memledger.free(
+                "kv_state", self.slot_state_bytes, owner=owner,
+                kind="free_slot",
+            )
         for p in self._slot_pages.pop(slot, []):
             self.refcount[p] -= 1
             self._trim_reserve(p)

@@ -331,6 +331,10 @@ class Server:
         self.engine = engine
         self.sentinel = sentinel
         self.policy = policy
+        if policy is not None and policy.cfg.preempt:
+            # A family whose slots keep what an eviction would drop says
+            # so here, by name (``SchedulingPolicy(preempt=False)`` runs).
+            engine.model.check_preemption()
         # Fleet identity (ISSUE 19): a stable stamp on stats() and the
         # memory verdict so fleet-merged stats attribute bytes/tokens
         # per worker, not per process-anonymous engine. Standalone
@@ -678,11 +682,16 @@ class Server:
                 break
             slot = self.free[-1]
             feed = live.feed_tokens()
+            passed_up = alloc.prefix_hits_passed_up
             plan = alloc.admit(
                 slot, feed, live.remaining_new(),
                 owner=live.req.rid, tenant=live.req.tenant or None,
                 tick=self.tick,
             )
+            if alloc.prefix_hits_passed_up > passed_up:
+                # A registered prefix the model's layout cannot use (its
+                # state at that boundary is not kept): computed anew.
+                obs.counter("prefix_hits_passed_up", 1.0)
             if plan is None:
                 # Pool full RIGHT NOW (nothing was taken) — back to the
                 # queue head; retry after a retirement (or a preemption)
@@ -815,6 +824,7 @@ class Server:
 
         The feed is made of values, so every step in flight is fetched
         first; an EOS among them may have retired the slot already."""
+        self.engine.model.check_preemption()
         self._drain()
         if slot not in self.live:
             return
@@ -1674,6 +1684,8 @@ class Server:
             self._host_held_peak = max(self._host_held_peak, host_held)
             gauges["host_held_bytes"] = float(host_held)
         kv_held = ml.held("kv_pages") + ml.held("kv_cow_reserve")
+        if self.engine.slot_state_bytes:  # the live slots' seats
+            kv_held += ml.held("kv_state")
         gauges["kv_held_bytes"] = float(kv_held)
         if "kv_headroom_pct" in head:
             pct = head["kv_headroom_pct"]
@@ -2249,6 +2261,8 @@ class Server:
             prefix_pages_shared_peak=self._pages_shared_peak,
             kv_cow_copies=alloc.cow_copies,
         )
+        if not alloc.prefix_shareable:
+            out["prefix_hits_passed_up"] = alloc.prefix_hits_passed_up
         if self._host_tier:
             # Host-tier roll-up (ISSUE 20): tier occupancy plus the
             # spill/restream traffic and where prefix hits landed.

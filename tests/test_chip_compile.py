@@ -535,3 +535,101 @@ def test_xing4_paged_steps_fit_and_update_in_place(xing4_engine, step):
         # A tick's temporaries are O(rows): under the smallest buffer.
         assert mem.temp_size_in_bytes < min(
             l.size * l.dtype.itemsize for l in pool)
+
+
+# Olmo-Hybrid-7B as benchmark/configs/olmo-hybrid-7b-8of32.json serves it:
+# two periods (6 linear layers, 2 full), 64 slots of 4,096 positions over
+# 128-position pages, chunks of 512, the sampler's tile 7,168 rows.
+OH_SLOTS, OH_POSITIONS, OH_PAGE, OH_CHUNK, OH_SAMPLE = 64, 4096, 128, 512, 7168
+OH_LIMIT = 15.0e9  # arguments + temporaries a step may hold (ISSUE 32)
+
+
+def test_gated_delta_kernels(v5e):
+    """The rule's chunk and step kernels at the published widths: 96 and
+    192 wide heads on whole lane tiles inside the kernel, the step's state
+    aliased to its result."""
+    from mpit_tpu.ops import gated_delta as gd
+
+    h, dk, dv = 30, 96, 192
+    bf, f32 = jnp.bfloat16, jnp.float32
+    text = _compile_on_chip(
+        v5e, lambda *a: gd.gdn_chunk(*a, interpret=False),
+        _sds((2, OH_CHUNK, h, dk), bf), _sds((2, OH_CHUNK, h, dk), bf),
+        _sds((2, OH_CHUNK, h, dv), bf), _sds((2, OH_CHUNK, h), f32),
+        _sds((2, OH_CHUNK, h), f32), _sds((2, h, dk, dv), f32))
+    assert "gdn_chunk" in text
+    text = _compile_on_chip(
+        v5e, lambda *a: gd.gdn_step(*a, interpret=False),
+        _sds((OH_SLOTS, h, dk), bf), _sds((OH_SLOTS, h, dk), bf),
+        _sds((OH_SLOTS, h, dv), bf), _sds((OH_SLOTS, h), f32),
+        _sds((OH_SLOTS, h), f32), _sds((OH_SLOTS, h, dk, dv), f32))
+    assert "gdn_step" in text
+
+
+@pytest.fixture(scope="module")
+def olmo_hybrid_engine(v5e):
+    """The configuration's engine on shapes alone (4.9 GB of parameters
+    are never made) and a two-slot page pool; the steps are then lowered
+    for the pool of all 64 slots."""
+    from mpit_tpu.models.olmo_hybrid import OlmoHybridConfig, init_params
+    from mpit_tpu.ops import decode_attention
+    from mpit_tpu.serve import Engine
+
+    cfg = OlmoHybridConfig(num_hidden_layers=8, max_seq_len=OH_POSITIONS)
+    params = jax.eval_shape(lambda: init_params(cfg, jax.random.key(0)))
+    pps = OH_POSITIONS // OH_PAGE
+    was = decode_attention._use_kernel
+    decode_attention._use_kernel = lambda interpret: True
+    eng = Engine(cfg, params, slots=OH_SLOTS, max_len=OH_POSITIONS,
+                 kv_pages=2 * pps, kv_page_size=OH_PAGE,
+                 prefill_chunk=OH_CHUNK, sample_block=OH_SAMPLE)
+    one = SingleDeviceSharding(v5e.devices[0])
+    yield eng, lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one), tree)
+    decode_attention._use_kernel = was
+
+
+@pytest.mark.parametrize("step", ["decode", "prefill"])
+def test_olmo_hybrid_paged_steps_fit_and_update_in_place(
+        olmo_hybrid_engine, step):
+    """Both steps of the cell, pool of 64 x 4,096 positions and 64 seats
+    of state: every buffer of both pools aliased to an output, the rule's
+    and the attention's kernels in the text, and arguments + temporaries
+    under 15.0 GB (how 64 slots were kept: the sampler's tile of 8,192
+    rows would pad the head with a copy of 0.82 GB and pass it)."""
+    import dataclasses
+
+    eng, on_chip = olmo_hybrid_engine
+    assert eng._prefill_counts == (1, 2, 4)
+    pages = OH_SLOTS * eng.pages_per_slot
+    full = lambda bufs: tuple(
+        jax.ShapeDtypeStruct((pages, *b.shape[1:]), b.dtype) for b in bufs)
+    cache = dataclasses.replace(
+        eng.cache, k=full(eng.cache.k), v=full(eng.cache.v))
+    s = eng.slots
+    i32, f32 = jnp.zeros((s,), jnp.int32), jnp.zeros((s,), jnp.float32)
+    bt, key = jnp.zeros((s, eng.pages_per_slot), jnp.int32), jax.random.key(0)
+    if step == "decode":
+        jit, args = eng._decode_paged_jit, (
+            eng.params, cache, eng.last_token, jnp.zeros((s,), bool), bt,
+            key, f32, i32)
+        kernels = ("gdn_step", "paged_decode_attn")
+    else:
+        n = eng._prefill_counts[-1]  # the largest step a tick can meet
+        z = jnp.zeros((n,), jnp.int32)
+        jit, args = eng._prefill_compact_jit, (
+            eng.params, cache, eng.last_token, z,
+            jnp.zeros((n, OH_CHUNK), jnp.int32), z, z, z,
+            jnp.zeros((n,), bool), bt, key, f32, i32)
+        kernels = ("gdn_chunk", "paged_decode_attn", "paged_kv_write")
+    compiled = jit.lower(*on_chip(args)).compile()
+    text = compiled.as_text()
+    for name in kernels:
+        assert name in text, name
+    mem = compiled.memory_analysis()
+    pools = jax.tree.leaves((cache.k, cache.v, cache.state))
+    assert mem.alias_size_in_bytes >= sum(
+        l.size * l.dtype.itemsize for l in pools)
+    held = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert held < OH_LIMIT, held

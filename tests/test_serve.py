@@ -88,7 +88,7 @@ class TestKVCacheParity:
         cache = alloc_paged_cache(CFG, slots=2, num_pages=4, page_size=8)
         padded = np.zeros((2, 8), np.int32)
         padded[0, : len(prompt)] = prompt
-        logits, (k2, v2), aux = CFG.serve_model().forward_paged(
+        logits, (k2, v2, _), aux = CFG.serve_model().forward_paged(
             params, jnp.asarray(padded), cache,
             jnp.asarray([[0, 1], [2, 3]], jnp.int32),
             jnp.ones((2, 8), bool), return_hidden=False,
@@ -1121,7 +1121,7 @@ class TestOneEngine:
             assert c.tokens == ref_greedy(
                 model, engine.params, c.prompt, len(c.tokens))
 
-    @pytest.mark.parametrize("family", ["gpt2", "xing4"])
+    @pytest.mark.parametrize("family", ["gpt2", "xing4", "olmo_hybrid"])
     def test_family_conforms_to_the_model_interface(self, family):
         """What the engine asks of a family, asked of each: the cache
         row layout times the pool's dtype is ``Engine.page_bytes``,
@@ -1135,6 +1135,19 @@ class TestOneEngine:
             params = GPT2(CFG).init(
                 jax.random.key(0), jnp.zeros((1, 8), jnp.int32))["params"]
             lacks = {}
+        elif family == "olmo_hybrid":
+            from mpit_tpu.models.olmo_hybrid import (
+                OlmoHybridConfig, init_params)
+
+            cfg = OlmoHybridConfig.tiny(max_seq_len=64, dtype=jnp.float32)
+            params = init_params(cfg, jax.random.key(0))
+            lacks = {
+                "tp": (True, "tensor parallelism"),
+                "kv_dtype": ("int8", "int8 cache"),
+                "weights_dtype": ("int8", "int8 weights"),
+                "spec_k": (2, "speculative"),
+                "host_pages": (2, "host KV tier"),
+            }
         else:
             from mpit_tpu.models.xing4 import Xing4Config, init_params
 
@@ -1156,20 +1169,33 @@ class TestOneEngine:
             with pytest.raises(ValueError, match=what):
                 model.check_supported(**{**modes, mode: value})
         lay = model.cache_layout()
+        assert len(lay.layers) == cfg.num_layers
         slots, pages, ps = 2, 8, 16
         engine = Engine(cfg, params, slots=slots, max_len=64, kv_pages=pages,
                         kv_page_size=ps)
         itemsize = jnp.dtype(lay.dtype).itemsize
-        assert engine.page_bytes == (
-            ps * (lay.k_width + lay.v_width) * itemsize * lay.num_layers)
+        assert engine.page_bytes == ps * itemsize * sum(
+            l.k_width + l.v_width for l in lay.page_layers)
+        row = lay.page_layers[0]
         assert model.kv_row_bytes(lay.dtype) == (
-            (lay.k_width + lay.v_width) / 2 * itemsize)
+            (row.k_width + row.v_width) / 2 * itemsize)
+        assert engine.slot_state_bytes == lay.state_slot_bytes() == sum(
+            leaf.nbytes for leaf in jax.tree.leaves(engine.cache.state)
+        ) // slots
+        assert engine.allocator.prefix_shareable == (not lay.state_layers)
         cache = alloc_paged_cache(cfg, slots, pages, ps)
-        want = lambda w: [(pages, ps, w)] * lay.num_layers
-        assert [b.shape for b in cache.k] == want(lay.k_width)
-        assert [b.shape for b in cache.v] == want(lay.v_width)
+        k_want = [(pages, ps, l.k_width) for l in lay.page_layers]
+        v_want = [(pages, ps, l.v_width) for l in lay.page_layers]
+        state_want = [
+            {name: ((slots, *shape), jnp.dtype(dt))
+             for name, shape, dt in l.buffers} for l in lay.state_layers]
+        shapes = lambda state: [
+            {n: (b.shape, b.dtype) for n, b in seat.items()} for seat in state]
+        assert [b.shape for b in cache.k] == k_want
+        assert [b.shape for b in cache.v] == v_want
+        assert shapes(cache.state) == state_want
         t = 4
-        out, (k2, v2), aux = model.forward_paged(
+        out, (k2, v2, state2), aux = model.forward_paged(
             params, jnp.ones((slots, t), jnp.int32), cache,
             jnp.arange(slots * 4, dtype=jnp.int32).reshape(slots, 4),
             jnp.ones((slots, t), bool), return_hidden=True,
@@ -1177,10 +1203,11 @@ class TestOneEngine:
         )
         assert out.shape[:2] == (slots, t)
         assert model.head_table(params).shape[1] == out.shape[-1]
-        assert [b.shape for b in k2] == want(lay.k_width)
-        assert [b.shape for b in v2] == want(lay.v_width)
+        assert [b.shape for b in k2] == k_want
+        assert [b.shape for b in v2] == v_want
+        assert shapes(state2) == state_want
         assert all(b.dtype == lay.dtype for b in (*k2, *v2))
-        assert (aux is None) == (family == "gpt2")
+        assert (aux is None) == (family != "xing4")
 
 
 class TestServeCLI:

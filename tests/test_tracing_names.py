@@ -283,7 +283,23 @@ def _ship_jaxpr(monkeypatch):
         jnp.ones((8, 128), jnp.float32))
 
 
+def _gdn_jaxpr():
+    from mpit_tpu.ops import gated_delta as gd
+
+    q = jnp.ones((1, 8, 3, 12), jnp.float32)
+    v, g = jnp.ones((1, 8, 3, 24), jnp.float32), jnp.zeros((1, 8, 3))
+    s = jnp.zeros((1, 3, 12, 24), jnp.float32)
+
+    def both(q, v, g, s):
+        o, s = gd.gdn_chunk(q, q, v, g, g, s, interpret=True)
+        return gd.gdn_step(q[:, 0], q[:, 0], v[:, 0], g[:, 0], g[:, 0], s,
+                           interpret=True)
+
+    return jax.make_jaxpr(both)(q, v, g, s)
+
+
 KERNELS = {
+    "gated_delta": (_gdn_jaxpr, {"gdn_chunk", "gdn_step"}),
     "flash": (_flash_jaxpr, {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}),
     "quantized_matmul": (_qmm_jaxpr, {"quantized_matmul"}),
     "ring_step": (_ring_jaxpr, {"ring_step"}),
@@ -296,3 +312,50 @@ def test_the_other_kernels_carry_their_names(kernel, monkeypatch):
     build, names = KERNELS[kernel]
     jaxpr = build(monkeypatch) if kernel == "kv_ship" else build()
     assert names <= _pallas_names(jaxpr.jaxpr)
+
+
+def test_a_state_keeping_family_names_its_layers_and_its_passed_up_hits():
+    """The third family's steps lower with the scopes its per-layer
+    metrics read (``benchmark/families/olmo_hybrid/scopes.json`` lists the
+    same names), and a prefix hit it cannot use is a counter of that
+    name on the host's clock."""
+    import json
+    import os
+
+    from mpit_tpu.models.olmo_hybrid import OlmoHybridConfig, init_params
+
+    cfg = OlmoHybridConfig.tiny(num_hidden_layers=4, max_seq_len=64)
+    engine = Engine(cfg, init_params(cfg, jax.random.key(0)), slots=2,
+                    max_len=64, kv_page_size=16, prefill_chunk=16)
+    s = engine.slots
+    i32, f32 = jnp.zeros((s,), jnp.int32), jnp.zeros((s,), jnp.float32)
+    bt = jnp.asarray(engine.allocator.block_tables, jnp.int32)
+    key = jax.random.key(0)
+    texts = [
+        engine._decode_paged_jit.lower(
+            engine.params, engine.cache, engine.last_token,
+            jnp.ones((s,), bool), bt, key, f32, i32
+        ).as_text(debug_info=True),
+        engine._prefill_paged_jit.lower(
+            engine.params, engine.cache, engine.last_token,
+            jnp.zeros((s, 16), jnp.int32), i32, i32, i32,
+            jnp.zeros((s,), bool), bt, key, f32, i32
+        ).as_text(debug_info=True),
+    ]
+    with open(os.path.join(
+            os.path.dirname(__file__), "..", "benchmark", "families",
+            "olmo_hybrid", "scopes.json")) as f:
+        stated = json.load(f)["scopes"]
+    assert set(stated) == {"linear_attn", "gdn_conv", "gdn_chunk", "gdn_step",
+                           "state_pool_move"}
+    for scope in stated:
+        assert any(re.search(rf'["/(]{scope}[/)]', t) for t in texts), scope
+    rec = obs.Recorder()
+    with obs.local_recorder(rec):
+        server = Server(engine)
+        for rid in (1, 2):
+            server.submit(Request(rid=rid, prompt=[5, 9, 3, 7, 2, 8, 1, 4, 6],
+                                  max_new_tokens=12 if rid == 1 else 2))
+            server.run(max_ticks=server.tick + 3)
+        server.run()
+    assert rec.counter_total("prefix_hits_passed_up") == 1

@@ -214,7 +214,7 @@ def test_paged_prefill_then_decode_matches_the_reference_logits(tiny, mode):
         rows = (np.arange(width)[None] < n) & (np.arange(eng.slots) == 1)[
             :, None]
         lengths = jnp.asarray([0, base, 0], jnp.int32)
-        logits, (k, v), counts = forward(
+        logits, (k, v, _), counts = forward(
             params, jnp.asarray(tokens),
             PagedKVCache(k=cache.k, v=cache.v, lengths=lengths), bt,
             jnp.asarray(rows), jnp.asarray(rows))
