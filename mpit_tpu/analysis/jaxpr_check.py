@@ -20,6 +20,9 @@ Library (works on a jaxpr, a ClosedJaxpr, or a callable + args):
 - :func:`assert_no_transfer` — no ``device_put`` / host-callback
   primitives inside a step's jaxpr (a jitted hot-path step must not
   smuggle host round-trips).
+- :func:`find_primitives` / :func:`assert_no_primitive` — the named
+  primitives anywhere in a jaxpr, nested ones included ("the greedy
+  branch of the sampler neither sorts nor draws noise").
 - :func:`max_eqn_count` / :func:`eqn_count` — growth pin.
 - :func:`donation_aliases` / :func:`assert_donation_consumed` — count
   ``tf.aliasing_output`` annotations in lowered StableHLO: donation
@@ -50,6 +53,8 @@ __all__ = [
     "assert_no_intermediate",
     "assert_intermediate",
     "assert_no_transfer",
+    "find_primitives",
+    "assert_no_primitive",
     "eqn_count",
     "max_eqn_count",
     "donation_aliases",
@@ -153,15 +158,29 @@ def _walk_eqns(jaxpr):
 
 def assert_no_transfer(jaxpr, what="step"):
     """No host-transfer / callback primitives inside the step."""
-    bad = [
-        e.primitive.name
-        for e in _walk_eqns(jaxpr)
-        if e.primitive.name in _TRANSFER_PRIMS
-    ]
+    bad = find_primitives(jaxpr, _TRANSFER_PRIMS)
     if bad:
         raise JaxprContractError(
             f"{what} contains host-transfer primitives {sorted(set(bad))} "
             "— a jitted hot-path step must not smuggle host round-trips"
+        )
+
+
+def find_primitives(jaxpr, prims) -> list:
+    """Names of the eqns (nested jaxprs included) whose primitive is
+    one of ``prims``, in the order they are met."""
+    return [
+        e.primitive.name for e in _walk_eqns(jaxpr)
+        if e.primitive.name in prims
+    ]
+
+
+def assert_no_primitive(jaxpr, prims, what="step"):
+    """None of the primitives ``prims`` anywhere in the jaxpr."""
+    bad = find_primitives(jaxpr, prims)
+    if bad:
+        raise JaxprContractError(
+            f"{what} contains {sorted(set(bad))} ({len(bad)} eqns)"
         )
 
 
@@ -412,8 +431,34 @@ def _contract_quantized_weights(ctx):
     )
 
 
+# What only a sampling row needs of the blocked sampler: the top-k
+# sorts, the Gumbel field, the division by the temperature and the
+# gathers that merge candidates.
+SAMPLING_PRIMS = frozenset({
+    "sort", "top_k", "random_bits", "random_fold_in", "threefry2x32",
+    "div", "gather",
+})
+
+
+def sampler_branches(jaxpr):
+    """``(greedy, general)`` jaxprs of ``lm_head_sample``'s one
+    conditional: ``lax.cond`` keeps the false branch first, and the
+    predicate is "some row samples"."""
+    conds = [
+        e for e in _as_jaxpr(jaxpr).eqns if e.primitive.name == "cond"
+    ]
+    if len(conds) != 1 or len(conds[0].params["branches"]) != 2:
+        raise JaxprContractError(
+            f"lm_head_sample holds {len(conds)} top-level conditionals, "
+            "not its one of two branches — the greedy path went away"
+        )
+    return conds[0].params["branches"]
+
+
 def _contract_lm_head_sample(ctx):
-    """The blocked sampler never runs the full-width logits matmul."""
+    """The blocked sampler never runs the full-width logits matmul, on
+    either path; its greedy path holds nothing that only a sampling row
+    needs, and its general path still does (anti-vacuity)."""
     import jax
     import jax.numpy as jnp
 
@@ -423,14 +468,23 @@ def _contract_lm_head_sample(ctx):
     S, V, D = 5, 256, 16
     h = jnp.zeros((S, D), jnp.float32)
     head = jnp.zeros((V, D), jnp.float32)
-    temp = jnp.ones((S,), jnp.float32)
-    topk = jnp.zeros((S,), jnp.int32)
     jx = jax.make_jaxpr(
-        lambda h, w: lm_head_sample(
+        lambda h, w, temp, topk: lm_head_sample(
             h, w, jax.random.key(0), temp, topk, block_size=64
         )
-    )(h, head)
+    )(h, head, jnp.ones((S,), jnp.float32), jnp.zeros((S,), jnp.int32))
     assert_no_intermediate(jx, (S, V), what="lm_head_sample")
+    greedy, general = sampler_branches(jx)
+    assert_no_primitive(
+        greedy, SAMPLING_PRIMS, what="lm_head_sample's greedy path"
+    )
+    if not {"top_k", "random_bits"} <= set(
+        find_primitives(general, SAMPLING_PRIMS)
+    ):
+        raise JaxprContractError(
+            "lm_head_sample's general path no longer sorts and draws "
+            "noise — the pin on the greedy path is vacuous"
+        )
 
 
 def _contract_lm_head_verify(ctx):

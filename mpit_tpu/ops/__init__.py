@@ -21,7 +21,8 @@ hand-scheduled TPU kernels below the XLA tier:
   online-logsumexp trick applied over the vocabulary axis; never
   materializes the [B, T, vocab] f32 logits), plus the blocked decode
   head ``lm_head_sample`` (greedy/top-k/temperature sampling with a
-  running top-k merge across vocab blocks — the serving analogue).
+  running top-k merge across vocab blocks — the serving analogue; a
+  call in which no row samples takes a greedy scan of one max a block).
 - :mod:`mpit_tpu.ops.decode_attention` — flash-decode against the paged
   KV pool: blocked over the cache length with online softmax and
   per-slot length-aware skipping (K/V stay in HBM and are read in place

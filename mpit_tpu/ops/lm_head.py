@@ -280,8 +280,20 @@ def _head_blocks(head, block):
 # decode step used to materialize the full [slots, vocab] f32 logits just
 # to pick one token per slot; this computes the pick per vocab block with
 # a running top-k merge, so the live tile is [slots, block] — the same
-# trick lm_head_xent plays for training, applied to sampling.
+# trick lm_head_xent plays for training, applied to sampling. A call in
+# which no row samples runs a scan that keeps the running argmax alone
+# (ISSUE 33).
 # ---------------------------------------------------------------------------
+
+
+def _merge_first_max(gv, gi, logits, off):
+    """Merge a block's ``(max, argmax)`` into the running pair. Strict
+    ``>`` keeps the FIRST max across blocks, as ``jnp.argmax`` does
+    within one: the pair ends as ``argmax`` over the whole vocabulary."""
+    bm = jnp.max(logits, axis=-1)
+    bmi = jnp.argmax(logits, axis=-1).astype(jnp.int32) + off
+    upd = bm > gv
+    return jnp.where(upd, bm, gv), jnp.where(upd, bmi, gi)
 
 
 def lm_head_sample(
@@ -316,15 +328,30 @@ def lm_head_sample(
         ``compute_dtype`` with f32 accumulation.
       k_cap: static width of the running top-k candidate buffer.
 
-    Per vocab block the scan carries (1) the running argmax of the raw
-    logits — greedy bit-matches ``argmax`` over the full logits because
-    the strict-``>`` merge keeps the first occurrence, exactly
-    ``jnp.argmax``'s tie rule; (2) the running argmax of
-    ``logit/temp + gumbel`` — exact full-vocab categorical via the
-    Gumbel-max trick; (3) the top-``k_cap`` (value, index, noised-score)
-    triples merged across blocks — the final top-k draw thresholds at
-    the k-th largest value *inside the buffer* and Gumbel-argmaxes the
-    survivors, so no second pass over the vocabulary is needed.
+    **Two paths, chosen on the device by what the rows ask for**
+    (``lax.cond`` on ``jnp.any(temperature > 0)``, outside the scan):
+
+    - *no row samples* (every serving tick of greedy traffic): a scan
+      that carries the running ``(max, argmax)`` of the raw logits and
+      nothing else — one product, one max and one argmax a vocab block;
+      no noise is drawn, nothing is divided, sorted or gathered. Greedy
+      bit-matches ``argmax`` over the full logits because the
+      strict-``>`` merge keeps the first occurrence, exactly
+      ``jnp.argmax``'s tie rule;
+    - *some row samples*: the general scan, which carries per vocab
+      block (1) the same running argmax, by the same expressions on the
+      same tile, so a greedy row's token does not depend on which path
+      its neighbours sent the call down; (2) the running argmax of
+      ``logit/temp + gumbel`` — exact full-vocab categorical via the
+      Gumbel-max trick; (3) the top-``k_cap`` (value, index,
+      noised-score) triples merged across blocks — the final top-k draw
+      thresholds at the k-th largest value *inside the buffer* and
+      Gumbel-argmaxes the survivors, so no second pass over the
+      vocabulary is needed. One sampling row sends the whole call down
+      this path, at the price every call paid before there were two.
+
+    The predicate is computed from ``temperature`` alone, which a mesh
+    replicates, so every device of one takes the same branch.
 
     Returns ``[S]`` int32 token ids.
     """
@@ -335,68 +362,84 @@ def lm_head_sample(
     blk_ids = jnp.arange(n_blocks, dtype=jnp.int32)
     n = h.shape[0]
     kb = min(k_cap, vocab)
-    temp = jnp.maximum(jnp.asarray(temperature, jnp.float32), 1e-6)
+    temperature = jnp.asarray(temperature, jnp.float32)
+    top_k = jnp.asarray(top_k, jnp.int32)
     cd = jnp.dtype(compute_dtype)
-
-    def tick(carry, xs):
-        gv, gi, sv, si, bv, bi, bs = carry
-        head_b, off, blk = xs
-        valid = off + jnp.arange(block, dtype=jnp.int32) < vocab
-        logits = _block_logits(h, head_b, valid, cd)  # [S, block] f32
-        # (1) greedy: strict > keeps the FIRST max — jnp.argmax's rule.
-        bm = jnp.max(logits, axis=-1)
-        bmi = jnp.argmax(logits, axis=-1).astype(jnp.int32) + off
-        upd = bm > gv
-        gv, gi = jnp.where(upd, bm, gv), jnp.where(upd, bmi, gi)
-        # (2) full-vocab Gumbel-max on temperature-scaled logits.
-        g = jax.random.gumbel(
-            jax.random.fold_in(key, blk), (n, block), jnp.float32
-        )
-        scaled = jnp.where(
-            valid[None, :], logits / temp[:, None] + g, _NEG_BIG
-        )
-        sm = jnp.max(scaled, axis=-1)
-        smi = jnp.argmax(scaled, axis=-1).astype(jnp.int32) + off
-        supd = sm > sv
-        sv, si = jnp.where(supd, sm, sv), jnp.where(supd, smi, si)
-        # (3) running top-k candidates: merge this block's top-kb
-        # (value, global index, noised score) into the buffer.
-        cv, ci = lax.top_k(logits, min(kb, block))
-        cs = jnp.take_along_axis(scaled, ci, axis=-1)
-        allv = jnp.concatenate([bv, cv], axis=-1)
-        alli = jnp.concatenate([bi, ci + off], axis=-1)
-        alls = jnp.concatenate([bs, cs], axis=-1)
-        bv, sel = lax.top_k(allv, kb)
-        bi = jnp.take_along_axis(alli, sel, axis=-1)
-        bs = jnp.take_along_axis(alls, sel, axis=-1)
-        return (gv, gi, sv, si, bv, bi, bs), None
-
     neg = jnp.full((n,), -jnp.inf, jnp.float32)
     zero_i = jnp.zeros((n,), jnp.int32)
-    init = (
-        neg, zero_i,  # greedy running (max, argmax)
-        neg, zero_i,  # full-vocab gumbel running (max, argmax)
-        jnp.full((n, kb), _NEG_BIG, jnp.float32),  # top-k values
-        jnp.zeros((n, kb), jnp.int32),  # top-k global indices
-        jnp.full((n, kb), _NEG_BIG, jnp.float32),  # top-k noised scores
-    )
-    (gv, gi, sv, si, bv, bi, bs), _ = lax.scan(
-        tick, init, (head_blocks, offsets, blk_ids),
-        unroll=min(n_blocks, 16),
-    )
-    # Top-k draw: threshold at the row's k-th largest value inside the
-    # buffer (reference semantics: keep logits >= thresh), Gumbel-argmax
-    # the survivors.
-    kk = jnp.clip(jnp.asarray(top_k, jnp.int32), 1, kb)
-    thresh = jnp.take_along_axis(bv, (kk - 1)[:, None], axis=-1)
-    kept = jnp.where(bv >= thresh, bs, -jnp.inf)
-    tk_tok = jnp.take_along_axis(
-        bi, jnp.argmax(kept, axis=-1)[:, None], axis=-1
-    )[:, 0]
-    top_k = jnp.asarray(top_k, jnp.int32)
-    sampled = jnp.where(top_k > 0, tk_tok, si)
-    greedy = jnp.asarray(temperature, jnp.float32) <= 0.0
-    return jnp.where(greedy, gi, sampled).astype(jnp.int32)
+
+    def block_logits(head_b, off):
+        valid = off + jnp.arange(block, dtype=jnp.int32) < vocab
+        return _block_logits(h, head_b, valid, cd), valid  # [S, block] f32
+
+    def greedy_path():
+        def tick(carry, xs):
+            head_b, off = xs
+            logits, _ = block_logits(head_b, off)
+            return _merge_first_max(*carry, logits, off), None
+
+        (_, gi), _ = lax.scan(
+            tick, (neg, zero_i), (head_blocks, offsets),
+            unroll=min(n_blocks, 16),
+        )
+        return gi
+
+    def general_path():
+        temp = jnp.maximum(temperature, 1e-6)
+
+        def tick(carry, xs):
+            gv, gi, sv, si, bv, bi, bs = carry
+            head_b, off, blk = xs
+            logits, valid = block_logits(head_b, off)
+            # (1) greedy: the greedy path's merge.
+            gv, gi = _merge_first_max(gv, gi, logits, off)
+            # (2) full-vocab Gumbel-max on temperature-scaled logits.
+            g = jax.random.gumbel(
+                jax.random.fold_in(key, blk), (n, block), jnp.float32
+            )
+            scaled = jnp.where(
+                valid[None, :], logits / temp[:, None] + g, _NEG_BIG
+            )
+            sm = jnp.max(scaled, axis=-1)
+            smi = jnp.argmax(scaled, axis=-1).astype(jnp.int32) + off
+            supd = sm > sv
+            sv, si = jnp.where(supd, sm, sv), jnp.where(supd, smi, si)
+            # (3) running top-k candidates: merge this block's top-kb
+            # (value, global index, noised score) into the buffer.
+            cv, ci = lax.top_k(logits, min(kb, block))
+            cs = jnp.take_along_axis(scaled, ci, axis=-1)
+            allv = jnp.concatenate([bv, cv], axis=-1)
+            alli = jnp.concatenate([bi, ci + off], axis=-1)
+            alls = jnp.concatenate([bs, cs], axis=-1)
+            bv, sel = lax.top_k(allv, kb)
+            bi = jnp.take_along_axis(alli, sel, axis=-1)
+            bs = jnp.take_along_axis(alls, sel, axis=-1)
+            return (gv, gi, sv, si, bv, bi, bs), None
+
+        init = (
+            neg, zero_i,  # greedy running (max, argmax)
+            neg, zero_i,  # full-vocab gumbel running (max, argmax)
+            jnp.full((n, kb), _NEG_BIG, jnp.float32),  # top-k values
+            jnp.zeros((n, kb), jnp.int32),  # top-k global indices
+            jnp.full((n, kb), _NEG_BIG, jnp.float32),  # top-k noised scores
+        )
+        (gv, gi, sv, si, bv, bi, bs), _ = lax.scan(
+            tick, init, (head_blocks, offsets, blk_ids),
+            unroll=min(n_blocks, 16),
+        )
+        # Top-k draw: threshold at the row's k-th largest value inside
+        # the buffer (reference semantics: keep logits >= thresh),
+        # Gumbel-argmax the survivors.
+        kk = jnp.clip(top_k, 1, kb)
+        thresh = jnp.take_along_axis(bv, (kk - 1)[:, None], axis=-1)
+        kept = jnp.where(bv >= thresh, bs, -jnp.inf)
+        tk_tok = jnp.take_along_axis(
+            bi, jnp.argmax(kept, axis=-1)[:, None], axis=-1
+        )[:, 0]
+        sampled = jnp.where(top_k > 0, tk_tok, si)
+        return jnp.where(temperature <= 0.0, gi, sampled).astype(jnp.int32)
+
+    return lax.cond(jnp.any(temperature > 0.0), general_path, greedy_path)
 
 
 # ---------------------------------------------------------------------------
@@ -473,11 +516,7 @@ def lm_head_verify(
         head_b, off = xs
         valid = off + jnp.arange(block, dtype=jnp.int32) < vocab
         logits = _block_logits(h, head_b, valid, cd)  # [N, block] f32
-        # Greedy: strict > keeps the FIRST max — jnp.argmax's rule.
-        bm = jnp.max(logits, axis=-1)
-        bmi = jnp.argmax(logits, axis=-1).astype(jnp.int32) + off
-        upd = bm > gv
-        gv, gi = jnp.where(upd, bm, gv), jnp.where(upd, bmi, gi)
+        gv, gi = _merge_first_max(gv, gi, logits, off)
         # Full-support logsumexp of logits/temp (padded cols: -big).
         scaled = logits / temp[:, None]
         sm = jnp.max(scaled, axis=-1)

@@ -24,7 +24,15 @@ cached K/V and the request loop is continuous batching.
   sampling streams the LM head per vocab block
   (:func:`mpit_tpu.ops.lm_head.lm_head_sample`) — the decode step
   never materializes ``[slots, vocab]`` logits or ``[slots, H, T,
-  max_len]`` scores; ``Engine(decode_attention="reference")`` runs the
+  max_len]`` scores. The blocked head takes the path its rows ask for,
+  chosen on the device inside the one compiled step: a step in which
+  no slot's ``temperature`` is above 0 takes one max and one argmax a
+  vocab block; one sampling slot sends that step down the general scan
+  (Gumbel noise, the top-k sorts and the candidate merge), whose greedy
+  rows carry the same tokens. ``Server`` counts the steps of each kind
+  (``stats()["steps_greedy_head"]`` / ``["steps_sampled_head"]``) and
+  labels the span that enqueues one (``sampler_path``).
+  ``Engine(decode_attention="reference")`` runs the
   gather-dense attention and the whole-logits sampler as the parity
   oracle.
 - :mod:`~mpit_tpu.serve.scheduler` — the continuous-batching loop:

@@ -28,7 +28,12 @@ Observability (``mpit_tpu.obs``) is first-class, not bolted on:
 - ``slot_occupancy`` gauge + ``serve_tokens``/``serve_requests``
   counters each tick; ``serve_steps_overlapped`` (a step enqueued while
   an older one was unfetched) and ``serve_steps_drained`` (a fetch with
-  nothing enqueued behind it) say how often the overlap engaged.
+  nothing enqueued behind it) say how often the overlap engaged;
+  ``serve_steps_greedy_head`` / ``serve_steps_sampled_head`` count the
+  steps enqueued by what their temperatures asked of the sampler (no
+  row samples: the blocked head takes one max a block; any row does:
+  noise, sort and candidate merge), and the ``prefill`` / ``decode``
+  span that enqueues a step says which in ``sampler_path``.
 
 An optional :class:`mpit_tpu.obs.Sentinel` (``phases=("decode",
 "prefill")``) watches the tick stream for spikes/sustained degradation
@@ -459,6 +464,10 @@ class Server:
         self._in_flight: deque[_Step] = deque()
         self.steps_overlapped = 0  # enqueued while an older step was unfetched
         self.steps_drained = 0  # fetched with nothing enqueued behind
+        # Steps enqueued, by whether any slot's temperature asked the
+        # sampler to sample (``lm_head_sample`` branches on the same).
+        self.steps_greedy_head = 0
+        self.steps_sampled_head = 0
         self.completed: list[Completed] = []
         self.shed: list[Request] = []
         self.shed_causes: dict[str, int] = {}  # cause -> count (ISSUE 12)
@@ -990,11 +999,24 @@ class Server:
             and head.tick < (self.tick if before is None else before)
         )
 
+    def _sampler_path(self) -> str:
+        """Which way the temperatures a step is given send the blocked
+        sampler: ``lm_head_sample``'s own predicate on the host's copy
+        (a compacted chunk step evaluates it over its participants'
+        rows alone, so ``sampled`` is an upper bound there)."""
+        return "sampled" if np.any(self._temp > 0) else "greedy"
+
     def _enqueued(self, step: _Step) -> None:
         """``step`` joins what is in flight, behind whatever is unfetched."""
         if self._in_flight:
             self.steps_overlapped += 1
             obs.counter("serve_steps_overlapped")
+        if self._sampler_path() == "greedy":
+            self.steps_greedy_head += 1
+            obs.counter("serve_steps_greedy_head")
+        else:
+            self.steps_sampled_head += 1
+            obs.counter("serve_steps_sampled_head")
         self._in_flight.append(step)
 
     def _fetch(self) -> _Step:
@@ -1077,6 +1099,7 @@ class Server:
                     admitted=len(finishing),
                     chunks=int((chunk_lens > 0).sum()),
                     rids=[live.req.rid for live in self.prefilling.values()],
+                    sampler_path=self._sampler_path(),
                 )
         if obs.enabled():
             attrs.update(self._labels["prefill"])
@@ -1528,6 +1551,8 @@ class Server:
                 cache_rows=int(lens.sum()) if decoding else 0,
                 **self._labels["decode"],
             )
+            if decoding:
+                attrs["sampler_path"] = self._sampler_path()
         t0 = time.perf_counter()
         with obs.span("decode", **attrs):
             if decoding:
@@ -2216,6 +2241,10 @@ class Server:
             # work queued while the host handled tokens.
             "steps_overlapped": self.steps_overlapped,
             "steps_drained": self.steps_drained,
+            # Steps enqueued in which no slot asked for sampling (the
+            # blocked head's greedy scan) and in which one did.
+            "steps_greedy_head": self.steps_greedy_head,
+            "steps_sampled_head": self.steps_sampled_head,
         }
         # The cache's wire dtype (ISSUE 15): what a cached row occupies
         # HBM as — "int8" on the quantized engines, the model dtype

@@ -303,6 +303,60 @@ class TestJaxprLibrary:
         with pytest.raises(jaxpr_check.JaxprContractError):
             jaxpr_check.assert_no_transfer(jx)
 
+    def test_primitive_pin_descends_nested_jaxprs(self):
+        import jax
+        import jax.numpy as jnp
+        from jax import lax
+
+        def f(x):
+            def body(c, _):
+                return lax.top_k(c, 4)[0], ()
+
+            out, _ = lax.scan(body, x, None, length=2)
+            return out / 2.0
+
+        jx = jax.make_jaxpr(f)(jnp.ones((3, 4)))
+        assert jaxpr_check.find_primitives(jx, {"top_k", "div"}) == [
+            "top_k", "div",
+        ]
+        jaxpr_check.assert_no_primitive(jx, {"sort", "random_bits"})
+        with pytest.raises(jaxpr_check.JaxprContractError, match="top_k"):
+            jaxpr_check.assert_no_primitive(jx, {"top_k"})
+
+    @pytest.mark.parametrize("broken", ["greedy_sorts", "no_cond"])
+    def test_lm_head_sample_contract_catches_a_lost_greedy_path(
+        self, monkeypatch, broken
+    ):
+        """The ``lm-head-sample`` contract holds on the tree, and fails
+        when the sampler goes back to one path that sorts for every
+        call, or keeps two that both do."""
+        import jax
+        import jax.numpy as jnp
+        from jax import lax
+
+        from mpit_tpu.ops import lm_head
+
+        assert jaxpr_check.sweep(names={"lm-head-sample"}) == []
+
+        def sorts(h, head):
+            return lax.top_k(h @ head.T[:, :8], 2)[1][:, 0]
+
+        def one_path(h, head, key, temperature, top_k, **kw):
+            if broken == "no_cond":
+                return sorts(h, head)
+            return lax.cond(
+                jnp.any(temperature > 0),
+                lambda: sorts(h, head) + 1, lambda: sorts(h, head),
+            )
+
+        monkeypatch.setattr(lm_head, "lm_head_sample", one_path)
+        (violation,) = jaxpr_check.sweep(names={"lm-head-sample"})
+        assert "lm-head-sample" in violation.message
+        assert (
+            "0 top-level conditionals" if broken == "no_cond"
+            else "greedy path contains ['top_k']"
+        ) in violation.message
+
     def test_donation_detection(self):
         import jax
         import jax.numpy as jnp

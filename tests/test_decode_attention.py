@@ -532,6 +532,143 @@ class TestLMHeadSample:
         got = lm_head_sample(h, head, key, temp, topk, block_size=64)
         assert jnp.all(got == self._oracle(full, key, temp, topk, 64))
 
+    # -- the greedy path (ISSUE 33): a call in which no row samples takes
+    # one max and one argmax a block and nothing else ---------------------
+    @staticmethod
+    def _exact_setup(S=6, D=24, V=203, seed=0):
+        """Small whole numbers: every product and every partial sum is
+        exact in bfloat16 operands and float32 accumulation alike, in
+        whatever order a backend adds, so logits TIE often and a tie is
+        a tie for the blocked head and the oracle both."""
+        rng = np.random.RandomState(seed)
+        h = jnp.asarray(rng.randint(-3, 4, (S, D)).astype(np.float32))
+        head = jnp.asarray(rng.randint(-2, 3, (V, D)).astype(np.float32))
+        return h, head
+
+    @staticmethod
+    def _full_logits(h, head, cd):
+        from mpit_tpu.ops.quantized_matmul import (
+            QuantizedTensor, dequantize_tensor,
+        )
+
+        if isinstance(head, QuantizedTensor):
+            head = dequantize_tensor(head)
+        return jnp.dot(
+            h.astype(cd), head.astype(cd).T,
+            preferred_element_type=jnp.float32,
+        )
+
+    @pytest.mark.parametrize("cd", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize(
+        "case", ["ties", "ragged_vocab", "one_block", "quantized_head"]
+    )
+    def test_all_greedy_batch_is_full_argmax(self, case, cd):
+        """Bit for bit ``argmax`` of the full float32 logits, first
+        occurrence on a tie, across and within blocks."""
+        from mpit_tpu.ops.quantized_matmul import quantize_tensor
+
+        block = 64
+        if case == "ties":
+            # Rows 7, 70 (another block) and 71 (the same block) repeat
+            # row 3; h's first row is chosen to make them the maximum.
+            h, head = self._exact_setup(V=256)
+            head = head.at[jnp.asarray([7, 70, 71])].set(head[3])
+            h = h.at[0].set(3.0 * jnp.sign(head[3]))
+        elif case == "ragged_vocab":
+            h, head = self._exact_setup(V=203)  # 3 blocks and 11 rows
+        elif case == "one_block":
+            h, head = self._exact_setup(V=50)
+            block = 8192  # clamped to the vocabulary rounded up to 128
+        else:
+            h, head = self._exact_setup(V=203)
+            head = quantize_tensor(head)
+        full = self._full_logits(h, head, cd)
+        S = h.shape[0]
+        got = lm_head_sample(
+            h, head, jax.random.key(3), jnp.zeros((S,), jnp.float32),
+            jnp.zeros((S,), jnp.int32), block_size=block, compute_dtype=cd,
+        )
+        want = jnp.argmax(full, -1).astype(jnp.int32)
+        assert got.dtype == jnp.int32 and jnp.all(got == want)
+        if case == "ties":
+            assert int(got[0]) == 3
+            assert int(jnp.sum(full[0] == full[0].max())) >= 4
+
+    @pytest.mark.parametrize("cd", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize(
+        "temps",
+        [
+            (0.0, 0.8, 0.0, 0.0, -1.0, 0.0),  # one sampling row
+            (0.5, 0.0, 2.0, 0.0, 1.0, 0.7),  # mostly sampling rows
+        ],
+    )
+    def test_greedy_rows_do_not_depend_on_their_neighbours_path(
+        self, temps, cd
+    ):
+        """A sampling row sends the whole call down the general path;
+        the greedy rows' tokens are the all-greedy call's same rows, and
+        the sampled and top-k rows are the full-logits oracle's under
+        the same key (what the function gave before it had two paths)."""
+        h, head = self._exact_setup()
+        key = jax.random.key(11)
+        temp = jnp.asarray(temps, jnp.float32)
+        topk = jnp.asarray([0, 0, 3, 50, 7, 0], jnp.int32)
+        zeros = jnp.zeros_like(temp)
+        all_greedy = lm_head_sample(
+            h, head, key, zeros, topk, block_size=64, compute_dtype=cd
+        )
+        mixed = lm_head_sample(
+            h, head, key, temp, topk, block_size=64, compute_dtype=cd
+        )
+        greedy = np.asarray(temp) <= 0
+        assert greedy.any() and not greedy.all()
+        assert np.array_equal(
+            np.asarray(mixed)[greedy], np.asarray(all_greedy)[greedy]
+        )
+        full = self._full_logits(h, head, cd)
+        assert jnp.all(mixed == self._oracle(full, key, temp, topk, 64))
+        assert jnp.all(all_greedy == jnp.argmax(full, -1))
+
+    def test_path_is_chosen_on_the_device_under_jit(self):
+        """One compiled function serves both kinds of call: the
+        predicate is data, not a trace-time constant."""
+        h, head = self._exact_setup()
+        key = jax.random.key(5)
+        topk = jnp.zeros((6,), jnp.int32)
+        f = jax.jit(
+            lambda t: lm_head_sample(h, head, key, t, topk, block_size=64)
+        )
+        full = self._full_logits(h, head, jnp.float32)
+        zeros = jnp.zeros((6,), jnp.float32)
+        assert jnp.all(f(zeros) == jnp.argmax(full, -1))
+        warm = jnp.full((6,), 1.3, jnp.float32)
+        assert jnp.all(f(warm) == self._oracle(full, key, warm, topk, 64))
+        assert f._cache_size() == 1
+
+    def test_greedy_branch_holds_no_sampling_work(self):
+        """The jaxpr of the branch a greedy call takes: no sort, no
+        noise, no division, no gather; the other branch has them all."""
+        from mpit_tpu.analysis import jaxpr_check as jc
+
+        h, head = self._setup()
+        jx = jax.make_jaxpr(
+            lambda h, w, t, k: lm_head_sample(
+                h, w, jax.random.key(0), t, k, block_size=64
+            )
+        )(h, head, jnp.zeros((5,), jnp.float32), jnp.zeros((5,), jnp.int32))
+        greedy, general = jc.sampler_branches(jx)
+        jc.assert_no_primitive(greedy, jc.SAMPLING_PRIMS)
+        assert {"top_k", "random_bits", "div", "gather"} <= set(
+            jc.find_primitives(general, jc.SAMPLING_PRIMS)
+        )
+        # One product a block on either path, and the scan's carry on
+        # the greedy one is two vectors of a value a row.
+        assert "dot_general" in jc.find_primitives(greedy, {"dot_general"})
+        (scan,) = [
+            e for e in jc._walk_eqns(greedy) if e.primitive.name == "scan"
+        ]
+        assert scan.params["num_carry"] == 2
+
     def test_no_full_logits_in_jaxpr(self):
         """The pin, same style as the training LM-head: no [S, vocab]
         f32 intermediate anywhere in the jaxpr when block < vocab."""

@@ -558,6 +558,72 @@ class TestServeKernelObservability:
         # Short contexts in a 32-row cache must have skipped tiles.
         assert summ["counters"]["decode_blocks_skipped"] > 0
 
+    @pytest.mark.parametrize("temperature", [0.0, 0.8])
+    def test_sampler_path_counters_and_span_label(
+        self, model_and_params, temperature
+    ):
+        """ISSUE 33: every step enqueued is counted by what its
+        temperatures ask of the blocked head, and the span that enqueues
+        it says which. A greedy run counts ``steps_greedy_head`` = steps
+        and no other; one ``temperature`` 0.8 request turns every step
+        it lives through into a ``sampled`` one, and the steps after it
+        has gone are greedy again."""
+        _, params = model_and_params
+        rec = obs.Recorder()
+
+        def enqueuing_spans():
+            spans = [
+                (e[1], e[5] or {}) for e in rec.snapshot()["events"]
+                if e[0] == "X"
+            ]
+            steps = sum(
+                n in ("decode_dispatch", "prefill_dispatch")
+                for n, _ in spans
+            )
+            paths = [
+                a["sampler_path"] for n, a in spans
+                if n in ("decode", "prefill") and "sampler_path" in a
+            ]
+            assert len(paths) == steps  # fetch-only spans carry none
+            return steps, paths
+
+        with obs.local_recorder(rec):
+            engine = Engine(CFG, params, slots=2, max_len=32, prefill_len=8)
+            server = Server(engine)
+            # The request that may sample is admitted first and ends last.
+            server.submit(Request(
+                rid=0, prompt=PROMPTS[0], max_new_tokens=9,
+                temperature=temperature,
+            ))
+            for i in (1, 2):
+                server.submit(
+                    Request(rid=i, prompt=PROMPTS[i], max_new_tokens=3)
+                )
+            assert len(server.run()) == 3
+            steps, paths = enqueuing_spans()
+            want = "sampled" if temperature > 0 else "greedy"
+            other = "greedy" if temperature > 0 else "sampled"
+            assert steps >= 9 and paths == [want] * steps
+            stats = server.stats()
+            assert stats[f"steps_{want}_head"] == steps
+            assert stats[f"steps_{other}_head"] == 0
+            assert rec.counter_total(f"serve_steps_{want}_head") == steps
+            assert rec.counter_total(f"serve_steps_{other}_head") == 0
+            # With the sampling request gone the next steps are greedy.
+            server.submit(
+                Request(rid=3, prompt=PROMPTS[3], max_new_tokens=3)
+            )
+            server.run()
+            later, paths = enqueuing_spans()
+        assert later > steps and paths[steps:] == ["greedy"] * (later - steps)
+        stats = server.stats()
+        assert stats["steps_greedy_head"] == later - (
+            steps if temperature > 0 else 0
+        )
+        assert stats["steps_sampled_head"] == (
+            steps if temperature > 0 else 0
+        )
+
     @pytest.mark.parametrize(
         "how,decode,prefill",
         [
