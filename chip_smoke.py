@@ -36,6 +36,13 @@ raises — there is no handler that lets the run end 0):
                  gather-dense reference, at the shapes of
                  ``benchmark/configs/olmo-hybrid-7b-8of32.json``; each
                  kernel's call timed. ``--phases`` picks one-chip phases.
+- ``dsa_kernels`` learned sparse attention's three operations
+                 (``ops/dsa.py``) alone at the shapes of
+                 ``benchmark/configs/glm-5.2-5of78-ep16.json``: the index
+                 scores' kernel against its lax twin (a tick's row a slot
+                 and a chunk's rows), the choice by bisection against
+                 ``lax.top_k`` as sets, the chosen rows' attention against
+                 a masked dense one; each call timed.
 - ``dp4``        (``--chips 4`` only, and then the only phase) ZeRO-1 data
                  parallel over four chips vs the same global batch and seed
                  on one of them; then ``grad_sync=ring`` and ``ring_q8``.
@@ -80,6 +87,12 @@ TOL_X4_PAGED_VS_PLAIN = 0.15
 # reference: GPT-2's tolerance, same weights and dtype, other op order.
 TOL_GDN_KERNEL_VS_TWIN = 0.02
 
+# The index scores' kernel against its twin: relu-weighted sums of 32
+# bf16 dot products of order 10; the chosen rows' attention against the
+# masked dense one: weighted latents of order 0.1-1 in bf16.
+TOL_DSA_SCORES = 0.05
+TOL_DSA_ATTN = 0.03
+
 FULL = dict(
     model=["--num-layers", "12", "--d-model", "768", "--num-heads", "12",
            "--vocab-size", "50257", "--seq-len", "1024"],
@@ -100,6 +113,11 @@ FULL = dict(
     # and the page.
     gdn=dict(h=30, dk=96, dv=192, chunk=512, seqs=2, slots=64,
              positions=4096, page=128, interpret=None),
+    # The glm52 cell's shapes: a tick's slots, a chunk's rows, a slot's
+    # positions and the page, the indexer's heads and width, the choice,
+    # attention's heads against a latent of 512 and a rotary key of 64.
+    dsa=dict(slots=16, chunk=512, positions=36864, page=256, hi=32, di=128,
+             topk=2048, heads=64, latent=512, rope=64, interpret=None),
 )
 TINY = dict(
     model=["--num-layers", "2", "--d-model", "64", "--num-heads", "4",
@@ -117,6 +135,8 @@ TINY = dict(
     xing4_probe=(27, 3),
     gdn=dict(h=3, dk=12, dv=24, chunk=100, seqs=2, slots=3, positions=256,
              page=16, interpret=True),
+    dsa=dict(slots=3, chunk=16, positions=128, page=16, hi=4, di=128,
+             topk=8, heads=4, latent=128, rope=16, interpret=True),
 )
 
 
@@ -775,6 +795,94 @@ def phase_gdn_kernels(sz, seed: int, rehearse: bool) -> None:
             assert err <= TOL_GDN_KERNEL_VS_TWIN, (name, err)
 
 
+def phase_dsa_kernels(sz, seed: int, rehearse: bool) -> None:
+    """Learned sparse attention's three operations alone, at the glm52
+    cell's shapes: each against what it must equal, each call timed."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax import lax
+
+    from mpit_tpu.ops import dsa
+    from mpit_tpu.ops import mla_attention as mla
+
+    g = sz["dsa"]
+    b, ps, k, interp = g["slots"], g["page"], g["topk"], g["interpret"]
+    pps = g["positions"] // ps
+    dt = jnp.float32 if rehearse else jnp.bfloat16
+    key = jax.random.key(seed)
+    ks = jax.random.split(key, 8)
+    rs = np.random.RandomState(seed)
+    table = jnp.asarray(rs.permutation(b * pps).reshape(b, pps), jnp.int32)
+    lengths = jnp.asarray(rs.randint(
+        g["positions"] // 2, g["positions"] - g["chunk"], size=b), jnp.int32)
+    key_pool = jax.random.normal(ks[0], (b * pps, ps, g["di"]), dt)
+    out = {"rows_cached": int(lengths.sum()) + b}
+    kern = jax.jit(lambda *a: dsa.dsa_index_scores(*a, interpret=interp))
+    twin = jax.jit(dsa.reference_dsa_index_scores)
+    scores = {}
+    for name, rows, t in (("tick", b, 1), ("chunk", 1, g["chunk"])):
+        q = jax.random.normal(ks[1], (rows, t, g["hi"], g["di"]), dt)
+        w = 0.1 * jax.random.normal(ks[2], (rows, t, g["hi"]), jnp.float32)
+        args = (q, w, key_pool, lengths[:rows], table[:rows])
+        got, want = kern(*args), twin(*args)
+        seen = jnp.isfinite(want)
+        assert bool(jnp.all(jnp.isfinite(got) == seen)), name
+        out[f"scores_{name}_err"] = _max_abs(
+            jnp.where(seen, got, 0.0), jnp.where(seen, want, 0.0))
+        out[f"scores_{name}_kernel_ms"] = _median_ms(kern, *args)
+        out[f"scores_{name}_twin_ms"] = _median_ms(twin, *args)
+        scores[name] = got
+        del want
+    for name, sc in scores.items():
+        select = jax.jit(lambda s: dsa.dsa_select(s, k))
+        top = jax.jit(lambda s: lax.top_k(s, k)[1])
+        mask, idx = select(sc), top(sc)
+        flat = np.asarray(mask).reshape(-1, mask.shape[-1])
+        want = np.zeros(flat.shape, bool)
+        np.put_along_axis(want, np.asarray(idx).reshape(-1, k), True, -1)
+        out[f"select_{name}_rows_differing"] = int(
+            (flat != want).any(-1).sum())
+        out[f"select_{name}_ms"] = _median_ms(select, sc)
+        out[f"top_k_{name}_ms"] = _median_ms(top, sc)
+    to_rows = jax.jit(lambda m: dsa.mask_to_rows(m, k))
+    mask = jax.jit(lambda s: dsa.dsa_select(s, k))(scores["tick"])[:, 0]
+    rows, n = to_rows(mask)
+    want = np.stack([np.flatnonzero(r)[:k] for r in np.asarray(mask)])
+    out["mask_to_rows_wrong"] = int((np.asarray(rows) != want).sum())
+    out["mask_to_rows_ms"] = _median_ms(to_rows, mask)
+    ckv = jax.random.normal(ks[3], (b * pps, ps, g["latent"]), dt)
+    kr = jax.random.normal(ks[4], (b * pps, ps, mla.lane_pad(g["rope"])), dt)
+    qa = jax.random.normal(ks[5], (b, g["heads"], g["latent"]), dt) * 0.05
+    qr = jax.random.normal(ks[6], (b, g["heads"], g["rope"]), dt) * 0.05
+    attn = jax.jit(lambda *a: dsa.dsa_sparse_attn(*a, scale=1.0))
+    got = attn(qa, qr, ckv, kr, rows, n, table)
+
+    def dense(qa, qr, ckv, kr, mask, table):  # every row, the mask applied
+        c_all = ckv[table].reshape(b, -1, g["latent"])
+        r_all = kr[table].reshape(b, -1, kr.shape[-1])[..., : g["rope"]]
+        s = jnp.einsum("bhc,bkc->bhk", qa, c_all,
+                       preferred_element_type=jnp.float32)
+        s = s + jnp.einsum("bhr,bkr->bhk", qr, r_all,
+                           preferred_element_type=jnp.float32)
+        p = jax.nn.softmax(jnp.where(mask[:, None], s, -1e30), -1)
+        return jnp.einsum("bhk,bkc->bhc", p.astype(c_all.dtype), c_all,
+                          preferred_element_type=jnp.float32)
+
+    out["sparse_attn_err"] = _max_abs(
+        got, jax.jit(dense)(qa, qr, ckv, kr, mask, table))
+    out["sparse_attn_ms"] = _median_ms(attn, qa, qr, ckv, kr, rows, n, table)
+    out["rows_read"] = int(n.sum())
+    emit("dsa_kernels", **out, shapes=g,
+         tolerance=dict(scores=TOL_DSA_SCORES, attn=TOL_DSA_ATTN))
+    for name, err in out.items():
+        if name.startswith("scores_") and name.endswith("_err"):
+            assert err <= TOL_DSA_SCORES, (name, err)
+        if name.endswith("_differing") or name.endswith("_wrong"):
+            assert err == 0, (name, err)
+    assert out["sparse_attn_err"] <= TOL_DSA_ATTN, out["sparse_attn_err"]
+
+
 def _holds_a_shard_each(state, devices) -> dict:
     """ZeRO-1 optimizer state must span every device, a shard on each."""
     import jax
@@ -865,7 +973,7 @@ def main(argv=None) -> int:
     parser.add_argument("--seed", type=int, default=0)
     parser.add_argument("--rehearse", action="store_true")
     # One-chip phases to run, by name (all: every one, in this order).
-    one_chip = ("train", "serve", "serve_xing4", "gdn_kernels")
+    one_chip = ("train", "serve", "serve_xing4", "gdn_kernels", "dsa_kernels")
     parser.add_argument("--phases", default="all",
                         help="comma list of " + ", ".join(one_chip))
     args = parser.parse_args(argv)
@@ -895,6 +1003,8 @@ def main(argv=None) -> int:
             phase_serve_xing4(sz, args.seed, args.rehearse)
         if "gdn_kernels" in phases:
             phase_gdn_kernels(sz, args.seed, args.rehearse)
+        if "dsa_kernels" in phases:
+            phase_dsa_kernels(sz, args.seed, args.rehearse)
         ok = True
     if args.rehearse:
         # A rehearsal is never a pass: it says what it ran on, and 3.

@@ -1,8 +1,8 @@
 """sha256 of the lowered text of the steps the benchmark's cells run, AOT
 for a described v5e (no chip needed): GPT-2 large's paged decode, chunk
-and page-copy steps at the serving cells' shape, xing4's decode and
-compacted chunk steps at its cell's shape, GPT-2 small's train step over
-the 2x2. Two trees that print the same hashes run the same device
+and page-copy steps at the serving cells' shape, xing4's and glm_dsa's
+decode and compacted chunk steps at their cells' shapes, GPT-2 small's
+train step over the 2x2. Two trees that print the same hashes run the same device
 programs; a refactoring PR proves itself with
 
     python lowered_hashes.py            # from the root of each tree
@@ -104,6 +104,36 @@ def main():
                 jnp.zeros((n,), bool), bt, key, f32, i32)
         out[f"xing4.jit_prefill_paged.compact{n}"] = sha(eng._prefill_compact_jit.lower(*on_chip(args)).as_text())
     del eng
+
+    # glm_dsa, its cell's shape (a tree from before the family prints nothing for it)
+    try:
+        from mpit_tpu.models import glm_dsa
+    except ImportError:
+        glm_dsa = None
+    if glm_dsa is not None:
+        G_S, G_POS, G_PAGE, G_CHUNK = 16, 36864, 256, 512
+        gcfg = glm_dsa.GlmDsaConfig(
+            num_hidden_layers=5, vocab_size=19360, mlp_layer_types=("dense",) + ("sparse",) * 4,
+            indexer_types=("full", "shared", "shared", "shared", "full"), experts_held=tuple(range(16)),
+            max_seq_len=G_POS)
+        gparams = jax.eval_shape(lambda: glm_dsa.init_params(gcfg, jax.random.key(0)))
+        pps = G_POS // G_PAGE
+        eng = Engine(gcfg, gparams, slots=G_S, max_len=G_POS, seed=1, kv_pages=2 * pps, kv_page_size=G_PAGE,
+                     prefill_chunk=G_CHUNK, sample_block=4840)
+        seats = lambda bufs: tuple(
+            b if b is None else jax.ShapeDtypeStruct((G_S * pps, *b.shape[1:]), b.dtype) for b in bufs)
+        cache = dataclasses.replace(eng.cache, k=seats(eng.cache.k), v=seats(eng.cache.v), x=seats(eng.cache.x))
+        i32, f32 = jnp.zeros((G_S,), jnp.int32), jnp.zeros((G_S,), jnp.float32)
+        bt = jnp.zeros((G_S, pps), jnp.int32)
+        args = (eng.params, cache, eng.last_token, jnp.zeros((G_S,), bool), bt, key, f32, i32)
+        out["glm_dsa.jit_decode_paged"] = sha(eng._decode_paged_jit.lower(*on_chip(args)).as_text())
+        for n in eng._prefill_counts:
+            z = jnp.zeros((n,), jnp.int32)
+            args = (eng.params, cache, eng.last_token, z, jnp.zeros((n, G_CHUNK), jnp.int32), z, z, z,
+                    jnp.zeros((n,), bool), bt, key, f32, i32)
+            out[f"glm_dsa.jit_prefill_paged.compact{n}"] = sha(
+                eng._prefill_compact_jit.lower(*on_chip(args)).as_text())
+        del eng
 
     # GPT-2 small train step, as benchmark/drivers/pretrain.py builds it, over the described 2x2 (the dp4 cell)
     from jax.sharding import PartitionSpec as P

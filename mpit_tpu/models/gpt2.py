@@ -573,7 +573,7 @@ class GPT2ServeModel(ServeModel):
     def cache_layout(self) -> CacheLayout:
         cfg = self.cfg
         width = cfg.num_heads * cfg.head_dim
-        return CacheLayout((PageLayer(width, width),) * cfg.num_layers,
+        return CacheLayout((PageLayer((width, width)),) * cfg.num_layers,
                            cfg.dtype, scale_width=cfg.num_heads)
 
     def kv_row_bytes(self, dtype) -> float:

@@ -398,7 +398,7 @@ class OlmoHybridServeModel(ServeModel):
             ("conv", ((cfg.linear_conv_kernel_dim - 1) * cfg.conv_channels,),
              cfg.dtype),
         ))
-        page = PageLayer(cfg.hidden_size, cfg.hidden_size)
+        page = PageLayer((cfg.hidden_size, cfg.hidden_size))
         return CacheLayout(
             tuple(page if kind == FULL else seat for kind in cfg.layer_types),
             cfg.dtype)

@@ -6,8 +6,10 @@ cache row of ``heads x head_dim``. It now takes a :class:`ServeModel` and
 asks it, and nothing else, for what differs between families:
 
 - the **cache layout** (:meth:`ServeModel.cache_layout`): for each of
-  the model's layers, whether it keeps pages (the widths of the two
-  buffers a page pool keeps it) or a fixed state a slot (the shapes and
+  the model's layers, whether it keeps pages (how many seats a cached
+  position has in that layer's pool, and their widths: two everywhere
+  but where a sparse-attention layer keeps an index key beside its
+  latent and its rotary key) or a fixed state a slot (the shapes and
   dtypes of what a sequence keeps between steps). GPT-2 caches a key and
   a value of ``heads x head_dim`` each; a latent-attention model caches
   one latent row all heads share and the rotary part of its key; a
@@ -45,12 +47,31 @@ __all__ = ["CacheLayout", "PageLayer", "StateLayer", "ServeModel",
 
 @dataclasses.dataclass(frozen=True)
 class PageLayer:
-    """A layer that keeps pages: ``k_width`` and ``v_width`` values a
-    cached position in the pool's two seats (what they hold is the
-    family's business)."""
+    """A layer that keeps pages: ``widths`` says how many seats a cached
+    position has in this layer's pool (two or three) and the values it
+    keeps in each, under one block table and one lifetime. What a seat
+    holds is the family's business; the pool calls the first two ``k``
+    and ``v`` and a third ``x``."""
 
-    k_width: int
-    v_width: int
+    widths: tuple
+
+    def __post_init__(self):
+        if len(self.widths) not in (2, 3):
+            raise ValueError(
+                f"a page layer keeps two or three seats, not {self.widths}")
+
+    @property
+    def k_width(self) -> int:
+        return self.widths[0]
+
+    @property
+    def v_width(self) -> int:
+        return self.widths[1]
+
+    @property
+    def x_width(self) -> int:
+        """The third seat's width; 0 where the layer keeps two."""
+        return self.widths[2] if len(self.widths) == 3 else 0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -89,16 +110,23 @@ class CacheLayout:
     def prefix_shareable(self) -> bool:
         """Whether pages mapped from another sequence's prefix are all
         the sequence needs: not where a layer's state at that boundary
-        would have to be restored too."""
-        return not self.state_layers
+        would have to be restored too, and not yet where a layer
+        keeps a third seat (untested with a mapped prefix)."""
+        return not self.state_layers and not self.third_seats
 
     def page_bytes(self, page_size: int, dtype, quantized: bool) -> int:
-        """One page across every page-holding layer and both seats, as
-        the pool stores it (an int8 pool: payload and float32 scales)."""
+        """One page across every page-holding layer and every seat it
+        keeps, as the pool stores it (an int8 pool: payload and float32
+        scales a seat)."""
         item = 1 if quantized else np.dtype(dtype).itemsize
-        scales = 2 * 4 * self.scale_width if quantized else 0
+        scales = 4 * self.scale_width if quantized else 0
         return page_size * sum(
-            (l.k_width + l.v_width) * item + scales for l in self.page_layers)
+            w * item + scales for l in self.page_layers for w in l.widths)
+
+    @property
+    def third_seats(self) -> bool:
+        """Whether any page layer keeps a third seat."""
+        return any(l.x_width for l in self.page_layers)
 
     def state_slot_bytes(self) -> int:
         """What one slot keeps in the state pool, every layer."""
@@ -136,6 +164,12 @@ class ServeModel:
         """Raise if a live slot of this family cannot be evicted and
         resumed (what it keeps beside pages would be lost)."""
 
+    def rows_attended(self, cached):
+        """Of ``cached`` rows a slot holds (an integer array), those a
+        decode tick's attention reads a layer: all of them unless the
+        family's attention chooses (the ``decode`` span's ``rows_read``)."""
+        return cached
+
     # -- the injected kernels -------------------------------------------------
     def with_decode_attention(self, *, block_k: int, interpret,
                               page_size: int) -> "ServeModel":
@@ -161,7 +195,8 @@ class ServeModel:
                       *, return_hidden, row_valid=None, slot_index=None):
         """``(out, (k, v, state), aux)``: the page buffers and the state
         pool as the step leaves them (``cache.state`` where the layout
-        has no state layer). ``row_valid`` [B, T] marks the rows that are
+        has no state layer); a family whose layout has third seats
+        returns ``(k, v, state, x)``, ``x`` as ``cache.x`` holds it. ``row_valid`` [B, T] marks the rows that are
         real tokens (a family may skip the others). ``slot_index`` [B]
         says which slot's seat in the state pool each batch row reads
         and leaves (an index past the slots: a padding row, nothing

@@ -267,10 +267,15 @@ def _dot(x, w, out_dtype=None):
         out_dtype or x.dtype)
 
 
-def mla_project(ap, h, cfg: Xing4Config, cos, sin):
+def mla_project(ap, h, cfg, cos, sin, *, rope=None, with_cq: bool = False):
     """Queries and the row to cache from ``h`` [B, T, d] (normalised):
     ``q_nope`` [B, T, H, dn], ``q_rope`` [B, T, H, dr] (rotated), ``c_kv``
-    [B, T, C] (normalised), ``k_rope`` [B, T, dr] (rotated)."""
+    [B, T, C] (normalised), ``k_rope`` [B, T, dr] (rotated). ``cfg`` is
+    any configuration with the latent-attention keys (``models/glm_dsa.py``
+    shares this); ``rope`` rotates in another pair layout than
+    :func:`apply_rope`'s, and ``with_cq`` appends the normalised query
+    latent ``c_q`` [B, T, q_lora_rank] (an indexer projects from it)."""
+    rope = rope or apply_rope
     hn, dn, dr = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
                   cfg.qk_rope_head_dim)
     c_q = rms_norm(_dot(h, ap["w_dq"]), ap["q_norm"], cfg.rms_norm_eps)
@@ -279,18 +284,20 @@ def mla_project(ap, h, cfg: Xing4Config, cos, sin):
     kv = _dot(h, ap["w_dkv"])
     c_kv = rms_norm(kv[..., :cfg.kv_lora_rank], ap["kv_norm"],
                     cfg.rms_norm_eps)
-    k_rope = apply_rope(kv[..., cfg.kv_lora_rank:], cos, sin)
-    q_rope = apply_rope(q_rope, cos[..., None, :], sin[..., None, :])
+    k_rope = rope(kv[..., cfg.kv_lora_rank:], cos, sin)
+    q_rope = rope(q_rope, cos[..., None, :], sin[..., None, :])
+    if with_cq:
+        return q_nope, q_rope, c_kv, k_rope, c_q
     return q_nope, q_rope, c_kv, k_rope
 
 
-def _w_ukv(ap, cfg: Xing4Config):
+def _w_ukv(ap, cfg):
     return ap["w_ukv"].reshape(
         cfg.kv_lora_rank, cfg.num_attention_heads,
         cfg.qk_nope_head_dim + cfg.v_head_dim)
 
 
-def mla_absorbed(ap, q_nope, q_rope, attend, cfg: Xing4Config):
+def mla_absorbed(ap, q_nope, q_rope, attend, cfg):
     """Attention with ``W_UK`` folded into the query and ``W_UV`` into the
     output: ``attend(q_abs [B, H, C], q_rope [B, H, dr]) -> [B, H, C]``
     (the weighted latents). One query position a slot."""
@@ -307,9 +314,11 @@ def mla_absorbed(ap, q_nope, q_rope, attend, cfg: Xing4Config):
     return o.reshape(o.shape[0], -1)
 
 
-def mla_expanded_dense(ap, q_nope, q_rope, c_kv, k_rope, cfg: Xing4Config):
+def mla_expanded_dense(ap, q_nope, q_rope, c_kv, k_rope, cfg, select=None):
     """Causal expanded attention of whole sequences, nothing cached:
-    ``[B, T, H * dv]``. The plain forward's, for tests."""
+    ``[B, T, H * dv]``. The plain forward's, for tests. ``select``
+    [B, T, T] bool: the positions a query may attend to beside being
+    causal (a sparse-attention layer's choice)."""
     dn = cfg.qk_nope_head_dim
     kv = jnp.einsum("bkc,chd->bkhd", c_kv, _w_ukv(ap, cfg),
                     preferred_element_type=jnp.float32).astype(c_kv.dtype)
@@ -319,13 +328,15 @@ def mla_expanded_dense(ap, q_nope, q_rope, c_kv, k_rope, cfg: Xing4Config):
                        preferred_element_type=jnp.float32)
     t = s.shape[-1]
     causal = jnp.tril(jnp.ones((t, t), bool))
+    if select is not None:
+        causal = causal & select[:, None]
     p = jax.nn.softmax(jnp.where(causal, s * cfg.softmax_scale, -jnp.inf), -1)
     o = jnp.einsum("bhtk,bkhd->bthd", p.astype(kv.dtype), kv[..., dn:],
                    preferred_element_type=jnp.float32).astype(c_kv.dtype)
     return o.reshape(*o.shape[:2], -1)
 
 
-def mlp_or_experts(lp, u, cfg: Xing4Config, valid):
+def mlp_or_experts(lp, u, cfg, valid):
     """The layer's second sublayer on ``u`` [B, T, d]: the gated MLP of a
     leading dense layer, else the expert layer. Returns ``(y, counts)``,
     ``counts`` [E] int32 or None."""
@@ -488,7 +499,8 @@ class Xing4ServeModel(ServeModel):
         # The latent and, in a buffer of its own padded to whole lane
         # tiles, the key's rotary part: 512 + 128 values a position.
         cfg = self.cfg
-        row = PageLayer(cfg.kv_lora_rank, mla.lane_pad(cfg.qk_rope_head_dim))
+        row = PageLayer(
+            (cfg.kv_lora_rank, mla.lane_pad(cfg.qk_rope_head_dim)))
         return CacheLayout((row,) * cfg.num_hidden_layers, cfg.dtype)
 
     def kv_row_bytes(self, dtype) -> float:

@@ -212,14 +212,18 @@ def mla_paged_decode_attention(
 
 def mla_paged_prefill_attention(
     q_nope, q_rope, ckv_pool, kr_pool, lengths, block_table, w_ukv, *,
-    scale, tile: int = 1024,
+    scale, tile: int = 1024, select=None,
 ):
     """Expanded attention of a chunk: ``q_nope`` [B, T, H, dn] and
     ``q_rope`` [B, T, H, dr], query ``t`` at position ``lengths + t``,
     against positions ``0 .. lengths + T - 1`` of each slot's pages (the
     chunk's own rows already written). ``w_ukv`` [C, H, dn + dv] expands a
     tile of latents to its keys and values; tiles past the longest slot's
-    last visible position are never visited. Returns ``[B, T, H, dv]``."""
+    last visible position are never visited. ``select`` [B, T, S] bool
+    (``S`` the slot's positions; None: none) is a second condition on
+    visibility: query ``t`` attends to position ``s`` only where it is
+    set (learned sparse attention's choice, ``ops/dsa.py``). Returns
+    ``[B, T, H, dv]``."""
     b, t, h, dn = q_nope.shape
     dr = q_rope.shape[-1]
     dv = w_ukv.shape[-1] - dn
@@ -235,6 +239,9 @@ def mla_paged_prefill_attention(
     # products and their sum were three [H, T, tile] float32 arrays
     # through HBM where this is one.)
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
+    if select is not None:  # whole tiles, so that a tile is one slice
+        select = jnp.pad(
+            select, ((0, 0), (0, 0), (0, n_max * tk - select.shape[-1])))
 
     def body(i, carry):
         m, l, acc = carry
@@ -254,6 +261,8 @@ def mla_paged_prefill_attention(
                        preferred_element_type=jnp.float32)
         k_pos = i * tk + jnp.arange(tk)
         vis = k_pos[None, None, :] <= t_pos[:, :, None]  # [B, T, tk]
+        if select is not None:
+            vis = vis & lax.dynamic_slice_in_dim(select, i * tk, tk, axis=2)
         s = jnp.where(vis[:, None], s * scale, _NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)

@@ -96,6 +96,19 @@ prefix hit is passed up and counted: ``prefix_hits_passed_up``).
 that divides the vocabulary (7168 for this family's 100,352) spares a
 padded copy of the table a step.
 
+``--family glm_dsa`` (ISSUE 34) serves the fourth: latent attention
+under learned sparse attention (``models/glm_dsa.py``, ``ops/dsa.py``): a
+layer with an indexer keeps an index key a cached position in a third
+seat of the page pool, scores every cached position for the query and
+attends to the ``index_topk`` best only; the layers marked ``shared``
+reuse the choice of the layer before them. ``--model tiny`` or
+``published``, or ``--model-config FILE`` (a file cut to one chip's share
+of an expert-parallel deployment gives the experts held under
+``n_routed_experts`` and the router's width under ``published``). It
+raises, by name, for ``--mesh``, ``--kv-dtype int8``, ``--weights-dtype
+int8``, ``--spec-k``, ``--kv-host-pages``, a ``--policy`` that preempts,
+``--fleet`` shipment, ``--ckpt``; a repeated prompt is computed whole.
+
 Config follows the ``asyncsgd.config`` pattern: one dataclass, argparse
 generated from its fields.
 """
@@ -117,7 +130,7 @@ class ServeConfig:
     """Options for the serving CLI (the ``opt`` table analogue)."""
 
     ckpt: str = ""  # dense .npz from --save-dense ("" = random init)
-    family: str = "gpt2"  # the model family: gpt2 | xing4 | olmo_hybrid
+    family: str = "gpt2"  # gpt2 | xing4 | olmo_hybrid | glm_dsa
     model: str = "tiny"  # random-init size: tiny | small (families: published)
     # xing4: a JSON file with the keys of the published config.json (as
     # benchmark/configs/xing4-29b-a4b-6of40.json holds them); "" = --model.
@@ -225,22 +238,30 @@ class ServeConfig:
         return parse_mesh(self.mesh)
 
 
-def _xing4_model(cfg: ServeConfig):
-    """Random weights of the ``xing4`` family (``models/xing4.py``): the
-    published sizes, those of ``--model-config``'s file, or the tiny
-    preset. The family serves on one chip in bf16 or f32; the engine
-    raises, by name, for what it lacks (``--mesh``, ``--kv-dtype int8``,
-    ``--weights-dtype int8``, ``--spec-k``, ``--kv-host-pages``)."""
-    from mpit_tpu.models.xing4 import Xing4Config, init_params
+# The families that serve random weights from --seed: the module of each
+# under ``mpit_tpu.models`` and its configuration class there. Each serves
+# on one chip in bf16 or f32; the engine and the server raise, by name,
+# for what a family lacks (``--mesh``, ``--kv-dtype int8``,
+# ``--weights-dtype int8``, ``--spec-k``, ``--kv-host-pages``, and for a
+# family that keeps more than two-seat pages a preempting ``--policy``).
+_FAMILIES = {
+    "xing4": ("xing4", "Xing4Config"),
+    "olmo_hybrid": ("olmo_hybrid", "OlmoHybridConfig"),
+    "glm_dsa": ("glm_dsa", "GlmDsaConfig"),
+}
 
-    return _family_model(cfg, Xing4Config, init_params)
 
+def _family_model(cfg: ServeConfig):
+    """Random weights of a family that has no checkpoint loader
+    (``_FAMILIES``): the published sizes, those of ``--model-config``'s
+    file, or the tiny preset."""
+    import importlib
 
-def _family_model(cfg: ServeConfig, config_cls, init_params):
-    """Random weights of a family that has no checkpoint loader: the
-    published sizes, those of ``--model-config``'s file, or the tiny
-    preset."""
     import jax
+
+    module, config_name = _FAMILIES[cfg.family]
+    module = importlib.import_module(f"mpit_tpu.models.{module}")
+    config_cls, init_params = getattr(module, config_name), module.init_params
 
     if cfg.ckpt:
         raise SystemExit(
@@ -255,16 +276,6 @@ def _family_model(cfg: ServeConfig, config_cls, init_params):
     else:
         mcfg = config_cls(max_seq_len=longest)
     return init_params(mcfg, jax.random.key(cfg.seed)), mcfg
-
-
-def _olmo_hybrid_model(cfg: ServeConfig):
-    """Random weights of the ``olmo_hybrid`` family
-    (``models/olmo_hybrid.py``). The family serves on one chip in bf16 or
-    f32; the engine and the server raise, by name, for what would move or
-    roll back a slot's recurrent state."""
-    from mpit_tpu.models.olmo_hybrid import OlmoHybridConfig, init_params
-
-    return _family_model(cfg, OlmoHybridConfig, init_params)
 
 
 def _build_engine(cfg: ServeConfig):
@@ -316,13 +327,12 @@ def _build_engine(cfg: ServeConfig):
             "fused-dequant matmuls"
         )
 
-    if cfg.family == "xing4":
-        params, mcfg = _xing4_model(cfg)
-    elif cfg.family == "olmo_hybrid":
-        params, mcfg = _olmo_hybrid_model(cfg)
+    if cfg.family in _FAMILIES:
+        params, mcfg = _family_model(cfg)
     elif cfg.family != "gpt2":
         raise SystemExit(
-            f"--family {cfg.family!r}: expected gpt2, xing4 or olmo_hybrid")
+            f"--family {cfg.family!r}: expected gpt2, "
+            + ", ".join(_FAMILIES))
     elif cfg.ckpt:
         params, mcfg = load_gpt2_params(cfg.ckpt, num_heads=cfg.num_heads)
     else:

@@ -754,13 +754,14 @@ class Engine:
                     write_valid, row_valid=None, slot_index=None):
                 # Blocked head: the forward ends at ln_f and the step
                 # samples from hiddens; the reference engine: logits.
-                out, (k2, v2, state2), aux = model.forward_paged(
+                # (k, v, state), and the third seats after them where
+                # the family's layout has any.
+                out, (k2, v2, *rest), aux = model.forward_paged(
                     prms, tokens, cache, block_tables, write_valid,
                     return_hidden=self._blocked_head, row_valid=row_valid,
                     slot_index=slot_index,
                 )
-                new = PagedKVCache(k=k2, v=v2, lengths=cache.lengths,
-                                   state=state2)
+                new = PagedKVCache(k2, v2, cache.lengths, *rest)
                 return (out, new) if aux is None else (out, new, aux)
 
         self.model = model
@@ -930,6 +931,10 @@ class Engine:
         )
         # Per-execution modeled costs (set by register_roofline).
         self.roofline_costs: dict | None = None
+        # What the model's steps counted beside tokens, while a recorder
+        # was on (_note_aux): totals, and the last fetched step's a phase.
+        self.step_counts: dict = {}
+        self.last_counts: dict = {}
         # WIRE bytes, not logical bytes: an int8 weight store's param
         # read per decode tick is the int8 payload + the f32 scale
         # column (ISSUE 17 — decode_achieved_hbm_bytes must count what
@@ -957,7 +962,7 @@ class Engine:
         self.memledger = MemLedger(platform=platform)
         register_param_store(self.memledger, self.params)
         nbytes = lambda tree: sum(l.nbytes for l in jax.tree.leaves(tree))
-        kv_buf = nbytes((self.cache.k, self.cache.v))
+        kv_buf = nbytes((self.cache.k, self.cache.v, self.cache.x))
         # The state pool (what the layout's recurrent layers keep a
         # slot; nothing for a model whose layers all keep pages).
         state_buf = nbytes(self.cache.state)
@@ -1384,7 +1389,8 @@ class Engine:
             )
 
         cp = lambda c: dataclasses.replace(
-            c, k=jax.tree.map(cp1, c.k), v=jax.tree.map(cp1, c.v)
+            c, k=jax.tree.map(cp1, c.k), v=jax.tree.map(cp1, c.v),
+            x=jax.tree.map(cp1, c.x),
         )
         if not self.spec_k:
             return cp(cache)
@@ -1624,16 +1630,31 @@ class Engine:
         obs.gauge("prefill_rows_computed", float(computed))
         obs.gauge("prefill_rows_valid", float(valid))
 
-    @staticmethod
-    def _note_aux(phase: str, aux) -> None:
+    def _note_aux(self, phase: str, aux) -> None:
         """What the model counted in a step, as counters and gauges:
         per-layer, per-expert token counts ``[layers, experts]`` give
         ``moe_expert_tokens`` (by layer), ``moe_experts_hit`` (experts with
-        a token, mean over layers) and ``moe_load_max_over_mean``. Called
-        after the step's tokens are on the host, so it waits for nothing."""
+        a token, mean over layers) and ``moe_load_max_over_mean``. A
+        family that counts more hands a dict: the token counts under
+        ``expert_tokens`` and every other entry a counter of its own
+        name, summed (``step_counts`` keeps their totals and
+        ``last_counts`` what the last fetched step of a phase said, for
+        the scheduler's span). Called after the step's tokens are on the
+        host, so it waits for nothing."""
         if not aux:
             return
-        counts = np.asarray(aux[0])
+        counts, more = aux[0], {}
+        if isinstance(counts, dict):
+            got = jax.device_get(counts)  # one fetch for all of them
+            counts = got.pop("expert_tokens", None)
+            more = {k: float(np.sum(v)) for k, v in got.items()}
+        for name, value in more.items():
+            obs.counter(name, value, phase=phase)
+            self.step_counts[name] = self.step_counts.get(name, 0.0) + value
+        self.last_counts[phase] = more
+        if counts is None:
+            return
+        counts = np.asarray(counts)
         for layer, row in enumerate(counts):
             obs.counter("moe_expert_tokens", float(row.sum()), layer=layer)
         obs.gauge("moe_experts_hit",

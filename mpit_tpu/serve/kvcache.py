@@ -144,9 +144,14 @@ class PagedKVCache:
     # sequence whatever its length), donated and returned by the steps
     # as the pages are. Empty for a model whose layers all keep pages.
     state: Any = ()
+    # The third seats: ``()`` where no page layer of the layout keeps
+    # one (the leaves are then what they always were), else one entry a
+    # page layer, a buffer ``[num_pages, page_size, width]`` under the
+    # same block table as ``k`` / ``v``, or None for a two-seat layer.
+    x: Any = ()
 
     def tree_flatten(self):
-        return (self.k, self.v, self.lengths, self.state), None
+        return (self.k, self.v, self.lengths, self.state, self.x), None
 
     @classmethod
     def tree_unflatten(cls, _aux, children):
@@ -180,7 +185,8 @@ def alloc_paged_cache(
     quantized: bool = False,
 ) -> PagedKVCache:
     """Allocate the zeroed page pool: per layer one K and one V buffer
-    ``[num_pages, page_size, heads*head_dim]``. HBM cost is ``num_pages ×
+    ``[num_pages, page_size, heads*head_dim]`` (and a third seat where
+    the model's layout says the layer keeps one). HBM cost is ``num_pages ×
     page_size`` cache rows — chosen by budget, independent of ``slots``
     (the batch width) and of any per-slot ``max_len`` — and the packed
     rows are whole 128-lane tiles at GPT-2's widths, so the device holds
@@ -200,6 +206,8 @@ def alloc_paged_cache(
     return PagedKVCache(
         k=tuple(seat(l.k_width) for l in layout.page_layers),
         v=tuple(seat(l.v_width) for l in layout.page_layers),
+        x=tuple(seat(l.x_width) if l.x_width else None
+                for l in layout.page_layers) if layout.third_seats else (),
         lengths=jnp.zeros((slots,), jnp.int32),
         # A recurrent layer's seats, a slot each: zeros, though a slot's
         # first chunk starts from zeros whatever its seat holds.

@@ -1101,6 +1101,13 @@ class Server:
                     rids=[live.req.rid for live in self.prefilling.values()],
                     sampler_path=self._sampler_path(),
                 )
+                # As the decode span's: what each of the chunk's rows
+                # finds cached, itself included, and what it reads.
+                seen = np.concatenate([
+                    b + 1 + np.arange(n) for b, n in zip(base, chunk_lens)])
+                attrs["rows_cached"] = int(seen.sum())
+                attrs["rows_read"] = int(
+                    self.engine.model.rows_attended(seen).sum())
         if obs.enabled():
             attrs.update(self._labels["prefill"])
         with obs.span("prefill", **attrs):
@@ -1553,8 +1560,15 @@ class Server:
             )
             if decoding:
                 attrs["sampler_path"] = self._sampler_path()
+                # Rows a layer's attention finds cached (the new row
+                # with them) and those of them it reads: fewer only
+                # where the family's attention chooses its rows.
+                attrs["rows_cached"] = int((lens + 1).sum())
+                attrs["rows_read"] = int(
+                    self.engine.model.rows_attended(lens + 1).sum())
         t0 = time.perf_counter()
-        with obs.span("decode", **attrs):
+        span = obs.span("decode", **attrs)
+        with span:
             if decoding:
                 self._enqueued(_Step(
                     "decode",
@@ -1567,6 +1581,9 @@ class Server:
                     live.issued += 1
                     live.last_touch = self.tick
             landed = self._land("decode", self.tick)
+            if landed and attrs:
+                # What the fetched step counted on the device.
+                span.attrs.update(self.engine.last_counts.get("decode", {}))
         with obs.span("retire"):
             for step in landed:
                 self._settle_decode(step, t0)
@@ -2241,6 +2258,8 @@ class Server:
             # work queued while the host handled tokens.
             "steps_overlapped": self.steps_overlapped,
             "steps_drained": self.steps_drained,
+            # What the model's steps counted beside tokens (a recorder on).
+            "step_counts": dict(self.engine.step_counts),
             # Steps enqueued in which no slot asked for sampling (the
             # blocked head's greedy scan) and in which one did.
             "steps_greedy_head": self.steps_greedy_head,

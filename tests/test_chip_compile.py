@@ -633,3 +633,124 @@ def test_olmo_hybrid_paged_steps_fit_and_update_in_place(
     held = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
     assert held < OH_LIMIT, held
+
+
+# GLM-5.2 as benchmark/configs/glm-5.2-5of78-ep16.json serves it: 1 dense
+# + 4 expert layers, 16 of 256 experts and 19,360 rows of the vocabulary
+# held, two layers with an indexer (a third seat of 128 values a
+# position); 16 slots of 36,864 positions in pages of 256, chunks of 512
+# rows (four slots' in the largest compacted step), the sampler's tile
+# 4,840 rows.
+GD_SLOTS, GD_POSITIONS, GD_PAGE, GD_CHUNK, GD_SAMPLE = 16, 36864, 256, 512, 4840
+GD_LIMIT = 14.0e9  # arguments + temporaries a step may hold (ISSUE 34)
+
+
+def _gd_config():
+    from mpit_tpu.models.glm_dsa import GlmDsaConfig
+
+    return GlmDsaConfig(
+        num_hidden_layers=5, vocab_size=19360,
+        mlp_layer_types=("dense",) + ("sparse",) * 4,
+        indexer_types=("full", "shared", "shared", "shared", "full"),
+        experts_held=tuple(range(16)), max_seq_len=GD_POSITIONS)
+
+
+@pytest.mark.parametrize("t", [1, GD_CHUNK], ids=["tick", "chunk"])
+def test_dsa_index_scores_kernel(v5e, t):
+    """The index scores' kernel at the cell's shapes: a tick's row a slot
+    against 144 pages of keys, and a chunk's 512 rows of one slot."""
+    from mpit_tpu.ops.dsa import dsa_index_scores
+
+    cfg = _gd_config()
+    b = GD_SLOTS if t == 1 else 1
+    pps = GD_POSITIONS // GD_PAGE
+    hi, di = cfg.index_n_heads, cfg.index_head_dim
+    text = _compile_on_chip(
+        v5e,
+        lambda q, w, pool, lens, bt: dsa_index_scores(
+            q, w, pool, lens, bt, interpret=False),
+        _sds((b, t, hi, di), jnp.bfloat16), _sds((b, t, hi), jnp.float32),
+        _sds((2 * pps, GD_PAGE, di), jnp.bfloat16),
+        _sds((b,), jnp.int32), _sds((b, pps), jnp.int32),
+    )
+    assert ("dsa_index_scores_tick" if t == 1
+            else "dsa_index_scores_chunk") in text
+
+
+@pytest.fixture(scope="module")
+def glm_dsa_engine(v5e):
+    """The configuration's engine on shapes alone (7.8 GB of parameters
+    are never made) and a two-slot pool; the steps are then lowered for
+    the pool of all 16 slots."""
+    from mpit_tpu.models.glm_dsa import init_params
+    from mpit_tpu.ops import decode_attention
+    from mpit_tpu.serve import Engine
+
+    cfg = _gd_config()
+    params = jax.eval_shape(lambda: init_params(cfg, jax.random.key(0)))
+    pps = GD_POSITIONS // GD_PAGE
+    was = decode_attention._use_kernel
+    decode_attention._use_kernel = lambda interpret: True
+    eng = Engine(cfg, params, slots=GD_SLOTS, max_len=GD_POSITIONS,
+                 kv_pages=2 * pps, kv_page_size=GD_PAGE,
+                 prefill_chunk=GD_CHUNK, sample_block=GD_SAMPLE)
+    one = SingleDeviceSharding(v5e.devices[0])
+    yield eng, lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one), tree)
+    decode_attention._use_kernel = was
+
+
+@pytest.mark.parametrize("step", ["decode", "prefill"])
+def test_glm_dsa_paged_steps_fit_and_update_in_place(glm_dsa_engine, step):
+    """Both steps of the cell, pool of 16 x 36,864 positions: every buffer
+    of the pool, the third seats among them, aliased to an output; the
+    kernels in the text; arguments + temporaries under 14.0 GB (printed:
+    11.9 GB of them are the weights and the pool; the chunk step is the
+    one of four slots' 512 rows)."""
+    import dataclasses
+
+    eng, on_chip = glm_dsa_engine
+    assert eng._prefill_counts == (1, 2, 4)  # 2,048 rows a step at most
+    pages = GD_SLOTS * eng.pages_per_slot
+    full = lambda bufs: tuple(
+        b if b is None else jax.ShapeDtypeStruct((pages, *b.shape[1:]),
+                                                  b.dtype) for b in bufs)
+    cache = dataclasses.replace(
+        eng.cache, k=full(eng.cache.k), v=full(eng.cache.v),
+        x=full(eng.cache.x))
+    s = eng.slots
+    i32, f32 = jnp.zeros((s,), jnp.int32), jnp.zeros((s,), jnp.float32)
+    bt, key = jnp.zeros((s, eng.pages_per_slot), jnp.int32), jax.random.key(0)
+    if step == "decode":
+        jit, args = eng._decode_paged_jit, (
+            eng.params, cache, eng.last_token, jnp.zeros((s,), bool), bt,
+            key, f32, i32)
+        kernels = ("dsa_index_scores_tick", "mla_paged_decode_attn")
+    else:
+        n = eng._prefill_counts[-1]  # the largest step a tick can meet
+        z = jnp.zeros((n,), jnp.int32)
+        jit, args = eng._prefill_compact_jit, (
+            eng.params, cache, eng.last_token, z,
+            jnp.zeros((n, GD_CHUNK), jnp.int32), z, z, z,
+            jnp.zeros((n,), bool), bt, key, f32, i32)
+        kernels = ("dsa_index_scores_chunk", "paged_kv_write")
+    compiled = jit.lower(*on_chip(args)).compile()
+    text = compiled.as_text()
+    for name in kernels:
+        assert name in text, name
+    mem = compiled.memory_analysis()
+    pool = jax.tree.leaves((cache.k, cache.v, cache.x))
+    assert len(pool) == 5 + 5 + 2
+    pool_bytes = sum(l.size * l.dtype.itemsize for l in pool)
+    assert pool_bytes == GD_SLOTS * GD_POSITIONS * 6912
+    assert mem.alias_size_in_bytes >= pool_bytes
+    held = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    print(f"glm_dsa {step}: arguments {mem.argument_size_in_bytes / 1e9:.2f} "
+          f"GB, temporaries {mem.temp_size_in_bytes / 1e9:.2f} GB, held "
+          f"{held / 1e9:.2f} GB")
+    assert held < GD_LIMIT, held
+    if step == "decode":
+        # A tick's temporaries are O(rows): far under the smallest buffer.
+        assert mem.temp_size_in_bytes < min(
+            l.size * l.dtype.itemsize for l in pool)

@@ -1187,7 +1187,8 @@ class TestOneEngine:
             assert c.tokens == ref_greedy(
                 model, engine.params, c.prompt, len(c.tokens))
 
-    @pytest.mark.parametrize("family", ["gpt2", "xing4", "olmo_hybrid"])
+    @pytest.mark.parametrize(
+        "family", ["gpt2", "xing4", "olmo_hybrid", "glm_dsa"])
     def test_family_conforms_to_the_model_interface(self, family):
         """What the engine asks of a family, asked of each: the cache
         row layout times the pool's dtype is ``Engine.page_bytes``,
@@ -1215,9 +1216,14 @@ class TestOneEngine:
                 "host_pages": (2, "host KV tier"),
             }
         else:
-            from mpit_tpu.models.xing4 import Xing4Config, init_params
+            if family == "glm_dsa":
+                from mpit_tpu.models.glm_dsa import (
+                    GlmDsaConfig as Config, init_params)
+            else:
+                from mpit_tpu.models.xing4 import (
+                    Xing4Config as Config, init_params)
 
-            cfg = Xing4Config.tiny(max_seq_len=64, dtype=jnp.float32)
+            cfg = Config.tiny(max_seq_len=64, dtype=jnp.float32)
             params = init_params(cfg, jax.random.key(0))
             lacks = {
                 "tp": (True, "tensor parallelism"),
@@ -1241,14 +1247,15 @@ class TestOneEngine:
                         kv_page_size=ps)
         itemsize = jnp.dtype(lay.dtype).itemsize
         assert engine.page_bytes == ps * itemsize * sum(
-            l.k_width + l.v_width for l in lay.page_layers)
+            w for l in lay.page_layers for w in l.widths)
         row = lay.page_layers[0]
         assert model.kv_row_bytes(lay.dtype) == (
             (row.k_width + row.v_width) / 2 * itemsize)
         assert engine.slot_state_bytes == lay.state_slot_bytes() == sum(
             leaf.nbytes for leaf in jax.tree.leaves(engine.cache.state)
         ) // slots
-        assert engine.allocator.prefix_shareable == (not lay.state_layers)
+        assert engine.allocator.prefix_shareable == (
+            not lay.state_layers and not lay.third_seats)
         cache = alloc_paged_cache(cfg, slots, pages, ps)
         k_want = [(pages, ps, l.k_width) for l in lay.page_layers]
         v_want = [(pages, ps, l.v_width) for l in lay.page_layers]
@@ -1260,8 +1267,13 @@ class TestOneEngine:
         assert [b.shape for b in cache.k] == k_want
         assert [b.shape for b in cache.v] == v_want
         assert shapes(cache.state) == state_want
+        # A third seat where the layout gives the layer one, and only there.
+        x_want = [(pages, ps, l.x_width) if l.x_width else None
+                  for l in lay.page_layers] if lay.third_seats else []
+        seats = lambda x: [None if b is None else b.shape for b in x]
+        assert seats(cache.x) == x_want
         t = 4
-        out, (k2, v2, state2), aux = model.forward_paged(
+        out, (k2, v2, state2, *x2), aux = model.forward_paged(
             params, jnp.ones((slots, t), jnp.int32), cache,
             jnp.arange(slots * 4, dtype=jnp.int32).reshape(slots, 4),
             jnp.ones((slots, t), bool), return_hidden=True,
@@ -1273,7 +1285,8 @@ class TestOneEngine:
         assert [b.shape for b in v2] == v_want
         assert shapes(state2) == state_want
         assert all(b.dtype == lay.dtype for b in (*k2, *v2))
-        assert (aux is None) == (family != "xing4")
+        assert [seats(x) for x in x2] == ([x_want] if lay.third_seats else [])
+        assert (aux is None) == (family not in ("xing4", "glm_dsa"))
 
 
 class TestServeCLI:

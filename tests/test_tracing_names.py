@@ -359,3 +359,68 @@ def test_a_state_keeping_family_names_its_layers_and_its_passed_up_hits():
             server.run(max_ticks=server.tick + 3)
         server.run()
     assert rec.counter_total("prefix_hits_passed_up") == 1
+
+
+def test_a_choosing_family_names_its_layers_and_counts_what_it_reads():
+    """The fourth family's steps lower with the scopes its per-layer
+    metrics read (``benchmark/families/glm_dsa/scopes.json`` lists the
+    same names) and its index-scores kernel under its two names; its
+    ``decode`` and ``prefill`` spans carry the rows attention found
+    cached and those it read, and the counters of what the steps counted
+    on the device land on the ``decode`` span."""
+    import json
+    import os
+
+    from mpit_tpu.models.glm_dsa import GlmDsaConfig, init_params
+
+    cfg = GlmDsaConfig.tiny(max_seq_len=64)
+    engine = Engine(cfg, init_params(cfg, jax.random.key(0)), slots=2,
+                    max_len=64, kv_page_size=16, prefill_chunk=16,
+                    decode_attention="interpret")
+    s = engine.slots
+    i32, f32 = jnp.zeros((s,), jnp.int32), jnp.zeros((s,), jnp.float32)
+    bt = jnp.asarray(engine.allocator.block_tables, jnp.int32)
+    key = jax.random.key(0)
+    decode = (engine._decode_paged_jit, (
+        engine.params, engine.cache, engine.last_token,
+        jnp.ones((s,), bool), bt, key, f32, i32))
+    prefill = (engine._prefill_paged_jit, (
+        engine.params, engine.cache, engine.last_token,
+        jnp.zeros((s, 16), jnp.int32), i32, i32, i32,
+        jnp.zeros((s,), bool), bt, key, f32, i32))
+    texts = [jit.lower(*args).as_text(debug_info=True)
+             for jit, args in (decode, prefill)]
+    with open(os.path.join(
+            os.path.dirname(__file__), "..", "benchmark", "families",
+            "glm_dsa", "scopes.json")) as f:
+        stated = json.load(f)["scopes"]
+    assert {"dsa_index", "dsa_select", "dsa_sparse_attn"} <= set(stated)
+    for scope in stated:
+        assert any(re.search(rf'["/(]{scope}[/)]', t) for t in texts), scope
+    assert "dsa_index_scores_tick" in _pallas_names(
+        jax.make_jaxpr(decode[0])(*decode[1]).jaxpr)
+    assert "dsa_index_scores_chunk" in _pallas_names(
+        jax.make_jaxpr(prefill[0])(*prefill[1]).jaxpr)
+    rec = obs.Recorder()
+    with obs.local_recorder(rec):
+        server = Server(engine)
+        server.submit(Request(rid=1, prompt=list(range(1, 20)),
+                              max_new_tokens=6))
+        server.run()
+    spans = {n: [e[5] for e in rec.snapshot()["events"] if e[1] == n]
+             for n in ("decode", "prefill")}
+    ticks = [a for a in spans["decode"] if a.get("active")]
+    # 19 rows and the new one cached, the 8 chosen read, and so on up.
+    assert [(a["rows_cached"], a["rows_read"]) for a in ticks] == [
+        (20 + i, 8) for i in range(len(ticks))]
+    chunks = [a for a in spans["prefill"] if a.get("chunks")]
+    assert [a["rows_cached"] for a in chunks] == [
+        sum(range(1, 17)), sum(range(17, 20))]
+    assert [a["rows_read"] for a in chunks] == [
+        sum(min(r, 8) for r in range(1, 17)), 8 * 3]
+    landed = [a for a in spans["decode"] if "dsa_rows_read" in a]
+    assert landed and all(
+        a["dsa_rows_read"] == 8 * cfg.num_hidden_layers for a in landed)
+    for name in ("dsa_rows_read", "dsa_rows_cached", "moe_choices",
+                 "moe_choices_here"):
+        assert rec.counter_total(name) > 0
