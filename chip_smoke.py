@@ -43,6 +43,12 @@ raises — there is no handler that lets the run end 0):
                  and a chunk's rows), the choice by bisection against
                  ``lax.top_k`` as sets, the chosen rows' attention against
                  a masked dense one; each call timed.
+- ``chunk_rows`` GPT-2 large at the serving cells' shape (16 slots of 1,024
+                 positions, chunks of 64): the compacted chunk step timed
+                 alone at 16 to 1,024 rows, against the full-batch step,
+                 and the host's side of a chunk tick (``prefill_dispatch``)
+                 both ways: the table ``serve/engine.py``'s
+                 ``_WEIGHT_BOUND_ROWS`` is read from.
 - ``dp4``        (``--chips 4`` only, and then the only phase) ZeRO-1 data
                  parallel over four chips vs the same global batch and seed
                  on one of them; then ``grad_sync=ring`` and ``ring_q8``.
@@ -118,6 +124,12 @@ FULL = dict(
     # attention's heads against a latent of 512 and a rotary key of 64.
     dsa=dict(slots=16, chunk=512, positions=36864, page=256, hi=32, di=128,
              topk=2048, heads=64, latent=512, rope=64, interpret=None),
+    # The GPT-2 large cells' shape, and the (participants, chunk width)
+    # pairs whose compacted step is timed: 16 to 1,024 rows.
+    chunk_rows=dict(layers=36, heads=20, d_model=1280, vocab=50257,
+                    slots=16, positions=1024, page=16, chunk=64, prefix=256,
+                    steps=((1, 16), (1, 32), (1, 64), (2, 64), (4, 64),
+                           (8, 64), (16, 64)), reps=20),
 )
 TINY = dict(
     model=["--num-layers", "2", "--d-model", "64", "--num-heads", "4",
@@ -137,6 +149,9 @@ TINY = dict(
              page=16, interpret=True),
     dsa=dict(slots=3, chunk=16, positions=128, page=16, hi=4, di=128,
              topk=8, heads=4, latent=128, rope=16, interpret=True),
+    chunk_rows=dict(layers=2, heads=4, d_model=64, vocab=512, slots=4,
+                    positions=128, page=16, chunk=16, prefix=32,
+                    steps=((1, 8), (1, 16), (2, 16), (4, 16)), reps=2),
 )
 
 
@@ -883,6 +898,163 @@ def phase_dsa_kernels(sz, seed: int, rehearse: bool) -> None:
     assert out["sparse_attn_err"] <= TOL_DSA_ATTN, out["sparse_attn_err"]
 
 
+def phase_chunk_rows(sz, seed: int, rehearse: bool) -> None:
+    """What a chunk step costs by its rows, on GPT-2 large at the serving
+    cells' shape: the compacted step alone (its arguments on the device,
+    ``reps`` calls enqueued back to back, the last waited for) for each
+    ``(participants, width)`` of ``sz["chunk_rows"]["steps"]``, the
+    full-batch step with two participants, and the host's time in
+    ``Engine.prefill_dispatch`` for one and two participants through the
+    compacted and the full-batch step."""
+    import gc
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from mpit_tpu.models import GPT2, GPT2Config
+    from mpit_tpu.serve import Engine, warm_engine
+    from mpit_tpu.serve import engine as engine_module
+
+    c = sz["chunk_rows"]
+    cfg = GPT2Config(
+        vocab_size=c["vocab"], max_seq_len=c["positions"],
+        num_layers=c["layers"], num_heads=c["heads"], d_model=c["d_model"],
+        d_ff=4 * c["d_model"])
+    shapes = jax.eval_shape(
+        lambda: GPT2(cfg).init(jax.random.key(0),
+                               jnp.zeros((1, 8), jnp.int32))["params"])
+    leaves, tree = jax.tree.flatten(shapes)
+    keys = jax.random.split(jax.random.key(seed), len(leaves))
+    params = jax.tree.unflatten(tree, [
+        (0.02 * jax.random.normal(k, l.shape)).astype(jnp.bfloat16)
+        for k, l in zip(keys, leaves)])
+    s, w, pps = c["slots"], c["chunk"], c["positions"] // c["page"]
+
+    def build():
+        return Engine(
+            cfg, params, slots=s, max_len=c["positions"], seed=seed,
+            kv_pages=s * pps, kv_page_size=c["page"], prefill_chunk=w,
+            decode_attention=sz["decode_attention"])
+
+    def enqueued_ms(call, reps):
+        """``reps`` calls back to back, the last waited for: a call's
+        time where the device is the slower side."""
+        jax.block_until_ready(call())
+        t0 = time.perf_counter()
+        for _ in range(reps):
+            out = call()
+        jax.block_until_ready(out)
+        return round(1e3 * (time.perf_counter() - t0) / reps, 3)
+
+    def build_under(bound):
+        """An engine whose chunk steps start at ``bound`` rows: the
+        steered constant is the tests' way, never an option."""
+        kept = engine_module._WEIGHT_BOUND_ROWS
+        engine_module._WEIGHT_BOUND_ROWS = bound
+        try:
+            return build()
+        finally:
+            engine_module._WEIGHT_BOUND_ROWS = kept
+
+    counts = engine_module._chunk_step_counts(s, w)  # the rule's own
+    eng = build_under(w)  # a step a count from one participant up
+    # Every slot owns its own pages, a prefix of c["prefix"] rows cached.
+    table = jnp.arange(s * pps, dtype=jnp.int32).reshape(s, pps)
+    key = jax.random.key(seed)
+    temp, topk = jnp.zeros((s,), jnp.float32), jnp.zeros((s,), jnp.int32)
+    rng = np.random.RandomState(seed)
+
+    def compact_call(n, width):
+        toks = jnp.asarray(rng.randint(0, c["vocab"], (n, width)), jnp.int32)
+        idx = jnp.arange(n, dtype=jnp.int32)
+        base = jnp.full((n,), c["prefix"], jnp.int32)
+        lens = jnp.full((n,), width, jnp.int32)
+        floor, mask = jnp.zeros((n,), jnp.int32), jnp.ones((n,), bool)
+
+        def call():
+            eng.cache, eng.last_token, *_ = eng._prefill_compact_jit(
+                eng.params, eng.cache, eng.last_token, idx, toks, base,
+                lens, floor, mask, table, key, temp, topk)
+            return eng.last_token
+        return call
+
+    def full_call(takers):
+        toks = jnp.asarray(rng.randint(0, c["vocab"], (s, w)), jnp.int32)
+        lens = jnp.where(jnp.arange(s) < takers, w, 0).astype(jnp.int32)
+        base = jnp.where(lens > 0, c["prefix"], 0).astype(jnp.int32)
+        floor = jnp.zeros((s,), jnp.int32)
+
+        def call():
+            eng.cache, eng.last_token, *_ = eng._prefill_paged_jit(
+                eng.params, eng.cache, eng.last_token, toks, base, lens,
+                floor, lens > 0, table, key, temp, topk)
+            return eng.last_token
+        return call
+
+    steps = [
+        dict(participants=n, width=width, rows=n * width,
+             step_ms=enqueued_ms(compact_call(n, width), c["reps"]))
+        for n, width in c["steps"]]
+    full_ms = enqueued_ms(full_call(2), c["reps"])
+    peak = (jax.local_devices()[0].memory_stats() or {}).get(
+        "peak_bytes_in_use")
+
+    def host_ms(fn, reps=3 * c["reps"]):
+        """Median host time of ``fn()`` alone (what it enqueued is waited
+        for outside the clock)."""
+        times = []
+        for _ in range(reps):
+            t0 = time.perf_counter()
+            out = fn()
+            times.append(time.perf_counter() - t0)
+            jax.block_until_ready(out)
+        return round(1e3 * sorted(times)[len(times) // 2], 3)
+
+    def dispatch_ms(engine, takers):
+        toks = rng.randint(0, c["vocab"], (s, w)).astype(np.int32)
+        lens = np.where(np.arange(s) < takers, w, 0).astype(np.int32)
+        zeros = np.zeros((s,), np.int32)
+        return host_ms(lambda: engine.prefill_dispatch(
+            toks, zeros, lens, zeros, lens > 0,
+            np.zeros((s,), np.float32), zeros)[0])
+
+    # A group's small arguments to the device: the one vector through
+    # the jitted chunk_rows, as the compacted dispatch moves them, against
+    # a transfer each, as it did before.
+    six = [np.zeros((2,), np.int32)] * 4 + [
+        np.zeros((2, w), np.int32), np.zeros((2,), bool)]
+    vector = np.zeros((2 * (w + 5) + s * pps,), np.int32)
+    transfer = dict(
+        one_vector_ms=host_ms(lambda: eng._chunk_rows_jit(vector)),
+        six_asarray_ms=host_ms(lambda: [jnp.asarray(a) for a in six]),
+        split_ms=host_ms(lambda: engine_module._split_pair(key)),
+    )
+    t0 = time.perf_counter()
+    warm_engine(eng)
+    warm_s = round(time.perf_counter() - t0, 2)
+    compact_host = {k: dispatch_ms(eng, k) for k in (1, 2)}
+    del eng
+    gc.collect()
+    eng = build_under(1 << 30)  # the full-batch step's host side
+    assert not eng._prefill_counts
+    t0 = time.perf_counter()
+    warm_engine(eng)
+    full_warm_s = round(time.perf_counter() - t0, 2)
+    full_host = {k: dispatch_ms(eng, k) for k in (1, 2)}
+    del eng
+    gc.collect()
+    emit(
+        "chunk_rows", layers=c["layers"], d_model=c["d_model"], slots=s,
+        chunk=w, prefix_rows=c["prefix"], prefill_counts=counts,
+        compact_steps=steps, full_batch_step_ms_two_participants=full_ms,
+        peak_bytes_in_use=peak,
+        prefill_dispatch_host_ms=dict(compact=compact_host, full=full_host),
+        group_transfer_host_ms=transfer,
+        warm_engine_s=dict(compact=warm_s, full=full_warm_s),
+    )
+
+
 def _holds_a_shard_each(state, devices) -> dict:
     """ZeRO-1 optimizer state must span every device, a shard on each."""
     import jax
@@ -973,7 +1145,8 @@ def main(argv=None) -> int:
     parser.add_argument("--seed", type=int, default=0)
     parser.add_argument("--rehearse", action="store_true")
     # One-chip phases to run, by name (all: every one, in this order).
-    one_chip = ("train", "serve", "serve_xing4", "gdn_kernels", "dsa_kernels")
+    one_chip = ("train", "serve", "serve_xing4", "gdn_kernels", "dsa_kernels",
+                "chunk_rows")
     parser.add_argument("--phases", default="all",
                         help="comma list of " + ", ".join(one_chip))
     args = parser.parse_args(argv)
@@ -1005,6 +1178,8 @@ def main(argv=None) -> int:
             phase_gdn_kernels(sz, args.seed, args.rehearse)
         if "dsa_kernels" in phases:
             phase_dsa_kernels(sz, args.seed, args.rehearse)
+        if "chunk_rows" in phases:
+            phase_chunk_rows(sz, args.seed, args.rehearse)
         ok = True
     if args.rehearse:
         # A rehearsal is never a pass: it says what it ran on, and 3.

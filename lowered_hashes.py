@@ -1,6 +1,7 @@
 """sha256 of the lowered text of the steps the benchmark's cells run, AOT
 for a described v5e (no chip needed): GPT-2 large's paged decode, chunk
-and page-copy steps at the serving cells' shape, xing4's and glm_dsa's
+(the full-batch step and the compacted one a count of participants) and
+page-copy steps at the serving cells' shape, xing4's and glm_dsa's
 decode and compacted chunk steps at their cells' shapes, GPT-2 small's
 train step over the 2x2. Two trees that print the same hashes run the same device
 programs; a refactoring PR proves itself with
@@ -81,6 +82,12 @@ def main():
     toks = jnp.zeros((S, eng.prefill_chunk), jnp.int32)
     args = (eng.params, cache, eng.last_token, toks, i32, i32, i32, msk, bt, key, f32, i32)
     out["gpt2l.jit_prefill_paged"] = sha(eng._prefill_paged_jit.lower(*on_chip(args)).as_text())
+    # The compacted chunk step, one a count of participants (a tree from before GPT-2 took it prints none)
+    for n in eng._prefill_counts:
+        z = jnp.zeros((n,), jnp.int32)
+        args = (eng.params, cache, eng.last_token, z, jnp.zeros((n, eng.prefill_chunk), jnp.int32), z, z, z,
+                jnp.zeros((n,), bool), bt, key, f32, i32)
+        out[f"gpt2l.jit_prefill_paged.compact{n}"] = sha(eng._prefill_compact_jit.lower(*on_chip(args)).as_text())
     args = (cache, jnp.zeros((), jnp.int32), jnp.zeros((), jnp.int32))
     out["gpt2l.jit_copy_page"] = sha(eng._copy_page_jit.lower(*on_chip(args)).as_text())
     del eng

@@ -3,11 +3,13 @@ ONE jitted decode (+ the tiny page copy).
 
 The execution contract:
 
-- **Fixed shapes, no per-request recompiles.** Both steps run over the
-  whole slot batch — a prefill chunk on ``[slots, prefill_chunk]`` padded
-  prompt slices (slots without a chunk compute and are discarded; past
-  ``_FULL_BATCH_ROWS`` rows the tick is compacted to its participants),
-  decode on ``[slots, 1]``.
+- **Fixed shapes, no per-request recompiles.** Decode runs on
+  ``[slots, 1]``; a prefill chunk on ``[n, prefill_chunk]`` padded
+  prompt slices of the ``n`` slots that take part, ``n`` one of a few
+  compiled counts (``_chunk_step_counts``: a chunk tick costs what its
+  participants cost). A speculative or tensor-parallel engine, and one
+  too small for a step over fewer slots to be faster, runs the chunk
+  on ``[slots, prefill_chunk]`` and discards the slots without one.
 - **The cache is a page pool** (``serve.kvcache.PagedKVCache`` + the host
   ``PageAllocator``): ``kv_pages`` pages of ``kv_page_size`` positions
   shared by all slots, indirected by per-slot block tables. K/V appends
@@ -196,14 +198,57 @@ def _zeroed(cache):
 # parity oracle.
 _DECODE_MODES = ("kernel", "interpret", "reference")
 
-# Rows of a prefill chunk tick (slots x prefill_chunk) up to which the one
-# full-batch step stays: GPT-2 large's 16 x 64 and every rehearsal size.
-# Past it the tick is compacted to the slots that take part, in steps of
-# at most _COMPACT_ROWS rows (the size of a step's activations: 2,048 rows
-# of the widest model served here take 0.5 GB of temporaries, and every
-# compiled count keeps its own beside the weights and the pool).
-_FULL_BATCH_ROWS = 1024
+# A chunk step reads every block's weights whatever its rows, so up to some
+# count of rows its time does not rise with them, and past it the step is
+# bound by its products: _WEIGHT_BOUND_ROWS, the knee, read from this
+# table (197 TFLOP/s over 819 GB/s put it at 240 rows of bf16 weights at the
+# MXU's peak, whatever the model). GPT-2 large (36 blocks of width 1,280,
+# 1.42 GB of block weights: 1.7 ms at 819 GB/s) on one v5e chip, the
+# compacted chunk step alone, a prefix of 256 cached rows a participant,
+# 20 steps enqueued back to back (chip_smoke.py --phases chunk_rows; PR 35,
+# calls 1 and 3 agree to 0.03 ms):
+#
+#     rows   16     32     64     128    256    512    1,024
+#     ms     2.83   2.81   2.87   2.98   5.04   9.17   16.96
+#
+# 128 rows cost 4 % more than 64 and 6 % more than 16; 256 rows cost 69 %
+# more than 128. (The full-batch step, 1,024 rows with two slots taking
+# part: 14.93 ms.) Past the knee a step over twice the slots saves, against
+# two steps, the part of a weight read that its products no longer hide:
+# 16 % at 256 rows, 8 % at 512 and at 1,024. Every compiled count costs
+# set-up: 4.5 s of tracing, loading and a first run at 36 layers with the
+# persistent cache warm, 20-25 s cold (PR 35, call 3), and its own temporaries
+# beside the weights and the pool; a step has at most _COMPACT_ROWS rows
+# (2,048 rows of the widest model served here take 0.5 GB of them).
+_WEIGHT_BOUND_ROWS = 128
 _COMPACT_ROWS = 2048
+
+
+def _chunk_step_counts(slots: int, chunk: int) -> tuple:
+    """Counts of participants a chunk tick's step is compiled for; a tick
+    pads its participants to the next count and takes more of them than
+    the largest in several calls.
+
+    The smallest is the first power of two whose ``n x chunk`` rows reach
+    ``_WEIGHT_BOUND_ROWS``: up to there more slots ride the same weight
+    read for nothing, so no smaller step is worth compiling. Where that
+    takes several slots (a chunk narrower than the knee) it is the only
+    count: more slots than that refill in one tick too seldom for a
+    larger step's 8-16 % to pay a compiled count's set-up. Where one
+    slot's chunk is past the knee already, two slots at once are the
+    common case and the doublings whose rows fit ``_COMPACT_ROWS`` are
+    compiled too. Empty where the smallest step would be the whole slot
+    batch: the one full-batch step then serves every tick, as it does on
+    a speculative or tensor-parallel engine."""
+    n = 1
+    while n * chunk < _WEIGHT_BOUND_ROWS:
+        n *= 2
+    if n >= slots:
+        return ()
+    counts = [n]
+    while n == 1 and 2 * counts[-1] <= min(slots, _COMPACT_ROWS // chunk):
+        counts.append(2 * counts[-1])
+    return tuple(counts)
 
 
 def sample_tokens(logits, key, temperature, top_k):
@@ -848,26 +893,23 @@ class Engine:
             "prefill_paged", self._paged_prefill_step,
             donate=(1, 13) if draft else (1,),
         )
-        # A chunk tick computes [slots, prefill_chunk] rows whoever
-        # takes part. Up to _FULL_BATCH_ROWS that is cheap and the one
-        # step stays; past it the step runs over the participants
-        # only, compiled once for each power-of-two count of them
-        # whose rows fit _COMPACT_ROWS (more participants than the
-        # largest count go in several calls of a tick).
-        if (
-            slots * self.prefill_chunk > _FULL_BATCH_ROWS
-            and not self.spec_k and tp_axis is None
-        ):
-            n, counts = 1, []
-            while n <= slots and (
-                not counts or n * self.prefill_chunk <= _COMPACT_ROWS
-            ):
-                counts.append(n)
-                n *= 2
-            self._prefill_counts = tuple(counts)
+        # A chunk tick costs what its participants cost: the step runs
+        # over the slots that take part, compiled once for each count
+        # of _chunk_step_counts (one rule for every family, read from
+        # the shape). The full-batch step above stays for what cannot
+        # take the compacted one yet, and for an engine so small that
+        # no step over fewer slots would be faster.
+        if not self.spec_k and tp_axis is None:
+            self._prefill_counts = _chunk_step_counts(
+                slots, self.prefill_chunk
+            )
+        if self._prefill_counts:
             self._prefill_compact_jit = _jit_as(
                 "prefill_paged", self._paged_prefill_compact_step,
                 donate=(1,),
+            )
+            self._chunk_rows_jit = _jit_as(
+                "chunk_rows", self._chunk_rows_step
             )
         if self.spec_k:
             self._spec_draft_jit = _jit_as(
@@ -1173,6 +1215,23 @@ class Engine:
         the rows that are no tokens (padding of a chunk, idle slots);
         nothing, and nothing traced, for one that computes them all."""
         return (rows(),) if self.model.skips_invalid_rows else ()
+
+    def _chunk_rows_step(self, packed):
+        """A compacted chunk step's small arguments out of the one
+        vector the host moved them in (:meth:`_stage_chunk_rows`): the
+        group's ``slot_idx``, ``tokens``, ``base``, ``chunk_lens``,
+        ``floor`` and ``sample_mask``, and the block tables. The count of
+        participants is the vector's length."""
+        w = self.prefill_chunk
+        tables = self.slots * self.pages_per_slot
+        rows = packed[: packed.shape[0] - tables].reshape(-1, w + 5)
+        return (
+            rows[:, w], rows[:, :w], rows[:, w + 1], rows[:, w + 2],
+            rows[:, w + 3], rows[:, w + 4] != 0,
+            packed[packed.shape[0] - tables :].reshape(
+                self.slots, self.pages_per_slot
+            ),
+        )
 
     def _paged_prefill_compact_step(
         self, params, cache, last, slot_idx, tokens, base, chunk_lens,
@@ -1515,7 +1574,11 @@ class Engine:
                 tokens, base, chunk_lens, floor, sample_mask, temp, topk
             )
         aux = ()
-        with obs.span("prefill_dispatch"):  # staging and enqueue
+        computed = chunk_lens.size * self.prefill_chunk
+        valid = int(chunk_lens.sum())
+        with obs.span(  # staging and enqueue
+            "prefill_dispatch", **self._rows_attrs(computed, valid)
+        ):
             args = [
                 self.params,
                 self.cache,
@@ -1543,10 +1606,7 @@ class Engine:
                 self.cache, self.last_token, *aux = self.compile_watch.call(
                     "prefill", self._prefill_paged_jit, *args
                 )
-        return (
-            self.last_token, [aux], chunk_lens.size * self.prefill_chunk,
-            int(chunk_lens.sum()),
-        )
+        return self.last_token, [aux], computed, valid
 
     def _prefill_compact_dispatch(
         self, tokens, base, chunk_lens, floor, sample_mask, temp, topk
@@ -1557,38 +1617,59 @@ class Engine:
         next compiled count. Every group's step is enqueued; the last
         one's tokens hold them all."""
         takers = np.flatnonzero(chunk_lens > 0)
-        most, computed, aux_all = self._prefill_counts[-1], 0, []
-        with obs.span("prefill_dispatch"):  # staging and enqueue
-            bt = self._stage(
-                "block_tables", self.allocator.block_tables, np.int32
-            )
+        most = self._prefill_counts[-1]
+        groups = [takers[g : g + most] for g in range(0, len(takers), most)]
+        sizes = [
+            next(c for c in self._prefill_counts if c >= len(group))
+            for group in groups
+        ]
+        computed, aux_all = sum(sizes) * self.prefill_chunk, []
+        valid = int(chunk_lens.sum())
+        with obs.span(  # staging and enqueue
+            "prefill_dispatch", **self._rows_attrs(computed, valid)
+        ):
             temp = self._stage("temp", temp, np.float32)
             topk = self._stage("topk", topk, np.int32)
-            for g0 in range(0, len(takers), most):
-                group = takers[g0 : g0 + most]
-                n = next(c for c in self._prefill_counts if c >= len(group))
-
-                def pad(a, fill=0):  # the group's rows, then padding
-                    a = np.asarray(a)
-                    tail = np.full((n - len(group), *a.shape[1:]), fill,
-                                   a.dtype)
-                    return np.concatenate([a[group], tail])
-
+            for group, n in zip(groups, sizes):
+                staged = self._stage_chunk_rows(
+                    n, group, tokens, base, chunk_lens, floor, sample_mask
+                )
                 self.cache, self.last_token, *aux = self.compile_watch.call(
                     "prefill", self._prefill_compact_jit,
-                    self.params, self.cache, self.last_token,
-                    jnp.asarray(pad(np.arange(self.slots), self.slots),
-                                jnp.int32),
-                    jnp.asarray(pad(tokens), jnp.int32),
-                    jnp.asarray(pad(base), jnp.int32),
-                    jnp.asarray(pad(chunk_lens), jnp.int32),
-                    jnp.asarray(pad(floor), jnp.int32),
-                    jnp.asarray(pad(sample_mask), bool),
-                    bt, self._split(), temp, topk,
+                    self.params, self.cache, self.last_token, *staged,
+                    self._split(), temp, topk,
                 )
-                computed += n * self.prefill_chunk
                 aux_all.append(aux)
-        return self.last_token, aux_all, computed, int(chunk_lens.sum())
+        return self.last_token, aux_all, computed, valid
+
+    def _stage_chunk_rows(self, n, group, tokens, base, chunk_lens, floor,
+                          sample_mask) -> tuple:
+        """A compacted step's arguments from ``slot_idx`` to
+        ``block_tables``, on the device, for the slots ``group`` padded to
+        ``n`` rows (a padding row names the slot past the last and holds
+        zeros). The seven ride in ONE int32 vector, moved as the one
+        argument of the jitted :meth:`_chunk_rows_step`, which takes it
+        apart again: on the chip's host a transfer costs 0.2-0.3 ms and
+        a small jitted call 0.3-0.7 whatever they carry, so this is 0.7 ms
+        where six transfers and the tables' were 1.5-1.8 (PR 35, call 2),
+        beside 1.6 ms for the enqueue of the step itself. The vector is
+        made anew for every step, so nothing the scheduler writes to
+        again reaches one (:meth:`_stage`'s rule), and its tables are
+        what :meth:`_stage` keeps for the decode step behind."""
+        w, g = self.prefill_chunk, len(group)
+        tables = self.allocator.block_tables
+        packed = np.zeros((n * (w + 5) + tables.size,), np.int32)
+        rows = packed[: n * (w + 5)].reshape(n, w + 5)
+        rows[:, w] = self.slots
+        rows[:g, w] = group
+        rows[:g, :w] = np.asarray(tokens)[group]
+        for col, a in enumerate((base, chunk_lens, floor, sample_mask), 1):
+            rows[:g, w + col] = np.asarray(a)[group]
+        held = packed[n * (w + 5) :].reshape(tables.shape)
+        held[:] = tables
+        *staged, on_device = self._chunk_rows_jit(packed)
+        self._staged["block_tables"] = (held, on_device)
+        return (*staged, on_device)
 
     def prefill_fetch(self, step) -> np.ndarray:
         """The tokens of a chunk :meth:`prefill_dispatch` enqueued, as
@@ -1609,19 +1690,29 @@ class Engine:
         """Compile the compacted prefill step for every count of
         participants a tick can meet (``warm_engine`` calls this), with
         padding alone: nothing is written."""
-        w = self.prefill_chunk
+        none = np.zeros((0,), np.int32)
+        empty = np.zeros((self.slots, self.prefill_chunk), np.int32)
+        zeros = np.zeros((self.slots,), np.int32)
         for n in self._prefill_counts:
-            i32 = jnp.zeros((n,), jnp.int32)
             self.cache, self.last_token, *_ = self.compile_watch.call(
                 "prefill", self._prefill_compact_jit,
                 self.params, self.cache, self.last_token,
-                jnp.full((n,), self.slots, jnp.int32),
-                jnp.zeros((n, w), jnp.int32), i32, i32, i32,
-                jnp.zeros((n,), bool),
-                jnp.asarray(self.allocator.block_tables, jnp.int32),
+                *self._stage_chunk_rows(
+                    n, none, empty, zeros, zeros, zeros, zeros
+                ),
                 self._split(), jnp.zeros((self.slots,), jnp.float32),
                 jnp.zeros((self.slots,), jnp.int32),
             )
+
+    @staticmethod
+    def _rows_attrs(computed: int, valid: int) -> dict:
+        """What a chunk tick's ``prefill_dispatch`` span says of its
+        rows: those its steps compute and those of them that are no
+        prompt tokens (padding of a chunk, of a count, idle slots).
+        Nothing while no recorder is on."""
+        if not obs.enabled():
+            return {}
+        return dict(rows_computed=computed, rows_wasted=computed - valid)
 
     @staticmethod
     def _note_prefill_rows(computed: int, valid: int) -> None:

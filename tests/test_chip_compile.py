@@ -323,6 +323,77 @@ def test_paged_step_is_o_rows(large_engines, kv_dtype, step):
     assert mem.alias_size_in_bytes >= sum(leaf.nbytes for leaf in pool)
 
 
+# GPT-2 large as the benchmark's serving cells run it: 36 layers, the whole
+# vocabulary, 16 slots of 1,024 positions in pages of 16, chunks of 64.
+L_SLOTS, L_POSITIONS, L_COUNTS = 16, 1024, (2,)
+
+
+@pytest.fixture(scope="module")
+def gpt2_large_engine(v5e):
+    """The cell's engine on shapes alone (parameters by ``eval_shape``, a
+    two-slot pool); the steps are then lowered for the pool of 16 slots."""
+    from mpit_tpu.models import GPT2, GPT2Config
+    from mpit_tpu.ops import decode_attention
+    from mpit_tpu.serve import Engine
+
+    cfg = GPT2Config(vocab_size=50257, d_ff=5120,
+                     **{**LARGE, "num_layers": 36})
+    params = jax.eval_shape(lambda: jax.tree.map(
+        lambda a: a.astype(jnp.bfloat16),
+        GPT2(cfg).init(jax.random.key(0),
+                       jnp.zeros((1, 8), jnp.int32))["params"]))
+    was = decode_attention._use_kernel
+    decode_attention._use_kernel = lambda interpret: True
+    eng = Engine(cfg, params, slots=L_SLOTS, max_len=L_POSITIONS,
+                 kv_pages=2 * L_POSITIONS // PAGE, kv_page_size=PAGE,
+                 prefill_chunk=CHUNK)
+    one = SingleDeviceSharding(v5e.devices[0])
+    yield eng, lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one), tree)
+    decode_attention._use_kernel = was
+
+
+@pytest.mark.parametrize("n", L_COUNTS)
+def test_gpt2_large_compacted_chunk_step_fits_and_updates_in_place(
+        gpt2_large_engine, n):
+    """The compacted chunk step of every count of participants the cell
+    compiles (one: two slots' 128 rows), pool of 16 x 1,024 positions:
+    the kernels inside, every buffer of the pool aliased to an output,
+    and what the step needs beside its arguments a small part of the
+    16 GB (every compiled count keeps its own temporaries)."""
+    from mpit_tpu.serve.kvcache import PagedKVCache
+
+    eng, on_chip = gpt2_large_engine
+    assert eng._prefill_counts == L_COUNTS
+    pages = L_SLOTS * eng.pages_per_slot
+    full = lambda bufs: tuple(
+        jax.ShapeDtypeStruct((pages, *b.shape[1:]), b.dtype) for b in bufs)
+    cache = PagedKVCache(k=full(eng.cache.k), v=full(eng.cache.v),
+                         lengths=eng.cache.lengths)
+    s = eng.slots
+    z = jnp.zeros((n,), jnp.int32)
+    args = (
+        eng.params, cache, eng.last_token, z,
+        jnp.zeros((n, CHUNK), jnp.int32), z, z, z, jnp.zeros((n,), bool),
+        jnp.zeros((s, eng.pages_per_slot), jnp.int32), jax.random.key(0),
+        jnp.zeros((s,), jnp.float32), jnp.zeros((s,), jnp.int32))
+    compiled = eng._prefill_compact_jit.lower(*on_chip(args)).compile()
+    text = compiled.as_text()
+    assert "paged_decode_attn" in text and "paged_kv_write" in text
+    pool = jax.tree.leaves((cache.k, cache.v))
+    shape = f"bf16[{','.join(map(str, pool[0].shape))}]"
+    params, aliased = _entry_parameters(text), _aliased_parameters(text)
+    assert sum(params[i] == shape for i in aliased) == len(pool) == 72
+    mem = compiled.memory_analysis()
+    pool_bytes = sum(l.size * l.dtype.itemsize for l in pool)
+    assert mem.alias_size_in_bytes >= pool_bytes
+    # Arguments 4.57 GB (weights 1.55, pool 3.02); temporaries 0.20 GB
+    # (0.20-0.22 at every count from 1 to 16), the head's padded copy
+    # most of them (ROADMAP A14).
+    assert mem.argument_size_in_bytes < 4.7e9, mem.argument_size_in_bytes
+    assert mem.temp_size_in_bytes < 0.3e9, mem.temp_size_in_bytes
+
+
 @pytest.mark.parametrize("t", [1, CHUNK], ids=["decode", "prefill"])
 @pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
 def test_tp_paged_forward_compiles(v5e, kv_dtype, t, monkeypatch):

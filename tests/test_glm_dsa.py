@@ -380,7 +380,7 @@ def test_served_tokens_are_the_reference_argmax(tiny):
 def test_compacted_chunk_tick_equals_the_full_batch_one(tiny, monkeypatch):
     cfg, params = tiny
     full = _engine(cfg, params, slots=4)
-    monkeypatch.setattr(engine_module, "_FULL_BATCH_ROWS", 8)
+    monkeypatch.setattr(engine_module, "_WEIGHT_BOUND_ROWS", 8)
     monkeypatch.setattr(engine_module, "_COMPACT_ROWS", 16)
     compact = _engine(cfg, params, slots=4)
     assert compact._prefill_counts == (1, 2)

@@ -270,7 +270,7 @@ def _gaps(cfg, params, prompts, served) -> float:
 
 
 def _compacting(monkeypatch):
-    monkeypatch.setattr(engine_module, "_FULL_BATCH_ROWS", 16)
+    monkeypatch.setattr(engine_module, "_WEIGHT_BOUND_ROWS", 16)
     monkeypatch.setattr(engine_module, "_COMPACT_ROWS", 32)
 
 

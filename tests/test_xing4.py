@@ -267,7 +267,7 @@ def test_compacted_chunk_tick_equals_the_full_batch_one(tiny, monkeypatch):
     cfg, params = tiny
     full = _engine(cfg, params, slots=4)
     assert not full._prefill_counts  # 4 x 8 rows: the one step stays
-    monkeypatch.setattr(engine_module, "_FULL_BATCH_ROWS", 8)
+    monkeypatch.setattr(engine_module, "_WEIGHT_BOUND_ROWS", 8)
     monkeypatch.setattr(engine_module, "_COMPACT_ROWS", 16)
     compact = _engine(cfg, params, slots=4)
     assert compact._prefill_counts == (1, 2)  # 8 and 16 rows of 16
@@ -287,7 +287,7 @@ def test_compacted_tick_reports_its_rows(tiny, monkeypatch):
     from mpit_tpu import obs
 
     cfg, params = tiny
-    monkeypatch.setattr(engine_module, "_FULL_BATCH_ROWS", 8)
+    monkeypatch.setattr(engine_module, "_WEIGHT_BOUND_ROWS", 8)
     eng = _engine(cfg, params, slots=4)
     rec = obs.enable(obs.Recorder())
     try:
