@@ -34,7 +34,11 @@ def main(argv: list[str] | None = None) -> int:
 
 
 if __name__ == "__main__":
+    from mpit_tpu.obs import startup
     from mpit_tpu.utils import compile_cache_dir
 
     compile_cache_dir()
+    # One ``ready`` line on stderr when the first step's output is.
+    startup.install()
+    startup.on_ready(startup.say_ready)
     raise SystemExit(main())

@@ -97,6 +97,18 @@ mpit_tpu.obs capacity`` prints the offline verdict — on-TPU reconciled
 against ``device.memory_stats()``, off-TPU platform-labeled modeled
 bytes (never fabricated device numbers).
 
+ISSUE 36 adds the START-UP layer (:mod:`~mpit_tpu.obs.startup`): an
+always-on, bounded record of what a process did before its first tick
+or step — the program's start-up boundaries (``engine_build``,
+``cache_alloc``, ``warmup``, ``state_init``, ``cost_query``) and JAX's
+own compile events as ``jit_trace`` / ``jit_lower`` /
+``backend_compile`` spans named by executable, with the persistent
+cache's verdict on each — mirrored into a recorder where one is
+enabled. ``CompileWatch`` detects by the same events;
+``startup.report()`` is the CLIs' ``ready`` line and
+``Server.stats()["startup"]``; a compile after ``ready`` is a
+``compile_after_ready`` instant that names its function.
+
 Instrumented call sites: ``train.loop.hardened_loop`` (prefetch-wait /
 step / host-fence / eval / checkpoint / divergence-restore phases),
 ``comm.collectives`` (per-op modeled wire bytes — recorded at *trace*
@@ -116,6 +128,7 @@ from mpit_tpu.obs import (
     memledger,
     roofline,
     slo,
+    startup,
     stream,
     trace,
 )
@@ -175,6 +188,7 @@ __all__ = [
     "snapshot_trace_events",
     "span",
     "span_at",
+    "startup",
     "stream",
     "summary",
     "trace",

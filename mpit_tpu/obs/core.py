@@ -527,8 +527,15 @@ _OVERLAPPED_PHASES = ("prefetch_host", "prefetch_device_put")
 # covers the same interval as the step/prefill/decode span whose first
 # call triggered the compile. Wall-time reconciliations that sum
 # sequential loop phases must exclude these, exactly like the
-# pipeline-thread overlapped phases above.
-_OVERLAY_PHASES = ("compile",)
+# pipeline-thread overlapped phases above. The start-up record's spans
+# (obs.startup, mirrored into an enabled recorder) are overlays too:
+# JAX's compile events and ``first_run`` are the ``compile`` span's
+# children, and ``state_init`` / ``engine_build`` / ``cache_alloc`` /
+# ``cost_query`` come before a loop's first step or inside ``warmup``.
+_OVERLAY_PHASES = (
+    "compile", "jit_trace", "jit_lower", "backend_compile", "first_run",
+    "state_init", "engine_build", "cache_alloc", "cost_query",
+)
 
 
 def gap_attribution(summ: Mapping | None = None) -> dict:

@@ -38,10 +38,11 @@ everywhere and labeled modeled.
 Compile observability rides along:
 
 - :class:`CompileWatch` — detects XLA compiles of watched jitted
-  callables by jit-cache growth: each compile emits a ``compile`` span
+  callables by JAX's own compile events (``obs.startup``): each compile
+  emits a ``compile`` span with the executable's ``module``
   (overlaying the phase span that triggered it — excluded from
   sequential wall reconciliation via ``obs.core._OVERLAY_PHASES``), a
-  ``compiles`` counter and a ``<scope>_compiles`` gauge; growth past
+  ``compiles`` counter and a ``<scope>_compiles`` gauge; a compile past
   the declared lifetime expectation (the serve engine's "two compiles,
   zero per-request recompiles" claim) emits an ``unexpected_recompile``
   instant and feeds :meth:`~mpit_tpu.obs.sentinel.Sentinel.note`.
@@ -60,11 +61,11 @@ extract costs or resolve peaks.
 from __future__ import annotations
 
 import statistics
-import time
 from collections import deque
 from typing import Any, Mapping
 
 from mpit_tpu.obs import core as _core
+from mpit_tpu.obs import startup as _startup
 
 __all__ = [
     "CompileWatch",
@@ -387,24 +388,38 @@ def decode_step_hbm_bytes(
 
 
 class CompileWatch:
-    """Detects XLA compiles of watched jitted callables and pins a
+    """Counts the XLA compiles of watched step calls and pins a
     lifetime expectation.
 
-    Detection is jit-cache growth around a call (``_cache_size()``; a
-    callable without it is silently unwatchable — ``call`` degrades to
-    a plain invocation). On growth the call's wall time was dominated
-    by trace+compile, so a ``compile`` span covering the call is
-    recorded (an OVERLAY of the triggering phase's own span — see
-    ``obs.core._OVERLAY_PHASES``), plus a ``compiles`` counter and a
-    ``<scope>_compiles`` gauge (the pinned engine-lifetime metric).
-    Growth past ``expected`` additionally emits an
-    ``unexpected_recompile`` instant and, when a sentinel is attached,
-    lands in its anomaly report — the runtime guard on "N compiles,
-    zero per-request recompiles" claims.
+    ``call(phase, fn, *args)`` is the seam that says WHICH engine and
+    phase a compile belongs to. Detection is JAX's own events
+    (``obs.startup``'s listener): a ``backend_compile`` received in the
+    calling thread while the call is open. Nothing is probed before or
+    after a call that does not compile. On a compile the start-up
+    record (and an enabled recorder) get a ``compile`` span from the
+    call's start to its output ready (an OVERLAY of the triggering
+    phase's own span, see ``obs.core._OVERLAY_PHASES``) with ``phase``,
+    ``scope``, ``module`` (``jit_<name>`` of the executable) and the
+    caller's ``attrs``; its children are JAX's ``jit_trace`` /
+    ``jit_lower`` / ``backend_compile`` events and ``first_run`` (the
+    last compile's end to the output ready: the call waits for it, once,
+    only when it compiled). A ``compiles`` counter and the
+    ``<scope>_compiles`` gauge (the pinned engine-lifetime metric)
+    follow. A compile past ``expected`` additionally emits an
+    ``unexpected_recompile`` instant with the function's name and, when
+    a sentinel is attached, lands in its anomaly report: the runtime
+    guard on "N compiles, zero per-request recompiles" claims.
+    ``pinned=False`` records the span of a helper's compile
+    (``chunk_rows``) without counting it against the pin.
+
+    No callable is known that compiles without firing the events, so
+    there is no second detector; a callable that never reaches JAX
+    reads zero compiles.
     """
 
     def __init__(self, *, expected: int | None = None,
                  scope: str = "engine", sentinel=None):
+        _startup.install()  # the listeners, once a process
         self.expected = expected
         self.scope = scope
         self.sentinel = sentinel
@@ -412,40 +427,28 @@ class CompileWatch:
         self.unexpected = 0
         self.events: list[dict] = []
 
-    @staticmethod
-    def cache_size(fn) -> int | None:
-        try:
-            return fn._cache_size()
-        except Exception:
-            return None
-
-    def call(self, phase: str, fn, *args):
-        """Invoke ``fn(*args)``, recording a compile event if the jit
-        cache grew across the call."""
-        before = self.cache_size(fn)
-        t0 = time.perf_counter()
-        out = fn(*args)
-        if before is not None:
-            after = self.cache_size(fn)
-            if after is not None and after > before:
-                self.on_compile(phase, t0, time.perf_counter())
+    def call(self, phase: str, fn, *args, pinned: bool = True, **attrs):
+        """Invoke ``fn(*args)``, recording a compile event if JAX
+        compiled in this thread meanwhile."""
+        with _startup.Watch() as watch:
+            out = fn(*args)
+        if watch.compiled:
+            done = watch.close(out, phase=phase, scope=self.scope, **attrs)
+            if pinned:
+                self.on_compile(phase, *done)
         return out
 
-    def on_compile(self, phase: str, t0: float, t1: float) -> None:
+    def on_compile(self, phase: str, t0: float, t1: float,
+                   fun: str = "") -> None:
         self.compiles += 1
         unexpected = (
             self.expected is not None and self.compiles > self.expected
-        )
-        # The span covers trace + compile + the first execution (they
-        # are inseparable inside one jit call) — labeled so the trace
-        # reader knows the wall is compiler-dominated, not steady-state.
-        _core.span_at(
-            "compile", t0, t1, phase=phase, scope=self.scope,
         )
         _core.counter("compiles")
         _core.gauge(f"{self.scope}_compiles", float(self.compiles))
         event = {
             "phase": phase,
+            "fun": fun,
             "seconds": round(t1 - t0, 6),
             "count": self.compiles,
             "unexpected": unexpected,
@@ -457,12 +460,12 @@ class CompileWatch:
                 # note() emits the structured ``anomaly`` instant too.
                 self.sentinel.note(
                     "unexpected_recompile", phase, self.compiles,
-                    expected=self.expected, scope=self.scope,
+                    expected=self.expected, scope=self.scope, fun=fun,
                 )
             else:
                 _core.instant(
                     "unexpected_recompile", phase=phase, scope=self.scope,
-                    count=self.compiles, expected=self.expected,
+                    count=self.compiles, expected=self.expected, fun=fun,
                 )
 
 

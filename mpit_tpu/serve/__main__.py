@@ -56,6 +56,17 @@ never a fabricated percentage), and the final JSON carries the
 per-phase ``roofline`` roll-up plus ``engine_compiles`` (pinned
 lifetime compile count; an unexpected recompile lands in the sentinel).
 
+Start-up (ISSUE 36): one ``ready`` line on stderr when the engine takes
+traffic (the warm-up's end, or a server nobody warmed: its first token)
+— ``ready {"scope": "engine", "ready_s": ..., "seconds": {<phase>:
+...}, "executables": ..., "cache_misses": ..., "slowest": [...]}``,
+``obs.startup.report()``: seconds since the process's start, seconds
+covered by each start-up phase (``warmup``, ``compile``, ``jit_trace``,
+``jit_lower``, ``backend_compile``, ``first_run``, ``cost_query``: the
+price of the cost query's second compile of each step), the slowest
+executables by name. The final JSON's ``startup`` holds the same and
+``compiles_after_ready`` by function: a compile in a tick has a name.
+
 ``--slo-ttft-p95 / --slo-latency-p95 / --slo-shed-rate`` declare SLO
 targets; an ``obs.slo.SLOMonitor`` evaluates them over the rolling
 windows each tick, breaches land in the trace / the sentinel, and the
@@ -762,7 +773,12 @@ def main(argv: list[str] | None = None) -> dict:
 
 
 if __name__ == "__main__":
+    from mpit_tpu.obs import startup
     from mpit_tpu.utils import compile_cache_dir
 
     compile_cache_dir()
+    # One ``ready`` line on stderr when the engine takes traffic (the
+    # warm-up's end, or the first token of a server nobody warmed).
+    startup.install()
+    startup.on_ready(startup.say_ready)
     print(json.dumps(main(sys.argv[1:])))

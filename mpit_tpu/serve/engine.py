@@ -90,6 +90,7 @@ from mpit_tpu.ops.quantized_matmul import (
 )
 from mpit_tpu import obs
 from mpit_tpu.obs import roofline as _roofline
+from mpit_tpu.obs import startup as _startup
 from mpit_tpu.ops.decode_attention import (
     flash_paged_decode_attention,
     num_kv_blocks,
@@ -138,6 +139,22 @@ _DTYPE_SHORT = {"float32": "f32", "bfloat16": "bf16", "int8": "int8"}
 # VMEM tile at a time, never as a full f32 array in HBM. Same lifetime
 # compile count; the decode HBM sweep's weight term shrinks ~4x.
 _WEIGHT_DTYPES = ("f32", "int8")
+
+
+def _startup_span(name: str):
+    """The decorated constructor as one span of the start-up record
+    (``obs.startup``; the compile listeners are registered first)."""
+
+    def deco(init):
+        @functools.wraps(init)
+        def built(self, *args, **kwargs):
+            _startup.install()
+            with _startup.span(name):
+                init(self, *args, **kwargs)
+
+        return built
+
+    return deco
 
 
 def _jit_as(name: str, step, donate=()):
@@ -475,6 +492,7 @@ class Engine:
     is the slot-width control arrays only.
     """
 
+    @_startup_span("engine_build")
     def __init__(
         self,
         model,
@@ -879,11 +897,16 @@ class Engine:
             host_pages=self.host_pages,
             prefix_shareable=layout.prefix_shareable,
         )
-        self.cache = alloc_paged_cache(
-            model, slots, self.num_pages, self.page_size,
-            sharding=sharding, dtype=self._cache_dtype,
-            quantized=self.kv_quantized,
-        )
+        with _startup.span("cache_alloc") as alloc:
+            self.cache = alloc_paged_cache(
+                model, slots, self.num_pages, self.page_size,
+                sharding=sharding, dtype=self._cache_dtype,
+                quantized=self.kv_quantized,
+            )
+            # pages, state, third seats
+            alloc.set(bytes=sum(
+                leaf.nbytes for leaf in jax.tree.leaves(self.cache)
+            ))
         # Every step that writes the pool donates it (argument
         # positions of the cache and, on a speculative engine, the
         # draft cache): the scatter of a tick's rows is then the
@@ -1637,7 +1660,7 @@ class Engine:
                 self.cache, self.last_token, *aux = self.compile_watch.call(
                     "prefill", self._prefill_compact_jit,
                     self.params, self.cache, self.last_token, *staged,
-                    self._split(), temp, topk,
+                    self._split(), temp, topk, count=n,
                 )
                 aux_all.append(aux)
         return self.last_token, aux_all, computed, valid
@@ -1667,7 +1690,10 @@ class Engine:
             rows[:g, w + col] = np.asarray(a)[group]
         held = packed[n * (w + 5) :].reshape(tables.shape)
         held[:] = tables
-        *staged, on_device = self._chunk_rows_jit(packed)
+        # Its compile is a span of the record and no count of the pin.
+        *staged, on_device = self.compile_watch.call(
+            "prefill", self._chunk_rows_jit, packed, pinned=False, count=n
+        )
         self._staged["block_tables"] = (held, on_device)
         return (*staged, on_device)
 
@@ -1701,7 +1727,7 @@ class Engine:
                     n, none, empty, zeros, zeros, zeros, zeros
                 ),
                 self._split(), jnp.zeros((self.slots,), jnp.float32),
-                jnp.zeros((self.slots,), jnp.int32),
+                jnp.zeros((self.slots,), jnp.int32), count=n,
             )
 
     @staticmethod
@@ -2009,10 +2035,11 @@ class Engine:
         utilization loop (``obs.roofline``).
 
         This AOT-lowers+compiles each step a second time (there is no
-        public way to reach the jit cache's executable); callers pay it
-        once, after warmup — ``warm_engine(register_costs=True)``, the
-        serve CLI and bench do. The modeled decode cost is the PADDED
-        number by construction; the scheduler corrects the HBM side
+        public way to reach the jit cache's executable), each inside a
+        ``cost_query`` span of the start-up record, which gives its
+        price; callers pay it once, after warmup —
+        ``warm_engine(register_costs=True)``, the serve CLI and bench
+        do. The modeled decode cost is the PADDED number by construction; the scheduler corrects the HBM side
         per tick with :meth:`decode_achieved_hbm_bytes`. Returns
         ``{phase: {flops, hbm_bytes}}`` (zeros + ``error`` when a
         backend can't report costs)."""
@@ -2064,7 +2091,8 @@ class Engine:
         out = {}
         for phase, (fn, args) in steps.items():
             try:
-                cost = _roofline.cost_from_fn(fn, *args)
+                with _startup.span("cost_query", phase=phase):
+                    cost = _roofline.cost_from_fn(fn, *args)
             except Exception as e:  # a backend without AOT cost support
                 if self.platform == "tpu":
                     raise  # on the chip this is a fault, not a backend gap

@@ -146,16 +146,23 @@ def warm_engine(engine, *, register_costs: bool = False) -> None:
     not the compiler. Prompt content is irrelevant: the padded
     prefill/decode buffers fix the traced shapes.
 
-    The whole warm run is spanned as ``warmup`` (ISSUE 8 satellite:
-    warmup time is attributed, not a silent gap in the trace), and the
-    compiles it triggers land as ``compile`` spans + the
-    ``engine_compiles`` gauge via the engine's CompileWatch.
-    ``register_costs=True`` additionally registers the steps'
-    ``cost_analysis()`` costs with the recorder
-    (:meth:`~mpit_tpu.serve.engine.Engine.register_roofline`) — opt-in
-    because it re-compiles each step once for the cost query; bench and
-    the serve CLI pass it, parity tests don't pay it."""
-    with obs.span("warmup"):
+    The whole warm run is the ``warmup`` span of the start-up record
+    (``obs.startup``; in an enabled recorder too: warmup time is
+    attributed, not a silent gap in the trace). Every step call in which
+    JAX compiled lands inside it as a ``compile`` span (``phase``,
+    ``scope``, ``module`` and, for a compacted chunk step, ``count``)
+    whose children are JAX's own ``jit_trace`` / ``jit_lower`` /
+    ``backend_compile`` events, named by executable, and ``first_run``
+    (the compile's end to the call's output ready: the step's first
+    execution), beside the ``engine_compiles`` gauge, via the engine's
+    CompileWatch. ``register_costs=True`` additionally registers the
+    steps' ``cost_analysis()`` costs with the recorder
+    (:meth:`~mpit_tpu.serve.engine.Engine.register_roofline`): opt-in
+    because it lowers and compiles each step a second time for the cost
+    query, which shows as one ``cost_query`` span a step; bench and the
+    serve CLI pass it, parity tests don't pay it. The warm-up's end
+    makes the engine ``ready`` (``obs.startup.ready("engine")``)."""
+    with obs.startup.span("warmup"):
         warm = Server(engine)
         warm.submit(Request(rid="warm", prompt=[1, 2, 3], max_new_tokens=2))
         warm.run()
@@ -178,6 +185,7 @@ def warm_engine(engine, *, register_costs: bool = False) -> None:
         if register_costs:
             engine.register_roofline()
     engine.reset()
+    obs.startup.ready("engine")
 
 
 @dataclasses.dataclass
@@ -1243,6 +1251,9 @@ class Server:
                 live.first_token_t = t_first
                 live.tokens = [tok]
                 self._record_ttft(live, t_first)
+                # An engine nobody warmed is ready with its first token
+                # (ignored inside a warm-up's span, and once ready).
+                obs.startup.ready("engine")
             self._token_landed(slot, live, t_first)
 
     def _record_ttft(self, live: _Live, t_first: float) -> None:
@@ -2278,6 +2289,9 @@ class Server:
         # anything above is an unexpected recompile the watch also
         # flagged.
         out["engine_compiles"] = self.engine.compile_watch.compiles
+        # What the process did before it was ready, and any compile
+        # since, by function (obs.startup; the CLI's ``ready`` line).
+        out["startup"] = obs.startup.report()
         if self._decode_hbm_bytes:
             out["decode_hbm_bytes_modeled"] = round(
                 self._decode_hbm_bytes, 1

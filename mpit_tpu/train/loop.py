@@ -250,12 +250,13 @@ def hardened_loop(
     rate_trace: list[float] = []
     # Compile observability (ISSUE 8): the first step's XLA compile
     # becomes a visible `compile` span (an overlay of that step's own
-    # span — obs.core._OVERLAY_PHASES) + counter; any LATER jit-cache
-    # growth is an unexpected recompile (a shape/dtype leak into the
-    # step) — instant + sentinel note. Costs nothing when step_fn is
-    # not a jitted callable (no _cache_size) or obs is disabled.
+    # span — obs.core._OVERLAY_PHASES) + counter; any LATER compile
+    # is an unexpected recompile (a shape/dtype leak into the
+    # step) — instant + sentinel note. Detection is JAX's own compile
+    # events in this thread while the step call is open (obs.startup):
+    # a step that does not compile is not probed.
     compile_watch = obs.roofline.CompileWatch(
-        expected=1, scope="train_step", sentinel=sentinel
+        expected=1, scope="train", sentinel=sentinel
     )
     # Executed grad-sync mode stamp (ISSUE 9 satellite): label the step
     # spans the way serve stamps ``attention=`` — "ring" off-TPU runs
@@ -425,6 +426,10 @@ def hardened_loop(
                         state, metrics = compile_watch.call(
                             "step", step_fn, state, batch
                         )
+                    if step == start_step:
+                        # A step that compiled waited for its output
+                        # (CompileWatch): the trainer is ready.
+                        obs.startup.ready("train")
                     if sentinel is not None:
                         # Host-side wall per iteration (dispatch time on
                         # the async path — spikes here mean the HOST

@@ -27,6 +27,7 @@ from jax.sharding import PartitionSpec as P
 
 from mpit_tpu import opt as gopt
 from mpit_tpu.comm import collectives as C
+from mpit_tpu.obs import startup as _startup
 from mpit_tpu.opt.sharded import state_partition_specs
 
 
@@ -94,7 +95,8 @@ def zero1_state_fns(
         f = world.shard_map(
             _per_device_init, in_specs=(P(), specs.extra), out_specs=specs
         )
-        return jax.jit(f)(params, extra)
+        with _startup.span("state_init"):
+            return jax.jit(f)(params, extra)
 
     return stx, state_specs, init_fn
 
@@ -155,6 +157,7 @@ def make_train_step(
     """
     from mpit_tpu.train.grad_sync import GradSync
 
+    _startup.install()
     gs = (
         grad_sync
         if isinstance(grad_sync, GradSync)
@@ -265,20 +268,31 @@ def make_train_step(
             ),
         )
         f = compiled.get(key)
-        if f is None:
-            f = build_step(state.params, state.extra)
-            compiled[key] = f
-        return f(state, batch)
+        if f is not None:
+            return f(state, batch)
+        f = compiled[key] = build_step(state.params, state.extra)
+        # The first call of a structure compiles: the start-up record's
+        # ``compile`` span (jit_train_step) with JAX's events and
+        # ``first_run`` as children, unless a caller's CompileWatch is
+        # open round this call and records it (hardened_loop's). The
+        # watch waits for the first step's output, once; with it ready
+        # the trainer is.
+        with _startup.Watch() as watch:
+            out = f(state, batch)
+        if watch.compiled:
+            watch.close(out, phase="step", scope="train")
+            _startup.ready("train")
+        return out
 
     # AOT seam: the raw jax.jit object, for `.lower()` against abstract
     # args on a topology mesh (utils/aot.py compile_multichip).
     step_fn.build = build_step
 
     def _cache_size():
-        # Compile-watch seam (obs.roofline.CompileWatch, ISSUE 8): the
-        # jit-cache population summed over the per-structure compiled
-        # steps — growth across a call means an XLA compile happened
-        # (first step, or an unexpected shape/dtype-change recompile).
+        # The jit-cache population summed over the per-structure
+        # compiled steps: growth across a call means an XLA compile
+        # happened. The benchmark's EdgeStep carries it along;
+        # obs.roofline.CompileWatch reads JAX's events, not this.
         return sum(f._cache_size() for f in compiled.values())
 
     step_fn._cache_size = _cache_size

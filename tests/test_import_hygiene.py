@@ -1,6 +1,6 @@
 """Import hygiene for the host-pure hot-path modules (ISSUE 8 satellite).
 
-``obs.stream``, ``obs.slo`` and ``serve.loadgen`` are the "pure host
+``obs.stream``, ``obs.slo``, ``obs.startup`` and ``serve.loadgen`` are the "pure host
 python in the hot path" layer: the serve scheduler feeds them per
 tick/request, and the CLI imports them at startup. Their claim — no
 jax, no numpy at module level — is what keeps disabled-overhead near
@@ -47,6 +47,7 @@ _SCRIPT = textwrap.dedent(
 
     import mpit_tpu.obs.stream
     import mpit_tpu.obs.slo
+    import mpit_tpu.obs.startup
     import mpit_tpu.serve.loadgen
 
     heavy = sorted(
@@ -61,6 +62,11 @@ _SCRIPT = textwrap.dedent(
     assert reg.quantile("ttft", 0.5) is not None
     spec = mpit_tpu.serve.loadgen.parse_load_spec("rate=8,process=bursty")
     assert spec.rate == 8.0 and spec.process == "bursty"
+    # The start-up record fills and rolls up before jax is imported
+    # (install() alone imports jax.monitoring, when it is called).
+    with mpit_tpu.obs.startup.span("engine_build"):
+        pass
+    assert mpit_tpu.obs.startup.report()["seconds"].keys() == {"engine_build"}
     assert not any(
         m in sys.modules for m in ("jax", "jaxlib", "numpy", "flax")
     )
