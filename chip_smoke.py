@@ -46,7 +46,8 @@ raises — there is no handler that lets the run end 0):
 - ``chunk_rows`` GPT-2 large at the serving cells' shape (16 slots of 1,024
                  positions, chunks of 64): the compacted chunk step timed
                  alone at 16 to 1,024 rows, against the full-batch step,
-                 and the host's side of a chunk tick (``prefill_dispatch``)
+                 the 128-row step with its two seats one slot's, two
+                 slots' and one slot's beside padding, and the host's side of a chunk tick (``prefill_dispatch``)
                  both ways: the table ``serve/engine.py``'s
                  ``_WEIGHT_BOUND_ROWS`` is read from.
 - ``dp4``        (``--chips 4`` only, and then the only phase) ZeRO-1 data
@@ -965,12 +966,18 @@ def phase_chunk_rows(sz, seed: int, rehearse: bool) -> None:
     temp, topk = jnp.zeros((s,), jnp.float32), jnp.zeros((s,), jnp.int32)
     rng = np.random.RandomState(seed)
 
-    def compact_call(n, width):
+    def seats_call(idx, base, lens, width):
+        """The compacted step over seats of ``width`` rows: seat ``i``
+        is slot ``idx[i]``'s ``lens[i]`` tokens from position
+        ``base[i]``."""
+        idx = list(idx)
+        n = len(idx)
         toks = jnp.asarray(rng.randint(0, c["vocab"], (n, width)), jnp.int32)
-        idx = jnp.arange(n, dtype=jnp.int32)
-        base = jnp.full((n,), c["prefix"], jnp.int32)
-        lens = jnp.full((n,), width, jnp.int32)
-        floor, mask = jnp.zeros((n,), jnp.int32), jnp.ones((n,), bool)
+        # As the scheduler marks them: a slot's last seat samples.
+        mask = jnp.asarray([
+            lens[k] > 0 and i not in idx[k + 1:] for k, i in enumerate(idx)])
+        idx, base, lens = (jnp.asarray(a, jnp.int32) for a in (idx, base, lens))
+        floor = jnp.zeros((n,), jnp.int32)
 
         def call():
             eng.cache, eng.last_token, *_ = eng._prefill_compact_jit(
@@ -978,6 +985,9 @@ def phase_chunk_rows(sz, seed: int, rehearse: bool) -> None:
                 lens, floor, mask, table, key, temp, topk)
             return eng.last_token
         return call
+
+    def compact_call(n, width):
+        return seats_call(range(n), [c["prefix"]] * n, [width] * n, width)
 
     def full_call(takers):
         toks = jnp.asarray(rng.randint(0, c["vocab"], (s, w)), jnp.int32)
@@ -997,6 +1007,22 @@ def phase_chunk_rows(sz, seed: int, rehearse: bool) -> None:
              step_ms=enqueued_ms(compact_call(n, width), c["reps"]))
         for n, width in c["steps"]]
     full_ms = enqueued_ms(full_call(2), c["reps"])
+    # The step of the rule's own count with its seats filled three ways
+    # (ISSUE 40): one slot's two next chunks, two slots' chunks, one
+    # slot's chunk and padding. Three rounds, the forms in turn.
+    n, pre = max(counts[0], 2) if counts else 2, c["prefix"]
+    forms = dict(
+        one_slot_chained=seats_call(
+            [0] * n, [pre + k * w for k in range(n)], [w] * n, w),
+        a_slot_a_seat=compact_call(n, w),
+        one_seat_and_padding=seats_call(
+            [0] + [s] * (n - 1), [pre] + [0] * (n - 1),
+            [w] + [0] * (n - 1), w),
+    )
+    seat_forms = {name: [] for name in forms}
+    for _ in range(3):
+        for name, call in forms.items():
+            seat_forms[name].append(enqueued_ms(call, c["reps"]))
     peak = (jax.local_devices()[0].memory_stats() or {}).get(
         "peak_bytes_in_use")
 
@@ -1048,6 +1074,7 @@ def phase_chunk_rows(sz, seed: int, rehearse: bool) -> None:
         "chunk_rows", layers=c["layers"], d_model=c["d_model"], slots=s,
         chunk=w, prefix_rows=c["prefix"], prefill_counts=counts,
         compact_steps=steps, full_batch_step_ms_two_participants=full_ms,
+        seat_forms_step_ms=dict(seats=n, rows=n * w, **seat_forms),
         peak_bytes_in_use=peak,
         prefill_dispatch_host_ms=dict(compact=compact_host, full=full_host),
         group_transfer_host_ms=transfer,

@@ -1,8 +1,8 @@
 """sha256 of the lowered text of the steps the benchmark's cells run, AOT
 for a described v5e (no chip needed): GPT-2 large's paged decode, chunk
 (the full-batch step and the compacted one a count of participants) and
-page-copy steps at the serving cells' shape, xing4's and glm_dsa's
-decode and compacted chunk steps at their cells' shapes, GPT-2 small's
+page-copy steps at the serving cells' shape, xing4's, glm_dsa's and
+olmo_hybrid's decode and compacted chunk steps at their cells' shapes, GPT-2 small's
 train step over the 2x2. Two trees that print the same hashes run the same device
 programs; a refactoring PR proves itself with
 
@@ -139,6 +139,32 @@ def main():
             args = (eng.params, cache, eng.last_token, z, jnp.zeros((n, G_CHUNK), jnp.int32), z, z, z,
                     jnp.zeros((n,), bool), bt, key, f32, i32)
             out[f"glm_dsa.jit_prefill_paged.compact{n}"] = sha(
+                eng._prefill_compact_jit.lower(*on_chip(args)).as_text())
+        del eng
+
+    # olmo_hybrid, its cell's shape (a tree from before the family prints nothing for it)
+    try:
+        from mpit_tpu.models import olmo_hybrid
+    except ImportError:
+        olmo_hybrid = None
+    if olmo_hybrid is not None:
+        O_S, O_POS, O_PAGE, O_CHUNK = 64, 4096, 128, 512
+        ocfg = olmo_hybrid.OlmoHybridConfig(num_hidden_layers=8, max_seq_len=O_POS)
+        oparams = jax.eval_shape(lambda: olmo_hybrid.init_params(ocfg, jax.random.key(0)))
+        pps = O_POS // O_PAGE
+        eng = Engine(ocfg, oparams, slots=O_S, max_len=O_POS, seed=1, kv_pages=2 * pps, kv_page_size=O_PAGE,
+                     prefill_chunk=O_CHUNK, sample_block=7168)
+        pool = lambda bufs: tuple(jax.ShapeDtypeStruct((O_S * pps, *b.shape[1:]), b.dtype) for b in bufs)
+        cache = dataclasses.replace(eng.cache, k=pool(eng.cache.k), v=pool(eng.cache.v))
+        i32, f32 = jnp.zeros((O_S,), jnp.int32), jnp.zeros((O_S,), jnp.float32)
+        bt = jnp.zeros((O_S, pps), jnp.int32)
+        args = (eng.params, cache, eng.last_token, jnp.zeros((O_S,), bool), bt, key, f32, i32)
+        out["olmo_hybrid.jit_decode_paged"] = sha(eng._decode_paged_jit.lower(*on_chip(args)).as_text())
+        for n in eng._prefill_counts:
+            z = jnp.zeros((n,), jnp.int32)
+            args = (eng.params, cache, eng.last_token, z, jnp.zeros((n, O_CHUNK), jnp.int32), z, z, z,
+                    jnp.zeros((n,), bool), bt, key, f32, i32)
+            out[f"olmo_hybrid.jit_prefill_paged.compact{n}"] = sha(
                 eng._prefill_compact_jit.lower(*on_chip(args)).as_text())
         del eng
 

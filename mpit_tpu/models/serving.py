@@ -142,6 +142,15 @@ class ServeModel:
     # as no tokens; the engine then traces and passes that mask.
     skips_invalid_rows = False
 
+    @property
+    def keeps_pages_alone(self) -> bool:
+        """Whether pages are all a slot keeps from one chunk of its
+        prompt to the next: a later chunk then needs nothing of an
+        earlier one but its rows in the pool, and the two may share a
+        step (``Engine.spare_seats``). Not where a layer carries a state
+        a slot, which a step reads once and leaves once."""
+        return not self.cache_layout().state_layers
+
     # -- geometry ----------------------------------------------------------
     def cache_layout(self) -> CacheLayout:
         raise NotImplementedError

@@ -926,6 +926,13 @@ class Engine:
             self._prefill_counts = _chunk_step_counts(
                 slots, self.prefill_chunk
             )
+        # Where the smallest compiled step holds several seats (a chunk
+        # narrower than the knee) and pages are all a slot keeps, a seat
+        # no slot took goes to a prompt that is there (spare_seats).
+        self._chains = (
+            bool(self._prefill_counts) and self._prefill_counts[0] > 1
+            and model.keeps_pages_alone
+        )
         if self._prefill_counts:
             self._prefill_compact_jit = _jit_as(
                 "prefill_paged", self._paged_prefill_compact_step,
@@ -1267,7 +1274,14 @@ class Engine:
         through it. ``slot_idx`` past the slots marks padding up to the
         compiled count (its ``chunk_lens`` is 0): nothing of it is
         written anywhere. The device work follows ``len(slot_idx) x
-        chunk`` rows, not ``slots x chunk``."""
+        chunk`` rows, not ``slots x chunk``.
+
+        A row is a SEAT: a slot may hold several, its prompt's next
+        chunks in order (:meth:`spare_seats`). A layer writes every
+        seat's rows into the pool before its attention reads it, so a
+        later seat's queries (positions ``base + t`` of their own row)
+        find the earlier seat's rows there, as they would a tick later;
+        the slot's new length is its last seat's end."""
         s = cache.lengths.shape[0]
         at = jnp.minimum(slot_idx, s - 1)
         t_idx = jnp.arange(tokens.shape[1])[None, :]
@@ -1287,12 +1301,17 @@ class Engine:
             topk[at],
         )
         # A scatter past the slots is dropped: padding and the slots
-        # that sample nothing leave no mark.
+        # that sample nothing leave no mark. Every seat of a slot writes
+        # the furthest end among them, so it is nothing which lands last.
+        idx = jnp.where(participates, slot_idx, s)
+        ends = jnp.max(
+            jnp.where(
+                idx[:, None] == idx[None, :], (base + chunk_lens)[None, :], 0
+            ),
+            axis=1,
+        )
         new_cache = dataclasses.replace(
-            new,
-            lengths=cache.lengths.at[
-                jnp.where(participates, slot_idx, s)
-            ].set(base + chunk_lens, mode="drop"),
+            new, lengths=cache.lengths.at[idx].set(ends, mode="drop")
         )
         new_last = last.at[jnp.where(sample_mask, slot_idx, s)].set(
             tok, mode="drop"
@@ -1570,22 +1589,53 @@ class Engine:
         return held[1]
 
     def prefill_paged(
-        self, tokens, base, chunk_lens, floor, sample_mask, temp, topk
+        self, tokens, base, chunk_lens, floor, sample_mask, temp, topk,
+        seats=None,
     ) -> np.ndarray:
-        """One prefill chunk over the slot batch: ``tokens``
-        [slots, prefill_chunk] int32 (padded slices), ``base``/``chunk_lens``/``floor`` [slots] int32 and
-        ``sample_mask`` [slots] bool per :meth:`_paged_prefill_step`.
+        """One prefill chunk tick: row ``i`` of ``tokens``
+        [rows, prefill_chunk] int32 (padded slices), ``base`` /
+        ``chunk_lens`` / ``floor`` [rows] int32 and ``sample_mask`` [rows]
+        bool per :meth:`_paged_prefill_step` is a SEAT of slot
+        ``seats[i]``: a chunk of that slot's prompt. ``seats`` None: a
+        row a slot, row ``i`` slot ``i``'s. A slot may hold as many more
+        seats as :meth:`spare_seats` allows, its prompt's next chunks in
+        order. ``temp`` / ``topk`` are [slots] always.
         Block tables come from the engine's allocator. Returns the
         per-slot last token (the first OUTPUT token for slots whose
         ``sample_mask`` is set) as host numpy: :meth:`prefill_dispatch`
         and then :meth:`prefill_fetch`, for a caller that wants the
         tokens before it does anything else."""
         return self.prefill_fetch(self.prefill_dispatch(
-            tokens, base, chunk_lens, floor, sample_mask, temp, topk
+            tokens, base, chunk_lens, floor, sample_mask, temp, topk, seats
         ))
 
+    def spare_seats(self, takers: int) -> int:
+        """Seats that a chunk tick of ``takers`` slots, one seat each,
+        computes for nobody: its last step's compiled count less the
+        slots in it. They cost the device nothing more (the step is
+        compiled at the knee of its time over its rows, ``_chunk_step_
+        counts``), so the scheduler gives each to the next chunk of a
+        prompt that is there. 0 where the smallest compiled step is one
+        seat or the whole batch, and where a slot keeps a state beside
+        its pages."""
+        if not self._chains:
+            return 0
+        return sum(self._step_sizes(takers)) - takers
+
+    def _step_sizes(self, seats: int) -> list:
+        """The compiled counts of the steps a chunk tick of ``seats``
+        seats takes: ``_prefill_counts[-1]`` at a time, what is left
+        padded to the next count."""
+        most = self._prefill_counts[-1]
+        full, rest = divmod(seats, most)
+        sizes = [most] * full
+        if rest:
+            sizes.append(next(c for c in self._prefill_counts if c >= rest))
+        return sizes
+
     def prefill_dispatch(
-        self, tokens, base, chunk_lens, floor, sample_mask, temp, topk
+        self, tokens, base, chunk_lens, floor, sample_mask, temp, topk,
+        seats=None,
     ):
         """Stage and enqueue one prefill chunk (arguments as
         :meth:`prefill_paged`) and return what the step left on the
@@ -1594,13 +1644,19 @@ class Engine:
         chunk_lens = np.asarray(chunk_lens)
         if self._prefill_counts:
             return self._prefill_compact_dispatch(
-                tokens, base, chunk_lens, floor, sample_mask, temp, topk
+                tokens, base, chunk_lens, floor, sample_mask, temp, topk,
+                seats,
+            )
+        if seats is not None:  # the full-batch step: a row a slot
+            tokens, base, chunk_lens, floor, sample_mask = (
+                self._by_slot(seats, a)
+                for a in (tokens, base, chunk_lens, floor, sample_mask)
             )
         aux = ()
         computed = chunk_lens.size * self.prefill_chunk
         valid = int(chunk_lens.sum())
         with obs.span(  # staging and enqueue
-            "prefill_dispatch", **self._rows_attrs(computed, valid)
+            "prefill_dispatch", **self._rows_attrs(computed, valid, 0)
         ):
             args = [
                 self.params,
@@ -1629,33 +1685,47 @@ class Engine:
                 self.cache, self.last_token, *aux = self.compile_watch.call(
                     "prefill", self._prefill_paged_jit, *args
                 )
-        return self.last_token, [aux], computed, valid
+        return self.last_token, [aux], (computed, valid, 0)
+
+    def _by_slot(self, seats, rows) -> np.ndarray:
+        """``rows`` a seat as rows a slot (no slot holds two: the
+        full-batch step gives no seat away), zeros for the others."""
+        rows = np.asarray(rows)
+        out = np.zeros((self.slots, *rows.shape[1:]), rows.dtype)
+        out[np.asarray(seats)] = rows
+        return out
 
     def _prefill_compact_dispatch(
-        self, tokens, base, chunk_lens, floor, sample_mask, temp, topk
+        self, tokens, base, chunk_lens, floor, sample_mask, temp, topk,
+        seats,
     ):
         """A chunk tick over its participants only (see
-        :meth:`_paged_prefill_compact_step`): the slots with a chunk are
-        taken ``_prefill_counts[-1]`` at a time, each group padded to the
-        next compiled count. Every group's step is enqueued; the last
+        :meth:`_paged_prefill_compact_step`): the seats with a chunk are
+        taken ``_prefill_counts[-1]`` at a time, in their order (a slot's
+        later seat never before its earlier one), each group padded to
+        the next compiled count. Every group's step is enqueued; the last
         one's tokens hold them all."""
         takers = np.flatnonzero(chunk_lens > 0)
+        slot_of = (
+            np.arange(len(chunk_lens)) if seats is None else np.asarray(seats)
+        )
         most = self._prefill_counts[-1]
         groups = [takers[g : g + most] for g in range(0, len(takers), most)]
-        sizes = [
-            next(c for c in self._prefill_counts if c >= len(group))
-            for group in groups
-        ]
+        sizes = self._step_sizes(len(takers))
         computed, aux_all = sum(sizes) * self.prefill_chunk, []
         valid = int(chunk_lens.sum())
+        # Prompt tokens in a seat that is not its slot's first.
+        _, first = np.unique(slot_of[takers], return_index=True)
+        rows = computed, valid, valid - int(chunk_lens[takers[first]].sum())
         with obs.span(  # staging and enqueue
-            "prefill_dispatch", **self._rows_attrs(computed, valid)
+            "prefill_dispatch", **self._rows_attrs(*rows)
         ):
             temp = self._stage("temp", temp, np.float32)
             topk = self._stage("topk", topk, np.int32)
             for group, n in zip(groups, sizes):
                 staged = self._stage_chunk_rows(
-                    n, group, tokens, base, chunk_lens, floor, sample_mask
+                    n, slot_of[group], group, tokens, base, chunk_lens,
+                    floor, sample_mask,
                 )
                 self.cache, self.last_token, *aux = self.compile_watch.call(
                     "prefill", self._prefill_compact_jit,
@@ -1663,14 +1733,15 @@ class Engine:
                     self._split(), temp, topk, count=n,
                 )
                 aux_all.append(aux)
-        return self.last_token, aux_all, computed, valid
+        return self.last_token, aux_all, rows
 
-    def _stage_chunk_rows(self, n, group, tokens, base, chunk_lens, floor,
-                          sample_mask) -> tuple:
+    def _stage_chunk_rows(self, n, slots, group, tokens, base, chunk_lens,
+                          floor, sample_mask) -> tuple:
         """A compacted step's arguments from ``slot_idx`` to
-        ``block_tables``, on the device, for the slots ``group`` padded to
-        ``n`` rows (a padding row names the slot past the last and holds
-        zeros). The seven ride in ONE int32 vector, moved as the one
+        ``block_tables``, on the device, for the seats ``group`` (rows of
+        the caller's arrays) of the slots ``slots``, padded to ``n`` rows
+        (a padding row names the slot past the last and holds zeros).
+        The seven ride in ONE int32 vector, moved as the one
         argument of the jitted :meth:`_chunk_rows_step`, which takes it
         apart again: on the chip's host a transfer costs 0.2-0.3 ms and
         a small jitted call 0.3-0.7 whatever they carry, so this is 0.7 ms
@@ -1684,7 +1755,7 @@ class Engine:
         packed = np.zeros((n * (w + 5) + tables.size,), np.int32)
         rows = packed[: n * (w + 5)].reshape(n, w + 5)
         rows[:, w] = self.slots
-        rows[:g, w] = group
+        rows[:g, w] = slots
         rows[:g, :w] = np.asarray(tokens)[group]
         for col, a in enumerate((base, chunk_lens, floor, sample_mask), 1):
             rows[:g, w + col] = np.asarray(a)[group]
@@ -1700,14 +1771,14 @@ class Engine:
     def prefill_fetch(self, step) -> np.ndarray:
         """The tokens of a chunk :meth:`prefill_dispatch` enqueued, as
         host numpy: the wait for the step and the copy back."""
-        last, aux_all, computed, valid = step
+        last, aux_all, rows = step
         with obs.span("prefill_fetch"):  # the wait and the copy back
             # The step's one deliberate completion fence (docstring
             # contract: the fetch closes the caller's span).
             # analysis: allow(host-sync-in-hot-seam)
             toks = np.asarray(last)
         if obs.enabled():
-            self._note_prefill_rows(computed, valid)
+            self._note_prefill_rows(*rows)
             for aux in aux_all:
                 self._note_aux("prefill", aux)
         return toks
@@ -1724,28 +1795,34 @@ class Engine:
                 "prefill", self._prefill_compact_jit,
                 self.params, self.cache, self.last_token,
                 *self._stage_chunk_rows(
-                    n, none, empty, zeros, zeros, zeros, zeros
+                    n, none, none, empty, zeros, zeros, zeros, zeros
                 ),
                 self._split(), jnp.zeros((self.slots,), jnp.float32),
                 jnp.zeros((self.slots,), jnp.int32), count=n,
             )
 
     @staticmethod
-    def _rows_attrs(computed: int, valid: int) -> dict:
+    def _rows_attrs(computed: int, valid: int, chained: int) -> dict:
         """What a chunk tick's ``prefill_dispatch`` span says of its
-        rows: those its steps compute and those of them that are no
-        prompt tokens (padding of a chunk, of a count, idle slots).
-        Nothing while no recorder is on."""
+        rows: those its steps compute, those of them that are no prompt
+        tokens (padding of a chunk, of a count, idle slots), and the
+        prompt tokens that rode a seat no slot had taken
+        (:meth:`spare_seats`). Nothing while no recorder is on."""
         if not obs.enabled():
             return {}
-        return dict(rows_computed=computed, rows_wasted=computed - valid)
+        return dict(
+            rows_computed=computed, rows_wasted=computed - valid,
+            rows_chained=chained,
+        )
 
     @staticmethod
-    def _note_prefill_rows(computed: int, valid: int) -> None:
-        """Rows a chunk tick computed and those of them that were prompt
-        tokens (gauges; the benchmark reads the waste from them)."""
+    def _note_prefill_rows(computed: int, valid: int, chained: int) -> None:
+        """Rows a chunk tick computed, those of them that were prompt
+        tokens and those of these in a slot's second seat (gauges; the
+        benchmark reads the waste from them)."""
         obs.gauge("prefill_rows_computed", float(computed))
         obs.gauge("prefill_rows_valid", float(valid))
+        obs.gauge("prefill_rows_chained", float(chained))
 
     def _note_aux(self, phase: str, aux) -> None:
         """What the model counted in a step, as counters and gauges:

@@ -79,7 +79,9 @@ Admission is a PAGE grant, not just a slot grant — the head of the
 queue gets a free slot plus its whole page requirement (fresh pages + shared-prefix mappings + COW reserve,
 all-or-nothing) or waits; prompts feed the device ``prefill_chunk``
 tokens per tick interleaved with decode (``prefilling`` state — a long
-admit cannot head-of-line-block TTFT for live slots); a finished prompt
+admit cannot head-of-line-block TTFT for live slots), and one chunk more
+for every seat the tick's step would otherwise compute for nobody
+(``Engine.spare_seats``, :meth:`Server._stage_chunk`); a finished prompt
 is registered in the allocator's prefix index so later identical
 prefixes map the same pages (refcounted, copy-on-write on divergence —
 the scheduler calls ``cow_before_write`` before every prefill-chunk /
@@ -1083,8 +1085,9 @@ class Server:
                 self._settle_prefill(step)
 
     def _prefill_chunk_tick(self) -> None:
-        """Enqueue ONE prompt chunk for every prefilling slot (one
-        batched call), then fetch the chunk the last tick enqueued.
+        """Enqueue one prompt chunk for every prefilling slot, and one
+        more for as many as the step has seats to spare (one batched
+        call), then fetch the chunk the last tick enqueued.
 
         Slots whose final prompt token rides this chunk register their
         prompt in the prefix index (only now — an index entry must never
@@ -1099,13 +1102,12 @@ class Server:
         attrs = {}
         chunk = bool(self.prefilling)
         if chunk:
-            tokens, base, chunk_lens, floor, sample_mask, finishing = (
-                self._stage_chunk()
-            )
+            (seats, tokens, base, chunk_lens, floor, sample_mask,
+             finishing) = self._stage_chunk()
             if obs.enabled():  # a disabled span costs a tick nothing
                 attrs = dict(
                     admitted=len(finishing),
-                    chunks=int((chunk_lens > 0).sum()),
+                    chunks=len(seats),
                     rids=[live.req.rid for live in self.prefilling.values()],
                     sampler_path=self._sampler_path(),
                 )
@@ -1124,39 +1126,70 @@ class Server:
                     "prefill",
                     self.engine.prefill_dispatch(
                         tokens, base, chunk_lens, floor, sample_mask,
-                        self._temp, self._topk,
+                        self._temp, self._topk, seats,
                     ),
                     finishing, self.tick,
                 ))
             landed = self._land("prefill", self.tick + bool(self._spec))
         t_end = time.perf_counter()
         if chunk:
-            self._chunk_enqueued(chunk_lens, finishing, t_end - now, t_end)
+            self._chunk_enqueued(
+                np.bincount(seats, chunk_lens, self.engine.slots),
+                finishing, t_end - now, t_end,
+            )
         for step in landed:
             self._settle_prefill(step)
 
     def _stage_chunk(self):
-        """The next chunk of every prefilling slot as the step's host
-        arrays, the page copies its writes need enqueued before it, and
-        the slots whose final prompt token rides it."""
+        """The tick's SEATS as the step's host arrays (a row a seat:
+        ``seats[i]`` is the slot whose chunk row ``i`` holds), the page
+        copies their writes need enqueued before it, and the slots whose
+        final prompt token rides it.
+
+        Every prefilling slot takes a seat: its next chunk. The seats
+        the engine's step would then compute for nobody
+        (``Engine.spare_seats``) go, one more chunk at a time, to the
+        slot with the most prompt left beyond what it holds already.
+        A slot's pages were all granted at admission, so a further seat
+        needs none; it starts where the seat before it ends, and only on
+        a page boundary: the pool's page writer lands whole pages, and
+        two seats that met inside one would both write it."""
         eng = self.engine
         alloc = eng.allocator
-        s, w = eng.slots, eng.prefill_chunk
-        tokens = np.zeros((s, w), np.int32)
-        base = np.zeros((s,), np.int32)
-        chunk_lens = np.zeros((s,), np.int32)
-        floor = np.zeros((s,), np.int32)
-        sample_mask = np.zeros((s,), bool)
-        finishing: list[tuple[int, _Live]] = []
+        w, page = eng.prefill_chunk, eng.page_size
+        rows = []  # (slot, first position, tokens) a seat, in step order
+        at = {}  # where a slot's next seat would start
         for slot, live in self.prefilling.items():
+            n = min(w, len(live.feed_tokens()) - live.base)
+            rows.append((slot, live.base, n))
+            at[slot] = live.base + n
+
+        def left(slot):
+            return len(self.prefilling[slot].feed_tokens()) - at[slot]
+
+        for _ in range(eng.spare_seats(len(rows))):
+            slot = max(
+                (s for s in at if at[s] % page == 0), key=left, default=None
+            )
+            if slot is None or left(slot) <= 0:
+                break
+            n = min(w, left(slot))
+            rows.append((slot, at[slot], n))
+            at[slot] += n
+        seats = np.zeros((len(rows),), np.int32)
+        tokens = np.zeros((len(rows), w), np.int32)
+        base, chunk_lens, floor = (np.zeros_like(seats) for _ in range(3))
+        sample_mask = np.zeros((len(rows),), bool)
+        finishing: list[tuple[int, _Live]] = []
+        for i, (slot, start, n) in enumerate(rows):
+            live = self.prefilling[slot]
             p = live.feed_tokens()
-            n = min(w, len(p) - live.base)
-            # First write of this chunk: at the floor on a partial-page
-            # prefix hit, else at the feed base. A write landing in a
+            # First write of this seat: at the floor on a partial-page
+            # prefix hit, else where it starts. A write landing in a
             # still-shared page copies it out first (device page copy);
             # the allocator's admission reserve guarantees the free page.
-            first_write = max(live.base, live.floor)
-            if first_write < live.base + n:
+            first_write = max(start, live.floor)
+            if first_write < start + n:
                 pair = alloc.cow_before_write(slot, first_write)
                 if pair is not None:
                     eng.copy_page(*pair)
@@ -1165,14 +1198,13 @@ class Server:
                             live.req.rid, "cow_copy", tick=self.tick,
                             src=pair[0], dst=pair[1], phase="prefill",
                         )
-            tokens[slot, :n] = p[live.base : live.base + n]
-            base[slot] = live.base
-            chunk_lens[slot] = n
-            floor[slot] = live.floor
-            if live.base + n == len(p):
-                sample_mask[slot] = True
+            tokens[i, :n] = p[start : start + n]
+            seats[i], base[i], chunk_lens[i] = slot, start, n
+            floor[i] = live.floor
+            if start + n == len(p):
+                sample_mask[i] = True
                 finishing.append((slot, live))
-        return tokens, base, chunk_lens, floor, sample_mask, finishing
+        return seats, tokens, base, chunk_lens, floor, sample_mask, finishing
 
     def _chunk_enqueued(self, chunk_lens, finishing, dur: float,
                         t_end: float) -> None:

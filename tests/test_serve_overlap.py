@@ -216,12 +216,12 @@ class Recording:
         self.log.append((event, kind, sid, rids, t0, time.perf_counter()))
 
     def prefill_dispatch(self, tokens, base, chunk_lens, floor, sample_mask,
-                         temp, topk):
+                         temp, topk, seats):
         t0 = time.perf_counter()
         rids = [self.server.prefilling[s].req.rid
-                for s in np.flatnonzero(sample_mask)]
+                for s in seats[sample_mask]]
         step = self._engine.prefill_dispatch(
-            tokens, base, chunk_lens, floor, sample_mask, temp, topk)
+            tokens, base, chunk_lens, floor, sample_mask, temp, topk, seats)
         self._note("enqueue", "prefill", step, rids, t0)
         return step
 
