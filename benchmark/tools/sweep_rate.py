@@ -37,17 +37,26 @@ def main(workload: str, seconds: float, changes: list) -> None:
         if not isinstance(change, dict):
             change = {"rate_per_s": change, "answer_cap_s": 20.0}
         mix = {k: v for k, v in {**traffic, **change}.items() if v is not None}
+        window = {}  # the driver's ``window`` line: the queue at both edges
+
+        def say(kind, **f):
+            if kind == "window":
+                window.update(f)
+            bench_run.say(kind, changed=change, **f)
+
         ctx = {
             "cell": cell, "config": config, "devices": devs, "seed": 9000 + i,
             "traffic": mix, "seconds": seconds, "trace": False,
             "rehearse": False, "control": False, "setup": {}, "compiles": None,
-            "trace_dir": "",
-            "say": lambda kind, change=change, **f: bench_run.say(kind, changed=change, **f),
+            "trace_dir": "", "say": say,
         }
         run = driver.run(ctx)
         bench_run.say("swept", changed=change, attempted=run["attempted"],
                       failed=run["failed"], correct=run["correct"],
-                      **run["end_to_end"])
+                      **run["end_to_end"],
+                      **{k: window.get(k) for k in (
+                          "queued_at_open", "queued_at_close", "still_queued",
+                          "ttft_max_ms", "late_p95_ms", "ran_past_window_s")})
 
 
 if __name__ == "__main__":

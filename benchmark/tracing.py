@@ -12,11 +12,16 @@ import shutil
 import time
 
 TRACE_CAP_S = 6.0
+# An open loop starts the profiler this long before its window opens
+# (``drivers/requests.py``): what queued up behind the start has to drain
+# before the window does.
+START_AHEAD_S = 0.25
 
 
-def start(trace_dir: str) -> float:
+def start(trace_dir: str, say=None) -> float:
     """Begin a trace; returns the host time of the mark that ties the
-    host clock to the trace clock."""
+    host clock to the trace clock. ``say``, where given, is told how long
+    the profiler took to start (and by ``stop`` to write the trace)."""
     import jax
 
     from benchmark import xplane
@@ -25,17 +30,23 @@ def start(trace_dir: str) -> float:
     options = jax.profiler.ProfileOptions()
     options.python_tracer_level = 0  # the interpreter's calls are not needed
     options.host_tracer_level = 2
+    t0 = time.perf_counter()
     jax.profiler.start_trace(trace_dir, profiler_options=options)
     t_mark = time.perf_counter()
+    if say is not None:
+        say("trace_started", start_trace_s=t_mark - t0)
     with jax.profiler.TraceAnnotation(xplane.MARK):
         pass
     return t_mark
 
 
-def stop() -> None:
+def stop(say=None) -> None:
     import jax
 
+    t0 = time.perf_counter()
     jax.profiler.stop_trace()
+    if say is not None:
+        say("trace_stopped", stop_trace_s=time.perf_counter() - t0)
 
 
 def host_spans(recorder, t0: float, t1: float) -> list:
