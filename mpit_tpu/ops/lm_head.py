@@ -66,7 +66,8 @@ def _match_vma(x, *refs):
 
 
 def _block_logits(h, head_block, valid, compute_dtype):
-    """[N, D] x [block, D] -> [N, block] f32 logits; padded cols -> -big.
+    """[N, D] x [rows, D] -> [N, block] f32 logits (``block`` is
+    ``valid``'s length, ``rows <= block``); padded cols -> -big.
 
     A quantized head block (ISSUE 17) dequantizes HERE, per vocab tile
     inside the scan — the only f32 view of the head that ever exists is
@@ -83,6 +84,14 @@ def _block_logits(h, head_block, valid, compute_dtype):
             h.astype(compute_dtype),
             head_block.astype(compute_dtype).T,
             preferred_element_type=jnp.float32,
+        )
+    short = valid.shape[0] - logits.shape[1]
+    if short:
+        # The ragged last block of a sampler's head (_block_runner):
+        # its [N, tail] logits are padded to the tile, not the table's
+        # rows, so the caller sees the tile a zero-padded table gave.
+        logits = jnp.pad(
+            logits, ((0, 0), (0, short)), constant_values=_NEG_BIG
         )
     return jnp.where(valid[None, :], logits, _NEG_BIG)
 
@@ -237,41 +246,56 @@ def _round_up(x: int, m: int) -> int:
     return x + (-x) % m
 
 
-def _head_blocks(head, block):
-    """Pad head rows to a ``block`` multiple and tile to ``[n_blocks,
-    block, d]`` — plain arrays and
-    :class:`~mpit_tpu.ops.quantized_matmul.QuantizedTensor` alike.
-    Quantized pad rows are zero int8 with scale 1.0 (exact-zero
-    dequant); either way the ``valid`` column mask in
-    :func:`_block_logits` scores pad columns ``-big`` before any merge.
-    A quantized result is itself a ``QuantizedTensor`` of tiles:
-    ``lax.scan`` slices pytree xs leaf-wise, so each tick receives one
-    ``(q [block, d], scale [block, 1])`` pair."""
-    vocab, d = head.shape
-    pad = (-vocab) % block
-    if isinstance(head, QuantizedTensor):
-        q, scale = head.q, head.scale
-        if pad:
-            q = jnp.concatenate(
-                [q, jnp.zeros((pad, d), q.dtype)], axis=0
-            )
-            scale = jnp.concatenate(
-                [scale, jnp.ones((pad, 1), scale.dtype)], axis=0
-            )
-        n = q.shape[0] // block
-        return (
-            QuantizedTensor(
-                q=q.reshape(n, block, d),
-                scale=scale.reshape(n, block, 1),
-            ),
-            n,
+def _block_runner(head, block):
+    """``run(tick, init, xs) -> carry``: ``tick(carry, (head_b, *x)) ->
+    (carry, None)`` over the head's vocabulary blocks, a scan over the
+    ``vocab // block`` full ones and then once more on the ragged last
+    ``vocab % block`` rows if there are any. ``xs`` are the arrays that
+    ride beside the blocks (offsets, block ids, ``qprobs`` tiles), a row
+    a block, the tail's last. ``head`` is a plain array or a
+    :class:`~mpit_tpu.ops.quantized_matmul.QuantizedTensor` (``q`` and
+    ``scale`` go together: each tick receives one ``(q [rows, d], scale
+    [rows, 1])`` pair).
+
+    The table is never padded or copied; the tail's LOGITS are padded
+    (:func:`_block_logits`). Which form runs is read from the shapes:
+
+    - the block divides the vocabulary: the tiles are a reshape of the
+      table, taken here, and ``run`` is the one scan over ``(tiles,
+      *xs)`` that it always was;
+    - a ragged table is read where it lies: every tick slices its block
+      out of ``head`` itself, inside the loop, so the slice fuses into
+      the product. (A prefix of the table tiled by a reshape and handed
+      to the scan is a buffer to the compiler, 126 MB for GPT-2: the
+      copy this form exists to avoid, under the name ``slice``.)"""
+    n_full, tail_rows = divmod(head.shape[0], block)
+    unroll = min(n_full, 16)
+    if not tail_rows:
+        tiles = jax.tree.map(
+            lambda x: x.reshape(n_full, block, x.shape[-1]), head
         )
-    if pad:
-        head = jnp.concatenate(
-            [head, jnp.zeros((pad, d), head.dtype)], axis=0
+        return lambda tick, init, xs: lax.scan(
+            tick, init, (tiles, *xs), unroll=unroll
+        )[0]
+
+    def rows(start, size):
+        return jax.tree.map(
+            lambda x: lax.dynamic_slice_in_dim(x, start, size), head
         )
-    n = head.shape[0] // block
-    return head.reshape(n, block, d), n
+
+    def run(tick, carry, xs):
+        if n_full:
+            starts = jnp.arange(n_full, dtype=jnp.int32) * block
+            carry, _ = lax.scan(
+                lambda c, x: tick(c, (rows(x[0], block), *x[1:])),
+                carry,
+                (starts, *(x[:n_full] for x in xs)),
+                unroll=unroll,
+            )
+        tail = rows(n_full * block, tail_rows)
+        return tick(carry, (tail, *(x[-1] for x in xs)))[0]
+
+    return run
 
 
 # ---------------------------------------------------------------------------
@@ -357,7 +381,8 @@ def lm_head_sample(
     """
     vocab, d = head.shape
     block = min(block_size, _round_up(vocab, 128))
-    head_blocks, n_blocks = _head_blocks(head, block)
+    over_blocks = _block_runner(head, block)
+    n_blocks = -(-vocab // block)
     offsets = jnp.arange(n_blocks, dtype=jnp.int32) * block
     blk_ids = jnp.arange(n_blocks, dtype=jnp.int32)
     n = h.shape[0]
@@ -378,10 +403,7 @@ def lm_head_sample(
             logits, _ = block_logits(head_b, off)
             return _merge_first_max(*carry, logits, off), None
 
-        (_, gi), _ = lax.scan(
-            tick, (neg, zero_i), (head_blocks, offsets),
-            unroll=min(n_blocks, 16),
-        )
+        _, gi = over_blocks(tick, (neg, zero_i), (offsets,))
         return gi
 
     def general_path():
@@ -423,9 +445,8 @@ def lm_head_sample(
             jnp.zeros((n, kb), jnp.int32),  # top-k global indices
             jnp.full((n, kb), _NEG_BIG, jnp.float32),  # top-k noised scores
         )
-        (gv, gi, sv, si, bv, bi, bs), _ = lax.scan(
-            tick, init, (head_blocks, offsets, blk_ids),
-            unroll=min(n_blocks, 16),
+        gv, gi, sv, si, bv, bi, bs = over_blocks(
+            tick, init, (offsets, blk_ids)
         )
         # Top-k draw: threshold at the row's k-th largest value inside
         # the buffer (reference semantics: keep logits >= thresh),
@@ -501,7 +522,8 @@ def lm_head_verify(
             [qprobs, jnp.zeros((qprobs.shape[0], pad), qprobs.dtype)],
             axis=1,
         )
-    head_blocks, n_blocks = _head_blocks(head, block)
+    over_blocks = _block_runner(head, block)
+    n_blocks = -(-vocab // block)
     offsets = jnp.arange(n_blocks, dtype=jnp.int32) * block
     blk_ids = jnp.arange(n_blocks, dtype=jnp.int32)
     n = h.shape[0]
@@ -547,9 +569,7 @@ def lm_head_verify(
         jnp.full((n, kb), _NEG_BIG, jnp.float32),  # top-k values
         jnp.zeros((n, kb), jnp.int32),  # top-k global indices
     )
-    (gv, gi, m, s, tl, bv, bi), _ = lax.scan(
-        tick_a, init, (head_blocks, offsets), unroll=min(n_blocks, 16)
-    )
+    gv, gi, m, s, tl, bv, bi = over_blocks(tick_a, init, (offsets,))
     lse_full = m + jnp.log(s)
     kk = jnp.clip(top_k, 1, kb)
     thresh = jnp.take_along_axis(bv, (kk - 1)[:, None], axis=1)[:, 0]
@@ -600,10 +620,8 @@ def lm_head_verify(
             upd = sm > rv
             return (jnp.where(upd, sm, rv), jnp.where(upd, smi, ri)), None
 
-        (_, ri), _ = lax.scan(
-            tick_b, (neg, zero_i),
-            (head_blocks, offsets, blk_ids, qp_blocks),
-            unroll=min(n_blocks, 16),
+        _, ri = over_blocks(
+            tick_b, (neg, zero_i), (offsets, blk_ids, qp_blocks)
         )
         return ri
 

@@ -103,9 +103,10 @@ int8``, ``--weights-dtype int8``, ``--spec-k``, ``--kv-host-pages``, a
 ``--policy`` that preempts (``preempt=0`` runs), ``--fleet`` shipment,
 ``--ckpt``. A prompt that repeats an earlier one is computed whole (the
 prefix hit is passed up and counted: ``prefix_hits_passed_up``).
-``--sample-block`` is the blocked sampler's tile of the head's rows: one
-that divides the vocabulary (7168 for this family's 100,352) spares a
-padded copy of the table a step.
+``--sample-block`` is the blocked sampler's tile of the head's rows (7168
+divides this family's 100,352). Since PR 45 a tile that does not divide
+the vocabulary costs no copy of the table: the full blocks are read where
+they lie and the ragged last block's logits are padded.
 
 ``--family glm_dsa`` (ISSUE 34) serves the fourth: latent attention
 under learned sparse attention (``models/glm_dsa.py``, ``ops/dsa.py``): a

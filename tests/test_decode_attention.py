@@ -498,39 +498,68 @@ class TestLMHeadSample:
             temp <= 0, jnp.argmax(logits, -1).astype(jnp.int32), samp
         )
 
-    def test_greedy_bitmatches_full_argmax(self):
-        h, head = self._setup()
-        full = jnp.dot(h, head.T, preferred_element_type=jnp.float32)
+    # The three shapes the blocked head tells apart (``_block_runner``),
+    # as ``(vocab, block_size)``, each under a plain and an int8 head:
+    # the block divides the vocabulary (one scan over a reshape, as it
+    # always was); full blocks and a ragged tail (the scan reads the
+    # table where it lies, the tail's LOGITS are padded); a vocabulary
+    # under one block (the tail alone).
+    SHAPES = {
+        "divides": (256, 64),
+        "ragged_tail": (203, 64),  # 3 blocks and 11 rows
+        "under_one_block": (50, 8192),  # clamped to 128: 50 rows, no scan
+    }
+    shapes = pytest.mark.parametrize("shape", list(SHAPES))
+    heads = pytest.mark.parametrize("quantized", [False, True],
+                                    ids=["plain", "int8"])
+
+    def _case(self, shape, quantized):
+        """``(h, head, full logits, resolved block)`` of one shape."""
+        from mpit_tpu.ops.quantized_matmul import quantize_tensor
+
+        V, block_size = self.SHAPES[shape]
+        h, head = self._setup(V=V)
+        if quantized:
+            head = quantize_tensor(head)
+        full = self._full_logits(h, head, jnp.float32)
+        return h, head, full, block_size, min(block_size, V + (-V) % 128)
+
+    @shapes
+    @heads
+    def test_greedy_bitmatches_full_argmax(self, shape, quantized):
+        h, head, full, block_size, _ = self._case(shape, quantized)
         got = lm_head_sample(
             h, head, jax.random.key(3),
             jnp.zeros((5,), jnp.float32), jnp.zeros((5,), jnp.int32),
-            block_size=64,
+            block_size=block_size,
         )
         assert jnp.all(got == jnp.argmax(full, -1))
 
+    @shapes
+    @heads
     @pytest.mark.parametrize(
         "t_val,k_val", [(1.0, 0), (0.7, 5), (2.5, 1), (1.0, 128), (0.5, 17)]
     )
     def test_topk_temperature_match_oracle_under_fixed_key(
-        self, t_val, k_val
+        self, t_val, k_val, shape, quantized
     ):
-        h, head = self._setup()
+        h, head, full, block_size, block = self._case(shape, quantized)
         key = jax.random.key(7)
-        full = jnp.dot(h, head.T, preferred_element_type=jnp.float32)
         temp = jnp.full((5,), t_val, jnp.float32)
         topk = jnp.full((5,), k_val, jnp.int32)
-        got = lm_head_sample(h, head, key, temp, topk, block_size=64)
-        want = self._oracle(full, key, temp, topk, 64)
+        got = lm_head_sample(h, head, key, temp, topk, block_size=block_size)
+        want = self._oracle(full, key, temp, topk, block)
         assert jnp.all(got == want)
 
-    def test_per_slot_mixed_modes(self):
-        h, head = self._setup()
+    @shapes
+    @heads
+    def test_per_slot_mixed_modes(self, shape, quantized):
+        h, head, full, block_size, block = self._case(shape, quantized)
         key = jax.random.key(11)
-        full = jnp.dot(h, head.T, preferred_element_type=jnp.float32)
         temp = jnp.asarray([0.0, 1.0, 0.5, 2.0, -1.0], jnp.float32)
         topk = jnp.asarray([0, 0, 3, 50, 7], jnp.int32)
-        got = lm_head_sample(h, head, key, temp, topk, block_size=64)
-        assert jnp.all(got == self._oracle(full, key, temp, topk, 64))
+        got = lm_head_sample(h, head, key, temp, topk, block_size=block_size)
+        assert jnp.all(got == self._oracle(full, key, temp, topk, block))
 
     # -- the greedy path (ISSUE 33): a call in which no row samples takes
     # one max and one argmax a block and nothing else ---------------------
@@ -668,6 +697,81 @@ class TestLMHeadSample:
             e for e in jc._walk_eqns(greedy) if e.primitive.name == "scan"
         ]
         assert scan.params["num_carry"] == 2
+
+    @staticmethod
+    def _trace(fn, V, quantized, S=5, D=24):
+        """The jaxpr of the blocked sampler or verifier over a head of
+        ``V`` rows in blocks of 64."""
+        from mpit_tpu.ops.lm_head import lm_head_verify
+        from mpit_tpu.ops.quantized_matmul import quantize_tensor
+
+        h = jnp.zeros((S, D), jnp.float32)
+        head = jnp.zeros((V, D), jnp.float32)
+        if quantized:
+            head = quantize_tensor(head)
+        temp = jnp.ones((S,), jnp.float32)
+        topk = jnp.zeros((S,), jnp.int32)
+        if fn == "sample":
+            return jax.make_jaxpr(
+                lambda h, w: lm_head_sample(
+                    h, w, jax.random.key(0), temp, topk, block_size=64
+                )
+            )(h, head)
+        return jax.make_jaxpr(
+            lambda h, w, q: lm_head_verify(
+                h, w, topk, q, jax.random.key(0), temp, topk,
+                block_size=64, k_cap=8,
+            )
+        )(h, head, jnp.zeros((S, V), jnp.float32))
+
+    @classmethod
+    def _products_outside_scans(cls, jaxpr):
+        """``dot_general`` equations that no ``scan`` holds: the ticks a
+        blocked head runs beside its loop over the full blocks."""
+        from mpit_tpu.analysis import jaxpr_check as jc
+
+        n = 0
+        for e in jc._as_jaxpr(jaxpr).eqns:
+            if e.primitive.name == "dot_general":
+                n += 1
+            elif e.primitive.name != "scan":
+                for p in e.params.values():
+                    for sub in jc.sub_jaxprs(p):
+                        n += cls._products_outside_scans(sub)
+        return n
+
+    @pytest.mark.parametrize("quantized", [False, True], ids=["plain", "int8"])
+    @pytest.mark.parametrize("fn", ["sample", "verify"])
+    def test_the_table_is_never_rebuilt(self, fn, quantized):
+        """A ragged vocabulary is read where it lies: nothing in the
+        jaxpr concatenates or pads to an array of ``d_model`` columns
+        (the table, or an int8 table's rows; ``[rows, 1]`` would be its
+        scales), and the tail is one tick more on each path (the
+        sampler's two branches; the verifier's two passes). Where the
+        block divides the vocabulary there is no tail tick at all and
+        nothing is padded."""
+        from mpit_tpu.analysis import jaxpr_check as jc
+
+        D, ticks = 24, 2
+        ragged = self._trace(fn, 203, quantized, D=D)
+        rebuilt = [
+            (e.primitive.name, v.aval.shape)
+            for e in jc._walk_eqns(ragged)
+            if e.primitive.name in ("concatenate", "pad")
+            for v in e.outvars
+            if v.aval.shape[-1] in (D, 1)
+        ]
+        assert not rebuilt, rebuilt
+        assert self._products_outside_scans(ragged) == ticks
+        assert jc.find_primitives(ragged, {"pad"}) == ["pad"] * ticks
+        assert jc.find_primitives(ragged, {"dynamic_slice"})
+        divides = self._trace(fn, 256, quantized, D=D)
+        assert self._products_outside_scans(divides) == 0
+        assert not jc.find_primitives(divides, {"pad", "dynamic_slice"})
+        # One block holds the whole vocabulary: the tail alone, no scan.
+        alone = self._trace(fn, 50, quantized, D=D)
+        assert self._products_outside_scans(alone) == ticks
+        assert not jc.find_primitives(alone, {"scan"})
 
     def test_no_full_logits_in_jaxpr(self):
         """The pin, same style as the training LM-head: no [S, vocab]
