@@ -1,8 +1,8 @@
 """sha256 of the lowered text of the steps the benchmark's cells run, AOT
 for a described v5e (no chip needed): GPT-2 large's paged decode, chunk
 (the full-batch step and the compacted one a count of participants) and
-page-copy steps at the serving cells' shape, xing4's, glm_dsa's and
-olmo_hybrid's decode and compacted chunk steps at their cells' shapes, GPT-2 small's
+page-copy steps at the serving cells' shape, xing4's, glm_dsa's,
+olmo_hybrid's and laguna's decode and compacted chunk steps at their cells' shapes, GPT-2 small's
 train step over the 2x2. Two trees that print the same hashes run the same device
 programs; a refactoring PR proves itself with
 
@@ -165,6 +165,36 @@ def main():
             args = (eng.params, cache, eng.last_token, z, jnp.zeros((n, O_CHUNK), jnp.int32), z, z, z,
                     jnp.zeros((n,), bool), bt, key, f32, i32)
             out[f"olmo_hybrid.jit_prefill_paged.compact{n}"] = sha(
+                eng._prefill_compact_jit.lower(*on_chip(args)).as_text())
+        del eng
+
+    # laguna, its cell's shape (a tree from before the family prints nothing for it)
+    try:
+        from mpit_tpu.models import laguna
+    except ImportError:
+        laguna = None
+    if laguna is not None:
+        L_S, L_POS, L_PAGE, L_CHUNK = 32, 21504, 256, 512
+        lcfg = laguna.LagunaConfig(num_hidden_layers=5, vocab_size=25088, experts_held=tuple(range(64)),
+                                   max_seq_len=L_POS)
+        lparams = jax.eval_shape(lambda: laguna.init_params(lcfg, jax.random.key(0)))
+        pps = L_POS // L_PAGE
+        eng = Engine(lcfg, lparams, slots=L_S, max_len=L_POS, seed=1, kv_pages=2 * pps, kv_page_size=L_PAGE,
+                     prefill_chunk=L_CHUNK, sample_block=6272)
+        # The window layers' pool is sized by the slots, whatever kv_pages is: only the full layers' grows.
+        pool = lambda bufs: tuple(
+            jax.ShapeDtypeStruct((L_S * pps if b.shape[0] == 2 * pps else b.shape[0], *b.shape[1:]), b.dtype)
+            for b in bufs)
+        cache = dataclasses.replace(eng.cache, k=pool(eng.cache.k), v=pool(eng.cache.v))
+        i32, f32 = jnp.zeros((L_S,), jnp.int32), jnp.zeros((L_S,), jnp.float32)
+        bt = jnp.zeros((L_S, 2 * pps), jnp.int32)  # a table a lifetime, side by side
+        args = (eng.params, cache, eng.last_token, jnp.zeros((L_S,), bool), bt, key, f32, i32)
+        out["laguna.jit_decode_paged"] = sha(eng._decode_paged_jit.lower(*on_chip(args)).as_text())
+        for n in eng._prefill_counts:
+            z = jnp.zeros((n,), jnp.int32)
+            args = (eng.params, cache, eng.last_token, z, jnp.zeros((n, L_CHUNK), jnp.int32), z, z, z,
+                    jnp.zeros((n,), bool), bt, key, f32, i32)
+            out[f"laguna.jit_prefill_paged.compact{n}"] = sha(
                 eng._prefill_compact_jit.lower(*on_chip(args)).as_text())
         del eng
 

@@ -51,9 +51,14 @@ class PageLayer:
     position has in this layer's pool (two or three) and the values it
     keeps in each, under one block table and one lifetime. What a seat
     holds is the family's business; the pool calls the first two ``k``
-    and ``v`` and a third ``x``."""
+    and ``v`` and a third ``x``. ``window`` is the lifetime: 0 keeps
+    every position of the sequence; ``w`` keeps the last ``w`` (a query at
+    position ``t`` reads ``t - w < s <= t``), and the pages behind them go
+    back to the pool of the window layers, which has its own block
+    table."""
 
     widths: tuple
+    window: int = 0
 
     def __post_init__(self):
         if len(self.widths) not in (2, 3):
@@ -107,21 +112,36 @@ class CacheLayout:
         return tuple(l for l in self.layers if isinstance(l, StateLayer))
 
     @property
+    def window(self) -> int:
+        """The window of the layers that keep a window of positions (one
+        for all of them); 0 where every page layer keeps them all."""
+        windows = {l.window for l in self.page_layers if l.window}
+        if len(windows) > 1:
+            raise ValueError(
+                f"window layers of one model share a window, not {windows}")
+        return windows.pop() if windows else 0
+
+    @property
     def prefix_shareable(self) -> bool:
         """Whether pages mapped from another sequence's prefix are all
         the sequence needs: not where a layer's state at that boundary
-        would have to be restored too, and not yet where a layer
-        keeps a third seat (untested with a mapped prefix)."""
-        return not self.state_layers and not self.third_seats
+        would have to be restored too, not yet where a layer
+        keeps a third seat (untested with a mapped prefix), and not
+        where a layer keeps a window (its pages of the prefix are
+        gone)."""
+        return not (self.state_layers or self.third_seats or self.window)
 
-    def page_bytes(self, page_size: int, dtype, quantized: bool) -> int:
-        """One page across every page-holding layer and every seat it
-        keeps, as the pool stores it (an int8 pool: payload and float32
-        scales a seat)."""
+    def page_bytes(self, page_size: int, dtype, quantized: bool, *,
+                   window: bool = False) -> int:
+        """One page across every page-holding layer of one lifetime (the
+        layers that keep every position, or with ``window`` those that
+        keep a window) and every seat it keeps, as the pool stores it (an
+        int8 pool: payload and float32 scales a seat)."""
         item = 1 if quantized else np.dtype(dtype).itemsize
         scales = 4 * self.scale_width if quantized else 0
         return page_size * sum(
-            w * item + scales for l in self.page_layers for w in l.widths)
+            w * item + scales for l in self.page_layers
+            if bool(l.window) == window for w in l.widths)
 
     @property
     def third_seats(self) -> bool:

@@ -95,6 +95,10 @@ from mpit_tpu.ops.ring_collectives import dequantize_blocks, sublane_for
 
 __all__ = [
     "flash_paged_decode_attention",
+    "grouped_paged_attention",
+    "reference_grouped_paged_attention",
+    "grouped_rows",
+    "grouped_block_k",
     "paged_write_pages",
     "writes_by_pages",
     "reference_paged_decode_attention",
@@ -775,6 +779,231 @@ def paged_write_pages(pool, new, lengths, block_table, valid=None, *,
     return _paged_write_call(
         pool, new, lengths, block_table, valid, interpret=bool(interpret)
     )
+
+
+# ---------------------------------------------------------------------------
+# Grouped-query heads, and a window. The pool row of a layer with fewer
+# key/value heads than query heads is ``H_kv x D`` lanes; the ``G = H /
+# H_kv`` query heads of a group are rows against ONE key/value head's
+# lanes, the form ``ops/mla_attention.py`` has for a row all heads share.
+# The same loop takes a first position: with ``window`` set a query at
+# position ``t`` attends ``t - window < s <= t``, so the loop starts at the
+# tile that holds the slot's first visible key and the tiles (and pages)
+# before it are not visited.
+# ---------------------------------------------------------------------------
+
+# Query rows one call of the grouped kernel takes a slot (a longer chunk
+# goes as that many calls' worth of "slots", each at its own fill of the
+# same pages, as olmo_hybrid's full layers do): a key/value head's product
+# then has ``rows x G`` rows, 384-576 at G = 6 and 9, and the eight heads'
+# float32 accumulators stay under 2.5 MB of VMEM.
+_GROUP_ROWS = 64
+
+
+def grouped_rows(t_q: int) -> int:
+    """Query rows a call of the grouped kernel takes a slot."""
+    return _GROUP_ROWS if t_q % _GROUP_ROWS == 0 else t_q
+
+
+def grouped_block_k(page_size: int) -> int:
+    """Cache positions a step of the grouped kernel's loop takes: a
+    divisor of the page, :data:`_TILE_ROWS` at most."""
+    bk = min(_TILE_ROWS, page_size)
+    while page_size % bk:
+        bk -= 1
+    return bk
+
+
+def reference_grouped_paged_attention(q, k_pool, v_pool, lengths,
+                                      block_table, *, window: int = 0):
+    """Gather-dense attention of ``q`` [B, T, H, D] (query ``t`` at
+    position ``lengths + t``) over pools ``[P, ps, H_kv x D]`` through
+    ``block_table``: query head ``j`` reads key/value head ``j // (H /
+    H_kv)``; with ``window``, positions ``t - window < s <= t`` only (a
+    table entry outside a slot's window may name any page). The grouped
+    kernel's oracle and the fallback off the TPU."""
+    b, t, h, d = q.shape
+    h_kv = k_pool.shape[-1] // d
+    g = h // h_kv
+    with jax.named_scope("kv_gather"):
+        take = lambda pool: pool[
+            jnp.clip(block_table, 0, pool.shape[0] - 1)
+        ].reshape(b, -1, h_kv, d)
+        k, v = take(k_pool), take(v_pool)
+    qg = q.reshape(b, t, h_kv, g, d)
+    s = jnp.einsum("btkgd,bskd->bkgts", qg, k,
+                   preferred_element_type=jnp.float32) * d ** -0.5
+    pos = lengths[:, None] + jnp.arange(t)[None, :]  # [B, T]
+    key = jnp.arange(k.shape[1])
+    vis = key[None, None, :] <= pos[:, :, None]
+    if window:
+        vis &= key[None, None, :] > pos[:, :, None] - window
+    p = jax.nn.softmax(jnp.where(vis[:, None, None], s, _NEG_INF), axis=-1)
+    o = jnp.einsum("bkgts,bskd->btkgd", p.astype(v.dtype), v,
+                   preferred_element_type=jnp.float32)
+    return o.reshape(b, t, h, d).astype(q.dtype)
+
+
+def _grouped_kernel(lengths_ref, bt_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf,
+                    v_buf, sem, *, block_k, page_size, window, t_q, head_dim,
+                    scale):
+    """One slot: for each key/value head, its group's ``M = G x t_q`` query
+    rows (row ``g x t_q + t`` is query ``t`` of the group's head ``g``;
+    rows past that are padding) against that head's lanes of the slot's
+    tiles. ``lengths_ref`` [B] and ``bt_ref`` [B, pages_per_slot] in SMEM,
+    ``q_ref`` / ``o_ref`` [1, H_kv, M, D] in VMEM, the pools in HBM, a
+    tile of ``block_k`` positions (a divisor of the page) a DMA, double
+    buffered. The loop runs from the tile of the first position any of
+    the slot's queries sees to the tile of the last."""
+    b = pl.program_id(0)
+    length = lengths_ref[b]
+    s = bt_ref.shape[1] * page_size
+    h_kv, m_rows, d = q_ref.shape[1], q_ref.shape[2], head_dim
+    n_k = jnp.clip((length + t_q + block_k - 1) // block_k, 1, s // block_k)
+    k_0 = (jnp.maximum(length - window + 1, 0) // block_k) if window else 0
+
+    def dma(hbm, buf, row, slot, ki):
+        page = bt_ref[b, (ki * block_k) // page_size]
+        src = hbm.at[page, pl.ds((ki * block_k) % page_size, block_k)]
+        return pltpu.make_async_copy(src, buf.at[slot], sem.at[row, slot])
+
+    channels = [(k_hbm, k_buf, 0), (v_hbm, v_buf, 1)]
+    for hbm, buf, row in channels:
+        dma(hbm, buf, row, 0, k_0).start()
+
+    # Row r is query r mod t_q, at position length + that.
+    row = lax.broadcasted_iota(jnp.int32, (m_rows, block_k), 0)
+    t_pos = length + (lax.rem(row, t_q) if t_q > 1 else 0)
+
+    def body(ki, carry):
+        slot = lax.rem(ki - k_0, 2)
+
+        @pl.when(ki + 1 < n_k)
+        def _prefetch():
+            for hbm, buf, row in channels:
+                dma(hbm, buf, row, 1 - slot, ki + 1).start()
+
+        for hbm, buf, row in channels:
+            dma(hbm, buf, row, slot, ki).wait()
+        k_pos = ki * block_k + lax.broadcasted_iota(
+            jnp.int32, (m_rows, block_k), 1)
+        vis = k_pos <= t_pos
+        if window:
+            vis &= k_pos > t_pos - window
+        out = []
+        for h in range(h_kv):
+            m, l, acc = carry[3 * h : 3 * h + 3]
+            k_blk = k_buf[slot, :, h * d : (h + 1) * d]
+            v_blk = v_buf[slot, :, h * d : (h + 1) * d]
+            sc = lax.dot_general(
+                q_ref[0, h], k_blk, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            sc = jnp.where(vis, sc, _NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(sc, axis=1, keepdims=True))
+            # A row whose window starts past this tile has seen nothing
+            # yet (its m is still the floor): the select keeps exp(0)
+            # out of its sums.
+            p = jnp.where(vis, jnp.exp(sc - m_new), 0.0)
+            alpha = jnp.exp(m - m_new)
+            out += [
+                m_new,
+                alpha * l + jnp.sum(p, axis=1, keepdims=True),
+                alpha * acc + lax.dot_general(
+                    p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32),
+            ]
+        return tuple(out)
+
+    init = []
+    for _ in range(h_kv):
+        init += [jnp.full((m_rows, 1), _NEG_INF, jnp.float32),
+                 jnp.zeros((m_rows, 1), jnp.float32),
+                 jnp.zeros((m_rows, d), jnp.float32)]
+    carry = lax.fori_loop(k_0, n_k, body, tuple(init))
+    for h in range(h_kv):
+        l, acc = carry[3 * h + 1 : 3 * h + 3]
+        # Every query sees its own position; the guard keeps a padding
+        # row, or a malformed call, finite.
+        o_ref[0, h] = (acc / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("window", "block_k", "interpret"))
+def _grouped_call(q, k_pool, v_pool, lengths, block_table, *, window,
+                  block_k, interpret):
+    b, t, h, d = q.shape
+    page_size, w = k_pool.shape[1], k_pool.shape[2]
+    h_kv = w // d
+    g = h // h_kv
+    # [B, T, H, D] -> [B, H_kv, G x T, D], the group's rows padded to whole
+    # sublane tiles (a decode tick's G rows to 16).
+    sub = sublane_for(q.dtype)
+    m_rows = -(-g * t // sub) * sub
+    qg = jnp.transpose(q.reshape(b, t, h_kv, g, d), (0, 2, 3, 1, 4))
+    qg = jnp.pad(qg.reshape(b, h_kv, g * t, d),
+                 ((0, 0), (0, 0), (0, m_rows - g * t), (0, 0)))
+    kern = functools.partial(
+        _grouped_kernel, block_k=block_k, page_size=page_size, window=window,
+        t_q=t, head_dim=d, scale=1.0 / (d ** 0.5))
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    rows = pl.BlockSpec((1, h_kv, m_rows, d), lambda i: (i, 0, 0, 0),
+                        memory_space=pltpu.VMEM)
+    o = pl.pallas_call(
+        kern,
+        # A decode tick's calls and a chunk's are told apart by name.
+        name="gqa_paged_decode_attn" if t == 1 else "gqa_paged_chunk_attn",
+        grid=(b,),
+        in_specs=[smem, smem, rows, hbm, hbm],
+        out_specs=rows,
+        out_shape=jax.ShapeDtypeStruct((b, h_kv, m_rows, d), q.dtype),
+        scratch_shapes=[
+            pltpu.VMEM((2, block_k, w), k_pool.dtype),
+            pltpu.VMEM((2, block_k, w), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+        ],
+        # A chunk's programs hold their queries and results double
+        # buffered and a float32 accumulator and statistics a head: 17.6
+        # MB at 72 query heads, past the 16 MB a kernel gets unasked.
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=(48 if t > 1 else 16) * 2**20),
+        interpret=bool(interpret),
+    )(jnp.asarray(lengths, jnp.int32), jnp.asarray(block_table, jnp.int32),
+      qg, k_pool, v_pool)
+    o = o[:, :, : g * t].reshape(b, h_kv, g, t, d)
+    return jnp.transpose(o, (0, 3, 1, 2, 4)).reshape(b, t, h, d)
+
+
+def grouped_paged_attention(q, k_pool, v_pool, lengths, block_table, *,
+                            window: int = 0,
+                            interpret: bool | None = None):
+    """Attention of ``q`` [B, T, H, D] (query ``t`` at position ``lengths
+    + t``, its key and value already in the pool) against one layer's
+    pools ``[P, page_size, H_kv x D]`` through ``block_table`` [B,
+    pages_per_slot]: grouped-query heads (``H`` a multiple of ``H_kv``)
+    and, with ``window``, the positions ``t - window < s <= t`` only: the
+    slot's pages before its first visible position are not read, and
+    their table entries may name any page. Returns ``[B, T, H, D]``.
+    A chunk of more than :data:`_GROUP_ROWS` rows goes to the kernel that
+    many rows a program. ``interpret`` as in
+    :func:`flash_paged_decode_attention`."""
+    if not _use_kernel(interpret):
+        return reference_grouped_paged_attention(
+            q, k_pool, v_pool, lengths, block_table, window=window)
+    b, t, h, d = q.shape
+    rows = grouped_rows(t)
+    parts = t // rows
+    lengths = jnp.asarray(lengths, jnp.int32)
+    if parts > 1:
+        q = q.reshape(b * parts, rows, h, d)
+        lengths = (lengths[:, None] + rows * jnp.arange(parts)[None, :]
+                   ).reshape(-1)
+        block_table = jnp.repeat(block_table, parts, axis=0)
+    out = _grouped_call(
+        q, k_pool, v_pool, lengths, block_table, window=int(window),
+        block_k=grouped_block_k(k_pool.shape[1]),
+        interpret=bool(interpret))
+    return out.reshape(b, t, h, d)
 
 
 def flash_paged_decode_attention(

@@ -1,10 +1,12 @@
-"""A serving expert layer: sigmoid routing, no capacity, no dropped token.
+"""A serving expert layer: sigmoid or softmax routing, no capacity, no
+dropped token.
 
 ``parallel/moe.py`` trains with a capacity: ``top_k_dispatch`` fills
 ``[S, E, C]`` slots and a token past an expert's capacity is dropped. A
 decode tick cannot drop a token, so this layer has no slots to fill:
 
-1. **route** in float32: ``s = sigmoid(x W_r)``, the ``k`` experts with
+1. **route** in float32: ``s = sigmoid(x W_r)`` (or a softmax over the
+   router's experts, ``score="softmax"``), the ``k`` experts with
    the largest ``s + b`` (``b`` a selection bias that moves the choice and
    never the weight: DeepSeek-V3's ``noaux_tc``), weights ``scale x
    s[chosen] / sum s[chosen]``;
@@ -40,14 +42,21 @@ __all__ = ["route", "gated_mlp", "expert_layer"]
 
 
 def route(x, w_router, bias, *, top_k: int, scale: float,
-          normalise: bool = True):
+          normalise: bool = True, score: str = "sigmoid"):
     """``x`` [M, D] -> chosen experts ``[M, k]`` int32 and their weights
     ``[M, k]`` float32. Scores and the choice are float32 at
     ``precision=highest``: a near tie must not depend on bf16 rounding of
-    the router's own product."""
-    s = jax.nn.sigmoid(jnp.dot(
+    the router's own product. ``score`` is ``"sigmoid"`` of each logit, or
+    ``"softmax"`` over all the router's experts."""
+    logits = jnp.dot(
         x.astype(jnp.float32), w_router.astype(jnp.float32),
-        precision=lax.Precision.HIGHEST))
+        precision=lax.Precision.HIGHEST)
+    if score == "sigmoid":
+        s = jax.nn.sigmoid(logits)
+    elif score == "softmax":
+        s = jax.nn.softmax(logits, axis=-1)
+    else:
+        raise ValueError(f"score must be 'sigmoid' or 'softmax', not {score!r}")
     _, idx = lax.top_k(s + bias.astype(jnp.float32), top_k)
     g = jnp.take_along_axis(s, idx, axis=-1)
     if normalise:
@@ -69,11 +78,12 @@ def gated_mlp(x, w_gate, w_up, w_down, out_dtype=None):
 
 def expert_layer(x, mp, *, top_k: int, scale: float, n_experts: int,
                  held=None, valid=None, normalise: bool = True,
-                 router_input=None, out_dtype=None):
+                 router_input=None, out_dtype=None, score: str = "sigmoid"):
     """The whole layer on ``x`` [M, D]. ``router_input`` [M, D] is what the
     router scores where the caller has ``x`` in more than its matrix
     products' precision (a choice between two experts all but tied should
-    not turn on the rounding of ``x`` to bf16); ``out_dtype`` is the
+    not turn on the rounding of ``x`` to bf16); ``score`` is
+    :func:`route`'s; ``out_dtype`` is the
     result's (None: ``x``'s; the sum is float32). ``mp`` holds ``router`` [D, E],
     ``bias`` [E], the held experts' ``w_gate`` / ``w_up`` [E_held, D, F]
     and ``w_down`` [E_held, F, D] in the order of ``held``, and the shared
@@ -85,7 +95,7 @@ def expert_layer(x, mp, *, top_k: int, scale: float, n_experts: int,
     with jax.named_scope("moe_route"):
         idx, gates = route(x if router_input is None else router_input,
                            mp["router"], mp["bias"], top_k=top_k,
-                           scale=scale, normalise=normalise)
+                           scale=scale, normalise=normalise, score=score)
         if valid is not None:
             idx = jnp.where(valid[:, None], idx, n_experts)  # nowhere
         counts = jnp.zeros((n_experts,), jnp.int32).at[idx.reshape(-1)].add(

@@ -121,6 +121,20 @@ raises, by name, for ``--mesh``, ``--kv-dtype int8``, ``--weights-dtype
 int8``, ``--spec-k``, ``--kv-host-pages``, a ``--policy`` that preempts,
 ``--fleet`` shipment, ``--ckpt``; a repeated prompt is computed whole.
 
+``--family laguna`` (ISSUE 46) serves the fifth: grouped-query heads
+whose count differs by layer (48 in a full layer, 72 in a window layer,
+over 8 cached heads), window layers whose pages keep the last
+``sliding_window`` positions beside full layers whose pages keep them
+all, in one allocator with a block table a lifetime (``models/laguna.py``,
+``serve/kvcache.py``); a sigmoid gate a head on the attention output;
+softmax-routed experts with a shared one. ``--model tiny`` or
+``published``, or ``--model-config FILE`` (a file cut to one chip's share
+gives the experts held under ``num_experts`` and the router's width under
+``published``). It raises, by name, for ``--mesh``, ``--kv-dtype int8``,
+``--weights-dtype int8``, ``--spec-k``, ``--kv-host-pages``, a
+``--policy`` that preempts, ``--fleet`` shipment, ``--ckpt``; a repeated
+prompt is computed whole (a window layer's pages of the prefix are gone).
+
 Config follows the ``asyncsgd.config`` pattern: one dataclass, argparse
 generated from its fields.
 """
@@ -142,7 +156,7 @@ class ServeConfig:
     """Options for the serving CLI (the ``opt`` table analogue)."""
 
     ckpt: str = ""  # dense .npz from --save-dense ("" = random init)
-    family: str = "gpt2"  # gpt2 | xing4 | olmo_hybrid | glm_dsa
+    family: str = "gpt2"  # gpt2 | xing4 | olmo_hybrid | glm_dsa | laguna
     model: str = "tiny"  # random-init size: tiny | small (families: published)
     # xing4: a JSON file with the keys of the published config.json (as
     # benchmark/configs/xing4-29b-a4b-6of40.json holds them); "" = --model.
@@ -260,6 +274,7 @@ _FAMILIES = {
     "xing4": ("xing4", "Xing4Config"),
     "olmo_hybrid": ("olmo_hybrid", "OlmoHybridConfig"),
     "glm_dsa": ("glm_dsa", "GlmDsaConfig"),
+    "laguna": ("laguna", "LagunaConfig"),
 }
 
 

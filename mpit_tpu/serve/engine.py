@@ -111,6 +111,7 @@ from mpit_tpu.serve.kvcache import (
     QuantizedKV,
     alloc_paged_cache,
     paged_cache_specs,
+    window_slot_pages,
 )
 from mpit_tpu.serve.weights import (
     params_wire_bytes,
@@ -892,16 +893,28 @@ class Engine:
         # index, COW reservations, per-slot block tables (the tables
         # ride into every jitted step as a tiny int32 argument).
         layout = model.cache_layout()
+        # The pool of the layers that keep a window of positions (none
+        # where every page layer keeps them all): what every slot can
+        # hold there at once, whatever max_len is.
+        per_slot = window_slot_pages(
+            layout.window, self.prefill_chunk, self.page_size
+        )
+        self.window_pages = slots * per_slot
         self.allocator = PageAllocator(
             self.num_pages, self.page_size, self.pages_per_slot, slots,
             host_pages=self.host_pages,
             prefix_shareable=layout.prefix_shareable,
+            window=layout.window, window_pages=self.window_pages,
+            window_slot_pages=per_slot,
         )
+        # Columns of the block tables a step is handed: a table a
+        # lifetime, side by side.
+        self._table_cols = self.allocator.block_tables.shape[1]
         with _startup.span("cache_alloc") as alloc:
             self.cache = alloc_paged_cache(
                 model, slots, self.num_pages, self.page_size,
                 sharding=sharding, dtype=self._cache_dtype,
-                quantized=self.kv_quantized,
+                quantized=self.kv_quantized, window_pages=self.window_pages,
             )
             # pages, state, third seats
             alloc.set(bytes=sum(
@@ -1073,12 +1086,23 @@ class Engine:
                 self.page_size, kv_item, self.kv_quantized
             ) if self.spec_k else 0
         )
-        assert self.page_bytes * self.num_pages == kv_buf + draft_kv
+        # The same of the window layers' pool (0 where there is none).
+        self.window_page_bytes = layout.page_bytes(
+            self.page_size, kv_item, self.kv_quantized, window=True
+        )
+        window_buf = self.window_page_bytes * self.window_pages
+        assert (self.page_bytes * self.num_pages + window_buf
+                == kv_buf + draft_kv)
         self.memledger.register(
             "kv_pages",
             capacity_bytes=self.num_pages * self.page_bytes,
             nested_in="kv_pool",
         )
+        if window_buf:
+            self.memledger.register(
+                "kv_window_pages", capacity_bytes=window_buf,
+                nested_in="kv_pool",
+            )
         self.memledger.register("kv_cow_reserve", nested_in="kv_pool")
         # A slot's seat in the state pool, held from admission to
         # release as its pages are.
@@ -1090,6 +1114,7 @@ class Engine:
             )
         self.allocator.memledger = self.memledger
         self.allocator.page_bytes = self.page_bytes
+        self.allocator.window_page_bytes = self.window_page_bytes
         self.allocator.slot_state_bytes = self.slot_state_bytes
         if self.host_pages:
             # ISSUE 20: the host-RAM page store. Charged at spill
@@ -1253,13 +1278,13 @@ class Engine:
         ``floor`` and ``sample_mask``, and the block tables. The count of
         participants is the vector's length."""
         w = self.prefill_chunk
-        tables = self.slots * self.pages_per_slot
+        tables = self.slots * self._table_cols
         rows = packed[: packed.shape[0] - tables].reshape(-1, w + 5)
         return (
             rows[:, w], rows[:, :w], rows[:, w + 1], rows[:, w + 2],
             rows[:, w + 3], rows[:, w + 4] != 0,
             packed[packed.shape[0] - tables :].reshape(
-                self.slots, self.pages_per_slot
+                self.slots, self._table_cols
             ),
         )
 
@@ -2129,7 +2154,7 @@ class Engine:
             [self.draft_params, self.draft_cache] if self.spec_k else []
         )
         toks = jnp.zeros((s, self.prefill_chunk), jnp.int32)
-        bt = jnp.zeros((s, self.pages_per_slot), jnp.int32)
+        bt = jnp.zeros((s, self._table_cols), jnp.int32)
         steps = {
             "prefill": (
                 self._prefill_paged_jit,

@@ -58,6 +58,21 @@ model finds a registered prefix, counts it (``prefix_hits_passed_up``)
 and maps nothing shared: pages without the state at that boundary would
 serve wrong tokens with no error.
 
+TWO LIFETIMES (ISSUE 46). A page layer keeps every position of a
+sequence, or the last ``window`` of them
+(:class:`~mpit_tpu.models.serving.PageLayer`). The window layers have a
+pool of their own, sized to what the slots can hold there at once
+(:func:`window_slot_pages` a slot: ``window + prefill_chunk`` positions
+and a page), and a block table of their own, which rides beside the
+other one in the columns past ``pages_per_slot`` of the allocator's
+``block_tables`` (``window_tables`` is that view): the same page index
+means the same positions in both. A window page is mapped just before the
+step that writes it and goes back to the window pool once every later
+query's window has passed it (:meth:`PageAllocator.advance_window`);
+admission promises a slot its most and refuses a request the window pool
+could not serve. Such a layout maps no shared prefix: a window layer's
+pages of the prefix are gone.
+
 HOST TIER (ISSUE 20). HBM pages are the scarce resource; host RAM is
 the next 10×. ``host_pages > 0`` gives the allocator a second page
 namespace — host page ids are bookkeeping handles whose PAYLOADS live
@@ -98,6 +113,7 @@ __all__ = [
     "PageAllocator",
     "AdmitPlan",
     "pages_needed",
+    "window_slot_pages",
     "QuantizedKV",
     "kv_wire_bytes_per_row",
 ]
@@ -163,7 +179,8 @@ class PagedKVCache:
 
     @property
     def num_pages(self) -> int:
-        return self.k[0].shape[0]
+        """Pages of the pool of the layers that keep every position."""
+        return max(jax.tree.leaves(k)[0].shape[0] for k in self.k)
 
     @property
     def page_size(self) -> int:
@@ -183,6 +200,7 @@ def alloc_paged_cache(
     dtype=None,
     sharding=None,
     quantized: bool = False,
+    window_pages: int = 0,
 ) -> PagedKVCache:
     """Allocate the zeroed page pool: per layer one K and one V buffer
     ``[num_pages, page_size, heads*head_dim]`` (and a third seat where
@@ -194,19 +212,21 @@ def alloc_paged_cache(
     int8 pages + per-(row, head) scale planes ``[num_pages, page_size,
     heads]`` — a page costs ``page_size × kv_wire_bytes_per_row(H, Dh,
     "int8")`` bytes, so the same budget holds ~2× the pages of a bf16
-    pool."""
+    pool. A layer that keeps a window of positions gets ``window_pages``
+    pages instead: the window layers' pool."""
     # The row a layer caches is the model's to say: GPT-2's K and V of
     # ``heads*head_dim`` each, a latent-attention model's shared latent
     # and its key's rotary part (``models.serving.CacheLayout``).
     layout = as_serve_model(cfg).cache_layout()
     dt = dtype or layout.dtype
     kw = {"device": sharding} if sharding is not None else {}
-    seat = lambda width: _alloc_kv(
-        (num_pages, page_size, width), dt, quantized, kw, layout.scale_width)
+    seat = lambda l, width: _alloc_kv(
+        (window_pages if l.window else num_pages, page_size, width), dt,
+        quantized, kw, layout.scale_width)
     return PagedKVCache(
-        k=tuple(seat(l.k_width) for l in layout.page_layers),
-        v=tuple(seat(l.v_width) for l in layout.page_layers),
-        x=tuple(seat(l.x_width) if l.x_width else None
+        k=tuple(seat(l, l.k_width) for l in layout.page_layers),
+        v=tuple(seat(l, l.v_width) for l in layout.page_layers),
+        x=tuple(seat(l, l.x_width) if l.x_width else None
                 for l in layout.page_layers) if layout.third_seats else (),
         lengths=jnp.zeros((slots,), jnp.int32),
         # A recurrent layer's seats, a slot each: zeros, though a slot's
@@ -245,6 +265,14 @@ def pages_needed(prompt_len: int, max_new_tokens: int, page_size: int) -> int:
     is ``prompt_len + max_new_tokens - 2`` and the fill watermark is
     ``prompt_len + max_new_tokens - 1``."""
     return -(-(prompt_len + max_new_tokens - 1) // page_size)
+
+
+def window_slot_pages(window: int, chunk: int, page_size: int) -> int:
+    """The most pages one slot holds in a window layer: a step of up to
+    ``chunk`` rows reads from ``window - 1`` positions before its first
+    row to its last, ``window + chunk - 1`` positions that start anywhere
+    in a page."""
+    return -(-(window + chunk) // page_size) + 1 if window else 0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -338,7 +366,9 @@ class PageAllocator:
 
     def __init__(self, num_pages: int, page_size: int,
                  pages_per_slot: int, slots: int, *,
-                 host_pages: int = 0, prefix_shareable: bool = True):
+                 host_pages: int = 0, prefix_shareable: bool = True,
+                 window: int = 0, window_pages: int = 0,
+                 window_slot_pages: int = 0):
         if num_pages < 1:
             raise ValueError(f"num_pages must be >= 1, got {num_pages}")
         if host_pages < 0:
@@ -353,7 +383,19 @@ class PageAllocator:
         self.page_size = page_size
         self.pages_per_slot = pages_per_slot
         self.slots = slots
-        self.block_tables = np.zeros((slots, pages_per_slot), np.int32)
+        # The second lifetime (0: none): layers that keep the last
+        # ``window`` positions, ``window_pages`` pages in a pool of their
+        # own, ``window_slot_pages`` of them a slot at the most. Their
+        # table is the columns past ``pages_per_slot`` of the one array
+        # the steps are handed.
+        self.window = window
+        self.window_pages = window_pages if window else 0
+        self.window_slot_pages = window_slot_pages if window else 0
+        self.block_tables = np.zeros(
+            (slots, pages_per_slot * (2 if window else 1)), np.int32)
+        self.window_tables = self.block_tables[:, pages_per_slot:]
+        self.window_page_bytes = 0.0
+        self.window_free: list[int] = list(range(self.window_pages))[::-1]
         # ISSUE 18: the engine binds its MemLedger + the wire bytes one
         # page occupies (all layers, K+V, target + draft pool) after
         # construction; every PHYSICAL page transition below then emits
@@ -386,6 +428,16 @@ class PageAllocator:
                 self.memledger.free(
                     "kv_state", seated * self.slot_state_bytes, kind="reset"
                 )
+        if self.memledger is not None and self.window_pages_in_use:
+            self.memledger.free(
+                "kv_window_pages",
+                self.window_pages_in_use * self.window_page_bytes,
+                kind="reset",
+            )
+        self.window_free = list(range(self.window_pages))[::-1]
+        self._slot_window: dict[int, dict[int, int]] = {}  # index -> page
+        self._window_promised: dict[int, int] = {}  # slot -> its most
+        self.window_pages_returned = 0  # behind the window, since reset
         self.block_tables[:] = 0
         self.refcount = np.zeros(self.num_pages, np.int64)
         self.free: list[int] = list(range(self.num_pages))[::-1]  # pop()=0 first
@@ -459,6 +511,65 @@ class PageAllocator:
     def pages_for(self, prompt_len: int, max_new_tokens: int) -> int:
         return pages_needed(prompt_len, max_new_tokens, self.page_size)
 
+    # -- the window layers' pool ----------------------------------------------
+    @property
+    def window_pages_in_use(self) -> int:
+        return self.window_pages - len(self.window_free)
+
+    @property
+    def window_occupancy(self) -> float:
+        return (self.window_pages_in_use / self.window_pages
+                if self.window_pages else 0.0)
+
+    @property
+    def window_free_pages(self) -> int:
+        """Window pages admittable RIGHT NOW: free, less what the slots
+        already admitted may still take of their promise."""
+        owed = sum(n - len(self._slot_window[s])
+                   for s, n in self._window_promised.items())
+        return len(self.window_free) - owed
+
+    def window_pages_for(self, prompt_len: int, max_new_tokens: int) -> int:
+        """The most pages a request holds in the window pool at once."""
+        return min(self.window_slot_pages,
+                   self.pages_for(prompt_len, max_new_tokens))
+
+    def advance_window(self, slot: int, start: int, end: int) -> int:
+        """Before a step of ``slot`` whose rows are positions ``start ..
+        end - 1``: its first row reads back to ``first = start - window +
+        1``, so map a window page for every page index from there to the
+        last row's that has none, and give back the pages wholly before
+        ``first`` (no later query's window reaches them: positions only
+        grow). Returns the pages given back; 0 where no layer keeps a
+        window. The promise of admission covers what is taken."""
+        if not self.window:
+            return 0
+        ps, held = self.page_size, self._slot_window[slot]
+        lo, hi = max(0, start - self.window + 1) // ps, (end - 1) // ps
+        gone = [i for i in held if i < lo]
+        for i in gone:
+            self.window_free.append(held.pop(i))
+            self.window_tables[slot, i] = 0
+        took = [i for i in range(lo, hi + 1) if i not in held]
+        if len(held) + len(took) > self._window_promised[slot]:
+            raise RuntimeError(
+                f"slot {slot} would hold {len(held) + len(took)} window "
+                f"pages, promised {self._window_promised[slot]} "
+                "(a step longer than the chunk the pool was sized for)")
+        for i in took:
+            held[i] = self.window_tables[slot, i] = self.window_free.pop()
+        self.window_pages_returned += len(gone)
+        if self.memledger is not None and len(took) != len(gone):
+            owner, tenant = self._slot_owner.get(slot, (None, None))
+            delta = (len(took) - len(gone)) * self.window_page_bytes
+            if delta > 0:
+                self.memledger.grant("kv_window_pages", delta, owner=owner,
+                                     tenant=tenant, kind="window")
+            else:
+                self.memledger.free("kv_window_pages", -delta, owner=owner,
+                                    kind="window")
+        return len(gone)
+
     # -- admission ----------------------------------------------------------
     def _find_shared_prefix(self, prompt: tuple):
         """Longest registered prefix of ``prompt``, every length probed
@@ -518,6 +629,14 @@ class PageAllocator:
         # copy) — or nothing: no partial allocation.
         if self.free_pages < own_needed + (1 if partial_shared else 0):
             return None
+        if self.window:
+            # Both lifetimes or nothing: the window pool must be able to
+            # serve this slot's most beside what it has promised already.
+            need_window = self.window_pages_for(len(prompt), max_new_tokens)
+            if self.window_free_pages < need_window:
+                return None
+            self._window_promised[slot] = need_window
+            self._slot_window[slot] = {}
         fresh = [self.free.pop() for _ in range(own_needed)]
         for p in fresh:
             self.refcount[p] = 1
@@ -862,6 +981,15 @@ class PageAllocator:
         advertised K/V is about to be recycled)."""
         owner, _ = self._slot_owner.pop(slot, (None, None))
         released = 0
+        if self.window and slot in self._slot_window:
+            back = list(self._slot_window.pop(slot).values())
+            del self._window_promised[slot]
+            self.window_free.extend(back)
+            if self.memledger is not None and back:
+                self.memledger.free(
+                    "kv_window_pages", len(back) * self.window_page_bytes,
+                    owner=owner, kind="free_slot",
+                )
         if (self.memledger is not None and self.slot_state_bytes
                 and slot in self._slot_pages):
             self.memledger.free(

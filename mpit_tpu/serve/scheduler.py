@@ -487,6 +487,8 @@ class Server:
         self._kv_occ_sum = 0.0
         self._kv_occ_peak = 0.0
         self._pages_shared_peak = 0
+        # The window pool's fullest (an engine whose layout has a window).
+        self._window_occ_peak = 0.0
         self._concurrency_peak = 0
         self._truncated = False  # a run stopped with work still pending
         self._pool_exhausted = False  # edge-trigger for the obs instant
@@ -1140,6 +1142,16 @@ class Server:
         for step in landed:
             self._settle_prefill(step)
 
+    def _window_advance(self, slot: int, start: int, end: int) -> None:
+        """Before a step of ``slot`` whose rows are positions ``start ..
+        end - 1``: the window layers' pages they reach are mapped, those
+        behind their window go back to the window pool
+        (``PageAllocator.advance_window``; nothing where no layer keeps a
+        window), and what came back is counted."""
+        back = self.engine.allocator.advance_window(slot, start, end)
+        if back:
+            obs.counter("kv_window_pages_returned", float(back))
+
     def _stage_chunk(self):
         """The tick's SEATS as the step's host arrays (a row a seat:
         ``seats[i]`` is the slot whose chunk row ``i`` holds), the page
@@ -1198,6 +1210,7 @@ class Server:
                             live.req.rid, "cow_copy", tick=self.tick,
                             src=pair[0], dst=pair[1], phase="prefill",
                         )
+            self._window_advance(slot, start, start + n)
             tokens[i, :n] = p[start : start + n]
             seats[i], base[i], chunk_lens[i] = slot, start, n
             floor[i] = live.floor
@@ -1580,9 +1593,9 @@ class Server:
             # this is a no-op refcount probe.
             for slot, live in decoding:
                 active[slot] = True
-                pair = self.engine.allocator.cow_before_write(
-                    slot, live.cache_fill()
-                )
+                fill = live.cache_fill()
+                self._window_advance(slot, fill, fill + 1)
+                pair = self.engine.allocator.cow_before_write(slot, fill)
                 if pair is not None:
                     self.engine.copy_page(*pair)
                     if self._ledger is not None:
@@ -1741,6 +1754,11 @@ class Server:
         self._kv_occ_peak = max(self._kv_occ_peak, occ)
         self._pages_shared_peak = max(self._pages_shared_peak, shared)
         obs.gauge("kv_pool_occupancy", occ)
+        if alloc.window:
+            # The window layers' pool as this tick's steps leave it.
+            obs.gauge("kv_window_pool_occupancy", alloc.window_occupancy)
+            self._window_occ_peak = max(
+                self._window_occ_peak, alloc.window_occupancy)
         obs.gauge("prefix_pages_shared", float(shared))
         if self.stream is not None:
             self.stream.set_gauge("kv_pool_occupancy", occ)
@@ -1771,6 +1789,8 @@ class Server:
         kv_held = ml.held("kv_pages") + ml.held("kv_cow_reserve")
         if self.engine.slot_state_bytes:  # the live slots' seats
             kv_held += ml.held("kv_state")
+        if self.engine.allocator.window:  # the window layers' pages
+            kv_held += ml.held("kv_window_pages")
         gauges["kv_held_bytes"] = float(kv_held)
         if "kv_headroom_pct" in head:
             pct = head["kv_headroom_pct"]
@@ -2357,6 +2377,12 @@ class Server:
         )
         if not alloc.prefix_shareable:
             out["prefix_hits_passed_up"] = alloc.prefix_hits_passed_up
+        if alloc.window:
+            out.update(
+                kv_window_pool_pages=alloc.window_pages,
+                kv_window_occupancy_peak=round(self._window_occ_peak, 4),
+                kv_window_pages_returned=alloc.window_pages_returned,
+            )
         if self._host_tier:
             # Host-tier roll-up (ISSUE 20): tier occupancy plus the
             # spill/restream traffic and where prefix hits landed.

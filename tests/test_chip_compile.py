@@ -850,3 +850,136 @@ def test_glm_dsa_paged_steps_fit_and_update_in_place(glm_dsa_engine, step):
         # A tick's temporaries are O(rows): far under the smallest buffer.
         assert mem.temp_size_in_bytes < min(
             l.size * l.dtype.itemsize for l in pool)
+
+
+# Laguna-S-2.1 as benchmark/configs/laguna-s-2.1-5of48-ep4.json serves it:
+# slots, positions, page, chunk and the sampler's tile are READ from that
+# file, so a change there is compiled here. 1 dense + 4 expert layers, 64 of
+# 256 experts and 25,088 rows of the vocabulary held; two full layers whose
+# pages keep every position and three window layers whose pool is slots x 5
+# pages whatever the positions.
+LG_LIMIT = 15.75e9  # what loads on the chip: arguments + temporaries
+
+
+def _lg_file():
+    import json
+    import os
+
+    with open(os.path.join(
+            os.path.dirname(__file__), "..", "benchmark", "configs",
+            "laguna-s-2.1-5of48-ep4.json")) as f:
+        return json.load(f)
+
+
+@pytest.mark.parametrize("heads,window,t", [
+    (48, 0, 1), (72, 512, 1), (48, 0, 512), (72, 512, 512)],
+    ids=["full-tick", "window-tick", "full-chunk", "window-chunk"])
+def test_grouped_attention_kernel(v5e, heads, window, t):
+    """The grouped kernel at the cell's shapes: groups of 6 and of 9 query
+    heads over 8 cached heads of 128, a tick's row a slot and a chunk's 512
+    rows, a window layer's first position."""
+    from mpit_tpu.ops.decode_attention import grouped_paged_attention
+
+    serve = _lg_file()["serve"]
+    page = serve["kv_page_size"]
+    pps = serve["slot_positions"] // page
+    b = serve["slots"] if t == 1 else 4
+    pages = b * (5 if window else pps)
+    text = _compile_on_chip(
+        v5e,
+        lambda q, k, v, lens, bt: grouped_paged_attention(
+            q, k, v, lens, bt, window=window, interpret=False),
+        _sds((b, t, heads, 128), jnp.bfloat16),
+        _sds((pages, page, 1024), jnp.bfloat16),
+        _sds((pages, page, 1024), jnp.bfloat16),
+        _sds((b,), jnp.int32), _sds((b, pps), jnp.int32),
+    )
+    assert ("gqa_paged_decode_attn" if t == 1
+            else "gqa_paged_chunk_attn") in text
+
+
+@pytest.fixture(scope="module")
+def laguna_engine(v5e):
+    """The configuration's engine on shapes alone (6.0 GB of parameters are
+    never made) and a two-slot pool of the full layers; the steps are then
+    lowered for the pool of all the slots."""
+    from mpit_tpu.models.laguna import LagunaConfig, init_params
+    from mpit_tpu.ops import decode_attention
+    from mpit_tpu.serve import Engine
+
+    model = _lg_file()
+    serve = model["serve"]
+    cfg = LagunaConfig.from_dict(model, max_seq_len=serve["slot_positions"])
+    params = jax.eval_shape(lambda: init_params(cfg, jax.random.key(0)))
+    pps = serve["slot_positions"] // serve["kv_page_size"]
+    was = decode_attention._use_kernel
+    decode_attention._use_kernel = lambda interpret: True
+    eng = Engine(cfg, params, slots=serve["slots"],
+                 max_len=serve["slot_positions"], kv_pages=2 * pps,
+                 kv_page_size=serve["kv_page_size"],
+                 prefill_chunk=serve["prefill_chunk"],
+                 sample_block=serve["sample_block"])
+    one = SingleDeviceSharding(v5e.devices[0])
+    yield eng, lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one), tree)
+    decode_attention._use_kernel = was
+
+
+@pytest.mark.parametrize("step", ["decode", "prefill"])
+def test_laguna_paged_steps_fit_and_update_in_place(laguna_engine, step):
+    """Both steps of the cell at the file's slots and positions: every
+    buffer of both pools aliased to an output; the grouped kernel in the
+    text; the window layers' pool slots x (window + chunk + page) positions
+    (one lifetime for all five layers would be 14.1 GB of pages); arguments
+    + temporaries under the 15.75 GB that load (the chunk step is the one
+    of four slots' 512 rows, 2,048 in all)."""
+    import dataclasses
+
+    eng, on_chip = laguna_engine
+    serve = _lg_file()["serve"]
+    slots, chunk = serve["slots"], serve["prefill_chunk"]
+    assert eng._prefill_counts[-1] * chunk == 2048
+    two = 2 * eng.pages_per_slot  # the fixture's pool of the full layers
+    pages = slots * eng.pages_per_slot
+    full = lambda bufs: tuple(
+        jax.ShapeDtypeStruct(
+            (pages if b.shape[0] == two else b.shape[0], *b.shape[1:]),
+            b.dtype) for b in bufs)
+    cache = dataclasses.replace(
+        eng.cache, k=full(eng.cache.k), v=full(eng.cache.v))
+    window_rows = 512 + chunk + serve["kv_page_size"]
+    assert [b.shape[0] * b.shape[1] for b in cache.k] == [
+        slots * serve["slot_positions"], *[slots * window_rows] * 3,
+        slots * serve["slot_positions"]]
+    i32, f32 = jnp.zeros((slots,), jnp.int32), jnp.zeros((slots,), jnp.float32)
+    bt = jnp.zeros((slots, 2 * eng.pages_per_slot), jnp.int32)
+    key = jax.random.key(0)
+    if step == "decode":
+        jit, args = eng._decode_paged_jit, (
+            eng.params, cache, eng.last_token, jnp.zeros((slots,), bool), bt,
+            key, f32, i32)
+        kernels = ("gqa_paged_decode_attn",)
+    else:
+        n = eng._prefill_counts[-1]  # the largest step a tick can meet
+        z = jnp.zeros((n,), jnp.int32)
+        jit, args = eng._prefill_compact_jit, (
+            eng.params, cache, eng.last_token, z,
+            jnp.zeros((n, chunk), jnp.int32), z, z, z,
+            jnp.zeros((n,), bool), bt, key, f32, i32)
+        kernels = ("gqa_paged_chunk_attn", "paged_kv_write")
+    compiled = jit.lower(*on_chip(args)).compile()
+    text = compiled.as_text()
+    for name in kernels:
+        assert name in text, name
+    mem = compiled.memory_analysis()
+    pool = jax.tree.leaves((cache.k, cache.v))
+    pool_bytes = sum(l.size * l.dtype.itemsize for l in pool)
+    assert pool_bytes == slots * 4096 * (
+        2 * serve["slot_positions"] + 3 * window_rows)
+    assert mem.alias_size_in_bytes >= pool_bytes
+    held = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    print(f"laguna {step}: arguments {mem.argument_size_in_bytes / 1e9:.2f} "
+          f"GB, temporaries {mem.temp_size_in_bytes / 1e9:.2f} GB, held "
+          f"{held / 1e9:.2f} GB")
+    assert held < LG_LIMIT, held
