@@ -43,6 +43,13 @@ raises — there is no handler that lets the run end 0):
                  and a chunk's rows), the choice by bisection against
                  ``lax.top_k`` as sets, the chosen rows' attention against
                  a masked dense one; each call timed.
+- ``mla_chunk_kernel`` a chunk's expanded latent attention
+                 (``ops/mla_attention.py``, kernel ``mla_paged_chunk_attn``)
+                 alone against its lax twin at the shapes of the xing4 cell
+                 (1 x 2,048 rows, 32 heads of 192 / 128, prefixes 0, 4,096
+                 and 10,240) and of the glm52 cell (4 x 512 rows, 64 heads
+                 of 256 / 256, a prefix of 30k, a choice of 2,048 of the
+                 visible); each call timed.
 - ``chunk_rows`` GPT-2 large at the serving cells' shape (16 slots of 1,024
                  positions, chunks of 64): the compacted chunk step timed
                  alone at 16 to 1,024 rows, against the full-batch step,
@@ -99,6 +106,9 @@ TOL_GDN_KERNEL_VS_TWIN = 0.02
 # masked dense one: weighted latents of order 0.1-1 in bf16.
 TOL_DSA_SCORES = 0.05
 TOL_DSA_ATTN = 0.03
+# The chunk's latent attention kernel against its lax twin: outputs of
+# order 0.1-1 in bf16, the same products in another order.
+TOL_MLA_CHUNK = 0.03
 
 FULL = dict(
     model=["--num-layers", "12", "--d-model", "768", "--num-heads", "12",
@@ -125,6 +135,14 @@ FULL = dict(
     # attention's heads against a latent of 512 and a rotary key of 64.
     dsa=dict(slots=16, chunk=512, positions=36864, page=256, hi=32, di=128,
              topk=2048, heads=64, latent=512, rope=64, interpret=None),
+    # The two latent cells' chunk steps: participants x rows, heads and
+    # their widths, a slot's positions, the prefixes timed, the choice.
+    mla_chunk=dict(
+        latent=512, page=256, interpret=None,
+        xing4=dict(b=1, t=2048, h=32, dn=128, dr=64, dv=128,
+                   positions=13312, prefixes=(0, 4096, 10240), topk=0),
+        glm52=dict(b=4, t=512, h=64, dn=192, dr=64, dv=256,
+                   positions=36864, prefixes=(30000,), topk=2048)),
     # The GPT-2 large cells' shape, and the (participants, chunk width)
     # pairs whose compacted step is timed: 16 to 1,024 rows.
     chunk_rows=dict(layers=36, heads=20, d_model=1280, vocab=50257,
@@ -150,6 +168,12 @@ TINY = dict(
              page=16, interpret=True),
     dsa=dict(slots=3, chunk=16, positions=128, page=16, hi=4, di=128,
              topk=8, heads=4, latent=128, rope=16, interpret=True),
+    mla_chunk=dict(
+        latent=128, page=16, interpret=True,
+        xing4=dict(b=1, t=32, h=2, dn=128, dr=64, dv=128, positions=128,
+                   prefixes=(0, 40), topk=0),
+        glm52=dict(b=3, t=32, h=2, dn=192, dr=64, dv=256, positions=128,
+                   prefixes=(70,), topk=8)),
     chunk_rows=dict(layers=2, heads=4, d_model=64, vocab=512, slots=4,
                     positions=128, page=16, chunk=16, prefix=32,
                     steps=((1, 8), (1, 16), (2, 16), (4, 16)), reps=2),
@@ -899,6 +923,63 @@ def phase_dsa_kernels(sz, seed: int, rehearse: bool) -> None:
     assert out["sparse_attn_err"] <= TOL_DSA_ATTN, out["sparse_attn_err"]
 
 
+def phase_mla_chunk_kernel(sz, seed: int, rehearse: bool) -> None:
+    """A chunk's expanded latent attention alone at the two latent cells'
+    shapes: the kernel against its lax twin, each call timed."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from mpit_tpu.ops import mla_attention as mla
+
+    g = sz["mla_chunk"]
+    ps, interp = g["page"], g["interpret"]
+    dt = jnp.float32 if rehearse else jnp.bfloat16
+    out = {}
+    for name in ("xing4", "glm52"):
+        c = g[name]
+        b, t, h, topk = c["b"], c["t"], c["h"], c["topk"]
+        pps = c["positions"] // ps
+        ks = jax.random.split(jax.random.fold_in(jax.random.key(seed), h), 6)
+        qn = jax.random.normal(ks[0], (b, t, h, c["dn"]), dt)
+        qr = jax.random.normal(ks[1], (b, t, h, c["dr"]), dt)
+        ckv = jax.random.normal(ks[2], (b * pps, ps, g["latent"]), dt)
+        kr = jnp.pad(
+            jax.random.normal(ks[3], (b * pps, ps, c["dr"]), dt),
+            ((0, 0), (0, 0), (0, mla.lane_pad(c["dr"]) - c["dr"])))
+        w = 0.05 * jax.random.normal(
+            ks[4], (g["latent"], h, c["dn"] + c["dv"]), dt)
+        table = jnp.asarray(np.random.RandomState(seed).permutation(
+            b * pps).reshape(b, pps), jnp.int32)
+        scale = (c["dn"] + c["dr"]) ** -0.5
+        kern = jax.jit(lambda select, *a: mla.mla_paged_prefill_attention(
+            *a, scale=scale, select=select, interpret=interp))
+        twin = jax.jit(
+            lambda select, *a: mla.reference_mla_paged_prefill_attention(
+                *a, scale=scale, select=select))
+        for prefix in c["prefixes"]:
+            # Participants a page apart, as slots that refill together are.
+            lengths = jnp.maximum(
+                prefix - ps * jnp.arange(b, dtype=jnp.int32), 0)
+            select = None
+            if topk:  # about topk of each row's visible positions
+                pos = lengths[:, None] + jnp.arange(t)[None, :]
+                u = jax.random.uniform(ks[5], (b, t, pps * ps))
+                select = u * (pos[:, :, None] + 1) < topk
+            args = (select, qn, qr, ckv, kr, lengths, table, w)
+            got, want = kern(*args), twin(*args)
+            assert bool(jnp.all(jnp.isfinite(got.astype(jnp.float32)))), name
+            tag = f"{name}_prefix{prefix}"
+            out[f"{tag}_err"] = _max_abs(got, want)
+            del got, want
+            out[f"{tag}_kernel_ms"] = _median_ms(kern, *args)
+            out[f"{tag}_twin_ms"] = _median_ms(twin, *args)
+    emit("mla_chunk_kernel", **out, shapes=g, tolerance=TOL_MLA_CHUNK)
+    for name, err in out.items():
+        if name.endswith("_err"):
+            assert err <= TOL_MLA_CHUNK, (name, err)
+
+
 def phase_chunk_rows(sz, seed: int, rehearse: bool) -> None:
     """What a chunk step costs by its rows, on GPT-2 large at the serving
     cells' shape: the compacted step alone (its arguments on the device,
@@ -1173,7 +1254,7 @@ def main(argv=None) -> int:
     parser.add_argument("--rehearse", action="store_true")
     # One-chip phases to run, by name (all: every one, in this order).
     one_chip = ("train", "serve", "serve_xing4", "gdn_kernels", "dsa_kernels",
-                "chunk_rows")
+                "mla_chunk_kernel", "chunk_rows")
     parser.add_argument("--phases", default="all",
                         help="comma list of " + ", ".join(one_chip))
     args = parser.parse_args(argv)
@@ -1205,6 +1286,8 @@ def main(argv=None) -> int:
             phase_gdn_kernels(sz, args.seed, args.rehearse)
         if "dsa_kernels" in phases:
             phase_dsa_kernels(sz, args.seed, args.rehearse)
+        if "mla_chunk_kernel" in phases:
+            phase_mla_chunk_kernel(sz, args.seed, args.rehearse)
         if "chunk_rows" in phases:
             phase_chunk_rows(sz, args.seed, args.rehearse)
         ok = True

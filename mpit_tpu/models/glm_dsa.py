@@ -398,8 +398,20 @@ class GlmDsaServeModel(ServeModel):
         model.decode_block_k = mla.pick_mla_block_k(page_size)
         return model
 
+    def attention_tiling(self, t_q, *, page_size, kv_dtype, tp=1):
+        del kv_dtype, tp
+        return mla.latent_attention_tiling(
+            t_q, page_size, self.cfg.qk_nope_head_dim,
+            self.cfg.qk_rope_head_dim)
+
     def head_table(self, params):
         return params["head"]
+
+    def _attend_chunk(self, *args, **kw):
+        if self._kernel:
+            return mla.mla_paged_prefill_attention(
+                *args, interpret=self._interpret, **kw)
+        return mla.reference_mla_paged_prefill_attention(*args, **kw)
 
     def _scores(self, q, w, key_pool, lengths, block_tables):
         if self._kernel:
@@ -492,7 +504,7 @@ class GlmDsaServeModel(ServeModel):
                                             block_tables, *chosen),
                         cfg)[:, None]
                 else:
-                    o = mla.mla_paged_prefill_attention(
+                    o = self._attend_chunk(
                         qn, qr, ckv_pool, kr_pool, lengths, block_tables,
                         _w_ukv(ap, cfg), scale=cfg.softmax_scale,
                         select=chosen).reshape(b, t, -1)

@@ -536,6 +536,12 @@ class Xing4ServeModel(ServeModel):
         model.decode_block_k = mla.pick_mla_block_k(page_size)
         return model
 
+    def attention_tiling(self, t_q, *, page_size, kv_dtype, tp=1):
+        del kv_dtype, tp
+        return mla.latent_attention_tiling(
+            t_q, page_size, self.cfg.qk_nope_head_dim,
+            self.cfg.qk_rope_head_dim)
+
     def head_table(self, params):
         return params["head"]
 
@@ -547,6 +553,12 @@ class Xing4ServeModel(ServeModel):
                 block_k=self.decode_block_k, interpret=self._interpret)
         return lambda qa, qr: mla.reference_mla_paged_decode_attention(
             qa, qr, ckv_pool, kr_pool, lengths, block_table, scale=scale)
+
+    def _attend_chunk(self, *args, **kw):
+        if self._kernel:
+            return mla.mla_paged_prefill_attention(
+                *args, interpret=self._interpret, **kw)
+        return mla.reference_mla_paged_prefill_attention(*args, **kw)
 
     def forward_paged(self, params, tokens, cache, block_tables, write_valid,
                       *, return_hidden, row_valid=None, slot_index=None):
@@ -583,7 +595,7 @@ class Xing4ServeModel(ServeModel):
                             self._attend_decode(ckv_pool, kr_pool, lengths,
                                                 block_tables), cfg)[:, None]
                     else:
-                        o = mla.mla_paged_prefill_attention(
+                        o = self._attend_chunk(
                             qn, qr, ckv_pool, kr_pool, lengths, block_tables,
                             _w_ukv(ap, cfg), scale=cfg.softmax_scale)
                         o = o.reshape(b, t, -1)

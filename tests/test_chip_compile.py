@@ -15,6 +15,7 @@ steers that choice with ``monkeypatch`` — never through a product option.
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
 
@@ -535,6 +536,34 @@ def test_mla_decode_kernel(v5e):
     assert "mla_paged_decode_attn" in text
 
 
+@pytest.mark.parametrize(
+    "b,t,h,dn,dv,pps,masked",
+    [(1, X4_CHUNK, 32, 128, 128, X4_POSITIONS // X4_PAGE, False),
+     (4, 512, 64, 192, 256, 144, True)],
+    ids=["xing4", "glm52"])
+def test_mla_chunk_kernel(v5e, b, t, h, dn, dv, pps, masked):
+    """A chunk's expanded latent attention at the two cells' shapes: 32
+    heads of 128 + 64 / 128 over 2,048 rows, and four participants' 512
+    rows under the choice's mask, 64 heads of 192 + 64 / 256 (the key's
+    rotary part in the rest of the expanded key's second lane tile)."""
+    from mpit_tpu.ops.mla_attention import mla_paged_prefill_attention
+
+    bf, c, dr, ps = jnp.bfloat16, 512, 64, X4_PAGE
+    args = [_sds((b, t, h, dn), bf), _sds((b, t, h, dr), bf),
+            _sds((2 * pps, ps, c), bf), _sds((2 * pps, ps, 128), bf),
+            _sds((b,), jnp.int32), _sds((b, pps), jnp.int32),
+            _sds((c, h, dn + dv), bf)]
+    if masked:
+        args.append(_sds((b, t, pps * ps), bool))
+    text = _compile_on_chip(
+        v5e,
+        lambda *a: mla_paged_prefill_attention(
+            *a[:7], scale=(dn + dr) ** -0.5,
+            select=a[7] if masked else None, interpret=False),
+        *args)
+    assert "mla_paged_chunk_attn" in text
+
+
 @pytest.mark.parametrize("width", [512, 128], ids=["latent", "rope"])
 def test_paged_write_pages_latent_layout(v5e, width):
     """A chunk's rows into a buffer of the latent pool, a page at a time."""
@@ -608,8 +637,13 @@ def test_xing4_paged_steps_fit_and_update_in_place(xing4_engine, step):
     compiled = jit.lower(*on_chip(args)).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
-    assert ("mla_paged_decode_attn" if step == "decode"
-            else "paged_kv_write") in text
+    for name in (("mla_paged_decode_attn",) if step == "decode"
+                 else ("mla_paged_chunk_attn", "paged_kv_write")):
+        assert name in text, name
+    if step == "prefill":
+        # A chunk's scores stay in the kernel: no [participants, heads,
+        # rows, tile] float32 array is left in the step.
+        assert not re.search(rf"f32\[\d+,32,{X4_CHUNK},\d+\]", text)
     pool = jax.tree.leaves((cache.k, cache.v))
     want = {}
     for leaf in pool:
@@ -625,6 +659,9 @@ def test_xing4_paged_steps_fit_and_update_in_place(xing4_engine, step):
     assert mem.alias_size_in_bytes >= pool_bytes
     held = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    print(f"xing4 {step}: arguments {mem.argument_size_in_bytes / 1e9:.2f} "
+          f"GB, temporaries {mem.temp_size_in_bytes / 1e9:.2f} GB, held "
+          f"{held / 1e9:.2f} GB")
     assert held < V5E_HBM, held
     if step == "decode":
         # A tick's temporaries are O(rows): under the smallest buffer.
@@ -829,11 +866,14 @@ def test_glm_dsa_paged_steps_fit_and_update_in_place(glm_dsa_engine, step):
             eng.params, cache, eng.last_token, z,
             jnp.zeros((n, GD_CHUNK), jnp.int32), z, z, z,
             jnp.zeros((n,), bool), bt, key, f32, i32)
-        kernels = ("dsa_index_scores_chunk", "paged_kv_write")
+        kernels = ("dsa_index_scores_chunk", "mla_paged_chunk_attn",
+                   "paged_kv_write")
     compiled = jit.lower(*on_chip(args)).compile()
     text = compiled.as_text()
     for name in kernels:
         assert name in text, name
+    if step == "prefill":  # the scores stay in the kernel
+        assert not re.search(rf"f32\[\d+,64,{GD_CHUNK},\d+\]", text)
     mem = compiled.memory_analysis()
     pool = jax.tree.leaves((cache.k, cache.v, cache.x))
     assert len(pool) == 5 + 5 + 2

@@ -348,6 +348,7 @@ def test_a_tick_under_the_choice_takes_the_dense_kernel(tiny):
     assert {"mla_paged_decode_attn", "dsa_index_scores_tick"} <= names
 
 
+
 def _serve(engine, prompts, new=6):
     server = Server(engine)
     for i, p in enumerate(prompts):
@@ -533,19 +534,26 @@ def test_the_steps_lower_with_their_scope_and_kernel_names(tiny, step):
             eng.params, eng.cache, eng.last_token, jnp.ones((s,), bool), bt,
             key, f32, i32)
         scopes += ["mla_absorb", "dsa_sparse_attn"]
+        kernels = {"dsa_index_scores_tick", "mla_paged_decode_attn"}
+        form = "latent_absorbed"
     else:
         jit, args = eng._prefill_paged_jit, (
             eng.params, eng.cache, eng.last_token,
             jnp.zeros((s, eng.prefill_chunk), jnp.int32), i32, i32, i32,
             jnp.zeros((s,), bool), bt, key, f32, i32)
-        scopes += ["kv_gather", "mla_expand"]
+        # The chunk's kernel reads the pages in place: it and the
+        # layouts round it are the scope mla_expand, and kv_gather is
+        # the lax twin's alone.
+        scopes.append("mla_expand")
+        kernels = {"dsa_index_scores_chunk", "mla_paged_chunk_attn"}
+        form = "latent_expanded_kernel"
     text = jit.lower(*args).as_text(debug_info=True)
     assert f"module @jit_{step}_paged " in text
     for scope in scopes:
         assert re.search(rf'["/(]{scope}[/)]', text), scope
-    assert ("dsa_index_scores_tick" if step == "decode"
-            else "dsa_index_scores_chunk") in _pallas_names(
-        jax.make_jaxpr(jit)(*args).jaxpr)
+    assert kernels <= _pallas_names(jax.make_jaxpr(jit)(*args).jaxpr)
+    rows = 1 if step == "decode" else eng.prefill_chunk
+    assert eng.attention_tiling(rows)["attention_form"] == form
     out = jax.eval_shape(jit, *args)
     assert set(out[2]) == {"dsa_rows_read", "dsa_rows_cached",
                            "expert_tokens", "moe_choices", "moe_choices_here"}

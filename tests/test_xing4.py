@@ -260,6 +260,30 @@ def test_served_tokens_are_the_reference_argmax(tiny):
         assert float(gap.max()) < 1e-4, (rid, gap)
 
 
+def test_the_spans_say_which_latent_kernel_a_step_ran(tiny):
+    """A traced window counts the chunk steps the expanded kernel ran in:
+    every ``prefill`` span carries its form and blocks, every ``decode``
+    span the absorbed kernel's."""
+    from mpit_tpu import obs
+
+    cfg, params = tiny
+    rec = obs.Recorder()
+    with obs.local_recorder(rec):
+        _serve(_engine(cfg, params, mode="interpret"),
+               _prompts(cfg, lens=(19,)), new=3)
+    events = rec.snapshot()["events"]
+    for name, want in (
+            ("prefill", {"attention_form": "latent_expanded_kernel",
+                         "attention_rows": 1024, "attention_query_rows": 8}),
+            ("decode", {"attention_form": "latent_absorbed",
+                        "attention_rows": 16})):
+        spans = [e[5] for e in events if e[1] == name]
+        assert spans
+        for attrs in spans:
+            assert want.items() <= attrs.items()
+            assert ("attention_query_rows" in attrs) == (name == "prefill")
+
+
 def test_compacted_chunk_tick_equals_the_full_batch_one(tiny, monkeypatch):
     """Past the row rule the chunk tick runs over its participants only,
     in steps compiled for a power-of-two count of them: same tokens, same
@@ -362,8 +386,11 @@ def test_the_steps_lower_with_their_scope_and_kernel_names(tiny, step):
             eng.params, eng.cache, eng.last_token,
             jnp.zeros((s, eng.prefill_chunk), jnp.int32), i32, i32, i32,
             jnp.zeros((s,), bool), bt, key, f32, i32)
-        scopes += ["kv_gather", "mla_expand"]
-        kernels = set()
+        # The chunk's kernel reads the pages in place: it and the
+        # layouts round it are the scope mla_expand, and kv_gather is
+        # the lax twin's alone.
+        scopes.append("mla_expand")
+        kernels = {"mla_paged_chunk_attn"}
     lowered = jit.lower(*args)
     text = lowered.as_text(debug_info=True)
     assert f"module @jit_{step}_paged " in text
